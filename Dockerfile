@@ -22,7 +22,7 @@ ARG JAX_EXTRA=cpu
 WORKDIR /app
 COPY pyproject.toml /app/
 COPY llms_on_kubernetes_tpu /app/llms_on_kubernetes_tpu
-RUN pip install --no-cache-dir "jax[${JAX_EXTRA}]>=0.4.30" \
+RUN pip install --no-cache-dir "jax[${JAX_EXTRA}]==0.9.0" \
     && pip install --no-cache-dir ".[serve,hf]"
 COPY --from=native-builder /src/native/router/llkt-router /usr/local/bin/
 COPY --from=native-builder /src/native/loader/libstload.so /app/native/loader/

@@ -32,10 +32,10 @@ def main() -> int:
     ap.add_argument("--dtype", default=None)
     args = ap.parse_args()
 
-    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
+
+    from llms_on_kubernetes_tpu.cli import configure_compilation_cache
+    configure_compilation_cache()
 
     from llms_on_kubernetes_tpu.configs import REGISTRY, get_config
     from llms_on_kubernetes_tpu.engine.engine import Engine, EngineConfig, SamplingParams

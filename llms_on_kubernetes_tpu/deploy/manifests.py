@@ -226,6 +226,10 @@ def _engine_container(m: ModelSpec, spec: DeploySpec) -> Manifest:
         c["volumeMounts"] = [{
             "name": "hf-cache", "mountPath": "/root/.cache/huggingface",
         }]
+        # XLA's persistent compile cache beside the weights, on the same
+        # PVC: a restarted pod loads its executables instead of compiling
+        c["env"].append({"name": "JAX_COMPILATION_CACHE_DIR",
+                         "value": "/root/.cache/huggingface/xla_cache"})
     elif spec.host_model_path:
         c["volumeMounts"] = [{
             "name": "models", "mountPath": "/mnt/models", "readOnly": True,

@@ -220,7 +220,7 @@ def alert_rules() -> dict[str, Any]:
                                 "{{ $value }}s over the last 30m; "
                                 "scale-out arrives too late to help a "
                                 "spike. Check the persistent compile "
-                                "cache (LLMK_COMPILE_CACHE_DIR on the "
+                                "cache (JAX_COMPILATION_CACHE_DIR on the "
                                 "weight PVC) and weight-load times."
                             ),
                         },

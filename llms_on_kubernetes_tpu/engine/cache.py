@@ -117,21 +117,25 @@ class KVPool:
                 f"dtype={self.data.dtype}, quantized={self.quantized})")
 
 
-def init_pages(cfg: CacheConfig) -> tuple[KVPool, KVPool]:
+def init_pages(cfg: CacheConfig, sharding=None) -> tuple[KVPool, KVPool]:
     """Flat head-major pools [n_kv, L * P, page, d] (layer l's block starts
-    at l * P; see module docstring for why the layer axis is folded in)."""
+    at l * P; see module docstring for why the layer axis is folded in).
+    ``sharding`` (parallel/sharding.pool_sharding on the engine's mesh)
+    creates every leaf already sharded, so no device ever holds a whole
+    pool."""
     shape = (cfg.num_kv_heads, cfg.num_layers * cfg.num_pages,
              cfg.page_size, cfg.head_dim)
     if cfg.kv_dtype == "int8":
         def one():
-            return KVPool(jnp.zeros(shape, jnp.int8),
-                          jnp.zeros(shape[:3], jnp.float32))
+            return KVPool(jnp.zeros(shape, jnp.int8, device=sharding),
+                          jnp.zeros(shape[:3], jnp.float32, device=sharding))
         return one(), one()
     if cfg.kv_dtype is not None:
         raise ValueError(f"unsupported kv_dtype {cfg.kv_dtype!r} "
                          f"(None or 'int8')")
     dt = jnp.dtype(cfg.dtype)
-    return KVPool(jnp.zeros(shape, dt)), KVPool(jnp.zeros(shape, dt))
+    return (KVPool(jnp.zeros(shape, dt, device=sharding)),
+            KVPool(jnp.zeros(shape, dt, device=sharding)))
 
 
 def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:

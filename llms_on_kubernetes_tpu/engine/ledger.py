@@ -24,8 +24,8 @@ but never counted as useful stream time.
 FLOPs/bytes ride the same records (2 * active-params per token for
 compute; weight + KV-page traffic for memory), giving the ``llm_mfu_
 ratio`` / ``llm_mbu_ratio`` gauges (Chowdhery et al., PaLM 2022). On
-CPU smoke runs the peak table falls back to a nominal figure — the
-ratios are plumbing-real but not hardware-meaningful there (see
+the CPU platform the peak is a nominal figure — the ratios are
+plumbing-real but not hardware-meaningful there (see
 k8s/tpu-models/README.md "Goodput & chip-time accounting").
 
 :class:`StepAnomalyDetector` watches the same per-dispatch durations
@@ -46,45 +46,48 @@ from typing import Any, Optional
 PHASES = ("prefill", "decode", "spec_waste", "early_exit")
 WASTE_PHASES = ("spec_waste", "early_exit")
 
-# device_kind substring -> (peak dense bf16 FLOP/s, peak HBM bytes/s).
-# Nominal public figures; overridable via LLMK_PEAK_TFLOPS / LLMK_PEAK_GBPS
-# for hardware the table has never heard of.
-_PEAK_TABLE = (
-    ("v6e", (918e12, 1640e9)),
-    ("v5p", (459e12, 2765e9)),
-    ("v5e", (197e12, 819e9)),  # matches "v5 lite" kinds via the v5e alias
-    ("v5litepod", (197e12, 819e9)),
-    ("v4", (275e12, 1228e9)),
-    ("v3", (123e12, 900e9)),
-)
-# CPU / unknown accelerator: a deliberately small nominal peak so smoke
-# MFU is a sane nonzero ratio instead of ~0 against a TPU-sized peak.
-_PEAK_FALLBACK = (5e11, 5e10)
+# device_kind, exactly as the runtime reports it -> (peak dense bf16
+# FLOP/s, peak HBM bytes/s, source). A v5e chip reports "TPU v5 lite"
+# (read on the chip, jax 0.9.0 / libtpu 0.0.34); the other spellings are
+# the marketing names the same runtimes have used for the same parts.
+_V5E = (197e12, 819e9, "cloud.google.com/tpu/docs/v5e")
+_V5P = (459e12, 2765e9, "cloud.google.com/tpu/docs/v5p")
+_V6E = (918e12, 1640e9, "cloud.google.com/tpu/docs/v6e")
+_PEAKS = {
+    "TPU v5 lite": _V5E, "TPU v5e": _V5E,
+    "TPU v5": _V5P, "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E, "TPU v6e": _V6E,
+    "TPU v4": (275e12, 1228e9, "cloud.google.com/tpu/docs/v4"),
+    "TPU v3": (123e12, 900e9, "cloud.google.com/tpu/docs/v3"),
+}
+# CPU only: a deliberately small nominal peak so smoke MFU is a sane
+# nonzero ratio instead of ~0 against a TPU-sized peak.
+_CPU_NOMINAL = (5e11, 5e10)
 
 
 def detect_peak() -> tuple[float, float]:
     """(peak FLOP/s, peak bytes/s) for the local accelerator.
 
-    Env overrides win; else the device kind maps through the table;
-    else the nominal CPU fallback. Never raises — the ledger must work
-    wherever the engine does."""
+    LLMK_PEAK_TFLOPS + LLMK_PEAK_GBPS win (hardware the table has never
+    heard of); else the device kind must be in ``_PEAKS``. An accelerator
+    that is not is an error naming the string it reports — a default peak
+    would put a wrong MFU/MBU on every dashboard without complaint. Only
+    the CPU platform gets the nominal figure."""
     flops = os.environ.get("LLMK_PEAK_TFLOPS")
     gbps = os.environ.get("LLMK_PEAK_GBPS")
     if flops and gbps:
-        try:
-            return float(flops) * 1e12, float(gbps) * 1e9
-        except ValueError:
-            pass
-    try:
-        import jax
+        return float(flops) * 1e12, float(gbps) * 1e9
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower().replace(" ", "")
-        for key, peaks in _PEAK_TABLE:
-            if key in kind:
-                return peaks
-    except Exception:
-        pass
-    return _PEAK_FALLBACK
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return _CPU_NOMINAL
+    if dev.device_kind not in _PEAKS:
+        raise RuntimeError(
+            f"no peak FLOP/s and HBM bytes/s known for accelerator "
+            f"device_kind {dev.device_kind!r}: add it to engine/ledger.py "
+            f"_PEAKS, or set LLMK_PEAK_TFLOPS and LLMK_PEAK_GBPS")
+    return _PEAKS[dev.device_kind][:2]
 
 
 def _active_params(cfg: Any) -> int:

@@ -54,12 +54,26 @@ _lib_tried = False
 
 
 def _find_lib() -> Optional[str]:
+    """LLMK_NATIVE_LOADER_PATH (the image's prebuilt library), else the
+    checkout's native/loader/libstload.so — BUILT from the tracked source
+    first (``make`` is a no-op when it is fresh): a git-ignored binary
+    found lying in the tree says nothing about the source beside it, and
+    a failed build is an error, not a reason to read another way. None
+    only outside a checkout (an installed package without native/)."""
     override = os.environ.get("LLMK_NATIVE_LOADER_PATH")
     if override:
         return override if os.path.exists(override) else None
-    root = pathlib.Path(__file__).resolve().parents[2]
-    cand = root / "native" / "loader" / "libstload.so"
-    return str(cand) if cand.exists() else None
+    src = pathlib.Path(__file__).resolve().parents[2] / "native" / "loader"
+    if not (src / "Makefile").exists():
+        return None
+    import subprocess
+
+    r = subprocess.run(["make", "-C", str(src)], capture_output=True,
+                       text=True)
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"building native/loader failed:\n{r.stderr[-2000:]}")
+    return str(src / "libstload.so")
 
 
 def _load_lib() -> Optional[ctypes.CDLL]:

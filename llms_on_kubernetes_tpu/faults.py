@@ -13,10 +13,6 @@ Spec grammar::
 
 Known fault names (each documented at its injection site):
 
-- ``backend_hang``        — accelerator-backend initialization never
-  returns (simulates a wedged TPU runtime).  Injected immediately before
-  the first backend touch in ``bench.py``'s probe subprocess; the parent's
-  hard timeout must convert it into a clean JSON error.
 - ``engine_stall[:N]``    — the harvester never observes completion of the
   N-th (default: first) device step, simulating a hung device program.
   The engine watchdog must detect it and shed in-flight work.

@@ -18,6 +18,7 @@ Layout choices (TPU-first):
 from __future__ import annotations
 
 import os
+import sys
 from typing import Optional
 
 import jax
@@ -26,22 +27,96 @@ import jax.numpy as jnp
 NEG_INF = -2.0e38  # large finite negative; avoids NaN from (-inf) - (-inf)
 
 
-def use_pallas_kernels() -> bool:
-    """Kernel selection: LLMK_ATTENTION_IMPL = pallas | xla | auto.
+def pallas_mode() -> Optional[str]:
+    """How the Pallas kernels run here: "compiled", "interpret" or None
+    (the XLA reference ops). LLMK_ATTENTION_IMPL = pallas | xla | auto.
 
-    auto (default) picks the Pallas kernels on TPU and the XLA reference
+    auto (default) compiles the kernels on TPU and takes the XLA reference
     path everywhere else (CPU tests, local/ramalama-equivalent serving).
+    ``pallas`` on the CPU backend runs them through the Pallas interpreter
+    (how the CPU tests pin kernel semantics); the interpreter is never
+    chosen on an accelerator.
     """
     impl = os.environ.get("LLMK_ATTENTION_IMPL", "auto")
-    if impl == "pallas":
-        return True
-    if impl == "xla":
-        return False
-    if impl != "auto":
+    if impl not in ("pallas", "xla", "auto"):
         raise ValueError(
             f"LLMK_ATTENTION_IMPL={impl!r} is not one of pallas|xla|auto"
         )
-    return jax.default_backend() == "tpu"
+    backend = jax.default_backend()
+    if impl == "xla" or (impl == "auto" and backend != "tpu"):
+        return None
+    return "interpret" if backend == "cpu" else "compiled"
+
+
+def _no_pallas_why() -> str:
+    """Why pallas_mode() is None, for the dispatchers' records."""
+    if os.environ.get("LLMK_ATTENTION_IMPL") == "xla":
+        return "LLMK_ATTENTION_IMPL=xla"
+    return f"{jax.default_backend()} backend"
+
+
+def check_interpret(interpret: bool) -> bool:
+    """Guard every ``pallas_call(interpret=...)``: on an accelerator the
+    interpreter would run the kernel as slow XLA ops and still answer —
+    exactly the kind of fallback that hides the device."""
+    if interpret and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"Pallas interpret mode requested on the "
+            f"{jax.default_backend()!r} backend; it is a CPU-only test path")
+    return interpret
+
+
+# Scratch + temporaries a kernel may claim of a TensorCore's VMEM (128 MiB
+# on v4/v5e/v6e), leaving room for Mosaic's own internal scratch. The
+# dispatchers check each kernel's estimate against it and take the XLA path
+# (saying so) rather than let Mosaic discover the overflow.
+VMEM_BUDGET_BYTES = 96 << 20
+
+# op -> (impl, why): what each dispatcher last chose, recorded at trace
+# time. Every change is printed once to stderr as
+# "[attention] op=<op> impl=<impl> why=<why>" so a log reader outside the
+# process (chip_smoke.py) can tell which implementation actually ran.
+_chosen: dict[str, tuple[str, str]] = {}
+
+
+def _choose(op: str, impl: str, why: str) -> None:
+    if _chosen.get(op) != (impl, why):
+        _chosen[op] = (impl, why)
+        print(f"[attention] op={op} impl={impl} why={why}",
+              file=sys.stderr, flush=True)
+
+
+def _per_kv_head_shard(fn, n_kv: int, args, head_axes, out_head_axes):
+    """Run a Pallas call once per tensor-parallel shard.
+
+    XLA cannot partition a custom call: given operands sharded over
+    ``model`` it would first gather them (the whole KV pool) onto every
+    chip. The pool and q/k/v are already sharded on their head axes
+    (parallel/sharding.py), so each device runs the kernel on its own
+    heads under ``shard_map``, as ops/cp.py does for ``seq``.
+    ``head_axes[i]`` is the head axis of ``args[i]`` (None = replicated);
+    ``out_head_axes`` mirrors ``fn``'s outputs. Calls ``fn(*args)``
+    directly when there is nothing to shard: no mesh, model=1, or kv heads
+    the model axis does not divide (the pool is then replicated, see
+    parallel/sharding._axis)."""
+    from jax.sharding import PartitionSpec as P
+
+    from llms_on_kubernetes_tpu.parallel.mesh import AXIS_MODEL, get_active_mesh
+
+    mesh = get_active_mesh()
+    tp = int(mesh.shape[AXIS_MODEL]) if mesh is not None else 1
+    if tp == 1 or n_kv % tp != 0:
+        return fn(*args)
+
+    def spec(ax):
+        return P() if ax is None else P(*([None] * ax), AXIS_MODEL)
+
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(spec(ax) for ax in head_axes),
+        out_specs=jax.tree.map(spec, out_head_axes),
+        check_vma=False,
+    )(*args)
 
 
 def softcap(logits: jnp.ndarray, cap: Optional[float]) -> jnp.ndarray:
@@ -229,11 +304,18 @@ def chunk_attention(
 # ---------------------------------------------------------------------------
 # Dispatchers (what the decoder calls)
 # ---------------------------------------------------------------------------
+# Each dispatcher picks a compiled Pallas kernel, the interpreted kernel
+# (CPU tests under LLMK_ATTENTION_IMPL=pallas) or the XLA reference op from
+# what it can observe at trace time, and records the pick through _choose.
 
 def _static_window(w) -> bool:
     # Gemma-style interleaved layers trace the window as a scalar inside
     # lax.scan; the Pallas kernels need it static -> fall back to XLA there.
     return w is None or isinstance(w, int)
+
+
+def _mib(n: int) -> str:
+    return f"{n / (1 << 20):.0f} MiB"
 
 
 def dispatch_prefill_attention(q, k, v, lengths, *, scale, sliding_window=None,
@@ -242,6 +324,7 @@ def dispatch_prefill_attention(q, k, v, lengths, *, scale, sliding_window=None,
         # multimodal prompts take the XLA reference path: the image-block
         # bidirectional mask is a [B, T, T] override the flash/ring
         # kernels don't express (yet)
+        _choose("prefill", "xla", "multimodal image-block mask")
         return prefill_attention(q, k, v, lengths, scale=scale,
                                  sliding_window=sliding_window,
                                  attn_softcap=attn_softcap,
@@ -253,26 +336,44 @@ def dispatch_prefill_attention(q, k, v, lengths, *, scale, sliding_window=None,
     # SURVEY §5 noted the reference had no long-context story at all.
     from llms_on_kubernetes_tpu.parallel.mesh import get_active_mesh, seq_parallelism
 
-    if seq_parallelism() > 1 and _static_window(sliding_window):
+    static = _static_window(sliding_window)
+    if seq_parallelism() > 1 and static:
         from llms_on_kubernetes_tpu.ops.ring_attention import ring_prefill_attention
 
+        _choose("prefill", "ring", f"seq-parallel mesh ({seq_parallelism()})")
         return ring_prefill_attention(
             q, k, v, lengths, get_active_mesh(), scale=scale,
             attn_softcap=attn_softcap, sliding_window=sliding_window,
         )
-    if use_pallas_kernels() and _static_window(sliding_window):
-        from llms_on_kubernetes_tpu.ops.pallas_flash import BLOCK_Q, flash_prefill_attention
+    mode = pallas_mode()
+    why = None
+    if mode is None:
+        why = _no_pallas_why()
+    elif not static:
+        why = "traced (per-layer) sliding window"
+    else:
+        from llms_on_kubernetes_tpu.ops.pallas_flash import (
+            BLOCK_Q, flash_prefill_attention, flash_vmem_bytes,
+        )
 
-        T = q.shape[1]
-        if T % min(BLOCK_Q, T) == 0:
-            return flash_prefill_attention(
-                q, k, v, lengths, scale=scale,
-                sliding_window=sliding_window, attn_softcap=attn_softcap,
-                interpret=jax.default_backend() == "cpu",
-            )
-    return prefill_attention(q, k, v, lengths, scale=scale,
-                             sliding_window=sliding_window,
-                             attn_softcap=attn_softcap)
+        T, d = q.shape[1], q.shape[3]
+        need = flash_vmem_bytes(T, d, q.dtype.itemsize)
+        if T % min(BLOCK_Q, T) != 0:
+            why = f"bucket {T} is not a multiple of {BLOCK_Q}"
+        elif need > VMEM_BUDGET_BYTES:
+            why = (f"bucket {T} needs {_mib(need)} VMEM > "
+                   f"{_mib(VMEM_BUDGET_BYTES)} budget")
+    if why is not None:
+        _choose("prefill", "xla", why)
+        return prefill_attention(q, k, v, lengths, scale=scale,
+                                 sliding_window=sliding_window,
+                                 attn_softcap=attn_softcap)
+    _choose("prefill", f"pallas-{mode}", f"flash kernel, bucket {T}")
+    return _per_kv_head_shard(
+        lambda q, k, v, lengths: flash_prefill_attention(
+            q, k, v, lengths, scale=scale, sliding_window=sliding_window,
+            attn_softcap=attn_softcap, interpret=mode == "interpret"),
+        k.shape[2], (q, k, v, lengths), (2, 2, 2, None), 2)
 
 
 def dispatch_chunk_attention(q, k_pages, v_pages, page_table, history,
@@ -287,6 +388,7 @@ def dispatch_chunk_attention(q, k_pages, v_pages, page_table, history,
         # replicated inputs (pinned by tests/test_cp.py)
         from llms_on_kubernetes_tpu.ops.cp import cp_chunk_attention
 
+        _choose("chunk", "cp", f"seq-parallel mesh ({seq_parallelism()})")
         return cp_chunk_attention(
             q, k_pages, v_pages, page_table, history, chunk_lengths,
             scale=scale, sliding_window=sliding_window,
@@ -294,10 +396,41 @@ def dispatch_chunk_attention(q, k_pages, v_pages, page_table, history,
     # XLA gather path everywhere for now: chunked prefill is bandwidth-bound
     # on the page gather, which XLA fuses acceptably; a Pallas paged-flash
     # chunk kernel is the designated upgrade path (see pallas_flash.py).
+    _choose("chunk", "xla", "no chunk kernel")
     return chunk_attention(q, k_pages, v_pages, page_table, history,
                            chunk_lengths, scale=scale,
                            sliding_window=sliding_window,
                            attn_softcap=attn_softcap)
+
+
+def _paged_kernel_mode(q, k_pages, page_table, sliding_window):
+    """(mode, why) for the paged decode kernels on these operands: mode
+    is pallas_mode() when a kernel applies, else None with the reason."""
+    from llms_on_kubernetes_tpu.ops.pallas_paged import paged_vmem_bytes
+
+    mode = pallas_mode()
+    if mode is None:
+        return None, _no_pallas_why()
+    if not _static_window(sliding_window):
+        return None, "traced (per-layer) sliding window"
+    quantized = getattr(k_pages, "quantized", False)
+    kd = getattr(k_pages, "data", k_pages)
+    n_kv, _, page, d = kd.shape
+    if mode == "compiled":
+        # Mosaic tiling on real TPU (the interpreter takes any shape): the
+        # manual page DMA needs a lane-aligned head_dim (d=64/96 models —
+        # TinyLlama, Phi-3 — take the XLA gather path), and the int8
+        # kernels' per-token scale DMAs land at lane offset i*page_size
+        if d % 128 != 0:
+            return None, f"head_dim {d} is not a multiple of 128"
+        if quantized and page % 128 != 0:
+            return None, f"int8 KV needs page_size % 128 == 0, got {page}"
+    need = paged_vmem_bytes(n_kv, page_table.shape[1] * page, d,
+                            kd.dtype.itemsize, quantized)
+    if need > VMEM_BUDGET_BYTES:
+        return None, (f"slot of {page_table.shape[1] * page} tokens needs "
+                      f"{_mib(need)} VMEM > {_mib(VMEM_BUDGET_BYTES)} budget")
+    return mode, ""
 
 
 def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
@@ -305,7 +438,7 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
                                    sliding_window=None, attn_softcap=None):
     """Decode attention WITH the current token's KV append.
 
-    On the Pallas fast path the write folds INTO the attention kernel
+    Under kv_write="fused" the write folds INTO the attention kernel
     (pallas_paged.pallas_paged_attention_write): the per-slot program DMAs
     the new row into the pool in place and merges the current token's
     contribution in registers — eliminating the per-slot DUS write loop
@@ -314,70 +447,58 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
     (pallas_paged_attention_write_int8): the new row is quantized in
     registers with the same arithmetic as cache.quantize_kv, so pool
     bytes match the DUS path exactly. Anywhere the fused kernels don't
-    apply (CP meshes, traced gemma windows, sub-128 head_dim on real TPU,
-    kv_write config other than "fused") this is exactly write_tokens +
-    dispatch_paged_attention.
+    apply (CP meshes, whatever _paged_kernel_mode rules out, sub-8 page
+    sizes on real TPU, kv_write config other than "fused") this is
+    exactly write_tokens + dispatch_paged_attention.
 
     q [B, n_q, d]; k_new/v_new [B, n_kv, d] (post-rope);
     write_positions [B, 1] (negative => idle/trash).
     Returns (attn [B, n_q, d], k_pages, v_pages)."""
-    from llms_on_kubernetes_tpu.engine.cache import kv_write_strategy
+    from llms_on_kubernetes_tpu.engine.cache import KVPool, kv_write_strategy
     from llms_on_kubernetes_tpu.ops.cp import dispatch_write_tokens
     from llms_on_kubernetes_tpu.parallel.mesh import seq_parallelism
 
-    on_cpu = jax.default_backend() == "cpu"
-    d_ok = q.shape[-1] % 128 == 0 or on_cpu
-    # the in-kernel append is an 8-token-block RMW (Mosaic sublane tiling):
-    # sub-8 page sizes can't host an aligned block
-    kd_shape = getattr(k_pages, "data", k_pages).shape
-    page_ok = kd_shape[2] % 8 == 0 or on_cpu
-    quantized = getattr(k_pages, "quantized", False)
-    # the int8 twin additionally RMWs full [n_kv, page] scale rows, which
-    # Mosaic only accepts 128-lane-aligned on real TPU (same constraint
-    # as the read-only int8 decode kernel below)
-    page_ok_int8 = kd_shape[2] % 128 == 0 or on_cpu
-    fused = (kv_write_strategy() == "fused"
-             and seq_parallelism() == 1
-             and use_pallas_kernels() and _static_window(sliding_window)
-             and d_ok and page_ok
-             and (not quantized or page_ok_int8))
-    if fused and quantized:
-        from llms_on_kubernetes_tpu.engine.cache import KVPool
-        from llms_on_kubernetes_tpu.ops.pallas_paged import (
-            pallas_paged_attention_write_int8,
-        )
+    mode = None
+    if kv_write_strategy() == "fused" and seq_parallelism() == 1:
+        mode, _ = _paged_kernel_mode(q, k_pages, page_table, sliding_window)
+        kd_shape = getattr(k_pages, "data", k_pages).shape
+        # the in-kernel append is an 8-token-block RMW (Mosaic sublane
+        # tiling): sub-8 page sizes can't host an aligned block
+        if mode == "compiled" and kd_shape[2] % 8 != 0:
+            mode = None
+    if mode is None:
+        k_pages, v_pages = dispatch_write_tokens(
+            k_pages, v_pages, k_new[:, None], v_new[:, None], page_table,
+            write_positions)
+        attn = dispatch_paged_attention(
+            q, k_pages, v_pages, page_table, lengths, scale=scale,
+            sliding_window=sliding_window, attn_softcap=attn_softcap)
+        return attn, k_pages, v_pages
 
-        attn, kd, ks, vd, vs = pallas_paged_attention_write_int8(
-            q, k_pages.data, k_pages.scale, v_pages.data, v_pages.scale,
-            page_table, lengths, k_new, v_new, scale=scale,
-            sliding_window=sliding_window, attn_softcap=attn_softcap,
-            interpret=on_cpu,
-        )
+    from llms_on_kubernetes_tpu.ops import pallas_paged
+
+    kw = dict(scale=scale, sliding_window=sliding_window,
+              attn_softcap=attn_softcap, interpret=mode == "interpret")
+    n_kv = k_new.shape[1]
+    if getattr(k_pages, "quantized", False):
+        _choose("decode", f"pallas-{mode}", "fused int8 write+attend kernel")
+        attn, kd, ks, vd, vs = _per_kv_head_shard(
+            lambda *a: pallas_paged.pallas_paged_attention_write_int8(*a, **kw),
+            n_kv,
+            (q, k_pages.data, k_pages.scale, v_pages.data, v_pages.scale,
+             page_table, lengths, k_new, v_new),
+            (1, 0, 0, 0, 0, None, None, 1, 1), (1, 0, 0, 0, 0))
         return attn, KVPool(kd, ks), KVPool(vd, vs)
-    if fused:
-        from llms_on_kubernetes_tpu.ops.pallas_paged import (
-            pallas_paged_attention_write,
-        )
-
-        kd = getattr(k_pages, "data", k_pages)
-        vd = getattr(v_pages, "data", v_pages)
-        attn, kd, vd = pallas_paged_attention_write(
-            q, kd, vd, page_table, lengths, k_new, v_new, scale=scale,
-            sliding_window=sliding_window, attn_softcap=attn_softcap,
-            interpret=jax.default_backend() == "cpu",
-        )
-        if hasattr(k_pages, "data"):
-            from llms_on_kubernetes_tpu.engine.cache import KVPool
-
-            return attn, KVPool(kd), KVPool(vd)
-        return attn, kd, vd
-    k_pages, v_pages = dispatch_write_tokens(
-        k_pages, v_pages, k_new[:, None], v_new[:, None], page_table,
-        write_positions)
-    attn = dispatch_paged_attention(
-        q, k_pages, v_pages, page_table, lengths, scale=scale,
-        sliding_window=sliding_window, attn_softcap=attn_softcap)
-    return attn, k_pages, v_pages
+    _choose("decode", f"pallas-{mode}", "fused write+attend kernel")
+    attn, kd, vd = _per_kv_head_shard(
+        lambda *a: pallas_paged.pallas_paged_attention_write(*a, **kw),
+        n_kv,
+        (q, getattr(k_pages, "data", k_pages),
+         getattr(v_pages, "data", v_pages), page_table, lengths, k_new, v_new),
+        (1, 0, 0, None, None, 1, 1), (1, 0, 0))
+    if hasattr(k_pages, "data"):
+        return attn, KVPool(kd), KVPool(vd)
+    return attn, kd, vd
 
 
 def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,
@@ -391,45 +512,33 @@ def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,
         # (traced gemma window sizes hoist through the shard_map fine)
         from llms_on_kubernetes_tpu.ops.cp import cp_paged_attention
 
+        _choose("decode", "cp", f"seq-parallel mesh ({seq_parallelism()})")
         return cp_paged_attention(
             q, k_pages, v_pages, page_table, lengths, scale=scale,
             sliding_window=sliding_window, attn_softcap=attn_softcap)
-    # The decode kernel's manual page DMA needs a lane-aligned head_dim on
-    # real TPU (Mosaic pads the pool's minor dim to 128 and rejects sub-tile
-    # slices); d=64/96 models (TinyLlama, Phi-3) take the XLA gather path.
-    d_ok = q.shape[-1] % 128 == 0 or jax.default_backend() == "cpu"
-    if use_pallas_kernels() and _static_window(sliding_window) and d_ok:
-        if getattr(k_pages, "quantized", False):
-            # the int8 kernel's scale DMAs land at lane offset i*page_size,
-            # which Mosaic only accepts 128-aligned: off-TPU (interpret)
-            # any page works, on TPU page_size must be a 128 multiple
-            # (engine warns at startup otherwise and this falls back to
-            # the XLA gather path)
-            page_ok = (k_pages.data.shape[2] % 128 == 0
-                       or jax.default_backend() == "cpu")
-            if page_ok:
-                from llms_on_kubernetes_tpu.ops.pallas_paged import (
-                    pallas_paged_attention_int8,
-                )
+    mode, why = _paged_kernel_mode(q, k_pages, page_table, sliding_window)
+    if mode is None:
+        _choose("decode", "xla", why)
+        return paged_attention(q, k_pages, v_pages, page_table, lengths,
+                               scale=scale, sliding_window=sliding_window,
+                               attn_softcap=attn_softcap)
 
-                return pallas_paged_attention_int8(
-                    q, k_pages.data, k_pages.scale, v_pages.data,
-                    v_pages.scale, page_table, lengths, scale=scale,
-                    sliding_window=sliding_window, attn_softcap=attn_softcap,
-                    interpret=jax.default_backend() == "cpu",
-                )
-            return paged_attention(q, k_pages, v_pages, page_table, lengths,
-                                   scale=scale, sliding_window=sliding_window,
-                                   attn_softcap=attn_softcap)
-        from llms_on_kubernetes_tpu.ops.pallas_paged import pallas_paged_attention
+    from llms_on_kubernetes_tpu.ops import pallas_paged
 
-        return pallas_paged_attention(
-            q, getattr(k_pages, "data", k_pages),
-            getattr(v_pages, "data", v_pages), page_table, lengths,
-            scale=scale, sliding_window=sliding_window,
-            attn_softcap=attn_softcap,
-            interpret=jax.default_backend() == "cpu",
-        )
-    return paged_attention(q, k_pages, v_pages, page_table, lengths,
-                           scale=scale, sliding_window=sliding_window,
-                           attn_softcap=attn_softcap)
+    kw = dict(scale=scale, sliding_window=sliding_window,
+              attn_softcap=attn_softcap, interpret=mode == "interpret")
+    kd = getattr(k_pages, "data", k_pages)
+    if getattr(k_pages, "quantized", False):
+        _choose("decode", f"pallas-{mode}", "int8 paged kernel")
+        return _per_kv_head_shard(
+            lambda *a: pallas_paged.pallas_paged_attention_int8(*a, **kw),
+            kd.shape[0],
+            (q, k_pages.data, k_pages.scale, v_pages.data, v_pages.scale,
+             page_table, lengths),
+            (1, 0, 0, 0, 0, None, None), 1)
+    _choose("decode", f"pallas-{mode}", "paged kernel")
+    return _per_kv_head_shard(
+        lambda *a: pallas_paged.pallas_paged_attention(*a, **kw),
+        kd.shape[0],
+        (q, kd, getattr(v_pages, "data", v_pages), page_table, lengths),
+        (1, 0, 0, None, None), 1)

@@ -34,8 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from llms_on_kubernetes_tpu.ops.shard_map_compat import shard_map
-
 from llms_on_kubernetes_tpu.ops.attention import NEG_INF, _gather_pool, softcap
 from llms_on_kubernetes_tpu.parallel.mesh import (
     AXIS_MODEL, AXIS_SEQ, get_active_mesh, seq_parallelism,
@@ -86,11 +84,11 @@ def dispatch_write_tokens(k_pages, v_pages, k, v, page_table, positions):
         W = (kp.data if hasattr(kp, "data") else kp).shape[1]
         return write_tokens(kp, vp, kk, vv, pt, pos, owner=(r * W, W))
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pool_spec, pool_spec, kv_spec, kv_spec, P(), P()),
         out_specs=(pool_spec, pool_spec),
-        check=False,
+        check_vma=False,
     )(k_pages, v_pages, k, v, page_table, positions)
 
 
@@ -160,11 +158,11 @@ def cp_paged_attention(q, k_pages, v_pages, page_table, lengths, *, scale,
         out = _merge_partials(num, den, m, AXIS_SEQ)     # [B, nk, g, d]
         return out.reshape(B, qq.shape[1], d).astype(qq.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, P(), P()),
         out_specs=q_spec,
-        check=False,
+        check_vma=False,
     )(q, k_pages, v_pages, page_table, lengths)
 
 
@@ -213,9 +211,9 @@ def cp_chunk_attention(q, k_pages, v_pages, page_table, history,
         out = out.transpose(0, 3, 1, 2, 4).reshape(B, T, qq.shape[2], d)
         return out.astype(qq.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, P(), P(), P()),
         out_specs=q_spec,
-        check=False,
+        check_vma=False,
     )(q, k_pages, v_pages, page_table, history, chunk_lengths)

@@ -136,19 +136,18 @@ def lora_delta(eq: str, x: jnp.ndarray, lora: LoRAStack,
             and lora.rank % mesh.shape[ax] == 0:
         from jax.sharding import PartitionSpec as P
 
-        from llms_on_kubernetes_tpu.ops.shard_map_compat import shard_map
-
         def local(xf_, idx_, a_, b_):
             # each device scans its rank shard; delta is a sum over rank
             return jax.lax.psum(scan_slots(xf_, idx_, a_, b_), ax)
 
         out_ndim = len(eq.split("->")[1])
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(*([None] * xf.ndim)), P(None),
                       P(*([None] * (a.ndim - 1) + [ax])),
                       P(None, ax, *([None] * (b.ndim - 2)))),
             out_specs=P(*([None] * out_ndim)),
+            check_vma=False,
         )(xf, idx, a, b)
     return scan_slots(xf, idx, a, b)
 

@@ -33,9 +33,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llms_on_kubernetes_tpu.ops.attention import NEG_INF, softcap
+from llms_on_kubernetes_tpu.ops.attention import (
+    NEG_INF, check_interpret, softcap,
+)
 
 BLOCK_Q = 128
+
+
+def flash_vmem_bytes(T: int, d: int, itemsize: int) -> int:
+    """VMEM one program needs at bucket T: the head's K and V blocks
+    (double-buffered by the pipeline) and their f32 casts, four
+    [BLOCK_Q, T] f32 tiles (logits, mask, probabilities, exp temporaries)
+    and 4 MiB for the q/o blocks and Mosaic's own scratch. Passed to
+    Mosaic as ``vmem_limit_bytes`` and checked against
+    ``attention.VMEM_BUDGET_BYTES`` where the kernel is chosen."""
+    kv = 2 * T * d * (2 * itemsize + 4)
+    return kv + 4 * min(BLOCK_Q, T) * T * 4 + (4 << 20)
 
 
 def _flash_kernel(
@@ -125,6 +138,8 @@ def flash_prefill_attention(
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, n_q, T, d), q.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=flash_vmem_bytes(T, d, q.dtype.itemsize)),
+        interpret=check_interpret(interpret),
     )(lengths.astype(jnp.int32), qh, kh, vh)
     return jnp.swapaxes(out, 1, 2)  # back to [B, T, n_q, d]

@@ -21,8 +21,14 @@ slot's pages HBM→VMEM once and attends in-place:
   trash page 0 — uniform DMA pattern, garbage masked out), waits once,
   then computes the whole group's attention with two MXU matmuls
   ([group, d] x [d, S] and [group, S] x [S, d]) in f32.
-- K/V stream through VMEM scratch ([S_max, d] each: 32 pages x 64 x 128
-  x bf16 = 512 KB — well under the ~16 MB budget).
+- A slot's pages are staged in VMEM scratch ([n_kv, S_max, d] each for K
+  and V) and attended in blocks of <= 512 tokens with an online softmax
+  (``_attend_staged``), so the f32 temporaries stay a few MiB however
+  long the slot is. The scratch itself is what bounds S_max:
+  ``paged_vmem_bytes`` is its size plus headroom, passed to Mosaic as the
+  kernel's ``vmem_limit_bytes`` (the default scoped limit is 16 MiB, which
+  a 4096-token bf16 slot of 8 heads x 128 already fills) and checked
+  against ``attention.VMEM_BUDGET_BYTES`` where the kernel is chosen.
 """
 
 from __future__ import annotations
@@ -35,7 +41,114 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llms_on_kubernetes_tpu.ops.attention import NEG_INF, softcap
+from llms_on_kubernetes_tpu.ops.attention import (
+    NEG_INF, check_interpret, softcap,
+)
+
+_BLOCK_TOKENS = 512   # keys attended per online-softmax block
+# block temporaries (f32 K/V casts, logits, probabilities), the
+# double-buffered q/o blocks and the write kernels' small RMW scratch
+_VMEM_HEADROOM = 16 << 20
+
+
+def paged_vmem_bytes(n_kv: int, S: int, d: int, itemsize: int,
+                     quantized: bool = False) -> int:
+    """VMEM the paged decode kernels need for one slot of S tokens: the K
+    and V staging scratch (plus f32 per-token scales when int8) and
+    ``_VMEM_HEADROOM``."""
+    per_tok = n_kv * (d * itemsize + (4 if quantized else 0))
+    return 2 * S * per_tok + _VMEM_HEADROOM
+
+
+def _compiler_params(n_kv, S, d, dtype, quantized=False):
+    return pltpu.CompilerParams(vmem_limit_bytes=paged_vmem_bytes(
+        n_kv, S, d, jnp.dtype(dtype).itemsize, quantized))
+
+
+def _block_tokens(page_size: int, pages_per_seq: int) -> int:
+    """Largest whole-page block <= _BLOCK_TOKENS that tiles the slot."""
+    g = max(1, min(pages_per_seq, _BLOCK_TOKENS // page_size))
+    while pages_per_seq % g:
+        g -= 1
+    return g * page_size
+
+
+def _attend_staged(q, k_buf, v_buf, ks_buf, vs_buf, n_valid, q_pos, *,
+                   blk: int, scale: float, sliding_window: Optional[int],
+                   attn_softcap: Optional[float]):
+    """Online-softmax attention of q [n_kv, group, d] (f32) over the keys
+    [0, n_valid) staged in k_buf/v_buf [n_kv, S, d], one ``blk``-token
+    block at a time. ``ks_buf``/``vs_buf`` [n_kv, S] are the per-token
+    int8 scales (None for a float pool): the per-key scale is applied to
+    the LOGITS column and the per-value scale to the PROBABILITY column
+    (q.(k*s) == (q.k)*s), both lane-dim broadcasts. Returns the partials
+    (m [n_kv, group, 1], l [n_kv, group, 1], acc [n_kv, group, d]); the
+    caller divides (and, in the write kernels, first merges the current
+    token). Blocks wholly outside [q_pos - window, n_valid) are skipped.
+
+    Stale scratch (pages never DMA'd, lanes beyond n_valid) may hold
+    anything, NaN included: logits there are REPLACED by the substitutive
+    mask, probabilities are zeroed, and V rows / value scales are zeroed
+    before the p @ v matmul (0 * NaN = NaN otherwise)."""
+    n_kv, group, d = q.shape
+    lo = 0
+    if sliding_window is not None:
+        lo = jnp.maximum(q_pos - sliding_window + 1, 0) // blk
+    hi = (n_valid + blk - 1) // blk
+
+    def body(j, carry):
+        m, l, acc = carry
+        start = pl.multiple_of(j * blk, blk)
+        k = k_buf[:, pl.ds(start, blk), :].astype(jnp.float32)
+        v = v_buf[:, pl.ds(start, blk), :].astype(jnp.float32)
+        row = start + jax.lax.broadcasted_iota(jnp.int32, (n_kv, blk, 1), 1)
+        v = jnp.where(row < n_valid, v, 0.0)
+        logits = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * scale                                      # [n_kv, group, blk]
+        k_pos = start + jax.lax.broadcasted_iota(
+            jnp.int32, (n_kv, group, blk), 2)
+        valid = k_pos < n_valid
+        if ks_buf is not None:
+            logits = logits * ks_buf[:, pl.ds(start, blk)][:, None, :]
+        logits = softcap(logits, attn_softcap)
+        mask = valid
+        if sliding_window is not None:
+            mask &= k_pos > q_pos - sliding_window
+        logits = jnp.where(mask, logits, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        if vs_buf is not None:
+            sc_v = vs_buf[:, pl.ds(start, blk)][:, None, :]
+            p = p * jnp.where(valid[:, :1], sc_v, 0.0)
+        acc = alpha * acc + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                              # [n_kv, group, d]
+        return m_new, l, acc
+
+    init = (jnp.full((n_kv, group, 1), NEG_INF, jnp.float32),
+            jnp.zeros((n_kv, group, 1), jnp.float32),
+            jnp.zeros((n_kv, group, d), jnp.float32))
+    return jax.lax.fori_loop(lo, hi, body, init)
+
+
+def _merge_current(q, part, k_cur, v_cur, *, scale, attn_softcap):
+    """Fold the current token (k_cur/v_cur [n_kv, d] f32, held in
+    registers — never read back from HBM; always inside any sliding
+    window, it IS the query position) into staged partials and normalize.
+    Returns o [n_kv, group, d]."""
+    m, l, acc = part
+    l_cur = jnp.sum(q * k_cur[:, None, :], axis=-1, keepdims=True) * scale
+    l_cur = softcap(l_cur, attn_softcap)               # [n_kv, group, 1]
+    m_new = jnp.maximum(m, l_cur)
+    alpha = jnp.exp(m - m_new)
+    w_cur = jnp.exp(l_cur - m_new)
+    num = alpha * acc + w_cur * v_cur[:, None, :]
+    return num / (alpha * l + w_cur)
 
 
 def _paged_kernel(
@@ -65,7 +178,6 @@ def _paged_kernel(
     [n_kv, page, d] strided block for every head at once, and the two MXU
     contractions run batched over heads."""
     b = pl.program_id(0)
-    S = pages_per_seq * page_size
     length = lengths_ref[b]
     # LENGTH-BOUNDED DMA: only pages actually covering this slot's tokens
     # are fetched. A slot 100 tokens into a 2048-token window must not pay
@@ -105,37 +217,12 @@ def _paged_kernel(
             ).wait()
 
     q = q_ref[0].astype(jnp.float32)                   # [n_kv, group, d]
-    k = k_buf[:].astype(jnp.float32)                   # [n_kv, S, d]
-    v = v_buf[:].astype(jnp.float32)
-    n_kv, group, d = q.shape
-    # stale (un-DMA'd) V rows must be zeroed: the p @ v matmul multiplies
-    # masked-out (zero) probabilities by them, and 0 * NaN = NaN. K needs
-    # no fix ONLY because the mask below is a substitutive jnp.where that
-    # REPLACES garbage logits wholesale — an additive `logits + NEG_INF`
-    # formulation would let stale-K NaNs through (NaN + c = NaN).
-    v = jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, (n_kv, S, 1), 1) < length, v, 0.0)
-
-    logits = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale                                          # [n_kv, group, S]
-    logits = softcap(logits, attn_softcap)
-
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (n_kv, group, S), 2)
-    mask = k_pos < length
-    if sliding_window is not None:
-        mask &= k_pos > (length - 1) - sliding_window
-    logits = jnp.where(mask, logits, NEG_INF)
-
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    p = jnp.exp(logits - m)
-    denom = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) / denom
-    o_ref[0] = o.astype(o_ref.dtype)
+    _, l, acc = _attend_staged(
+        q, k_buf, v_buf, None, None, length, length - 1,
+        blk=_block_tokens(page_size, pages_per_seq), scale=scale,
+        sliding_window=sliding_window, attn_softcap=attn_softcap)
+    # idle slot (length 0): no block ran, l == 0 -> a finite zero row
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_kernel_int8(
@@ -173,7 +260,6 @@ def _paged_kernel_int8(
     i*page_size, which Mosaic accepts only when page_size is a multiple
     of the 128-lane tile (enforced by the dispatcher)."""
     b = pl.program_id(0)
-    S = pages_per_seq * page_size
     length = lengths_ref[b]
     n_pages = (length + page_size - 1) // page_size
 
@@ -223,39 +309,11 @@ def _paged_kernel_int8(
                 sems.at[3, i]).wait()
 
     q = q_ref[0].astype(jnp.float32)                   # [n_kv, group, d]
-    k = k_buf[:].astype(jnp.float32)                   # [n_kv, S, d] UNSCALED
-    v = v_buf[:].astype(jnp.float32)
-    n_kv, group, d = q.shape
-    sc_k = ks_buf[:][:, None, :]                       # [n_kv, 1, S]
-    sc_v = vs_buf[:][:, None, :]
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (n_kv, group, S), 2)
-    valid = k_pos < length
-
-    logits = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale                                          # [n_kv, group, S]
-    # per-key dequant folded into the logits column; stale lanes (beyond
-    # length) can hold garbage scales — substitutive masking below removes
-    # them wholesale, and sc_v is zeroed there so p@v never sees them
-    logits = logits * sc_k
-    logits = softcap(logits, attn_softcap)
-
-    mask = valid
-    if sliding_window is not None:
-        mask &= k_pos > (length - 1) - sliding_window
-    logits = jnp.where(mask, logits, NEG_INF)
-
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    p = jnp.exp(logits - m)
-    denom = jnp.sum(p, axis=-1, keepdims=True)
-    # per-value dequant folded into the probability column
-    p = p * jnp.where(valid[:, :1], sc_v, 0.0)
-    o = jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) / denom
-    o_ref[0] = o.astype(o_ref.dtype)
+    _, l, acc = _attend_staged(
+        q, k_buf, v_buf, ks_buf, vs_buf, length, length - 1,
+        blk=_block_tokens(page_size, pages_per_seq), scale=scale,
+        sliding_window=sliding_window, attn_softcap=attn_softcap)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -312,7 +370,8 @@ def pallas_paged_attention_int8(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, group, d), q.dtype),
-        interpret=interpret,
+        compiler_params=_compiler_params(n_kv, S, d, k_data.dtype, True),
+        interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_data, k_scale, v_data, v_scale)
     return out.reshape(B, n_q, d)
@@ -358,7 +417,6 @@ def _paged_kernel_write(
     Idle slots (length == 0) skip the write and produce a harmless
     pure-current-token output (discarded by the engine)."""
     b = pl.program_id(0)
-    S = pages_per_seq * page_size
     length = lengths_ref[b]
     cached = length - 1                       # tokens already in the pool
     n_pages = (cached + page_size - 1) // page_size
@@ -428,42 +486,14 @@ def _paged_kernel_write(
             vblk, v_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[1]).start()
 
     q = q_ref[0].astype(jnp.float32)                   # [n_kv, group, d]
-    k = k_buf[:].astype(jnp.float32)                   # [n_kv, S, d]
-    v = v_buf[:].astype(jnp.float32)
-    n_kv, group, d = q.shape
-    v = jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, (n_kv, S, 1), 1) < cached, v, 0.0)
-
-    logits = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale                                          # [n_kv, group, S]
-    logits = softcap(logits, attn_softcap)
-
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (n_kv, group, S), 2)
-    mask = k_pos < cached
-    if sliding_window is not None:
-        mask &= k_pos > cached - sliding_window        # q_pos == cached
-    logits = jnp.where(mask, logits, NEG_INF)
-
-    # current token, in registers (never read back from HBM); always
-    # inside any sliding window (it IS the query position)
-    k_new = k_new_ref[0].astype(jnp.float32)           # [n_kv, d]
-    v_new = v_new_ref[0].astype(jnp.float32)
-    l_cur = jnp.sum(q * k_new[:, None, :], axis=-1) * scale  # [n_kv, group]
-    l_cur = softcap(l_cur, attn_softcap)
-
-    m1 = jnp.max(logits, axis=-1)                      # [n_kv, group]
-    m = jnp.maximum(m1, l_cur)
-    p = jnp.exp(logits - m[..., None])
-    num = jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                  # [n_kv, group, d]
-    w_cur = jnp.exp(l_cur - m)                         # [n_kv, group]
-    num = num + w_cur[..., None] * v_new[:, None, :]
-    den = jnp.sum(p, axis=-1) + w_cur
-    o_ref[0] = (num / den[..., None]).astype(o_ref.dtype)
+    part = _attend_staged(
+        q, k_buf, v_buf, None, None, cached, cached,
+        blk=_block_tokens(page_size, pages_per_seq), scale=scale,
+        sliding_window=sliding_window, attn_softcap=attn_softcap)
+    o_ref[0] = _merge_current(
+        q, part, k_new_ref[0].astype(jnp.float32),
+        v_new_ref[0].astype(jnp.float32),
+        scale=scale, attn_softcap=attn_softcap).astype(o_ref.dtype)
 
     @pl.when(length > 0)
     def _finish():
@@ -538,7 +568,8 @@ def pallas_paged_attention_write(
         # inputs count scalar-prefetch args first: pt=0, lengths=1, q=2,
         # k_pages=3, v_pages=4, k_new=5, v_new=6; outputs: attn=0, k=1, v=2
         input_output_aliases={3: 1, 4: 2},
-        interpret=interpret,
+        compiler_params=_compiler_params(n_kv, S, d, k_pages.dtype),
+        interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pages, v_pages,
       k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype))
@@ -662,7 +693,7 @@ def pallas_paged_write_window(
         # inputs count scalar-prefetch args first: pt=0, base=1, widths=2,
         # k_pages=3, v_pages=4, k_new=5, v_new=6; outputs: k=0, v=1
         input_output_aliases={3: 0, 4: 1},
-        interpret=interpret,
+        interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), base.astype(jnp.int32),
       widths.astype(jnp.int32), k_pages, v_pages,
       k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype))
@@ -725,7 +756,6 @@ def _paged_kernel_write_int8(
     DEQUANTIZED value (data * scale), so the output matches a
     write-then-attend over the quantized pool, not the fp input."""
     b = pl.program_id(0)
-    S = pages_per_seq * page_size
     length = lengths_ref[b]
     cached = length - 1                       # tokens already in the pool
     n_pages = (cached + page_size - 1) // page_size
@@ -819,45 +849,16 @@ def _paged_kernel_write_int8(
             vsrow, vs_out.at[:, w_pid], wsem.at[3]).start()
 
     q = q_ref[0].astype(jnp.float32)                   # [n_kv, group, d]
-    k = k_buf[:].astype(jnp.float32)                   # [n_kv, S, d] UNSCALED
-    v = v_buf[:].astype(jnp.float32)
-    n_kv, group, d = q.shape
-    sc_k = ks_buf[:][:, None, :]                       # [n_kv, 1, S]
-    sc_v = vs_buf[:][:, None, :]
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (n_kv, group, S), 2)
-    valid = k_pos < cached
-
-    logits = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale                                          # [n_kv, group, S]
-    logits = logits * sc_k
-    logits = softcap(logits, attn_softcap)
-
-    mask = valid
-    if sliding_window is not None:
-        mask &= k_pos > cached - sliding_window        # q_pos == cached
-    logits = jnp.where(mask, logits, NEG_INF)
-
-    # current token, dequantized in registers (always inside any window)
-    k_cur = kq.astype(jnp.float32) * ks_new[:, None]   # [n_kv, d]
-    v_cur = vq.astype(jnp.float32) * vs_new[:, None]
-    l_cur = jnp.sum(q * k_cur[:, None, :], axis=-1) * scale  # [n_kv, group]
-    l_cur = softcap(l_cur, attn_softcap)
-
-    m1 = jnp.max(logits, axis=-1)                      # [n_kv, group]
-    m = jnp.maximum(m1, l_cur)
-    p = jnp.exp(logits - m[..., None])
-    den = jnp.sum(p, axis=-1)
-    p = p * jnp.where(valid[:, :1], sc_v, 0.0)         # per-value dequant
-    num = jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                  # [n_kv, group, d]
-    w_cur = jnp.exp(l_cur - m)                         # [n_kv, group]
-    num = num + w_cur[..., None] * v_cur[:, None, :]
-    den = den + w_cur
-    o_ref[0] = (num / den[..., None]).astype(o_ref.dtype)
+    part = _attend_staged(
+        q, k_buf, v_buf, ks_buf, vs_buf, cached, cached,
+        blk=_block_tokens(page_size, pages_per_seq), scale=scale,
+        sliding_window=sliding_window, attn_softcap=attn_softcap)
+    # current token, dequantized in registers: the output matches a
+    # write-then-attend over the quantized pool, not the fp input
+    o_ref[0] = _merge_current(
+        q, part, kq.astype(jnp.float32) * ks_new[:, None],
+        vq.astype(jnp.float32) * vs_new[:, None],
+        scale=scale, attn_softcap=attn_softcap).astype(o_ref.dtype)
 
     @pl.when(length > 0)
     def _finish():
@@ -950,7 +951,8 @@ def pallas_paged_attention_write_int8(
         # k_data=3, k_scale=4, v_data=5, v_scale=6, k_new=7, v_new=8;
         # outputs: attn=0, kd=1, ks=2, vd=3, vs=4
         input_output_aliases={3: 1, 4: 2, 5: 3, 6: 4},
-        interpret=interpret,
+        compiler_params=_compiler_params(n_kv, S, d, k_data.dtype, True),
+        interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_data, k_scale, v_data, v_scale,
       k_new.astype(jnp.float32), v_new.astype(jnp.float32))
@@ -1105,7 +1107,7 @@ def pallas_paged_write_window_int8(
         # k_data=3, k_scale=4, v_data=5, v_scale=6, k_new=7, v_new=8;
         # outputs: kd=0, ks=1, vd=2, vs=3
         input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
-        interpret=interpret,
+        interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), base.astype(jnp.int32),
       widths.astype(jnp.int32), k_data, k_scale, v_data, v_scale,
       k_new.astype(jnp.float32), v_new.astype(jnp.float32))
@@ -1163,7 +1165,8 @@ def pallas_paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, group, d), q.dtype),
-        interpret=interpret,
+        compiler_params=_compiler_params(n_kv, S, d, k_pages.dtype),
+        interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pages, v_pages)
     return out.reshape(B, n_q, d)

@@ -124,7 +124,6 @@ def ring_prefill_attention(
     'model' inside the shard_map (when it divides evenly), so CP×TP runs
     with no head all-gather — each device owns its heads' slice of its
     sequence chunk and only K/V blocks move, around the seq ring."""
-    from llms_on_kubernetes_tpu.ops.shard_map_compat import shard_map
     from llms_on_kubernetes_tpu.parallel.mesh import AXIS_MODEL
 
     n_q, n_kv = q.shape[2], k.shape[2]
@@ -132,7 +131,7 @@ def ring_prefill_attention(
     heads = (AXIS_MODEL if model_size > 1 and n_q % model_size == 0
              and n_kv % model_size == 0 else None)
     spec = P(None, AXIS_SEQ, heads, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_local, axis_name=AXIS_SEQ, scale=scale,
             attn_softcap=attn_softcap, sliding_window=sliding_window,
@@ -140,6 +139,6 @@ def ring_prefill_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec, P()),
         out_specs=spec,
-        check=False,
+        check_vma=False,
     )
     return fn(q, k, v, lengths)

@@ -5,12 +5,12 @@ from llms_on_kubernetes_tpu.parallel.mesh import (
     make_mesh,
 )
 from llms_on_kubernetes_tpu.parallel.sharding import (
-    cache_specs,
+    pool_sharding,
     param_specs,
     shard_params,
 )
 
 __all__ = [
     "AXIS_DATA", "AXIS_EXPERT", "AXIS_MODEL",
-    "make_mesh", "param_specs", "cache_specs", "shard_params",
+    "make_mesh", "param_specs", "pool_sharding", "shard_params",
 ]

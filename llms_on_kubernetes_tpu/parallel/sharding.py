@@ -78,32 +78,28 @@ def param_specs(cfg: ModelConfig, mesh: Mesh) -> Params:
     return specs
 
 
-def cache_specs(cfg: ModelConfig, mesh: Mesh) -> tuple[P, P]:
+def pool_sharding(cfg: ModelConfig, mesh: Mesh) -> NamedSharding:
+    """Sharding of every KVPool leaf (data [KV, L*P, page, hd] AND the
+    int8 per-token scales [KV, L*P, page]): the leading kv-head axis over
+    ``model``, so each TP shard keeps its own heads' pages and scales
+    local. On a seq>1 mesh the FLAT PAGE axis additionally shards over
+    ``seq`` (context parallelism, ops/cp.py): total KV capacity then
+    scales with the ring size instead of being bounded by one device's
+    share. Trailing axes are left off the spec (= replicated) so one spec
+    fits both leaves."""
     from llms_on_kubernetes_tpu.parallel.mesh import AXIS_SEQ
 
     m_kv = _axis(mesh, cfg.num_kv_heads, AXIS_MODEL)
     sq = AXIS_SEQ if mesh.shape.get(AXIS_SEQ, 1) > 1 else None
-    spec = P(m_kv, sq, None, None)  # [KV, L*P, page, hd] flat head-major
-    return spec, spec
+    return NamedSharding(mesh, P(m_kv, sq))
 
 
 def shard_pool(pool, cfg: ModelConfig, mesh: Mesh):
-    """Device_put a KVPool onto the mesh: every leaf (int8 data AND the
-    per-token scales) shards its leading kv-head axis over the model axis,
-    so each TP shard keeps its own heads' pages and scales local. On a
-    seq>1 mesh the FLAT PAGE axis additionally shards over ``seq``
-    (context parallelism, ops/cp.py): total KV capacity then scales with
-    the ring size instead of being bounded by one device's share."""
-    from llms_on_kubernetes_tpu.parallel.mesh import AXIS_SEQ
-
-    m_kv = _axis(mesh, cfg.num_kv_heads, AXIS_MODEL)
-    sq = AXIS_SEQ if mesh.shape.get(AXIS_SEQ, 1) > 1 else None
-
-    def put(x):
-        spec = P(m_kv, sq, *([None] * (x.ndim - 2)))
-        return jax.device_put(x, NamedSharding(mesh, spec))
-
-    return jax.tree.map(put, pool)
+    """Device_put an existing KVPool onto the mesh (see pool_sharding; the
+    engine creates its pools sharded from the start via
+    cache.init_pages)."""
+    sharding = pool_sharding(cfg, mesh)
+    return jax.tree.map(lambda x: jax.device_put(x, sharding), pool)
 
 
 def shard_lora_stack(stack, mesh: Mesh):

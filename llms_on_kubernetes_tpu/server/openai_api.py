@@ -601,13 +601,10 @@ class OpenAIServer:
         # the first /metrics scrape already carries the full cold start
         for phase, seconds in cold_start.drain():
             self.metrics["cold_start"].labels(phase=phase).observe(seconds)
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:
-            backend = "none"
+        import jax
+
         build_info_metrics(
-            self.registry, backend=backend,
+            self.registry, backend=jax.default_backend(),
             role=getattr(getattr(engine, "config", None), "role", None)
             or "both")
         # runtime telemetry (device memory, live buffers, jit compile

@@ -1952,6 +1952,10 @@ class Router:
         finally:
             if active is not None:
                 active.inflight -= 1
+            # a client that hung up (cancellation, or resp.write raising)
+            # leaves through here with the upstream still open: close it,
+            # or the replica decodes the abandoned stream to its end
+            upstream.close()
 
     async def _resume_upstream(self, request: web.Request, model: str,
                                headers: dict, body: bytes,

@@ -19,8 +19,7 @@ needs HBM headroom and compile-stall visibility, not just TTFT):
   request behind compilation; ``llm_jit_cache_hits_total`` counts
   persistent-compilation-cache hits when that cache is enabled.
 
-Everything degrades gracefully: a jax without ``monitoring`` listeners,
-a device without ``memory_stats``, or a refresh failure mid-scrape must
+A device without ``memory_stats`` or a refresh failure mid-scrape must
 never take down ``/metrics``.
 """
 
@@ -88,53 +87,72 @@ class RuntimeTelemetry:
     pushed by ``jax.monitoring`` listeners registered once per process
     (the listener API has no deregistration, so a process-global guard
     keeps re-instantiation — tests build many servers — from stacking
-    duplicate listeners).
+    duplicate listeners). ``serve`` installs them before its warmup
+    compiles, when no registry exists yet: what they see until the first
+    instance is built is held in ``_early`` and folded into that
+    instance's counters, so a warm restart's persistent-cache hits are on
+    its first scrape.
     """
 
     _listener_lock = threading.Lock()
     _listener_host: "RuntimeTelemetry | None" = None
+    _installed = False
+    # [compiles, compile seconds, cache hits] seen before any host existed
+    _early = [0, 0.0, 0]
 
     def __init__(self, registry: Registry):
         self.metrics = runtime_metrics(registry)
-        self._install_listeners()
-
-    # -- compile counters (push) ---------------------------------------
-
-    def _install_listeners(self) -> None:
         cls = RuntimeTelemetry
+        cls.install_listeners()
         with cls._listener_lock:
-            first = cls._listener_host is None
             # newest instance wins: the latest server's registry is the
             # one being scraped; earlier ones are dead test fixtures
             cls._listener_host = self
-            if not first:
+            compiles, seconds, hits = cls._early
+            cls._early = [0, 0.0, 0]
+        self.metrics["jit_compiles"].inc(compiles)
+        self.metrics["jit_compile_seconds"].inc(seconds)
+        self.metrics["jit_cache_hits"].inc(hits)
+
+    # -- compile counters (push) ---------------------------------------
+
+    @classmethod
+    def install_listeners(cls) -> None:
+        from jax import monitoring
+
+        with cls._listener_lock:
+            if cls._installed:
                 return
-            try:
-                from jax import monitoring
-                monitoring.register_event_listener(cls._dispatch_event)
-                monitoring.register_event_duration_secs_listener(
-                    cls._dispatch_duration)
-            except Exception:
-                # jax without the monitoring API (or import failure):
-                # compile counters stay at 0 but keep rendering
-                cls._listener_host = None
+            cls._installed = True
+            monitoring.register_event_listener(cls._dispatch_event)
+            monitoring.register_event_duration_secs_listener(
+                cls._dispatch_duration)
 
     @staticmethod
     def _dispatch_event(event: str, **kw) -> None:
-        host = RuntimeTelemetry._listener_host
-        if host is None:
+        if "cache_hit" not in event:
             return
-        if "cache_hit" in event:
-            host.metrics["jit_cache_hits"].inc()
+        cls = RuntimeTelemetry
+        with cls._listener_lock:
+            host = cls._listener_host
+            if host is None:
+                cls._early[2] += 1
+                return
+        host.metrics["jit_cache_hits"].inc()
 
     @staticmethod
     def _dispatch_duration(event: str, duration: float, **kw) -> None:
-        host = RuntimeTelemetry._listener_host
-        if host is None:
+        if "backend_compile" not in event:
             return
-        if "backend_compile" in event:
-            host.metrics["jit_compiles"].inc()
-            host.metrics["jit_compile_seconds"].inc(max(0.0, duration))
+        cls = RuntimeTelemetry
+        with cls._listener_lock:
+            host = cls._listener_host
+            if host is None:
+                cls._early[0] += 1
+                cls._early[1] += max(0.0, duration)
+                return
+        host.metrics["jit_compiles"].inc()
+        host.metrics["jit_compile_seconds"].inc(max(0.0, duration))
 
     # -- device memory (pull, at scrape) -------------------------------
 

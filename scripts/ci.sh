@@ -398,12 +398,6 @@ else
   fails=$((fails + 1))
 fi
 
-note "entry-point contracts"
-if ! "$REPO/scripts/check_entrypoints.sh"; then
-  echo "ci: entry-point checks FAILED"
-  fails=$((fails + 1))
-fi
-
 echo
 if [ "$fails" -ne 0 ]; then
   echo "ci: $fails gate(s) failed"

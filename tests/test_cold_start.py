@@ -2,7 +2,7 @@
 
 CI-tier (CPU): the persistent XLA compilation cache that ISSUE 7 mounts
 on the weight PVC must actually shorten a warm restart — two fresh
-processes share one ``LLMK_COMPILE_CACHE_DIR`` and the second's compile
+processes share one ``JAX_COMPILATION_CACHE_DIR`` and the second's compile
 is measurably faster (cache hit instead of recompilation).
 
 Opt-in hardware run: ``LLMK_TEST_COLDSTART=1 pytest tests/test_cold_start.py
@@ -41,16 +41,29 @@ PROBE_BUDGET_S = 420.0  # readinessProbe: 120s initial + 30s x 10 failures
 # persistent compile cache (CPU, runs in CI)
 # ---------------------------------------------------------------------------
 
-def test_configure_compilation_cache_env_override(tmp_path, monkeypatch):
+def test_compilation_cache_rule(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: JAX keeps its cache there and the
+    program updates no directory in code. Unset: <checkout>/.jax_cache, a
+    fixed path."""
+    import jax
+
     from llms_on_kubernetes_tpu.cli import configure_compilation_cache
 
-    cache = tmp_path / "xla"
-    monkeypatch.setenv("LLMK_COMPILE_CACHE_DIR", str(cache))
-    assert configure_compilation_cache() == str(cache)
-    assert cache.is_dir()
-    # empty string disables (ephemeral nodes with no PVC to persist to)
-    monkeypatch.setenv("LLMK_COMPILE_CACHE_DIR", "")
-    assert configure_compilation_cache() is None
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (updates.append(name), real_update(name, val)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert configure_compilation_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert configure_compilation_cache() == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(REPO / ".jax_cache")
+    finally:
+        real_update("jax_compilation_cache_dir", before)
 
 
 # compile something expensive enough that a recompile-vs-cache-hit gap
@@ -60,9 +73,10 @@ import os, sys, time
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 from llms_on_kubernetes_tpu.cli import configure_compilation_cache
 d = configure_compilation_cache()
-assert d == os.environ["LLMK_COMPILE_CACHE_DIR"], d
 import jax
 import jax.numpy as jnp
+assert d == jax.config.jax_compilation_cache_dir \
+    == os.environ["JAX_COMPILATION_CACHE_DIR"], d
 
 @jax.jit
 def f(x):
@@ -82,7 +96,7 @@ print("COMPILE_S", time.perf_counter() - t0)
 
 def _compile_once(cache_dir: str) -> float:
     env = dict(os.environ)
-    env["LLMK_COMPILE_CACHE_DIR"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     # the forced 8-device host platform is irrelevant here; keep the
     # subprocess a plain single-device CPU like a real serving pod

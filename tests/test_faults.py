@@ -13,7 +13,7 @@ connection resets and stalls for the Python router. Covered here:
   and the engine wedges (submit rejects, step no-ops);
 - /health vs /ready lifecycle (loading/serving/draining/wedged) and the
   llm_engine_state gauge;
-- bench.py / dryrun_multichip under LLMK_FAULT=backend_hang (subprocess:
+- dryrun_multichip on the devices it is given (subprocess:
   one parseable error JSON line / CPU path untouched by the hang).
 
 The native router's equivalents live in tests/test_native_router.py and
@@ -21,7 +21,6 @@ tests/test_native_sanitizers.py.
 """
 
 import asyncio
-import json
 import os
 import socket
 import struct
@@ -50,8 +49,8 @@ def test_fault_spec_parsing(monkeypatch):
     assert faults.get("engine_stall") == ""
     assert faults.get_float("slow_step", 0.2) == 0.5
     assert faults.get_float("engine_stall", 7.0) == 7.0  # bare -> default
-    assert not faults.is_active("backend_hang")
-    assert faults.get_float("backend_hang", 1.0) is None
+    assert not faults.is_active("queue_stall")
+    assert faults.get_float("queue_stall", 1.0) is None
     monkeypatch.delenv("LLMK_FAULT")
     assert not faults.is_active("engine_stall")  # read at call time
 
@@ -59,7 +58,7 @@ def test_fault_spec_parsing(monkeypatch):
 def test_inject_hooks_noop_when_inactive(monkeypatch):
     monkeypatch.delenv("LLMK_FAULT", raising=False)
     t0 = time.monotonic()
-    faults.inject_hang("backend_hang")
+    faults.inject_hang("engine_stall")
     faults.inject_delay("slow_step", 5.0)
     assert time.monotonic() - t0 < 0.5
 
@@ -907,53 +906,21 @@ def test_preempt_replica_drains_single_victim_without_drops(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# hardened entry points under a hung backend (subprocess, like production)
+# the layout check runs on the devices it is given
 # ---------------------------------------------------------------------------
 
 @pytest.mark.e2e
-def test_bench_backend_hang_emits_error_json():
-    env = dict(os.environ)
-    env.update(LLMK_FAULT="backend_hang", LLMK_BACKEND_PROBE_TIMEOUT_S="3",
-               BENCH_MODEL="debug-tiny")
-    t0 = time.monotonic()
-    r = subprocess.run([sys.executable, "bench.py"], cwd=REPO, env=env,
-                       capture_output=True, text=True, timeout=60)
-    assert time.monotonic() - t0 < 55, "hang must be bounded by the probe"
-    assert r.returncode != 0
-    lines = [ln for ln in r.stdout.splitlines() if ln.strip()]
-    assert len(lines) == 1, f"stdout contract is ONE JSON line: {lines}"
-    doc = json.loads(lines[0])
-    assert doc["error"]["type"] == "BackendProbeError"
-    assert "did not complete" in doc["error"]["message"]
-
-
-@pytest.mark.e2e
-@pytest.mark.slow
-def test_dryrun_multichip_untouched_by_backend_hang():
-    # the CPU-subprocess path must never initialize the default backend,
-    # so a wedged accelerator runtime cannot stall it (round-5 rc=124).
-    # slow: ~20 s, dominated by a cold jax import in the child process.
-    env = dict(os.environ)
-    env["LLMK_FAULT"] = "backend_hang"
+def test_layout_check_names_the_devices_it_ran_on():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
     r = subprocess.run([sys.executable, "__graft_entry__.py", "2"],
                        cwd=REPO, env=env, capture_output=True, text=True,
                        timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
-    assert "dryrun_multichip(2): OK" in r.stdout
-
-
-@pytest.mark.e2e
-def test_dryrun_subprocess_timeout_kills_wedged_child():
-    code = (
-        "import sys; sys.path.insert(0, '.'); "
-        "import __graft_entry__ as g\n"
-        "try:\n"
-        "    g._dryrun_subprocess(2, timeout_s=0.5)\n"
-        "except RuntimeError as e:\n"
-        "    assert 'wall-clock' in str(e), e\n"
-        "    print('TIMEOUT-OK')\n"
-    )
-    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
-                       capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "TIMEOUT-OK" in r.stdout
+    assert "dryrun_multichip(2): OK on 2 cpu device(s)" in r.stdout
+    # fewer devices than asked for is an error, not a smaller mesh
+    r = subprocess.run([sys.executable, "__graft_entry__.py", "4"],
+                       cwd=REPO, env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode != 0
+    assert "need 4 devices, have 2" in r.stderr

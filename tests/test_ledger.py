@@ -162,13 +162,51 @@ def test_utilization_bounded():
     assert 0.0 < mfu2 < 1e-3 and 0.0 < mbu2 < 1e-3
 
 
-def test_detect_peak_never_raises(monkeypatch):
+class _Dev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def _with_device(monkeypatch, platform, kind):
+    import jax
+    monkeypatch.delenv("LLMK_PEAK_TFLOPS", raising=False)
+    monkeypatch.delenv("LLMK_PEAK_GBPS", raising=False)
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(platform, kind)])
+
+
+@pytest.mark.parametrize("kind,peak", [
+    ("TPU v5 lite", (197e12, 819e9)),     # what a v5e chip reports
+    ("TPU v5e", (197e12, 819e9)),
+    ("TPU v5p", (459e12, 2765e9)),
+    ("TPU v6 lite", (918e12, 1640e9)),
+    ("TPU v4", (275e12, 1228e9)),
+])
+def test_detect_peak_by_reported_device_kind(monkeypatch, kind, peak):
+    _with_device(monkeypatch, "tpu", kind)
+    assert detect_peak() == peak
+
+
+def test_detect_peak_unknown_accelerator_is_an_error(monkeypatch):
+    """An accelerator the table has never heard of fails start-up with
+    the string it reports; only the CPU platform gets a nominal peak."""
+    _with_device(monkeypatch, "tpu", "TPU v9 mega")
+    with pytest.raises(RuntimeError, match="TPU v9 mega"):
+        detect_peak()
+    _with_device(monkeypatch, "gpu", "NVIDIA H100")
+    with pytest.raises(RuntimeError, match="NVIDIA H100"):
+        detect_peak()
+    _with_device(monkeypatch, "cpu", "cpu")
+    assert detect_peak() == (5e11, 5e10)
+
+
+def test_detect_peak_env_override(monkeypatch):
+    _with_device(monkeypatch, "tpu", "TPU v9 mega")
     monkeypatch.setenv("LLMK_PEAK_TFLOPS", "918")
     monkeypatch.setenv("LLMK_PEAK_GBPS", "1640")
     assert detect_peak() == (918e12, 1640e9)
     monkeypatch.setenv("LLMK_PEAK_TFLOPS", "not-a-number")
-    f, b = detect_peak()  # falls through to device table / fallback
-    assert f > 0 and b > 0
+    with pytest.raises(ValueError):
+        detect_peak()
 
 
 def test_reset_zeroes_accounting():

@@ -307,6 +307,32 @@ models:
     assert env2["LLMK_DRAFT_MODEL"] == "/models/draft.gguf"
 
 
+def test_compile_cache_lives_on_the_weight_pvc():
+    """Every workload that mounts the weight PVC (Deployment and multi-host
+    StatefulSet) points JAX_COMPILATION_CACHE_DIR into it, so a restarted
+    pod finds its executables; the path sits under the PVC's mount."""
+    spec = load_spec("""
+namespace: tpu-models
+models:
+  - modelName: mistral-7b
+    huggingfaceId: mistralai/Mistral-7B-Instruct-v0.2
+    tpu: {accelerator: v5e, chips: 8}
+  - modelName: llama-3-70b
+    huggingfaceId: meta-llama/Meta-Llama-3-70B-Instruct
+    tpu: {accelerator: v5e, chips: 16}
+""")
+    ms = render_manifests(spec)
+    for kind, name in (("Deployment", "model-mistral-7b"),
+                       ("StatefulSet", "model-llama-3-70b")):
+        c = by_name(ms, kind, name)["spec"]["template"]["spec"][
+            "containers"][0]
+        env = {e["name"]: e.get("value") for e in c["env"]}
+        mounts = [m["mountPath"] for m in c["volumeMounts"]
+                  if m["name"] == "hf-cache"]
+        assert len(mounts) == 1
+        assert env["JAX_COMPILATION_CACHE_DIR"].startswith(mounts[0] + "/")
+
+
 def test_decode_steps_threads_to_engine_env():
     """ISSUE 8: decodeSteps rides as LLMK_DECODE_STEPS env (not an engine
     arg, keeping the argv contract stable); absent by default."""

@@ -3,7 +3,8 @@
 ops/attention.py is the semantically-authoritative implementation
 (its own tests pin it against brute-force numpy); these tests pin the
 Pallas kernels to it in interpreter mode so they run in CI without TPU
-hardware — the compiled path is exercised by bench.py on the real chip.
+hardware — the compiled path is exercised by tests/test_tpu_hardware.py
+and chip_smoke.py on the real chip.
 """
 
 import jax
@@ -298,3 +299,54 @@ def test_paged_write_window_matches_reference(rng):
         jnp.asarray(widths_np), k_new, v_new, interpret=True)
     np.testing.assert_array_equal(np.asarray(kp2), kp_ref)
     np.testing.assert_array_equal(np.asarray(vp2), vp_ref)
+
+
+# ---------------------------------------------------------------------------
+# no fallback that hides the device
+# ---------------------------------------------------------------------------
+
+def test_interpret_mode_on_an_accelerator_is_an_error(rng, monkeypatch):
+    """The interpreter is a CPU test path: asked for on any other backend,
+    the kernel raises instead of answering as slow XLA ops."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    q, k, v = _qkv(rng, 1, 16, 4, 2, 8)
+    lengths = jnp.asarray([16], jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="interpret mode.*'tpu'"):
+        attention.check_interpret(True)
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        flash_prefill_attention(q, k, v, lengths, scale=0.3, interpret=True)
+    assert attention.check_interpret(False) is False
+    # and the dispatchers never ask for it there
+    assert attention.pallas_mode() == "compiled"
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    assert attention.pallas_mode() == "compiled"
+
+
+def test_dispatchers_say_what_they_took(rng, monkeypatch, capfd):
+    """Each dispatcher records its choice and prints a change once, so a
+    reader outside the process can tell which implementation ran and why
+    — including a geometry the VMEM budget rules out."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    q, k, v = _qkv(rng, 1, 16, 4, 2, 8)
+    lengths = jnp.asarray([16], jnp.int32)
+    attention._chosen.clear()
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "xla")
+    for _ in range(2):
+        attention.dispatch_prefill_attention(q, k, v, lengths, scale=0.3)
+    assert attention._chosen["prefill"] == ("xla", "LLMK_ATTENTION_IMPL=xla")
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    attention.dispatch_prefill_attention(q, k, v, lengths, scale=0.3)
+    assert attention._chosen["prefill"][0] == "pallas-interpret"
+    # a slot too long to stage in VMEM: XLA, and the reason says so
+    kp = jnp.zeros((8, 2, 64, 128), jnp.bfloat16)
+    pt = jnp.zeros((1, 512), jnp.int32)          # 512 x 64 = 32k tokens
+    mode, why = attention._paged_kernel_mode(
+        jnp.zeros((1, 32, 128), jnp.bfloat16), kp, pt, 4096)
+    assert mode is None and "VMEM" in why and "32768 tokens" in why
+    err = capfd.readouterr().err
+    assert err.count("[attention] op=prefill impl=xla "
+                     "why=LLMK_ATTENTION_IMPL=xla") == 1
+    assert "[attention] op=prefill impl=pallas-interpret" in err

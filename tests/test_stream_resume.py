@@ -409,6 +409,48 @@ def test_mid_stream_death_resumes_on_other_replica():
     run_two_replicas(body, fail1=fail, fail2=fail)
 
 
+def test_client_hang_up_closes_the_upstream():
+    """A client that disconnects mid-stream must take the upstream down
+    with it: left open, the replica keeps decoding the abandoned stream
+    to its end (seen from chip_smoke.py as a SIGTERM'd server that would
+    not drain)."""
+    async def go():
+        gone = asyncio.Event()
+
+        async def chat(request: web.Request) -> web.StreamResponse:
+            resp = web.StreamResponse(
+                headers={"Content-Type": "text/event-stream"})
+            await resp.prepare(request)
+            frame = ("data: " + json.dumps({"choices": [
+                {"index": 0, "delta": {"content": "x"},
+                 "finish_reason": None}]}) + "\n\n").encode()
+            try:
+                while True:             # a stream with no end of its own
+                    await resp.write(frame)
+                    await asyncio.sleep(0.01)
+            except (ConnectionResetError, asyncio.CancelledError):
+                gone.set()
+                raise
+
+        app = web.Application()
+        app.router.add_post("/v1/chat/completions", chat)
+        backend = TestClient(TestServer(app))
+        await backend.start_server()
+        router = Router({"m": [str(backend.make_url("")).rstrip("/")]})
+        client = TestClient(TestServer(router.make_app()))
+        await client.start_server()
+        try:
+            r = await client.post("/v1/chat/completions", json=STREAM_REQ)
+            assert r.status == 200
+            await r.content.readany()
+            r.close()
+            await asyncio.wait_for(gone.wait(), timeout=10)
+        finally:
+            await client.close()
+            await backend.close()
+    asyncio.run(go())
+
+
 def test_resume_trims_replayed_echo():
     """Death BETWEEN a data chunk and its tok comment: the client has text
     the journal does not. The resumed replica deterministically re-emits
