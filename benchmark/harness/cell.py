@@ -19,7 +19,7 @@ import sys
 import tempfile
 import time
 
-from . import client, manifest, schedule, setup_steps, stats
+from . import client, lifecycle, manifest, schedule, setup_steps, stats
 from .launcher import Failed, NoResult, Stack
 from .peaks import PEAKS
 
@@ -96,7 +96,8 @@ def _start_reduce(profile_dir: str) -> "subprocess.Popen | None":
     """Start reducing the newest .xplane.pb under the server's profile
     directory, in a child process held to the CPU (the parent never
     imports jax; the child touches no chip, so it may run while the server
-    is being stopped)."""
+    is being stopped). It dies with this process; ``served`` reaps it on
+    any way out that ``_finish_reduce`` did not see."""
     files = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if not files:
@@ -104,7 +105,7 @@ def _start_reduce(profile_dir: str) -> "subprocess.Popen | None":
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "xplane.py")
-    return subprocess.Popen(
+    return lifecycle.spawn(
         [sys.executable, script, max(files, key=os.path.getmtime)],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
 
@@ -128,11 +129,14 @@ def _finish_reduce(proc) -> "dict | None":
 # --------------------------------------------------------------------------
 
 @contextlib.contextmanager
-def served(cell, platform: str, chips: int, t_process_start: float):
+def served(cell, platform: str, chips: int, timeline: lifecycle.Timeline):
     """The cell's configuration behind a router, ready and warmed up for
-    the cell's mix; stopped and cleaned up on the way out. Yields a
-    ``Context`` with the stack, the device, the phases' times and the list
-    of 503s the set-up requests waited out."""
+    the cell's mix; stopped and cleaned up on the way out: gracefully
+    when the block ends by itself, at once (SIGKILL) when an exception or
+    a signal ends it, with whatever ``up.reducer`` still runs. Call it
+    from the main thread (``launcher.Child``). Yields a ``Context`` with
+    the stack, the device, the phases' times and the list of 503s the
+    set-up requests waited out."""
     config, mix = cell.config, cell.mix
     workdir = tempfile.mkdtemp(prefix="bench-")
     profile_dir = os.path.join(workdir, "profiles")
@@ -143,12 +147,15 @@ def served(cell, platform: str, chips: int, t_process_start: float):
         # how a stall inside a window is told from a slow request
         "JAX_LOG_COMPILES": "1"})
     waited: list = []
+    up = None
     try:
         stack.start()
+        print(f"{timeline.who}: children server={stack.server.proc.pid} "
+              f"router={stack.router.proc.pid}", file=sys.stderr, flush=True)
         device = stack.wait_device(
             chips, None if platform == "cpu" else PEAKS,
             time.monotonic() + 300.0)
-        give_up = t_process_start + START_BUDGET_S
+        give_up = timeline.t0 + START_BUDGET_S
         while True:
             stack.check_alive()
             try:
@@ -161,30 +168,37 @@ def served(cell, platform: str, chips: int, t_process_start: float):
                 raise Failed("server not ready within the start budget")
             time.sleep(0.25)
         t_ready = time.monotonic()
+        timeline.enter("shapes")
         warm = setup_steps.load_shapes(
             stack.router_port, stack.server_port, config["registry_name"],
             config, mix, waited)
-        yield Context(stack=stack, device=device, waited=waited, warm=warm,
-                      t_ready=t_ready, t_warm=time.monotonic(),
-                      profile_dir=profile_dir,
-                      t_process_start=t_process_start)
+        up = Context(stack=stack, device=device, waited=waited, warm=warm,
+                     t_ready=t_ready, t_warm=time.monotonic(),
+                     profile_dir=profile_dir, timeline=timeline,
+                     reducer=None)
+        yield up
     except BaseException:
+        # nothing is left to read of a run that ends here: no 30 s of
+        # grace a child, whoever ends this process waits less than that
+        stack.stop(hard=True)
         for c in stack.children:
             print(f"--- last lines of {c.name} ---\n{c.tail()}",
                   file=sys.stderr)
         raise
     finally:
         stack.stop()
+        lifecycle.reap(up and up.reducer)
         shutil.rmtree(workdir, ignore_errors=True)
 
 
 def storms(up, cell) -> dict:
-    spent = time.monotonic() - up.t_process_start
+    up.timeline.enter("storms")
+    spent = time.monotonic() - up.timeline.t0
     budget = COLD_STORM_BUDGET_S if spent > STORM_BUDGET_S else STORM_BUDGET_S
     return setup_steps.storms(
         up.stack.router_port, up.stack.server_port,
         cell.config["registry_name"], cell.mix,
-        float(cell.cell["knee_rps"]), up.t_process_start + budget,
+        float(cell.cell["knee_rps"]), up.timeline.t0 + budget,
         up.waited)
 
 
@@ -201,6 +215,12 @@ def offer(up, cell, rate: float, seconds: float, seed: int,
     if tail_s:
         parts.append(("tail", schedule.plan(mix, rate, tail_s, seed, "tail"),
                       tail_s))
+    # where the open loop is by the clock, for a run that is ended inside
+    # it; a traced run's tail holds the profiler's capture and its drain
+    # the profiler's stop
+    t_tail = pre_s + float(seconds)
+    up.timeline.enter("preroll", window=pre_s, drain=t_tail + tail_s,
+                      **({"tail": t_tail} if tail_s else {}))
     got = asyncio.run(client.open_loop(
         up.stack.router_port, cell.config["registry_name"], mix, parts,
         float(mix["drain_limit_s"]), side))
@@ -211,7 +231,8 @@ def offer(up, cell, rate: float, seconds: float, seed: int,
 
 
 def run(cell_name: str, seed: int, seconds: float, trace: bool,
-        rehearse: bool, t_process_start: float) -> int:
+        rehearse: bool, timeline: lifecycle.Timeline) -> int:
+    t_process_start = timeline.t0
     bench = manifest.load_benchmark()
     cell = manifest.load_cell(cell_name)
     config, mix = cell.config, cell.mix
@@ -230,10 +251,11 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         chips = int(declared[cell_name]["chips"])
     model = config["registry_name"]
     with served(cell, "cpu" if rehearse else "tpu", chips,
-                t_process_start) as up:
+                timeline) as up:
         stack, device, waited, warm = (up.stack, up.device, up.waited,
                                        up.warm)
         t_ready, t_warm = up.t_ready, up.t_warm
+        timeline.enter("check")
         check = setup_steps.check_outputs(
             stack.router_port, model, cell.cell["config"], config, waited)
         t_checked = time.monotonic()
@@ -260,12 +282,14 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         attention = stack.attention_impl()
         compiled = stack.compile_log()
         stack.check_alive()
-        reducer = _start_reduce(up.profile_dir) if trace else None
+        timeline.enter("stop")
+        up.reducer = _start_reduce(up.profile_dir) if trace else None
         # everything a run reads of its children is read: end them at once
         # (a graceful stop of the server takes 8-14 s of a run's 360)
         exit_codes = stack.stop(hard=True)
         t_stopped = time.monotonic()
-        reduced = _finish_reduce(reducer)
+        timeline.enter("reduce")
+        reduced = _finish_reduce(up.reducer)
         t_reduced = time.monotonic()
 
     records = got["records"]

@@ -16,7 +16,7 @@ import subprocess
 import sys
 import time
 
-from . import manifest
+from . import lifecycle, manifest
 
 
 class NoResult(Exception):
@@ -36,13 +36,16 @@ def free_port() -> int:
 
 class Child:
     """One ``python -m llms_on_kubernetes_tpu ...`` process, its output in
-    a log file the parent can read while it runs."""
+    a log file the parent can read while it runs. The kernel kills it when
+    the parent dies (``lifecycle.spawn``); that follows the thread that
+    forked, so build a ``Child`` from the main thread only. It leads a
+    session of its own, so that ``stop`` can end its helpers with it."""
 
     def __init__(self, name: str, args: list, env: dict, workdir: str):
         self.name = name
         self.log_path = os.path.join(workdir, f"{name}.log")
         self._log = open(self.log_path, "wb")
-        self.proc = subprocess.Popen(
+        self.proc = lifecycle.spawn(
             [sys.executable, "-m", "llms_on_kubernetes_tpu", *args],
             cwd=manifest.REPO_DIR, env=env, stdout=self._log,
             stderr=subprocess.STDOUT, start_new_session=True)

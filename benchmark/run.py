@@ -9,6 +9,12 @@ JAX), warms up, checks outputs, offers open-loop traffic for --seconds,
 drains, and prints the result as the last line of stdout. Without a TPU
 that is in the benchmark's peak table, or outside a checkout, it prints no
 result and exits non-zero. ``BENCH_RUN`` in the environment is ignored.
+
+However it ends, it leaves no process behind (``harness/lifecycle.py``):
+stderr names the children it started ("benchmark: children server=PID
+router=PID"), and a run ended by SIGTERM, SIGHUP or SIGINT stops them,
+says "benchmark: ended by signal N after S s in phase P", prints no
+result and exits 128 + N.
 """
 
 import time
@@ -21,10 +27,10 @@ import sys  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from harness import cell, launcher, manifest  # noqa: E402
+from harness import cell, launcher, lifecycle, manifest  # noqa: E402
 
 
-def main() -> int:
+def main(timeline: lifecycle.Timeline) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload")
     ap.add_argument("--seed", type=int, default=0)
@@ -43,7 +49,7 @@ def main() -> int:
         if not name:
             ap.error("--workload is required")
         return cell.run(name, args.seed, seconds, bool(args.trace),
-                        args.rehearse, T0)
+                        args.rehearse, timeline)
     except (launcher.NoResult, manifest.ManifestError) as e:
         print(f"benchmark: no result: {e}", file=sys.stderr)
         return 2
@@ -53,4 +59,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(lifecycle.guarded(main, lifecycle.Timeline(T0)))

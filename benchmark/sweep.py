@@ -29,7 +29,8 @@ import sys  # noqa: E402
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from harness import cell as cellmod  # noqa: E402
-from harness import launcher, manifest, setup_steps, stats  # noqa: E402
+from harness import (launcher, lifecycle, manifest,  # noqa: E402
+                     setup_steps, stats)
 
 
 def judge(got: dict, mix: dict, rate: float) -> dict:
@@ -62,7 +63,7 @@ def judge(got: dict, mix: dict, rate: float) -> dict:
     }
 
 
-def main() -> int:
+def main(timeline: lifecycle.Timeline) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--rates", required=True,
@@ -81,7 +82,7 @@ def main() -> int:
                 f"{args.workload!r} is not a {platform} cell")
         rows = []
         with cellmod.served(cell, platform, int(cell.config["chips"]),
-                            T0) as up:
+                            timeline) as up:
             cellmod.storms(up, cell)
             for i, rate in enumerate(rates):
                 before = setup_steps.compiles(up.stack.server_port)
@@ -121,4 +122,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(lifecycle.guarded(main, lifecycle.Timeline(T0, "sweep")))

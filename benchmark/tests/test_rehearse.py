@@ -10,6 +10,7 @@ import sys
 import pytest
 
 from harness import manifest
+from test_lifecycle import CHILDREN, still_alive_after
 
 RUN = os.path.join(manifest.BENCH_DIR, "run.py")
 ENV = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -23,6 +24,9 @@ def rehearsal():
         env=ENV, capture_output=True, text=True, timeout=900,
         cwd=manifest.REPO_DIR)
     assert proc.returncode == 0, proc.stderr[-2000:]
+    # a run that ends by itself leaves neither child behind
+    pids = [int(g) for g in CHILDREN.search(proc.stderr).groups()]
+    assert still_alive_after(pids, 5) == []
     return proc.stdout.strip().splitlines()
 
 
