@@ -238,7 +238,9 @@ def measure_engine(eng, cfg, prompt_len, gen_len, rng) -> dict:
         out.update({
             "goodput_tokens_per_chip_s": round(
                 snap["decode_tokens"] / window_s, 1),
-            "mfu": round(snap["flops"] / (led.peak_flops * window_s), 6),
+            # None on a CPU: it has no peak to be a share of
+            "mfu": (None if led.peak_flops is None else round(
+                snap["flops"] / (led.peak_flops * window_s), 6)),
             "wasted_chip_fraction": round(
                 snap["wasted_ms"] / max(snap["window_ms"], 1e-9), 4),
             "chip_ms_attributed": round(snap["attributed_ms"], 1),

@@ -286,10 +286,8 @@ def main(argv: list[str] | None = None) -> int:
     # and the jit counters see the warmup's compiles and cache hits
     print(f"[serve] persistent compile cache: "
           f"{configure_compilation_cache()}", file=sys.stderr)
-    from llms_on_kubernetes_tpu.server.runtime_telemetry import (
-        RuntimeTelemetry,
-    )
-    RuntimeTelemetry.install_listeners()
+    from llms_on_kubernetes_tpu.engine import jit_events
+    jit_events.install()
     # which device answered, before the minutes of loading and compiling:
     # a start on the wrong platform should be visible (chip_smoke.py reads
     # this line) long before /ready
@@ -463,6 +461,7 @@ def main(argv: list[str] | None = None) -> int:
         tokenizer = load_tokenizer(model_dir)
     served = args.served_model_name or model_cfg.name
     peak = ("off" if engine.ledger is None else
+            "none" if engine.ledger.peak_flops is None else
             f"{engine.ledger.peak_flops / 1e12:g}TFLOP/s,"
             f"{engine.ledger.peak_bytes_s / 1e9:g}GB/s")
     print(f"[serve] {served}: mesh={dict(mesh.shape)} dtype={args.dtype} "

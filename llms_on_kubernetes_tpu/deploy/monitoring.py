@@ -587,9 +587,11 @@ def grafana_dashboard() -> dict[str, Any]:
         _panel(6, "JIT compiles vs cache hits",
                ["rate(llm_jit_compiles_total[5m])",
                 "rate(llm_jit_cache_hits_total[5m])"], 12, 16),
-        _panel(7, "Step time split: device vs host",
-               ["rate(llm_step_device_seconds_total[5m])",
-                "rate(llm_step_host_seconds_total[5m])"], 0, 24,
+        _panel(7, "Device time by dispatch kind vs idle by cause",
+               ["sum by (kind) "
+                "(rate(llm_dispatch_device_seconds_total[5m]))",
+                "sum by (host) "
+                "(rate(llm_device_idle_seconds_total[5m]))"], 0, 24,
                unit="percentunit"),
         _panel(8, "Fleet: replica health / engine state",
                ["llm_replica_healthy", "llm_engine_state",
@@ -694,6 +696,15 @@ def grafana_dashboard() -> dict[str, Any]:
         _panel(34, "Tracing: traces dropped (by reason)",
                ["sum by (reason) "
                 "(rate(llm_trace_dropped_total[5m]))"], 12, 128),
+        _panel(35, "Dispatch waits by kind: behind earlier dispatches / "
+               "host enqueue, seconds per dispatch",
+               ["sum by (kind) "
+                "(rate(llm_dispatch_behind_seconds_total[5m])) / "
+                "sum by (kind) (rate(llm_dispatches_total[5m]))",
+                "sum by (kind) "
+                "(rate(llm_dispatch_enqueue_seconds_total[5m])) / "
+                "sum by (kind) (rate(llm_dispatches_total[5m]))"],
+               0, 136, unit="s"),
     ]
     return {
         "title": "LLM serving on TPU — cluster overview",

@@ -493,6 +493,7 @@ class PageAllocator:
 
     def __init__(self, num_pages: int, page_size: int, num_slots: int,
                  pages_per_slot: int, prefix_caching: bool = False):
+        self.num_pages = num_pages
         self.page_size = page_size
         self.pages_per_slot = pages_per_slot
         self.num_slots = num_slots
@@ -522,6 +523,13 @@ class PageAllocator:
     @property
     def num_evictable_pages(self) -> int:
         return len(self._lru)
+
+    @property
+    def num_live_pages(self) -> int:
+        """Pages some live sequence holds: neither free nor a finished
+        request's cached prefix waiting for eviction (page 0 is trash)."""
+        return (self.num_pages - 1 - len(self.free_pages)
+                - len(self._lru))
 
     def pages_needed(self, num_tokens: int) -> int:
         return -(-num_tokens // self.page_size)

@@ -25,7 +25,9 @@ bridges to asyncio.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -36,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from llms_on_kubernetes_tpu.configs import ModelConfig, get_config
+from llms_on_kubernetes_tpu.engine import jit_events
 from llms_on_kubernetes_tpu.engine.cache import (
     CacheConfig, HostKVCache, PageAllocator, init_pages,
 )
@@ -51,6 +54,13 @@ from llms_on_kubernetes_tpu.models.decoder import (
 )
 
 Params = dict[str, Any]
+
+
+# the engine thread's phases (llmk.admit, llmk.pack, llmk.dispatch,
+# llmk.harvest, llmk.wait, llmk.emit) on the profiler's clock: any capture
+# shows what the host did in each device gap. Outside a capture an
+# annotation costs well under a microsecond.
+_phase = jax.profiler.TraceAnnotation
 
 
 class EngineStallError(RuntimeError):
@@ -509,6 +519,13 @@ class Request:
     admitted_at: Optional[float] = None  # prefill dispatched (TTFT breakdown)
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None  # terminal event recorded (_finish)
+    # from the dispatch record of the prefill that produced the first
+    # token (engine/ledger.py writes each once): the call that enqueued
+    # it returned, the device was free for it, its read landed. They
+    # split admitted_at..first_token_at for the trace; None without ledger
+    prefill_launched_at: Optional[float] = None
+    prefill_started_at: Optional[float] = None
+    prefill_read_at: Optional[float] = None
     # server-side trace sink (duck-typed: anything with .event(name, **kv));
     # the API layer points this at the request's Trace so engine-side
     # preemption/deadline/stall land on the distributed timeline. None for
@@ -548,7 +565,7 @@ class InflightStep:
     spec: bool = False                     # speculative verify dispatch:
     #                                        pack is (packs [K,B,W], accept [B])
     drafted: Optional[dict] = None         # slot -> drafted tokens this window
-    launched_at: float = 0.0               # dispatch time (goodput ledger)
+    dseq: int = -1                         # its dispatch record (ledger)
 
 
 class _Harvester(threading.Thread):
@@ -574,7 +591,7 @@ class _Harvester(threading.Thread):
       reads it doesn't depend on."""
 
     def __init__(self, readers: Optional[int] = None,
-                 batch: Optional[int] = None):
+                 batch: Optional[int] = None, watch: bool = False):
         import os
         super().__init__(daemon=True, name="engine-harvester")
         self._cv = threading.Condition()
@@ -585,6 +602,16 @@ class _Harvester(threading.Thread):
         # monotonic completion time per done key — when its device_get
         # landed. The goodput ledger segments busy time on these.
         self._done_t: dict[int, float] = {}
+        # ``watch`` (the engine has a ledger): one more thread waits on
+        # every pushed result in LAUNCH order and stamps when it is
+        # complete on the device. A read lands later, a batch's items
+        # all at once, and sometimes much later: with both readers
+        # inside priority batches nobody reads the decode steps, and the
+        # step ahead of a prefill was stamped after the prefill's read
+        # (up to a quarter of the prefill records on the chip)
+        self._watching = watch
+        self._watch: "collections.deque[tuple[int, Any]]" = collections.deque()
+        self._ready_t: dict[int, float] = {}
         self._done_upto = -1
         self._next_seq = 0                  # next step seq to mark done
         self._stopping = False
@@ -614,12 +641,43 @@ class _Harvester(threading.Thread):
                                  name=f"engine-harvester-{i + 1}")
             t.start()
             self._extra.append(t)
+        if self._watching:
+            t = threading.Thread(target=self._run_watch, daemon=True,
+                                 name="engine-harvester-watch")
+            t.start()
+            self._extra.append(t)
 
     def push(self, key: int, res: Any, priority: bool = False) -> None:
         _start_host_copy(res)  # transfer overlaps with device compute
         with self._cv:
             (self._prio if priority else self._pending).append((key, res))
+            if self._watching:
+                self._watch.append((key, res))
             self._cv.notify_all()
+
+    def _run_watch(self) -> None:
+        while True:
+            with self._cv:
+                while not self._watch and not self._stopping:
+                    self._cv.wait()
+                if not self._watch:
+                    return
+                key, res = self._watch.popleft()
+            try:
+                # the fault that stands for a slow device (see run())
+                # slows what is seen of it here too
+                from llms_on_kubernetes_tpu import faults
+                faults.inject_delay("slow_step", 0.2)
+                jax.block_until_ready(res)
+            except BaseException:  # noqa: BLE001 — the read surfaces it
+                continue
+            t_ready = time.monotonic()
+            with self._cv:
+                self._ready_t[key] = t_ready
+                # a key read and discarded before this thread got to stamp
+                # it is never taken away again: bound the dict itself
+                if len(self._ready_t) > 256:
+                    del self._ready_t[next(iter(self._ready_t))]
 
     def run(self) -> None:
         while True:
@@ -692,10 +750,14 @@ class _Harvester(threading.Thread):
             return self._done[key]
 
     def done_time(self, key: int) -> float:
-        """Monotonic time key's device_get completed (ledger segmenting);
-        falls back to now for keys whose stamp was already discarded."""
+        """Monotonic time key's result was complete on the device (the
+        watcher's stamp) or, failing that, its device_get completed — the
+        earlier of the two (ledger segmenting); falls back to now for
+        keys whose stamps were already discarded."""
         with self._cv:
-            return self._done_t.get(key, time.monotonic())
+            seen = [t for t in (self._ready_t.get(key), self._done_t.get(key))
+                    if t is not None]
+        return min(seen) if seen else time.monotonic()
 
     def wait_done(self, seq: int, wake: Optional[threading.Event] = None,
                   timeout_s: Optional[float] = None) -> None:
@@ -748,11 +810,13 @@ class _Harvester(threading.Thread):
             for s in [s for s in self._done if 0 <= s <= seq]:
                 del self._done[s]
                 self._done_t.pop(s, None)
+                self._ready_t.pop(s, None)
 
     def discard_key(self, key: int) -> None:
         with self._cv:
             self._done.pop(key, None)
             self._done_t.pop(key, None)
+            self._ready_t.pop(key, None)
 
     def stop(self) -> None:
         with self._cv:
@@ -1518,9 +1582,16 @@ class Engine:
                     warmup=int(os.environ.get("LLMK_ANOMALY_WARMUP", "12")),
                 )
             self.ledger = GoodputLedger(cfg, detector=det)
-        # in-flight prefill dispatches awaiting their priority read:
-        # key -> (launch time, [(request, prefill tokens), ...])
-        self._ledger_prefills: dict[int, tuple[float, list]] = {}
+            # a dispatch during which the process compiled, or fetched an
+            # executable from its persistent cache, re-traced its step
+            jit_events.install()
+        # one number per device dispatch, in launch order: the ledger's
+        # record, the seq of its llmk.dispatch trace annotation and (for a
+        # prefill, as -1 - seq) the key of its priority read
+        self._dispatch_seq = itertools.count()
+        # step() found the engine without work since the last launch: the
+        # next dispatch's idle gap is nobody's fault
+        self._saw_no_work = False
 
         self._prefill_packed = jax.jit(
             _prefill_packed_step, static_argnums=(1,), donate_argnums=(4, 5, 6)
@@ -1573,13 +1644,12 @@ class Engine:
         # (request, priority key, row) awaiting a first-token read
         self._pending_first: list[tuple[Request, int, int]] = []
         self._seq_counter = iter(range(2 ** 62))     # decode steps (dense)
-        self._first_counter = iter(range(2 ** 62))   # priority prefill reads
         # set by submit(): breaks the backpressure wait so admission (and
         # the new request's prefill dispatch) never waits out a read
         self._admit_wake = threading.Event()
         self._harvester: Optional[_Harvester] = None
         if self._async:
-            self._harvester = _Harvester()
+            self._harvester = _Harvester(watch=self.ledger is not None)
             self._harvester.start()
             import weakref
             weakref.finalize(self, self._harvester.stop)
@@ -2044,9 +2114,12 @@ class Engine:
         events += self._reap_aborted()
         if self._async:
             try:
-                admitted = self._admit_async(events)
-                status = self._launch_decode_async(admitted, events)
-                events += self._harvest(drain=status == "idle")
+                with _phase("llmk.admit"):
+                    admitted = self._admit_async(events)
+                with _phase("llmk.pack"):
+                    status = self._launch_decode_async(admitted, events)
+                with _phase("llmk.harvest"):
+                    events += self._harvest(drain=status == "idle")
             except EngineStallError as e:
                 events += self._shed_wedged(str(e))
                 status = "idle"
@@ -2054,15 +2127,21 @@ class Engine:
                 # nothing to do until device work completes; a bounded nap
                 # keeps the loop from burning the GIL the harvester needs
                 # (admissions arriving mid-nap wait <= 1 ms)
-                time.sleep(0.001)
+                with _phase("llmk.wait"):
+                    time.sleep(0.001)
         else:
-            events += self._admit_one()
-            events += self._decode_once()
-        for ev in events:
-            payload = (ev.new_tokens, ev.finished, ev.finish_reason)
-            ev.request.events.put(payload)
-            if ev.request.on_event is not None:
-                ev.request.on_event(payload)
+            with _phase("llmk.admit"):
+                events += self._admit_one()
+            with _phase("llmk.pack"):
+                events += self._decode_once()
+        with _phase("llmk.emit"):
+            for ev in events:
+                payload = (ev.new_tokens, ev.finished, ev.finish_reason)
+                ev.request.events.put(payload)
+                if ev.request.on_event is not None:
+                    ev.request.on_event(payload)
+        if not self.has_work():
+            self._saw_no_work = True
         return events
 
     def abort(self, req: Request, reason: str = "abort") -> None:
@@ -2101,6 +2180,38 @@ class Engine:
                 events.append(self._finish(r, r.abort_reason))
         return events
 
+    @contextlib.contextmanager
+    def _dispatch(self, kind: str, name: str, shape: str,
+                  rows: Optional[list] = None):
+        """Around the jitted call(s) of ONE device dispatch: opens its
+        ledger record, puts ``llmk.dispatch`` with its kind and seq on the
+        profiler's timeline, and on the way out stamps the launch (the
+        work is enqueued) with the host time the call took and whether the
+        process re-traced meanwhile. Yields the dispatch's seq."""
+        seq = next(self._dispatch_seq)
+        led = self.ledger
+        rec = None
+        if led is not None:
+            events = jit_events.count()
+            no_work, self._saw_no_work = self._saw_no_work, False
+            rec = led.open(seq, kind, name, shape, time.monotonic(),
+                           rows=rows, after_no_work=no_work)
+        try:
+            with _phase("llmk.dispatch", kind=kind, seq=seq):
+                yield seq
+        except BaseException:
+            if rec is not None:
+                led.abandon(seq)
+            raise
+        if rec is not None:
+            retraced = jit_events.count() != events
+            led.launched(rec, time.monotonic(), retraced)
+            if retraced:
+                from llms_on_kubernetes_tpu.server.tracing import jlog
+
+                jlog("dispatch_retraced", kind=kind, step=name, shape=shape,
+                     seq=seq, seconds=round(rec.enqueue_ms / 1000.0, 3))
+
     def _mh_send(self, op: int, **fields) -> None:
         """Announce the next device call to follower pods (no-op single-host).
         One packed broadcast per call — see engine/multihost.py."""
@@ -2123,7 +2234,6 @@ class Engine:
 
     def _pack_prefill_row(self, packed: np.ndarray, row: int, req: Request,
                           n: int, slot: int) -> None:
-        req.admitted_at = time.monotonic()
         packed[row, 0] = n
         packed[row, 1] = req.params.top_k
         packed[row, 2] = np.float32(req.params.temperature).view(np.int32)
@@ -2158,7 +2268,8 @@ class Engine:
         raise ValueError(f"no prefill bucket fits {n} tokens")
 
     def _chunked_prefill(self, slot: int, req: Request,
-                         prefill_tokens: list[int], start: int = 0):
+                         prefill_tokens: list[int], led_rows: list,
+                         start: int = 0):
         """Prefill a prompt in bucket-size chunks against the paged pool
         (prefill-with-history attention, forward_chunk), beginning at
         position ``start`` (> 0 when a cached prefix was adopted — those
@@ -2167,7 +2278,21 @@ class Engine:
         each chunk chains on the previous through the donated page pool —
         no host read here, so the async pipeline stays full. Returns the
         FINAL chunk's (packed result, device tokens) pair (row 0 is the
-        request's first generated token)."""
+        request's first generated token) and the seq of the chain's one
+        dispatch record (only the final chunk is ever read, so the host
+        can time the chain, not its links)."""
+        n = len(prefill_tokens)
+        step = max(self.config.prefill_buckets)
+        chunks = -(-(n - start) // step)
+        with self._dispatch("chunk", "_chunk_packed_step",
+                            f"{chunks}x{self._bucket_for(min(step, n - start))}",
+                            led_rows) as dseq:
+            pack, toks = self._chunk_chain(slot, req, prefill_tokens, start)
+        self.slot_len[slot] = n
+        return pack, toks, dseq
+
+    def _chunk_chain(self, slot: int, req: Request,
+                     prefill_tokens: list[int], start: int):
         from llms_on_kubernetes_tpu.engine.multihost import MSG_CHUNK
 
         n = len(prefill_tokens)
@@ -2218,7 +2343,6 @@ class Engine:
             if new_state is not None:
                 self._fsm_state = new_state
             pos += m
-        self.slot_len[slot] = n
         return pack, toks
 
     def _cache_salt_for(self, images) -> Optional[bytes]:
@@ -2517,10 +2641,11 @@ class Engine:
         return pack, toks
 
     def _dispatch_mm_prefill(self, slot: int, req: Request,
-                             prefill_tokens: list[int]):
+                             prefill_tokens: list[int], led_rows: list):
         """Build a multimodal admission's inputs, announce them to
         follower pods (control word + pixel payload), and run the encode
-        + prefill. Returns the device SampleResult."""
+        + prefill (one dispatch record: only the prefill is read). Returns
+        the device SampleResult and the dispatch's seq."""
         cfg = self.model_config
         n = len(prefill_tokens)
         bucket = self._bucket_for(n)
@@ -2550,9 +2675,11 @@ class Engine:
                           pre_packed=packed)
             mh.send_mm_payload(self._mh_shapes, req.images,
                                None if pos3 is None else pos3[0])
-        pack, toks = self._mm_execute(req.images, tokens, packed, pos3)
+        with self._dispatch("prefill", "_prefill_mm_packed_step",
+                            f"1x{bucket}", led_rows) as dseq:
+            pack, toks = self._mm_execute(req.images, tokens, packed, pos3)
         self.slot_len[slot] = n
-        return pack, toks
+        return pack, toks, dseq
 
     # ------------------------------------------------------------------
     # grammar-constrained decoding: device-table residency
@@ -2721,15 +2848,16 @@ class Engine:
         if resumed and req.fsm_row >= 0:
             self._fsm_replay(req)  # stages fsm_set for the next decode
 
-        t_launch = time.monotonic()
+        led_rows = [(req, "prefill", n - hit or n)]
         if req.images is not None and hit == 0:
-            pack, _toks = self._dispatch_mm_prefill(slot, req, prefill_tokens)
+            pack, _toks, dseq = self._dispatch_mm_prefill(
+                slot, req, prefill_tokens, led_rows)
         elif hit > 0 or n > max(self.config.prefill_buckets):
             # cache-hit admissions run the chunk path: prefill-with-history
             # attention over the remainder, history = the adopted prefix
             # (for a multimodal hit the remainder is pure text)
-            pack, _toks = self._chunked_prefill(slot, req, prefill_tokens,
-                                                start=hit)
+            pack, _toks, dseq = self._chunked_prefill(
+                slot, req, prefill_tokens, led_rows, start=hit)
         else:
             from llms_on_kubernetes_tpu.engine.multihost import MSG_PREFILL
 
@@ -2744,13 +2872,15 @@ class Engine:
             use_fsm = packed[0, _FSM_PRE] >= 0
             self._mh_send(MSG_PREFILL, pre_tokens=tokens, pre_packed=packed,
                           fsm_used=use_fsm)
-            (pack, _toks, self.k_pages, self.v_pages, self.token_counts,
-             new_state) = self._prefill_packed(
-                self.params, self.model_config, jnp.asarray(tokens),
-                jnp.asarray(packed), self.k_pages, self.v_pages,
-                self.token_counts, self._key,
-                self._fsm_args() if use_fsm else None,
-            )
+            with self._dispatch("prefill", "_prefill_packed_step",
+                                f"1x{bucket}", led_rows) as dseq:
+                (pack, _toks, self.k_pages, self.v_pages, self.token_counts,
+                 new_state) = self._prefill_packed(
+                    self.params, self.model_config, jnp.asarray(tokens),
+                    jnp.asarray(packed), self.k_pages, self.v_pages,
+                    self.token_counts, self._key,
+                    self._fsm_args() if use_fsm else None,
+                )
             if new_state is not None:
                 self._fsm_state = new_state
             self.slot_len[slot] = n
@@ -2761,13 +2891,14 @@ class Engine:
                                            salt=req.cache_salt)
         if resumed:
             req.pending_token = req.output[-1]
+            if self.ledger is not None:
+                self.ledger.close(dseq, None)   # nobody reads a re-prefill
             return []
         t0 = time.perf_counter()
         host = HostSample(np.asarray(jax.device_get(pack)))
         self._device_time_s += time.perf_counter() - t0
         if self.ledger is not None:
-            self.ledger.record(t_launch, time.monotonic(),
-                               [(req, "prefill", n - hit or n)])
+            self.ledger.close(dseq, time.monotonic())
         first = int(host.tokens[0])
         req.pending_token = first
         req.first_token_at = time.monotonic()
@@ -2863,19 +2994,22 @@ class Engine:
                 events.append(self._finish(req, "stalled"))
         self._inflight.clear()
         self._pending_first = []
-        self._ledger_prefills.clear()
+        if self.ledger is not None:
+            self.ledger.abandon()   # their reads will never come
         return events
 
     def _note_admission(self, req: Request) -> None:
         """Per-tenant admission accounting, recorded where a request takes
         its slot. Only FIRST admissions count (admitted_at is still None;
         a preemption round trip is not new tenant throughput) — the
-        serving loop drains these into the llm_tenant_* series."""
+        serving loop drains these into the llm_tenant_* series. The one
+        writer of admitted_at, on every prefill path."""
         if req.admitted_at is not None:
             return
+        req.admitted_at = time.monotonic()
         self.tenant_admitted[(req.tenant, req.priority)] += 1
         self.tenant_wait_obs.append(
-            (req.tenant, time.monotonic() - req.submitted_at, req.priority))
+            (req.tenant, req.admitted_at - req.submitted_at, req.priority))
 
     def _preempt_youngest(self) -> None:
         """Free a victim's pages; requeue it to re-prefill (prompt +
@@ -2984,7 +3118,6 @@ class Engine:
         self.decode_dispatches += 1
         self.decode_tokens += len(active)
         self.steps_obs.append(1)
-        t_launch = time.monotonic()
         packed = self._dec_template(active)
         for i, r in active:
             packed[i, 0] = self.slot_len[i] + 1
@@ -2996,21 +3129,23 @@ class Engine:
 
         use_fsm = self._fsm_any_active()
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
-        (pack, _toks, self.k_pages, self.v_pages, self.token_counts,
-         new_state) = self._decode_packed(
-            self.params, self.model_config, jnp.asarray(packed),
-            self._zeros_B, self._zeros_1, self.k_pages, self.v_pages,
-            self.token_counts, self._key,
-            self._fsm_args() if use_fsm else None,
-        )
+        with self._dispatch("decode", "_decode_packed_step",
+                            f"1x{len(active)}") as dseq:
+            (pack, _toks, self.k_pages, self.v_pages, self.token_counts,
+             new_state) = self._decode_packed(
+                self.params, self.model_config, jnp.asarray(packed),
+                self._zeros_B, self._zeros_1, self.k_pages, self.v_pages,
+                self.token_counts, self._key,
+                self._fsm_args() if use_fsm else None,
+            )
         if new_state is not None:
             self._fsm_state = new_state
         t0 = time.perf_counter()
         host = HostSample(np.asarray(jax.device_get(pack)))
         self._device_time_s += time.perf_counter() - t0
         if self.ledger is not None:
-            self.ledger.record(t_launch, time.monotonic(),
-                               [(r, "decode", 1) for _i, r in active])
+            self.ledger.close(dseq, time.monotonic(),
+                              [(r, "decode", 1) for _i, r in active])
 
         events: list[StepEvent] = []
         for i, r in active:
@@ -3143,16 +3278,16 @@ class Engine:
             # below is dispatched — its history attention reads them.
             # Outside the lock: the np.stack memcpy must not block submit()
             self._host_kv_commit(slot, req)
-            t_launch = time.monotonic()
+            led_rows = [(req, "prefill", max(1, len(prefill_tokens) - hit))]
             if req.images is not None and hit == 0:
-                pack, toks = self._dispatch_mm_prefill(slot, req,
-                                                       prefill_tokens)
+                pack, toks, dseq = self._dispatch_mm_prefill(
+                    slot, req, prefill_tokens, led_rows)
                 n_chunks = 2  # image encode + prefill
             else:
                 # cache-hit remainder (pure text for multimodal hits) or
                 # an out-of-bucket text prompt
-                pack, toks = self._chunked_prefill(slot, req, prefill_tokens,
-                                                   start=hit)
+                pack, toks, dseq = self._chunked_prefill(
+                    slot, req, prefill_tokens, led_rows, start=hit)
                 n_chunks = -(-(len(prefill_tokens) - hit)
                              // max(self.config.prefill_buckets))
             if req.cache_salt is not None:
@@ -3162,19 +3297,17 @@ class Engine:
                                 + 2.0 * n_chunks * self._est_step)
             merge = {"toks": toks, "slots": {}}
             if resumed:
-                # no priority read to segment on — the resumed re-prefill's
-                # device time folds into the next decode harvest's segment
+                # no priority read: the host knows the re-prefill done
+                # only when the decode step launched behind it is read
                 req.pending_token = req.output[-1]
                 merge["slots"][slot] = (True, req.output[-1], 0)
+                if self.ledger is not None:
+                    self.ledger.close(dseq, None)
             else:
-                key = -1 - next(self._first_counter)
+                key = -1 - dseq
                 self._harvester.push(key, pack, priority=True)
                 merge["slots"][slot] = (False, 0, 0)
                 self._pending_first.append((req, key, 0))
-                if self.ledger is not None:
-                    self._ledger_prefills[key] = (
-                        t_launch,
-                        [(req, max(1, len(prefill_tokens) - hit))])
             return merge
         if not picked:
             return None
@@ -3197,16 +3330,20 @@ class Engine:
             self.slot_len[slot] = n
 
         use_fsm = bool((packed[:, _FSM_PRE] >= 0).any())
-        t_launch = time.monotonic()
         self._mh_send(MSG_PREFILL, pre_tokens=tokens, pre_packed=packed,
                       fsm_used=use_fsm)
-        (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-         new_state) = self._prefill_packed(
-            self.params, self.model_config, jnp.asarray(tokens),
-            jnp.asarray(packed), self.k_pages, self.v_pages,
-            self.token_counts, self._key,
-            self._fsm_args() if use_fsm else None,
-        )
+        # every picked row rode the dispatch, resumed ones included
+        led_rows = [(req, "prefill", max(1, len(ptoks)))
+                    for _slot, req, _resumed, ptoks in picked]
+        with self._dispatch("prefill", "_prefill_packed_step",
+                            f"{K}x{bucket}", led_rows) as dseq:
+            (pack, toks, self.k_pages, self.v_pages, self.token_counts,
+             new_state) = self._prefill_packed(
+                self.params, self.model_config, jnp.asarray(tokens),
+                jnp.asarray(packed), self.k_pages, self.v_pages,
+                self.token_counts, self._key,
+                self._fsm_args() if use_fsm else None,
+            )
         if new_state is not None:
             self._fsm_state = new_state
         self._busy_until = (max(time.monotonic(), self._busy_until)
@@ -3216,14 +3353,10 @@ class Engine:
         key = None
         if any(not resumed for _, _, resumed, _ in picked):
             # priority read: first tokens jump the decode-read queue
-            key = -1 - next(self._first_counter)
+            key = -1 - dseq
             self._harvester.push(key, pack, priority=True)
-            if self.ledger is not None:
-                # every picked row rode the dispatch, resumed ones included
-                self._ledger_prefills[key] = (
-                    t_launch,
-                    [(req, max(1, len(ptoks)))
-                     for _slot, req, _resumed, ptoks in picked])
+        elif self.ledger is not None:
+            self.ledger.close(dseq, None)   # nobody reads a re-prefill
         merge = {"toks": toks, "slots": {}}
         for row, (slot, req, resumed, _ptoks) in enumerate(picked):
             if resumed:
@@ -3328,19 +3461,20 @@ class Engine:
         self._mh_send(MSG_DECODE, dec_packed=packed,
                       last_valid=bool(self._inflight),
                       use_prefill=admitted is not None, fsm_used=use_fsm)
-        (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-         new_state) = self._decode_packed(
-            self.params, self.model_config, jnp.asarray(packed),
-            last_toks, prefill_toks, self.k_pages, self.v_pages,
-            self.token_counts, self._key,
-            self._fsm_args() if use_fsm else None,
-        )
+        with self._dispatch("decode", "_decode_packed_step",
+                            f"1x{len(active)}") as dseq:
+            (pack, toks, self.k_pages, self.v_pages, self.token_counts,
+             new_state) = self._decode_packed(
+                self.params, self.model_config, jnp.asarray(packed),
+                last_toks, prefill_toks, self.k_pages, self.v_pages,
+                self.token_counts, self._key,
+                self._fsm_args() if use_fsm else None,
+            )
         if new_state is not None:
             self._fsm_state = new_state
         seq = next(self._seq_counter)
         step = InflightStep(pack, toks, active, seq,
-                            planned={i: 1 for i, _r in active},
-                            launched_at=time.monotonic())
+                            planned={i: 1 for i, _r in active}, dseq=dseq)
         self._inflight.append(step)
         self._harvester.push(seq, pack)
         now = time.monotonic()
@@ -3446,19 +3580,21 @@ class Engine:
         # multihost always clamps decode_steps to 1 in EngineConfig, so
         # this path never needs a broadcast message
         use_fsm = self._fsm_any_active()
-        (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-         new_state) = self._decode_multi(
-            self.params, self.model_config, K, jnp.asarray(packed),
-            last_toks, prefill_toks, self.k_pages, self.v_pages,
-            self.token_counts, self._key,
-            self._fsm_args() if use_fsm else None,
-        )
+        with self._dispatch("decode", "_decode_multi_packed_step",
+                            f"{K}x{len(active)}") as dseq:
+            (pack, toks, self.k_pages, self.v_pages, self.token_counts,
+             new_state) = self._decode_multi(
+                self.params, self.model_config, K, jnp.asarray(packed),
+                last_toks, prefill_toks, self.k_pages, self.v_pages,
+                self.token_counts, self._key,
+                self._fsm_args() if use_fsm else None,
+            )
         if new_state is not None:
             self._fsm_state = new_state
         seq = next(self._seq_counter)
         step = InflightStep(pack, toks, active, seq,
                             planned={i: plan.get(i, 0) for i, _r in active},
-                            launched_at=time.monotonic())
+                            dseq=dseq)
         self._inflight.append(step)
         self._harvester.push(seq, pack)
         now = time.monotonic()
@@ -3555,19 +3691,20 @@ class Engine:
         full = np.concatenate([packed, ext], axis=1)
 
         use_fsm = self._fsm_any_active()
-        (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-         new_state) = self._decode_spec(
-            self.params, self.model_config, K, jnp.asarray(full),
-            self.k_pages, self.v_pages, self.token_counts, self._key,
-            self._fsm_args() if use_fsm else None,
-        )
+        with self._dispatch("spec", "_decode_spec_packed_step",
+                            f"{K}x{len(active)}") as dseq:
+            (pack, toks, self.k_pages, self.v_pages, self.token_counts,
+             new_state) = self._decode_spec(
+                self.params, self.model_config, K, jnp.asarray(full),
+                self.k_pages, self.v_pages, self.token_counts, self._key,
+                self._fsm_args() if use_fsm else None,
+            )
         if new_state is not None:
             self._fsm_state = new_state
         seq = next(self._seq_counter)
         step = InflightStep(pack, toks, active, seq,
                             planned={i: plan.get(i, 0) for i, _r in active},
-                            spec=True, drafted=drafted,
-                            launched_at=time.monotonic())
+                            spec=True, drafted=drafted, dseq=dseq)
         self._inflight.append(step)
         self._harvester.push(seq, pack)
         now = time.monotonic()
@@ -3607,15 +3744,17 @@ class Engine:
             # and feed the model a wrong input token.
             key = self._head_blocking_first()
             if key is not None:
-                self._harvester.wait_key(key, timeout_s=budget)
+                with _phase("llmk.wait"):
+                    self._harvester.wait_key(key, timeout_s=budget)
                 continue
             if self._inflight:
                 k = (len(self._inflight) if drain
                      else len(self._inflight) - (depth - 1))
-                self._harvester.wait_done(
-                    self._inflight[k - 1].seq,
-                    wake=None if drain else self._admit_wake,
-                    timeout_s=budget)
+                with _phase("llmk.wait"):
+                    self._harvester.wait_done(
+                        self._inflight[k - 1].seq,
+                        wake=None if drain else self._admit_wake,
+                        timeout_s=budget)
                 if not drain and self._admit_wake.is_set():
                     # a submission wants admission NOW; collect whatever
                     # completed and hand control back (pipeline may sit
@@ -3624,8 +3763,9 @@ class Engine:
                     break
                 continue
             # drain with only firsts left
-            self._harvester.wait_key(self._pending_first[0][1],
-                                     timeout_s=budget)
+            with _phase("llmk.wait"):
+                self._harvester.wait_key(self._pending_first[0][1],
+                                         timeout_s=budget)
         # pacing calibration: completion spacing per decode step bounds the
         # device step time from ABOVE (reads add latency, never remove it),
         # so track the MINIMUM with slow upward drift. A mean/EMA here is
@@ -3691,12 +3831,8 @@ class Engine:
             self._pending_first = still
             done_keys = {k for _, k, _ in done_entries}
             for k in done_keys - {k for _, k, _ in still}:
-                led = self._ledger_prefills.pop(k, None)
-                if led is not None and self.ledger is not None:
-                    t_launch, rows = led
-                    self.ledger.record(
-                        t_launch, self._harvester.done_time(k),
-                        [(req, "prefill", n) for req, n in rows])
+                if self.ledger is not None:
+                    self.ledger.close(-1 - k, self._harvester.done_time(k))
                 self._harvester.discard_key(k)
 
         processed = -1
@@ -3761,8 +3897,8 @@ class Engine:
                     # plain path would have produced anyway
                     spec_accepted += max(0, consumed - 1)
             if self.ledger is not None:
-                self.ledger.record(
-                    step.launched_at, self._harvester.done_time(step.seq),
+                self.ledger.close(
+                    step.dseq, self._harvester.done_time(step.seq),
                     led_rows, window=arr.shape[0])
             self.decode_dispatches += 1
             self.decode_tokens += consumed_total

@@ -8,13 +8,24 @@ This module answers that question with an explicit accounting identity:
     attributed (prefill + decode) + wasted (spec_waste + early_exit)
       + idle  ==  ledger window (first launch -> last completion)
 
-Every dispatch the engine launches is recorded here with its launch and
-completion timestamps. Because the device executes dispatches serially,
-the busy interval attributable to dispatch N is the segment from the
-previous dispatch's completion (or N's own launch, whichever is later)
-to N's completion — segments never overlap, gaps between them are idle,
-and the sum conserves wall time by construction (ci.sh gates this on
-the smoke run). Each segment is then split across the rows that rode
+Every dispatch the engine launches is ONE record here
+(:class:`Dispatch`): opened at the launch site, closed when its host read
+lands, and booked in LAUNCH order — the order the device runs them in,
+which is not the order the reads are collected in (a prefill's priority
+read overtakes the decode step launched ahead of it). The busy interval
+of dispatch N is the segment from the previous dispatch's completion (or
+N's own launch, whichever is later) to N's completion, and N cannot have
+completed after a dispatch launched later that has already been read —
+segments never overlap, gaps between them are idle, and the sum conserves
+wall time by construction (ci.sh gates this on the smoke run). A
+dispatch's completion is what the engine's harvester saw of it: the
+moment its result was ready on the device (a watcher thread waits on
+every result in launch order) or its read landed, whichever came first;
+a record that has neither takes the earliest read of anything launched
+after it and says ``end_clamped``. The same
+record is the dispatch span: what it waited behind, how long the device
+held it, what the host was doing in the gap before it
+(``GET /debug/engine`` lists the newest). Each segment is then split across the rows that rode
 the dispatch, weighted by planned window tokens: consumed tokens bill
 to the stream's ``prefill``/``decode`` phase, speculative rejected
 tails to ``spec_waste``, and masked/abandoned rows to ``early_exit`` —
@@ -23,9 +34,8 @@ but never counted as useful stream time.
 
 FLOPs/bytes ride the same records (2 * active-params per token for
 compute; weight + KV-page traffic for memory), giving the ``llm_mfu_
-ratio`` / ``llm_mbu_ratio`` gauges (Chowdhery et al., PaLM 2022). On
-the CPU platform the peak is a nominal figure — the ratios are
-plumbing-real but not hardware-meaningful there (see
+ratio`` / ``llm_mbu_ratio`` gauges (Chowdhery et al., PaLM 2022). A CPU
+has no peak in the table and reports neither (see
 k8s/tpu-models/README.md "Goodput & chip-time accounting").
 
 :class:`StepAnomalyDetector` watches the same per-dispatch durations
@@ -38,6 +48,8 @@ is still live.
 from __future__ import annotations
 
 import collections
+import dataclasses
+import itertools
 import math
 import os
 import threading
@@ -60,19 +72,21 @@ _PEAKS = {
     "TPU v4": (275e12, 1228e9, "cloud.google.com/tpu/docs/v4"),
     "TPU v3": (123e12, 900e9, "cloud.google.com/tpu/docs/v3"),
 }
-# CPU only: a deliberately small nominal peak so smoke MFU is a sane
-# nonzero ratio instead of ~0 against a TPU-sized peak.
-_CPU_NOMINAL = (5e11, 5e10)
+KINDS = ("prefill", "chunk", "decode", "spec")
+IDLE_HOSTS = ("no_work", "compile", "scheduling")
+# launched and not yet booked: a pipeline holds async_depth decode steps
+# and the prefills of one admission round, a handful
+MAX_OPEN = 64
 
 
-def detect_peak() -> tuple[float, float]:
+def detect_peak() -> Optional[tuple[float, float]]:
     """(peak FLOP/s, peak bytes/s) for the local accelerator.
 
     LLMK_PEAK_TFLOPS + LLMK_PEAK_GBPS win (hardware the table has never
     heard of); else the device kind must be in ``_PEAKS``. An accelerator
     that is not is an error naming the string it reports — a default peak
-    would put a wrong MFU/MBU on every dashboard without complaint. Only
-    the CPU platform gets the nominal figure."""
+    would put a wrong MFU/MBU on every dashboard without complaint. The
+    CPU platform has no peak: None, and the ledger reports no MFU/MBU."""
     flops = os.environ.get("LLMK_PEAK_TFLOPS")
     gbps = os.environ.get("LLMK_PEAK_GBPS")
     if flops and gbps:
@@ -81,7 +95,7 @@ def detect_peak() -> tuple[float, float]:
 
     dev = jax.devices()[0]
     if dev.platform == "cpu":
-        return _CPU_NOMINAL
+        return None
     if dev.device_kind not in _PEAKS:
         raise RuntimeError(
             f"no peak FLOP/s and HBM bytes/s known for accelerator "
@@ -160,25 +174,80 @@ class StepAnomalyDetector:
         return True
 
 
+@dataclasses.dataclass
+class Dispatch:
+    """One device dispatch, from its launch to its booked segment.
+
+    The engine thread opens it at the launch site, marks it launched when
+    the jitted call returns (the work is then in the device's queue) and
+    closes it when its host read lands; the ledger fills the rest when it
+    books the record, in launch order. A chunked prefill's chain of
+    dispatches, of which only the last is read, is one record."""
+    seq: int
+    kind: str                    # one of KINDS
+    name: str                    # the jitted step's name
+    shape: str                   # rows x bucket, or K x slots active
+    t_call: float                # host entered the jitted call
+    rows: Optional[list] = None  # [(request, phase, tokens)]; dropped when booked
+    window: int = 1
+    after_no_work: bool = False  # the engine had run out of work before it
+    t_launch: float = 0.0        # the call returned: enqueued on the device
+    enqueue_ms: float = 0.0      # host time inside the call; a re-trace shows here
+    retraced: bool = False       # the process compiled or hit its cache meanwhile
+    closed: bool = False
+    t_done: Optional[float] = None   # read landed; None: nobody reads it
+    # its end is the read of something launched AFTER it (its own read
+    # landed later, or never): device_ms is then an upper bound that holds
+    # the time of whatever ran up to that read, booked at 0 in its turn
+    end_clamped: bool = False
+    # booked:
+    seg_start: float = 0.0       # when the device was free for it
+    behind_ms: float = 0.0       # seg_start - t_launch: queued behind earlier ones
+    device_ms: float = 0.0       # t_done - seg_start
+    idle_before_ms: float = 0.0  # the device's gap before seg_start
+    idle_host: str = ""          # one of IDLE_HOSTS, when idle_before_ms > 0
+    tokens: int = 0
+    flops: float = 0.0
+    hbm_bytes: float = 0.0
+
+    def to_dict(self, t0: float) -> dict:
+        """JSON view; times in ms since ``t0`` (the ledger's first launch)."""
+        d = {"seq": self.seq, "kind": self.kind, "name": self.name,
+             "shape": self.shape, "tokens": self.tokens,
+             "launch_ms": round((self.t_launch - t0) * 1000.0, 3),
+             "enqueue_ms": round(self.enqueue_ms, 3),
+             "retraced": self.retraced,
+             "behind_ms": round(self.behind_ms, 3),
+             "device_ms": round(self.device_ms, 3),
+             "idle_before_ms": round(self.idle_before_ms, 3)}
+        if self.idle_host:
+            d["idle_host"] = self.idle_host
+        if self.end_clamped:
+            d["end_clamped"] = True
+        return d
+
+
 class GoodputLedger:
     """Chip-time attribution for one engine (see module docstring).
 
-    All mutation happens on the engine thread via :meth:`record`;
-    readers (the serving loop's metrics drain, bench, /metrics
-    callbacks) take the same lock through :meth:`snapshot` /
-    :meth:`utilization`, so a scrape never sees a half-applied record.
+    All mutation happens on the engine thread via :meth:`open` /
+    :meth:`launched` / :meth:`close`; readers (the serving loop's metrics
+    drain, bench, /metrics callbacks, /debug/engine) take the same lock
+    through :meth:`snapshot` / :meth:`utilization` / :meth:`dispatches`,
+    so a scrape never sees a half-applied record.
     """
 
     def __init__(self, model_config: Any,
                  detector: Optional[StepAnomalyDetector] = None,
                  peak_flops: Optional[float] = None,
                  peak_bytes_s: Optional[float] = None):
-        pf, pb = (peak_flops, peak_bytes_s)
-        if pf is None or pb is None:
-            dpf, dpb = detect_peak()
-            pf, pb = pf or dpf, pb or dpb
-        self.peak_flops = float(pf)
-        self.peak_bytes_s = float(pb)
+        if peak_flops is None or peak_bytes_s is None:
+            detected = detect_peak() or (None, None)
+            peak_flops = peak_flops or detected[0]
+            peak_bytes_s = peak_bytes_s or detected[1]
+        # None (a CPU): no MFU/MBU is reported
+        self.peak_flops = peak_flops
+        self.peak_bytes_s = peak_bytes_s
         params = _active_params(model_config)
         dtype_bytes = 2 if "16" in str(model_config.dtype) else 4
         # compute: the standard 2*N MAC count per token (PaLM appendix B;
@@ -193,40 +262,131 @@ class GoodputLedger:
 
         self.detector = detector
         self._lock = threading.Lock()
+        # launched and not yet booked, in launch order
+        self._open: "collections.deque[Dispatch]" = collections.deque()
+        # booked, newest last: the rolling window the MFU/MBU gauges are
+        # computed over, and what /debug/engine lists
+        self._records: "collections.deque[Dispatch]" = collections.deque(
+            maxlen=2048)
+        self._zero()
+
+    def _zero(self) -> None:
         self._last_complete: Optional[float] = None
         self.t_first: Optional[float] = None
         self.t_last: Optional[float] = None
         self.dispatches = 0
+        self.lost = 0       # dropped unbooked: their reads never came
         self.busy_ms = 0.0
         self.idle_ms = 0.0
         self.phase_ms: dict[str, float] = {p: 0.0 for p in PHASES}
         self.tenant_ms: dict[tuple[str, str], float] = {}
+        # per kind: [dispatches, device ms, behind ms, enqueue ms]
+        self.kind_stats: dict[str, list] = {k: [0, 0.0, 0.0, 0.0]
+                                            for k in KINDS}
+        self.idle_host_ms: dict[str, float] = {h: 0.0 for h in IDLE_HOSTS}
         self.flops = 0.0
         self.hbm_bytes = 0.0
         self.decode_tokens = 0
         self.prefill_tokens = 0
         self.anomaly_events = 0
         self._anomaly_pending = False
-        # (t_done, duration_s, flops, bytes) of recent dispatches — the
-        # rolling window the MFU/MBU gauges are computed over
-        self._recent: "collections.deque[tuple]" = collections.deque(
-            maxlen=2048)
+        self._open.clear()
+        self._records.clear()
 
     # -- recording (engine thread) -------------------------------------
 
-    def record(self, t_launch: float, t_done: float,
-               rows: list[tuple[Optional[Any], str, int]],
-               window: int = 1) -> float:
-        """Book one device dispatch.
+    def open(self, seq: int, kind: str, name: str, shape: str,
+             t_call: float, rows: Optional[list] = None,
+             after_no_work: bool = False) -> Dispatch:
+        """A dispatch is about to be launched. ``rows`` may come now (a
+        prefill knows them) or with :meth:`close` (a fused decode window
+        knows consumed against wasted only at harvest)."""
+        rec = Dispatch(seq, kind, name, shape, t_call, rows=rows,
+                       after_no_work=after_no_work)
+        with self._lock:
+            # a head whose read never came (it raised on the way) must not
+            # hold everything launched after it unbooked for ever: with
+            # this many behind it the device is long past it. It is
+            # dropped; its time falls to the next segment, or to idle
+            while (len(self._open) >= MAX_OPEN
+                   and not self._open[0].closed):
+                self._open.popleft()
+                self.lost += 1
+                self._book_ready()
+            self._open.append(rec)
+        return rec
+
+    def launched(self, rec: Dispatch, t_launch: float,
+                 retraced: bool = False) -> None:
+        """The jitted call returned: ``rec`` is in the device's queue."""
+        with self._lock:
+            rec.t_launch = t_launch
+            rec.enqueue_ms = max(0.0, t_launch - rec.t_call) * 1000.0
+            rec.retraced = retraced
+            for req, phase, _w in rec.rows or ():
+                if (phase == "prefill" and req is not None and getattr(
+                        req, "prefill_launched_at", None) is None):
+                    req.prefill_launched_at = t_launch
+
+    def close(self, seq: int, t_done: Optional[float],
+              rows: Optional[list] = None, window: int = 1) -> None:
+        """``seq``'s host read landed at ``t_done``; None for a dispatch
+        nobody reads (a resumed request's re-prefill), which the host knows
+        to be done only when the next one it does read is. Books every
+        record that is now the oldest open one and closed.
 
         ``rows`` is ``[(request_or_None, phase, weight_tokens), ...]``
         — one entry per (slot, phase) share of the dispatch; a fused
         window row typically contributes a ``decode`` entry for its
         consumed tokens and a waste entry for its planned-minus-consumed
         tail. ``window`` is the fused step count K (weight-streaming
-        traffic scales with it, not with batch width). Returns the busy
-        segment duration in seconds."""
-        rows = [(r, ph, int(w)) for r, ph, w in rows if w > 0]
+        traffic scales with it, not with batch width)."""
+        with self._lock:
+            rec = next((r for r in self._open if r.seq == seq), None)
+            if rec is None or rec.closed:
+                return
+            rec.closed = True
+            rec.t_done = t_done
+            rec.window = window
+            if rows is not None:
+                rec.rows = rows
+            if t_done is not None:
+                for req, phase, _w in rec.rows or ():
+                    if (phase == "prefill" and req is not None and getattr(
+                            req, "prefill_read_at", None) is None):
+                        req.prefill_read_at = t_done
+            self._book_ready()
+
+    def _book_ready(self) -> None:
+        """Book every record that is the oldest open one and closed."""
+        while self._open and self._open[0].closed:
+            head = self._open[0]
+            # an in-order device cannot finish the head after a later
+            # launch that has already been read (a priority read that
+            # overtook it; the one stamp of a batched read)
+            done = [r.t_done for r in itertools.islice(self._open, 1, None)
+                    if r.closed and r.t_done is not None]
+            if head.t_done is not None:
+                done.append(head.t_done)
+            if not done:
+                break      # unread, and nothing launched after it read yet
+            self._open.popleft()
+            head.end_clamped = head.t_done is None or min(done) < head.t_done
+            self._book(head, min(done))
+
+    def abandon(self, seq: Optional[int] = None) -> None:
+        """Forget open records whose reads will never come: one whose
+        launch raised (``seq``), or all of them (a wedged device)."""
+        with self._lock:
+            if seq is None:
+                self._open.clear()
+            else:
+                self._open = collections.deque(
+                    r for r in self._open if r.seq != seq)
+                self._book_ready()
+
+    def _book(self, rec: Dispatch, t_done: float) -> None:
+        rows = [(r, ph, int(w)) for r, ph, w in rec.rows or () if w > 0]
         tok_w = sum(w for _r, _ph, w in rows)
         total_w = tok_w
         if not rows:
@@ -235,65 +395,73 @@ class GoodputLedger:
             # the conservation identity keeps holding
             rows = [(None, "early_exit", 1)]
             total_w = 1
-        with self._lock:
-            if self._last_complete is None:
-                seg_start = t_launch
-                self.t_first = t_launch
-            else:
-                seg_start = max(t_launch, self._last_complete)
-                self.idle_ms += max(
-                    0.0, (seg_start - self._last_complete)) * 1000.0
-            dur = max(0.0, t_done - seg_start)
-            if self._last_complete is None or t_done > self._last_complete:
-                self._last_complete = t_done
-            self.t_last = self._last_complete
-            self.dispatches += 1
-            self.busy_ms += dur * 1000.0
+        if self._last_complete is None:
+            seg_start = rec.t_launch
+            self.t_first = rec.t_launch
+        else:
+            seg_start = max(rec.t_launch, self._last_complete)
+            idle = max(0.0, seg_start - self._last_complete) * 1000.0
+            if idle > 0.0:
+                rec.idle_before_ms = idle
+                rec.idle_host = ("no_work" if rec.after_no_work
+                                 else "compile" if rec.retraced
+                                 else "scheduling")
+                self.idle_ms += idle
+                self.idle_host_ms[rec.idle_host] += idle
+        dur = max(0.0, t_done - seg_start)
+        if self._last_complete is None or t_done > self._last_complete:
+            self._last_complete = t_done
+        self.t_last = self._last_complete
+        self.dispatches += 1
+        self.busy_ms += dur * 1000.0
+        rec.t_done = t_done
+        rec.seg_start = seg_start
+        rec.behind_ms = (seg_start - rec.t_launch) * 1000.0
+        rec.device_ms = dur * 1000.0
+        kind = self.kind_stats.setdefault(rec.kind, [0, 0.0, 0.0, 0.0])
+        kind[0] += 1
+        kind[1] += rec.device_ms
+        kind[2] += rec.behind_ms
+        kind[3] += rec.enqueue_ms
 
-            for req, phase, w in rows:
-                share_ms = (dur * 1000.0 * w / total_w) if total_w else 0.0
-                self.phase_ms[phase] += share_ms
-                tenant = getattr(req, "tenant", "") or ""
-                key = (tenant, phase)
-                self.tenant_ms[key] = self.tenant_ms.get(key, 0.0) + share_ms
-                if req is not None:
-                    req.chip_ms[phase] = req.chip_ms.get(phase, 0.0) + share_ms
-                if phase == "decode":
-                    self.decode_tokens += w
-                elif phase == "prefill":
-                    self.prefill_tokens += w
+        for req, phase, w in rows:
+            share_ms = (dur * 1000.0 * w / total_w) if total_w else 0.0
+            self.phase_ms[phase] += share_ms
+            tenant = getattr(req, "tenant", "") or ""
+            key = (tenant, phase)
+            self.tenant_ms[key] = self.tenant_ms.get(key, 0.0) + share_ms
+            if req is not None:
+                req.chip_ms[phase] = req.chip_ms.get(phase, 0.0) + share_ms
+            if phase == "decode":
+                self.decode_tokens += w
+            elif phase == "prefill":
+                self.prefill_tokens += w
+                if (req is not None and getattr(
+                        req, "prefill_started_at", None) is None):
+                    req.prefill_started_at = seg_start
 
-            # planned rows are computed whether or not the stream keeps
-            # them — wasted FLOPs are the whole point of measuring
-            flops = self.flops_per_token * tok_w
-            hbm = (self.param_bytes * max(1, int(window))
-                   + self.kv_bytes_per_token * tok_w)
-            self.flops += flops
-            self.hbm_bytes += hbm
-            self._recent.append((t_done, dur, flops, hbm))
+        # planned rows are computed whether or not the stream keeps
+        # them — wasted FLOPs are the whole point of measuring
+        rec.tokens = tok_w
+        rec.flops = self.flops_per_token * tok_w
+        rec.hbm_bytes = (self.param_bytes * max(1, int(rec.window))
+                         + self.kv_bytes_per_token * tok_w)
+        self.flops += rec.flops
+        self.hbm_bytes += rec.hbm_bytes
+        rec.rows = None     # the ring must not keep requests alive
+        self._records.append(rec)
 
-            if self.detector is not None and dur > 0.0:
-                if self.detector.observe(dur, t_done):
-                    self.anomaly_events += 1
-                    self._anomaly_pending = True
-        return dur
+        if self.detector is not None and dur > 0.0:
+            if self.detector.observe(dur, t_done):
+                self.anomaly_events += 1
+                self._anomaly_pending = True
 
     def reset(self) -> None:
         """Zero all accounting (bench measurement windows exclude warmup
         dispatches this way). The detector's learned baseline survives —
         forgetting it would re-open the warmup window."""
         with self._lock:
-            self._last_complete = None
-            self.t_first = self.t_last = None
-            self.dispatches = 0
-            self.busy_ms = self.idle_ms = 0.0
-            self.phase_ms = {p: 0.0 for p in PHASES}
-            self.tenant_ms = {}
-            self.flops = self.hbm_bytes = 0.0
-            self.decode_tokens = self.prefill_tokens = 0
-            self.anomaly_events = 0
-            self._anomaly_pending = False
-            self._recent.clear()
+            self._zero()
 
     def take_anomaly(self) -> bool:
         """True once per detector trigger (serving-loop poll)."""
@@ -304,20 +472,32 @@ class GoodputLedger:
     # -- reading (any thread) ------------------------------------------
 
     def utilization(self, window_s: float = 60.0,
-                    now: Optional[float] = None) -> tuple[float, float]:
-        """(MFU, MBU) over the trailing ``window_s`` of dispatches."""
+                    now: Optional[float] = None
+                    ) -> Optional[tuple[float, float]]:
+        """(MFU, MBU) over the trailing ``window_s`` of dispatches; None
+        where the device has no peak (a CPU)."""
+        if self.peak_flops is None or self.peak_bytes_s is None:
+            return None
         with self._lock:
-            if not self._recent:
+            if not self._records:
                 return 0.0, 0.0
-            t_hi = now if now is not None else self._recent[-1][0]
+            t_hi = now if now is not None else self._records[-1].t_done
             lo = t_hi - window_s
-            ent = [e for e in self._recent if e[0] >= lo]
+            ent = [r for r in self._records if r.t_done >= lo]
             if not ent:
                 return 0.0, 0.0
-            elapsed = max(t_hi - min(e[0] - e[1] for e in ent), 1e-9)
-            mfu = sum(e[2] for e in ent) / (self.peak_flops * elapsed)
-            mbu = sum(e[3] for e in ent) / (self.peak_bytes_s * elapsed)
+            elapsed = max(t_hi - min(r.seg_start for r in ent), 1e-9)
+            mfu = sum(r.flops for r in ent) / (self.peak_flops * elapsed)
+            mbu = sum(r.hbm_bytes for r in ent) / (
+                self.peak_bytes_s * elapsed)
             return min(mfu, 1.0), min(mbu, 1.0)
+
+    def dispatches_view(self, limit: int = 64) -> list[dict]:
+        """The newest ``limit`` booked records, oldest first."""
+        with self._lock:
+            recs = list(self._records)[-limit:] if limit > 0 else []
+            t0 = self.t_first or 0.0
+            return [r.to_dict(t0) for r in recs]
 
     def snapshot(self) -> dict:
         """Cumulative totals (ms / counts), for delta-draining into
@@ -332,9 +512,14 @@ class GoodputLedger:
                 "attributed_ms": attributed,
                 "wasted_ms": wasted,
                 "idle_ms": self.idle_ms,
+                "idle_host_ms": dict(self.idle_host_ms),
                 "busy_ms": self.busy_ms,
                 "window_ms": window_ms,
                 "dispatches": self.dispatches,
+                "lost": self.lost,
+                "kinds": {k: {"dispatches": v[0], "device_ms": v[1],
+                              "behind_ms": v[2], "enqueue_ms": v[3]}
+                          for k, v in self.kind_stats.items()},
                 "flops": self.flops,
                 "hbm_bytes": self.hbm_bytes,
                 "decode_tokens": self.decode_tokens,

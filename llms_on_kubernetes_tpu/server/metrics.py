@@ -335,7 +335,13 @@ def engine_metrics(registry: Registry) -> dict:
         "batch_occupancy": Gauge(
             "llm_decode_batch_occupancy", "Active decode slots", registry),
         "kv_pages_used": Gauge(
-            "llm_kv_pages_used", "KV pages allocated", registry),
+            "llm_kv_pages_used", "KV pages allocated (finished requests' "
+            "cached prefixes included: it stays near the pool's size)",
+            registry),
+        "kv_pages_live": Gauge(
+            "llm_kv_pages_live", "KV pages held by live sequences: "
+            "allocated and neither free nor an evictable cached prefix",
+            registry),
         "waiting": Gauge(
             "llm_waiting_requests", "Requests queued for admission", registry),
         # same value as llm_waiting_requests but model-labeled: the
@@ -467,9 +473,8 @@ def engine_metrics(registry: Registry) -> dict:
             "Model FLOPs utilization over the trailing minute: achieved "
             "FLOP/s (2 * active params per planned token, wasted rows "
             "included) over the accelerator's nominal dense peak "
-            "(PaLM-style MFU; on CPU smoke runs the peak is a nominal "
-            "fallback, so treat the ratio as plumbing, not hardware "
-            "truth)", registry),
+            "(PaLM-style MFU; never set on a CPU, which has no peak)",
+            registry),
         "mbu": Gauge(
             "llm_mbu_ratio",
             "Memory-bandwidth utilization over the trailing minute: "
@@ -485,6 +490,35 @@ def engine_metrics(registry: Registry) -> dict:
             "phases sum to the ledger's wall-clock window "
             "(conservation is CI-gated)",
             registry, label_names=("phase",)),
+        "dispatches": Counter(
+            "llm_dispatches_total",
+            "Device dispatches booked by the ledger, by kind of step "
+            "(prefill, chunk = a chunked prefill's chain, decode = one "
+            "fused window, spec = a speculative verify)",
+            registry, label_names=("kind",)),
+        "dispatch_device_seconds": Counter(
+            "llm_dispatch_device_seconds_total",
+            "Seconds the device held dispatches of each kind: from the "
+            "device being free for one (the previous one's completion, "
+            "or its own launch) to its read landing, in launch order",
+            registry, label_names=("kind",)),
+        "dispatch_behind_seconds": Counter(
+            "llm_dispatch_behind_seconds_total",
+            "Seconds dispatches of each kind sat enqueued behind earlier "
+            "ones before the device was free for them",
+            registry, label_names=("kind",)),
+        "dispatch_enqueue_seconds": Counter(
+            "llm_dispatch_enqueue_seconds_total",
+            "Host seconds inside the jitted calls that launched "
+            "dispatches of each kind (a re-trace shows here)",
+            registry, label_names=("kind",)),
+        "device_idle_seconds": Counter(
+            "llm_device_idle_seconds_total",
+            "Seconds the device sat between dispatches, by what the host "
+            "was doing: no_work (nothing active or waiting), compile (the "
+            "next dispatch re-traced), scheduling (anything else); sums "
+            "to llm_chip_seconds_total{phase=\"idle\"}",
+            registry, label_names=("host",)),
         "tenant_chip_seconds": Counter(
             "llm_tenant_chip_seconds_total",
             "Chip time attributed per fair-queue tenant and ledger "
@@ -506,6 +540,15 @@ def engine_metrics(registry: Registry) -> dict:
     # dashboard's rate() panel and the router's /metrics/cluster merge
     # would not see the series until the first trigger
     m["auto_profile"].labels(reason="step_anomaly")
+    # likewise the dispatch counters, for every kind and host there is
+    from llms_on_kubernetes_tpu.engine.ledger import IDLE_HOSTS, KINDS
+
+    for kind in KINDS:
+        for series in ("dispatches", "dispatch_device_seconds",
+                       "dispatch_behind_seconds", "dispatch_enqueue_seconds"):
+            m[series].labels(kind=kind)
+    for host in IDLE_HOSTS:
+        m["device_idle_seconds"].labels(host=host)
     return m
 
 

@@ -195,14 +195,12 @@ class EngineLoop(threading.Thread):
     def __init__(self, engine: Engine, metrics: Optional[dict] = None,
                  model_name: str = "",
                  flight: Optional[tracing.FlightRecorder] = None,
-                 telemetry: Optional[RuntimeTelemetry] = None,
                  profiles=None):
         super().__init__(daemon=True, name="engine-loop")
         self.engine = engine
         self.metrics = metrics
         self.model_name = model_name
         self.flight = flight
-        self.telemetry = telemetry
         self.profiles = profiles  # ProfileManager for watchdog captures
         self._wake = threading.Event()
         self._stop_evt = threading.Event()
@@ -219,6 +217,9 @@ class EngineLoop(threading.Thread):
         # (delta-style, matching the other counters above)
         self._led_phase_seen: dict[str, float] = {}
         self._led_tenant_seen: dict[tuple, float] = {}
+        self._led_dispatches_seen = 0
+        self._led_kind_seen: dict[tuple, float] = {}
+        self._led_idle_seen: dict[str, float] = {}
         self._led_frame_seen = (0.0, 0.0)
         self.auto_profiles = 0
 
@@ -271,16 +272,15 @@ class EngineLoop(threading.Thread):
             t0 = time.monotonic()
             events = eng.step()
             dt = time.monotonic() - t0
-            # kernel-vs-host attribution: how much of this step's wall
-            # time was spent blocked on the device (dispatch + harvest
-            # reads) vs host-side scheduling. Clamped to [0, dt] — the
-            # harvester runs concurrently, so its delta can exceed this
-            # step's own wall time.
+            # for the flight recorder: how much of this step's wall time
+            # the engine was blocked on reads (not device time: with
+            # dispatches in flight the device works while the host does;
+            # llm_dispatch_device_seconds_total is the device's). Clamped
+            # to [0, dt] — the harvester runs concurrently, so its delta
+            # can exceed this step's own wall time.
             device_s = 0.0
             if hasattr(eng, "device_wait_s"):
                 device_s = max(0.0, min(eng.device_wait_s() - dw0, dt))
-            if self.telemetry is not None:
-                self.telemetry.record_step_split(dt, device_s)
             occupancy = sum(r is not None for r in eng.slots)
             pages_used = eng.config.num_pages - 1 - eng.allocator.num_free_pages
             step_tokens = sum(len(ev.new_tokens) for ev in events)
@@ -373,10 +373,13 @@ class EngineLoop(threading.Thread):
                                 tenant=key[0], phase=key[1]).inc(
                                     (ms - seen) / 1000.0)
                             self._led_tenant_seen[key] = ms
-                    m["mfu"].set(led_util[0])
-                    m["mbu"].set(led_util[1])
+                    self._drain_dispatch_totals(led_snap)
+                    if led_util is not None:
+                        m["mfu"].set(led_util[0])
+                        m["mbu"].set(led_util[1])
                 m["batch_occupancy"].set(occupancy)
                 m["kv_pages_used"].set(pages_used)
+                m["kv_pages_live"].set(eng.allocator.num_live_pages)
                 m["waiting"].set(len(eng.waiting))
                 m["queue_depth"].labels(
                     model=self.model_name,
@@ -432,11 +435,40 @@ class EngineLoop(threading.Thread):
                     frame.update(
                         chip_attr_ms=round(attr - pa, 3),
                         chip_waste_ms=round(waste - pw, 3),
-                        mfu=round(led_util[0], 5),
                     )
+                    if led_util is not None:
+                        frame["mfu"] = round(led_util[0], 5)
                 self.flight.record(**frame)
             if led is not None and led.take_anomaly():
                 self._trigger_auto_profile()
+
+    # ledger snapshot field of a kind -> (counter, units per second)
+    _DISPATCH_SERIES = (("dispatches", "dispatches", 1.0),
+                        ("device_ms", "dispatch_device_seconds", 1000.0),
+                        ("behind_ms", "dispatch_behind_seconds", 1000.0),
+                        ("enqueue_ms", "dispatch_enqueue_seconds", 1000.0))
+
+    def _drain_dispatch_totals(self, led_snap: dict) -> None:
+        """The dispatch records' own totals into their counters: by kind
+        of step, and by what the host was doing in each device gap.
+        They move only when a dispatch is booked."""
+        if led_snap["dispatches"] == self._led_dispatches_seen:
+            return
+        self._led_dispatches_seen = led_snap["dispatches"]
+        m = self.metrics
+        for kind, tot in led_snap["kinds"].items():
+            for field, series, per_s in self._DISPATCH_SERIES:
+                seen = self._led_kind_seen.get((kind, field), 0.0)
+                if tot[field] > seen:
+                    m[series].labels(kind=kind).inc(
+                        (tot[field] - seen) / per_s)
+                    self._led_kind_seen[(kind, field)] = tot[field]
+        for host, ms in led_snap["idle_host_ms"].items():
+            seen = self._led_idle_seen.get(host, 0.0)
+            if ms > seen:
+                m["device_idle_seconds"].labels(host=host).inc(
+                    (ms - seen) / 1000.0)
+                self._led_idle_seen[host] = ms
 
     def _trigger_auto_profile(self) -> None:
         """One bounded, rate-limited profiler capture while the step-time
@@ -629,7 +661,6 @@ class OpenAIServer:
         self.loop_thread = EngineLoop(engine, self.metrics,
                                       model_name=model_name,
                                       flight=self.flight,
-                                      telemetry=self.telemetry,
                                       profiles=self.profiles)
         self.engine = engine
         # readiness lifecycle: loading -> serving -> draining; "wedged" is
@@ -1048,10 +1079,16 @@ class OpenAIServer:
     async def debug_engine(self, request: web.Request) -> web.Response:
         """Engine flight recorder: the last N decode steps (step time,
         occupancy, KV pages, shed/preempted counts, token throughput) so a
-        wedged or slow engine can be diagnosed post-hoc. ``?limit=N``
-        trims to the most recent N steps."""
-        snap = self.flight.snapshot(
-            limit=self._int_query(request, "limit", 0) or None)
+        wedged or slow engine can be diagnosed post-hoc, and beside them
+        the ledger's newest N dispatch records (what each device dispatch
+        was, what it queued behind, how long the device held it, what the
+        host was doing in the gap before it). ``?limit=N`` trims both to
+        the most recent N."""
+        limit = self._int_query(request, "limit", 0) or None
+        snap = self.flight.snapshot(limit=limit)
+        led = getattr(self.engine, "ledger", None)
+        snap["dispatches"] = (led.dispatches_view(limit or 64)
+                              if led is not None else [])
         snap["state"] = self.state
         snap["model"] = self.model_name
         snap["role"] = self.engine.config.role or "both"
@@ -1749,8 +1786,9 @@ class OpenAIServer:
     def _finalize_trace(self, trace, status: str, resp) -> None:
         """Derive the request's span timeline from the engine Request
         timestamps (single writer each: submit/admit/first-token/finish)
-        and publish it. Spans are disjoint by construction, so their
-        durations sum to at most the end-to-end latency."""
+        and publish it. The phases are disjoint by construction, so their
+        durations sum to at most the end-to-end latency; the ``prefill``
+        phase has four children (:meth:`_split_first_token`)."""
         now = time.monotonic()
         many = len(trace.engine_reqs) > 1
 
@@ -1779,8 +1817,11 @@ class OpenAIServer:
                     # actually consumed, vs the wall-clock span bounds
                     pre_kw["chip_ms"] = round(
                         req.chip_ms.get("prefill", 0.0), 3)
-                eng_span("prefill", adm,
-                         ft if ft is not None else fin, **pre_kw)
+                pre_id = tracing.new_span_id()
+                trace.add_span("prefill", adm, ft if ft is not None else fin,
+                               span_id=pre_id, parent_span_id=trace.span_id,
+                               **pre_kw)
+                self._split_first_token(trace, req, pre_id, meta)
             if ft is not None:
                 dec_kw = dict(meta, tokens=len(req.output))
                 if req.chip_ms:
@@ -1805,6 +1846,34 @@ class OpenAIServer:
             tokens=sum(len(r.output) for r in trace.engine_reqs))
         tracing.maybe_log_slow(trace, "api")
         self._export_trace(trace)
+
+    @staticmethod
+    def _split_first_token(trace, req, parent_id: str, meta: dict) -> None:
+        """Four disjoint children of the ``prefill`` span that sum to it
+        exactly, from the timestamps the request took off its prefill's
+        dispatch record: ``prefill.pack`` (admission to launch: host KV
+        commit, packing, enqueue), ``prefill.behind`` (launch to the
+        device being free for it: the dispatches ahead), ``prefill.device``
+        (to its read landing: the prefill itself — every dispatch of a
+        chunked one — and its priority read) and ``prefill.emit`` (to the
+        first token: the engine thread getting round to it). Nothing
+        without a ledger, or before the first token."""
+        adm, ft = req.admitted_at, req.first_token_at
+        launched, read = req.prefill_launched_at, req.prefill_read_at
+        if ft is None or launched is None or read is None:
+            return
+        launched = min(max(launched, adm), ft)
+        # not booked yet (a dispatch launched ahead of it is still unread):
+        # it then waited behind nothing the ledger has seen
+        started = req.prefill_started_at or launched
+        read = min(max(read, launched), ft)
+        started = min(max(started, launched), read)
+        for name, start, end in (("prefill.pack", adm, launched),
+                                 ("prefill.behind", launched, started),
+                                 ("prefill.device", started, read),
+                                 ("prefill.emit", read, ft)):
+            trace.add_span(name, start, end, span_id=tracing.new_span_id(),
+                           parent_span_id=parent_id, **meta)
 
     def _export_trace(self, trace) -> None:
         """Tail-sampling + OTLP enqueue for a finished fragment; never

@@ -162,7 +162,17 @@ class ProfileManager:
         Returns the source tag recorded in capture.json."""
         try:
             import jax.profiler as jprof
-            jprof.start_trace(out_dir)
+
+            # the device's lines and the host's TraceMe events (the
+            # engine's llmk.* phases among them), and not the python
+            # tracer: nothing reads its frames, and its stop alone took
+            # ~50 s of a capture on the CPU. (On the chip the stop is the
+            # device's own events — 400k ops a 1.5 s capture of a 7B model
+            # — and takes a minute with or without it, or the HLO protos.)
+            opts = jprof.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            jprof.start_trace(out_dir, profiler_options=opts)
         except Exception:
             sampler = _SamplingProfiler()
             sampler.start()

@@ -13,7 +13,7 @@ needs HBM headroom and compile-stall visibility, not just TTFT):
 - ``llm_device_live_buffer_bytes{device}``: bytes of live jax arrays per
   device, from ``jax.live_arrays()`` — backend-independent, and the only
   device-memory signal the CPU backend has.
-- jit compile counters via ``jax.monitoring`` listeners:
+- jit compile counters (``jax.monitoring`` listeners, engine/jit_events.py):
   ``llm_jit_compiles_total`` / ``llm_jit_compile_seconds_total`` count
   backend (XLA) compiles — each one is a jit-cache *miss* that stalled a
   request behind compilation; ``llm_jit_cache_hits_total`` counts
@@ -25,8 +25,7 @@ never take down ``/metrics``.
 
 from __future__ import annotations
 
-import threading
-
+from llms_on_kubernetes_tpu.engine import jit_events
 from llms_on_kubernetes_tpu.server.metrics import Counter, Gauge, Registry
 
 # memory_stats() keys worth exporting when present (allocator-dependent;
@@ -67,14 +66,6 @@ def runtime_metrics(registry: Registry) -> dict:
             "llm_jit_cache_hits_total",
             "Persistent compilation-cache hits (0 unless the cache is "
             "enabled)", registry),
-        "step_device_seconds": Counter(
-            "llm_step_device_seconds_total",
-            "Engine-step seconds spent blocked on device work "
-            "(dispatch waits + device->host reads)", registry),
-        "step_host_seconds": Counter(
-            "llm_step_host_seconds_total",
-            "Engine-step seconds spent in host-side scheduling "
-            "(step wall time minus device wait)", registry),
     }
 
 
@@ -83,81 +74,36 @@ class RuntimeTelemetry:
 
     ``refresh()`` is called from the ``/metrics`` handler (scrape-time
     freshness) and is cheap: ``memory_stats()`` is a dict read,
-    ``live_arrays()`` walks the live-buffer list. Compile counters are
-    pushed by ``jax.monitoring`` listeners registered once per process
-    (the listener API has no deregistration, so a process-global guard
-    keeps re-instantiation — tests build many servers — from stacking
-    duplicate listeners). ``serve`` installs them before its warmup
-    compiles, when no registry exists yet: what they see until the first
-    instance is built is held in ``_early`` and folded into that
-    instance's counters, so a warm restart's persistent-cache hits are on
-    its first scrape.
+    ``live_arrays()`` walks the live-buffer list. The compile counters are
+    the process's own (:mod:`engine.jit_events`: ``jax.monitoring``
+    listeners put on once per process, by ``serve`` before its warmup
+    compiles), copied here at every scrape, so a warm restart's
+    persistent-cache hits are on its first scrape and the engine, which
+    reads the same totals around each dispatch, knows nothing of this
+    module.
     """
-
-    _listener_lock = threading.Lock()
-    _listener_host: "RuntimeTelemetry | None" = None
-    _installed = False
-    # [compiles, compile seconds, cache hits] seen before any host existed
-    _early = [0, 0.0, 0]
 
     def __init__(self, registry: Registry):
         self.metrics = runtime_metrics(registry)
-        cls = RuntimeTelemetry
-        cls.install_listeners()
-        with cls._listener_lock:
-            # newest instance wins: the latest server's registry is the
-            # one being scraped; earlier ones are dead test fixtures
-            cls._listener_host = self
-            compiles, seconds, hits = cls._early
-            cls._early = [0, 0.0, 0]
-        self.metrics["jit_compiles"].inc(compiles)
-        self.metrics["jit_compile_seconds"].inc(seconds)
-        self.metrics["jit_cache_hits"].inc(hits)
+        jit_events.install()
+        self._copy_jit_totals()
 
-    # -- compile counters (push) ---------------------------------------
+    # -- compile counters (the process's, copied at scrape) -------------
 
-    @classmethod
-    def install_listeners(cls) -> None:
-        from jax import monitoring
-
-        with cls._listener_lock:
-            if cls._installed:
-                return
-            cls._installed = True
-            monitoring.register_event_listener(cls._dispatch_event)
-            monitoring.register_event_duration_secs_listener(
-                cls._dispatch_duration)
-
-    @staticmethod
-    def _dispatch_event(event: str, **kw) -> None:
-        if "cache_hit" not in event:
-            return
-        cls = RuntimeTelemetry
-        with cls._listener_lock:
-            host = cls._listener_host
-            if host is None:
-                cls._early[2] += 1
-                return
-        host.metrics["jit_cache_hits"].inc()
-
-    @staticmethod
-    def _dispatch_duration(event: str, duration: float, **kw) -> None:
-        if "backend_compile" not in event:
-            return
-        cls = RuntimeTelemetry
-        with cls._listener_lock:
-            host = cls._listener_host
-            if host is None:
-                cls._early[0] += 1
-                cls._early[1] += max(0.0, duration)
-                return
-        host.metrics["jit_compiles"].inc()
-        host.metrics["jit_compile_seconds"].inc(max(0.0, duration))
+    def _copy_jit_totals(self) -> None:
+        compiles, seconds, hits = jit_events.totals()
+        for key, total in (("jit_compiles", compiles),
+                           ("jit_compile_seconds", seconds),
+                           ("jit_cache_hits", hits)):
+            counter = self.metrics[key]
+            counter.inc(max(0.0, total - counter.value))
 
     # -- device memory (pull, at scrape) -------------------------------
 
     def refresh(self) -> None:
-        """Re-sample device memory + live buffers. Never raises."""
+        """Re-sample device memory + live buffers and copy the compile
+        counters. Never raises."""
+        self._copy_jit_totals()
         try:
             self._refresh_device_memory()
         except Exception:
@@ -201,16 +147,3 @@ class RuntimeTelemetry:
                 # stats, so live-buffer bytes stand in for bytes_in_use
                 mem.labels(device=name,
                            kind="live_buffer_bytes").set(live.get(name, 0.0))
-
-    # -- per-step attribution (pushed by the engine loop) ---------------
-
-    def record_step_split(self, step_s: float, device_s: float) -> None:
-        """Fold one engine step's kernel-vs-host split into the counters.
-
-        ``device_s`` is the engine's cumulative-device-wait delta for the
-        step (time blocked on dispatch/harvest reads); the remainder of
-        the step wall time is host scheduling work.
-        """
-        device_s = max(0.0, min(device_s, step_s))
-        self.metrics["step_device_seconds"].inc(device_s)
-        self.metrics["step_host_seconds"].inc(max(0.0, step_s - device_s))

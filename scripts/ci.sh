@@ -345,12 +345,11 @@ if None in (attr, wasted, idle, wall) or wall <= 0:
     sys.exit(1)
 total = attr + wasted + idle
 sys.exit(0 if abs(total - wall) / wall <= 0.05
-         and doc.get("goodput_tokens_per_chip_s") is not None
-         and (doc.get("mfu") or 0) > 0 else 1)'; then
+         and doc.get("goodput_tokens_per_chip_s") is not None else 1)'; then
     echo "ci: goodput ledger smoke OK (conservation within 5%)"
   else
     echo "ci: goodput ledger smoke FAILED (attributed + wasted + idle"
-    echo "    drifts > 5% from the engine-loop busy wall, or no MFU)"
+    echo "    drifts > 5% from the engine-loop busy wall)"
     fails=$((fails + 1))
   fi
 

@@ -21,6 +21,8 @@ accounting, and the anomaly-triggered auto-profiler.
 """
 
 import asyncio
+import itertools
+import threading
 import time
 
 import numpy as np
@@ -29,10 +31,11 @@ from aiohttp.test_utils import TestClient, TestServer
 
 from llms_on_kubernetes_tpu.configs import get_config
 from llms_on_kubernetes_tpu.engine.engine import (
-    Engine, EngineConfig, SamplingParams,
+    Engine, EngineConfig, SamplingParams, _Harvester,
 )
 from llms_on_kubernetes_tpu.engine.ledger import (
-    PHASES, GoodputLedger, StepAnomalyDetector, detect_peak,
+    IDLE_HOSTS, MAX_OPEN, PHASES, GoodputLedger, StepAnomalyDetector,
+    detect_peak,
 )
 from test_adapters import write_peft
 
@@ -55,6 +58,30 @@ def _ledger(**kw):
     return GoodputLedger(get_config("debug-tiny"), **kw)
 
 
+_seqs = itertools.count()
+
+
+def _open(led, t_launch, kind="decode", rows=None, enqueue_s=0.0,
+          retraced=False, after_no_work=False):
+    """A dispatch launched at ``t_launch`` and not yet read; its seq."""
+    seq = next(_seqs)
+    rec = led.open(seq, kind, f"_{kind}_step", "1x1", t_launch - enqueue_s,
+                   rows=rows, after_no_work=after_no_work)
+    led.launched(rec, t_launch, retraced=retraced)
+    return seq
+
+
+def _record(led, t_launch, t_done, rows, window=1, kind="decode"):
+    """One dispatch, read as soon as it is launched (launch order and
+    read order agree)."""
+    led.close(_open(led, t_launch, kind), t_done, rows, window)
+
+
+def _booked(led):
+    """seq -> the booked record's /debug/engine view."""
+    return {d["seq"]: d for d in led.dispatches_view(2048)}
+
+
 def _conserves(snap):
     total = snap["attributed_ms"] + snap["wasted_ms"] + snap["idle_ms"]
     assert total == pytest.approx(snap["window_ms"], rel=1e-9, abs=1e-6)
@@ -69,12 +96,12 @@ def test_fused_window_attribution_and_conservation():
     led = _ledger()
     a, b = _Req("t-a"), _Req("t-b")
     # dispatch 1: launch 0.0, done 0.1 -> 100 ms busy
-    led.record(0.0, 0.1, [(a, "decode", 4), (b, "decode", 4)], window=4)
+    _record(led, 0.0, 0.1, [(a, "decode", 4), (b, "decode", 4)], window=4)
     # dispatch 2 launched while 1 was in flight: its busy segment is
     # 0.1 -> 0.2 (the device runs dispatches serially), never 0.05 -> 0.2
-    led.record(0.05, 0.2, [(a, "decode", 4), (b, "decode", 4)], window=4)
+    _record(led, 0.05, 0.2, [(a, "decode", 4), (b, "decode", 4)], window=4)
     # 100 ms gap, then a window where `a` early-exits after 2 of 4 rows
-    led.record(0.3, 0.35,
+    _record(led, 0.3, 0.35,
                [(a, "decode", 2), (a, "early_exit", 2), (b, "decode", 4)],
                window=4)
 
@@ -101,7 +128,7 @@ def test_spec_rejected_tail_books_waste_but_keeps_flops():
     MFU numerator keeps them."""
     led = _ledger()
     r = _Req("spec-tenant")
-    led.record(0.0, 0.08, [(r, "decode", 2), (r, "spec_waste", 2)], window=4)
+    _record(led, 0.0, 0.08, [(r, "decode", 2), (r, "spec_waste", 2)], window=4)
     snap = led.snapshot()
     _conserves(snap)
     assert snap["phase_ms"]["decode"] == pytest.approx(40.0)
@@ -120,7 +147,7 @@ def test_zero_row_dispatch_still_conserves():
     """Every slot finished mid-flight: the dispatch still burned chip
     time, which must book as waste — not leak out of the identity."""
     led = _ledger()
-    led.record(0.0, 0.05, [])
+    _record(led, 0.0, 0.05, [])
     snap = led.snapshot()
     _conserves(snap)
     assert snap["phase_ms"]["early_exit"] == pytest.approx(50.0)
@@ -141,7 +168,7 @@ def test_attribution_fuzz_conservation():
         rows = [(reqs[rng.integers(5)], PHASES[rng.integers(4)],
                  int(rng.integers(0, 5)))
                 for _ in range(int(rng.integers(1, 4)))]
-        led.record(t_launch, t, rows, window=int(rng.integers(1, 5)))
+        _record(led, t_launch, t, rows, window=int(rng.integers(1, 5)))
     snap = led.snapshot()
     _conserves(snap)
     # per-request sums == per-tenant sums == phase totals
@@ -150,14 +177,357 @@ def test_attribution_fuzz_conservation():
     assert req_total == pytest.approx(ten_total, rel=1e-9)
 
 
+def test_prefill_read_before_the_decode_launched_ahead_of_it():
+    """The device runs dispatches in LAUNCH order; reads are collected in
+    another: a first token's priority read is booked before the decode
+    step launched ahead of it. Each keeps its own segment: the decode
+    step its 60 ms, the prefill the 40 ms after it (booking in the order
+    of the calls gave the prefill 100 ms and the decode step about 0)."""
+    led = _ledger()
+    a, b = _Req("a"), _Req("b")
+    _record(led, 0.0, 0.10, [(a, "decode", 4)], window=4)
+    dec = _open(led, 0.05)                                  # queued behind #1
+    pre = _open(led, 0.06, "prefill", rows=[(b, "prefill", 16)])
+    led.close(pre, 0.20)                                    # read first...
+    assert led.snapshot()["dispatches"] == 1                # ...booked later
+    led.close(dec, 0.21, [(a, "decode", 4)], window=4)     # a late, batched stamp
+    booked = _booked(led)
+    # the decode step cannot have ended after the prefill behind it was read
+    assert booked[dec]["device_ms"] == pytest.approx(100.0)
+    assert booked[dec]["behind_ms"] == pytest.approx(50.0)
+    assert booked[pre]["device_ms"] == pytest.approx(0.0, abs=1e-6)
+    snap = led.snapshot()
+    _conserves(snap)
+    assert snap["window_ms"] == pytest.approx(200.0)
+    # with the decode step's own read known to be earlier, both get theirs
+    led2 = _ledger()
+    b = _Req("b")
+    _record(led2, 0.0, 0.10, [(a, "decode", 4)], window=4)
+    dec = _open(led2, 0.05)
+    pre = _open(led2, 0.06, "prefill", rows=[(b, "prefill", 16)])
+    led2.close(pre, 0.20)
+    led2.close(dec, 0.16, [(a, "decode", 4)], window=4)
+    booked = _booked(led2)
+    assert booked[dec]["device_ms"] == pytest.approx(60.0)
+    assert booked[pre]["device_ms"] == pytest.approx(40.0)
+    assert booked[pre]["behind_ms"] == pytest.approx(100.0)  # 0.06 -> 0.16
+    assert b.chip_ms["prefill"] == pytest.approx(40.0)
+    # the request's timestamps come off its prefill's record
+    assert b.prefill_launched_at == pytest.approx(0.06)
+    assert b.prefill_started_at == pytest.approx(0.16)
+    assert b.prefill_read_at == pytest.approx(0.20)
+    snap = led2.snapshot()
+    _conserves(snap)
+    assert snap["kinds"]["prefill"] == {
+        "dispatches": 1, "device_ms": pytest.approx(40.0),
+        "behind_ms": pytest.approx(100.0), "enqueue_ms": 0.0}
+    assert snap["kinds"]["decode"]["dispatches"] == 2
+
+
+class _SlowResult:
+    """A device result whose host copy lands ``delay`` seconds after it is
+    asked for (``jax.device_get`` calls ``__array__``)."""
+
+    def __init__(self, delay):
+        self.delay = delay
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, *a, **kw):
+        time.sleep(self.delay)
+        return np.zeros((1,), np.int32)
+
+
+class _OnDevice(_SlowResult):
+    """Complete on the device ``delay`` s after the one before it (the
+    watcher waits on them in launch order); its host copy is instant."""
+
+    def block_until_ready(self):
+        time.sleep(self.delay)
+        return self
+
+    def __array__(self, *a, **kw):
+        return np.zeros((1,), np.int32)
+
+
+def test_read_batch_of_three_steps_yields_three_segments():
+    """The harvester reads decode steps in batches, one stamp a batch;
+    its watcher stamps each step when ITS result is complete, so three
+    steps of one read batch book three segments (by the batch's stamp the
+    first step took all of it and the others nothing)."""
+    hv = _Harvester(readers=1, batch=4, watch=True)
+    gate = threading.Event()
+
+    class _Gate(_SlowResult):
+        def __array__(self, *a, **kw):
+            gate.wait(5.0)      # hold the reader until all three are queued
+            return super().__array__()
+
+    hv.start()
+    try:
+        hv.push(-100, _Gate(0.0), priority=True)
+        led = _ledger()
+        r = _Req()
+        t0 = time.monotonic()
+        seqs = [_open(led, t0) for _ in range(3)]
+        for i in range(3):
+            hv.push(i, _OnDevice(0.03))
+        time.sleep(0.12)
+        gate.set()
+        hv.wait_done(2, timeout_s=10.0)
+        assert len({hv._done_t[i] for i in range(3)}) == 1  # one read batch
+        stamps = [hv.done_time(i) for i in range(3)]
+        assert stamps[0] < stamps[1] < stamps[2] < hv._done_t[0]
+        for seq, t in zip(seqs, stamps):
+            led.close(seq, t, [(r, "decode", 4)], window=4)
+    finally:
+        hv.stop()
+    booked = _booked(led)
+    assert all(booked[s]["device_ms"] >= 25.0 for s in seqs), booked
+    _conserves(led.snapshot())
+
+
+def test_a_starved_read_does_not_move_a_dispatch_s_end():
+    """With every reader inside a priority batch nobody reads the decode
+    steps: the step launched ahead of a prefill is read AFTER that
+    prefill, and by the reads alone it took both dispatches' time and the
+    prefill none. The harvester's watcher waits on every result in launch
+    order and stamps when it is complete on the device; ``done_time`` is
+    the earlier stamp, and each dispatch keeps its own segment."""
+    hv = _Harvester(readers=1, batch=4, watch=True)
+    gate = threading.Event()
+
+    class _Held(_SlowResult):               # holds the one reader
+        def __array__(self, *a, **kw):
+            gate.wait(5.0)
+            return super().__array__()
+
+    hv.start()
+    try:
+        hv.push(-100, _Held(0.0), priority=True)
+        led = _ledger()
+        a, b = _Req("a"), _Req("b")
+        t0 = time.monotonic()
+        dec = _open(led, t0)
+        pre = _open(led, t0, "prefill", rows=[(b, "prefill", 16)])
+        hv.push(0, _OnDevice(0.04))                     # the decode step
+        hv.push(-1 - pre, _OnDevice(0.04), priority=True)   # the prefill
+        time.sleep(0.15)
+        gate.set()                          # the reads land only now
+        hv.wait_key(-1 - pre, timeout_s=10.0)
+        hv.wait_done(0, timeout_s=10.0)
+        t_dec, t_pre = hv.done_time(0), hv.done_time(-1 - pre)
+        assert t0 + 0.03 < t_dec < t_pre < t0 + 0.14
+        assert hv._done_t[0] > t0 + 0.14 and hv._done_t[-1 - pre] > t0 + 0.14
+        led.close(pre, t_pre)                           # collected first
+        led.close(dec, t_dec, [(a, "decode", 4)], window=4)
+        hv.discard_key(-1 - pre)
+        hv.discard_upto(0)
+        assert not hv._ready_t or set(hv._ready_t) == {-100}
+    finally:
+        hv.stop()
+    booked = _booked(led)
+    assert booked[dec]["device_ms"] == pytest.approx(40.0, abs=12.0)
+    assert booked[pre]["device_ms"] == pytest.approx(40.0, abs=12.0)
+    assert "end_clamped" not in booked[dec]
+    _conserves(led.snapshot())
+    # without a ledger nobody asks for the stamps: no watcher runs
+    plain = _Harvester(readers=1)
+    plain.start()
+    try:
+        plain.push(0, _OnDevice(0.0))
+        plain.wait_done(0, timeout_s=10.0)
+        assert not plain._ready_t and len(plain._extra) == 0
+    finally:
+        plain.stop()
+
+
+def test_identity_holds_with_out_of_order_closes():
+    """Property: whatever order the reads are collected in, unread
+    dispatches among them, every record is booked once, in launch order,
+    and the identity holds exactly."""
+    rng = np.random.default_rng(11)
+    led = _ledger()
+    reqs = [_Req(f"t{i}") for i in range(4)]
+    t = 0.0
+    pending, n = [], 0
+    for _ in range(300):
+        t += rng.uniform(0.0, 0.01)
+        kind = ("prefill", "decode", "chunk")[int(rng.integers(3))]
+        seq = _open(led, t, kind)
+        n += 1
+        if rng.uniform() < 0.15:
+            led.close(seq, None, [(reqs[0], "prefill", 8)])   # nobody reads it
+        else:
+            pending.append(seq)
+        rng.shuffle(pending)
+        while pending and rng.uniform() < 0.6:
+            t += rng.uniform(0.0, 0.01)
+            rows = [(reqs[int(rng.integers(4))], PHASES[int(rng.integers(4))],
+                     int(rng.integers(0, 5)))
+                    for _ in range(int(rng.integers(1, 4)))]
+            led.close(pending.pop(), t, rows, window=int(rng.integers(1, 5)))
+    for seq in pending:
+        t += 0.001
+        led.close(seq, t, [(reqs[1], "decode", 1)])
+    snap = led.snapshot()
+    assert snap["dispatches"] == n and not led._open
+    assert sum(k["dispatches"] for k in snap["kinds"].values()) == n
+    _conserves(snap)
+    assert sum(k["device_ms"] for k in snap["kinds"].values()) == \
+        pytest.approx(snap["busy_ms"])
+    assert sum(snap["idle_host_ms"].values()) == pytest.approx(
+        snap["idle_ms"])
+    booked = led.dispatches_view(2048)
+    assert [d["seq"] for d in booked] == sorted(d["seq"] for d in booked)
+    assert all(d["device_ms"] >= 0.0 and d["behind_ms"] >= 0.0
+               for d in booked)
+
+
+def test_a_clamped_end_is_marked_on_the_record():
+    """A decode step whose own read lands after the read of the prefill
+    launched behind it takes both dispatches' time and the prefill 0: the
+    record says so (``end_clamped``), so a reader of /debug/engine can
+    count how often the split between the two is not known."""
+    led = _ledger()
+    a, b = _Req("a"), _Req("b")
+    _record(led, 0.0, 0.10, [(a, "decode", 4)], window=4)
+    dec = _open(led, 0.05)
+    pre = _open(led, 0.06, "prefill", rows=[(b, "prefill", 16)])
+    unread = _open(led, 0.07, "prefill", rows=[(a, "prefill", 8)])
+    led.close(pre, 0.20)
+    led.close(unread, None)                 # a re-prefill nobody reads
+    led.close(dec, 0.21, [(a, "decode", 4)], window=4)
+    nxt = _open(led, 0.08)
+    led.close(nxt, 0.30, [(a, "decode", 4)], window=4)
+    booked = _booked(led)
+    assert booked[dec].get("end_clamped") is True
+    assert booked[dec]["device_ms"] == pytest.approx(100.0)
+    assert "end_clamped" not in booked[pre]
+    assert booked[pre]["device_ms"] == pytest.approx(0.0, abs=1e-6)
+    assert booked[unread].get("end_clamped") is True    # the next read's time
+    assert "end_clamped" not in booked[nxt]
+    _conserves(led.snapshot())
+
+
+def test_a_head_whose_read_never_comes_is_dropped_not_waited_for():
+    """A record that is never closed (its read raised on the way) would
+    hold every later record unbooked and let the open list grow without
+    bound. With MAX_OPEN records launched behind it, it is dropped; its
+    time falls to the next segment or to idle, and the identity holds."""
+    led = _ledger()
+    r = _Req()
+    _record(led, 0.0, 0.01, [(r, "decode", 1)])
+    lost = _open(led, 0.01)                         # never closed
+    t = 0.02
+    for i in range(3 * MAX_OPEN):
+        seq = _open(led, t)
+        led.close(seq, t + 0.005, [(r, "decode", 1)])
+        t += 0.01
+        assert len(led._open) <= MAX_OPEN
+    snap = led.snapshot()
+    assert snap["lost"] == 1 and lost not in _booked(led)
+    assert snap["dispatches"] == 1 + 3 * MAX_OPEN and not led._open
+    _conserves(snap)
+
+
+def test_abandoning_the_head_books_what_waited_behind_it():
+    """A launch that raised is taken out by seq; records closed behind it
+    are booked at once, not at the next close."""
+    led = _ledger()
+    r = _Req()
+    head = _open(led, 0.0)
+    nxt = _open(led, 0.01)
+    led.close(nxt, 0.05, [(r, "decode", 1)])
+    assert led.snapshot()["dispatches"] == 0
+    led.abandon(head)
+    assert led.snapshot()["dispatches"] == 1 and not led._open
+    led.abandon()                                   # a wedged device: all
+    assert not led._open
+
+
+def test_jit_events_are_the_process_s_and_no_server_moves_them():
+    """The engine reads the count of compiles and cache hits around each
+    dispatch from engine/jit_events.py, which knows no server; a server's
+    telemetry COPIES the same totals at each scrape. So building a server
+    in mid-dispatch cannot move the count (it used to: the early counts
+    were folded into the newest server's counters in two steps) and the
+    engine module does not import the server's."""
+    import inspect
+
+    import jax
+    import jax.numpy as jnp
+
+    from llms_on_kubernetes_tpu.engine import engine as engine_mod
+    from llms_on_kubernetes_tpu.engine import jit_events
+    from llms_on_kubernetes_tpu.server.metrics import Registry
+    from llms_on_kubernetes_tpu.server.runtime_telemetry import (
+        RuntimeTelemetry,
+    )
+
+    assert "runtime_telemetry" not in inspect.getsource(engine_mod)
+    jit_events.install()
+    jit_events.install()                            # once per process
+    before = jit_events.count()
+    jax.jit(lambda x: x * 3 + before)(jnp.ones((3,))).block_until_ready()
+    moved = jit_events.count()
+    assert moved > before                           # a compile or a cache hit
+    first = RuntimeTelemetry(Registry())
+    second = RuntimeTelemetry(Registry())           # tests build many servers
+    assert jit_events.count() == moved
+    compiles, seconds, hits = jit_events.totals()
+    for tel in (first, second):
+        tel.refresh()
+        assert tel.metrics["jit_compiles"].value == compiles
+        assert tel.metrics["jit_cache_hits"].value == hits
+        assert tel.metrics["jit_compile_seconds"].value == pytest.approx(
+            seconds)
+    jax.jit(lambda x: x * 5 - moved)(jnp.ones((3,))).block_until_ready()
+    first.refresh()
+    assert (first.metrics["jit_compiles"].value
+            + first.metrics["jit_cache_hits"].value) == jit_events.count()
+    assert jit_events.count() > moved
+
+
+def test_idle_host_takes_each_of_its_three_values():
+    """What the host was doing in the device's gap before a dispatch:
+    it had no work, it was re-tracing the step, or anything else."""
+    led = _ledger()
+    r = _Req()
+    _record(led, 0.0, 0.1, [(r, "decode", 4)])
+    # the engine had run out of work before this launch
+    led.close(_open(led, 0.3, after_no_work=True), 0.4, [(r, "decode", 4)])
+    # the jitted call compiled for 150 ms before it enqueued anything
+    led.close(_open(led, 0.6, enqueue_s=0.15, retraced=True), 0.7,
+              [(r, "decode", 4)])
+    # neither: the host was slow to launch
+    led.close(_open(led, 0.75), 0.8, [(r, "decode", 4)])
+    # launched while the device was busy: no gap, no label
+    led.close(_open(led, 0.78), 0.9, [(r, "decode", 4)])
+    hosts = [(d.get("idle_host"), d["idle_before_ms"])
+             for d in led.dispatches_view()]
+    assert hosts == [(None, 0.0), ("no_work", pytest.approx(200.0)),
+                     ("compile", pytest.approx(200.0)),
+                     ("scheduling", pytest.approx(50.0)), (None, 0.0)]
+    snap = led.snapshot()
+    assert set(snap["idle_host_ms"]) == set(IDLE_HOSTS)
+    assert snap["idle_host_ms"] == {"no_work": pytest.approx(200.0),
+                                    "compile": pytest.approx(200.0),
+                                    "scheduling": pytest.approx(50.0)}
+    assert led.dispatches_view()[2]["enqueue_ms"] == pytest.approx(150.0)
+    assert led.dispatches_view()[2]["retraced"] is True
+    _conserves(snap)
+
+
 def test_utilization_bounded():
     led = _ledger(peak_flops=1.0, peak_bytes_s=1.0)  # absurdly low peak
     r = _Req()
-    led.record(0.0, 0.1, [(r, "decode", 4)], window=4)
+    _record(led, 0.0, 0.1, [(r, "decode", 4)], window=4)
     mfu, mbu = led.utilization()
     assert mfu == 1.0 and mbu == 1.0  # clamped, never a >100% ratio
     led2 = _ledger(peak_flops=1e18, peak_bytes_s=1e18)
-    led2.record(0.0, 0.1, [(r, "decode", 4)], window=4)
+    _record(led2, 0.0, 0.1, [(r, "decode", 4)], window=4)
     mfu2, mbu2 = led2.utilization()
     assert 0.0 < mfu2 < 1e-3 and 0.0 < mbu2 < 1e-3
 
@@ -188,7 +558,7 @@ def test_detect_peak_by_reported_device_kind(monkeypatch, kind, peak):
 
 def test_detect_peak_unknown_accelerator_is_an_error(monkeypatch):
     """An accelerator the table has never heard of fails start-up with
-    the string it reports; only the CPU platform gets a nominal peak."""
+    the string it reports; the CPU platform has no peak at all."""
     _with_device(monkeypatch, "tpu", "TPU v9 mega")
     with pytest.raises(RuntimeError, match="TPU v9 mega"):
         detect_peak()
@@ -196,7 +566,13 @@ def test_detect_peak_unknown_accelerator_is_an_error(monkeypatch):
     with pytest.raises(RuntimeError, match="NVIDIA H100"):
         detect_peak()
     _with_device(monkeypatch, "cpu", "cpu")
-    assert detect_peak() == (5e11, 5e10)
+    assert detect_peak() is None
+    # ... so a CPU's ledger books chip time and reports no MFU/MBU
+    led = GoodputLedger(get_config("debug-tiny"))
+    assert led.peak_flops is None and led.peak_bytes_s is None
+    _record(led, 0.0, 0.1, [(_Req(), "decode", 4)], window=4)
+    assert led.utilization() is None
+    assert led.snapshot()["busy_ms"] == pytest.approx(100.0)
 
 
 def test_detect_peak_env_override(monkeypatch):
@@ -211,13 +587,13 @@ def test_detect_peak_env_override(monkeypatch):
 
 def test_reset_zeroes_accounting():
     led = _ledger()
-    led.record(0.0, 0.1, [(_Req("x"), "decode", 4)], window=4)
+    _record(led, 0.0, 0.1, [(_Req("x"), "decode", 4)], window=4)
     led.reset()
     snap = led.snapshot()
     assert snap["dispatches"] == 0 and snap["window_ms"] == 0.0
     assert snap["busy_ms"] == 0.0 and snap["tenant_ms"] == {}
     # accounting restarts cleanly after the reset
-    led.record(5.0, 5.1, [(_Req("x"), "decode", 4)], window=4)
+    _record(led, 5.0, 5.1, [(_Req("x"), "decode", 4)], window=4)
     _conserves(led.snapshot())
 
 
@@ -452,7 +828,13 @@ def test_usage_header_spans_flight_and_metrics_carry_chip_time():
             keyed = [s for s in snap["steps"] if "chip_attr_ms" in s]
             assert keyed, "no flight frame carries ledger keys"
             assert sum(s["chip_attr_ms"] for s in keyed) > 0.0
-            assert all("mfu" in s for s in keyed)
+            # a CPU has no peak: no frame carries an MFU
+            assert not any("mfu" in s for s in keyed)
+            # beside the frames, the ledger's newest dispatch records
+            kinds = {d["kind"] for d in snap["dispatches"]}
+            assert {"prefill", "decode"} <= kinds
+            assert all(d["device_ms"] >= 0.0 and d["name"].endswith("_step")
+                       for d in snap["dispatches"])
 
             # /metrics: goodput series present and nonzero
             text = await (await client.get("/metrics")).text()
@@ -461,6 +843,23 @@ def test_usage_header_spans_flight_and_metrics_carry_chip_time():
             assert "llm_mfu_ratio" in text and "llm_mbu_ratio" in text
             assert 'llm_tenant_chip_seconds_total{' in text
             assert 'llm_auto_profile_total' in text
+            # the dispatch counters, drained beside them: what the device
+            # held of every kind is the chip time of every phase
+            import re
+
+            def series(name):
+                return {lab: float(v) for lab, v in re.findall(
+                    rf'^{name}{{\w+="(\w+)"}} (\S+)$', text, re.M)}
+            n = series("llm_dispatches_total")
+            assert n["prefill"] >= 1 and n["decode"] >= 1 and n["spec"] == 0
+            dev = series("llm_dispatch_device_seconds_total")
+            chip = series("llm_chip_seconds_total")
+            assert sum(dev.values()) == pytest.approx(
+                sum(v for ph, v in chip.items() if ph != "idle"), abs=1e-6)
+            assert sum(series("llm_device_idle_seconds_total").values()) \
+                == pytest.approx(chip.get("idle", 0.0), abs=1e-6)
+            assert set(series("llm_dispatch_behind_seconds_total")) == set(n)
+            assert set(series("llm_dispatch_enqueue_seconds_total")) == set(n)
         finally:
             await client.close()
     asyncio.run(go())

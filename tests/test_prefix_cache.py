@@ -142,6 +142,37 @@ def test_second_request_skips_cached_prefix_and_matches_cold(async_scheduling):
     assert other.output == ref_out.output
 
 
+def test_finished_requests_prefix_is_used_and_not_live():
+    """``llm_kv_pages_used`` counts a finished request's cached prefix
+    (the pages are allocated, evictable, and full of valid KV), so it
+    climbs to the pool's size and stays there; ``llm_kv_pages_live`` is
+    what live sequences hold, and falls back to 0 when they finish."""
+    eng = _mk()
+    a = eng.allocator
+
+    def used():
+        return eng.config.num_pages - 1 - a.num_free_pages
+
+    assert used() == a.num_live_pages == 0
+    req = eng.submit(SYSTEM + [30, 31, 32], SamplingParams(
+        temperature=0.0, max_tokens=30))
+    while not req.output:
+        eng.step()
+    assert a.num_live_pages == used() > 0       # decoding: all of it live
+    while not req.finished:
+        eng.step()
+    eng._drain_async()
+    assert a.num_live_pages == 0
+    assert used() == a.num_evictable_pages == 2  # the prompt's two full pages
+    # adopted again, the cached pages are live again
+    req = eng.submit(SYSTEM + [40], SamplingParams(
+        temperature=0.0, max_tokens=30))
+    while not req.output:
+        eng.step()
+    assert a.num_live_pages >= 2 and a.num_evictable_pages == 0
+    assert a.num_live_pages == used()
+
+
 def test_prefix_cache_off_by_flag():
     eng = _mk(prefix_caching=False)
     _run(eng, SYSTEM)
