@@ -140,11 +140,13 @@ class EngineConfig:
     multihost: bool = False
     # async (pipelined) scheduling: keep up to async_depth decode steps in
     # flight, feeding each step's on-device sampled tokens straight into the
-    # next launch; host copies are read by a dedicated harvester thread in
-    # batched device_gets, so the ENGINE thread never blocks on device
-    # work except for backpressure at full depth — admissions and their
-    # prefills dispatch immediately (vLLM-style async scheduling, re-done
-    # for JAX's dispatch model). Finishes/stop tokens are detected a
+    # next launch; host copies are read by a dedicated harvester thread,
+    # one result at a time in launch order, so the ENGINE thread never
+    # blocks on device work except for backpressure at full depth —
+    # admissions and their prefills dispatch immediately (vLLM-style
+    # async scheduling, re-done for JAX's dispatch model), and a first
+    # token whose read lands during that wait is handed to its request
+    # from inside it. Finishes/stop tokens are detected a
     # transfer-latency late; the speculative extra steps are harmless
     # (their writes land in pages that are only reused after
     # device-ordered completion). Works under multihost too: the packed
@@ -547,6 +549,8 @@ class StepEvent:
     new_tokens: list[int]
     finished: bool
     finish_reason: Optional[str]
+    first: bool = False      # carries the request's first token
+    handed_over: bool = False  # already on the request's queue
 
 
 @dataclasses.dataclass
@@ -571,163 +575,89 @@ class InflightStep:
 class _Harvester(threading.Thread):
     """Off-thread device->host reader for async scheduling.
 
-    The engine thread pushes device SampleResults in dispatch order; this
-    thread reads them with batched ``jax.device_get`` calls (one host
-    read amortized over everything completed) and marks them done.
-    The engine thread polls ``is_done``/``get`` without ever blocking on
-    device work — so a newly submitted request is admitted and its prefill
-    dispatched IMMEDIATELY, instead of queueing behind a blocking read of
-    ``async_depth`` in-flight decode steps (the round-2 gateway-TTFT
-    finding). All engine state stays on the engine thread; this thread
-    touches only device arrays and the results dict.
+    The engine thread pushes device results in LAUNCH order; this thread
+    waits on them in that order, one at a time, and publishes each the
+    moment it is on the host. The device runs dispatches in launch order
+    and ``push`` has begun the transfer, so waiting on the oldest alone
+    loses nothing: by the time a result is complete every result
+    launched before it has been read. A first token is published when
+    ITS prefill is done, never when a neighbour's is, and a decode step
+    is read while first tokens are queued behind it (batched reads by
+    several readers published a batch when its LAST item landed, and
+    starved the decode steps while every reader sat in a first-token
+    batch).
+    The engine thread polls ``is_done``/``key_done``/``get`` without
+    ever blocking on device work, so a newly submitted request is
+    admitted and its prefill dispatched IMMEDIATELY, instead of queueing
+    behind a blocking read of ``async_depth`` in-flight decode steps (the
+    round-2 gateway-TTFT finding). All engine state stays on the engine
+    thread; this thread touches only device arrays and the results dict.
 
-    Two classes of work:
-    - decode steps (non-negative dense seqs): read oldest-first in small
-      batches; done-ness is monotone (``is_done(s)`` implies every earlier
-      step is done), so the engine harvests a strict prefix each step.
-    - PRIORITY items (prefill results carrying first tokens, negative
-      keys): jump the read queue and are read in their own small batches —
-      a new request's TTFT must not wait behind a batch of decode-step
-      reads it doesn't depend on."""
+    Two classes of key:
+    - decode steps (non-negative dense seqs): done-ness is monotone
+      (``is_done(s)`` implies every earlier step is done), so the engine
+      harvests a strict prefix each step.
+    - first tokens (prefill results, negative keys): ``key_done``; the
+      engine's backpressure wait (``wait_done(keys=...)``) wakes for one."""
 
-    def __init__(self, readers: Optional[int] = None,
-                 batch: Optional[int] = None, watch: bool = False):
-        import os
+    def __init__(self):
         super().__init__(daemon=True, name="engine-harvester")
         self._cv = threading.Condition()
-        self._pending: "collections.deque[tuple[int, Any]]" = collections.deque()
-        self._prio: "collections.deque[tuple[int, Any]]" = collections.deque()
-        self._staged: dict[int, Any] = {}   # read but predecessors not done
-        self._done: dict[int, Any] = {}     # steps (dense prefix) + priority
-        # monotonic completion time per done key — when its device_get
-        # landed. The goodput ledger segments busy time on these.
-        self._done_t: dict[int, float] = {}
-        # ``watch`` (the engine has a ledger): one more thread waits on
-        # every pushed result in LAUNCH order and stamps when it is
-        # complete on the device. A read lands later, a batch's items
-        # all at once, and sometimes much later: with both readers
-        # inside priority batches nobody reads the decode steps, and the
-        # step ahead of a prefill was stamped after the prefill's read
-        # (up to a quarter of the prefill records on the chip)
-        self._watching = watch
-        self._watch: "collections.deque[tuple[int, Any]]" = collections.deque()
-        self._ready_t: dict[int, float] = {}
+        self._queue: "collections.deque[tuple[int, Any]]" = collections.deque()
+        # key -> (host copy, monotonic time the result was complete on the
+        # device: this thread woke from block_until_ready. The goodput
+        # ledger segments busy time on these)
+        self._done: dict[int, tuple[Any, float]] = {}
         self._done_upto = -1
-        self._next_seq = 0                  # next step seq to mark done
         self._stopping = False
         # a device_get failure (e.g. an OOM surfacing on the read)
-        # must surface on the ENGINE thread, not silently kill a reader —
+        # must surface on the ENGINE thread, not silently kill the reader —
         # otherwise every wait_done/wait_key blocks forever (observed as a
-        # bench hang). First error wins; all waiters re-raise it.
+        # bench hang). All waiters re-raise it.
         self._error: Optional[BaseException] = None
-        # cumulative seconds this thread spent blocked in device_get —
-        # the engine folds it into its kernel-vs-host attribution; plain
-        # float += is safe: only this thread writes, readers tolerate a
-        # slightly stale value
+        # cumulative seconds this thread spent blocked on the device and
+        # its reads — the engine folds it into its kernel-vs-host
+        # attribution; plain float += is safe: only this thread writes,
+        # readers tolerate a slightly stale value
         self.device_time_s = 0.0
-        # small batches + overlapped readers: one huge batched read would
-        # couple every completion to the newest dispatch and mark done in
-        # lumps; overlapping 2+ reads pipelines the read latency instead
-        self._batch = batch if batch is not None else int(
-            os.environ.get("LLMK_HARVEST_BATCH", "4"))
-        self._readers = readers if readers is not None else int(
-            os.environ.get("LLMK_HARVEST_READERS", "2"))
-        self._extra: list[threading.Thread] = []
 
-    def start(self) -> None:  # type: ignore[override]
-        super().start()
-        for i in range(self._readers - 1):
-            t = threading.Thread(target=self.run, daemon=True,
-                                 name=f"engine-harvester-{i + 1}")
-            t.start()
-            self._extra.append(t)
-        if self._watching:
-            t = threading.Thread(target=self._run_watch, daemon=True,
-                                 name="engine-harvester-watch")
-            t.start()
-            self._extra.append(t)
-
-    def push(self, key: int, res: Any, priority: bool = False) -> None:
+    def push(self, key: int, res: Any) -> None:
         _start_host_copy(res)  # transfer overlaps with device compute
         with self._cv:
-            (self._prio if priority else self._pending).append((key, res))
-            if self._watching:
-                self._watch.append((key, res))
+            self._queue.append((key, res))
             self._cv.notify_all()
 
-    def _run_watch(self) -> None:
-        while True:
-            with self._cv:
-                while not self._watch and not self._stopping:
-                    self._cv.wait()
-                if not self._watch:
-                    return
-                key, res = self._watch.popleft()
-            try:
-                # the fault that stands for a slow device (see run())
-                # slows what is seen of it here too
-                from llms_on_kubernetes_tpu import faults
-                faults.inject_delay("slow_step", 0.2)
-                jax.block_until_ready(res)
-            except BaseException:  # noqa: BLE001 — the read surfaces it
-                continue
-            t_ready = time.monotonic()
-            with self._cv:
-                self._ready_t[key] = t_ready
-                # a key read and discarded before this thread got to stamp
-                # it is never taken away again: bound the dict itself
-                if len(self._ready_t) > 256:
-                    del self._ready_t[next(iter(self._ready_t))]
-
     def run(self) -> None:
+        from llms_on_kubernetes_tpu import faults
+
         while True:
             with self._cv:
-                while not (self._pending or self._prio) and not self._stopping:
+                while not self._queue and not self._stopping:
                     self._cv.wait()
-                if self._stopping and not (self._pending or self._prio):
+                if not self._queue:
                     return
-                if self._prio:
-                    batch = list(self._prio)
-                    self._prio.clear()
-                    priority = True
-                else:
-                    n = min(max(1, self._batch), len(self._pending))
-                    batch = [self._pending.popleft() for _ in range(n)]
-                    priority = False
+                key, res = self._queue.popleft()
             try:
                 # deterministic fault hooks (LLMK_FAULT=): a wedged device
                 # read ("engine_stall" hangs here; the engine thread's
-                # watchdog wait must fire) or a slow-but-live one
-                # ("slow_step" delays each read)
-                from llms_on_kubernetes_tpu import faults
+                # watchdog wait must fire) or a slow-but-live device
+                # ("slow_step" delays each result)
                 faults.inject_hang("engine_stall")
                 faults.inject_delay("slow_step", 0.2)
                 t0 = time.perf_counter()
-                host = jax.device_get([r for _, r in batch])
+                jax.block_until_ready(res)
+                t_ready = time.monotonic()
+                host = jax.device_get(res)
                 self.device_time_s += time.perf_counter() - t0
             except BaseException as e:  # noqa: BLE001 — must not die silent
                 with self._cv:
-                    if self._error is None:
-                        self._error = e
+                    self._error = e
                     self._cv.notify_all()
                 return
-            t_read = time.monotonic()
             with self._cv:
-                if priority:
-                    for (key, _), h in zip(batch, host):
-                        self._done[key] = h
-                        self._done_t[key] = t_read
-                else:
-                    for (seq, _), h in zip(batch, host):
-                        self._staged[seq] = (h, t_read)
-                    # done-ness stays a dense seq prefix even with
-                    # overlapped readers finishing out of order
-                    while self._next_seq in self._staged:
-                        h, t = self._staged.pop(self._next_seq)
-                        self._done[self._next_seq] = h
-                        self._done_t[self._next_seq] = t
-                        self._done_upto = self._next_seq
-                        self._next_seq += 1
+                self._done[key] = (host, t_ready)
+                if key >= 0:
+                    self._done_upto = key
                 self._cv.notify_all()
 
     def _check_error(self) -> None:
@@ -747,32 +677,32 @@ class _Harvester(threading.Thread):
 
     def get(self, key: int) -> Any:
         with self._cv:
-            return self._done[key]
+            return self._done[key][0]
 
     def done_time(self, key: int) -> float:
-        """Monotonic time key's result was complete on the device (the
-        watcher's stamp) or, failing that, its device_get completed — the
-        earlier of the two (ledger segmenting); falls back to now for
-        keys whose stamps were already discarded."""
+        """Monotonic time key's result was complete on the device (ledger
+        segmenting); falls back to now for a key already discarded."""
         with self._cv:
-            seen = [t for t in (self._ready_t.get(key), self._done_t.get(key))
-                    if t is not None]
-        return min(seen) if seen else time.monotonic()
+            done = self._done.get(key)
+        return done[1] if done else time.monotonic()
 
     def wait_done(self, seq: int, wake: Optional[threading.Event] = None,
-                  timeout_s: Optional[float] = None) -> None:
-        """Block until step ``seq`` is done — or, if ``wake`` is given,
-        until it is set (a new submission wants admission NOW — submit()
-        pokes this cv; the caller re-enters its loop and the next step()
-        admits before waiting again). With ``timeout_s`` (the engine's
-        watchdog budget) raises EngineStallError if the step is still
-        incomplete at the deadline."""
+                  keys: tuple = (), timeout_s: Optional[float] = None) -> None:
+        """Block until step ``seq`` is done — or one of the first-token
+        ``keys`` is (the caller hands that token over and waits again) —
+        or, if ``wake`` is given, until it is set (a new submission wants
+        admission NOW — submit() pokes this cv; the caller re-enters its
+        loop and the next step() admits before waiting again). With
+        ``timeout_s`` (the engine's watchdog budget) raises
+        EngineStallError if the step is still incomplete at the deadline."""
         deadline = (None if timeout_s is None
                     else time.monotonic() + timeout_s)
         with self._cv:
             while self._done_upto < seq:
                 self._check_error()
                 if wake is not None and wake.is_set():
+                    return
+                if any(k in self._done for k in keys):
                     return
                 if deadline is None:
                     self._cv.wait()
@@ -809,14 +739,10 @@ class _Harvester(threading.Thread):
         with self._cv:
             for s in [s for s in self._done if 0 <= s <= seq]:
                 del self._done[s]
-                self._done_t.pop(s, None)
-                self._ready_t.pop(s, None)
 
     def discard_key(self, key: int) -> None:
         with self._cv:
             self._done.pop(key, None)
-            self._done_t.pop(key, None)
-            self._ready_t.pop(key, None)
 
     def stop(self) -> None:
         with self._cv:
@@ -1587,7 +1513,7 @@ class Engine:
             jit_events.install()
         # one number per device dispatch, in launch order: the ledger's
         # record, the seq of its llmk.dispatch trace annotation and (for a
-        # prefill, as -1 - seq) the key of its priority read
+        # prefill, as -1 - seq) the key of its first-token read
         self._dispatch_seq = itertools.count()
         # step() found the engine without work since the last launch: the
         # next dispatch's idle gap is nobody's fault
@@ -1641,21 +1567,32 @@ class Engine:
         # async scheduling state (see EngineConfig.async_scheduling)
         self._async = bool(engine_config.async_scheduling)
         self._inflight: "collections.deque[InflightStep]" = collections.deque()
-        # (request, priority key, row) awaiting a first-token read
+        # (request, harvester key, row) awaiting a first-token read
         self._pending_first: list[tuple[Request, int, int]] = []
         self._seq_counter = iter(range(2 ** 62))     # decode steps (dense)
         # set by submit(): breaks the backpressure wait so admission (and
         # the new request's prefill dispatch) never waits out a read
         self._admit_wake = threading.Event()
+        # first tokens handed to their requests, by who did it (_hand_over);
+        # the serving loop drains it into llm_first_tokens_total{delivered}
+        self.first_tokens_handed = {"backpressure": 0, "step": 0}
         self._harvester: Optional[_Harvester] = None
         if self._async:
-            self._harvester = _Harvester(watch=self.ledger is not None)
+            self._harvester = _Harvester()
             self._harvester.start()
             import weakref
             weakref.finalize(self, self._harvester.stop)
         # device-resident zero vectors for the packed steps (uploaded once)
         self._zeros_B = jnp.zeros((B,), jnp.int32)
         self._zeros_1 = jnp.zeros((1,), jnp.int32)
+        # what the fused decode launch passes for a token input no row
+        # reads (nothing in flight; no admission): the newest real one, so
+        # that the step is traced for ONE sharding annotation of each. With
+        # the zeros (uncommitted) every combination was a cache entry of
+        # its own, and one that the traffic before it never reached was
+        # traced and lowered under a request (17-20 s at 32 layers)
+        self._unread_toks = self._zeros_B
+        self._unread_prefill_toks = self._zeros_1
         # decode-row template cache (PR 3, profile-guided): the decode
         # packed array is mostly request-STATIC sampling columns, and
         # rebuilding every one of them per step in a Python loop (plus
@@ -2106,10 +2043,7 @@ class Engine:
             # the wedge and the server's readiness flip
             events += self._reap_aborted()
             for ev in events:
-                payload = (ev.new_tokens, ev.finished, ev.finish_reason)
-                ev.request.events.put(payload)
-                if ev.request.on_event is not None:
-                    ev.request.on_event(payload)
+                self._hand_over(ev)
             return events
         events += self._reap_aborted()
         if self._async:
@@ -2136,13 +2070,28 @@ class Engine:
                 events += self._decode_once()
         with _phase("llmk.emit"):
             for ev in events:
-                payload = (ev.new_tokens, ev.finished, ev.finish_reason)
-                ev.request.events.put(payload)
-                if ev.request.on_event is not None:
-                    ev.request.on_event(payload)
+                self._hand_over(ev)
         if not self.has_work():
             self._saw_no_work = True
         return events
+
+    def _hand_over(self, ev: StepEvent, where: str = "step") -> None:
+        """Put an event on its request's queue, once. A first token's
+        ``first_token_at`` is stamped HERE, where it leaves the engine,
+        not where its read was collected: span ``prefill.emit`` ends when
+        the client can have the token. ``where`` says who handed a first
+        token over: the backpressure wait of ``_harvest`` ("backpressure")
+        or the end of a ``step()`` ("step")."""
+        if ev.handed_over:
+            return
+        ev.handed_over = True
+        if ev.first:
+            ev.request.first_token_at = time.monotonic()
+            self.first_tokens_handed[where] += 1
+        payload = (ev.new_tokens, ev.finished, ev.finish_reason)
+        ev.request.events.put(payload)
+        if ev.request.on_event is not None:
+            ev.request.on_event(payload)
 
     def abort(self, req: Request, reason: str = "abort") -> None:
         """Request cancellation from any thread (client disconnect, server-side
@@ -2901,13 +2850,13 @@ class Engine:
             self.ledger.close(dseq, time.monotonic())
         first = int(host.tokens[0])
         req.pending_token = first
-        req.first_token_at = time.monotonic()
-        return self._emit(req, first, _lp_entry(host, 0))
+        return self._emit(req, first, _lp_entry(host, 0), first=True)
 
-    def _emit(self, req: Request, token: int,
-              lp: Optional[tuple] = None) -> list[StepEvent]:
+    def _emit(self, req: Request, token: int, lp: Optional[tuple] = None,
+              first: bool = False) -> list[StepEvent]:
         """Record a sampled token (+ its logprob data) and decide whether
-        the request finishes."""
+        the request finishes. ``first``: the request's first token, whose
+        hand-over stamps ``first_token_at`` (_hand_over)."""
         req.output.append(token)
         req.output_logprobs.append(lp)
         reason = None
@@ -2919,7 +2868,7 @@ class Engine:
             reason = "length"
         if reason is not None:
             self._finish(req, reason)
-        return [StepEvent(req, [token], req.finished, reason)]
+        return [StepEvent(req, [token], req.finished, reason, first=first)]
 
     def _finish(self, req: Request, reason: str) -> StepEvent:
         """Release a request's slot/pages and mark it finished."""
@@ -3297,7 +3246,7 @@ class Engine:
                                 + 2.0 * n_chunks * self._est_step)
             merge = {"toks": toks, "slots": {}}
             if resumed:
-                # no priority read: the host knows the re-prefill done
+                # no first-token read: the host knows the re-prefill done
                 # only when the decode step launched behind it is read
                 req.pending_token = req.output[-1]
                 merge["slots"][slot] = (True, req.output[-1], 0)
@@ -3305,7 +3254,7 @@ class Engine:
                     self.ledger.close(dseq, None)
             else:
                 key = -1 - dseq
-                self._harvester.push(key, pack, priority=True)
+                self._harvester.push(key, pack)
                 merge["slots"][slot] = (False, 0, 0)
                 self._pending_first.append((req, key, 0))
             return merge
@@ -3352,9 +3301,9 @@ class Engine:
             self.allocator.register_prefix(slot, req.prompt)
         key = None
         if any(not resumed for _, _, resumed, _ in picked):
-            # priority read: first tokens jump the decode-read queue
+            # a negative key: its read carries first tokens
             key = -1 - dseq
-            self._harvester.push(key, pack, priority=True)
+            self._harvester.push(key, pack)
         elif self.ledger is not None:
             self.ledger.close(dseq, None)   # nobody reads a re-prefill
         merge = {"toks": toks, "slots": {}}
@@ -3520,7 +3469,7 @@ class Engine:
                 continue
             prior = infl.get(i, 0)
             base0 = int(self.slot_len[i]) + prior + 1
-            # a first token still in the priority-read queue will be
+            # a first token whose read has not landed yet will be
             # emitted before any of this window's tokens — budget for it
             extra = 1 if id(r) in first_pending else 0
             budget = r.params.max_tokens - len(r.output) - prior - extra
@@ -3574,8 +3523,11 @@ class Engine:
             else:
                 packed[i, 1], packed[i, 2] = 1, r.pending_token
 
-        last_toks = self._inflight[-1].toks if self._inflight else self._zeros_B
-        prefill_toks = admitted["toks"] if admitted is not None else self._zeros_1
+        if admitted is not None:
+            self._unread_prefill_toks = admitted["toks"]
+        last_toks = (self._inflight[-1].toks if self._inflight
+                     else self._unread_toks)
+        prefill_toks = self._unread_prefill_toks
 
         # multihost always clamps decode_steps to 1 in EngineConfig, so
         # this path never needs a broadcast message
@@ -3596,6 +3548,7 @@ class Engine:
                             planned={i: plan.get(i, 0) for i, _r in active},
                             dseq=dseq)
         self._inflight.append(step)
+        self._unread_toks = toks
         self._harvester.push(seq, pack)
         now = time.monotonic()
         self._busy_until = max(now, self._busy_until) + self._est_step
@@ -3723,7 +3676,9 @@ class Engine:
         dispatch — proceeds while the harvester waits out the device and
         the host read. This is what bounds gateway TTFT: a new
         request's prefill no longer queues behind a blocking batched read
-        of the whole pipeline."""
+        of the whole pipeline. A first token is handed to its request
+        before the thread sleeps and whenever one lands during the sleep;
+        every other event leaves at the end of ``step()``."""
         events: list[StepEvent] = []
         if not self._inflight and not self._pending_first:
             return events
@@ -3737,9 +3692,14 @@ class Engine:
                     break
             elif len(self._inflight) < depth:
                 break
-            # blocked: wait for whatever gates the head. If the oldest
-            # step's request still awaits its FIRST token (its priority
-            # read hasn't landed), wait for that key — consuming the step
+            # blocked. A first token collected so far leaves the engine
+            # NOW, not a decode window later when the wait is over
+            for ev in events:
+                if ev.first:
+                    self._hand_over(ev, "backpressure")
+            # wait for whatever gates the head. If the oldest
+            # step's request still awaits its FIRST token (its read
+            # hasn't landed), wait for that key — consuming the step
             # early would let a stale first overwrite pending_token later
             # and feed the model a wrong input token.
             key = self._head_blocking_first()
@@ -3750,10 +3710,16 @@ class Engine:
             if self._inflight:
                 k = (len(self._inflight) if drain
                      else len(self._inflight) - (depth - 1))
+                # a first token landing ends the wait too: the loop
+                # collects it, hands it over above and comes back here.
+                # It does NOT leave for another round of step(), which
+                # would launch one more decode window for the next
+                # prefill to queue behind
                 with _phase("llmk.wait"):
                     self._harvester.wait_done(
                         self._inflight[k - 1].seq,
                         wake=None if drain else self._admit_wake,
+                        keys=tuple(key for _, key, _ in self._pending_first),
                         timeout_s=budget)
                 if not drain and self._admit_wake.is_set():
                     # a submission wants admission NOW; collect whatever
@@ -3809,12 +3775,8 @@ class Engine:
         constraints are satisfied — firsts in FIFO order, then steps in
         dispatch order while not gated by a pending first. Returns the
         number of decode steps consumed (pacing calibration)."""
-        # firsts are per-request-independent results: with overlapped
-        # harvester readers (LLMK_HARVEST_READERS >= 2) a LATER priority
-        # batch can land before an earlier in-flight one, so release every
-        # completed entry rather than stopping at the first not-done key —
-        # a FIFO prefix scan would couple independent requests' TTFT
-        # (round-3 advisor finding)
+        # firsts are per-request-independent results: release every
+        # completed entry rather than stopping at the first not-done key
         done_entries, still = [], []
         for entry in self._pending_first:
             (done_entries if self._harvester.key_done(entry[1])
@@ -3825,8 +3787,7 @@ class Engine:
             host = HostSample(np.asarray(self._harvester.get(key)))
             tok = int(host.tokens[row])
             req.pending_token = tok
-            req.first_token_at = time.monotonic()
-            events += self._emit(req, tok, _lp_entry(host, row))
+            events += self._emit(req, tok, _lp_entry(host, row), first=True)
         if done_entries:
             self._pending_first = still
             done_keys = {k for _, k, _ in done_entries}
@@ -3922,10 +3883,7 @@ class Engine:
         inspection / shutdown)."""
         events = self._harvest(drain=True)
         for ev in events:
-            payload = (ev.new_tokens, ev.finished, ev.finish_reason)
-            ev.request.events.put(payload)
-            if ev.request.on_event is not None:
-                ev.request.on_event(payload)
+            self._hand_over(ev)
         return events
 
     # ------------------------------------------------------------------

@@ -362,8 +362,8 @@ class GoodputLedger:
         while self._open and self._open[0].closed:
             head = self._open[0]
             # an in-order device cannot finish the head after a later
-            # launch that has already been read (a priority read that
-            # overtook it; the one stamp of a batched read)
+            # launch that has already been read (a first token's read,
+            # collected before the decode step launched ahead of it)
             done = [r.t_done for r in itertools.islice(self._open, 1, None)
                     if r.closed and r.t_done is not None]
             if head.t_done is not None:

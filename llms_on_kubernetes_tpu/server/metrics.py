@@ -526,6 +526,13 @@ def engine_metrics(registry: Registry) -> dict:
             "phases bill the tenant whose speculation or early exit "
             "burned the window)",
             registry, label_names=("tenant", "phase")),
+        "first_tokens": Counter(
+            "llm_first_tokens_total",
+            "First tokens handed to their requests, by who handed them "
+            "over: backpressure=the engine thread, from inside its wait at "
+            "full pipeline depth, as the token's read landed; step=at the "
+            "end of a scheduler step",
+            registry, label_names=("delivered",)),
         "auto_profile": Counter(
             "llm_auto_profile_total",
             "Automatic bounded profiler captures triggered by the "
@@ -540,6 +547,8 @@ def engine_metrics(registry: Registry) -> dict:
     # dashboard's rate() panel and the router's /metrics/cluster merge
     # would not see the series until the first trigger
     m["auto_profile"].labels(reason="step_anomaly")
+    for delivered in ("backpressure", "step"):
+        m["first_tokens"].labels(delivered=delivered)
     # likewise the dispatch counters, for every kind and host there is
     from llms_on_kubernetes_tpu.engine.ledger import IDLE_HOSTS, KINDS
 

@@ -207,6 +207,7 @@ class EngineLoop(threading.Thread):
         self._ttft_seen: set[str] = set()
         self._preempt_seen = 0
         self._early_exit_seen = 0
+        self._first_tokens_seen = {"backpressure": 0, "step": 0}
         self._spec_seen = {"drafted": 0, "accepted": 0}
         self._adapter_seen = {"hits": 0, "misses": 0, "evictions": 0}
         self._host_kv_seen = {"hits": 0, "misses": 0, "evictions": 0}
@@ -321,6 +322,12 @@ class EngineLoop(threading.Thread):
                     m["decode_early_exit"].inc(
                         early_exit - self._early_exit_seen)
                     self._early_exit_seen = early_exit
+                for where, v in getattr(
+                        eng, "first_tokens_handed", {}).items():
+                    if v > self._first_tokens_seen[where]:
+                        m["first_tokens"].labels(delivered=where).inc(
+                            v - self._first_tokens_seen[where])
+                        self._first_tokens_seen[where] = v
                 drafted = getattr(eng, "spec_drafted_tokens", 0)
                 if drafted > self._spec_seen["drafted"]:
                     m["spec_drafted"].inc(
@@ -1854,10 +1861,11 @@ class OpenAIServer:
         dispatch record: ``prefill.pack`` (admission to launch: host KV
         commit, packing, enqueue), ``prefill.behind`` (launch to the
         device being free for it: the dispatches ahead), ``prefill.device``
-        (to its read landing: the prefill itself — every dispatch of a
-        chunked one — and its priority read) and ``prefill.emit`` (to the
-        first token: the engine thread getting round to it). Nothing
-        without a ledger, or before the first token."""
+        (to its result being complete on the device: the prefill itself,
+        every dispatch of a chunked one) and ``prefill.emit`` (to
+        ``first_token_at``, stamped where the token's event is put on the
+        request's queue: the read, and the engine thread waking for it).
+        Nothing without a ledger, or before the first token."""
         adm, ft = req.admitted_at, req.first_token_at
         launched, read = req.prefill_launched_at, req.prefill_read_at
         if ft is None or launched is None or read is None:

@@ -7,6 +7,7 @@ with the byte tokenizer — the reference's black-box curl runbook
 
 import asyncio
 import json
+import pathlib
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -579,6 +580,40 @@ def test_first_token_span_splits_into_four_children(case):
     kinds = {d["kind"] for d in eng.ledger.dispatches_view(2048)}
     # (a resumed request re-prefills through its own cached prefix)
     assert ("chunk" in kinds) == (case != "bucketed")
+
+
+def test_first_tokens_counter_has_both_labels_from_the_start():
+    """``llm_first_tokens_total{delivered}`` is on a fresh exposition with
+    both labels at 0 (lint-clean, in the constructor-derived inventory),
+    and a request's first token moves exactly one of them."""
+    import sys
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
+                           / "scripts"))
+    import metrics_lint
+
+    assert "llm_first_tokens_total" in metrics_lint.known_emitted_names()
+
+    def counts(text):
+        return {where: float(line.rsplit(" ", 1)[1])
+                for line in text.splitlines()
+                for where in ("backpressure", "step")
+                if line.startswith(
+                    'llm_first_tokens_total{delivered="%s"}' % where)}
+
+    async def body(client):
+        text = await (await client.get("/metrics")).text()
+        assert metrics_lint.lint(text, "fresh") == []
+        assert counts(text) == {"backpressure": 0.0, "step": 0.0}
+        await client.post("/v1/completions", json={
+            "prompt": "abc", "max_tokens": 6, "temperature": 0})
+        for _ in range(50):
+            text = await (await client.get("/metrics")).text()
+            if sum(counts(text).values()):
+                break
+            await asyncio.sleep(0.02)
+        assert sum(counts(text).values()) == 1.0
+        assert metrics_lint.lint(text, "after") == []
+    with_client(body)
 
 
 def test_debug_engine_lists_dispatch_records():
