@@ -92,6 +92,15 @@ def _pollers(stack: Stack, mix: dict, sink: dict):
     return [poll, capture]
 
 
+def traced_window_s(asked_s: float, devs: dict) -> float:
+    """The length of the traced window: what the capture was asked for,
+    or the span of the device's own events where that is longer. The
+    server's ``duration_s`` is the time it slept between starting and
+    stopping the profiler, and stopping takes a moment more, so a device
+    that never idles shows events over slightly more than was asked."""
+    return max(float(asked_s), *(d["span_s"] for d in devs.values()))
+
+
 def _start_reduce(profile_dir: str) -> "subprocess.Popen | None":
     """Start reducing the newest .xplane.pb under the server's profile
     directory, in a child process held to the CPU (the parent never
@@ -348,6 +357,15 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
          warm_up=warm, storms=stormed, check=check, child_exit_codes=exit_codes,
          **timings)
 
+    # what ``correct`` compared, each number beside its limit: the last
+    # lines of stderr and the last key of the result line, which is all
+    # that is kept of a run that is not correct
+    compared = setup_steps.compared(check)
+    for name, c in compared.items():
+        print(f"{timeline.who}: compared {name} {c['value']} limit "
+              f"{c['limit']} ({c['better']} passes)", file=sys.stderr)
+    sys.stderr.flush()
+
     if rehearse:
         # counts only: a CPU timing never appears under a metric's name
         print(json.dumps({
@@ -357,7 +375,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
             "counts": {"output_tokens": sum(r.tokens for r in window),
                        "compiles_in_window": compiles,
                        "traced_planes": (reduced or {}).get("planes", [])
-                       if trace else None}}))
+                       if trace else None},
+            "compared": compared}))
         return 0
 
     mem = client.metric_values(after, "llm_device_memory_bytes",
@@ -389,7 +408,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
                 result["metrics"][m["name"]] = {"value": value,
                                                 "unit": m["unit"]}
         dev["busy_s"] = sum(busy) / len(busy)
-        dev["window_s"] = float(sink["capture"]["duration_s"])
+        dev["window_s"] = traced_window_s(sink["capture"]["duration_s"], devs)
         first = next(iter(devs.values()))
         result["breakdown"] = {"device_ops": first["device_ops"],
                                "idle_gaps": first["idle_gaps"]}
@@ -397,5 +416,6 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
                         "busy_s": d["busy_s"], "modules": d["modules"]}
                     for p, d in devs.items()},
              spans_kept=len(sink["spans"]), polls=len(sink["polls"]))
+    result["compared"] = compared
     print(json.dumps(result), flush=True)
     return 0

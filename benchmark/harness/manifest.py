@@ -5,11 +5,18 @@ A cell ``<config>.<mix>`` is described by data files, all under
 ``traffic/<mix>.json`` and, per metric, ``end_to_end/<metric>.json`` or
 ``layer_metrics/<metric>.json`` (each naming a reader in its ``readers/``). ``BENCHMARK.json`` at the
 root of the checkout says which metrics a cell reports.
+
+A configuration's file may name, for a block other than the dense one,
+the module that counts its shapes (``"shapes"``: ``harness/<module>.py``)
+and its plain reference (``"reference"``: ``reference/<module>.py``);
+where it names none they are ``harness/shapes.py`` and
+``reference/forward.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import os
@@ -100,3 +107,49 @@ def load_reader(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+# what a configuration's shape counts give (``harness/shapes.py`` is the
+# dense block's and the default). A function a module cannot give for its
+# block raises NotImplementedError when called; it never guesses.
+SHAPES_INTERFACE = ("weight_bytes", "kv_bytes_per_token", "pool_bytes",
+                    "decode_step_bytes", "decode_step_flops",
+                    "prefill_flops")
+REFERENCE_INTERFACE = ("logits_at",)
+
+
+def _load_named(package: str, config: dict, key: str, default: str,
+                interface: tuple):
+    """``<package>/<config[key]>.py`` as the module ``<package>.<name>``
+    (``default`` where the configuration's file has no such key)."""
+    name = config.get(key, default)
+    path = os.path.join(BENCH_DIR, package, f"{name}.py")
+    if not (isinstance(name, str) and name.isidentifier()
+            and os.path.isfile(path)):
+        raise ManifestError(
+            f"a configuration names {key!r}: {name!r}, and there is no "
+            f"{path}")
+    try:
+        mod = importlib.import_module(f"{package}.{name}")
+    except ImportError as e:
+        raise ManifestError(f"{path}: {e}") from e
+    missing = [f for f in interface if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ManifestError(f"{path} lacks {', '.join(missing)}")
+    return mod
+
+
+def shapes_of(config: dict):
+    """The module that counts ``config``'s bytes and operations: the
+    ``harness/<module>.py`` its file names under ``"shapes"``, else
+    ``harness/shapes.py``."""
+    return _load_named(__package__, config, "shapes", "shapes",
+                       SHAPES_INTERFACE)
+
+
+def reference_of(config: dict):
+    """``config``'s plain reference: the ``reference/<module>.py`` its
+    file names under ``"reference"``, else ``reference/forward.py``. It
+    has ``logits_at(cfg, params, tokens, positions)``."""
+    return _load_named("reference", config, "reference", "forward",
+                       REFERENCE_INTERFACE)

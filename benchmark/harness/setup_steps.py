@@ -31,6 +31,28 @@ def reachable_buckets(config: dict, mix: dict) -> list:
     return out
 
 
+def chunk_path_lengths(config: dict, mix: dict) -> list:
+    """Prompt lengths of the mix that are over the largest prefill bucket,
+    one for each SMALLER bucket the last chunk of such a prompt can land
+    in, longest first; none for a mix that stays inside its buckets. The
+    program cuts a longer prompt into chunks of the largest bucket and
+    pads the rest to the bucket that holds it, one executable a bucket.
+    Every such prompt runs a full chunk first, so a last chunk that lands
+    in the largest bucket is no executable of its own; where every one
+    does, the mix's longest prompt alone warms the full chunk."""
+    buckets = sorted(int(x) for x in config["prefill_buckets"])
+    top = buckets[-1]
+    lo, hi = int(mix["prompt_tokens"]["min"]), int(mix["prompt_tokens"]["max"])
+    out, seen = [], {top}
+    for n in range(hi, max(lo, top + 1) - 1, -1):
+        last = (n - 1) % top + 1
+        bucket = next(b for b in buckets if b >= last)
+        if bucket not in seen:
+            seen.add(bucket)
+            out.append(n)
+    return out or ([hi] if hi > top else [])
+
+
 def _complete(port: int, model: str, prompt: "str | list",
               max_tokens: int, waited: list) -> None:
     client.stream_docs(
@@ -47,8 +69,10 @@ def compiles(server_port: int) -> float:
 
 def load_shapes(router_port: int, server_port: int, model: str,
                 config: dict, mix: dict, waited: list) -> dict:
-    """One lone request and one burst for each reachable bucket: these
-    compile the big executables, or load them from the persistent cache."""
+    """One lone request and one burst for each reachable bucket, then,
+    for a mix whose prompts outgrow the largest bucket, one lone request
+    for each executable of the chunk path: these compile the big
+    executables, or load them from the persistent cache."""
     warm = mix["warmup"]
     shapes = reachable_buckets(config, mix)
     counts = [compiles(server_port)]
@@ -80,13 +104,20 @@ def load_shapes(router_port: int, server_port: int, model: str,
     # burst at a moment when the pipeline has just drained): two of four
     # runs met it inside or just after their window and lost 17-20 s to
     # the re-trace (my chip run, PR 23)
-    _complete(router_port, model,
-              [random_text(rng, shapes[0][1])
-               for _ in range(int(warm["burst"]))],
-              int(warm["output_tokens"]), waited)
+    # (a mix wholly over its buckets has no multi-row prefill to reach)
+    if shapes:
+        _complete(router_port, model,
+                  [random_text(rng, shapes[0][1])
+                   for _ in range(int(warm["burst"]))],
+                  int(warm["output_tokens"]), waited)
+    # last, so that a mix inside its buckets sends what it always sent
+    chunked = chunk_path_lengths(config, mix)
+    for length in chunked:
+        _complete(router_port, model, random_text(rng, length),
+                  int(warm["output_tokens"]), waited)
     counts.append(compiles(server_port))
     return {"jit_compiles_before_and_after": counts,
-            "shapes": [list(s) for s in shapes]}
+            "shapes": [list(s) for s in shapes], "chunk_path": chunked}
 
 
 def storms(router_port: int, server_port: int, model: str, mix: dict,
@@ -285,4 +316,27 @@ def check_outputs(port: int, model: str, config_name: str, config: dict,
         for v in vals)
     report["repeat_identical"] = a == b
     report["correct"] = bool(ok and a == b and report["finite"])
+    report["tolerance_nats"] = float(golden["tolerance"]["nats"]) if golden \
+        else None
     return report
+
+
+def compared(report: dict) -> dict:
+    """Every number ``check_outputs`` compared, beside its limit, under
+    short plain names: per golden prompt the largest difference in nats
+    between a served and a reference log-probability over the ids asked
+    for (``None`` where an id got no answer) and whether the server
+    counted the reference's prompt tokens; then the two self-consistency
+    checks. ``better`` says which side of the limit passes."""
+    tol = report.get("tolerance_nats")
+    out = {}
+    for p in report["prompts"]:
+        out[f"{p['name']}.nats"] = {"value": p["max_abs_diff"],
+                                    "limit": tol, "better": "lower"}
+        out[f"{p['name']}.tokens_ok"] = {
+            "value": int(p["prompt_tokens_ok"]), "limit": 1,
+            "better": "higher"}
+    for key in ("repeat_identical", "finite"):
+        out[key] = {"value": int(report[key]), "limit": 1,
+                    "better": "higher"}
+    return out

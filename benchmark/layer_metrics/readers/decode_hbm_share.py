@@ -1,12 +1,13 @@
 """The decode step's share of the HBM roofline in the traced window: the
 bytes one token step must read (every layer matrix and the output head
 once, plus the cached keys and values of the sequences decoding, from
-``harness/shapes.py``) over the peak bandwidth, over the measured device
+the shape counts the cell's configuration names: ``harness/shapes.py``
+where it names none) over the peak bandwidth, over the measured device
 time per token step. Batch and contexts are the client's own count of the
 streams that were between their first and last token, sampled every 50 ms
 of the traced window."""
 
-from harness import manifest, shapes
+from harness import manifest
 
 
 def decoding_at(records, t: float):
@@ -35,6 +36,7 @@ def read(ctx, module_regex: str):
         return None
     batch = sum(b for b, _ in samples) / len(samples)
     ctx_sum = sum(c for _, c in samples) / len(samples)
+    shapes = manifest.shapes_of(cfg)
     need_s = (shapes.decode_step_bytes(cfg, batch, ctx_sum)
               / ctx.peaks["hbm_bytes_s"])
     return 100.0 * need_s / step_s

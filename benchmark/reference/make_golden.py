@@ -3,8 +3,11 @@
 
     python3 benchmark/reference/make_golden.py <config> [--out FILE]
 
-Imports of the program: its registry entry and its seeded weight generator
-(``ops/quant.random_quantized_params``), nothing else. The weights' seed is
+The reference is the module the configuration's file names under
+``"reference"`` (``reference/<module>.py`` with ``logits_at``), else
+``forward.py``. Imports of the program: its registry entry and the seeded
+weight generator of what the configuration is served as (``served_as.
+weights``: ``SEEDED_WEIGHTS`` below), nothing else. The weights' seed is
 the program's own fixed one, so one golden file serves every ``--seed``.
 Run it on the device the configuration fits on (a 7B model: the chip).
 """
@@ -27,6 +30,50 @@ TOP = 20
 # what the byte tokenizer (served under --random-weights) makes of one
 # chat message: BOS, then the bytes of <user>content</user>
 BOS = 256
+
+
+def _int8(cfg):
+    from llms_on_kubernetes_tpu.ops.quant import random_quantized_params
+    return random_quantized_params(cfg, 0, dtype="bfloat16")
+
+
+def _bfloat16(cfg):
+    # the call engine/engine.py makes for ``serve --random-weights`` with
+    # no ``--quantization``, with EngineConfig's seed (0) and --dtype
+    import jax
+    from llms_on_kubernetes_tpu.models.decoder import init_params
+    return init_params(cfg, jax.random.key(0), dtype="bfloat16")
+
+
+# served_as.weights -> (the tree ``serve --random-weights`` builds for it,
+# what the golden file's "weights" string says of it). The reference
+# dequantizes or widens one layer at a time: never a float32 copy of a
+# whole layer stack.
+SEEDED_WEIGHTS = {
+    "int8": (_int8,
+             "llms_on_kubernetes_tpu.ops.quant."
+             "random_quantized_params(cfg, seed=0, dtype=bfloat16), "
+             "int8 matrices dequantized with their scales"),
+    "bfloat16": (_bfloat16,
+                 "llms_on_kubernetes_tpu.models.decoder.init_params(cfg, "
+                 "jax.random.key(0), dtype=bfloat16), widened to float32 "
+                 "a layer at a time"),
+}
+
+
+def seeded_weights(config: dict):
+    """(params, description) of the seeded weights ``config`` is served
+    with; a type nothing generates is an error, not a fallback."""
+    from harness import manifest
+    from llms_on_kubernetes_tpu.configs import get_config
+
+    kind = config["served_as"]["weights"]
+    if kind not in SEEDED_WEIGHTS:
+        raise manifest.ManifestError(
+            f"no seeded weights for served_as.weights {kind!r}: "
+            f"{sorted(SEEDED_WEIGHTS)}")
+    make, said = SEEDED_WEIGHTS[kind]
+    return make(get_config(config["registry_name"])), said
 
 
 def chat_token_ids(content: str) -> list:
@@ -61,17 +108,14 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from harness import manifest
-    from reference.forward import logits_at
-    from llms_on_kubernetes_tpu.configs import get_config
-    from llms_on_kubernetes_tpu.ops.quant import random_quantized_params
 
     config = manifest.load_json("configs", f"{args.config}.json")
-    params = random_quantized_params(
-        get_config(config["registry_name"]), 0, dtype="bfloat16")
+    reference = manifest.reference_of(config)
+    logits_at = reference.logits_at
+    params, weights = seeded_weights(config)
     dev = jax.devices()[0]
     done: dict = {}
     rows = []
@@ -100,12 +144,11 @@ def main() -> int:
         rows.append(dict(p, **done[p["content"]]))
     doc = {
         "config": args.config,
-        "reference": "benchmark/reference/forward.py: float32 jax.numpy, "
-                     "matmul precision highest, no cache, no batching",
+        "reference": f"benchmark/{reference.__name__.replace('.', '/')}.py: "
+                     "float32 jax.numpy, matmul precision highest, no "
+                     "cache, no batching",
         "made_on": {"platform": dev.platform, "kind": dev.device_kind},
-        "weights": "llms_on_kubernetes_tpu.ops.quant."
-                   "random_quantized_params(cfg, seed=0, dtype=bfloat16), "
-                   "int8 matrices dequantized with their scales",
+        "weights": weights,
         "tolerance": {"nats": args.tolerance, "why": args.why},
         "prompts": rows,
     }

@@ -5,12 +5,17 @@ import re
 
 import pytest
 
-from harness import manifest, shapes
+from harness import manifest, setup_steps, shapes, shapes_moe
 
 BENCH = manifest.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
+# cells and configurations of the CPU rehearsals: files beside the
+# benchmark's own, never entries of BENCHMARK.json
+REHEARSALS = ["debug-tiny.rehearse", "debug-tiny.rehearse-long",
+              "debug-moe.rehearse"]
+REHEARSAL_CONFIGS = {"debug-tiny": shapes, "debug-moe": shapes_moe}
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
 
 
@@ -74,12 +79,62 @@ def test_every_cell_finds_its_files_and_reports_enough(cell):
         for key in ("layer", "unit", "source", "moves"):
             assert spec[key] == m[key], (m["name"], key)
         assert callable(manifest.load_reader("per_layer", spec["reader"]).read)
-    mix = loaded.mix
-    # the longest prompt plus the longest answer fits a slot
+    assert_fits_a_slot(loaded)
+
+
+def assert_fits_a_slot(loaded):
+    """The longest prompt plus the longest answer fits a slot. (A prompt
+    may be longer than the largest prefill bucket: it takes the chunk
+    path, which set-up warms; ``setup_steps.chunk_path_lengths``.)"""
     flags = loaded.config["serve_flags"]
     slot = int(flags["--page-size"]) * int(flags["--pages-per-slot"])
+    mix = loaded.mix
     assert mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"] <= slot
-    assert mix["prompt_tokens"]["max"] <= max(loaded.config["prefill_buckets"])
+
+
+@pytest.mark.parametrize("cell", REHEARSALS)
+def test_a_rehearsal_cell_finds_its_files_and_is_no_entry(cell):
+    loaded = manifest.load_cell(cell)
+    assert cell not in CELLS and loaded.cell["config"] not in {
+        c["name"] for c in BENCH["configs"]}
+    assert loaded.config["platform"] == "cpu" and loaded.rate_rps > 0
+    assert_fits_a_slot(loaded)
+    manifest.shapes_of(loaded.config)
+    manifest.reference_of(loaded.config)
+    assert manifest.load_json("golden", f"{loaded.cell['config']}.json")[
+        "config"] == loaded.cell["config"]
+
+
+def test_the_long_rehearsal_outgrows_the_largest_bucket():
+    loaded = manifest.load_cell("debug-tiny.rehearse-long")
+    top = max(loaded.config["prefill_buckets"])
+    assert loaded.mix["prompt_tokens"]["max"] > top
+    # one lone prompt for each executable of the chunk path: 400 = 3 x 128
+    # + 16 runs full chunks of 128 and ends in the bucket of 32; 384 = 3 x
+    # 128 would run the full chunk's executable again and is not sent
+    assert setup_steps.chunk_path_lengths(loaded.config, loaded.mix) == [400]
+    assert [b for b, _ in setup_steps.reachable_buckets(
+        loaded.config, loaded.mix)] == [32, 128]
+
+
+@pytest.mark.parametrize("cell", CELLS + ["debug-tiny.rehearse",
+                                          "debug-moe.rehearse"])
+def test_a_mix_inside_its_buckets_warms_no_chunk_path(cell):
+    loaded = manifest.load_cell(cell)
+    assert setup_steps.chunk_path_lengths(loaded.config, loaded.mix) == []
+
+
+@pytest.mark.parametrize("lo,hi,want", [
+    (512, 3584, [3328]),         # 3 x 1024 + 256; 3584 ends in a full chunk
+    (1100, 1200, [1200]),        # every last chunk lands in the bucket of 256
+    (32, 1025, [1025]),          # one token over: a last chunk of one
+    (2048, 2048, [2048]),        # full chunks only: the longest warms them
+    (32, 1024, []),
+])
+def test_chunk_path_lengths_one_for_each_last_chunk_bucket(lo, hi, want):
+    config = {"prefill_buckets": [256, 1024]}
+    mix = {"prompt_tokens": {"min": lo, "max": hi}}
+    assert setup_steps.chunk_path_lengths(config, mix) == want
 
 
 @pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
@@ -89,12 +144,55 @@ def test_configurations(cfg):
     doc = manifest.load_json("configs", f"{cfg['name']}.json")
     assert doc["source"] == cfg["source"] and doc["reduced"] == cfg["reduced"]
     assert any(w["config"] == cfg["name"] for w in BENCH["workloads"])
-    # the bytes the file expects are the bytes its shapes give
-    assert shapes.weight_bytes(doc) == doc["expected_bytes"]["weights"]
-    assert shapes.pool_bytes(doc) == doc["expected_bytes"]["pool"]
+    assert_expected_bytes_and_flags(doc)
+
+
+def assert_expected_bytes_and_flags(doc):
+    # the bytes the file expects are the bytes ITS shape counts give: the
+    # module it names under "shapes", harness/shapes.py where it names none
+    counts = manifest.shapes_of(doc)
+    assert counts.weight_bytes(doc) == doc["expected_bytes"]["weights"]
+    assert counts.pool_bytes(doc) == doc["expected_bytes"]["pool"]
     flags = " ".join(doc["serve_flags"])
     assert ",".join(map(str, doc["prefill_buckets"])) == \
         doc["serve_flags"]["--prefill-buckets"] and "--model" not in flags
+
+
+@pytest.mark.parametrize("name", sorted(REHEARSAL_CONFIGS))
+def test_rehearsal_configurations(name):
+    doc = manifest.load_json("configs", f"{name}.json")
+    assert manifest.shapes_of(doc) is REHEARSAL_CONFIGS[name]
+    assert_expected_bytes_and_flags(doc)
+    if name == "debug-moe":
+        # the dense count sees one feed-forward network and no router: a
+        # file held to it could only write down bytes that are false
+        assert doc["shapes"] == "shapes_moe" and doc["reference"] == "moe"
+        assert shapes.weight_bytes(doc) == 126976
+        assert doc["expected_bytes"]["weights"] == 238592
+    else:
+        assert "shapes" not in doc and "reference" not in doc
+
+
+def test_the_default_modules_are_the_ones_the_benchmark_has():
+    doc = manifest.load_json("configs", "mistral-7b.json")
+    assert "shapes" not in doc and "reference" not in doc
+    assert manifest.shapes_of(doc) is shapes
+    from reference import forward
+    assert manifest.reference_of(doc) is forward
+
+
+@pytest.mark.parametrize("key,named", [
+    ("shapes", "no_such_module"), ("shapes", "../shapes"), ("shapes", ""),
+    ("shapes", 7), ("shapes", "peaks"),          # a file, not shape counts
+    ("reference", "no_such_module"), ("reference", "../forward"),
+    ("reference", "make_golden"),                # a file, no ``logits_at``
+])
+def test_a_module_that_is_not_there_is_a_manifest_error(key, named):
+    """Not an ImportError, and not a module of another kind."""
+    load = {"shapes": manifest.shapes_of,
+            "reference": manifest.reference_of}[key]
+    with pytest.raises(manifest.ManifestError):
+        load({key: named})
 
 
 def test_the_harness_names_no_cell_configuration_or_mix():

@@ -1,12 +1,13 @@
 """Every per-layer metric's reader on a hand-made context: what it reads,
 and that it returns nothing (not zero) where there is nothing to read."""
 
+import os
 import time
 
 import pytest
 
-from harness import manifest, shapes
-from harness.cell import Context
+from harness import manifest, shapes, shapes_moe, xplane
+from harness.cell import Context, traced_window_s
 from harness.client import Record
 from harness.peaks import PEAKS
 
@@ -93,6 +94,78 @@ def test_decode_hbm_share_is_needed_bytes_over_peak_over_step_time():
     assert 50 < got < 65        # 7.5 GB of weights alone are 9.2 ms
 
 
+def on_the_recorded_trace(cell_name, batch):
+    """``decode_hbm_share`` of ``cell_name`` over tests/data/tiny.xplane.pb
+    (recorded on the chip: three dispatches of ``jit_tiny_matmul_step``,
+    13.434 us together, read as this configuration's fused decode step)
+    with ``batch`` streams of 101 tokens decoding through the capture.
+    Every time is a constant, so the reading is the same digits each run."""
+    trace = xplane.reduce(xplane.load(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "data",
+        "tiny.xplane.pb")))
+    records = [rec(i, 1000.0, [(1000.5, 1), (1004.0, 1)], part="tail")
+               for i in range(batch)]
+    ctx = context(cell=manifest.load_cell(cell_name), records=records,
+                  trace=trace, trace_window=(1001.0, 1003.0))
+    return manifest.load_reader("per_layer", "decode_hbm_share").read(
+        ctx, "tiny_matmul_step")
+
+
+@pytest.mark.parametrize("batch,parent_read", [(1, 791247.217573256),
+                                               (8, 801360.4503580385)])
+def test_decode_hbm_share_of_the_dense_default_is_what_the_parent_read(
+        batch, parent_read):
+    """To the last digit: the numbers are those of the reader as it stood
+    before configurations could name their shape counts (PR 27's tree, the
+    same context), when it imported ``harness/shapes.py`` itself."""
+    assert "shapes" not in CELL.config
+    assert on_the_recorded_trace("mistral-7b.chat", batch) == parent_read
+
+
+@pytest.mark.parametrize("batch,layer_bytes,step_bytes", [
+    # one layer: attention 64 x 16 x (2 x 4 + 2 x 2) = 12288 int8 bytes,
+    # router 64 x 4 bf16 = 512, an expert 3 x 64 x 96 = 18432 int8 bytes;
+    # a step of 1 row touches 4 (1 - 1/2) = 2 experts of 4, one of 8 rows
+    # 4 (1 - 1/2^8) = 3.984375. A step: 2 layers, the head 256 x 64 bf16 =
+    # 32768, 128 an embedding row, 256 a cached token (2 x 2 x 2 x 16 bf16)
+    (1, 12288 + 512 + 2 * 18432,
+     2 * 49664 + 32768 + 128 + 101 * 256),
+    (8, 12288 + 512 + 3.984375 * 18432,
+     2 * 86240 + 32768 + 8 * 128 + 8 * 101 * 256),
+])
+def test_decode_hbm_share_follows_the_configurations_own_shapes(
+        batch, layer_bytes, step_bytes):
+    cfg = manifest.load_cell("debug-moe.rehearse").config
+    assert cfg["shapes"] == "shapes_moe"
+    assert shapes_moe._layer_bytes(
+        cfg, shapes_moe.experts_touched(cfg, batch)) == layer_bytes
+    assert shapes_moe.decode_step_bytes(cfg, batch, 101 * batch) == step_bytes
+    assert step_bytes == {1: 158080, 8: 413120}[batch]
+    step_s = 13.434e-6 / 3 / 4          # three dispatches of K = 4 steps
+    got = on_the_recorded_trace("debug-moe.rehearse", batch)
+    assert got == pytest.approx(100 * step_bytes / 819e9 / step_s, rel=1e-12)
+    # and not what the dense count would have given the same file
+    dense = shapes.decode_step_bytes(cfg, batch, 101 * batch)
+    assert dense < step_bytes
+    assert got != pytest.approx(100 * dense / 819e9 / step_s, rel=0.1)
+
+
+def test_moe_shapes_count_held_routed_and_touched_experts():
+    cfg = manifest.load_json("configs", "debug-moe.json")
+    assert shapes_moe.weight_bytes(cfg) == 2 * (12288 + 512 + 4 * 18432) \
+        + 2 * 32768 == 238592
+    assert shapes_moe.kv_bytes_per_token(cfg) == 256
+    assert shapes_moe.pool_bytes(cfg) == 256 * 64 * 64
+    assert shapes_moe.experts_touched(cfg, 0) == 0.0
+    assert shapes_moe.experts_touched(cfg, 1) == 2.0       # k of them
+    assert 3.99 < shapes_moe.experts_touched(cfg, 32) < 4.0
+    # a token computes with its k = 2 experts, whatever the batch
+    per_token = 2 * (12288 + 256 + 2 * 18432) + 256 * 64
+    assert shapes_moe.decode_step_flops(cfg, 8, 0) == 2.0 * per_token * 8
+    assert shapes_moe.prefill_flops(cfg, 1) == 2.0 * (
+        per_token + 2 * 4 * 16 * 2 * 1)
+
+
 @pytest.mark.parametrize("name,empty", [
     ("compiles_in_window", dict(after=[], before=[])),
     ("decode_occupancy", dict(polls=[])),
@@ -111,6 +184,24 @@ def test_shapes_give_the_bytes_the_server_reports():
     cfg = manifest.load_json("configs", "mistral-7b.json")
     assert shapes.kv_bytes_per_token(cfg) == 131072
     assert shapes.weight_bytes(cfg) == 7503609856     # PR 21's expected
+    assert shapes.pool_bytes(cfg) == 6450839552
+    # and through the loader, which gives this file the dense counts
+    assert manifest.shapes_of(cfg) is shapes
     # a window clips what a long context reads
     assert shapes.attended(cfg, 5000) == 4096
     assert shapes.prefill_flops(cfg, 1024) > 2 * 7e9 * 1024
+
+
+@pytest.mark.parametrize("asked, spans, want", [
+    (1.5, [1.42], 1.5),             # idle at the edges is the window's
+    (1.5, [1.500823604], 1.500823604),  # a chip that never idles: stopping
+    (1.5, [1.2, 1.5004, 1.49], 1.5004),  # the profiler took a moment more
+])
+def test_the_traced_window_is_never_shorter_than_what_the_device_showed(
+        asked, spans, want):
+    devs = {f"/device:TPU:{i}": {"span_s": s, "busy_s": s}
+            for i, s in enumerate(spans)}
+    window = traced_window_s(asked, devs)
+    assert window == want
+    busy = [d["busy_s"] for d in devs.values()]
+    assert 0 < sum(busy) / len(busy) <= window
