@@ -2934,17 +2934,12 @@ def make_configs():
             pages_per_slot=512 // page,
             num_pages=slots * (512 // page) + 1,
             prefill_buckets=(64,),
-            # deep READ pipeline: 8 unharvested steps keep device->host
-            # reads overlapped while the harvester threads wait them out
-            # (chosen where a read cost ~100 ms; ROADMAP S5 re-measures)
+            # deep READ pipeline: up to 8 unharvested steps (chosen where
+            # a read cost ~100 ms; ROADMAP S5 re-measures). The cap alone:
+            # WHEN a step is launched the engine times against the
+            # device's queue itself (Engine._decode_due), which is what
+            # bounds the work a new request's prefill waits behind
             async_depth=int(os.environ.get("BENCH_DEPTH", "8")),
-            # device-queue pacing: bounds the work a new request's prefill
-            # dispatch waits behind — the round-3 TTFT regression was an
-            # unbounded device queue at depth 8. The READ pipeline
-            # (async_depth) stays deep; only the dispatch gets deferred
-            # when the device already holds this many step-times of undone
-            # work. Default tuned on the v5e: see BENCH_r04 sweep.
-            pace_target_steps=float(os.environ.get("BENCH_PACE", "3")),
             # int8 KV cache (opt-in: BENCH_KV=int8, with BENCH_PAGE=128 for
             # the Mosaic-aligned kernel path): halves decode-attention HBM
             # traffic and doubles token capacity. At THIS bench's short
@@ -3216,7 +3211,6 @@ def _main() -> int:
         **trc,
         "batch": ecfg.max_decode_slots,
         "quantization": ecfg.quantization,
-        "pace_target_steps": ecfg.pace_target_steps,
         "async_depth": ecfg.async_depth,
         "decode_steps": ecfg.decode_steps,
         "platform": platform,

@@ -696,8 +696,9 @@ def grafana_dashboard() -> dict[str, Any]:
         _panel(34, "Tracing: traces dropped (by reason)",
                ["sum by (reason) "
                 "(rate(llm_trace_dropped_total[5m]))"], 12, 128),
-        _panel(35, "Dispatch waits by kind: behind earlier dispatches / "
-               "host enqueue, seconds per dispatch",
+        _panel(35, "Dispatch waits by kind: behind earlier dispatches "
+               "(a prefill: about half a decode window) / host enqueue, "
+               "seconds per dispatch",
                ["sum by (kind) "
                 "(rate(llm_dispatch_behind_seconds_total[5m])) / "
                 "sum by (kind) (rate(llm_dispatches_total[5m]))",
@@ -705,6 +706,10 @@ def grafana_dashboard() -> dict[str, Any]:
                 "(rate(llm_dispatch_enqueue_seconds_total[5m])) / "
                 "sum by (kind) (rate(llm_dispatches_total[5m]))"],
                0, 136, unit="s"),
+        _panel(36, "Decode launches by rule (late = the device was "
+               "already free: late over all is the miss rate)",
+               ["sum by (when) (rate(llm_decode_launches_total[5m]))"],
+               12, 136),
     ]
     return {
         "title": "LLM serving on TPU — cluster overview",

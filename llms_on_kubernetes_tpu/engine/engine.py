@@ -42,6 +42,7 @@ from llms_on_kubernetes_tpu.engine import jit_events
 from llms_on_kubernetes_tpu.engine.cache import (
     CacheConfig, HostKVCache, PageAllocator, init_pages,
 )
+from llms_on_kubernetes_tpu.engine.ledger import DECODE_LAUNCH_RULES
 from llms_on_kubernetes_tpu.engine.qos import (
     TenantFairQueue, normalize_priority, priority_rank,
 )
@@ -61,6 +62,11 @@ Params = dict[str, Any]
 # shows what the host did in each device gap. Outside a capture an
 # annotation costs well under a microsecond.
 _phase = jax.profiler.TraceAnnotation
+
+# a timed launch aims at least this far ahead of the device running dry
+_LEAD_FLOOR_S = 0.004
+# the lead falls by this share of its distance to what a launch needed
+_LEAD_DECAY = 1.0 / 16.0
 
 
 class EngineStallError(RuntimeError):
@@ -146,7 +152,12 @@ class EngineConfig:
     # admissions and their prefills dispatch immediately (vLLM-style
     # async scheduling, re-done for JAX's dispatch model), and a first
     # token whose read lands during that wait is handed to its request
-    # from inside it. Finishes/stop tokens are detected a
+    # from inside it. async_depth is the CAP on unharvested steps (it
+    # bounds speculation on finishes); WHEN the next steady-state step is
+    # launched is timed against the device's queue (Engine._decode_due):
+    # a new request's prefill waits behind whatever is enqueued, so a
+    # step is enqueued a measured lead before the device would run dry
+    # and not as soon as the cap has room. Finishes/stop tokens are detected a
     # transfer-latency late; the speculative extra steps are harmless
     # (their writes land in pages that are only reused after
     # device-ordered completion). Works under multihost too: the packed
@@ -154,14 +165,6 @@ class EngineConfig:
     # feeds each merge.
     async_scheduling: bool = True
     async_depth: int = 2
-    # device-queue pacing (0 = off): don't dispatch a decode step when the
-    # estimated undone device work already exceeds this many step-times.
-    # The pipeline cap (async_depth) bounds SPECULATION; this bounds the
-    # DEVICE QUEUE — the thing a newly admitted request's prefill waits
-    # behind. Set to ~(one-way dispatch latency / step time) + 1..2: big
-    # enough that the device never starves, small enough that TTFT ≈ a
-    # couple of step times + prefill + read latency.
-    pace_target_steps: float = 0.0
     # async admission: up to this many same-bucket waiting requests prefill
     # together in one [K, bucket] call (padded to exactly 1 or admit_batch
     # rows so each bucket compiles two executables, not one per K)
@@ -280,7 +283,8 @@ class EngineConfig:
     # goodput ledger (engine/ledger.py): per-request chip-time attribution
     # across prefill/decode/spec_waste/early_exit, MFU/MBU accounting, and
     # the step-time anomaly detector. None => env LLMK_LEDGER (default on).
-    # Off restores the exact pre-ledger hot path (no per-dispatch booking).
+    # Off leaves the dispatch timeline alone (segments and per-shape device
+    # times, which the scheduler's launch timing reads): no attribution.
     ledger: Optional[bool] = None
     # anomaly-triggered auto-profiling: when the ledger's EWMA + z-score
     # detector sees a sustained per-dispatch slowdown, the serving loop
@@ -687,14 +691,17 @@ class _Harvester(threading.Thread):
         return done[1] if done else time.monotonic()
 
     def wait_done(self, seq: int, wake: Optional[threading.Event] = None,
-                  keys: tuple = (), timeout_s: Optional[float] = None) -> None:
+                  keys: tuple = (), timeout_s: Optional[float] = None,
+                  until: Optional[float] = None) -> None:
         """Block until step ``seq`` is done — or one of the first-token
         ``keys`` is (the caller hands that token over and waits again) —
         or, if ``wake`` is given, until it is set (a new submission wants
         admission NOW — submit() pokes this cv; the caller re-enters its
-        loop and the next step() admits before waiting again). With
-        ``timeout_s`` (the engine's watchdog budget) raises
-        EngineStallError if the step is still incomplete at the deadline."""
+        loop and the next step() admits before waiting again) — or the
+        monotonic clock reaches ``until`` (the moment the caller means to
+        launch the next step at). With ``timeout_s`` (the engine's
+        watchdog budget) raises EngineStallError if the step is still
+        incomplete at the deadline."""
         deadline = (None if timeout_s is None
                     else time.monotonic() + timeout_s)
         with self._cv:
@@ -704,27 +711,31 @@ class _Harvester(threading.Thread):
                     return
                 if any(k in self._done for k in keys):
                     return
-                if deadline is None:
-                    self._cv.wait()
-                    continue
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
+                now = time.monotonic()
+                if until is not None and now >= until:
+                    return
+                if deadline is not None and now >= deadline:
                     raise EngineStallError(
                         f"device step {seq} produced no completion within "
                         f"{timeout_s:.1f}s watchdog budget")
-                self._cv.wait(timeout=min(remaining, 1.0))
+                waits = [t - now for t in (until, deadline) if t is not None]
+                # (the 1 s cap re-checks a reader's error)
+                self._cv.wait(timeout=min(waits + [1.0]) if waits else None)
 
     def poke(self) -> None:
         """Wake any wait_done(wake=...) waiter (called from submit())."""
         with self._cv:
             self._cv.notify_all()
 
-    def wait_key(self, key: int, timeout_s: Optional[float] = None) -> None:
+    def wait_key(self, key: int, timeout_s: Optional[float] = None,
+                 wake: Optional[threading.Event] = None) -> None:
         deadline = (None if timeout_s is None
                     else time.monotonic() + timeout_s)
         with self._cv:
             while key not in self._done:
                 self._check_error()
+                if wake is not None and wake.is_set():
+                    return
                 if deadline is None:
                     self._cv.wait()
                     continue
@@ -1511,6 +1522,17 @@ class Engine:
             # a dispatch during which the process compiled, or fetched an
             # executable from its persistent cache, re-traced its step
             jit_events.install()
+        # every dispatch launched and not yet booked, in launch order, and
+        # the device time each shape last took: the ledger's own records
+        # where it is on, the bare timeline where it is off
+        self.timeline = self.ledger
+        if self.timeline is None:
+            from llms_on_kubernetes_tpu.engine.ledger import DispatchTimeline
+
+            self.timeline = DispatchTimeline()
+        # the clock launches are stamped and timed on (a test's to replace,
+        # with the harvester whose completions it stamps)
+        self._clock = time.monotonic
         # one number per device dispatch, in launch order: the ledger's
         # record, the seq of its llmk.dispatch trace annotation and (for a
         # prefill, as -1 - seq) the key of its first-token read
@@ -1616,12 +1638,22 @@ class Engine:
         self._g_class_h = None           # host [G, vocab] int16
         self._g_trans_h = None           # host [S_cap, C_cap] int16
         self._g_dev = None               # (class_of, trans) device arrays
-        # pacing state: EMA of the device step time (measured from harvest
-        # completion spacing — in steady state the loop is device-paced)
-        # and the estimated wall time when all dispatched work completes
+        # the device step time, measured from harvest completion spacing
+        # (in steady state the loop is device-paced): the watchdog's
+        # budget and the API's Retry-After read it
         self._est_step = 0.02
-        self._busy_until = 0.0
         self._last_harvest_t: Optional[float] = None
+        # how long before the device runs dry a steady-state decode step
+        # is launched (_decode_due): what a launch was seen to need, from
+        # the moment aimed at to the enqueue returning (the thread waking
+        # late, the step() around it, packing, the jitted call) and by
+        # how much the device was free sooner than estimated. Tracked from
+        # ABOVE (_note_launch), the estimates' rule mirrored: both err
+        # towards launching early
+        self._lead = _LEAD_FLOOR_S
+        # decode steps launched, by the rule that launched each; the
+        # serving loop drains it into llm_decode_launches_total{when}
+        self.decode_launches = dict.fromkeys(DECODE_LAUNCH_RULES, 0)
         # watchdog: set by _shed_wedged() when a device step exceeded the
         # stall budget; a wedged engine rejects submissions (the server
         # flips readiness and a restart is the only recovery)
@@ -2052,17 +2084,15 @@ class Engine:
                     admitted = self._admit_async(events)
                 with _phase("llmk.pack"):
                     status = self._launch_decode_async(admitted, events)
+                # whatever the launch has to wait for (room in the
+                # pipeline; the moment a step is due, "early"; device work
+                # completing, "paced") is waited for in _harvest, where a
+                # submission or a landed first token ends the wait
                 with _phase("llmk.harvest"):
-                    events += self._harvest(drain=status == "idle")
+                    events += self._harvest(drain=status == "idle",
+                                            paced=status == "paced")
             except EngineStallError as e:
                 events += self._shed_wedged(str(e))
-                status = "idle"
-            if status == "paced" and not events and not self.waiting:
-                # nothing to do until device work completes; a bounded nap
-                # keeps the loop from burning the GIL the harvester needs
-                # (admissions arriving mid-nap wait <= 1 ms)
-                with _phase("llmk.wait"):
-                    time.sleep(0.001)
         else:
             with _phase("llmk.admit"):
                 events += self._admit_one()
@@ -2138,28 +2168,27 @@ class Engine:
         work is enqueued) with the host time the call took and whether the
         process re-traced meanwhile. Yields the dispatch's seq."""
         seq = next(self._dispatch_seq)
+        # without the ledger nothing is attributed to a request (rows) and
+        # nothing counts the process's compiles
         led = self.ledger
-        rec = None
-        if led is not None:
-            events = jit_events.count()
-            no_work, self._saw_no_work = self._saw_no_work, False
-            rec = led.open(seq, kind, name, shape, time.monotonic(),
-                           rows=rows, after_no_work=no_work)
+        events = jit_events.count() if led is not None else 0
+        no_work, self._saw_no_work = self._saw_no_work, False
+        rec = self.timeline.open(
+            seq, kind, name, shape, self._clock(),
+            rows=rows if led is not None else None, after_no_work=no_work)
         try:
             with _phase("llmk.dispatch", kind=kind, seq=seq):
                 yield seq
         except BaseException:
-            if rec is not None:
-                led.abandon(seq)
+            self.timeline.abandon(seq)
             raise
-        if rec is not None:
-            retraced = jit_events.count() != events
-            led.launched(rec, time.monotonic(), retraced)
-            if retraced:
-                from llms_on_kubernetes_tpu.server.tracing import jlog
+        retraced = led is not None and jit_events.count() != events
+        self.timeline.launched(rec, self._clock(), retraced)
+        if retraced:
+            from llms_on_kubernetes_tpu.server.tracing import jlog
 
-                jlog("dispatch_retraced", kind=kind, step=name, shape=shape,
-                     seq=seq, seconds=round(rec.enqueue_ms / 1000.0, 3))
+            jlog("dispatch_retraced", kind=kind, step=name, shape=shape,
+                 seq=seq, seconds=round(rec.enqueue_ms / 1000.0, 3))
 
     def _mh_send(self, op: int, **fields) -> None:
         """Announce the next device call to follower pods (no-op single-host).
@@ -2840,14 +2869,12 @@ class Engine:
                                            salt=req.cache_salt)
         if resumed:
             req.pending_token = req.output[-1]
-            if self.ledger is not None:
-                self.ledger.close(dseq, None)   # nobody reads a re-prefill
+            self.timeline.close(dseq, None)   # nobody reads a re-prefill
             return []
         t0 = time.perf_counter()
         host = HostSample(np.asarray(jax.device_get(pack)))
         self._device_time_s += time.perf_counter() - t0
-        if self.ledger is not None:
-            self.ledger.close(dseq, time.monotonic())
+        self.timeline.close(dseq, self._clock())
         first = int(host.tokens[0])
         req.pending_token = first
         return self._emit(req, first, _lp_entry(host, 0), first=True)
@@ -2943,8 +2970,7 @@ class Engine:
                 events.append(self._finish(req, "stalled"))
         self._inflight.clear()
         self._pending_first = []
-        if self.ledger is not None:
-            self.ledger.abandon()   # their reads will never come
+        self.timeline.abandon()   # their reads will never come
         return events
 
     def _note_admission(self, req: Request) -> None:
@@ -3092,9 +3118,8 @@ class Engine:
         t0 = time.perf_counter()
         host = HostSample(np.asarray(jax.device_get(pack)))
         self._device_time_s += time.perf_counter() - t0
-        if self.ledger is not None:
-            self.ledger.close(dseq, time.monotonic(),
-                              [(r, "decode", 1) for _i, r in active])
+        self.timeline.close(dseq, self._clock(),
+                            [(r, "decode", 1) for _i, r in active])
 
         events: list[StepEvent] = []
         for i, r in active:
@@ -3231,27 +3256,21 @@ class Engine:
             if req.images is not None and hit == 0:
                 pack, toks, dseq = self._dispatch_mm_prefill(
                     slot, req, prefill_tokens, led_rows)
-                n_chunks = 2  # image encode + prefill
             else:
                 # cache-hit remainder (pure text for multimodal hits) or
                 # an out-of-bucket text prompt
                 pack, toks, dseq = self._chunked_prefill(
                     slot, req, prefill_tokens, led_rows, start=hit)
-                n_chunks = -(-(len(prefill_tokens) - hit)
-                             // max(self.config.prefill_buckets))
             if req.cache_salt is not None:
                 self.allocator.register_prefix(slot, req.prompt,
                                                salt=req.cache_salt)
-            self._busy_until = (max(time.monotonic(), self._busy_until)
-                                + 2.0 * n_chunks * self._est_step)
             merge = {"toks": toks, "slots": {}}
             if resumed:
                 # no first-token read: the host knows the re-prefill done
                 # only when the decode step launched behind it is read
                 req.pending_token = req.output[-1]
                 merge["slots"][slot] = (True, req.output[-1], 0)
-                if self.ledger is not None:
-                    self.ledger.close(dseq, None)
+                self.timeline.close(dseq, None)
             else:
                 key = -1 - dseq
                 self._harvester.push(key, pack)
@@ -3295,8 +3314,6 @@ class Engine:
             )
         if new_state is not None:
             self._fsm_state = new_state
-        self._busy_until = (max(time.monotonic(), self._busy_until)
-                            + 2.0 * self._est_step)  # prefill ≈ 2 steps
         for slot, req, _resumed, _ptoks in picked:
             self.allocator.register_prefix(slot, req.prompt)
         key = None
@@ -3304,8 +3321,8 @@ class Engine:
             # a negative key: its read carries first tokens
             key = -1 - dseq
             self._harvester.push(key, pack)
-        elif self.ledger is not None:
-            self.ledger.close(dseq, None)   # nobody reads a re-prefill
+        else:
+            self.timeline.close(dseq, None)   # nobody reads a re-prefill
         merge = {"toks": toks, "slots": {}}
         for row, (slot, req, resumed, _ptoks) in enumerate(picked):
             if resumed:
@@ -3323,8 +3340,9 @@ class Engine:
         """Launch one decode step whose input tokens are assembled ON DEVICE
         from the newest in-flight step's output (continuing slots), host
         values (slots with no step in flight), and this step's prefill
-        (just-admitted slots). Returns "launched", "paced" (deliberately
-        deferred — the device queue is deep enough), or "idle"."""
+        (just-admitted slots). Returns "launched", "early" (not due yet:
+        _decode_due; _harvest waits out the rest), "paced" (nothing to
+        launch until device work completes), or "idle"."""
         if self.config.decode_steps > 1:
             if self._spec is not None:
                 st = self._launch_decode_spec(self.config.decode_steps,
@@ -3336,14 +3354,9 @@ class Engine:
         B = self.config.max_decode_slots
         max_len = self.config.max_model_len
 
-        pace = self.config.pace_target_steps
-        if pace > 0 and admitted is None and self._inflight:
-            # pacing: dispatching now would only deepen the queue a new
-            # request's prefill has to wait behind. (A step that just
-            # admitted always launches — its decode merges the prefill's
-            # sampled tokens.)
-            if self._busy_until - time.monotonic() > pace * self._est_step:
-                return "paced"
+        rule = self._decode_due(admitted)
+        if rule[0] is None:
+            return "early"
 
         # grow page tables; drain in-flight work, then preempt, on exhaustion.
         # inflight counts are computed ONCE per pass (a per-slot
@@ -3426,8 +3439,7 @@ class Engine:
                             planned={i: 1 for i, _r in active}, dseq=dseq)
         self._inflight.append(step)
         self._harvester.push(seq, pack)
-        now = time.monotonic()
-        self._busy_until = max(now, self._busy_until) + self._est_step
+        self._note_launch(*rule)
         return "launched"
 
     def _launch_decode_multi(self, K: int, admitted,
@@ -3446,10 +3458,9 @@ class Engine:
         B = self.config.max_decode_slots
         max_len = self.config.max_model_len
 
-        pace = self.config.pace_target_steps
-        if pace > 0 and admitted is None and self._inflight:
-            if self._busy_until - time.monotonic() > pace * self._est_step:
-                return "paced"
+        rule = self._decode_due(admitted)
+        if rule[0] is None:
+            return "early"
 
         # plan windows + grow page tables; drain in-flight work, then
         # preempt, on exhaustion (same recovery ladder as the K=1 path).
@@ -3550,8 +3561,7 @@ class Engine:
         self._inflight.append(step)
         self._unread_toks = toks
         self._harvester.push(seq, pack)
-        now = time.monotonic()
-        self._busy_until = max(now, self._busy_until) + self._est_step
+        self._note_launch(*rule)
         return "launched"
 
     def _launch_decode_spec(self, K: int, admitted,
@@ -3660,25 +3670,99 @@ class Engine:
                             spec=True, drafted=drafted, dseq=dseq)
         self._inflight.append(step)
         self._harvester.push(seq, pack)
-        now = time.monotonic()
-        self._busy_until = max(now, self._busy_until) + self._est_step
         return "launched"
 
-    def _harvest(self, drain: bool) -> list[StepEvent]:
+    def _decode_due(self, admitted=None) -> tuple[Optional[str], float, float]:
+        """When the next decode step is launched: ``(rule, due, window)``
+        with one of DECODE_LAUNCH_RULES if that is now, ``(None, due,
+        window)`` if not before ``due``.
+
+        A step that just admitted is launched at once ("admission"): it
+        merges the prefill's sampled tokens on the device. Otherwise
+        the device needs the next step when the work launched so far
+        ends, and a request that arrives meanwhile has its prefill
+        enqueued behind whatever is there: so the step is launched
+        ``_lead`` before the timeline's estimate of that end
+        (DispatchTimeline.free_at: the newest completion the harvester
+        has stamped plus the measured device times of what was launched
+        after it), and at the latest when the step ahead of it completes
+        (_harvest's wait ends there too). Where that cannot be timed, it
+        is launched as soon as the pipeline has room ("depth"): a shape
+        that never ran, a step no longer than two leads (a CPU engine; a
+        model with a 5 ms step), or multihost, whose followers mirror the
+        coordinator's launches and have not been run under a timed one."""
+        if admitted is not None:
+            return "admission", 0.0, 0.0
+        now = self._clock()
+        n = sum(r is not None for r in self.slots)
+        window = None if self.config.multihost else self.timeline.estimate(
+            "decode", f"{max(1, self.config.decode_steps)}x{n}")
+        if window is None or window <= 2.0 * self._lead:
+            return "depth", now, 0.0
+        hv = self._harvester
+        done = {s.dseq: hv.done_time(s.seq)
+                for s in self._inflight if hv.is_done(s.seq)}
+        for _req, key, _row in self._pending_first:
+            if hv.key_done(key):
+                done[-1 - key] = hv.done_time(key)
+        free = self.timeline.free_at(now, done)
+        if free is None:
+            return "depth", now, 0.0
+        t_free, busy = free
+        due = t_free - self._lead
+        if now < due and self._inflight:
+            return None, due, window
+        return ("timed" if busy else "late"), due, window
+
+    def _note_launch(self, rule: str, due: float, window: float) -> None:
+        """Count a decode launch under its rule, and move the lead to what
+        this launch needed: from the moment it aimed at (``due``, the
+        device's free time less the lead used) to now, the enqueue having
+        returned. That holds the thread's late wake, the step() around
+        it, packing and the jitted call, and for a "late" launch also by
+        how much the device was free sooner than estimated. The lead
+        rises to a larger need at once and falls to a smaller one slowly,
+        never under the floor (the estimates' rule, mirrored); a launch
+        that was not timed only lets it fall."""
+        self.decode_launches[rule] += 1
+        if rule == "admission":
+            return
+        need = _LEAD_FLOOR_S
+        if rule != "depth":
+            need = min(max(self._clock() - due, need), window)
+        self._lead += (need - self._lead) * (
+            1.0 if need > self._lead else _LEAD_DECAY)
+
+    def launch_view(self) -> dict:
+        """What times the next decode step, for ``GET /debug/engine``:
+        the lead, the device time each kind and shape last took, and the
+        launches so far by rule."""
+        return {"lead_ms": round(self._lead * 1000.0, 3),
+                "estimates_ms": self.timeline.estimates_view(),
+                "launches": dict(self.decode_launches)}
+
+    def _harvest(self, drain: bool, paced: bool = False) -> list[StepEvent]:
         """Consume host copies of completed device work from the harvester
         thread, in dispatch order, WITHOUT blocking on device execution.
 
         The engine thread blocks in exactly two cases: ``drain`` (state
         inspection / shutdown / memory pressure needs every result), and
-        backpressure (the pipeline holds ``async_depth`` unharvested decode
-        steps — launching more would speculate unboundedly). Everything
+        backpressure: the pipeline holds ``async_depth`` unharvested decode
+        steps (launching more would speculate unboundedly), or the next
+        one is not due yet (``_decode_due``: the wait then ends at that
+        moment, or when the step ahead completes), or there is nothing
+        to launch until some device work completes (``paced``: the wait
+        ends with the oldest result). Everything
         else — including admission of new requests and their prefill
         dispatch — proceeds while the harvester waits out the device and
-        the host read. This is what bounds gateway TTFT: a new
-        request's prefill no longer queues behind a blocking batched read
-        of the whole pipeline. A first token is handed to its request
+        the host read: a submission ends either wait at once. This is
+        what bounds gateway TTFT: a new request's prefill queues behind
+        what is on the device NOW, not behind a blocking batched read of
+        the whole pipeline nor behind a step enqueued before the device
+        needed it. A first token is handed to its request
         before the thread sleeps and whenever one lands during the sleep;
-        every other event leaves at the end of ``step()``."""
+        every other event leaves at the end of ``step()``, so the thread
+        does not sleep on any."""
         events: list[StepEvent] = []
         if not self._inflight and not self._pending_first:
             return events
@@ -3687,16 +3771,38 @@ class Engine:
         n_steps = 0
         while True:
             n_steps += self._collect_ready(events)
+            # what the wait is for: the k oldest steps in flight, and no
+            # later than `until`
+            until = None
             if drain:
                 if not self._inflight and not self._pending_first:
                     break
-            elif len(self._inflight) < depth:
-                break
+                k = len(self._inflight)
+            elif len(self._inflight) >= depth:
+                k = len(self._inflight) - (depth - 1)   # the one that makes room
+            elif paced:
+                if events or not (self._inflight or self._pending_first):
+                    break       # something completed: step() looks again
+                k = 1
+            else:
+                if not self._inflight:
+                    break
+                rule, until, _window = self._decode_due()
+                if rule is not None:
+                    break
+                # the launch comes no later than the completion of the
+                # step ahead of it
+                k = len(self._inflight)
             # blocked. A first token collected so far leaves the engine
             # NOW, not a decode window later when the wait is over
             for ev in events:
                 if ev.first:
                     self._hand_over(ev, "backpressure")
+            if until is not None and not all(ev.handed_over for ev in events):
+                # the pipeline has room and the next step is not due: the
+                # other events leave at the end of step(), then step()
+                # comes back here to wait
+                break
             # wait for whatever gates the head. If the oldest
             # step's request still awaits its FIRST token (its read
             # hasn't landed), wait for that key — consuming the step
@@ -3707,31 +3813,27 @@ class Engine:
                 with _phase("llmk.wait"):
                     self._harvester.wait_key(key, timeout_s=budget)
                 continue
-            if self._inflight:
-                k = (len(self._inflight) if drain
-                     else len(self._inflight) - (depth - 1))
-                # a first token landing ends the wait too: the loop
-                # collects it, hands it over above and comes back here.
-                # It does NOT leave for another round of step(), which
-                # would launch one more decode window for the next
-                # prefill to queue behind
-                with _phase("llmk.wait"):
-                    self._harvester.wait_done(
-                        self._inflight[k - 1].seq,
-                        wake=None if drain else self._admit_wake,
-                        keys=tuple(key for _, key, _ in self._pending_first),
-                        timeout_s=budget)
-                if not drain and self._admit_wake.is_set():
-                    # a submission wants admission NOW; collect whatever
-                    # completed and hand control back (pipeline may sit
-                    # one step over depth for one iteration)
-                    n_steps += self._collect_ready(events)
-                    break
-                continue
-            # drain with only firsts left
+            wake = None if drain else self._admit_wake
             with _phase("llmk.wait"):
-                self._harvester.wait_key(self._pending_first[0][1],
-                                         timeout_s=budget)
+                if self._inflight:
+                    # a first token landing ends the wait too: the loop
+                    # collects it, hands it over above and comes back
+                    # here. It does NOT leave for another round of step(),
+                    # which would launch one more decode window for the
+                    # next prefill to queue behind
+                    self._harvester.wait_done(
+                        self._inflight[k - 1].seq, wake=wake,
+                        keys=tuple(key for _, key, _ in self._pending_first),
+                        timeout_s=budget, until=until)
+                else:       # only firsts left
+                    self._harvester.wait_key(self._pending_first[0][1],
+                                             timeout_s=budget, wake=wake)
+            if wake is not None and wake.is_set():
+                # a submission wants admission NOW; collect whatever
+                # completed and hand control back (pipeline may sit
+                # one step over depth for one iteration)
+                n_steps += self._collect_ready(events)
+                break
         # pacing calibration: completion spacing per decode step bounds the
         # device step time from ABOVE (reads add latency, never remove it),
         # so track the MINIMUM with slow upward drift. A mean/EMA here is
@@ -3792,8 +3894,7 @@ class Engine:
             self._pending_first = still
             done_keys = {k for _, k, _ in done_entries}
             for k in done_keys - {k for _, k, _ in still}:
-                if self.ledger is not None:
-                    self.ledger.close(-1 - k, self._harvester.done_time(k))
+                self.timeline.close(-1 - k, self._harvester.done_time(k))
                 self._harvester.discard_key(k)
 
         processed = -1
@@ -3857,10 +3958,9 @@ class Engine:
                     # accepted drafts = consumed tokens minus the one the
                     # plain path would have produced anyway
                     spec_accepted += max(0, consumed - 1)
-            if self.ledger is not None:
-                self.ledger.close(
-                    step.dseq, self._harvester.done_time(step.seq),
-                    led_rows, window=arr.shape[0])
+            self.timeline.close(
+                step.dseq, self._harvester.done_time(step.seq),
+                led_rows, window=arr.shape[0])
             self.decode_dispatches += 1
             self.decode_tokens += consumed_total
             self.early_exit_steps += wasted

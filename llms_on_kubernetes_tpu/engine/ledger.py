@@ -74,6 +74,14 @@ _PEAKS = {
 }
 KINDS = ("prefill", "chunk", "decode", "spec")
 IDLE_HOSTS = ("no_work", "compile", "scheduling")
+# the rules a decode step is launched by (Engine.decode_launches,
+# llm_decode_launches_total{when}): "timed" = a lead before the device
+# was estimated to run dry, work still on it; "late" = the device was
+# already free (the estimate overshot, or the host came late: the gap is
+# the "scheduling" idle above); "admission" = directly behind the prefill
+# whose sampled tokens it merges; "depth" = as soon as the pipeline had
+# room, where the launch cannot be timed
+DECODE_LAUNCH_RULES = ("timed", "late", "admission", "depth")
 # launched and not yet booked: a pipeline holds async_depth decode steps
 # and the prefills of one admission round, a handful
 MAX_OPEN = 64
@@ -227,71 +235,53 @@ class Dispatch:
         return d
 
 
-class GoodputLedger:
-    """Chip-time attribution for one engine (see module docstring).
+class DispatchTimeline:
+    """The device's queue as the engine thread knows it: every dispatch
+    launched and not yet booked, in launch order, and what the booked
+    ones measured.
 
-    All mutation happens on the engine thread via :meth:`open` /
-    :meth:`launched` / :meth:`close`; readers (the serving loop's metrics
-    drain, bench, /metrics callbacks, /debug/engine) take the same lock
-    through :meth:`snapshot` / :meth:`utilization` / :meth:`dispatches`,
-    so a scrape never sees a half-applied record.
-    """
+    The engine keeps one whether or not chip time is attributed
+    (:class:`GoodputLedger` is this plus the accounting): the scheduler's
+    clock is read off it. Booking a record segments the device's time as
+    the module docstring says and yields ``device_ms``, and the timeline
+    keeps, per (kind, shape), the device time such a dispatch LAST took,
+    tracked from below: a segment's end is a thread waking from
+    ``block_until_ready``, so a sample comes out too long when its own
+    stamp is late, and the minimum with a slow drift upwards follows the
+    device and errs short, which makes :meth:`free_at` err EARLY, the
+    safe side for a launch timed against it. (The shortest of the last
+    four samples was tried on the chip: under load every recent stamp is
+    late, the estimate rises with them, launches come late, and two of
+    seven runs grew a tail.) A late stamp also makes the NEXT segment
+    short by as much (seen on the chip: a 67 ms window booked at 1.9
+    behind one booked at 130, a 26.5 ms prefill at 2.6): so a segment
+    that comes out short and started on the previous one's end is given
+    back what that one ran over its own estimate, up to its own; where
+    that cannot be known (a shape's first sample, or the previous
+    shape's) the short sample stays until the drift has undone it."""
 
-    def __init__(self, model_config: Any,
-                 detector: Optional[StepAnomalyDetector] = None,
-                 peak_flops: Optional[float] = None,
-                 peak_bytes_s: Optional[float] = None):
-        if peak_flops is None or peak_bytes_s is None:
-            detected = detect_peak() or (None, None)
-            peak_flops = peak_flops or detected[0]
-            peak_bytes_s = peak_bytes_s or detected[1]
-        # None (a CPU): no MFU/MBU is reported
-        self.peak_flops = peak_flops
-        self.peak_bytes_s = peak_bytes_s
-        params = _active_params(model_config)
-        dtype_bytes = 2 if "16" in str(model_config.dtype) else 4
-        # compute: the standard 2*N MAC count per token (PaLM appendix B;
-        # attention-score FLOPs are context-dependent and O(few %) at
-        # serving batch sizes, so the weight term is the estimate)
-        self.flops_per_token = 2.0 * params
-        self.param_bytes = float(params * dtype_bytes)
-        # KV traffic per token-step: one K+V page-write plus (amortized)
-        # the read of its own history — bounded below by the write
-        self.kv_bytes_per_token = float(
-            2 * model_config.num_layers * model_config.kv_dim * dtype_bytes)
+    # an estimate may rise by this factor a sample, and falls at once
+    DRIFT = 1.02
 
-        self.detector = detector
+    def __init__(self) -> None:
         self._lock = threading.Lock()
         # launched and not yet booked, in launch order
         self._open: "collections.deque[Dispatch]" = collections.deque()
-        # booked, newest last: the rolling window the MFU/MBU gauges are
-        # computed over, and what /debug/engine lists
-        self._records: "collections.deque[Dispatch]" = collections.deque(
-            maxlen=2048)
+        # (kind, shape) -> seconds; survives reset(), like the detector's
+        # baseline: the device does not forget how long a shape takes
+        self._est: dict[tuple[str, str], float] = {}
         self._zero()
 
     def _zero(self) -> None:
         self._last_complete: Optional[float] = None
-        self.t_first: Optional[float] = None
-        self.t_last: Optional[float] = None
-        self.dispatches = 0
+        # the record booked last ended on a LATER launch's read: the time
+        # of whatever ran up to that read is in it, and the next record
+        # books at 0, which says nothing about its shape
+        self._prev_clamped = False
+        # seconds the record booked last ran over its shape's estimate
+        self._prev_excess = 0.0
         self.lost = 0       # dropped unbooked: their reads never came
-        self.busy_ms = 0.0
-        self.idle_ms = 0.0
-        self.phase_ms: dict[str, float] = {p: 0.0 for p in PHASES}
-        self.tenant_ms: dict[tuple[str, str], float] = {}
-        # per kind: [dispatches, device ms, behind ms, enqueue ms]
-        self.kind_stats: dict[str, list] = {k: [0, 0.0, 0.0, 0.0]
-                                            for k in KINDS}
-        self.idle_host_ms: dict[str, float] = {h: 0.0 for h in IDLE_HOSTS}
-        self.flops = 0.0
-        self.hbm_bytes = 0.0
-        self.decode_tokens = 0
-        self.prefill_tokens = 0
-        self.anomaly_events = 0
-        self._anomaly_pending = False
         self._open.clear()
-        self._records.clear()
 
     # -- recording (engine thread) -------------------------------------
 
@@ -385,7 +375,162 @@ class GoodputLedger:
                     r for r in self._open if r.seq != seq)
                 self._book_ready()
 
-    def _book(self, rec: Dispatch, t_done: float) -> None:
+    def _book(self, rec: Dispatch, t_done: float) -> float:
+        """Segment ``rec``: when the device was free for it, how long it
+        held it, what gap came before. Returns the segment's seconds."""
+        if self._last_complete is None:
+            seg_start = rec.t_launch
+        else:
+            seg_start = max(rec.t_launch, self._last_complete)
+            idle = max(0.0, seg_start - self._last_complete) * 1000.0
+            if idle > 0.0:
+                rec.idle_before_ms = idle
+                rec.idle_host = ("no_work" if rec.after_no_work
+                                 else "compile" if rec.retraced
+                                 else "scheduling")
+        dur = max(0.0, t_done - seg_start)
+        if self._last_complete is None or t_done > self._last_complete:
+            self._last_complete = t_done
+        rec.t_done = t_done
+        rec.seg_start = seg_start
+        rec.behind_ms = (seg_start - rec.t_launch) * 1000.0
+        rec.device_ms = dur * 1000.0
+        key = (rec.kind, rec.shape)
+        est = self._est.get(key)
+        if dur > 0.0 and not rec.end_clamped and not self._prev_clamped:
+            sample = dur
+            if est is not None and dur < est and rec.idle_before_ms == 0.0:
+                # short, and its start was the end stamped before it: it
+                # is given back what that one ran over, up to the estimate
+                sample = min(est, dur + self._prev_excess)
+            self._est[key] = (sample if est is None or sample < est
+                              else min(est * self.DRIFT, sample))
+        self._prev_clamped = rec.end_clamped
+        self._prev_excess = (max(0.0, dur - est)
+                             if est is not None and not rec.end_clamped
+                             else 0.0)
+        rec.rows = None     # a record must not keep requests alive
+        return dur
+
+    def reset(self) -> None:
+        """Zero all accounting (bench measurement windows exclude warmup
+        dispatches this way). What was learned of the device survives
+        (the per-shape estimates, the detector's baseline) — forgetting
+        it would re-open the warmup window."""
+        with self._lock:
+            self._zero()
+
+    # -- reading --------------------------------------------------------
+
+    def estimate(self, kind: str, shape: str) -> Optional[float]:
+        """Seconds the device last held a dispatch of this kind and shape
+        (from below); None for one that never ran."""
+        return self._est.get((kind, shape))
+
+    def estimates_view(self) -> dict:
+        """``"kind shape" -> ms``, for ``GET /debug/engine``."""
+        with self._lock:
+            return {f"{kind} {shape}": round(s * 1000.0, 3)
+                    for (kind, shape), s in sorted(self._est.items())}
+
+    def free_at(self, now: float, done: dict) -> Optional[tuple[float, bool]]:
+        """When the device runs out of the work launched so far.
+
+        ``done`` maps the seq of an open record to the time its result
+        was complete on the device, for those the harvester has seen and
+        the engine has not collected yet. Returns ``(t, busy)``: with
+        ``busy`` the newest completion known plus the estimates of
+        everything launched after it (the one on the device now cannot
+        end before ``now``); without, nothing is ahead and ``t`` is when
+        the device went free, or ``now`` where nothing was ever launched.
+        None where a dispatch still ahead has a shape that never ran."""
+        with self._lock:
+            free = self._last_complete
+            busy = unknown = False
+            for rec in self._open:
+                t = rec.t_done if rec.closed else None
+                if t is None:
+                    t = done.get(rec.seq)
+                if t is not None:
+                    # in order: whatever was launched before it is done too
+                    free, busy, unknown = t, False, False
+                    continue
+                est = self._est.get((rec.kind, rec.shape))
+                if est is None:
+                    busy = unknown = True
+                    continue
+                end = (rec.t_launch if free is None
+                       else max(free, rec.t_launch)) + est
+                free = end if busy else max(end, now)
+                busy = True
+        if unknown:
+            return None
+        return (now if free is None else free), busy
+
+
+class GoodputLedger(DispatchTimeline):
+    """Chip-time attribution for one engine (see module docstring), on
+    the engine's :class:`DispatchTimeline`.
+
+    All mutation happens on the engine thread via :meth:`open` /
+    :meth:`launched` / :meth:`close`; readers (the serving loop's metrics
+    drain, bench, /metrics callbacks, /debug/engine) take the same lock
+    through :meth:`snapshot` / :meth:`utilization` / :meth:`dispatches`,
+    so a scrape never sees a half-applied record.
+    """
+
+    def __init__(self, model_config: Any,
+                 detector: Optional[StepAnomalyDetector] = None,
+                 peak_flops: Optional[float] = None,
+                 peak_bytes_s: Optional[float] = None):
+        if peak_flops is None or peak_bytes_s is None:
+            detected = detect_peak() or (None, None)
+            peak_flops = peak_flops or detected[0]
+            peak_bytes_s = peak_bytes_s or detected[1]
+        # None (a CPU): no MFU/MBU is reported
+        self.peak_flops = peak_flops
+        self.peak_bytes_s = peak_bytes_s
+        params = _active_params(model_config)
+        dtype_bytes = 2 if "16" in str(model_config.dtype) else 4
+        # compute: the standard 2*N MAC count per token (PaLM appendix B;
+        # attention-score FLOPs are context-dependent and O(few %) at
+        # serving batch sizes, so the weight term is the estimate)
+        self.flops_per_token = 2.0 * params
+        self.param_bytes = float(params * dtype_bytes)
+        # KV traffic per token-step: one K+V page-write plus (amortized)
+        # the read of its own history — bounded below by the write
+        self.kv_bytes_per_token = float(
+            2 * model_config.num_layers * model_config.kv_dim * dtype_bytes)
+
+        self.detector = detector
+        # booked, newest last: the rolling window the MFU/MBU gauges are
+        # computed over, and what /debug/engine lists
+        self._records: "collections.deque[Dispatch]" = collections.deque(
+            maxlen=2048)
+        super().__init__()
+
+    def _zero(self) -> None:
+        super()._zero()
+        self.t_first: Optional[float] = None
+        self.t_last: Optional[float] = None
+        self.dispatches = 0
+        self.busy_ms = 0.0
+        self.idle_ms = 0.0
+        self.phase_ms: dict[str, float] = {p: 0.0 for p in PHASES}
+        self.tenant_ms: dict[tuple[str, str], float] = {}
+        # per kind: [dispatches, device ms, behind ms, enqueue ms]
+        self.kind_stats: dict[str, list] = {k: [0, 0.0, 0.0, 0.0]
+                                            for k in KINDS}
+        self.idle_host_ms: dict[str, float] = {h: 0.0 for h in IDLE_HOSTS}
+        self.flops = 0.0
+        self.hbm_bytes = 0.0
+        self.decode_tokens = 0
+        self.prefill_tokens = 0
+        self.anomaly_events = 0
+        self._anomaly_pending = False
+        self._records.clear()
+
+    def _book(self, rec: Dispatch, t_done: float) -> float:
         rows = [(r, ph, int(w)) for r, ph, w in rec.rows or () if w > 0]
         tok_w = sum(w for _r, _ph, w in rows)
         total_w = tok_w
@@ -395,29 +540,15 @@ class GoodputLedger:
             # the conservation identity keeps holding
             rows = [(None, "early_exit", 1)]
             total_w = 1
-        if self._last_complete is None:
-            seg_start = rec.t_launch
+        dur = super()._book(rec, t_done)
+        if self.t_first is None:
             self.t_first = rec.t_launch
-        else:
-            seg_start = max(rec.t_launch, self._last_complete)
-            idle = max(0.0, seg_start - self._last_complete) * 1000.0
-            if idle > 0.0:
-                rec.idle_before_ms = idle
-                rec.idle_host = ("no_work" if rec.after_no_work
-                                 else "compile" if rec.retraced
-                                 else "scheduling")
-                self.idle_ms += idle
-                self.idle_host_ms[rec.idle_host] += idle
-        dur = max(0.0, t_done - seg_start)
-        if self._last_complete is None or t_done > self._last_complete:
-            self._last_complete = t_done
+        if rec.idle_before_ms > 0.0:
+            self.idle_ms += rec.idle_before_ms
+            self.idle_host_ms[rec.idle_host] += rec.idle_before_ms
         self.t_last = self._last_complete
         self.dispatches += 1
         self.busy_ms += dur * 1000.0
-        rec.t_done = t_done
-        rec.seg_start = seg_start
-        rec.behind_ms = (seg_start - rec.t_launch) * 1000.0
-        rec.device_ms = dur * 1000.0
         kind = self.kind_stats.setdefault(rec.kind, [0, 0.0, 0.0, 0.0])
         kind[0] += 1
         kind[1] += rec.device_ms
@@ -438,7 +569,7 @@ class GoodputLedger:
                 self.prefill_tokens += w
                 if (req is not None and getattr(
                         req, "prefill_started_at", None) is None):
-                    req.prefill_started_at = seg_start
+                    req.prefill_started_at = rec.seg_start
 
         # planned rows are computed whether or not the stream keeps
         # them — wasted FLOPs are the whole point of measuring
@@ -448,20 +579,13 @@ class GoodputLedger:
                          + self.kv_bytes_per_token * tok_w)
         self.flops += rec.flops
         self.hbm_bytes += rec.hbm_bytes
-        rec.rows = None     # the ring must not keep requests alive
         self._records.append(rec)
 
         if self.detector is not None and dur > 0.0:
             if self.detector.observe(dur, t_done):
                 self.anomaly_events += 1
                 self._anomaly_pending = True
-
-    def reset(self) -> None:
-        """Zero all accounting (bench measurement windows exclude warmup
-        dispatches this way). The detector's learned baseline survives —
-        forgetting it would re-open the warmup window."""
-        with self._lock:
-            self._zero()
+        return dur
 
     def take_anomaly(self) -> bool:
         """True once per detector trigger (serving-loop poll)."""

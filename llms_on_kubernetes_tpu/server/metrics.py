@@ -533,6 +533,18 @@ def engine_metrics(registry: Registry) -> dict:
             "full pipeline depth, as the token's read landed; step=at the "
             "end of a scheduler step",
             registry, label_names=("delivered",)),
+        "decode_launches": Counter(
+            "llm_decode_launches_total",
+            "Decode steps launched, by the rule that launched each: "
+            "timed=a measured lead before the device was estimated to run "
+            "out of work, work still on it; late=the device was already "
+            "free (the estimate overshot or the host came late; the gap "
+            "is llm_device_idle_seconds_total{host=\"scheduling\"}); "
+            "admission=directly behind a prefill, whose sampled tokens it "
+            "merges; depth=as soon as the pipeline had room (a shape that "
+            "never ran, a step no longer than two leads, multihost). "
+            "late over all is the miss rate",
+            registry, label_names=("when",)),
         "auto_profile": Counter(
             "llm_auto_profile_total",
             "Automatic bounded profiler captures triggered by the "
@@ -550,8 +562,12 @@ def engine_metrics(registry: Registry) -> dict:
     for delivered in ("backpressure", "step"):
         m["first_tokens"].labels(delivered=delivered)
     # likewise the dispatch counters, for every kind and host there is
-    from llms_on_kubernetes_tpu.engine.ledger import IDLE_HOSTS, KINDS
+    from llms_on_kubernetes_tpu.engine.ledger import (
+        DECODE_LAUNCH_RULES, IDLE_HOSTS, KINDS,
+    )
 
+    for when in DECODE_LAUNCH_RULES:
+        m["decode_launches"].labels(when=when)
     for kind in KINDS:
         for series in ("dispatches", "dispatch_device_seconds",
                        "dispatch_behind_seconds", "dispatch_enqueue_seconds"):

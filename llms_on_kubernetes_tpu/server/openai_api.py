@@ -208,6 +208,8 @@ class EngineLoop(threading.Thread):
         self._preempt_seen = 0
         self._early_exit_seen = 0
         self._first_tokens_seen = {"backpressure": 0, "step": 0}
+        self._decode_launches_seen: collections.Counter = (
+            collections.Counter())
         self._spec_seen = {"drafted": 0, "accepted": 0}
         self._adapter_seen = {"hits": 0, "misses": 0, "evictions": 0}
         self._host_kv_seen = {"hits": 0, "misses": 0, "evictions": 0}
@@ -328,6 +330,11 @@ class EngineLoop(threading.Thread):
                         m["first_tokens"].labels(delivered=where).inc(
                             v - self._first_tokens_seen[where])
                         self._first_tokens_seen[where] = v
+                for when, v in getattr(eng, "decode_launches", {}).items():
+                    if v > self._decode_launches_seen[when]:
+                        m["decode_launches"].labels(when=when).inc(
+                            v - self._decode_launches_seen[when])
+                        self._decode_launches_seen[when] = v
                 drafted = getattr(eng, "spec_drafted_tokens", 0)
                 if drafted > self._spec_seen["drafted"]:
                     m["spec_drafted"].inc(
@@ -1089,13 +1096,18 @@ class OpenAIServer:
         wedged or slow engine can be diagnosed post-hoc, and beside them
         the ledger's newest N dispatch records (what each device dispatch
         was, what it queued behind, how long the device held it, what the
-        host was doing in the gap before it). ``?limit=N`` trims both to
-        the most recent N."""
+        host was doing in the gap before it), and under ``"launch"`` what
+        times the next decode step: the lead, the device time each kind
+        and shape of dispatch last took, the launches by rule.
+        ``?limit=N`` trims the first two to the most recent N."""
         limit = self._int_query(request, "limit", 0) or None
         snap = self.flight.snapshot(limit=limit)
         led = getattr(self.engine, "ledger", None)
         snap["dispatches"] = (led.dispatches_view(limit or 64)
                               if led is not None else [])
+        launch_view = getattr(self.engine, "launch_view", None)
+        if launch_view is not None:
+            snap["launch"] = launch_view()
         snap["state"] = self.state
         snap["model"] = self.model_name
         snap["role"] = self.engine.config.role or "both"

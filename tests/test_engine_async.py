@@ -483,3 +483,288 @@ def test_decode_launch_with_nothing_in_flight_traces_nothing_new():
         eng.step()
     ref = _run_batch(_mk(False, decode_steps=4), [[1, 2, 3]], max_tokens=40)
     assert req.output == ref[0].output
+
+
+# ---------------------------------------------------------------------------
+# when a steady-state decode window is launched (PR 31): a lead before the
+# device is estimated to run dry, not as soon as the pipeline has room.
+# A simulated device on an injected clock: no thread, no sleeping
+# ---------------------------------------------------------------------------
+
+
+class _SimClock:
+    """The engine's clock. It moves when a wait is waited out and, by
+    ``tick`` a reading, while the host works."""
+
+    def __init__(self, tick=0.0):
+        self.t, self.tick = 1000.0, tick
+
+    def __call__(self):
+        self.t += self.tick
+        return self.t
+
+
+class _SimDevice:
+    """Stands where the engine's ``_Harvester`` does: a device that runs
+    what is pushed in launch order, each result taking ``cost(key)``
+    seconds of the test's clock, and stamps each completion on it. The
+    results themselves are the real ones (the CPU's). A wait moves the
+    clock to whatever ends it first; ``at(t, fn)`` lets something happen
+    at a moment inside one (a submission)."""
+
+    device_time_s = 0.0
+
+    def __init__(self, eng, cost, tick=0.0, estimates=()):
+        self.eng, self.cost = eng, cost
+        self.clock = _SimClock(tick)
+        self.free = self.clock.t
+        self.ends: dict[int, float] = {}
+        self.pushed: list[tuple[int, float]] = []       # (key, when)
+        self._res: dict[int, object] = {}
+        self._calls: list[tuple[float, object]] = []
+        eng._harvester.stop()
+        eng._harvester, eng._clock = self, self.clock
+        # what warm-up and the first requests would have taught
+        eng.timeline._est.update(estimates)
+
+    # -- what the engine calls ------------------------------------------
+
+    def push(self, key, res):
+        now = self.clock.t
+        self.free = max(self.free, now) + self.cost(key)
+        self.ends[key] = self.free
+        self._res[key] = res
+        self.pushed.append((key, now))
+
+    def is_done(self, seq):
+        return self.ends[seq] <= self.clock.t
+
+    key_done = is_done
+
+    def get(self, key):
+        import jax
+        return jax.device_get(self._res[key])
+
+    def done_time(self, key):
+        return self.ends[key]
+
+    def wait_done(self, seq, wake=None, keys=(), timeout_s=None, until=None):
+        while not self.is_done(seq):
+            if wake is not None and wake.is_set():
+                return
+            if any(self.is_done(k) for k in keys):
+                return
+            if until is not None and self.clock.t >= until:
+                return
+            self._advance([self.ends[seq], until]
+                          + [self.ends[k] for k in keys])
+
+    def wait_key(self, key, timeout_s=None):
+        while not self.is_done(key):
+            self._advance([self.ends[key]])
+
+    def discard_upto(self, seq):
+        for k in [k for k in self._res if 0 <= k <= seq]:
+            del self._res[k]
+
+    def discard_key(self, key):
+        self._res.pop(key, None)
+
+    def poke(self):
+        pass
+
+    def stop(self):
+        pass
+
+    # -- what the test calls --------------------------------------------
+
+    def at(self, t, fn):
+        self._calls.append((t, fn))
+        self._calls.sort(key=lambda c: c[0])
+
+    def _advance(self, moments):
+        """To the earliest of ``moments`` still ahead, or to a scheduled
+        call that comes before it (which then runs)."""
+        ahead = [m for m in moments if m is not None and m > self.clock.t]
+        target = min(ahead) if ahead else None
+        if self._calls and (target is None or self._calls[0][0] <= target):
+            t, fn = self._calls.pop(0)
+            self.clock.t = max(self.clock.t, t)
+            fn()
+            return
+        assert target is not None, "a wait that nothing ends"
+        self.clock.t = target
+
+    def drive(self, done):
+        steps = 0
+        while not done():
+            if self.eng.has_work():
+                self.eng.step()
+            else:
+                self._advance([])
+            steps += 1
+            assert steps < 5000
+
+    def decodes(self):
+        return [(k, t) for k, t in self.pushed if k >= 0]
+
+    def ahead_at(self, t):
+        """Decode windows launched before ``t`` and not complete at it."""
+        return [k for k, when in self.decodes()
+                if when < t and self.ends[k] > t]
+
+
+WINDOW, PREFILL = 0.064, 0.026
+_SEEDED = {("decode", f"4x{n}"): WINDOW for n in range(1, 5)}
+_SEEDED.update({("prefill", "1x16"): PREFILL, ("prefill", "4x16"): PREFILL})
+
+
+def _sim(cost=None, **kw):
+    eng = _mk(True, depth=2, decode_steps=4)
+    cost = cost or (lambda key: WINDOW if key >= 0 else PREFILL)
+    return eng, _SimDevice(eng, cost, **kw)
+
+
+_GREEDY = SamplingParams(temperature=0.0, max_tokens=40)
+
+
+def test_a_steady_window_is_launched_a_lead_before_the_device_runs_dry():
+    """(a) With nothing waiting, no window is launched before the end of
+    the work ahead less the lead, and none after that end: the device
+    never holds more than the rest of one window and never idles."""
+    eng, sim = _sim(estimates=_SEEDED)
+    req = eng.submit([1, 2, 3], _GREEDY)
+    sim.drive(lambda: req.finished)
+    launches = sim.decodes()
+    assert len(launches) == 10 and eng.decode_launches == {
+        "timed": 9, "late": 0, "admission": 1, "depth": 0}
+    lead = eng._lead
+    for (_k, when), (ahead, _w) in zip(launches[1:], launches):
+        assert sim.ends[ahead] - lead - 1e-9 <= when <= sim.ends[ahead]
+        assert len(sim.ahead_at(when)) == 1
+    idle = eng.ledger.snapshot()["idle_host_ms"]
+    assert idle["scheduling"] == 0.0 and eng.ledger.snapshot()["lost"] == 0
+    ref = _run_batch(_mk(False, decode_steps=4), [[1, 2, 3]], max_tokens=40)
+    assert req.output == ref[0].output
+
+
+def test_a_submission_in_the_timed_wait_finds_one_window_ahead():
+    """(b) A request that arrives while the thread waits for the launch
+    moment is admitted at that instant, and its prefill is enqueued with
+    at most ONE decode window not yet complete ahead of it."""
+    eng, sim = _sim(estimates=_SEEDED)
+    a = eng.submit([1, 2, 3], _GREEDY)
+    late = []
+    arrives = sim.clock.t + PREFILL + 3 * WINDOW + 0.020
+    sim.at(arrives, lambda: late.append(eng.submit([9, 10], _GREEDY)))
+    sim.drive(lambda: a.finished and late and late[0].finished)
+    (p_key, p_when), = [(k, t) for k, t in sim.pushed if k < 0][1:]
+    assert p_when == arrives
+    assert len(sim.ahead_at(p_when)) == 1
+    # behind: the rest of that window, not a whole one and a rest
+    assert sim.ends[p_key] - PREFILL - arrives < WINDOW
+    assert eng.decode_launches["admission"] == 2
+    ref = _run_batch(_mk(False, decode_steps=4), [[1, 2, 3], [9, 10]],
+                     max_tokens=40)
+    assert [a.output, late[0].output] == [r.output for r in ref]
+
+
+def test_a_first_token_in_the_timed_wait_leaves_from_inside_it():
+    """(c) The timed wait is the backpressure wait: a first token whose
+    read lands during it is handed over "backpressure", and the wait goes
+    on to the launch moment."""
+    eng, sim = _sim(estimates=_SEEDED)
+    a = eng.submit([1, 2, 3], _GREEDY)
+    late, seen = [], []
+    arrives = sim.clock.t + PREFILL + 3 * WINDOW + 0.020
+    sim.at(arrives, lambda: late.append(
+        eng.submit([9, 10], _GREEDY, on_event=lambda ev: seen.append(
+            (sim.clock.t, list(eng.decode_launches.values()))))))
+    sim.drive(lambda: bool(late))
+    before = dict(eng.first_tokens_handed)
+    sim.drive(lambda: bool(seen))
+    p_key = [k for k, _t in sim.pushed if k < 0][-1]
+    handed_at, launches = seen[0]
+    # as its read landed: the window launched behind it still runs, and
+    # nothing was launched to hand it over
+    assert handed_at == sim.ends[p_key]
+    assert len(sim.ahead_at(handed_at)) == 1
+    assert eng.first_tokens_handed["backpressure"] == (
+        before["backpressure"] + 1)
+    assert eng.first_tokens_handed["step"] == before["step"]
+    assert launches == list(eng.decode_launches.values())
+    sim.drive(lambda: a.finished and late[0].finished)
+    assert eng.decode_launches["late"] == eng.decode_launches["depth"] == 0
+
+
+def test_an_overshooting_estimate_books_late_and_widens_the_lead():
+    """(d) The device turns out faster than the estimate: the window ahead
+    completes before the launch moment, the launch comes at that
+    completion ("late"), the gap is the ledger's "scheduling" idle, and
+    the lead has grown by it; the estimate follows at once, so the next
+    launches are timed again."""
+    fast = 0.040
+    eng, sim = _sim(cost=lambda key: fast if key >= 0 else PREFILL,
+                    tick=0.0002, estimates=_SEEDED)
+    req = eng.submit([1, 2, 3], _GREEDY)
+    sim.drive(lambda: eng.decode_launches["late"] == 1)
+    (first, _w), (second, when) = sim.decodes()
+    assert sim.ends[first] <= when < sim.ends[first] + 0.01
+    assert eng._lead > 0.004
+    widened = eng._lead
+    sim.drive(lambda: req.finished)
+    assert eng.decode_launches["late"] == 1
+    assert eng.decode_launches["timed"] == 8
+    assert eng.timeline.estimate("decode", "4x1") == pytest.approx(
+        fast, abs=0.002)
+    assert 0.004 <= eng._lead < widened     # and falls again, slowly
+    idle = eng.ledger.snapshot()["idle_host_ms"]
+    gap_ms = (when - sim.ends[first]) * 1000.0
+    assert idle["scheduling"] == pytest.approx(gap_ms, abs=1.0) and gap_ms > 0
+    assert idle["no_work"] == idle["compile"] == 0.0
+
+
+@pytest.mark.parametrize("case", ["unknown_shape", "short_window"])
+def test_what_cannot_be_timed_is_launched_on_the_depth_rule(case):
+    """(e) A shape that never ran, or a window no longer than two leads:
+    the next window is launched as soon as the pipeline has room, two in
+    flight, as before. A shape that has run once is timed from then on."""
+    short = 0.006
+    if case == "unknown_shape":
+        eng, sim = _sim()
+    else:
+        eng, sim = _sim(cost=lambda key: short,
+                        estimates={k: short for k in _SEEDED})
+    req = eng.submit([1, 2, 3], SamplingParams(temperature=0.0,
+                                               max_tokens=12))
+    sim.drive(lambda: len(sim.decodes()) == 3)
+    (d0, t0), (d1, t1), (_d2, t2) = sim.decodes()
+    assert t1 == t0                 # behind the admission's, at once
+    if case == "unknown_shape":
+        # the first window's completion taught the timeline its shape
+        assert eng.timeline.estimate("decode", "4x1") == pytest.approx(WINDOW)
+        assert t2 == sim.ends[d1] - eng._lead
+        assert eng.decode_launches == {
+            "timed": 1, "late": 0, "admission": 1, "depth": 1}
+    else:
+        assert t2 == sim.ends[d0]   # when the first made room
+        assert eng.decode_launches == {
+            "timed": 0, "late": 0, "admission": 1, "depth": 2}
+    sim.drive(lambda: req.finished)
+    ref = _run_batch(_mk(False, decode_steps=4), [[1, 2, 3]], max_tokens=12)
+    assert req.output == ref[0].output
+
+
+def test_the_timeline_estimates_without_the_ledger():
+    """The launch timing reads the dispatch timeline, which the engine
+    keeps whether or not chip time is attributed."""
+    eng = _mk(True, depth=2, decode_steps=4, ledger=False)
+    assert eng.ledger is None
+    sim = _SimDevice(eng, lambda key: WINDOW if key >= 0 else PREFILL)
+    req = eng.submit([1, 2, 3], _GREEDY)
+    sim.drive(lambda: req.finished)
+    assert eng.timeline.estimate("decode", "4x1") == pytest.approx(WINDOW)
+    assert eng.timeline.estimate("prefill", "1x16") == pytest.approx(PREFILL)
+    assert eng.decode_launches["timed"] >= 5
+    assert eng.decode_launches["late"] == 0
+    assert req.chip_ms == {} and req.prefill_launched_at is None

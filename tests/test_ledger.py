@@ -504,6 +504,93 @@ def test_idle_host_takes_each_of_its_three_values():
     _conserves(snap)
 
 
+@pytest.mark.parametrize("with_ledger", [True, False])
+def test_estimates_follow_the_device_from_below(with_ledger):
+    """The timeline (the ledger's, or the bare one an engine keeps with
+    the ledger off) holds per (kind, shape) the device time a dispatch
+    last took: it falls to a shorter sample at once and rises to a longer
+    one by 2 % a sample; a record whose end was clamped to a later read,
+    and the one booked at 0 behind it, teach nothing."""
+    from llms_on_kubernetes_tpu.engine.ledger import DispatchTimeline
+
+    tl = _ledger() if with_ledger else DispatchTimeline()
+    assert tl.estimate("decode", "1x1") is None
+
+    def run(t_launch, t_done, kind="decode"):
+        tl.close(_open(tl, t_launch, kind), t_done, None)
+
+    run(0.0, 0.070)
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.070)
+    run(0.0, 0.130)                         # 60 ms: down at once
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.060)
+    run(0.0, 0.230)                         # 100 ms: up by 2 %
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.0612)
+    # an unread dispatch takes the time up to the next read (clamped);
+    # the next one books at 0: neither moves an estimate
+    unread = _open(tl, 0.23, "prefill")
+    tl.close(unread, None, None)
+    run(0.23, 0.33)
+    assert tl.estimate("prefill", "1x1") is None
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.0612)
+    assert tl.estimates_view() == {"decode 1x1": 61.2}
+    tl.reset()                              # what was learned survives
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.0612)
+
+
+def test_a_late_stamp_does_not_shorten_the_next_estimate():
+    """A completion stamped late makes its own segment long and the next
+    one short by as much; the short one is given back what the one
+    before it ran over its estimate, so a minimum does not keep it (on
+    the chip a 67 ms window was booked at 1.9 ms behind a late stamp)."""
+    from llms_on_kubernetes_tpu.engine.ledger import DispatchTimeline
+
+    tl = DispatchTimeline()
+
+    def run(t_launch, t_done):
+        tl.close(_open(tl, t_launch), t_done, None)
+
+    run(0.0, 0.067)
+    run(0.0, 0.134)
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.067)
+    run(0.0, 0.266)         # done at 0.201, stamped 65 ms late
+    run(0.0, 0.268)         # on time: 2 ms after the late stamp
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.067)
+    # a dispatch launched onto an idle device started on its own launch,
+    # not on the stamp before it: nothing is given back
+    run(0.400, 0.460)
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.060)
+    # and a device that really got slower is followed by the drift
+    # alone: its longer segments are not added to each other
+    for k in range(5):
+        run(0.0, 0.560 + 0.1 * k)
+    assert tl.estimate("decode", "1x1") == pytest.approx(0.060 * 1.02 ** 5)
+
+
+def test_free_at_adds_estimates_to_the_newest_completion():
+    """free_at: the newest completion known (booked, closed, or seen by
+    the harvester and passed in) plus the estimates of what was launched
+    after it; the dispatch on the device now cannot end before now; None
+    while a shape that never ran is still ahead."""
+    from llms_on_kubernetes_tpu.engine.ledger import DispatchTimeline
+
+    tl = DispatchTimeline()
+    assert tl.free_at(5.0, {}) == (5.0, False)      # nothing ever launched
+    first = _open(tl, 0.0)
+    assert tl.free_at(0.01, {}) is None             # never ran
+    tl.close(first, 0.067, None)
+    assert tl.free_at(0.1, {}) == (0.067, False)    # free since then
+    a, b = _open(tl, 0.07), _open(tl, 0.08)
+    assert tl.free_at(0.09, {}) == (pytest.approx(0.07 + 2 * 0.067), True)
+    # a overruns its estimate: it ends now at the earliest
+    assert tl.free_at(0.15, {}) == (pytest.approx(0.15 + 0.067), True)
+    # the harvester has seen a complete, the engine has not collected it
+    assert tl.free_at(0.15, {a: 0.14}) == (pytest.approx(0.14 + 0.067), True)
+    assert tl.free_at(0.25, {a: 0.14, b: 0.21}) == (0.21, False)
+    unknown = _open(tl, 0.22, kind="prefill")
+    assert tl.free_at(0.25, {a: 0.14, b: 0.21}) is None
+    assert tl.free_at(0.3, {a: 0.14, b: 0.21, unknown: 0.29}) == (0.29, False)
+
+
 def test_utilization_bounded():
     led = _ledger(peak_flops=1.0, peak_bytes_s=1.0)  # absurdly low peak
     r = _Req()

@@ -582,36 +582,48 @@ def test_first_token_span_splits_into_four_children(case):
     assert ("chunk" in kinds) == (case != "bucketed")
 
 
-def test_first_tokens_counter_has_both_labels_from_the_start():
-    """``llm_first_tokens_total{delivered}`` is on a fresh exposition with
-    both labels at 0 (lint-clean, in the constructor-derived inventory),
-    and a request's first token moves exactly one of them."""
+@pytest.mark.parametrize("series,label,values,moved", [
+    ("llm_first_tokens_total", "delivered", ("backpressure", "step"), 1.0),
+    # the 12 tokens of one request: a window behind its prefill, and more
+    ("llm_decode_launches_total", "when",
+     ("timed", "late", "admission", "depth"), None),
+])
+def test_labelled_counter_has_every_label_from_the_start(series, label,
+                                                         values, moved):
+    """``llm_first_tokens_total{delivered}`` and
+    ``llm_decode_launches_total{when}`` are on a fresh exposition with
+    every label at 0 (lint-clean, in the constructor-derived inventory);
+    a request's first token moves exactly one label of the first, and its
+    decode windows the second: one ``admission`` launch, then the rest."""
     import sys
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
                            / "scripts"))
     import metrics_lint
 
-    assert "llm_first_tokens_total" in metrics_lint.known_emitted_names()
+    assert series in metrics_lint.known_emitted_names()
 
     def counts(text):
-        return {where: float(line.rsplit(" ", 1)[1])
+        return {v: float(line.rsplit(" ", 1)[1])
                 for line in text.splitlines()
-                for where in ("backpressure", "step")
-                if line.startswith(
-                    'llm_first_tokens_total{delivered="%s"}' % where)}
+                for v in values
+                if line.startswith('%s{%s="%s"}' % (series, label, v))}
 
     async def body(client):
         text = await (await client.get("/metrics")).text()
         assert metrics_lint.lint(text, "fresh") == []
-        assert counts(text) == {"backpressure": 0.0, "step": 0.0}
+        assert counts(text) == dict.fromkeys(values, 0.0)
         await client.post("/v1/completions", json={
-            "prompt": "abc", "max_tokens": 6, "temperature": 0})
+            "prompt": "abc", "max_tokens": 12, "temperature": 0})
         for _ in range(50):
             text = await (await client.get("/metrics")).text()
-            if sum(counts(text).values()):
+            if sum(counts(text).values()) >= (moved or 2.0):
                 break
             await asyncio.sleep(0.02)
-        assert sum(counts(text).values()) == 1.0
+        if moved is not None:
+            assert sum(counts(text).values()) == moved
+        else:
+            assert counts(text)["admission"] == 1.0
+            assert sum(counts(text).values()) >= 2.0
         assert metrics_lint.lint(text, "after") == []
     with_client(body)
 
@@ -637,6 +649,15 @@ def test_debug_engine_lists_dispatch_records():
         one = await (await client.get("/debug/engine?limit=1")).json()
         assert len(one["dispatches"]) == 1 and len(one["steps"]) == 1
         assert one["dispatches"][0]["seq"] == recs[-1]["seq"]
+        # what times the next decode step: the lead, the device time each
+        # shape last took, the launches by rule
+        launch = snap["launch"]
+        assert launch["lead_ms"] >= 4.0
+        assert {"prefill 1x32", "decode 4x1"} <= set(launch["estimates_ms"])
+        assert all(ms > 0 for ms in launch["estimates_ms"].values())
+        assert set(launch["launches"]) == {"timed", "late", "admission",
+                                           "depth"}
+        assert launch["launches"]["admission"] == 1
     with_client(body)
 
 
