@@ -471,8 +471,8 @@ class ModelSpec:
     dtype: Optional[str] = None            # engine --dtype override
     # fused decode window: tokens sampled per device dispatch
     # (LLMK_DECODE_STEPS); None = engine default. Multihost replicas
-    # clamp to 1 at engine start until the broadcast protocol carries
-    # the window, so the spec accepts it everywhere.
+    # clamp to 1 at engine start (no two-process run has shown K > 1
+    # across hosts yet), so the spec accepts it everywhere.
     decode_steps: Optional[int] = None
     # speculative decoding tier (LLMK_SPECULATION): None = off,
     # "ngram" = model-free prompt lookup, "draft" = small draft model.

@@ -249,8 +249,10 @@ class EngineConfig:
     # tokens per dispatch. Amortizes the per-dispatch host round trip
     # (ROADMAP item 5) and is the substrate the verify-k-tokens
     # speculative path lands on. None => env LLMK_DECODE_STEPS (default
-    # 4). Forced to 1 under multihost until the broadcast protocol
-    # carries the window. Streams are bit-identical to decode_steps=1
+    # 4). Forced to 1 under multihost: the message carries K and
+    # followers enter the same step, but no two-process run has shown a
+    # window across hosts (ROADMAP D6). Streams are bit-identical to
+    # decode_steps=1
     # (same PRNG positions, same penalty-count evolution) — pinned by
     # tests/test_decode_multistep.py.
     decode_steps: Optional[int] = None
@@ -262,7 +264,7 @@ class EngineConfig:
     # verify dispatch — greedy outputs are bit-identical to speculation
     # off (exact-match acceptance), seeded sampling matches through the
     # fold_in(base, seed)+position PRNG chain. None/"off" disables. Forced
-    # off under multihost (the K=1 clamp leaves no draft room anyway).
+    # off under multihost (the K=1 clamp leaves no draft room).
     # Env: LLMK_SPECULATION / LLMK_DRAFT_MODEL.
     speculation: Optional[str] = None
     draft_model: Optional[str] = None
@@ -311,8 +313,7 @@ class EngineConfig:
             raise ValueError(
                 f"decode_steps must be >= 1, got {self.decode_steps}")
         if self.multihost and self.decode_steps > 1:
-            # followers mirror single-step MSG_DECODE programs; the packed
-            # broadcast does not carry the window yet
+            # no run has had two processes under K > 1 (ROADMAP D6)
             self.decode_steps = 1
         if self.speculation is None:
             self.speculation = os.environ.get("LLMK_SPECULATION") or None
@@ -331,9 +332,9 @@ class EngineConfig:
                 "speculation='draft' requires draft_model (registry name "
                 "or .gguf path)")
         if self.multihost and self.speculation is not None:
-            # the K=1 clamp above leaves no draft room, and followers
-            # could not mirror a variable-accept window — reject cleanly
-            # rather than diverge
+            # the K=1 clamp above leaves no draft room, and MSG_DECODE
+            # announces the plain step only — reject cleanly rather than
+            # diverge
             self.speculation = None
         if self.watchdog_stall_s is None:
             self.watchdog_stall_s = float(
@@ -559,17 +560,15 @@ class StepEvent:
 
 @dataclasses.dataclass
 class InflightStep:
-    """A launched-but-unharvested decode dispatch (async scheduling).
-    With fused multi-step decode one dispatch carries a WINDOW of up to
-    decode_steps tokens per slot; ``planned`` records how many tokens
-    each slot's row was budgeted for (None => legacy single-step, 1
-    per active slot)."""
-    pack: Any                              # device packed result:
-    #                                        [B, W] (K=1) or [K, B, W]
+    """A launched-but-unharvested decode dispatch (async scheduling):
+    one dispatch carries a WINDOW of up to decode_steps tokens per slot;
+    ``planned`` records how many tokens each slot's row was budgeted
+    for."""
+    pack: Any                              # device packed result [K, B, W]
     toks: Any                              # device [B] sampled tokens (merge)
     active: list[tuple[int, Request]]      # (slot, request) snapshot at launch
-    seq: int = -1                          # harvester sequence number
-    planned: Optional[dict] = None         # slot -> tokens planned this window
+    seq: int                               # harvester sequence number
+    planned: dict                          # slot -> tokens planned this window
     spec: bool = False                     # speculative verify dispatch:
     #                                        pack is (packs [K,B,W], accept [B])
     drafted: Optional[dict] = None         # slot -> drafted tokens this window
@@ -888,8 +887,8 @@ STOP_SLOTS = 8
 # packed decode columns: 0 lengths, 1 src, 2 vals, 3 top_k, 4 temps(bits),
 # 5 top_p(bits), 6 seed, 7 prefill_row, 8 presence(bits),
 # 9 frequency(bits), 10 pos_delta (mrope), 11 adapter_slot (-1 = base),
-# 12-14 fsm (row, set, val), 15 window budget (planned alive iterations —
-# multi-step decode only, 0/ignored for K=1), 16.. stop-token ids
+# 12-14 fsm (row, set, val), 15 window budget (planned alive iterations,
+# 0 = the row rides masked), 16.. stop-token ids
 # (STOP_SLOTS, -1 padded), then logit_bias ids/vals, then page_table
 _ADP_DEC = 11
 _FSM_DEC = 12
@@ -899,53 +898,14 @@ _BIAS_DEC = _STOP_DEC + STOP_SLOTS
 _DEC_COLS = _BIAS_DEC + 2 * LOGIT_BIAS_SLOTS
 
 
-def _decode_packed_step(params, cfg, packed, last_toks, prefill_toks,
-                        k_pages, v_pages, counts, base_key, fsm=None):
-    lengths = packed[:, 0]
-    src, vals = packed[:, 1], packed[:, 2]
-    top_ks = packed[:, 3]
-    temps = jax.lax.bitcast_convert_type(packed[:, 4], jnp.float32)
-    top_ps = jax.lax.bitcast_convert_type(packed[:, 5], jnp.float32)
-    seeds = packed[:, 6]
-    prefill_row = packed[:, 7]
-    presence = jax.lax.bitcast_convert_type(packed[:, 8], jnp.float32)
-    frequency = jax.lax.bitcast_convert_type(packed[:, 9], jnp.float32)
-    pos_delta = packed[:, 10]
-    adapter_idx = packed[:, _ADP_DEC]
-    bias = _unpack_bias(packed, _BIAS_DEC)
-    page_table = packed[:, _DEC_COLS:]
-
-    tokens = _merge_tokens(last_toks, src, vals, prefill_toks, prefill_row)
-    # the input token is always a previously-sampled OUTPUT token: count
-    # it before sampling so this step's draw sees it
-    counts = _count_decode_tokens(counts, tokens, lengths > 0)
-    logits, k_pages, v_pages = forward_decode(
-        params, cfg, tokens, lengths, k_pages, v_pages, page_table,
-        pos_delta=pos_delta, adapter_idx=adapter_idx,
-    )
-    keys = _slot_keys(base_key, seeds, lengths)
-    allowed = nxt_all = new_state = None
-    if fsm is not None:
-        g_rows = packed[:, _FSM_DEC]
-        base = jnp.where(packed[:, _FSM_DEC + 1] == 1,
-                         packed[:, _FSM_DEC + 2], fsm[0])
-        allowed, nxt_all, constrained = _fsm_apply(fsm, g_rows, base)
-    res = sample(logits, keys, temps, top_ks, top_ps,
-                 penalties=(presence, frequency, counts), bias=bias,
-                 allowed=allowed)
-    if fsm is not None:
-        new_state = jnp.where(constrained & (lengths > 0),
-                              _fsm_next(nxt_all, res.tokens), base)
-    return res.host_pack(), res.tokens, k_pages, v_pages, counts, new_state
-
-
 def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
                               prefill_toks, k_pages, v_pages, counts,
                               base_key, fsm=None):
-    """Fused multi-step decode: ONE dispatch runs K sampling steps via
-    lax.scan, returning the K packed host rows stacked [K, B, W].
+    """The decode step, for every K >= 1: ONE dispatch runs K sampling
+    steps via lax.scan, returning the K packed host rows stacked
+    [K, B, W] (the synchronous loop enters it with K = 1).
 
-    Parity with K chained _decode_packed_step calls is exact: iteration j
+    Parity with K chained K = 1 calls is exact: iteration j
     samples at sequence position lengths0 + j (same PRNG fold_in), counts
     its input token before sampling (same penalty evolution), and feeds
     its sampled token straight into iteration j+1's merge. Per-row
@@ -957,7 +917,7 @@ def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
     stays deterministic. The host (_emit) remains authoritative for
     finishes — the device mask can only under-run, never over-run, the
     stream. Grammar rows ride the loop: the FSM state is scan carry,
-    masked+advanced per iteration exactly as the single-step path."""
+    masked+advanced per iteration."""
     lengths0 = packed[:, 0]
     src, vals = packed[:, 1], packed[:, 2]
     top_ks = packed[:, 3]
@@ -1544,9 +1504,6 @@ class Engine:
         self._prefill_packed = jax.jit(
             _prefill_packed_step, static_argnums=(1,), donate_argnums=(4, 5, 6)
         )
-        self._decode_packed = jax.jit(
-            _decode_packed_step, static_argnums=(1,), donate_argnums=(5, 6, 7)
-        )
         self._decode_multi = jax.jit(
             _decode_multi_packed_step, static_argnums=(1, 2),
             donate_argnums=(6, 7, 8)
@@ -1604,17 +1561,17 @@ class Engine:
             self._harvester.start()
             import weakref
             weakref.finalize(self, self._harvester.stop)
-        # device-resident zero vectors for the packed steps (uploaded once)
-        self._zeros_B = jnp.zeros((B,), jnp.int32)
-        self._zeros_1 = jnp.zeros((1,), jnp.int32)
-        # what the fused decode launch passes for a token input no row
-        # reads (nothing in flight; no admission): the newest real one, so
-        # that the step is traced for ONE sharding annotation of each. With
-        # the zeros (uncommitted) every combination was a cache entry of
-        # its own, and one that the traffic before it never reached was
-        # traced and lowered under a request (17-20 s at 32 layers)
-        self._unread_toks = self._zeros_B
-        self._unread_prefill_toks = self._zeros_1
+        # what a decode step is passed for a token input no row reads
+        # (nothing in flight; no admission): the newest real one, so that
+        # the step is traced for ONE sharding annotation of each. With
+        # zeros (uncommitted) every combination was a cache entry of its
+        # own, and one that the traffic before it never reached was
+        # traced and lowered under a request (17-20 s at 32 layers).
+        # A follower keeps the same two (multihost.follower_loop): the
+        # newest decode step's tokens and the newest prefill's. Zeros
+        # until the first of each
+        self._unread_toks = jnp.zeros((B,), jnp.int32)
+        self._unread_prefill_toks = jnp.zeros((1,), jnp.int32)
         # decode-row template cache (PR 3, profile-guided): the decode
         # packed array is mostly request-STATIC sampling columns, and
         # rebuilding every one of them per step in a Python loop (plus
@@ -2828,13 +2785,13 @@ class Engine:
 
         led_rows = [(req, "prefill", n - hit or n)]
         if req.images is not None and hit == 0:
-            pack, _toks, dseq = self._dispatch_mm_prefill(
+            pack, toks, dseq = self._dispatch_mm_prefill(
                 slot, req, prefill_tokens, led_rows)
         elif hit > 0 or n > max(self.config.prefill_buckets):
             # cache-hit admissions run the chunk path: prefill-with-history
             # attention over the remainder, history = the adopted prefix
             # (for a multimodal hit the remainder is pure text)
-            pack, _toks, dseq = self._chunked_prefill(
+            pack, toks, dseq = self._chunked_prefill(
                 slot, req, prefill_tokens, led_rows, start=hit)
         else:
             from llms_on_kubernetes_tpu.engine.multihost import MSG_PREFILL
@@ -2852,7 +2809,7 @@ class Engine:
                           fsm_used=use_fsm)
             with self._dispatch("prefill", "_prefill_packed_step",
                                 f"1x{bucket}", led_rows) as dseq:
-                (pack, _toks, self.k_pages, self.v_pages, self.token_counts,
+                (pack, toks, self.k_pages, self.v_pages, self.token_counts,
                  new_state) = self._prefill_packed(
                     self.params, self.model_config, jnp.asarray(tokens),
                     jnp.asarray(packed), self.k_pages, self.v_pages,
@@ -2862,6 +2819,8 @@ class Engine:
             if new_state is not None:
                 self._fsm_state = new_state
             self.slot_len[slot] = n
+        # no row reads it; a follower passes its newest too (multihost)
+        self._unread_prefill_toks = toks
         # the dispatched prefill writes these pages; device order makes
         # them valid for any later-dispatched adopter
         if req.cache_salt is not None:
@@ -3067,6 +3026,35 @@ class Engine:
         packed[:, _DEC_COLS:] = self.allocator.page_tables
         return packed
 
+    def _pack_decode(self, active, plan: dict, infl: dict,
+                     merged: dict) -> np.ndarray:
+        """The decode step's packed array: the template plus each row's
+        dynamic columns. ``plan[i]`` is the row's window budget (0: it
+        rides masked), ``infl[i]`` the tokens its slot has in flight (its
+        input token is then the newest step's output, on the device) and
+        ``merged[i]`` an admission's ``(resumed, host value, prefill
+        row)``; a row in neither reads its request's pending token."""
+        packed = self._dec_template(active)
+        for i, r in active:
+            p, prior = plan.get(i, 0), infl.get(i, 0)
+            packed[i, 0] = 0 if p <= 0 else int(self.slot_len[i]) + prior + 1
+            packed[i, _BUD_DEC] = p
+            if r.fsm_row >= 0 and r.pending_fsm_state is not None:
+                packed[i, _FSM_DEC + 1] = 1      # resume: force state
+                packed[i, _FSM_DEC + 2] = r.pending_fsm_state
+                r.pending_fsm_state = None
+            if i in merged:
+                resumed, host_val, row = merged[i]
+                if resumed:              # resumed: host-known pending token
+                    packed[i, 1], packed[i, 2] = 1, host_val
+                else:                    # fresh: token sampled by the prefill
+                    packed[i, 1], packed[i, 7] = 2, row
+            elif prior > 0:
+                packed[i, 1] = 0         # newest in-flight step's output
+            else:
+                packed[i, 1], packed[i, 2] = 1, r.pending_token
+        return packed
+
     def _decode_once(self) -> list[StepEvent]:
         active = [(i, r) for i, r in enumerate(self.slots) if r is not None]
         if not active:
@@ -3093,30 +3081,25 @@ class Engine:
         self.decode_dispatches += 1
         self.decode_tokens += len(active)
         self.steps_obs.append(1)
-        packed = self._dec_template(active)
-        for i, r in active:
-            packed[i, 0] = self.slot_len[i] + 1
-            packed[i, 2] = r.pending_token
-            if r.fsm_row >= 0 and r.pending_fsm_state is not None:
-                packed[i, _FSM_DEC + 1] = 1      # resume: force state
-                packed[i, _FSM_DEC + 2] = r.pending_fsm_state
-                r.pending_fsm_state = None
+        # a window of one on every active row, every token host-known
+        packed = self._pack_decode(active, dict.fromkeys(
+            (i for i, _r in active), 1), {}, {})
 
         use_fsm = self._fsm_any_active()
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
-        with self._dispatch("decode", "_decode_packed_step",
+        with self._dispatch("decode", "_decode_multi_packed_step",
                             f"1x{len(active)}") as dseq:
-            (pack, _toks, self.k_pages, self.v_pages, self.token_counts,
-             new_state) = self._decode_packed(
-                self.params, self.model_config, jnp.asarray(packed),
-                self._zeros_B, self._zeros_1, self.k_pages, self.v_pages,
-                self.token_counts, self._key,
+            (pack, self._unread_toks, self.k_pages, self.v_pages,
+             self.token_counts, new_state) = self._decode_multi(
+                self.params, self.model_config, 1, jnp.asarray(packed),
+                self._unread_toks, self._unread_prefill_toks, self.k_pages,
+                self.v_pages, self.token_counts, self._key,
                 self._fsm_args() if use_fsm else None,
             )
         if new_state is not None:
             self._fsm_state = new_state
         t0 = time.perf_counter()
-        host = HostSample(np.asarray(jax.device_get(pack)))
+        host = HostSample(np.asarray(jax.device_get(pack))[0])
         self._device_time_s += time.perf_counter() - t0
         self.timeline.close(dseq, self._clock(),
                             [(r, "decode", 1) for _i, r in active])
@@ -3133,38 +3116,19 @@ class Engine:
     # async (pipelined) scheduling
     # ------------------------------------------------------------------
 
-    def _inflight_count(self, slot: int) -> int:
-        """In-flight decode steps that will grow THIS slot's current
-        request. Steps whose entry at this slot refers to a previous
-        (finished/preempted) occupant must not count — they write garbage
-        the harvest skips, and counting them would inflate the new
-        request's attention length into unwritten positions."""
-        cur = self.slots[slot]
-        return sum(1 for s in self._inflight
-                   for j, r in s.active if j == slot and r is cur)
-
-    def _inflight_counts(self) -> dict:
-        """Per-slot in-flight step counts in ONE pass over the pipeline
-        (same semantics as _inflight_count, amortized for the launch
-        path's B consumers)."""
-        counts: dict[int, int] = {}
-        for s in self._inflight:
-            for j, r in s.active:
-                if self.slots[j] is r:
-                    counts[j] = counts.get(j, 0) + 1
-        return counts
-
     def _inflight_tokens(self) -> dict:
-        """Per-slot in-flight TOKEN counts — like _inflight_counts, but a
-        fused multi-step dispatch contributes its planned window size, not
-        1. This is what the multi-step launch sizes page allocations and
-        window budgets from."""
+        """Per-slot in-flight TOKEN counts: what each unharvested window
+        planned for the slot's CURRENT request, which is what a launch
+        sizes page allocations and window budgets from. A step whose
+        entry at a slot refers to a previous (finished/preempted) occupant
+        does not count: it writes garbage the harvest skips, and counting
+        it would inflate the new request's attention length into
+        unwritten positions."""
         counts: dict[int, int] = {}
         for s in self._inflight:
             for j, r in s.active:
                 if self.slots[j] is r:
-                    p = 1 if s.planned is None else s.planned.get(j, 0)
-                    counts[j] = counts.get(j, 0) + p
+                    counts[j] = counts.get(j, 0) + s.planned.get(j, 0)
         return counts
 
     def _admit_async(self, events: list[StepEvent]):
@@ -3340,148 +3304,46 @@ class Engine:
         """Launch one decode step whose input tokens are assembled ON DEVICE
         from the newest in-flight step's output (continuing slots), host
         values (slots with no step in flight), and this step's prefill
-        (just-admitted slots). Returns "launched", "early" (not due yet:
-        _decode_due; _harvest waits out the rest), "paced" (nothing to
-        launch until device work completes), or "idle"."""
-        if self.config.decode_steps > 1:
-            if self._spec is not None:
-                st = self._launch_decode_spec(self.config.decode_steps,
-                                              admitted, events)
-                if st is not None:
-                    return st
-            return self._launch_decode_multi(self.config.decode_steps,
-                                             admitted, events)
-        B = self.config.max_decode_slots
+        (just-admitted slots): a speculative verify where a speculator
+        has drafts, else a window of decode_steps (1 included). Returns
+        "launched", "early" (not due yet: _decode_due; _harvest waits out
+        the rest), "paced" (nothing to launch until device work
+        completes), or "idle"."""
+        K = self.config.decode_steps
+        if self._spec is not None:
+            st = self._launch_decode_spec(K, admitted, events)
+            if st is not None:
+                return st
+        return self._launch_decode_multi(K, admitted, events)
+
+    def _plan_windows(self, K: int,
+                      events: list[StepEvent]) -> tuple[dict, dict]:
+        """Per slot, PLAN p <= K tokens for the next window and allocate
+        its pages up front. p is clipped by the request's remaining
+        max_tokens budget (less the tokens already in flight and a first
+        token whose read has not landed: it is emitted before any of this
+        window's) and by max_model_len; a row with p == 0 rides masked.
+        On exhaustion: drain in-flight work (finishes hiding in
+        unharvested steps free pages), then preempt. Returns ``(plan,
+        in-flight tokens per slot)``.
+
+        Token-level inflight counts: a planned-but-unharvested window
+        already owns its positions. Stale plan entries survive drains —
+        slot_len + inflight is invariant under harvest (tokens move from
+        in-flight to slot_len one-for-one), and so is the max_tokens
+        budget (output grows by exactly the harvested tokens)."""
         max_len = self.config.max_model_len
-
-        rule = self._decode_due(admitted)
-        if rule[0] is None:
-            return "early"
-
-        # grow page tables; drain in-flight work, then preempt, on exhaustion.
-        # inflight counts are computed ONCE per pass (a per-slot
-        # _inflight_count scan is O(B * depth * B) per launch — measured
-        # ~6 ms/step at B=64, a real slice of the step budget on a
-        # small-core host) and recomputed only when a drain/preempt
-        # changes the in-flight set.
-        infl = self._inflight_counts()
-        i = 0
-        while i < B:
-            r = self.slots[i]
-            if r is None:
-                i += 1
-                continue
-            need = int(self.slot_len[i]) + infl.get(i, 0) + 1
-            if need > max_len:
-                i += 1  # rides along idle; finishes by length at harvest
-                continue
-            try:
-                self.allocator.allocate(i, need)
-                i += 1
-            except MemoryError:
-                if self._inflight or self._pending_first:
-                    # freeing may come from finishes hiding in unharvested
-                    # steps — drain before resorting to preemption
-                    events += self._harvest(drain=True)
-                    infl = self._inflight_counts()
-                    continue
-                self._preempt_youngest()
-                infl = self._inflight_counts()
-
-        active = [(i, r) for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return "idle"
-
-        packed = self._dec_template(active)
-        for i, r in active:
-            need = int(self.slot_len[i]) + infl.get(i, 0) + 1
-            packed[i, 0] = 0 if need > max_len else need
-            if r.fsm_row >= 0 and r.pending_fsm_state is not None:
-                packed[i, _FSM_DEC + 1] = 1      # resume: force state
-                packed[i, _FSM_DEC + 2] = r.pending_fsm_state
-                r.pending_fsm_state = None
-            if admitted is not None and i in admitted["slots"]:
-                resumed, host_val, row = admitted["slots"][i]
-                if resumed:              # resumed: host-known pending token
-                    packed[i, 1], packed[i, 2] = 1, host_val
-                else:                    # fresh: token sampled by the prefill
-                    packed[i, 1], packed[i, 7] = 2, row
-            elif infl.get(i, 0) > 0:
-                packed[i, 1] = 0         # newest in-flight step's output
-            else:
-                packed[i, 1], packed[i, 2] = 1, r.pending_token
-
-        from llms_on_kubernetes_tpu.engine.multihost import MSG_DECODE
-
-        last_toks = self._inflight[-1].toks if self._inflight else self._zeros_B
-        prefill_toks = admitted["toks"] if admitted is not None else self._zeros_1
-
-        # followers pick the same token references by these flags: their own
-        # newest decode output (last_valid) / newest prefill-or-chunk output
-        # (use_prefill) are the same global arrays by SPMD determinism
-        use_fsm = self._fsm_any_active()
-        self._mh_send(MSG_DECODE, dec_packed=packed,
-                      last_valid=bool(self._inflight),
-                      use_prefill=admitted is not None, fsm_used=use_fsm)
-        with self._dispatch("decode", "_decode_packed_step",
-                            f"1x{len(active)}") as dseq:
-            (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-             new_state) = self._decode_packed(
-                self.params, self.model_config, jnp.asarray(packed),
-                last_toks, prefill_toks, self.k_pages, self.v_pages,
-                self.token_counts, self._key,
-                self._fsm_args() if use_fsm else None,
-            )
-        if new_state is not None:
-            self._fsm_state = new_state
-        seq = next(self._seq_counter)
-        step = InflightStep(pack, toks, active, seq,
-                            planned={i: 1 for i, _r in active}, dseq=dseq)
-        self._inflight.append(step)
-        self._harvester.push(seq, pack)
-        self._note_launch(*rule)
-        return "launched"
-
-    def _launch_decode_multi(self, K: int, admitted,
-                             events: list[StepEvent]) -> str:
-        """Multi-step variant of _launch_decode_async: one dispatch runs a
-        window of up to K decode steps per slot (_decode_multi_packed_step).
-
-        Per slot the launch PLANS p <= K tokens — clipped by the request's
-        remaining max_tokens budget (minus tokens already in flight and a
-        not-yet-harvested first token) and by max_model_len — allocates
-        pages for the whole window up front, and ships p as the row's
-        on-device budget. Rows with p == 0 ride along masked (lengths 0).
-        The harvest consumes up to p tokens per row; host-side _emit stays
-        authoritative for finishes, so a row that stops mid-window simply
-        wastes its tail (early-exit accounting)."""
-        B = self.config.max_decode_slots
-        max_len = self.config.max_model_len
-
-        rule = self._decode_due(admitted)
-        if rule[0] is None:
-            return "early"
-
-        # plan windows + grow page tables; drain in-flight work, then
-        # preempt, on exhaustion (same recovery ladder as the K=1 path).
-        # Token-level inflight counts: a planned-but-unharvested window
-        # already owns its positions. Stale plan entries survive drains —
-        # slot_len + inflight is invariant under harvest (tokens move from
-        # in-flight to slot_len one-for-one), and so is the max_tokens
-        # budget (output grows by exactly the harvested tokens).
         infl = self._inflight_tokens()
         first_pending = {id(r) for r, _k, _row in self._pending_first}
         plan: dict[int, int] = {}
         i = 0
-        while i < B:
+        while i < self.config.max_decode_slots:
             r = self.slots[i]
             if r is None:
                 i += 1
                 continue
             prior = infl.get(i, 0)
             base0 = int(self.slot_len[i]) + prior + 1
-            # a first token whose read has not landed yet will be
-            # emitted before any of this window's tokens — budget for it
             extra = 1 if id(r) in first_pending else 0
             budget = r.params.max_tokens - len(r.output) - prior - extra
             p = max(0, min(K, budget, max_len - base0 + 1))
@@ -3502,7 +3364,25 @@ class Engine:
                     continue
                 self._preempt_youngest()
                 infl = self._inflight_tokens()
+        return plan, infl
 
+    def _launch_decode_multi(self, K: int, admitted,
+                             events: list[StepEvent]) -> str:
+        """The window launch: one dispatch runs up to K decode steps per
+        slot (_decode_multi_packed_step), each row with the budget
+        _plan_windows gave it. The harvest consumes up to that many tokens
+        per row; host-side _emit stays authoritative for finishes, so a
+        row that stops mid-window simply wastes its tail (early-exit
+        accounting)."""
+        rule = self._decode_due(admitted)
+        if rule[0] is None:
+            return "early"
+        if admitted is not None:
+            # before any return: a follower takes every prefill's tokens
+            # as its newest (multihost.follower_loop), launch or none
+            self._unread_prefill_toks = admitted["toks"]
+
+        plan, infl = self._plan_windows(K, events)
         active = [(i, r) for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return "idle"
@@ -3513,36 +3393,16 @@ class Engine:
             # makes progress.
             return "paced"
 
-        packed = self._dec_template(active)
-        for i, r in active:
-            p = plan.get(i, 0)
-            packed[i, 0] = 0 if p <= 0 else \
-                int(self.slot_len[i]) + infl.get(i, 0) + 1
-            packed[i, _BUD_DEC] = p
-            if r.fsm_row >= 0 and r.pending_fsm_state is not None:
-                packed[i, _FSM_DEC + 1] = 1      # resume: force state
-                packed[i, _FSM_DEC + 2] = r.pending_fsm_state
-                r.pending_fsm_state = None
-            if admitted is not None and i in admitted["slots"]:
-                resumed, host_val, row = admitted["slots"][i]
-                if resumed:              # resumed: host-known pending token
-                    packed[i, 1], packed[i, 2] = 1, host_val
-                else:                    # fresh: token sampled by the prefill
-                    packed[i, 1], packed[i, 7] = 2, row
-            elif infl.get(i, 0) > 0:
-                packed[i, 1] = 0         # newest in-flight step's output
-            else:
-                packed[i, 1], packed[i, 2] = 1, r.pending_token
-
-        if admitted is not None:
-            self._unread_prefill_toks = admitted["toks"]
+        packed = self._pack_decode(
+            active, plan, infl, admitted["slots"] if admitted else {})
         last_toks = (self._inflight[-1].toks if self._inflight
                      else self._unread_toks)
         prefill_toks = self._unread_prefill_toks
 
-        # multihost always clamps decode_steps to 1 in EngineConfig, so
-        # this path never needs a broadcast message
+        from llms_on_kubernetes_tpu.engine.multihost import MSG_DECODE
+
         use_fsm = self._fsm_any_active()
+        self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
         with self._dispatch("decode", "_decode_multi_packed_step",
                             f"{K}x{len(active)}") as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
@@ -3591,30 +3451,9 @@ class Engine:
             # up to K tokens, which is what pipelining amortized)
             return "paced"
         B = self.config.max_decode_slots
-        max_len = self.config.max_model_len
 
-        # plan windows + grow page tables (the multi ladder, with an empty
-        # pipeline: MemoryError goes straight to preemption)
-        plan: dict[int, int] = {}
-        i = 0
-        while i < B:
-            r = self.slots[i]
-            if r is None:
-                i += 1
-                continue
-            base0 = int(self.slot_len[i]) + 1
-            budget = r.params.max_tokens - len(r.output)
-            p = max(0, min(K, budget, max_len - base0 + 1))
-            plan[i] = p
-            if p == 0:
-                i += 1
-                continue
-            try:
-                self.allocator.allocate(i, base0 + p - 1)
-                i += 1
-            except MemoryError:
-                self._preempt_youngest()
-
+        # nothing is in flight: the ladder goes straight to preemption
+        plan, infl = self._plan_windows(K, events)
         active = [(i, r) for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return None
@@ -3641,17 +3480,8 @@ class Engine:
             self._spec.policy.note_empty()
             return None               # nothing proposed: plain window
 
-        packed = self._dec_template(active)
-        for i, r in active:
-            p = plan.get(i, 0)
-            packed[i, 0] = 0 if p <= 0 else int(self.slot_len[i]) + 1
-            packed[i, _BUD_DEC] = p
-            if r.fsm_row >= 0 and r.pending_fsm_state is not None:
-                packed[i, _FSM_DEC + 1] = 1      # resume: force state
-                packed[i, _FSM_DEC + 2] = r.pending_fsm_state
-                r.pending_fsm_state = None
-            packed[i, 1], packed[i, 2] = 1, r.pending_token
-        full = np.concatenate([packed, ext], axis=1)
+        full = np.concatenate(
+            [self._pack_decode(active, plan, infl, {}), ext], axis=1)
 
         use_fsm = self._fsm_any_active()
         with self._dispatch("spec", "_decode_spec_packed_step",
@@ -3908,9 +3738,7 @@ class Engine:
             if isinstance(res, (tuple, list)):   # spec: (packs, accept)
                 res, accept = res
                 accept = np.asarray(accept)
-            arr = np.asarray(res)
-            if arr.ndim == 2:    # single-step pack [B, W] => window of 1
-                arr = arr[None]
+            arr = np.asarray(res)                # [K, B, W]
             hosts = [HostSample(arr[k]) for k in range(arr.shape[0])]
             processed = step.seq
             n_steps += 1
@@ -3918,7 +3746,7 @@ class Engine:
             spec_accepted = 0
             led_rows: list = []
             for slot, req in step.active:
-                p = 1 if step.planned is None else step.planned.get(slot, 0)
+                p = step.planned.get(slot, 0)
                 if p <= 0:
                     continue
                 waste_phase = ("spec_waste"

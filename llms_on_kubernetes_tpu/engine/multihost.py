@@ -18,17 +18,18 @@ rendezvous, SURVEY §2.4 / §5 "Distributed communication backend"):
   per step — the old header+payload protocol paid two.
 - ASYNC scheduling works across hosts: the decode input merge happens on
   device from the previous step's sampled tokens, so followers never need
-  host values — they mirror the coordinator's call sequence and keep
-  references to their own ``last_toks`` / ``prefill_toks`` outputs, which
-  are the same global arrays by SPMD determinism. The control word's
-  ``last_valid`` / ``use_prefill`` bits tell them which reference the
-  coordinator wired into the merge.
+  host values — they mirror the coordinator's call sequence and pass
+  every decode step their own newest decode output and newest prefill
+  output, as the coordinator does: the same global arrays by SPMD
+  determinism. The packed rows' source column says which rows read them.
+- A decode message enters the engine's ONE decode step
+  (``_decode_multi_packed_step``) with the window K of the control word.
 - Followers do no host reads and no allocation: page tables, lengths, and
   sampling parameters all ride inside the packed arrays.
 
 Message layout (all int32; floats ride bitcast, as in the packed steps):
 
-  ctrl[8]    = [op, k_rows, bucket, last_valid, use_prefill, fsm_used,
+  ctrl[6]    = [op, k (prefill rows | decode window K), bucket, fsm_used,
                 score_width, score_len]
   pre_tokens [admit_batch, max_bucket]   prefill/chunk token ids
   pre_packed [admit_batch, _CHK_COLS + pages_per_slot]
@@ -62,13 +63,13 @@ MSG_MM_PREFILL = 5
 # Sent only when the resident-grammar SET changes (admission-time).
 MSG_GRAMMAR = 6
 # prompt scoring (echo+logprobs): the control word carries the padded
-# width and true length in ctrl[6:8], then ONE extra broadcast ships the
+# width and true length in ctrl[4:6], then ONE extra broadcast ships the
 # [1, width] token row (width can exceed max_bucket — scoring pads to a
 # multiple of the largest bucket — so it can't ride pre_tokens). Followers
 # enter the same forward_score executable and discard the result.
 MSG_SCORE = 7
 
-CTRL_LEN = 8
+CTRL_LEN = 6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,6 +98,9 @@ class ProtoShapes:
     g_vocab: int = 0
     g_states: int = 0
     g_classes: int = 0
+    # the window K a decode message announces: the pipelined scheduler's
+    # decode_steps; the synchronous loop enters the step with K = 1
+    decode_steps: int = 1
 
     @classmethod
     def from_engine_config(cls, cfg: Any,
@@ -125,6 +129,7 @@ class ProtoShapes:
             mm_row_frames=row_frames,
             g_rows=cfg.max_grammars, g_vocab=vocab,
             g_states=cfg.grammar_states, g_classes=cfg.grammar_classes,
+            decode_steps=cfg.decode_steps if cfg.async_scheduling else 1,
         )
 
     def zeros(self) -> dict:
@@ -170,8 +175,6 @@ def send_message(
     pre_tokens: Optional[np.ndarray] = None,
     pre_packed: Optional[np.ndarray] = None,
     dec_packed: Optional[np.ndarray] = None,
-    last_valid: bool = False,
-    use_prefill: bool = False,
     fsm_used: bool = False,
     score: "Optional[tuple[int, int]]" = None,
 ) -> None:
@@ -187,11 +190,11 @@ def send_message(
         msg["pre_tokens"][:k, :bucket] = pre_tokens
         msg["pre_packed"][:k, :pre_packed.shape[1]] = pre_packed
     if dec_packed is not None:
+        k = shapes.decode_steps
         msg["dec_packed"][:, :] = dec_packed
-    msg["ctrl"][:6] = (op, k, bucket, int(last_valid), int(use_prefill),
-                       int(fsm_used))
+    msg["ctrl"][:4] = (op, k, bucket, int(fsm_used))
     if score is not None:
-        msg["ctrl"][6:8] = score
+        msg["ctrl"][4:6] = score
     _broadcast(msg)
 
 
@@ -255,7 +258,7 @@ def send_score_payload(tokens: np.ndarray) -> None:
 
 
 def receive_score_payload(width: int) -> np.ndarray:
-    """Follower: receive the [1, width] token row (width from ctrl[6])."""
+    """Follower: receive the [1, width] token row (width from ctrl[4])."""
     return np.asarray(_broadcast(np.zeros((1, width), np.int32)))
 
 
@@ -279,8 +282,10 @@ def follower_loop(engine: Any) -> None:
     The engine instance holds the sharded params/cache (global arrays whose
     addressable shards live on this host's chips) and the same jitted
     packed executables; this loop feeds them the broadcast inputs. By SPMD
-    determinism the follower's ``last_toks``/``prefill_toks`` outputs are
-    the same global arrays the coordinator wired into its decode merges.
+    determinism the follower's newest decode and prefill outputs
+    (``engine._unread_toks`` / ``_unread_prefill_toks``, kept where the
+    coordinator keeps its own) are the same global arrays the coordinator
+    passes its decode steps.
     """
     import jax.numpy as jnp
 
@@ -294,12 +299,9 @@ def follower_loop(engine: Any) -> None:
     shapes = ProtoShapes.from_engine_config(engine.config,
                                             engine.model_config)
     pps = engine.config.pages_per_slot
-    last_toks = engine._zeros_B
-    prefill_toks = engine._zeros_1
     while True:
         m = receive_message(shapes)
-        op, k, bucket, last_valid, use_prefill, fsm_used = (
-            int(x) for x in m["ctrl"][:6])
+        op, k, bucket, fsm_used = (int(x) for x in m["ctrl"][:4])
         if op == MSG_SHUTDOWN:
             return
         if op == MSG_IDLE:
@@ -322,7 +324,7 @@ def follower_loop(engine: Any) -> None:
             # every process inside the same executable
             from llms_on_kubernetes_tpu.engine.sampling import LOGPROB_TOPK
 
-            width, n = int(m["ctrl"][6]), int(m["ctrl"][7])
+            width, n = int(m["ctrl"][4]), int(m["ctrl"][5])
             toks = receive_score_payload(width)
             engine._score_jit(engine.params, engine.model_config,
                               jnp.asarray(toks), jnp.asarray([n], jnp.int32),
@@ -331,11 +333,10 @@ def follower_loop(engine: Any) -> None:
         if op == MSG_MM_PREFILL:
             images, pos3 = receive_mm_payload(
                 shapes, engine.model_config.vision.num_channels, bucket)
-            _pack, toks = engine._mm_execute(
+            _pack, engine._unread_prefill_toks = engine._mm_execute(
                 images, m["pre_tokens"][:k, :bucket],
                 m["pre_packed"][:k, :_PRE_COLS + pps],
                 None if pos3 is None else pos3[None])
-            prefill_toks = toks
             continue
         fsm = engine._fsm_args() if fsm_used else None
         if op in (MSG_PREFILL, MSG_CHUNK):
@@ -343,27 +344,21 @@ def follower_loop(engine: Any) -> None:
             tokens = jnp.asarray(m["pre_tokens"][:k, :bucket])
             packed = jnp.asarray(m["pre_packed"][:k, :cols])
             fn = engine._prefill_packed if op == MSG_PREFILL else engine._chunk_packed
-            (_pack, toks, engine.k_pages, engine.v_pages,
-             engine.token_counts, new_state) = fn(
+            (_pack, engine._unread_prefill_toks, engine.k_pages,
+             engine.v_pages, engine.token_counts, new_state) = fn(
                 engine.params, engine.model_config, tokens, packed,
                 engine.k_pages, engine.v_pages, engine.token_counts,
                 engine._key, fsm,
             )
-            if new_state is not None:
-                engine._fsm_state = new_state
-            prefill_toks = toks
         elif op == MSG_DECODE:
-            packed = jnp.asarray(m["dec_packed"])
-            last = last_toks if last_valid else engine._zeros_B
-            pre = prefill_toks if use_prefill else engine._zeros_1
-            (_pack, toks, engine.k_pages, engine.v_pages,
-             engine.token_counts, new_state) = engine._decode_packed(
-                engine.params, engine.model_config, packed, last, pre,
-                engine.k_pages, engine.v_pages, engine.token_counts,
-                engine._key, fsm,
+            (_pack, engine._unread_toks, engine.k_pages, engine.v_pages,
+             engine.token_counts, new_state) = engine._decode_multi(
+                engine.params, engine.model_config, k,
+                jnp.asarray(m["dec_packed"]), engine._unread_toks,
+                engine._unread_prefill_toks, engine.k_pages, engine.v_pages,
+                engine.token_counts, engine._key, fsm,
             )
-            if new_state is not None:
-                engine._fsm_state = new_state
-            last_toks = toks
         else:
             raise ValueError(f"unknown multihost op {op}")
+        if new_state is not None:
+            engine._fsm_state = new_state

@@ -485,6 +485,63 @@ def test_decode_launch_with_nothing_in_flight_traces_nothing_new():
     assert req.output == ref[0].output
 
 
+def _decode_steps_of(eng):
+    """The engine's jitted decode steps: the plain one and the verify."""
+    return sorted(n for n in vars(eng) if n.startswith("_decode_"))
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_every_decode_dispatch_is_the_one_step(k):
+    """K is a number, not a path: at decode_steps 1 and 4 alike every
+    decode dispatch the ledger books (``dispatches`` of GET /debug/engine)
+    is ``_decode_multi_packed_step`` at a shape ``Kx<rows>``, launched by
+    the one window launcher; the engine holds no other plain decode step,
+    and the stream is the synchronous reference's."""
+    eng = _mk(True, depth=2, decode_steps=k)
+    reqs = _run_batch(eng, PROMPTS[:4], max_tokens=9)
+    eng._drain_async()
+    decodes = [d for d in eng.ledger.dispatches_view()
+               if d["kind"] == "decode"]
+    assert decodes
+    for d in decodes:
+        assert d["name"] == "_decode_multi_packed_step"
+        assert d["shape"].startswith(f"{k}x")
+    assert _decode_steps_of(eng) == ["_decode_multi", "_decode_spec"]
+    assert sum(eng.decode_launches.values()) == len(decodes)
+    ref_eng = _mk(False, decode_steps=k)
+    ref = _run_batch(ref_eng, PROMPTS[:4], max_tokens=9)
+    for r, s in zip(reqs, ref):
+        assert r.output == s.output and r.finish_reason == s.finish_reason
+    # the reference loop enters the same step, a window of one at a time
+    assert {(d["name"], d["shape"][:2])
+            for d in ref_eng.ledger.dispatches_view()
+            if d["kind"] == "decode"} == {("_decode_multi_packed_step", "1x")}
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_a_row_whose_budget_is_in_flight_rides_masked(k):
+    """The window planner's rule holds at every K: once what is in flight
+    covers a request's max_tokens, no further token is planned for it (the
+    launch reports "paced" where every row is covered), so no dispatch's
+    tokens are thrown away for want of budget."""
+    eng = _mk(True, depth=3, decode_steps=k)
+    reqs = _run_batch(eng, PROMPTS[:2], max_tokens=6)
+    eng._drain_async()
+    assert [len(r.output) for r in reqs] == [6, 6]
+    assert all(r.finish_reason == "length" for r in reqs)
+    assert eng.early_exit_steps == 0
+    # max_tokens = 1: the first token is the whole answer and the
+    # admission's launch has nothing to plan
+    one = eng.submit([5, 6, 7], SamplingParams(temperature=0.0,
+                                               max_tokens=1))
+    eng._admit_wake.clear()
+    admitted = eng._admit_async([])
+    assert eng._launch_decode_async(admitted, []) == "paced"
+    while not one.finished:
+        eng.step()
+    assert len(one.output) == 1 and not eng._inflight
+
+
 # ---------------------------------------------------------------------------
 # when a steady-state decode window is launched (PR 31): a lead before the
 # device is estimated to run dry, not as soon as the pipeline has room.

@@ -119,7 +119,7 @@ def test_multihost_message_struct_fixed_shape():
 
 
 def test_multihost_score_message_roundtrip(monkeypatch):
-    """MSG_SCORE framing (PR 3): ctrl[6:8] carries (padded width, true
+    """MSG_SCORE framing (PR 3): ctrl[4:6] carries (padded width, true
     length); the follow-up payload broadcast ships the [1, width] token
     row. Coordinator-side sends are replayed through the follower-side
     receive helpers — same bytes out, same bytes in."""
@@ -141,7 +141,7 @@ def test_multihost_score_message_roundtrip(monkeypatch):
     monkeypatch.setattr(mh, "_broadcast", lambda v: next(replay))
     m = mh.receive_message(shapes)
     assert int(m["ctrl"][0]) == mh.MSG_SCORE
-    width, n = int(m["ctrl"][6]), int(m["ctrl"][7])
+    width, n = int(m["ctrl"][4]), int(m["ctrl"][5])
     assert (width, n) == (32, 5)
     got = mh.receive_score_payload(width)
     np.testing.assert_array_equal(got, toks)
@@ -167,7 +167,7 @@ def test_multihost_score_prompt_broadcasts_and_matches_single_host(
     assert len(sent) == 2  # one control word + one token payload
     ctrl = sent[0]["ctrl"]
     assert int(ctrl[0]) == mh.MSG_SCORE
-    assert (int(ctrl[6]), int(ctrl[7])) == (16, len(prompt))
+    assert (int(ctrl[4]), int(ctrl[5])) == (16, len(prompt))
     assert sent[1].shape == (1, 16)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     assert got[1] == want[1]
