@@ -165,18 +165,22 @@ _MAX_RMW_PAGES = 33
 # mid-process is no longer silently ignored (it was never re-read; now it
 # is explicitly documented as resolved once at EngineConfig construction).
 #
-# "fused": the decode write folds INTO the Pallas attention kernel
-# (ops/attention.dispatch_paged_attention_write) — no separate write op
-# at all; int8 KV pools route to the quantize-at-write twin kernel
-# (pool bytes match this module's quantize_kv bit-for-bit); falls back
-# to "dus" behavior wherever the fused kernels don't apply (CP meshes,
-# traced windows, small head_dim, int8 with page_size % 128 on real
-# TPU). Opt-in
-# (LLMK_KV_WRITE=fused) until validated on hardware: the kernel is only
-# interpreter-tested on CPU, and a silent KV corruption is the worst
-# failure mode a serving engine can ship as a default.
+# "fused" (the default): the decode write folds INTO the
+# Pallas attention kernel (ops/attention.dispatch_paged_attention_write) —
+# no separate write op at all; int8 KV pools route to the
+# quantize-at-write twin kernel (pool bytes match this module's
+# quantize_kv bit-for-bit). The dispatcher takes the kernel wherever it
+# observes, at trace time, that it applies, and is exactly "dus" wherever
+# it does not (CP meshes, traced windows, head_dim not a multiple of 128,
+# int8 at a page_size that is not a multiple of 128, the XLA path
+# off-TPU). Measured on a v5e at mistral-7b's shapes (PERF.md §6, PR 34):
+# the Mosaic kernel leaves the pool byte-identical to the "dus" loop and
+# updates it in place inside the K-step scan. The int8 twin has not been
+# timed in a cell (PERF.md §7). "dus" | "scatter" | "scatter-linear"
+# force the two-op path with that write.
 KV_WRITE_STRATEGIES = ("fused", "dus", "scatter", "scatter-linear")
-_active_kv_write = "dus"
+_DEFAULT_KV_WRITE = "fused"
+_active_kv_write = _DEFAULT_KV_WRITE
 
 
 def set_kv_write_strategy(strategy: str) -> None:
@@ -195,11 +199,11 @@ def default_kv_write_strategy() -> str:
     """Resolve the env default ONCE (EngineConfig construction time)."""
     import os
 
-    s = os.environ.get("LLMK_KV_WRITE", "dus")
+    s = os.environ.get("LLMK_KV_WRITE", _DEFAULT_KV_WRITE)
     # legacy spelling: LLMK_KV_WRITE=scatter + LLMK_SCATTER_VARIANT=linear
     if s == "scatter" and os.environ.get("LLMK_SCATTER_VARIANT") == "linear":
         s = "scatter-linear"
-    return s if s in KV_WRITE_STRATEGIES else "dus"
+    return s if s in KV_WRITE_STRATEGIES else _DEFAULT_KV_WRITE
 
 
 def _scatter_decode_writes() -> bool:
@@ -335,13 +339,15 @@ def write_tokens(
             lpid = pid - base
             owned = (lpid >= 0) & (lpid < width)
             pid = jnp.where(owned, lpid, 0)
-        # NOTE(measured, round 3): the unrolled per-slot DUS below costs
-        # ~3 ms/step at B=64 (4096 tiny ops). A batched Pallas write kernel
-        # (group read-merge-write per slot, all slots in one program) was
-        # prototyped and is bit-exact on TPU, but its unrolled DMA body
-        # made the STEP's Mosaic compile blow up at B=64 (64 kernel
-        # instances x 3*B DMA ops), and a grid/fori variant's per-slot
-        # serialization lands near DUS cost anyway — so DUS stays.
+        # The unrolled per-slot DUS below is pure op overhead: 0.9 us an
+        # op, 2 x B ops a layer whatever the occupancy (1.9 ms of a
+        # 16.6 ms mistral-7b token step at B=32 on a v5e: PERF.md §6,
+        # PR 34). The decode step no longer comes here where the paged
+        # decode kernel applies: the append rides inside that kernel
+        # (ops/attention.dispatch_paged_attention_write). This loop is
+        # what every other shape, mesh and backend still takes (a batched
+        # Pallas write kernel of its own was bit-exact but made the step's
+        # Mosaic compile blow up at B=64).
         for b in range(B):
             upd_k = k[b, 0].astype(dt)[:, None, None, :]   # [n_kv, 1, 1, d]
             upd_v = v[b, 0].astype(dt)[:, None, None, :]

@@ -215,9 +215,11 @@ class EngineConfig:
     max_grammars: int = 4
     grammar_states: int = 4096
     grammar_classes: int = 512
-    # decode KV write strategy: "dus" (default) | "scatter" |
-    # "scatter-linear" | "fused" (opt-in until hardware-validated —
-    # cache.py discusses the tradeoff). None => the LLMK_KV_WRITE env
+    # decode KV write strategy: "fused" (default: the append rides inside
+    # the paged decode kernel wherever the dispatcher observes that the
+    # kernel applies, and is "dus" elsewhere; measured on the chip, PERF.md
+    # §6, PR 34) | "dus" | "scatter" | "scatter-linear" (cache.py
+    # discusses the tradeoff). None => the LLMK_KV_WRITE env
     # default, resolved ONCE in __post_init__ — the strategy is part of
     # the engine's static config and baked into its executables, so env
     # mutation after construction has no effect (by design, documented)

@@ -438,18 +438,21 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
                                    sliding_window=None, attn_softcap=None):
     """Decode attention WITH the current token's KV append.
 
-    Under kv_write="fused" the write folds INTO the attention kernel
-    (pallas_paged.pallas_paged_attention_write): the per-slot program DMAs
-    the new row into the pool in place and merges the current token's
-    contribution in registers — eliminating the per-slot DUS write loop
-    (~3 ms/step of dispatch overhead at B=64, round-4 profile). int8 KV
-    pools take the quantize-at-write twin
-    (pallas_paged_attention_write_int8): the new row is quantized in
-    registers with the same arithmetic as cache.quantize_kv, so pool
-    bytes match the DUS path exactly. Anywhere the fused kernels don't
-    apply (CP meshes, whatever _paged_kernel_mode rules out, sub-8 page
-    sizes on real TPU, kv_write config other than "fused") this is
-    exactly write_tokens + dispatch_paged_attention.
+    Wherever the paged decode kernel applies (_paged_kernel_mode: compiled
+    or interpreted Pallas, a static window, and on the chip a lane-aligned
+    head_dim, a page that is a multiple of 8 and a slot inside the VMEM
+    budget) and the mesh has no seq axis, the write folds INTO the
+    attention kernel (pallas_paged.pallas_paged_attention_write): the
+    per-slot program DMAs the new row into the pool in place and merges
+    the current token's contribution in registers, and the per-slot DUS
+    write loop (2 x slots ops a layer, 1.9 ms of a 16.6 ms mistral-7b
+    token step on a v5e: PERF.md §6, PR 34) is gone. int8 KV pools take
+    the quantize-at-write twin (pallas_paged_attention_write_int8): the
+    new row is quantized in registers with the same arithmetic as
+    cache.quantize_kv, so pool bytes match the DUS path. Everywhere else,
+    and under a kv_write setting other than "fused", this is exactly
+    write_tokens + dispatch_paged_attention. The choice is made from what
+    is observed at trace time, so it holds for a whole executable.
 
     q [B, n_q, d]; k_new/v_new [B, n_kv, d] (post-rope);
     write_positions [B, 1] (negative => idle/trash).

@@ -421,6 +421,26 @@ def _paged_kernel_write(
     cached = length - 1                       # tokens already in the pool
     n_pages = (cached + page_size - 1) // page_size
 
+    # The new row's write is an 8-token-block READ-MODIFY-WRITE (Mosaic
+    # requires page-dim slices be 8-sublane-tile aligned): fetch the
+    # aligned block the new token lands in, splice the row in with a
+    # vector select, DMA the block back. The block's other rows are the
+    # same slot's own earlier tokens (pages are slot-private at the write
+    # position — adopted prefix pages always end before it) or unwritten
+    # garbage, both of which round-trip unchanged. The block's fetch is
+    # started WITH the page DMAs and waited for with them, so it costs no
+    # DMA round trip of its own.
+    pos = jnp.maximum(cached, 0)
+    w_pid = page_table_ref[b, pos // page_size]
+    off8 = pl.multiple_of((pos % page_size) // 8 * 8, 8)
+
+    @pl.when(length > 0)
+    def _write_fetch():
+        pltpu.make_async_copy(
+            k_hbm.at[:, w_pid, pl.ds(off8, 8)], kblk, wsem.at[0]).start()
+        pltpu.make_async_copy(
+            v_hbm.at[:, w_pid, pl.ds(off8, 8)], vblk, wsem.at[1]).start()
+
     for i in range(pages_per_seq):
         @pl.when(i < n_pages)
         def _start(i=i):
@@ -449,27 +469,10 @@ def _paged_kernel_write(
                 sems.at[1, i],
             ).wait()
 
-    # Write-back of the new row, AFTER the cached-page reads are done (the
-    # target page is often in this program's own read set — its stale
-    # lanes beyond `cached` are masked, so read-then-write order is safe).
-    # Mosaic requires page-dim slices be 8-sublane-tile aligned, so this
-    # is an 8-token-block READ-MODIFY-WRITE: fetch the aligned block the
-    # new token lands in, splice the row in with a vector select, DMA the
-    # block back. The block's other rows are the same slot's own earlier
-    # tokens (pages are slot-private at the write position — adopted
-    # prefix pages always end before it) or unwritten garbage, both of
-    # which round-trip unchanged.
-    pos = jnp.maximum(cached, 0)
-    w_pid = page_table_ref[b, pos // page_size]
-    off8 = pl.multiple_of((pos % page_size) // 8 * 8, 8)
-
-    @pl.when(length > 0)
-    def _write_fetch():
-        pltpu.make_async_copy(
-            k_hbm.at[:, w_pid, pl.ds(off8, 8)], kblk, wsem.at[0]).start()
-        pltpu.make_async_copy(
-            v_hbm.at[:, w_pid, pl.ds(off8, 8)], vblk, wsem.at[1]).start()
-
+    # Write-back AFTER the cached-page reads are done (the target page is
+    # often in this program's own read set — its stale lanes beyond
+    # `cached` are masked, so read-then-write order is safe); it overlaps
+    # the attention below and is waited for at the end.
     @pl.when(length > 0)
     def _write_back():
         pltpu.make_async_copy(
@@ -503,6 +506,9 @@ def _paged_kernel_write(
             vblk, v_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[1]).wait()
 
 
+@functools.partial(
+    jax.jit, static_argnames=("scale", "sliding_window", "attn_softcap", "interpret")
+)
 def pallas_paged_attention_write(
     q: jnp.ndarray,            # [B, n_q, d]
     k_pages: jnp.ndarray,      # [n_kv, P, page, d] (head-major pool; donated)
@@ -872,6 +878,9 @@ def _paged_kernel_write_int8(
             vsrow, vs_out.at[:, w_pid], wsem.at[3]).wait()
 
 
+@functools.partial(
+    jax.jit, static_argnames=("scale", "sliding_window", "attn_softcap", "interpret")
+)
 def pallas_paged_attention_write_int8(
     q: jnp.ndarray,            # [B, n_q, d]
     k_data: jnp.ndarray,       # [n_kv, P, page, d] int8 (donated)

@@ -154,9 +154,11 @@ def test_scatter_decode_writes_match_dus():
     import jax.numpy as jnp
 
     from llms_on_kubernetes_tpu.engine.cache import (
-        CacheConfig, init_pages, set_kv_write_strategy, write_tokens,
+        CacheConfig, init_pages, kv_write_strategy, set_kv_write_strategy,
+        write_tokens,
     )
 
+    before = kv_write_strategy()
     for kv_dtype in (None, "int8"):
         cfg = CacheConfig(num_layers=1, num_kv_heads=2, head_dim=8,
                           num_pages=24, page_size=4, pages_per_slot=4,
@@ -179,7 +181,7 @@ def test_scatter_decode_writes_match_dus():
                     np.asarray(kp2.data), np.asarray(vp2.data),
                     None if kp2.scale is None else np.asarray(kp2.scale))
         finally:
-            set_kv_write_strategy("dus")
+            set_kv_write_strategy(before)
         for mode in ("scatter", "scatter-linear"):
             for a, b in zip(outs["dus"], outs[mode]):
                 if a is not None:

@@ -466,6 +466,10 @@ def test_kv_write_config_plumbing(monkeypatch):
     monkeypatch.delenv("LLMK_SCATTER_VARIANT", raising=False)
     cfg = EngineConfig(model="debug-tiny", kv_write="scatter")
     assert cfg.kv_write == "scatter"
+    # unset: the fused append, which the dispatcher takes only where it
+    # observes that the kernel applies (tests/test_fused_decode_step.py)
+    assert EngineConfig(model="debug-tiny").kv_write == "fused"
+    monkeypatch.setenv("LLMK_KV_WRITE", "dus")
     assert EngineConfig(model="debug-tiny").kv_write == "dus"
     monkeypatch.setenv("LLMK_KV_WRITE", "scatter")
     monkeypatch.setenv("LLMK_SCATTER_VARIANT", "linear")

@@ -1,0 +1,175 @@
+"""The decode kernels of the main path, compiled for a v5e that is
+described and not attached (no chip, a few seconds each).
+
+Interpret mode cannot see what Mosaic refuses (a slice off the tiling, too
+much VMEM) nor whether XLA keeps an aliased pool in place; the chip's
+compiler, which is installed here, can. Nothing runs: results and times are
+tests/test_tpu_hardware.py's, on the chip. The topology is described inside
+a fixture, never at import (one process at a time may load the TPU's
+library, and every xdist worker imports this file).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# the dispatchers import ops/cp.py when they run; that module and the
+# decoder import each other, and only this order resolves
+import llms_on_kubernetes_tpu.models.decoder  # noqa: F401
+
+N_KV, GROUP, D = 8, 4, 128             # mistral-7b attention heads
+ROWS, PAGE, PPS, PAGES = 32, 64, 32, 769   # the mistral-7b.chat cell
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _operands(dev, kv_dtype, page, pps, heads=None, pools=None):
+    """Shapes of dispatch_paged_attention_write's operands on ``dev``; a
+    tensor-parallel case places q / k_new / v_new by ``heads`` and the
+    pools by ``pools``."""
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
+
+    def sds(shape, dtype, sharding=None):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding or dev)
+
+    def pool():
+        data = sds((N_KV, PAGES, page, D),
+                   jnp.int8 if kv_dtype == "int8" else jnp.bfloat16, pools)
+        scale = (sds((N_KV, PAGES, page), jnp.float32, pools)
+                 if kv_dtype == "int8" else None)
+        return KVPool(data, scale)
+
+    return (sds((ROWS, N_KV * GROUP, D), jnp.bfloat16, heads), pool(), pool(),
+            sds((ROWS, pps), jnp.int32), sds((ROWS,), jnp.int32),
+            sds((ROWS, N_KV, D), jnp.bfloat16, heads),
+            sds((ROWS, N_KV, D), jnp.bfloat16, heads),
+            sds((ROWS, 1), jnp.int32))
+
+
+def _compile_dispatch(args):
+    from llms_on_kubernetes_tpu.ops import attention
+
+    step = jax.jit(
+        lambda *a: attention.dispatch_paged_attention_write(
+            *a, scale=D ** -0.5, sliding_window=4096),
+        donate_argnums=(1, 2))
+    return step.lower(*args).compile()
+
+
+# pool type, page, pages a slot -> what the dispatcher must say it took
+CASES = {
+    "bf16 page 64 (the cell)": (None, 64, 32, "fused write+attend kernel"),
+    "int8 page 128": ("int8", 128, 16, "fused int8 write+attend kernel"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_decode_append_rides_the_kernel_in_place(one_chip, no_cache,
+                                                 monkeypatch, case):
+    """With nothing set, the decode dispatcher takes the fused write+attend
+    kernel at the serving geometry; Mosaic accepts it; and the compiled
+    program writes no row by ``dynamic-update-slice``, copies no pool and
+    hands both pools back in the buffers they came in."""
+    from llms_on_kubernetes_tpu.engine import cache
+    from llms_on_kubernetes_tpu.ops import attention
+
+    kv_dtype, page, pps, why = CASES[case]
+    assert cache.kv_write_strategy() == "fused"
+    # the code asks the backend, which is the CPU here
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    args = _operands(one_chip, kv_dtype, page, pps)
+    compiled = _compile_dispatch(args)
+    assert attention._chosen["decode"] == ("pallas-compiled", why)
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert " dynamic-update-slice(" not in hlo
+    pools = sum(leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(args[1:3]))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools // 8     # no copy of a pool
+
+
+def test_two_op_setting_still_compiles_the_dus_loop(one_chip, no_cache,
+                                                    monkeypatch):
+    """``kv_write="dus"`` (the path every shape the kernel does not take
+    falls back to) keeps its per-slot loop and the plain paged kernel."""
+    from llms_on_kubernetes_tpu.engine import cache
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setattr(cache, "_active_kv_write", "dus")
+    hlo = _compile_dispatch(_operands(one_chip, None, PAGE, PPS)).as_text()
+    assert attention._chosen["decode"] == ("pallas-compiled", "paged kernel")
+    assert hlo.count("tpu_custom_call") == 1
+    assert hlo.count(" dynamic-update-slice(") >= 2 * ROWS
+
+
+def test_tensor_parallel_append_stays_on_each_chips_heads(topo, no_cache,
+                                                          monkeypatch):
+    """``--tp 4``: each chip runs the fused kernel on its own two KV heads
+    (shard_map over ``model``) and writes its own shard of the pools in
+    place; nothing gathers a pool for the unpartitionable custom call."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llms_on_kubernetes_tpu.configs import get_config
+    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.parallel.mesh import (
+        AXIS_MODEL, make_mesh, set_active_mesh,
+    )
+    from llms_on_kubernetes_tpu.parallel.sharding import pool_sharding
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    mesh = make_mesh(model=4, devices=list(topo.devices)[:4])
+    args = _operands(
+        NamedSharding(mesh, P()), None, PAGE, PPS,
+        heads=NamedSharding(mesh, P(None, AXIS_MODEL)),
+        pools=pool_sharding(get_config("mistral-7b"), mesh))
+    set_active_mesh(mesh)
+    try:
+        compiled = _compile_dispatch(args)
+    finally:
+        set_active_mesh(None)
+    assert attention._chosen["decode"] == (
+        "pallas-compiled", "fused write+attend kernel")
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "all-gather" not in hlo
+    assert " dynamic-update-slice(" not in hlo
+    shard = sum(leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(args[1:3])) // 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= shard
+    assert mem.temp_size_in_bytes < shard // 8
