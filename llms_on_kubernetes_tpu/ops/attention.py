@@ -425,10 +425,10 @@ def _paged_kernel_mode(q, k_pages, page_table, sliding_window):
             return None, f"head_dim {d} is not a multiple of 128"
         if quantized and page % 128 != 0:
             return None, f"int8 KV needs page_size % 128 == 0, got {page}"
-    need = paged_vmem_bytes(n_kv, page_table.shape[1] * page, d,
-                            kd.dtype.itemsize, quantized)
+    need = paged_vmem_bytes(n_kv, page, page_table.shape[1], d, kd.dtype,
+                            quantized)
     if need > VMEM_BUDGET_BYTES:
-        return None, (f"slot of {page_table.shape[1] * page} tokens needs "
+        return None, (f"a block of {n_kv} x {d} heads needs "
                       f"{_mib(need)} VMEM > {_mib(VMEM_BUDGET_BYTES)} budget")
     return mode, ""
 
@@ -440,16 +440,21 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
 
     Wherever the paged decode kernel applies (_paged_kernel_mode: compiled
     or interpreted Pallas, a static window, and on the chip a lane-aligned
-    head_dim, a page that is a multiple of 8 and a slot inside the VMEM
-    budget) and the mesh has no seq axis, the write folds INTO the
-    attention kernel (pallas_paged.pallas_paged_attention_write): the
-    per-slot program DMAs the new row into the pool in place and merges
-    the current token's contribution in registers, and the per-slot DUS
-    write loop (2 x slots ops a layer, 1.9 ms of a 16.6 ms mistral-7b
-    token step on a v5e: PERF.md §6, PR 34) is gone. int8 KV pools take
-    the quantize-at-write twin (pallas_paged_attention_write_int8): the
-    new row is quantized in registers with the same arithmetic as
-    cache.quantize_kv, so pool bytes match the DUS path. Everywhere else,
+    head_dim, a page that is a multiple of 8 and a block's staging inside
+    the VMEM budget) and the mesh has no seq axis, the write folds INTO
+    the attention kernel (pallas_paged.pallas_paged_attention_write): one
+    program a slot, run in turn, each attending its row a 512-token block
+    at a time while the next block (the next LIVE slot's first, from a
+    row's last) and that slot's 8-row write block are already being
+    fetched; a live slot's program splices the new row into its write
+    block, DMAs it back into the pool in place and merges the current
+    token's contribution in registers; an idle slot's moves nothing. The
+    per-slot DUS write loop (2 x slots ops a layer, 1.9 ms of a 16.6 ms
+    mistral-7b token step on a v5e: PERF.md §6, PR 34) is gone. int8 KV
+    pools take the quantize-at-write twin
+    (pallas_paged_attention_write_int8): the new row is quantized in
+    registers with the same arithmetic as cache.quantize_kv, so pool
+    bytes match the DUS path. Everywhere else,
     and under a kv_write setting other than "fused", this is exactly
     write_tokens + dispatch_paged_attention. The choice is made from what
     is observed at trace time, so it holds for a whole executable.

@@ -101,8 +101,20 @@ def test_pallas_int8_kernel_matches_xla_reference():
                                rtol=2e-5, atol=2e-5)
 
 
+# cached tokens a row, page, pages a slot. "three blocks": 1536-token slots
+# of three 512-token attention blocks, so the kernel's pipeline (the next
+# live row's pages and scales fetched while this row attends) runs inside
+# a row and across an idle one; window 9 then skips two leading blocks.
+INT8_WRITE_GEOMETRY = {
+    "one block": ([13, 16, 1, 0, 31], 8, 4),
+    "three blocks": ([1499, 39, 0, 1535, 600], 64, 24),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(INT8_WRITE_GEOMETRY))
 @pytest.mark.parametrize("window,softcap", [(None, None), (9, None), (None, 40.0)])
-def test_fused_write_int8_k1_matches_write_tokens(window, softcap):
+def test_fused_write_int8_k1_matches_write_tokens(window, softcap, geometry,
+                                                  interpret=True):
     """The quantize-at-write twin of the fused decode kernel must match
     write_tokens on an int8 pool: same attention rows, and — outside the
     never-read trash page 0 — the same int8 bytes exactly, scales to
@@ -116,8 +128,9 @@ def test_fused_write_int8_k1_matches_write_tokens(window, softcap):
     )
 
     rng = np.random.default_rng(3)
-    KV, group, d, page, pps = 2, 2, 8, 8, 4
-    hist = np.asarray([13, 16, 1, 0, 31], np.int32)
+    hist, page, pps = INT8_WRITE_GEOMETRY[geometry]
+    KV, group, d = 2, 2, 8
+    hist = np.asarray(hist, np.int32)
     B, n_q = len(hist), KV * group
     P = B * pps + 1
     cc = CacheConfig(num_layers=1, num_kv_heads=KV, head_dim=d, num_pages=P,
@@ -150,7 +163,7 @@ def test_fused_write_int8_k1_matches_write_tokens(window, softcap):
     out, kd2, ks2, vd2, vs2 = pallas_paged_attention_write_int8(
         q, kp.data, kp.scale, vp.data, vp.scale, table, lengths,
         k_new, v_new, scale=d ** -0.5, sliding_window=window,
-        attn_softcap=softcap, interpret=True)
+        attn_softcap=softcap, interpret=interpret)
     act = np.asarray(lengths) > 0
     np.testing.assert_allclose(np.asarray(out)[act], np.asarray(ref)[act],
                                rtol=2e-5, atol=2e-5)

@@ -70,11 +70,62 @@ def _paged_setup(rng, B, n_kv, d, page, pages_per_seq, lengths):
     return k_pages, v_pages, jnp.asarray(table)
 
 
-@pytest.mark.parametrize("window,softcap", [(None, None), (7, None), (None, 50.0)])
-def test_paged_decode_matches_reference(rng, window, softcap):
-    B, n_q, n_kv, d, page, pps = 3, 4, 2, 8, 4, 4
-    lengths_np = np.asarray([13, 16, 5], np.int32)
+# The paged decode kernels run ONE pipeline across their grid steps (the
+# next live row's pages are fetched while this row attends; ops/
+# pallas_paged.py). What that can get wrong, at a geometry with several
+# attention blocks a slot (page 64 x 24 pages: three blocks of 512 tokens):
+# name -> (lengths incl. the current token, sliding window, rows whose first
+# page is ONE adopted prefix page)
+PIPE = dict(n_kv=2, group=2, d=8, page=64, pps=24)
+PIPELINE_CASES = {
+    "all rows idle": ([0, 0, 0, 0], None, ()),
+    "first live row is not row 0 and the last row is live":
+        ([0, 0, 70, 0, 0, 0, 9, 0, 130], None, ()),
+    "one token between two long rows": ([600, 1, 1100], None, ()),
+    "three blocks next to one page": ([1500, 40, 0, 1536, 33], None, ()),
+    "window skips leading blocks": ([1500, 1030, 0, 520, 1], 600, ()),
+    "two rows share a prefix page": ([200, 0, 300], None, (0, 2)),
+}
+# the parent commit's kernels on these inputs, bit for bit (block size and
+# order of arithmetic did not change with the pipeline)
+GOLDEN = {"one token between two long rows", "window skips leading blocks"}
+
+
+def _golden(kernel, case):
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "data",
+                        "paged_decode_golden.npz")
+    return np.load(path)[f"{kernel}: {case}"]
+
+
+def _share_first_page(table, share):
+    """Rows ``share[1:]`` adopt row ``share[0]``'s first page."""
+    table = np.asarray(table).copy()
+    for b in share[1:]:
+        table[b, 0] = table[share[0], 0]
+    return jnp.asarray(table)
+
+
+# name -> (geometry, lengths, window, softcap, rows sharing a first page)
+_SMALL = dict(n_kv=2, group=2, d=8, page=4, pps=4)
+DECODE_CASES = {
+    "small": (_SMALL, [13, 16, 5], None, None, ()),
+    "small, window 7": (_SMALL, [13, 16, 5], 7, None, ()),
+    "small, softcap 50": (_SMALL, [13, 16, 5], None, 50.0, ()),
+    **{name: (PIPE, lengths, window, None, share)
+       for name, (lengths, window, share) in PIPELINE_CASES.items()},
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_paged_decode_matches_reference(rng, case):
+    geo, lengths_np, window, softcap, share = DECODE_CASES[case]
+    n_kv, d, page, pps = geo["n_kv"], geo["d"], geo["page"], geo["pps"]
+    lengths_np = np.asarray(lengths_np, np.int32)
+    B, n_q = len(lengths_np), n_kv * geo["group"]
     k_pages, v_pages, table = _paged_setup(rng, B, n_kv, d, page, pps, lengths_np)
+    table = _share_first_page(table, share)
     q = jnp.asarray(rng.normal(size=(B, n_q, d)), jnp.float32)
     lengths = jnp.asarray(lengths_np)
     ref = paged_attention(q, k_pages, v_pages, table, lengths,
@@ -83,8 +134,13 @@ def test_paged_decode_matches_reference(rng, window, softcap):
     out = pallas_paged_attention(q, k_pages, v_pages, table, lengths,
                                  scale=d ** -0.5, sliding_window=window,
                                  attn_softcap=softcap, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    act = lengths_np > 0     # an idle row reads 0 here, an average there
+    np.testing.assert_allclose(np.asarray(out)[act], np.asarray(ref)[act],
                                rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(out)).all()
+    if case in GOLDEN:
+        np.testing.assert_array_equal(np.asarray(out),
+                                      _golden("plain", case))
 
 
 def test_paged_decode_idle_slot(rng):
@@ -151,12 +207,15 @@ def test_engine_greedy_identical_under_pallas(monkeypatch):
 
 
 def run_fused_write_case(rng, lengths_np, *, n_kv, group, d, page, pps,
-                         interpret, rtol=2e-5, atol=2e-5):
+                         interpret, rtol=2e-5, atol=2e-5, window=None,
+                         share=()):
     """One fused write+attend case against the DUS reference: same
     attention rows (active slots), finite output everywhere (idle rows
     must not NaN), and byte-identical pools outside the never-read trash
-    page 0. Shared with the hardware suite (test_tpu_hardware.py) so the
-    interpret-mode and Mosaic-lowered paths pin the SAME cases."""
+    page 0. ``share``: rows whose first page is one adopted prefix page
+    (read by each, written by none). Shared with the hardware suite
+    (test_tpu_hardware.py) so the interpret-mode and Mosaic-lowered paths
+    pin the SAME cases. Returns the attention rows."""
     from llms_on_kubernetes_tpu.engine.cache import KVPool, write_tokens
     from llms_on_kubernetes_tpu.ops.pallas_paged import (
         pallas_paged_attention_write,
@@ -166,6 +225,7 @@ def run_fused_write_case(rng, lengths_np, *, n_kv, group, d, page, pps,
     B, n_q = len(lengths_np), n_kv * group
     k_pages, v_pages, table = _paged_setup(rng, B, n_kv, d, page, pps,
                                            lengths_np)
+    table = _share_first_page(table, share)
     q = jnp.asarray(rng.normal(size=(B, n_q, d)), jnp.float32)
     k_new = jnp.asarray(rng.normal(size=(B, n_kv, d)), jnp.float32)
     v_new = jnp.asarray(rng.normal(size=(B, n_kv, d)), jnp.float32)
@@ -176,11 +236,11 @@ def run_fused_write_case(rng, lengths_np, *, n_kv, group, d, page, pps,
         KVPool(k_pages), KVPool(v_pages), k_new[:, None], v_new[:, None],
         table, jnp.asarray(wp))
     ref = paged_attention(q, kp_ref.data, vp_ref.data, table, lengths,
-                          scale=d ** -0.5)
+                          scale=d ** -0.5, sliding_window=window)
 
     out, kp2, vp2 = pallas_paged_attention_write(
         q, k_pages, v_pages, table, lengths, k_new, v_new,
-        scale=d ** -0.5, interpret=interpret)
+        scale=d ** -0.5, sliding_window=window, interpret=interpret)
     act = lengths_np > 0
     np.testing.assert_allclose(np.asarray(out)[act], np.asarray(ref)[act],
                                rtol=rtol, atol=atol)
@@ -192,6 +252,7 @@ def run_fused_write_case(rng, lengths_np, *, n_kv, group, d, page, pps,
                                   np.asarray(kp_ref.data)[:, 1:])
     np.testing.assert_array_equal(np.asarray(vp2)[:, 1:],
                                   np.asarray(vp_ref.data)[:, 1:])
+    return np.asarray(out)
 
 
 def test_paged_fused_write_page_boundary(rng):
@@ -204,15 +265,65 @@ def test_paged_fused_write_page_boundary(rng):
         n_kv=2, group=2, d=8, page=page, pps=pps, interpret=True)
 
 
-def test_paged_fused_write_idle_rows(rng):
-    """Idle rows (length 0): no NaN, no pool write. Both the all-idle
-    batch (every program skips its write) and idle rows interleaved with
-    active ones."""
-    # page >= 8: the kernel's read-modify-write block is 8 rows deep
-    run_fused_write_case(rng, [0, 0, 0],
-                         n_kv=1, group=2, d=8, page=8, pps=2, interpret=True)
-    run_fused_write_case(rng, [0, 5, 0, 8, 1],
-                         n_kv=2, group=2, d=8, page=8, pps=2, interpret=True)
+# page >= 8: the kernel's read-modify-write block is 8 rows deep
+WRITE_CASES = {
+    "every row idle": (dict(n_kv=1, group=2, d=8, page=8, pps=2),
+                       [0, 0, 0], None, ()),
+    "idle rows between live ones": (dict(n_kv=2, group=2, d=8, page=8, pps=2),
+                                    [0, 5, 0, 8, 1], None, ()),
+    **{name: (PIPE, *case) for name, case in PIPELINE_CASES.items()},
+}
+
+
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_paged_fused_write_idle_rows(rng, case):
+    """Idle rows (length 0): no NaN, no pool write, no DMA started for or
+    by them. The all-idle batch (every program skips its write), idle rows
+    interleaved with active ones, and what the pipeline across rows can
+    get wrong (PIPELINE_CASES)."""
+    geo, lengths, window, share = WRITE_CASES[case]
+    out = run_fused_write_case(rng, lengths, **geo, interpret=True,
+                               window=window, share=share)
+    if case in GOLDEN:
+        np.testing.assert_array_equal(out, _golden("write", case))
+
+
+_DEFERRED = """
+import numpy as np
+from jax.experimental.pallas import tpu as pltpu
+import test_kv_int8, test_pallas as T
+deferred = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                 detect_races=True,
+                                 uninitialized_memory="nan")
+for case in sorted(T.PIPELINE_CASES):
+    lengths, window, share = T.PIPELINE_CASES[case]
+    T.run_fused_write_case(np.random.default_rng(0), lengths, **T.PIPE,
+                           interpret=deferred, window=window, share=share)
+test_kv_int8.test_fused_write_int8_k1_matches_write_tokens(
+    9, None, "three blocks", interpret=deferred)
+from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu
+print("races", tpu.races.races_found)
+"""
+
+
+def test_paged_pipeline_with_dmas_that_land_at_their_wait():
+    """The plain interpreter copies at ``start``, so it cannot see a block
+    attended before its fetch was waited for, or a wait on the wrong half.
+    Pallas' TPU interpreter copies at the ``wait`` (uninitialised VMEM
+    reads NaN) and looks for races: the pipeline's cases, and the int8
+    twin, hold there too. In a process of its own, with a time limit: a
+    wait for a DMA nobody started does not fail, it hangs."""
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [here, os.path.dirname(here), os.environ.get("PYTHONPATH", "")]))
+    run = subprocess.run([sys.executable, "-c", _DEFERRED], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stderr[-3000:]
+    assert "races False" in run.stdout, run.stdout[-1000:]
 
 
 @pytest.mark.parametrize("window,softcap", [(None, None), (9, None), (None, 40.0)])
@@ -340,12 +451,18 @@ def test_dispatchers_say_what_they_took(rng, monkeypatch, capfd):
     monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
     attention.dispatch_prefill_attention(q, k, v, lengths, scale=0.3)
     assert attention._chosen["prefill"][0] == "pallas-interpret"
-    # a slot too long to stage in VMEM: XLA, and the reason says so
-    kp = jnp.zeros((8, 2, 64, 128), jnp.bfloat16)
+    # the kernels stage a block of 512 tokens, not a slot: a 32k-token slot
+    # of 8 x 128 heads (parent: 144 MiB of staging, the XLA path) fits ...
     pt = jnp.zeros((1, 512), jnp.int32)          # 512 x 64 = 32k tokens
     mode, why = attention._paged_kernel_mode(
-        jnp.zeros((1, 32, 128), jnp.bfloat16), kp, pt, 4096)
-    assert mode is None and "VMEM" in why and "32768 tokens" in why
+        jnp.zeros((1, 32, 128), jnp.bfloat16),
+        jnp.zeros((8, 2, 64, 128), jnp.bfloat16), pt, 4096)
+    assert (mode, why) == ("interpret", "")
+    # ... and heads too many to stage even a block of: XLA, saying why
+    mode, why = attention._paged_kernel_mode(
+        jnp.zeros((1, 256, 128), jnp.bfloat16),
+        jnp.zeros((256, 2, 64, 128), jnp.bfloat16), pt, 4096)
+    assert mode is None and "VMEM" in why and "256 x 128 heads" in why
     err = capfd.readouterr().err
     assert err.count("[attention] op=prefill impl=xla "
                      "why=LLMK_ATTENTION_IMPL=xla") == 1

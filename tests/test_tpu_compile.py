@@ -63,6 +63,8 @@ def _operands(dev, kv_dtype, page, pps, heads=None, pools=None):
     from llms_on_kubernetes_tpu.engine.cache import KVPool
 
     def sds(shape, dtype, sharding=None):
+        if sharding is None and dev is None:      # traced, never compiled
+            return jax.ShapeDtypeStruct(shape, dtype)
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding or dev)
 
     def pool():
@@ -89,9 +91,25 @@ def _compile_dispatch(args):
     return step.lower(*args).compile()
 
 
-# pool type, page, pages a slot -> what the dispatcher must say it took
+def _pallas_call(jaxpr):
+    """The first ``pallas_call`` equation under ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = _pallas_call(sub)
+            if found is not None:
+                return found
+
+
+# pool type, page, pages a slot -> what the dispatcher must say it took.
+# The 16,384-token slot is the longest that fitted the VMEM budget while a
+# whole slot was staged (PR 34: 64 MiB + headroom of 96): no shape that
+# took the kernel then may leave it for the XLA path.
 CASES = {
     "bf16 page 64 (the cell)": (None, 64, 32, "fused write+attend kernel"),
+    "bf16 page 64, a 16,384-token slot": (None, 64, 256,
+                                          "fused write+attend kernel"),
     "int8 page 128": ("int8", 128, 16, "fused int8 write+attend kernel"),
 }
 
@@ -121,6 +139,33 @@ def test_decode_append_rides_the_kernel_in_place(one_chip, no_cache,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < pools // 8     # no copy of a pool
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_is_given_the_vmem_the_dispatcher_counted(monkeypatch, case):
+    """``paged_vmem_bytes``, which the dispatcher holds against the budget,
+    is what the kernel hands Mosaic as its limit, and is the kernel's own
+    VMEM scratch (both halves of the staging and of the write blocks) plus
+    the headroom: a block's worth, whatever the slot's length."""
+    from llms_on_kubernetes_tpu.ops import attention, pallas_paged
+
+    kv_dtype, page, pps, _ = CASES[case]
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    args = _operands(None, kv_dtype, page, pps)
+    eqn = _pallas_call(jax.make_jaxpr(
+        lambda *a: attention.dispatch_paged_attention_write(
+            *a, scale=D ** -0.5, sliding_window=4096))(*args).jaxpr)
+    scratch = sum(
+        ref.size * ref.dtype.itemsize
+        for ref in eqn.params["grid_mapping"].scratch_avals
+        if "vmem" in str(ref.memory_space).lower())
+    counted = pallas_paged.paged_vmem_bytes(
+        N_KV, page, pps, D, args[1].data.dtype, kv_dtype == "int8")
+    assert counted == scratch + pallas_paged._VMEM_HEADROOM
+    limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert limit == counted <= attention.VMEM_BUDGET_BYTES
+    block = pallas_paged._block_tokens(page, pps)
+    assert block == 512 and scratch < 6 << 20   # 4 MiB + the write blocks
 
 
 def test_two_op_setting_still_compiles_the_dus_loop(one_chip, no_cache,

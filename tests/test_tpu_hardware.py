@@ -264,6 +264,98 @@ def test_smoke_shape_fused_write(page, pps, lengths):
                                rtol=2 ** -7, atol=2 ** -7)
 
 
+class _NoDMA:
+    """``pltpu`` with DMAs that move nothing (a kernel's compute alone)."""
+
+    class _Copy:
+        def start(self):
+            pass
+
+        wait = start
+
+    def __init__(self, real):
+        self._real = real
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+    def make_async_copy(self, *a, **kw):
+        return self._Copy()
+
+
+def _kernel_sides():
+    """(name, module) of this tree's ops/pallas_paged.py and, where the
+    chip call brought it, the parent commit's (.scratch/parent: the verify
+    skill's recipe)."""
+    import importlib.util
+    import os
+
+    from llms_on_kubernetes_tpu.ops import pallas_paged
+
+    sides = [("change", pallas_paged)]
+    path = ".scratch/parent/llms_on_kubernetes_tpu/ops/pallas_paged.py"
+    if os.path.exists(path):
+        spec = importlib.util.spec_from_file_location("parent_paged", path)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        sides.insert(0, ("parent", parent))
+    return sides
+
+
+def test_cell_kernel_time_fetch_attend_both(monkeypatch):
+    """The fused decode kernel at the cell's shape (13 of 32 rows live),
+    timed whole, with its DMAs alone (the block arithmetic removed) and
+    with its arithmetic alone (DMAs that move nothing): what the pipeline
+    across rows has to hide, and how much of it it hides. 256 launches
+    chained through q inside one executable, pools in place. (What the
+    kernel answers at this shape is test_smoke_shape_fused_write[cell]'s.)"""
+    import time
+
+    kp, vp, pt, lengths, q, k_new, v_new, *_ = _write_case(
+        12, 64, 32, None, CELL_LENGTHS)
+    live = int((np.asarray(lengths) > 0).sum())
+    n_calls = 256
+
+    def time_us(mod):
+        fn = mod.pallas_paged_attention_write.__wrapped__
+
+        @jax.jit
+        def chain(q, kd, vd):
+            def step(_, c):
+                o, kd, vd = fn(c[0], c[1], c[2], pt, lengths, k_new, v_new,
+                               scale=D ** -0.5, sliding_window=4096)
+                return o.astype(q.dtype), kd, vd
+            return jax.lax.fori_loop(0, n_calls, step, (q, kd, vd))
+
+        args = (q, kp.data + 0, vp.data + 0)
+        best = float("inf")
+        for _ in range(4):                      # the first run compiles
+            t0 = time.perf_counter()
+            jax.block_until_ready(chain(*args))
+            best = min(best, time.perf_counter() - t0)
+        return best / n_calls * 1e6
+
+    said = {"live_rows": live, "rows": len(CELL_LENGTHS)}
+    for name, mod in _kernel_sides():
+        both = time_us(mod)
+        with monkeypatch.context() as m:        # DMAs, no arithmetic
+            if hasattr(mod, "_attend_block"):
+                m.setattr(mod, "_attend_block", lambda q, carry, *a, **kw: carry)
+            else:
+                m.setattr(mod, "_attend_staged", lambda q, *a, **kw: (
+                    jnp.full(q.shape[:2] + (1,), -1e30, jnp.float32),
+                    jnp.zeros(q.shape[:2] + (1,), jnp.float32),
+                    jnp.zeros(q.shape, jnp.float32)))
+            fetch = time_us(mod)
+        with monkeypatch.context() as m:        # arithmetic, no DMAs
+            m.setattr(mod, "pltpu", _NoDMA(mod.pltpu))
+            attend = time_us(mod)
+        said[name] = {"both_us": round(both, 2), "fetch_only_us": round(fetch, 2),
+                      "attend_only_us": round(attend, 2),
+                      "both_us_a_live_row": round(both / live, 3)}
+    _report("pr37_kernel_split", said)
+
+
 def test_smoke_shape_fused_write_int8():
     from llms_on_kubernetes_tpu.ops.pallas_paged import (
         pallas_paged_attention_write_int8,
