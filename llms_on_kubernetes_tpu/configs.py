@@ -17,6 +17,7 @@ hand-written registry entry.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from typing import Any, Optional
 
@@ -46,9 +47,26 @@ class ModelConfig:
     # MoE (Mixtral)
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # None => dropless dispatch (C=N); training-style capacity limits are
-    # opt-in since drops make logits batch-composition-dependent
-    moe_capacity_factor: Optional[float] = None
+    # the router (ops/moe.route): "softmax" over all experts (Mixtral,
+    # Qwen3-MoE) or "sigmoid" per expert (LFM2-MoE); a selection bias that
+    # picks experts and never weighs them (``use_expert_bias``); whether
+    # the chosen scores are renormalised (/(sum + eps)); a route scale
+    moe_router: str = "softmax"
+    use_expert_bias: bool = False
+    norm_topk_prob: bool = True
+    moe_renorm_eps: float = 0.0
+    routed_scaling_factor: float = 1.0
+    # width of ONE routed expert where it differs from the dense network's
+    # (``intermediate_size``); None => intermediate_size
+    moe_intermediate_size: Optional[int] = None
+    # layers [0, num_dense_layers) keep a dense network in an expert model
+    num_dense_layers: int = 0
+    # a stack of more than one kind of layer (LFM2): per layer "conv" (a
+    # gated short convolution, state beside the KV pool) or
+    # "full_attention". None => every layer is full attention
+    layer_types: Optional[tuple] = None
+    conv_L_cache: int = 3                   # taps of the short convolution
+    conv_bias: bool = False
     # activation / norm variants
     hidden_act: str = "silu"                # silu | gelu_tanh
     norm_style: str = "llama"               # llama: x*w ; gemma: x*(1+w)
@@ -86,16 +104,56 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    def layer_kind(self, i: int) -> tuple:
+        """(operator, feed-forward) of layer ``i``: ("attn" | "conv",
+        "dense" | "moe")."""
+        op = ("conv" if self.layer_types is not None
+              and self.layer_types[i] == "conv" else "attn")
+        ff = "moe" if self.is_moe and i >= self.num_dense_layers else "dense"
+        return op, ff
+
+    @functools.cached_property
+    def layer_runs(self) -> tuple:
+        """The stack as runs of one kind: ((operator, feed-forward, first
+        layer, count), ...). A stack of one kind is one run."""
+        runs: list = []
+        for i in range(self.num_layers):
+            kind = self.layer_kind(i)
+            if runs and runs[-1][:2] == kind:
+                runs[-1] = (*kind, runs[-1][2], runs[-1][3] + 1)
+            else:
+                runs.append((*kind, i, 1))
+        return tuple(runs)
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers that keep keys and values: what the KV pool is sized by."""
+        return sum(n for op, _ff, _i, n in self.layer_runs if op == "attn")
+
+    @property
+    def num_conv_layers(self) -> int:
+        """Layers that keep a short-convolution state for each slot."""
+        return self.num_layers - self.num_attn_layers
+
+    @property
+    def num_moe_layers(self) -> int:
+        return sum(n for _op, ff, _i, n in self.layer_runs if ff == "moe")
+
+    @property
     def num_params(self) -> int:
         """Approximate parameter count (for memory budgeting)."""
-        d, f, v, L = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
+        d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
         attn = d * self.q_dim * 2 + d * self.kv_dim * 2
-        if self.is_moe:
-            mlp = 3 * d * f * self.num_experts + d * self.num_experts
-        else:
-            mlp = 3 * d * f
-        embed = v * d * (1 if self.tie_word_embeddings else 2)
-        return L * (attn + mlp) + embed
+        conv = 4 * d * d + self.conv_L_cache * d
+        moe = (3 * d * self.expert_width + d) * self.num_experts
+        total = v * d * (1 if self.tie_word_embeddings else 2)
+        for op, ff, _first, n in self.layer_runs:
+            total += n * ((attn if op == "attn" else conv)
+                          + (moe if ff == "moe" else 3 * d * f))
+        return total
 
 
 # ---------------------------------------------------------------------------
@@ -312,6 +370,28 @@ _register(
     "google/gemma-3-27b-it",
 )
 
+# LFM2-MoE (LiquidAI): a stack of three kinds of layer. The operator is a
+# gated short convolution (30 of 40 layers; per-slot state of the last
+# conv_L_cache - 1 gated inputs, no keys or values) or GQA attention with
+# 64-wide heads and q/k norms; the first two layers keep a dense network,
+# the rest route every token to 4 of 64 sigmoid-scored experts.
+_LFM2_PERIOD = ("conv", "conv", "full_attention", "conv")
+_register(
+    ModelConfig(
+        "lfm2-24b-a2b",
+        vocab_size=65536, hidden_size=2048, intermediate_size=11776,
+        num_layers=40, num_heads=32, num_kv_heads=8, head_dim=64,
+        rope_theta=1000000.0, max_position_embeddings=128000,
+        qk_norm=True, tie_word_embeddings=True,
+        layer_types=_LFM2_PERIOD * 10, conv_L_cache=3,
+        num_dense_layers=2, num_experts=64, num_experts_per_tok=4,
+        moe_intermediate_size=1536, moe_router="sigmoid",
+        use_expert_bias=True, norm_topk_prob=True, moe_renorm_eps=1e-6,
+        routed_scaling_factor=1.0,
+    ),
+    "LiquidAI/LFM2-24B-A2B",
+)
+
 # Tiny configs for tests / local CPU smoke runs.
 _register(
     ModelConfig(
@@ -351,6 +431,20 @@ _register(
         vocab_size=256, hidden_size=64, intermediate_size=96,
         num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
         max_position_embeddings=512, num_experts=4, num_experts_per_tok=2,
+    ),
+)
+_register(
+    ModelConfig(
+        # lfm2-24b-a2b's three kinds of layer at a size the CPU tests hold:
+        # conv + dense, then conv + experts, then attention + experts
+        "debug-lfm2",
+        vocab_size=258, hidden_size=64, intermediate_size=128,
+        num_layers=3, num_heads=4, num_kv_heads=2, head_dim=16,
+        max_position_embeddings=512, qk_norm=True, tie_word_embeddings=True,
+        layer_types=("conv", "conv", "full_attention"), conv_L_cache=3,
+        num_dense_layers=1, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=48, moe_router="sigmoid",
+        use_expert_bias=True, norm_topk_prob=True, moe_renorm_eps=1e-6,
     ),
 )
 
@@ -396,7 +490,41 @@ def _debug_qwen_mm() -> ModelConfig:
 _register(_debug_qwen_mm())
 
 
+def cut_to_layers(cfg: ModelConfig, layers: tuple, name: str) -> ModelConfig:
+    """``cfg`` with only the published ``layers`` (ascending indices): what
+    ONE stage of a pipeline over depth holds. Every width stays; of the
+    leading dense layers those among ``layers`` stay dense. Only for a
+    model whose layers are of several kinds (``layer_types``): its cut has
+    to keep whole periods of them, so it is named layer by layer; no other
+    entry can be served in part."""
+    if cfg.layer_types is None:
+        raise KeyError(f"{name!r}: {cfg.name} has one kind of layer and is "
+                       f"served whole")
+    if not layers or list(layers) != sorted(set(layers)) \
+            or not 0 <= layers[0] <= layers[-1] < cfg.num_layers:
+        raise KeyError(f"{name!r}: layers must be ascending indices below "
+                       f"{cfg.num_layers}")
+    return dataclasses.replace(
+        cfg, name=name, num_layers=len(layers),
+        num_dense_layers=sum(1 for i in layers if i < cfg.num_dense_layers),
+        layer_types=tuple(cfg.layer_types[i] for i in layers))
+
+
 def get_config(name: str) -> ModelConfig:
+    """A registry entry by name or alias. ``<entry>@<layers>`` (``3-10``,
+    ``0,3-10``) is that entry cut to those published layers
+    (``cut_to_layers``): the depth one chip holds of a model that a
+    pipeline spreads over several."""
+    base, at, spec = name.partition("@")
+    if at:
+        try:
+            layers = tuple(
+                i for part in spec.split(",")
+                for lo, _, hi in [part.partition("-")]
+                for i in range(int(lo), int(hi or lo) + 1))
+        except ValueError:
+            raise KeyError(f"{name!r}: layers are written 0,3-10") from None
+        return cut_to_layers(get_config(base), layers, name)
     key = name if name in REGISTRY else ALIASES.get(name.lower(), name)
     if key not in REGISTRY:
         raise KeyError(
@@ -492,6 +620,44 @@ def from_hf_config(hf: dict | str, name: str = "hf-model") -> ModelConfig:
         # experts use moe_intermediate_size, not the dense intermediate
         kw["intermediate_size"] = int(
             hf.get("moe_intermediate_size", hf["intermediate_size"]))
+    if model_type == "lfm2_moe":
+        # the published keys (LiquidAI/LFM2-24B-A2B config.json); tied
+        # embeddings and the 1e-6 of the renormalisation are the family's
+        # implementation's, not the file's
+        rope = hf.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default":
+            raise NotImplementedError(
+                f"lfm2_moe with rope_type {rope.get('rope_type')!r} is "
+                f"not supported yet")
+        types = tuple(hf["layer_types"])
+        if len(types) != kw["num_layers"] or set(types) - {
+                "conv", "full_attention"}:
+            raise NotImplementedError(
+                f"lfm2_moe layer_types {sorted(set(types))} over "
+                f"{len(types)} layers (num_hidden_layers "
+                f"{kw['num_layers']}): only conv and full_attention")
+        kw.update(
+            layer_types=types,
+            conv_L_cache=int(hf.get("conv_L_cache", 3)),
+            conv_bias=bool(hf.get("conv_bias", False)),
+            rope_theta=float(rope.get("rope_theta",
+                                      hf.get("rope_theta", 1000000.0))),
+            rms_norm_eps=float(hf.get("norm_eps", 1e-5)),
+            qk_norm=True,
+            tie_word_embeddings=bool(hf.get("tie_word_embeddings", True)),
+            num_dense_layers=int(hf.get("num_dense_layers", 0)),
+            num_experts=int(hf["num_experts"]),
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            moe_router="sigmoid",
+            use_expert_bias=bool(hf.get("use_expert_bias", False)),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            moe_renorm_eps=1e-6,
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        )
+        if kw["conv_bias"]:
+            raise NotImplementedError(
+                "lfm2_moe with conv_bias=true is not supported")
     if hf.get("query_pre_attn_scalar") is not None:
         kw["query_pre_attn_scalar"] = float(hf["query_pre_attn_scalar"])
     if model_type.startswith("gemma"):

@@ -710,6 +710,19 @@ def grafana_dashboard() -> dict[str, Any]:
                "already free: late over all is the miss rate)",
                ["sum by (when) (rate(llm_decode_launches_total[5m]))"],
                12, 136),
+        _panel(37, "Experts: share touched by a step, by kind of dispatch "
+               "(the share of the experts' weights it reads) / load, "
+               "fullest over mean expert (1 = even)",
+               ["sum by (kind) (rate(llm_moe_experts_touched_total[5m])) / "
+                "sum by (kind) (rate(llm_moe_expert_slots_total[5m]))",
+                "sum by (kind) "
+                "(rate(llm_moe_fullest_expert_rows_total[5m])) / "
+                "sum by (kind) (rate(llm_moe_mean_expert_rows_total[5m]))"],
+               0, 144),
+        _panel(38, "Conv state bytes / prefix reuse skipped (by reason)",
+               ["llm_conv_state_bytes",
+                "sum by (why) "
+                "(rate(llm_prefix_reuse_skipped_total[5m]))"], 12, 144),
     ]
     return {
         "title": "LLM serving on TPU — cluster overview",

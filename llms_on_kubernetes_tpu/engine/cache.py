@@ -45,6 +45,9 @@ import numpy as np
 
 @dataclasses.dataclass
 class CacheConfig:
+    # layers that keep keys and values (``ModelConfig.num_attn_layers``):
+    # a layer of another kind holds no page, and its per-slot state lives
+    # beside the pools (models/decoder.init_conv_state)
     num_layers: int
     num_kv_heads: int
     head_dim: int

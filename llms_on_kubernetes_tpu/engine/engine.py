@@ -50,8 +50,8 @@ from llms_on_kubernetes_tpu.engine.sampling import (
     MAX_CANDIDATES, HostSample, sample,
 )
 from llms_on_kubernetes_tpu.models.decoder import (
-    forward_chunk, forward_decode, forward_prefill, forward_verify,
-    init_params,
+    LayerAux, forward_chunk, forward_decode, forward_prefill, forward_verify,
+    init_conv_state, init_params,
 )
 
 Params = dict[str, Any]
@@ -900,9 +900,46 @@ _BIAS_DEC = _STOP_DEC + STOP_SLOTS
 _DEC_COLS = _BIAS_DEC + 2 * LOGIT_BIAS_SLOTS
 
 
+def _forward(forward, cfg, conv, slots, *args, **kw):
+    """``forward(*args, **kw)`` -> (logits, k_pages, v_pages, aux): with a
+    ``LayerAux`` for a model with conv layers (their per-slot state,
+    ``conv``; ``slots``: each row's, None where row i is slot i) or experts
+    (their row counts come back in it), and with None, traced exactly as
+    before there was one, for every other model."""
+    if not (cfg.num_conv_layers or cfg.is_moe):
+        return (*forward(*args, **kw), None)
+    return forward(*args, aux=LayerAux(conv=conv, slots=slots), **kw)
+
+
+def _pack_with_moe_rows(pack, aux):
+    """A step's packed host rows [B, W] with the rows each expert got
+    ([n_moe_layers, E], ``aux.moe_rows``) appended as whole rows, zero
+    padded: they reach the host in the read the tokens are read in, and
+    nothing that indexes a row by slot sees them (``moe_rows_of``)."""
+    if aux is None or aux.moe_rows is None:
+        return pack
+    W = pack.shape[1]
+    flat = aux.moe_rows.reshape(-1)
+    n = -(-flat.shape[0] // W)
+    flat = jnp.pad(flat, (0, n * W - flat.shape[0]))
+    return jnp.concatenate([pack, flat.reshape(n, W).astype(pack.dtype)])
+
+
+def moe_rows_of(arr: np.ndarray, rows: int, n_moe: int,
+                experts: int) -> "np.ndarray | None":
+    """The experts' rows [..., n_moe, E] that ``_pack_with_moe_rows`` put
+    behind the ``rows`` sample rows of a host pack [..., rows + n, W];
+    None where the pack carries none."""
+    if not n_moe or arr.shape[-2] <= rows:
+        return None
+    tail = arr[..., rows:, :].reshape(*arr.shape[:-2], -1)
+    return tail[..., :n_moe * experts].reshape(
+        *arr.shape[:-2], n_moe, experts)
+
+
 def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
                               prefill_toks, k_pages, v_pages, counts,
-                              base_key, fsm=None):
+                              base_key, fsm=None, conv=None):
     """The decode step, for every K >= 1: ONE dispatch runs K sampling
     steps via lax.scan, returning the K packed host rows stacked
     [K, B, W] (the synchronous loop enters it with K = 1).
@@ -919,7 +956,9 @@ def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
     stays deterministic. The host (_emit) remains authoritative for
     finishes — the device mask can only under-run, never over-run, the
     stream. Grammar rows ride the loop: the FSM state is scan carry,
-    masked+advanced per iteration."""
+    masked+advanced per iteration. So does ``conv``, the conv layers'
+    per-slot state (None for a model without them): a masked row leaves
+    its slot's state where its last live step put it."""
     lengths0 = packed[:, 0]
     src, vals = packed[:, 1], packed[:, 2]
     top_ks = packed[:, 3]
@@ -946,15 +985,17 @@ def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
     alive0 = (lengths0 > 0) & (budget > 0)
 
     def body(carry, j):
-        cur, alive, state, k_pages, v_pages, counts = carry
+        cur, alive, state, k_pages, v_pages, counts, conv = carry
         lengths = jnp.where(alive, lengths0 + j, 0)
         # the input token is always a previously-sampled OUTPUT token:
         # count it before sampling so this iteration's draw sees it
         counts = _count_decode_tokens(counts, cur, lengths > 0)
-        logits, k_pages, v_pages = forward_decode(
+        logits, k_pages, v_pages, aux = _forward(
+            forward_decode, cfg, conv, None,
             params, cfg, cur, lengths, k_pages, v_pages, page_table,
             pos_delta=pos_delta, adapter_idx=adapter_idx,
         )
+        conv = aux and aux.conv
         keys = _slot_keys(base_key, seeds, lengths)
         allowed = nxt_all = constrained = None
         if fsm is not None:
@@ -969,14 +1010,14 @@ def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
         stopped = ((stop_ids >= 0)
                    & (stop_ids == res.tokens[:, None])).any(axis=1)
         alive = alive & ~stopped & (j + 1 < budget)
-        return (new_toks, alive, state, k_pages, v_pages, counts), \
-            res.host_pack()
+        return (new_toks, alive, state, k_pages, v_pages, counts, conv), \
+            _pack_with_moe_rows(res.host_pack(), aux)
 
-    carry0 = (toks0, alive0, state0, k_pages, v_pages, counts)
-    (toks, _alive, state, k_pages, v_pages, counts), packs = jax.lax.scan(
-        body, carry0, jnp.arange(K, dtype=jnp.int32))
+    carry0 = (toks0, alive0, state0, k_pages, v_pages, counts, conv)
+    (toks, _alive, state, k_pages, v_pages, counts, conv), packs = \
+        jax.lax.scan(body, carry0, jnp.arange(K, dtype=jnp.int32))
     new_state = state if fsm is not None else None
-    return packs, toks, k_pages, v_pages, counts, new_state
+    return packs, toks, k_pages, v_pages, counts, new_state, conv
 
 
 def _decode_spec_packed_step(params, cfg, K, packed, k_pages, v_pages,
@@ -1147,7 +1188,7 @@ def _prefill_mm_packed_step(params, cfg, tokens, packed, img_embeds,
 
 
 def _prefill_packed_step(params, cfg, tokens, packed, k_pages, v_pages,
-                         counts, base_key, fsm=None):
+                         counts, base_key, fsm=None, conv=None):
     lengths = packed[:, 0]
     top_ks = packed[:, 1]
     temps = jax.lax.bitcast_convert_type(packed[:, 2], jnp.float32)
@@ -1164,7 +1205,8 @@ def _prefill_packed_step(params, cfg, tokens, packed, k_pages, v_pages,
     counts = _rebuild_count_rows(
         counts, tokens, slots, jnp.zeros_like(lengths), prompt_len, lengths,
         jnp.ones_like(lengths))
-    logits, k_pages, v_pages = forward_prefill(
+    logits, k_pages, v_pages, aux = _forward(
+        forward_prefill, cfg, conv, slots,
         params, cfg, tokens, lengths, k_pages, v_pages, page_table,
         adapter_idx=adapter_idx,
     )
@@ -1180,7 +1222,8 @@ def _prefill_packed_step(params, cfg, tokens, packed, k_pages, v_pages,
     if fsm is not None:
         new_state = _fsm_scatter(fsm, g_rows, init, nxt_all, res.tokens,
                                  lengths, slots)
-    return res.host_pack(), res.tokens, k_pages, v_pages, counts, new_state
+    return (_pack_with_moe_rows(res.host_pack(), aux), res.tokens, k_pages,
+            v_pages, counts, new_state, aux and aux.conv)
 
 
 # packed chunk columns: 0 chunk_len, 1 history, 2 top_k, 3 temps(bits),
@@ -1201,7 +1244,7 @@ _CHK_COLS = _BIAS_CHK + 2 * LOGIT_BIAS_SLOTS
 
 
 def _chunk_packed_step(params, cfg, tokens, packed, k_pages, v_pages,
-                       counts, base_key, fsm=None):
+                       counts, base_key, fsm=None, conv=None):
     lengths = packed[:, 0]
     history = packed[:, 1]
     top_ks = packed[:, 2]
@@ -1220,7 +1263,8 @@ def _chunk_packed_step(params, cfg, tokens, packed, k_pages, v_pages,
 
     counts = _rebuild_count_rows(
         counts, tokens, slots, history, prompt_len, lengths, reset)
-    logits, k_pages, v_pages = forward_chunk(
+    logits, k_pages, v_pages, aux = _forward(
+        forward_chunk, cfg, conv, slots,
         params, cfg, tokens, history, lengths, k_pages, v_pages, page_table,
         pos_delta=pos_delta, adapter_idx=adapter_idx,
     )
@@ -1235,7 +1279,8 @@ def _chunk_packed_step(params, cfg, tokens, packed, k_pages, v_pages,
     if fsm is not None:
         new_state = _fsm_scatter(fsm, g_rows, init, nxt_all, res.tokens,
                                  lengths, slots)
-    return res.host_pack(), res.tokens, k_pages, v_pages, counts, new_state
+    return (_pack_with_moe_rows(res.host_pack(), aux), res.tokens, k_pages,
+            v_pages, counts, new_state, aux and aux.conv)
 
 
 def _spill_gather_pages(k_pages, v_pages, flat_idx):
@@ -1289,6 +1334,36 @@ def _slot_keys(base_key, seeds, lengths):
     )(seeds, lengths)
 
 
+def _refuse_what_runs_cannot(cfg, ec, mesh, model_dir) -> None:
+    """A stack of several kinds of layer (``params["layers"]`` a tuple of
+    runs; conv layers with per-slot state) is served on one chip from
+    seeded weights, by the plain decode window. Every feature that reads
+    ``params["layers"]`` as one dict, or that moves KV pages without the
+    conv state at their boundary, refuses such a model here, at start-up,
+    rather than answer wrongly."""
+    if len(cfg.layer_runs) == 1:
+        return
+    asked = [
+        ("a checkpoint (no tensor names of this family are mapped: serve "
+         "it with --random-weights)", model_dir is not None),
+        ("--quantization", ec.quantization is not None),
+        ("a mesh of more than one device (--tp/--ep/--sp > 1)",
+         mesh is not None and mesh.size > 1),
+        ("multihost", ec.multihost),
+        ("LoRA adapters", bool(ec.adapters)),
+        ("speculation", ec.speculation is not None),
+        ("the host KV tier (kv_host_cache_gb: a spilled page carries no "
+         "conv state)", bool(ec.kv_host_cache_gb)),
+        ("a prefill or decode role (the handoff moves KV pages alone)",
+         ec.role not in (None, "both")),
+    ]
+    bad = [what for what, on in asked if on]
+    if bad:
+        raise ValueError(
+            f"{cfg.name}: a stack of {len(cfg.layer_runs)} runs of layers "
+            f"of different kinds does not support: " + "; ".join(bad))
+
+
 class Engine:
     """Multi-request continuous-batching engine for one model."""
 
@@ -1311,6 +1386,7 @@ class Engine:
             )
         self.model_config = model_config or get_config(engine_config.model)
         cfg = self.model_config
+        _refuse_what_runs_cannot(cfg, engine_config, mesh, model_dir)
         self.mesh = mesh
         from llms_on_kubernetes_tpu.parallel.mesh import AXIS_SEQ, set_active_mesh
 
@@ -1359,12 +1435,14 @@ class Engine:
                 self.params = init_params(
                     cfg, jax.random.key(engine_config.seed),
                     dtype=engine_config.dtype)
-            if mesh is not None:
+            if mesh is not None and len(cfg.layer_runs) == 1:
+                # (a stack of several runs is served on one device, where
+                # there is nothing to shard: _refuse_what_runs_cannot)
                 from llms_on_kubernetes_tpu.parallel.sharding import shard_params
                 self.params = shard_params(self.params, cfg, mesh)
 
         self.cache_config = CacheConfig(
-            num_layers=cfg.num_layers,
+            num_layers=cfg.num_attn_layers,
             num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.head_dim,
             num_pages=engine_config.num_pages,
@@ -1380,6 +1458,10 @@ class Engine:
             from llms_on_kubernetes_tpu.parallel.sharding import pool_sharding
             sharding = pool_sharding(cfg, mesh)
         self.k_pages, self.v_pages = init_pages(self.cache_config, sharding)
+        # the conv layers' per-slot state, beside the pools and donated
+        # through every step like them; None for a model without any
+        self.conv_state = init_conv_state(
+            cfg, engine_config.max_decode_slots, engine_config.dtype)
         if (engine_config.kv_cache_dtype == "int8"
                 and engine_config.page_size % 128 != 0
                 and jax.default_backend() == "tpu"):
@@ -1444,6 +1526,16 @@ class Engine:
         self._seed_rng = np.random.default_rng(engine_config.seed)
         self._lock = threading.Lock()
         self.preemptions = 0  # total KV-pressure preemptions (metrics)
+        # admissions that could have adopted a cached prefix and did not,
+        # by reason; drained into llm_prefix_reuse_skipped_total{why}
+        self.prefix_reuse_skipped = {"recurrent_state": 0}
+        # what the expert layers did, by kind of dispatch, booked where a
+        # dispatch's tokens are read (_book_moe); drained into llm_moe_*
+        from llms_on_kubernetes_tpu.engine.ledger import MOE_STATS
+
+        self.moe_stats = {kind: dict.fromkeys(MOE_STATS, 0.0)
+                          for kind in ("prefill", "chunk", "decode")}
+        self.moe_last: Optional[dict] = None   # /debug/engine "experts"
         # fused multi-step decode accounting (metrics + bench):
         self.decode_dispatches = 0   # decode device dispatches
         self.decode_tokens = 0       # tokens committed to streams by decode
@@ -1503,19 +1595,23 @@ class Engine:
         # next dispatch's idle gap is nobody's fault
         self._saw_no_work = False
 
+        # the conv state (the last argument; None for most models) is
+        # donated through every step like the pools
         self._prefill_packed = jax.jit(
-            _prefill_packed_step, static_argnums=(1,), donate_argnums=(4, 5, 6)
+            _prefill_packed_step, static_argnums=(1,),
+            donate_argnums=(4, 5, 6, 9)
         )
         self._decode_multi = jax.jit(
             _decode_multi_packed_step, static_argnums=(1, 2),
-            donate_argnums=(6, 7, 8)
+            donate_argnums=(6, 7, 8, 11)
         )
         self._decode_spec = jax.jit(
             _decode_spec_packed_step, static_argnums=(1, 2),
             donate_argnums=(4, 5, 6)
         )
         self._chunk_packed = jax.jit(
-            _chunk_packed_step, static_argnums=(1,), donate_argnums=(4, 5, 6)
+            _chunk_packed_step, static_argnums=(1,),
+            donate_argnums=(4, 5, 6, 9)
         )
         if cfg.vision is not None:
             from llms_on_kubernetes_tpu.models.vision import (
@@ -1550,6 +1646,9 @@ class Engine:
         self._inflight: "collections.deque[InflightStep]" = collections.deque()
         # (request, harvester key, row) awaiting a first-token read
         self._pending_first: list[tuple[Request, int, int]] = []
+        # harvester key of a first-token read -> (kind of its dispatch, its
+        # sample rows): what _book_moe needs when the read is consumed
+        self._first_reads: dict = {}
         self._seq_counter = iter(range(2 ** 62))     # decode steps (dense)
         # set by submit(): breaks the backpressure wait so admission (and
         # the new request's prefill dispatch) never waits out a read
@@ -2271,11 +2370,11 @@ class Engine:
             self._mh_send(MSG_CHUNK, pre_tokens=tokens, pre_packed=packed,
                           fsm_used=use_fsm)
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-             new_state) = self._chunk_packed(
+             new_state, self.conv_state) = self._chunk_packed(
                 self.params, self.model_config, jnp.asarray(tokens),
                 jnp.asarray(packed), self.k_pages, self.v_pages,
                 self.token_counts, self._key,
-                self._fsm_args() if use_fsm else None,
+                self._fsm_args() if use_fsm else None, self.conv_state,
             )
             if new_state is not None:
                 self._fsm_state = new_state
@@ -2321,6 +2420,11 @@ class Engine:
         admission commit) uploads pages and touches stats/recency, since
         a blocked admission re-probes every engine iteration."""
         if req.cache_salt is None:
+            return 0
+        if self.conv_state is not None:
+            # a cached page carries keys and values, not the conv layers'
+            # state at its end: nothing is adopted and the prompt is
+            # prefilled whole (_note_admission counts the skipped reuse)
             return 0
         hit = self.allocator.adopt_prefix(
             slot, prefill_tokens[:len(req.prompt)], salt=req.cache_salt)
@@ -2786,6 +2890,7 @@ class Engine:
             self._fsm_replay(req)  # stages fsm_set for the next decode
 
         led_rows = [(req, "prefill", n - hit or n)]
+        kind = "prefill"
         if req.images is not None and hit == 0:
             pack, toks, dseq = self._dispatch_mm_prefill(
                 slot, req, prefill_tokens, led_rows)
@@ -2793,6 +2898,7 @@ class Engine:
             # cache-hit admissions run the chunk path: prefill-with-history
             # attention over the remainder, history = the adopted prefix
             # (for a multimodal hit the remainder is pure text)
+            kind = "chunk"
             pack, toks, dseq = self._chunked_prefill(
                 slot, req, prefill_tokens, led_rows, start=hit)
         else:
@@ -2812,11 +2918,11 @@ class Engine:
             with self._dispatch("prefill", "_prefill_packed_step",
                                 f"1x{bucket}", led_rows) as dseq:
                 (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-                 new_state) = self._prefill_packed(
+                 new_state, self.conv_state) = self._prefill_packed(
                     self.params, self.model_config, jnp.asarray(tokens),
                     jnp.asarray(packed), self.k_pages, self.v_pages,
                     self.token_counts, self._key,
-                    self._fsm_args() if use_fsm else None,
+                    self._fsm_args() if use_fsm else None, self.conv_state,
                 )
             if new_state is not None:
                 self._fsm_state = new_state
@@ -2833,9 +2939,11 @@ class Engine:
             self.timeline.close(dseq, None)   # nobody reads a re-prefill
             return []
         t0 = time.perf_counter()
-        host = HostSample(np.asarray(jax.device_get(pack)))
+        arr = np.asarray(jax.device_get(pack))
+        host = HostSample(arr)
         self._device_time_s += time.perf_counter() - t0
         self.timeline.close(dseq, self._clock())
+        self._book_moe(kind, arr, 1)
         first = int(host.tokens[0])
         req.pending_token = first
         return self._emit(req, first, _lp_entry(host, 0), first=True)
@@ -2931,6 +3039,7 @@ class Engine:
                 events.append(self._finish(req, "stalled"))
         self._inflight.clear()
         self._pending_first = []
+        self._first_reads.clear()
         self.timeline.abandon()   # their reads will never come
         return events
 
@@ -2940,6 +3049,9 @@ class Engine:
         a preemption round trip is not new tenant throughput) — the
         serving loop drains these into the llm_tenant_* series. The one
         writer of admitted_at, on every prefill path."""
+        if (self.conv_state is not None and self.config.prefix_caching
+                and req.cache_salt is not None):
+            self.prefix_reuse_skipped["recurrent_state"] += 1
         if req.admitted_at is not None:
             return
         req.admitted_at = time.monotonic()
@@ -3092,17 +3204,20 @@ class Engine:
         with self._dispatch("decode", "_decode_multi_packed_step",
                             f"1x{len(active)}") as dseq:
             (pack, self._unread_toks, self.k_pages, self.v_pages,
-             self.token_counts, new_state) = self._decode_multi(
+             self.token_counts, new_state,
+             self.conv_state) = self._decode_multi(
                 self.params, self.model_config, 1, jnp.asarray(packed),
                 self._unread_toks, self._unread_prefill_toks, self.k_pages,
                 self.v_pages, self.token_counts, self._key,
-                self._fsm_args() if use_fsm else None,
+                self._fsm_args() if use_fsm else None, self.conv_state,
             )
         if new_state is not None:
             self._fsm_state = new_state
         t0 = time.perf_counter()
-        host = HostSample(np.asarray(jax.device_get(pack))[0])
+        arr = np.asarray(jax.device_get(pack))
+        host = HostSample(arr[0])
         self._device_time_s += time.perf_counter() - t0
+        self._book_moe("decode", arr, len(self.slots))
         self.timeline.close(dseq, self._clock(),
                             [(r, "decode", 1) for _i, r in active])
 
@@ -3240,6 +3355,7 @@ class Engine:
             else:
                 key = -1 - dseq
                 self._harvester.push(key, pack)
+                self._first_reads[key] = ("chunk", 1)
                 merge["slots"][slot] = (False, 0, 0)
                 self._pending_first.append((req, key, 0))
             return merge
@@ -3272,11 +3388,11 @@ class Engine:
         with self._dispatch("prefill", "_prefill_packed_step",
                             f"{K}x{bucket}", led_rows) as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-             new_state) = self._prefill_packed(
+             new_state, self.conv_state) = self._prefill_packed(
                 self.params, self.model_config, jnp.asarray(tokens),
                 jnp.asarray(packed), self.k_pages, self.v_pages,
                 self.token_counts, self._key,
-                self._fsm_args() if use_fsm else None,
+                self._fsm_args() if use_fsm else None, self.conv_state,
             )
         if new_state is not None:
             self._fsm_state = new_state
@@ -3287,6 +3403,7 @@ class Engine:
             # a negative key: its read carries first tokens
             key = -1 - dseq
             self._harvester.push(key, pack)
+            self._first_reads[key] = ("prefill", K)
         else:
             self.timeline.close(dseq, None)   # nobody reads a re-prefill
         merge = {"toks": toks, "slots": {}}
@@ -3408,11 +3525,11 @@ class Engine:
         with self._dispatch("decode", "_decode_multi_packed_step",
                             f"{K}x{len(active)}") as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-             new_state) = self._decode_multi(
+             new_state, self.conv_state) = self._decode_multi(
                 self.params, self.model_config, K, jnp.asarray(packed),
                 last_toks, prefill_toks, self.k_pages, self.v_pages,
                 self.token_counts, self._key,
-                self._fsm_args() if use_fsm else None,
+                self._fsm_args() if use_fsm else None, self.conv_state,
             )
         if new_state is not None:
             self._fsm_state = new_state
@@ -3564,6 +3681,29 @@ class Engine:
             need = min(max(self._clock() - due, need), window)
         self._lead += (need - self._lead) * (
             1.0 if need > self._lead else _LEAD_DECAY)
+
+    def _book_moe(self, kind: str, arr, rows: int) -> None:
+        """Book what the expert layers of one dispatch did, from the host
+        copy of its pack (``rows`` sample rows, then the experts' rows):
+        no device read of its own. A token step in which no row was live
+        routed nothing and is no step."""
+        cfg = self.model_config
+        got = moe_rows_of(np.asarray(arr), rows, cfg.num_moe_layers,
+                          cfg.num_experts)
+        if got is None:
+            return
+        steps = got.reshape(-1, cfg.num_moe_layers, cfg.num_experts)
+        steps = steps[steps.sum(axis=(1, 2)) > 0]
+        if not len(steps):
+            return
+        st = self.moe_stats[kind]
+        st["experts_touched"] += int((steps > 0).sum())
+        st["expert_slots"] += steps.size
+        st["routed_rows"] += int(steps.sum())
+        st["fullest_expert_rows"] += int(steps.max(axis=2).sum())
+        st["mean_expert_rows"] += float(steps.sum()) / cfg.num_experts
+        self.moe_last = {"kind": kind, "token_steps": len(steps),
+                         "rows_per_expert": steps[-1].tolist()}
 
     def launch_view(self) -> dict:
         """What times the next decode step, for ``GET /debug/engine``:
@@ -3727,6 +3867,8 @@ class Engine:
             done_keys = {k for _, k, _ in done_entries}
             for k in done_keys - {k for _, k, _ in still}:
                 self.timeline.close(-1 - k, self._harvester.done_time(k))
+                kind, rows = self._first_reads.pop(k)
+                self._book_moe(kind, self._harvester.get(k), rows)
                 self._harvester.discard_key(k)
 
         processed = -1
@@ -3742,6 +3884,8 @@ class Engine:
                 accept = np.asarray(accept)
             arr = np.asarray(res)                # [K, B, W]
             hosts = [HostSample(arr[k]) for k in range(arr.shape[0])]
+            if not step.spec:
+                self._book_moe("decode", arr, len(self.slots))
             processed = step.seq
             n_steps += 1
             consumed_total = wasted = max_consumed = 0

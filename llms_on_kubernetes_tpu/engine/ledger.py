@@ -82,6 +82,13 @@ IDLE_HOSTS = ("no_work", "compile", "scheduling")
 # whose sampled tokens it merges; "depth" = as soon as the pipeline had
 # room, where the launch cannot be timed
 DECODE_LAUNCH_RULES = ("timed", "late", "admission", "depth")
+# what the engine books of the expert layers (Engine.moe_stats,
+# llm_moe_<stat>_total{kind}), per kind of dispatch and summed over its
+# token steps and expert layers: experts that got at least one row /
+# experts there were / (token, expert) pairs routed / rows of each layer's
+# fullest expert / of its mean expert
+MOE_STATS = ("experts_touched", "expert_slots", "routed_rows",
+             "fullest_expert_rows", "mean_expert_rows")
 # launched and not yet booked: a pipeline holds async_depth decode steps
 # and the prefills of one admission round, a handful
 MAX_OPEN = 64
@@ -117,7 +124,7 @@ def _active_params(cfg: Any) -> int:
     share of the expert MLPs counts (num_params sums all experts)."""
     n = int(cfg.num_params)
     if getattr(cfg, "is_moe", False) and cfg.num_experts > 0:
-        d, f, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
+        d, f, L = cfg.hidden_size, cfg.expert_width, cfg.num_moe_layers
         all_mlp = 3 * d * f * cfg.num_experts
         active_mlp = 3 * d * f * cfg.num_experts_per_tok
         n -= L * (all_mlp - active_mlp)
@@ -500,7 +507,8 @@ class GoodputLedger(DispatchTimeline):
         # KV traffic per token-step: one K+V page-write plus (amortized)
         # the read of its own history — bounded below by the write
         self.kv_bytes_per_token = float(
-            2 * model_config.num_layers * model_config.kv_dim * dtype_bytes)
+            2 * model_config.num_attn_layers * model_config.kv_dim
+            * dtype_bytes)
 
         self.detector = detector
         # booked, newest last: the rolling window the MFU/MBU gauges are

@@ -345,18 +345,20 @@ def follower_loop(engine: Any) -> None:
             packed = jnp.asarray(m["pre_packed"][:k, :cols])
             fn = engine._prefill_packed if op == MSG_PREFILL else engine._chunk_packed
             (_pack, engine._unread_prefill_toks, engine.k_pages,
-             engine.v_pages, engine.token_counts, new_state) = fn(
+             engine.v_pages, engine.token_counts, new_state,
+             engine.conv_state) = fn(
                 engine.params, engine.model_config, tokens, packed,
                 engine.k_pages, engine.v_pages, engine.token_counts,
-                engine._key, fsm,
+                engine._key, fsm, engine.conv_state,
             )
         elif op == MSG_DECODE:
             (_pack, engine._unread_toks, engine.k_pages, engine.v_pages,
-             engine.token_counts, new_state) = engine._decode_multi(
+             engine.token_counts, new_state,
+             engine.conv_state) = engine._decode_multi(
                 engine.params, engine.model_config, k,
                 jnp.asarray(m["dec_packed"]), engine._unread_toks,
                 engine._unread_prefill_toks, engine.k_pages, engine.v_pages,
-                engine.token_counts, engine._key, fsm,
+                engine.token_counts, engine._key, fsm, engine.conv_state,
             )
         else:
             raise ValueError(f"unknown multihost op {op}")

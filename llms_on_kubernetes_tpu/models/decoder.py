@@ -11,6 +11,10 @@ TPU-first choices:
 - Parameters are plain pytrees with layers STACKED on a leading axis and the
   layer loop is ``lax.scan`` — one layer's HLO compiled once, so a 32-layer
   8B and an 80-layer 70B compile in the same time as a 2-layer test model.
+  A stack of more than one KIND of layer (``cfg.layer_runs``: a gated short
+  convolution or attention, a dense network or experts) is a tuple of such
+  stacks, one for each run of one kind, scanned one after the other; a
+  stack of one kind is one run and ``params["layers"]`` is its dict.
 - Head dims are explicit in weight shapes ([D, H, hd] not [D, H*hd]) so
   sharding rules can target the head axis directly (mesh axis "model").
 - All shapes static; prefill is bucketed by the caller; decode is a fixed
@@ -21,6 +25,7 @@ TPU-first choices:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 from typing import Any, Optional
@@ -81,7 +86,9 @@ def _unroll_layers() -> bool:
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[str] = None) -> Params:
     """Random-init parameters (layer-stacked). Layout matches weights.py loading."""
     dt = jnp.dtype(dtype or cfg.dtype)
-    L, D, F = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
+    if len(cfg.layer_runs) > 1:
+        return _init_runs(cfg, key, dt)
+    L, D, F = cfg.num_layers, cfg.hidden_size, cfg.expert_width
     H, KV, hd, V = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size
     keys = iter(jax.random.split(key, 32))
 
@@ -141,6 +148,125 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[str] = None) -
     return params
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal_stack(keys, shape, scale, dtype):
+    """[len(keys), *shape] normal draws x scale in ``dtype``, one layer at
+    a time: the float32 draw of ONE layer's tensor is the largest float32
+    array there ever is (5.2 G parameters of bfloat16 fit a 16 GB chip;
+    a float32 copy of one run's expert stacks would not)."""
+    return jax.lax.map(
+        lambda k: (jax.random.normal(k, shape, jnp.float32)
+                   * scale).astype(dtype), keys)
+
+
+def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
+    """Random-init parameters of a stack of more than one kind of layer:
+    ``params["layers"]`` is a tuple with one layer-stacked dict for each
+    run of ``cfg.layer_runs``. A layer's operator is attention (wq, wk, wv,
+    wo, q_norm, k_norm) or a gated short convolution (conv_in [D, 3D]: the
+    gates B and C and the input X, in that order; conv_w [D, taps], one
+    filter a channel; conv_out [D, D]); its feed-forward is a dense SwiGLU
+    network or routed experts (router, router_bias, w_gate/w_up/w_down
+    stacked over experts). ``attn_norm`` is the operator's norm whatever
+    the operator. The selection bias is NOT zero, so that a test can tell
+    selecting from weighing, and small beside the scores' own spread
+    (0.01 against 0.2): a trained bias evens the experts' load out, and a
+    random one as wide as the scores would pile the rows on a few."""
+    if cfg.attention_bias or cfg.post_norms or cfg.vision is not None \
+            or cfg.norm_style != "llama" or not cfg.qk_norm:
+        raise NotImplementedError(
+            f"{cfg.name}: a stack of several kinds of layer is built for "
+            f"the LFM2 block only (llama norms, q/k norms, no biases)")
+    D, F, Fm = cfg.hidden_size, cfg.intermediate_size, cfg.expert_width
+    H, KV, hd, V = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size
+    E, taps = cfg.num_experts, cfg.conv_L_cache
+    keys = iter(jax.random.split(key, 16 * len(cfg.layer_runs) + 4))
+
+    def init(n, *shape, scale):
+        return _normal_stack(jax.random.split(next(keys), n), shape,
+                             float(scale), dt)
+
+    runs = []
+    for op, ff, _first, n in cfg.layer_runs:
+        lp: Params = {"attn_norm": jnp.ones((n, D), dt),
+                      "mlp_norm": jnp.ones((n, D), dt)}
+        if op == "attn":
+            lp.update(
+                wq=init(n, D, H, hd, scale=D ** -0.5),
+                wk=init(n, D, KV, hd, scale=D ** -0.5),
+                wv=init(n, D, KV, hd, scale=D ** -0.5),
+                wo=init(n, H, hd, D, scale=(H * hd) ** -0.5),
+                q_norm=jnp.ones((n, hd), dt), k_norm=jnp.ones((n, hd), dt))
+        else:
+            lp.update(
+                conv_in=init(n, D, 3 * D, scale=D ** -0.5),
+                conv_w=init(n, D, taps, scale=taps ** -0.5),
+                conv_out=init(n, D, D, scale=D ** -0.5))
+        if ff == "moe":
+            lp.update(
+                router=init(n, D, E, scale=D ** -0.5),
+                router_bias=(jax.random.normal(next(keys), (n, E),
+                                               jnp.float32) * 0.01),
+                w_gate=init(n, E, D, Fm, scale=D ** -0.5),
+                w_up=init(n, E, D, Fm, scale=D ** -0.5),
+                w_down=init(n, E, Fm, D, scale=Fm ** -0.5))
+        else:
+            lp.update(
+                w_gate=init(n, D, F, scale=D ** -0.5),
+                w_up=init(n, D, F, scale=D ** -0.5),
+                w_down=init(n, F, D, scale=F ** -0.5))
+        runs.append(lp)
+    params: Params = {
+        # rows of unit norm: a tied table of unit-variance entries would
+        # put sqrt(D) on the token's own logit and bury what the layers add
+        "embed": init(1, V, D, scale=D ** -0.5 if cfg.tie_word_embeddings
+                      else 1.0)[0],
+        "final_norm": jnp.ones((D,), dt),
+        "layers": tuple(runs),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = init(1, D, V, scale=D ** -0.5)[0]
+    return params
+
+
+def layer_runs(cfg: ModelConfig, params: Params) -> tuple:
+    """``params["layers"]`` as the stacked dicts of ``cfg.layer_runs``: the
+    tuple itself, or the one dict of a stack of one kind."""
+    layers = params["layers"]
+    return tuple(layers) if isinstance(layers, (tuple, list)) else (layers,)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LayerAux:
+    """What a forward pass is handed and hands back beside the KV pools,
+    for a model that keeps per-slot state or routes to experts.
+
+    ``conv`` [n_conv_layers, slots + 1, taps - 1, D], in the activation
+    type: each conv layer's short-convolution state of every slot (the
+    last ``taps - 1`` gated inputs), donated and returned like the pools;
+    the last row is trash, where rows that are padding write. None where
+    the model has no conv layer, or for a pass from an empty state whose
+    state nobody keeps (scoring).
+    ``slots`` [B]: the slot of each row (prefill, chunk); None where row i
+    IS slot i (decode).
+    ``moe_rows`` [n_moe_layers, E] int32, handed BACK: the (token, expert)
+    pairs each expert got in this pass; None in, and None back where no
+    layer routes."""
+    conv: "jnp.ndarray | None" = None
+    slots: "jnp.ndarray | None" = None
+    moe_rows: "jnp.ndarray | None" = None
+
+
+def init_conv_state(cfg: ModelConfig, slots: int, dtype=None):
+    """Zeroed short-convolution state for ``slots`` decode slots (and the
+    trash row), or None for a model without conv layers."""
+    if not cfg.num_conv_layers:
+        return None
+    return jnp.zeros((cfg.num_conv_layers, slots + 1, cfg.conv_L_cache - 1,
+                      cfg.hidden_size), jnp.dtype(dtype or cfg.dtype))
+
+
 # ---------------------------------------------------------------------------
 # Layer
 # ---------------------------------------------------------------------------
@@ -159,22 +285,60 @@ def _qkv(lp: Params, cfg: ModelConfig, h: jnp.ndarray, adapter_idx=None):
     return q, k, v
 
 
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
 def _mlp(lp: Params, cfg: ModelConfig, h: jnp.ndarray, token_valid: jnp.ndarray,
-         adapter_idx=None) -> jnp.ndarray:
+         adapter_idx=None, ff: str = "dense", experts=None):
+    """The layer's feed-forward: (out [B, T, D], the rows each expert got
+    [E] or None for a dense network). ``experts``: (the run's expert
+    weights, stacked over its layers, this layer's index in them)."""
     act = _act(cfg)
-    if cfg.is_moe:
+    if ff == "moe":
         B, T, D = h.shape
-        out = moe_block(
-            h.reshape(B * T, D),
-            lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
+        stacks, layer = experts
+        out, rows = moe_block(
+            h.reshape(B * T, D), lp["router"],
+            *(stacks[k] for k in _EXPERT_STACKS), layer=layer,
             top_k=cfg.num_experts_per_tok, act=act,
-            capacity_factor=cfg.moe_capacity_factor,
             valid=token_valid.reshape(B * T),
+            bias=lp["router_bias"] if cfg.use_expert_bias else None,
+            scores=cfg.moe_router, renorm=cfg.norm_topk_prob,
+            eps=cfg.moe_renorm_eps, scale=cfg.routed_scaling_factor,
         )
-        return out.reshape(B, T, D)
+        return out.reshape(B, T, D), rows
     gate = act(_lqe("btd,df->btf", h, lp, "w_gate", adapter_idx))
     up = _lqe("btd,df->btf", h, lp, "w_up", adapter_idx)
-    return _lqe("btf,fd->btd", gate * up, lp, "w_down", adapter_idx)
+    return _lqe("btf,fd->btd", gate * up, lp, "w_down", adapter_idx), None
+
+
+def _short_conv(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
+                state: jnp.ndarray, n_valid: jnp.ndarray):
+    """LFM2's gated short convolution. u [B, T, D] (normed); ``state``
+    [B, taps - 1, D]: the gated inputs z at the row's last taps - 1
+    positions before u's first (zeros for a fresh sequence); ``n_valid``
+    [B]: how many of the T positions are real, from the left.
+
+      [Bg, Cg, X] = split3(u W_in);  z = Bg * X
+      c_t = sum_j w[:, j] * z_{t - (taps-1) + j}     (causal, depthwise)
+      out = (Cg * c) W_out
+
+    Returns (out [B, T, D], the state after the row's last real position
+    [B, taps - 1, D]). Padding lies to the right of every real position,
+    so it reaches neither a real position's sum nor the state."""
+    taps = cfg.conv_L_cache
+    T = u.shape[1]
+    with jax.named_scope("lfm2.conv"):
+        bg, cg, xg = jnp.split(qeinsum("btd,de->bte", u, lp["conv_in"]), 3,
+                               axis=-1)
+        zext = jnp.concatenate([state.astype(u.dtype), bg * xg], axis=1)
+        w = lp["conv_w"].astype(jnp.float32)                  # [D, taps]
+        c = sum(zext[:, j:j + T].astype(jnp.float32) * w[:, j]
+                for j in range(taps))
+        out = qeinsum("btd,de->bte", cg * c.astype(u.dtype), lp["conv_out"])
+        at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)
+        new_state = jnp.take_along_axis(zext, at[:, :, None], axis=1)
+    return out, new_state
 
 
 def _layer_step(
@@ -196,7 +360,44 @@ def _layer_step(
     rope_positions: "jnp.ndarray | None" = None,  # [B, T] mrope-shifted
     token_valid: "jnp.ndarray | None" = None,  # [B, T]; default: writes>=0
     adapter_idx: "jnp.ndarray | None" = None,  # [B] LoRA slot; -1 = base
+    kind: tuple = ("attn", "dense"),   # cfg.layer_kind: trace-time structure
+    conv_state: "jnp.ndarray | None" = None,   # [B, taps-1, D] (conv layer)
+    n_valid: "jnp.ndarray | None" = None,      # [B] real positions of T
+    experts=None,                              # _mlp's, for an expert layer
 ):
+    """One layer of ``kind`` (operator, feed-forward). Returns (x, k_pages,
+    v_pages, a conv layer's new state rows or None, the rows each expert
+    got or None)."""
+    op, ff = kind
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
+    if op == "conv":
+        out, conv_state = _short_conv(lp, cfg, h, conv_state, n_valid)
+    else:
+        out, k_pages, v_pages = _attention(
+            cfg, inv_freq, page_table, positions, write_positions, lengths,
+            mode, h, lp, k_pages, v_pages, layer_idx, inv_freq_local,
+            mm_groups, mm_pos3, rope_positions, adapter_idx)
+    if cfg.post_norms:
+        out = rms_norm(out, lp["attn_post_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
+    x = x + out
+
+    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
+    m, moe_rows = _mlp(lp, cfg, h,
+                       token_valid=(write_positions >= 0 if token_valid is None
+                                    else token_valid),
+                       adapter_idx=adapter_idx, ff=ff, experts=experts)
+    if cfg.post_norms:
+        m = rms_norm(m, lp["mlp_post_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
+    x = x + m
+    return x, k_pages, v_pages, conv_state, moe_rows
+
+
+def _attention(cfg, inv_freq, page_table, positions, write_positions,
+               lengths, mode, h, lp, k_pages, v_pages, layer_idx,
+               inv_freq_local, mm_groups, mm_pos3, rope_positions,
+               adapter_idx):
+    """The attention operator on the normed input ``h``: (W_o of the
+    attended values, k_pages, v_pages)."""
     scale = (cfg.query_pre_attn_scalar or cfg.head_dim) ** -0.5
     # Gemma-2/3 interleaved attention: layer is global iff (i+1) % pattern == 0;
     # local layers use sliding_window + rope_local_theta. The window becomes a
@@ -207,7 +408,6 @@ def _layer_step(
         window = jnp.where(is_global, jnp.int32(2 ** 30), jnp.int32(cfg.sliding_window))
         inv_freq = jnp.where(is_global, inv_freq, inv_freq_local)
 
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
     q, k, v = _qkv(lp, cfg, h, adapter_idx=adapter_idx)
     if mm_pos3 is not None:
         # multimodal prompt on an mrope model (Qwen3-VL): interleaved
@@ -257,26 +457,14 @@ def _layer_step(
                 attn_softcap=cfg.attn_softcap,
             )
     out = _lqe("bthk,hkd->btd", attn, lp, "wo", adapter_idx)
-    if cfg.post_norms:
-        out = rms_norm(out, lp["attn_post_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
-    x = x + out
-
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
-    m = _mlp(lp, cfg, h,
-             token_valid=(write_positions >= 0 if token_valid is None
-                          else token_valid),
-             adapter_idx=adapter_idx)
-    if cfg.post_norms:
-        m = rms_norm(m, lp["mlp_post_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
-    x = x + m
-    return x, k_pages, v_pages
+    return out, k_pages, v_pages
 
 
 def _run_layers(
     cfg: ModelConfig,
     params: Params,
     x: jnp.ndarray,
-    k_pages: jnp.ndarray,          # [KV, L*P, page, hd] flat pool
+    k_pages: jnp.ndarray,          # [KV, A*P, page, hd] flat pool
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,       # [B, pages_per_seq] per-layer-LOCAL ids
     positions: jnp.ndarray,
@@ -291,13 +479,18 @@ def _run_layers(
     rope_positions: "jnp.ndarray | None" = None,  # [B, T] mrope-shifted
     token_valid: "jnp.ndarray | None" = None,  # [B, T] MoE routing mask
     adapter_idx: "jnp.ndarray | None" = None,  # [B] LoRA slot; -1 = base
+    aux: "LayerAux | None" = None,
 ):
+    """The layer stack, run by run (``cfg.layer_runs``): each run is one
+    ``lax.scan`` over its stacked parameters, with the body of its kind
+    chosen at trace time. The pools hold the ATTENTION layers only, in
+    stack order; the conv state (``aux.conv``) the conv layers. Returns
+    (x, k_pages, v_pages, aux with the new state and the experts' rows)."""
     inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
     inv_freq_local = (
         jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_local_theta))
         if cfg.rope_local_theta is not None else None
     )
-    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     # flat-pool layer folding. Default (layer-major): layer l's pages live
     # in the block [l*P, (l+1)*P). Context parallelism (seq>1 mesh)
     # numbers PAGE-MAJOR (flat = page_id * L + l) instead, so a contiguous
@@ -306,43 +499,110 @@ def _run_layers(
     from llms_on_kubernetes_tpu.parallel.mesh import seq_parallelism
 
     cp = seq_parallelism() > 1
-    pages_per_layer = k_pages.shape[1] // cfg.num_layers
+    n_attn = cfg.num_attn_layers
+    pages_per_layer = k_pages.shape[1] // max(n_attn, 1)
+    conv = aux.conv if aux is not None else None
+    B, T = x.shape[:2]
+    if cfg.num_conv_layers:
+        if conv is None and mode != "prefill":
+            raise ValueError(
+                f"{cfg.name}: a {mode} pass continues a sequence and needs "
+                f"its conv state (aux.conv)")
+        n_valid = (lengths > 0).astype(jnp.int32) if mode == "decode" \
+            else lengths
+        slots = None if aux is None else aux.slots
+        trash = None if conv is None else conv.shape[1] - 1
 
-    def body(carry, per_layer):
-        xc, kp, vp = carry
-        idx, lp = per_layer
-        # pools ride the CARRY (aliased buffer -> in-place scatter), never
-        # the xs/ys path (which would rewrite the whole pool every step)
-        if cp:
-            pt = page_table * cfg.num_layers + idx
-        else:
-            pt = page_table + idx * pages_per_layer
-        xc, kp, vp = _layer_step(
-            cfg, inv_freq, pt, positions, write_positions, lengths, mode,
-            xc, lp, kp, vp, layer_idx=idx, inv_freq_local=inv_freq_local,
-            mm_groups=mm_groups, mm_pos3=mm_pos3,
-            rope_positions=rope_positions, token_valid=token_valid,
-            adapter_idx=adapter_idx,
+    def conv_rows(conv, ci):
+        """The state each row's convolution starts from, in conv layer ci."""
+        if mode == "prefill":       # a fresh sequence: never a slot's past
+            return jnp.zeros((B, cfg.conv_L_cache - 1, x.shape[-1]), x.dtype)
+        if mode == "decode":        # row i is slot i
+            return jax.lax.dynamic_index_in_dim(conv, ci, 0, False)[:B]
+        rows = jax.lax.dynamic_index_in_dim(conv, ci, 0, False)[slots]
+        # a chunk continues its slot's sequence; a prompt's first chunk
+        # starts one, whatever the slot's last occupant left
+        return jnp.where((positions[:, 0] > 0)[:, None, None], rows, 0)
+
+    def conv_write(conv, ci, old, new):
+        if conv is None:
+            return None
+        live = lengths > 0
+        if mode == "decode":        # idle rows leave their slot as it was
+            rows = jnp.where(live[:, None, None], new, old).astype(conv.dtype)
+            return jax.lax.dynamic_update_slice(
+                conv, rows[None], (ci, 0, 0, 0))
+        # padding rows carry no slot: they write the trash row
+        return conv.at[ci, jnp.where(live, slots, trash)].set(
+            new.astype(conv.dtype))
+
+    def run_body(kind, stacks, first, a0, c0):
+        """The scan body of one run: its kind, its expert stacks, and the
+        stack / attention / conv index of its first layer."""
+        op, _ff = kind
+
+        def body(carry, per_layer):
+            xc, kp, vp, cv = carry
+            i, lp = per_layer
+            idx, a_idx, c_idx = first + i, a0 + i, c0 + i
+            # pools ride the CARRY (aliased buffer -> in-place scatter), never
+            # the xs/ys path (which would rewrite the whole pool every step)
+            if cp:
+                pt = page_table * n_attn + a_idx
+            else:
+                pt = page_table + a_idx * pages_per_layer
+            old = conv_rows(cv, c_idx) if op == "conv" else None
+            xc, kp, vp, new, rows = _layer_step(
+                cfg, inv_freq, pt, positions, write_positions, lengths, mode,
+                xc, lp, kp, vp, layer_idx=idx, inv_freq_local=inv_freq_local,
+                mm_groups=mm_groups, mm_pos3=mm_pos3,
+                rope_positions=rope_positions, token_valid=token_valid,
+                adapter_idx=adapter_idx, kind=kind, conv_state=old,
+                n_valid=n_valid if op == "conv" else None,
+                experts=(stacks, i) if stacks else None,
+            )
+            if op == "conv":
+                cv = conv_write(cv, c_idx, old, new)
+            if deepstack is not None:
+                # DeepStack (Qwen3-VL): intermediate vision features are ADDED
+                # to the first n_taps decoder layers' outputs at image-token
+                # positions
+                n_taps = deepstack.shape[0]
+                tap = jnp.take(deepstack, jnp.clip(idx, 0, n_taps - 1), axis=0)
+                gathered = jnp.take_along_axis(tap, mm_idx[:, :, None], axis=1)
+                inject = mm_is_img[:, :, None] & (idx < n_taps)
+                xc = xc + jnp.where(inject, gathered.astype(xc.dtype), 0)
+            return (xc, kp, vp, cv), rows
+
+        return body
+
+    moe_rows = []
+    a0 = c0 = 0
+    for (op, ff, first, n), run in zip(cfg.layer_runs,
+                                       layer_runs(cfg, params)):
+        # an expert layer's weights stay in their stack, whole, beside
+        # the scan: the grouped product takes the stack and the layer's
+        # index, never a slice of it (ops/moe._grouped_dot)
+        stacks = ({k: run[k] for k in _EXPERT_STACKS} if ff == "moe" else {})
+        (x, k_pages, v_pages, conv), rows = jax.lax.scan(
+            run_body((op, ff), stacks, first, a0, c0),
+            (x, k_pages, v_pages, conv),
+            (jnp.arange(n, dtype=jnp.int32),
+             {k: v for k, v in run.items() if k not in stacks}),
+            # full unroll on TPU: no while loop may ever carry the pool (its
+            # boundary copy costs more than the whole rest of the step)
+            unroll=n if _unroll_layers() else 1,
         )
-        if deepstack is not None:
-            # DeepStack (Qwen3-VL): intermediate vision features are ADDED
-            # to the first n_taps decoder layers' outputs at image-token
-            # positions
-            n_taps = deepstack.shape[0]
-            tap = jnp.take(deepstack, jnp.clip(idx, 0, n_taps - 1), axis=0)
-            gathered = jnp.take_along_axis(tap, mm_idx[:, :, None], axis=1)
-            inject = mm_is_img[:, :, None] & (idx < n_taps)
-            xc = xc + jnp.where(inject, gathered.astype(xc.dtype), 0)
-        return (xc, kp, vp), None
-
-    (x, k_pages, v_pages), _ = jax.lax.scan(
-        body, (x, k_pages, v_pages), (layer_ids, params["layers"]),
-        # full unroll on TPU: no while loop may ever carry the pool (its
-        # boundary copy costs more than the whole rest of the step)
-        unroll=cfg.num_layers if _unroll_layers() else 1,
-    )
-    return x, k_pages, v_pages
-
+        if op == "attn":
+            a0 += n
+        else:
+            c0 += n
+        if rows is not None:
+            moe_rows.append(rows)
+    if aux is not None:
+        aux = LayerAux(conv=conv, slots=aux.slots,
+                       moe_rows=jnp.concatenate(moe_rows) if moe_rows else None)
+    return x, k_pages, v_pages, aux
 
 def _embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
     x = params["embed"][tokens]
@@ -367,6 +627,10 @@ def _logits(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 # Public forward passes
 # ---------------------------------------------------------------------------
 
+def _with_aux(out: tuple, aux: "LayerAux | None") -> tuple:
+    return out if aux is None else (*out, aux)
+
+
 def forward_prefill(
     params: Params,
     cfg: ModelConfig,
@@ -376,20 +640,23 @@ def forward_prefill(
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, pages_per_seq]
     adapter_idx: "jnp.ndarray | None" = None,  # [B] LoRA slot; -1 = base
+    aux: "LayerAux | None" = None,
 ):
-    """Process whole prompts; returns (last-token logits [B, V], new cache)."""
+    """Process whole prompts; returns (last-token logits [B, V], new cache)
+    and, where ``aux`` is given, the new ``LayerAux`` as a fourth result.
+    A prompt starts from an empty conv state, never from its slot's."""
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     write_positions = jnp.where(positions < lengths[:, None], positions, -1)
     x = _embed(params, cfg, tokens)
-    x, k_pages, v_pages = _run_layers(
+    x, k_pages, v_pages, aux = _run_layers(
         cfg, params, x, k_pages, v_pages, page_table,
         positions, write_positions, lengths, "prefill",
-        adapter_idx=adapter_idx,
+        adapter_idx=adapter_idx, aux=aux,
     )
     last = jnp.clip(lengths - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, D]
-    return _logits(params, cfg, x_last), k_pages, v_pages
+    return _with_aux((_logits(params, cfg, x_last), k_pages, v_pages), aux)
 
 
 def forward_score(
@@ -425,12 +692,12 @@ def forward_score(
     token_valid = positions < lengths[:, None]
     from llms_on_kubernetes_tpu.engine.cache import KVPool
 
-    dummy_shape = (cfg.num_kv_heads, cfg.num_layers, 1, cfg.head_dim)
+    dummy_shape = (cfg.num_kv_heads, cfg.num_attn_layers, 1, cfg.head_dim)
     k_pages = KVPool(jnp.zeros(dummy_shape, jnp.float32))
     v_pages = KVPool(jnp.zeros(dummy_shape, jnp.float32))
     page_table = jnp.zeros((B, 1), jnp.int32)
     x = _embed(params, cfg, tokens)
-    x, _, _ = _run_layers(
+    x, _, _, _ = _run_layers(
         cfg, params, x, k_pages, v_pages, page_table,
         positions, write_positions, lengths, "prefill",
         token_valid=token_valid,
@@ -509,7 +776,7 @@ def forward_prefill_mm(
     # Qwen3-VL keeps plain causal attention over image tokens
     bidir = mm_groups if cfg.vision.family == "siglip" else None
 
-    x, k_pages, v_pages = _run_layers(
+    x, k_pages, v_pages, _ = _run_layers(
         cfg, params, x, k_pages, v_pages, page_table,
         positions, write_positions, lengths, "prefill", mm_groups=bidir,
         mm_pos3=pos3, deepstack=deepstack, mm_idx=idx, mm_is_img=is_img,
@@ -531,10 +798,13 @@ def forward_chunk(
     page_table: jnp.ndarray,
     pos_delta: "jnp.ndarray | None" = None,  # [B] mrope position offset
     adapter_idx: "jnp.ndarray | None" = None,  # [B] LoRA slot; -1 = base
+    aux: "LayerAux | None" = None,
 ):
     """Chunked prefill: process one chunk of a prompt whose earlier chunks
     are already in the paged cache. Returns the chunk's last-token logits
-    [B, V] and the updated cache. With history=0 this is semantically
+    [B, V] and the updated cache (and the new ``aux`` where one is given:
+    a conv layer continues from its slot's state when history > 0, and
+    from an empty one at history 0). With history=0 this is semantically
     ``forward_prefill`` (pinned by tests), but attends through the page
     pool — the engine uses it only for out-of-bucket prompts.
 
@@ -552,14 +822,14 @@ def forward_chunk(
     rope_positions = (None if pos_delta is None
                       else positions + pos_delta[:, None])
     x = _embed(params, cfg, tokens)
-    x, k_pages, v_pages = _run_layers(
+    x, k_pages, v_pages, aux = _run_layers(
         cfg, params, x, k_pages, v_pages, page_table,
         positions, write_positions, lengths, "chunk",
-        rope_positions=rope_positions, adapter_idx=adapter_idx,
+        rope_positions=rope_positions, adapter_idx=adapter_idx, aux=aux,
     )
     last = jnp.clip(lengths - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return _logits(params, cfg, x_last), k_pages, v_pages
+    return _with_aux((_logits(params, cfg, x_last), k_pages, v_pages), aux)
 
 
 def forward_verify(
@@ -591,7 +861,7 @@ def forward_verify(
     rope_positions = (None if pos_delta is None
                       else positions + pos_delta[:, None])
     x = _embed(params, cfg, tokens)
-    x, k_pages, v_pages = _run_layers(
+    x, k_pages, v_pages, _ = _run_layers(
         cfg, params, x, k_pages, v_pages, page_table,
         positions, write_positions, lengths, "chunk",
         rope_positions=rope_positions, adapter_idx=adapter_idx,
@@ -614,8 +884,11 @@ def forward_decode(
     page_table: jnp.ndarray,
     pos_delta: "jnp.ndarray | None" = None,  # [B] mrope position offset
     adapter_idx: "jnp.ndarray | None" = None,  # [B] LoRA slot; -1 = base
+    aux: "LayerAux | None" = None,
 ):
-    """One decode step for every active slot; returns (logits [B, V], cache).
+    """One decode step for every active slot; returns (logits [B, V], cache)
+    and the new ``aux`` where one is given (row i is slot i: an idle row
+    leaves its slot's conv state as it was).
 
     ``pos_delta`` shifts the ROTARY position only (Qwen3-VL mrope: an
     image's soft tokens advance the position index by its merged grid
@@ -627,9 +900,9 @@ def forward_decode(
     rope_positions = (positions if pos_delta is None
                       else positions + pos_delta[:, None])
     x = _embed(params, cfg, tokens[:, None])
-    x, k_pages, v_pages = _run_layers(
+    x, k_pages, v_pages, aux = _run_layers(
         cfg, params, x, k_pages, v_pages, page_table,
         rope_positions, write_positions, lengths, "decode",
-        adapter_idx=adapter_idx,
+        adapter_idx=adapter_idx, aux=aux,
     )
-    return _logits(params, cfg, x[:, 0]), k_pages, v_pages
+    return _with_aux((_logits(params, cfg, x[:, 0]), k_pages, v_pages), aux)

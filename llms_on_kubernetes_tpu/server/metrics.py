@@ -545,6 +545,46 @@ def engine_metrics(registry: Registry) -> dict:
             "never ran, a step no longer than two leads, multihost). "
             "late over all is the miss rate",
             registry, label_names=("when",)),
+        # the expert layers (ops/moe.py), booked by the engine where a
+        # dispatch's tokens are read (Engine._book_moe); kind is the
+        # dispatch's: prefill | chunk | decode. All five are sums over
+        # token steps (a prefill or chunk is one) and expert layers
+        "moe_experts_touched": Counter(
+            "llm_moe_experts_touched_total",
+            "Experts that got at least one row, summed over token steps "
+            "and expert layers; over llm_moe_expert_slots_total it is the "
+            "share of its experts' weights a step reads",
+            registry, label_names=("kind",)),
+        "moe_expert_slots": Counter(
+            "llm_moe_expert_slots_total",
+            "Experts there were to touch: token steps x expert layers x "
+            "experts (a window step in which no row was live is no step)",
+            registry, label_names=("kind",)),
+        "moe_routed_rows": Counter(
+            "llm_moe_routed_rows_total",
+            "(token, expert) pairs routed: live rows x experts per token "
+            "x expert layers, summed over token steps",
+            registry, label_names=("kind",)),
+        "moe_fullest_expert_rows": Counter(
+            "llm_moe_fullest_expert_rows_total",
+            "Rows of the fullest expert of each expert layer, summed; over "
+            "llm_moe_mean_expert_rows_total it is the load imbalance "
+            "(1 = even)", registry, label_names=("kind",)),
+        "moe_mean_expert_rows": Counter(
+            "llm_moe_mean_expert_rows_total",
+            "Rows of the mean expert of each expert layer (routed rows / "
+            "experts), summed", registry, label_names=("kind",)),
+        "conv_state_bytes": Gauge(
+            "llm_conv_state_bytes",
+            "Device bytes of the per-slot short-convolution state that "
+            "conv layers keep beside the KV pool (0 for a model without "
+            "them)", registry),
+        "prefix_reuse_skipped": Counter(
+            "llm_prefix_reuse_skipped_total",
+            "Admissions that adopted no cached prefix though the prefix "
+            "cache was on, by reason: recurrent_state=the model has conv "
+            "layers, and a cached page holds no conv state at its end",
+            registry, label_names=("why",)),
         "auto_profile": Counter(
             "llm_auto_profile_total",
             "Automatic bounded profiler captures triggered by the "
@@ -563,9 +603,13 @@ def engine_metrics(registry: Registry) -> dict:
         m["first_tokens"].labels(delivered=delivered)
     # likewise the dispatch counters, for every kind and host there is
     from llms_on_kubernetes_tpu.engine.ledger import (
-        DECODE_LAUNCH_RULES, IDLE_HOSTS, KINDS,
+        DECODE_LAUNCH_RULES, IDLE_HOSTS, KINDS, MOE_STATS,
     )
 
+    m["prefix_reuse_skipped"].labels(why="recurrent_state")
+    for kind in ("prefill", "chunk", "decode"):
+        for stat in MOE_STATS:
+            m["moe_" + stat].labels(kind=kind)
     for when in DECODE_LAUNCH_RULES:
         m["decode_launches"].labels(when=when)
     for kind in KINDS:

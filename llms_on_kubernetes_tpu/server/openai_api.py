@@ -206,6 +206,9 @@ class EngineLoop(threading.Thread):
         self._stop_evt = threading.Event()
         self._ttft_seen: set[str] = set()
         self._preempt_seen = 0
+        self._moe_seen: collections.Counter = collections.Counter()
+        self._prefix_skipped_seen: collections.Counter = (
+            collections.Counter())
         self._early_exit_seen = 0
         self._first_tokens_seen = {"backpressure": 0, "step": 0}
         self._decode_launches_seen: collections.Counter = (
@@ -335,6 +338,19 @@ class EngineLoop(threading.Thread):
                         m["decode_launches"].labels(when=when).inc(
                             v - self._decode_launches_seen[when])
                         self._decode_launches_seen[when] = v
+                for kind, stats in getattr(eng, "moe_stats", {}).items():
+                    for stat, v in stats.items():
+                        seen = self._moe_seen[kind, stat]
+                        if v > seen:
+                            m["moe_" + stat].labels(kind=kind).inc(
+                                v - seen)
+                            self._moe_seen[kind, stat] = v
+                for why, v in getattr(
+                        eng, "prefix_reuse_skipped", {}).items():
+                    if v > self._prefix_skipped_seen[why]:
+                        m["prefix_reuse_skipped"].labels(why=why).inc(
+                            v - self._prefix_skipped_seen[why])
+                        self._prefix_skipped_seen[why] = v
                 drafted = getattr(eng, "spec_drafted_tokens", 0)
                 if drafted > self._spec_seen["drafted"]:
                     m["spec_drafted"].inc(
@@ -371,6 +387,9 @@ class EngineLoop(threading.Thread):
                 cc = getattr(eng, "cache_config", None)
                 if cc is not None:
                     m["kv_bytes_per_token"].set(cc.bytes_per_token)
+                conv = getattr(eng, "conv_state", None)
+                m["conv_state_bytes"].set(
+                    0 if conv is None else conv.size * conv.dtype.itemsize)
                 if led_snap is not None:
                     series = dict(led_snap["phase_ms"])
                     series["idle"] = led_snap["idle_ms"]
@@ -1098,7 +1117,9 @@ class OpenAIServer:
         was, what it queued behind, how long the device held it, what the
         host was doing in the gap before it), and under ``"launch"`` what
         times the next decode step: the lead, the device time each kind
-        and shape of dispatch last took, the launches by rule.
+        and shape of dispatch last took, the launches by rule, and under
+        ``"experts"`` the rows each expert of each expert layer got in
+        the newest dispatch whose tokens were read.
         ``?limit=N`` trims the first two to the most recent N."""
         limit = self._int_query(request, "limit", 0) or None
         snap = self.flight.snapshot(limit=limit)
@@ -1108,6 +1129,9 @@ class OpenAIServer:
         launch_view = getattr(self.engine, "launch_view", None)
         if launch_view is not None:
             snap["launch"] = launch_view()
+        # the newest booked dispatch's rows by expert layer and expert
+        # (a decode window: its last live token step); None before one
+        snap["experts"] = getattr(self.engine, "moe_last", None)
         snap["state"] = self.state
         snap["model"] = self.model_name
         snap["role"] = self.engine.config.role or "both"

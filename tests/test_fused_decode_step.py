@@ -79,7 +79,7 @@ def decode_window(cfg, params, k_pages, v_pages, packed, K, strategy):
             donate_argnums=(4, 5, 6))
         args = window_args(cfg, params, packed, k_pages, v_pages)
         compiled = step.lower(*args).compile()
-        packs, _toks, k_pages, v_pages, _counts, _ = compiled(*args)
+        packs, _toks, k_pages, v_pages, _counts, _, _ = compiled(*args)
     finally:
         C.set_kv_write_strategy(before)
     return np.asarray(packs), k_pages, v_pages, compiled

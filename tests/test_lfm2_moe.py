@@ -1,0 +1,704 @@
+"""LFM2-MoE on the normal path against its plain reference.
+
+The program (models/decoder.py, ops/moe.py, engine/engine.py) is held to
+``benchmark/reference/lfm2_moe.py`` — float32 ``jax.numpy``, no cache, no
+state, no batching, importing nothing of the program — on the seeded random
+weights of the ``debug-lfm2`` preset: three kinds of layer (conv + dense
+network, conv + experts, attention + experts), 8 experts top-2, 16-wide
+heads. In float32 the two agree to 1e-4 on logits on every path a request
+can take; in bfloat16 to a tolerance that each mechanism, left out or run
+in a lower type, breaks (``test_bfloat16_tolerance_catches``).
+"""
+
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+
+from reference import lfm2_moe as ref  # noqa: E402
+
+from llms_on_kubernetes_tpu.configs import (  # noqa: E402
+    from_hf_config, get_config,
+)
+from llms_on_kubernetes_tpu.engine.cache import (  # noqa: E402
+    CacheConfig, init_pages,
+)
+from llms_on_kubernetes_tpu.engine.engine import (  # noqa: E402
+    Engine, EngineConfig, SamplingParams,
+)
+from llms_on_kubernetes_tpu.models import decoder as dec  # noqa: E402
+from llms_on_kubernetes_tpu.ops import moe  # noqa: E402
+
+CFG = get_config("debug-lfm2")
+with open(os.path.join(REPO, "benchmark", "configs", "debug-lfm2.json")) as f:
+    REF_CFG = json.load(f)
+PAGE, PPS, SLOTS = 8, 8, 4
+F32_TOL = 1e-4
+# bfloat16 (weights and activations) against the float32 reference on the
+# same weights, on the log-probabilities of the reference's 8 best ids (what
+# the benchmark's check compares) at 5 positions of 24 prompts. Top-k
+# routing decides what "the same" can mean: where the k-th and the (k+1)-th
+# best selection score of some token lie within NEAR_TIE, bfloat16 rounding
+# may send the token to the other expert, and in this 3-layer model that
+# costs up to 0.58 nats (8 of the 24 prompts hold such a position; 5 of them
+# read 0.08-0.58, the other 3 read like the rest). Such a prompt is counted,
+# not compared. The other 16 read 0.038 nats at their largest (rms 0.012);
+# the tolerance is three times that, and every control of
+# test_bfloat16_tolerance_catches reads over it on the same 16: the
+# experts' weights cut to float8, the nearest type below, 0.17; the rest
+# 0.73 to 2.3
+BF16_TOL = 0.11
+NEAR_TIE = 1.5e-3
+
+
+def params_of(dtype):
+    return dec.init_params(CFG, jax.random.key(0), dtype=dtype)
+
+
+@pytest.fixture(scope="module")
+def params32():
+    return params_of("float32")
+
+
+def prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, n).tolist()
+
+
+def ref_logits(params, tokens, positions=None, cfg=REF_CFG):
+    positions = range(len(tokens)) if positions is None else positions
+    return np.asarray(ref.logits_at(cfg, params, list(tokens),
+                                    list(positions)))
+
+
+class Cache:
+    """Pools, conv state and page tables for SLOTS slots, and the jitted
+    forward passes: what the engine's steps hand to models/decoder.py."""
+
+    def __init__(self, params, cfg=CFG, dtype="float32"):
+        self.params, self.cfg = params, cfg
+        cc = CacheConfig(num_layers=cfg.num_attn_layers,
+                         num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+                         num_pages=SLOTS * PPS + 1, page_size=PAGE,
+                         pages_per_slot=PPS, dtype=dtype)
+        self.kp, self.vp = init_pages(cc)
+        self.conv = dec.init_conv_state(cfg, SLOTS, dtype)
+        self.tables = 1 + np.arange(SLOTS * PPS, dtype=np.int32).reshape(
+            SLOTS, PPS)
+        self._prefill = jax.jit(dec.forward_prefill, static_argnums=(1,))
+        self._chunk = jax.jit(dec.forward_chunk, static_argnums=(1,))
+        self._decode = jax.jit(dec.forward_decode, static_argnums=(1,))
+
+    def _keep(self, out):
+        logits, self.kp, self.vp, aux = out
+        self.conv = aux.conv
+        return np.asarray(logits), aux
+
+    def prefill(self, rows, bucket, slots):
+        """rows: token lists (an empty one is a padding row)."""
+        toks = np.zeros((len(rows), bucket), np.int32)
+        for i, r in enumerate(rows):
+            toks[i, :len(r)] = r
+        return self._keep(self._prefill(
+            self.params, self.cfg, jnp.asarray(toks),
+            jnp.asarray([len(r) for r in rows], jnp.int32), self.kp, self.vp,
+            jnp.asarray(self.tables[slots]),
+            aux=dec.LayerAux(conv=self.conv,
+                             slots=jnp.asarray(slots, jnp.int32))))
+
+    def chunk(self, tokens, history, bucket, slot):
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :len(tokens)] = tokens
+        return self._keep(self._chunk(
+            self.params, self.cfg, jnp.asarray(toks),
+            jnp.asarray([history], jnp.int32),
+            jnp.asarray([len(tokens)], jnp.int32), self.kp, self.vp,
+            jnp.asarray(self.tables[[slot]]),
+            aux=dec.LayerAux(conv=self.conv,
+                             slots=jnp.asarray([slot], jnp.int32))))
+
+    def decode(self, tokens, lengths):
+        """One token for every slot; lengths 0 = an idle row."""
+        return self._keep(self._decode(
+            self.params, self.cfg, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), self.kp, self.vp,
+            jnp.asarray(self.tables), aux=dec.LayerAux(conv=self.conv)))
+
+
+# ---------------------------------------------------------------------------
+# the forward passes, float32, tight
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,bucket", [(1, 16), (2, 16), (3, 16), (16, 16),
+                                      (17, 32), (31, 32), (32, 32)])
+def test_prefill_at_every_bucket_with_padding(params32, n, bucket):
+    c = Cache(params32)
+    toks = prompt(n, seed=n)
+    got, aux = c.prefill([toks], bucket, [1])
+    want = ref_logits(params32, toks, [n - 1])
+    np.testing.assert_allclose(got, want, atol=F32_TOL, rtol=0)
+    assert int(aux.moe_rows.sum()) == n * 2 * CFG.num_moe_layers
+
+
+def test_batched_prefill_of_rows_of_unequal_length(params32):
+    c = Cache(params32)
+    rows = [prompt(5, 1), prompt(30, 2), [], prompt(1, 3)]
+    got, aux = c.prefill(rows, 32, [3, 0, 0, 2])
+    for i, r in enumerate(rows):
+        if r:
+            np.testing.assert_allclose(
+                got[i], ref_logits(params32, r, [len(r) - 1])[0],
+                atol=F32_TOL, rtol=0)
+    # the padding row (slot column 0, like the engine's zeros) wrote the
+    # trash row and not slot 0, whose state is row 1's
+    conv = np.asarray(c.conv)
+    one = Cache(params32)
+    one.prefill([rows[1]], 32, [0])
+    np.testing.assert_array_equal(conv[:, 0], np.asarray(one.conv)[:, 0])
+    assert np.all(conv[:, 1] == 0)
+    assert int(aux.moe_rows.sum()) == 36 * 2 * CFG.num_moe_layers
+
+
+@pytest.mark.parametrize("n", [33, 40, 63])
+def test_a_prompt_longer_than_the_largest_bucket_carries_state_across_chunks(
+        params32, n):
+    c = Cache(params32)
+    toks = prompt(n, seed=n)
+    # a stale state in the slot, as its last occupant would have left it
+    c.conv = c.conv + 7.0
+    got = None
+    for at in range(0, n, 32):
+        part = toks[at:at + 32]
+        got, _ = c.chunk(part, at, 16 if len(part) <= 16 else 32, 2)
+    np.testing.assert_allclose(got, ref_logits(params32, toks, [n - 1]),
+                               atol=F32_TOL, rtol=0)
+
+
+def test_teacher_forced_decode_matches_the_full_forward_pass_everywhere(
+        params32):
+    """Prefill, then 13 decode steps through the cache, each held to the
+    reference's FULL forward pass of the whole sequence at its position;
+    slots 1 and 3 decode, slots 0 and 2 are idle rows."""
+    c = Cache(params32)
+    seqs = {1: prompt(6, 11) + prompt(13, 12), 3: prompt(19, 13) + prompt(13, 14)}
+    start = {1: 6, 3: 19}
+    c.prefill([seqs[1][:6]], 16, [1])
+    c.prefill([seqs[3][:19]], 32, [3])
+    want = {s: ref_logits(params32, seqs[s]) for s in seqs}
+    idle = np.asarray(c.conv)[:, [0, 2]].copy()
+    for step in range(13):
+        toks, lens = [0] * SLOTS, [0] * SLOTS
+        for s in seqs:
+            toks[s] = seqs[s][start[s] + step]
+            lens[s] = start[s] + step + 1
+        got, aux = c.decode(toks, lens)
+        for s in seqs:
+            np.testing.assert_allclose(
+                got[s], want[s][start[s] + step], atol=F32_TOL, rtol=0)
+        assert int(aux.moe_rows.sum()) == 2 * 2 * CFG.num_moe_layers
+    np.testing.assert_array_equal(np.asarray(c.conv)[:, [0, 2]], idle)
+
+
+# ---------------------------------------------------------------------------
+# the engine: fused K = 4 windows, slot reuse, idle rows, preemption,
+# the prefix cache. A request's every token is held to the reference's full
+# forward pass of prompt + output: the log-probability the engine reported
+# for it, and its best ids
+# ---------------------------------------------------------------------------
+
+def engine(params, **kw):
+    base = dict(model="debug-lfm2", dtype="float32", max_decode_slots=SLOTS,
+                page_size=PAGE, num_pages=SLOTS * PPS + 1, pages_per_slot=PPS,
+                prefill_buckets=(16, 32), async_scheduling=True,
+                decode_steps=4)
+    base.update(kw)
+    return Engine(EngineConfig(**base), params=params)
+
+
+def run(eng, reqs, limit=2000):
+    for _ in range(limit):
+        eng.step()
+        if all(r.finished for r in reqs):
+            return
+    raise AssertionError("the engine did not finish")
+
+
+def held_to_reference(params, req, tol=F32_TOL):
+    seq = req.prompt + req.output
+    lp = jax.nn.log_softmax(jnp.asarray(ref_logits(params, seq)), axis=-1)
+    lp = np.asarray(lp)
+    for j, (tok, entry) in enumerate(zip(req.output, req.output_logprobs)):
+        at = len(req.prompt) - 1 + j
+        assert abs(entry[0] - lp[at, tok]) < tol, (j, entry[0], lp[at, tok])
+        assert tok == int(np.argmax(lp[at]))
+
+
+def submit(eng, toks, n_out, **kw):
+    return eng.submit(list(toks), SamplingParams(
+        max_tokens=n_out, temperature=0.0, logprobs=True, **kw))
+
+
+def test_fused_windows_idle_rows_and_a_chunked_prompt(params32):
+    eng = engine(params32)
+    reqs = [submit(eng, prompt(7, 21), 14),       # >= 3 windows of K = 4
+            submit(eng, prompt(40, 22), 13)]      # longer than bucket 32
+    run(eng, reqs)                                # two of four slots idle
+    for r in reqs:
+        assert len(r.output) in (13, 14)
+        held_to_reference(params32, r)
+    assert eng.moe_stats["decode"]["routed_rows"] > 0
+    assert eng.moe_stats["chunk"]["routed_rows"] > 0
+    assert eng.moe_last["kind"] == "decode"
+
+
+def test_a_slot_reused_by_a_shorter_request_starts_from_an_empty_state(
+        params32):
+    eng = engine(params32, max_decode_slots=1, num_pages=PPS + 1)
+    first = submit(eng, prompt(30, 31), 9)
+    run(eng, [first])
+    second = submit(eng, prompt(3, 32), 9)
+    run(eng, [second])
+    held_to_reference(params32, first)
+    held_to_reference(params32, second)
+
+
+def test_preemption_and_resume_recompute_the_state(params32):
+    # 6 pages of 8 tokens for two requests that each grow to 4 pages: the
+    # younger is preempted and re-prefills prompt + output when pages free
+    eng = engine(params32, max_decode_slots=2, num_pages=7)
+    reqs = [submit(eng, prompt(12, 41), 18), submit(eng, prompt(12, 42), 18)]
+    run(eng, reqs)
+    assert eng.preemptions >= 1
+    for r in reqs:
+        assert len(r.output) == 18
+        held_to_reference(params32, r)
+
+
+def test_the_prefix_cache_adopts_nothing_and_counts_it(params32):
+    """A cached page holds keys and values, not the conv layers' state at
+    its end: a model with conv layers adopts no prefix. The second request
+    of one prompt is prefilled whole, answers as the first did, and the
+    skipped reuse is counted."""
+    eng = engine(params32)
+    toks = prompt(24, 51)           # three full pages: adoptable elsewhere
+    a = submit(eng, toks, 6)
+    run(eng, [a])
+    b = submit(eng, toks, 6)
+    run(eng, [b])
+    assert a.output == b.output
+    assert eng.allocator.hit_tokens_total == 0
+    assert eng.prefix_reuse_skipped == {"recurrent_state": 2}
+    held_to_reference(params32, b)
+    plain = Engine(EngineConfig(model="debug-tiny", dtype="float32",
+                                prefill_buckets=(32,)))
+    assert plain.conv_state is None
+    assert plain.prefix_reuse_skipped == {"recurrent_state": 0}
+
+
+@pytest.mark.parametrize("kw,word", [
+    (dict(quantization="int8"), "--quantization"),
+    (dict(speculation="ngram"), "speculation"),
+    (dict(kv_host_cache_gb=0.1), "host KV tier"),
+    (dict(adapters=(("a", "/nowhere"),)), "LoRA"),
+    (dict(multihost=True), "multihost"),
+    (dict(role="decode", kv_host_cache_gb=0.1), "role"),
+])
+def test_what_cannot_carry_the_state_refuses_at_start_up(kw, word):
+    with pytest.raises(ValueError, match="debug-lfm2.*does not support"):
+        try:
+            Engine(EngineConfig(model="debug-lfm2", **kw))
+        except ValueError as e:
+            assert word in str(e)
+            raise
+
+
+def test_a_checkpoint_of_this_family_is_not_mapped(tmp_path):
+    with pytest.raises(ValueError, match="no tensor names"):
+        Engine(EngineConfig(model="debug-lfm2"), model_dir=str(tmp_path))
+
+
+# ---------------------------------------------------------------------------
+# bfloat16: looser, and tight enough to catch each mechanism
+# ---------------------------------------------------------------------------
+
+N_PROMPTS = 24
+
+
+def top8_diffs(params, cfg=CFG, reference_params=None, seeds=range(N_PROMPTS)):
+    """Program minus reference, log-probabilities of the reference's 8 best
+    ids after a prefill and after each of 4 teacher-forced decode steps, a
+    row of 40 for each prompt (bfloat16 program; float32 reference on
+    ``reference_params``, the program's own unless a control changed them)."""
+    reference_params = params if reference_params is None else reference_params
+    out = []
+    for seed in seeds:
+        toks = prompt(10 + seed % 12, 100 + seed)
+        c = Cache(params, cfg, dtype="bfloat16")
+        got, _ = c.prefill([toks[:-4]], 32, [0])
+        rows = [got[0]]
+        for j in range(4):
+            n = len(toks) - 4 + j
+            got, _ = c.decode([toks[n], 0, 0, 0], [n + 1, 0, 0, 0])
+            rows.append(got[0])
+        want = ref_logits(reference_params, toks,
+                          range(len(toks) - 5, len(toks)))
+        row = []
+        for g, w in zip(rows, want):
+            g = np.asarray(jax.nn.log_softmax(g))
+            w = np.asarray(jax.nn.log_softmax(w))
+            top = np.argsort(-w)[:8]
+            row.append(g[top] - w[top])
+        out.append(np.concatenate(row))
+    return np.array(out)
+
+
+def narrowest_routing(params, tokens):
+    """The smallest gap, over the sequence's positions and the expert
+    layers, between the last score the reference's router chooses and the
+    first it does not."""
+    k, worst = REF_CFG["num_experts_per_tok"], []
+
+    def note(_layer, g, lp):
+        s = jnp.sort(jax.nn.sigmoid(g @ ref._f32(lp["router"]))
+                     + ref._f32(lp["router_bias"]), axis=-1)
+        worst.append(float(jnp.min(s[:, -k] - s[:, -k - 1])))
+
+    with jax.default_matmul_precision("highest"):
+        ref._hidden(REF_CFG, params, tokens, note)
+    return min(worst)
+
+
+@pytest.fixture(scope="module")
+def params16():
+    return params_of("bfloat16")
+
+
+@pytest.fixture(scope="module")
+def clear_seeds(params16):
+    """The prompts whose every routing is decided by more than NEAR_TIE."""
+    return [seed for seed in range(N_PROMPTS) if narrowest_routing(
+        params16, prompt(10 + seed % 12, 100 + seed)) >= NEAR_TIE]
+
+
+def test_bfloat16_agrees_within_its_tolerance(params16, clear_seeds):
+    assert len(clear_seeds) >= N_PROMPTS // 2
+    diffs = top8_diffs(params16)
+    assert np.max(np.abs(diffs[clear_seeds])) < BF16_TOL
+    # whatever lies over the tolerance is a prompt with a near-tie: a flip,
+    # not a fault
+    over = [seed for seed in range(N_PROMPTS)
+            if np.max(np.abs(diffs[seed])) >= BF16_TOL]
+    assert set(over) <= set(range(N_PROMPTS)) - set(clear_seeds)
+    assert len(over) <= N_PROMPTS // 4
+
+
+def _fp8(w):
+    return w.astype(jnp.float8_e4m3fn).astype(w.dtype)
+
+
+def _edit(params, run, **leaves):
+    layers = list(params["layers"])
+    layers[run] = dict(layers[run], **{
+        k: f(layers[run][k]) for k, f in leaves.items()})
+    return dict(params, layers=tuple(layers))
+
+
+CONTROLS = {
+    # (how the PROGRAM's weights or configuration are changed; the
+    # reference keeps the true ones)
+    "experts in a lower type": lambda p: (
+        _edit(_edit(p, 1, w_gate=_fp8, w_up=_fp8, w_down=_fp8),
+              2, w_gate=_fp8, w_up=_fp8, w_down=_fp8), CFG),
+    "conv in a lower type": lambda p: (
+        _edit(_edit(p, 0, conv_in=_fp8, conv_out=_fp8),
+              1, conv_in=_fp8, conv_out=_fp8), CFG),
+    "no selection bias": lambda p: (
+        p, dataclasses.replace(CFG, use_expert_bias=False)),
+    "no renormalisation": lambda p: (
+        p, dataclasses.replace(CFG, norm_topk_prob=False)),
+    "no q/k norms": lambda p: (p, dataclasses.replace(CFG, qk_norm=False)),
+    "one convolution tap left out": lambda p: (
+        _edit(p, 1, conv_w=lambda w: w.at[:, :, 0].set(0)), CFG),
+}
+
+
+@pytest.mark.parametrize("control", sorted(CONTROLS))
+def test_bfloat16_tolerance_catches(params16, clear_seeds, control):
+    changed, cfg = CONTROLS[control](params16)
+    assert np.max(np.abs(top8_diffs(
+        changed, cfg, reference_params=params16, seeds=clear_seeds))) \
+        > BF16_TOL
+
+
+# ---------------------------------------------------------------------------
+# units: the conv operator, the router, the grouped expert product
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_valid", [0, 1, 2, 5, 8])
+def test_the_conv_operator_against_an_explicit_loop(n_valid):
+    rng = np.random.default_rng(n_valid)
+    D, T, taps = 8, 8, 3
+    cfg = dataclasses.replace(CFG, hidden_size=D)
+    lp = {"conv_in": rng.normal(size=(D, 3 * D)).astype(np.float32),
+          "conv_w": rng.normal(size=(D, taps)).astype(np.float32),
+          "conv_out": rng.normal(size=(D, D)).astype(np.float32)}
+    u = rng.normal(size=(1, T, D)).astype(np.float32)
+    state = rng.normal(size=(1, taps - 1, D)).astype(np.float32)
+    out, new = dec._short_conv(
+        jax.tree_util.tree_map(jnp.asarray, lp), cfg, jnp.asarray(u),
+        jnp.asarray(state), jnp.asarray([n_valid], jnp.int32))
+    bcx = u[0] @ lp["conv_in"]
+    b, c, x = bcx[:, :D], bcx[:, D:2 * D], bcx[:, 2 * D:]
+    z = np.concatenate([state[0], b * x])      # z[t + 2] is position t's
+    want = np.zeros((T, D), np.float32)
+    for t in range(T):
+        acc = np.zeros(D, np.float32)
+        for j in range(taps):
+            acc += lp["conv_w"][:, j] * z[t + j]
+        want[t] = (c[t] * acc) @ lp["conv_out"]
+    np.testing.assert_allclose(np.asarray(out)[0, :max(n_valid, 1)],
+                               want[:max(n_valid, 1)], atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(new)[0], z[n_valid:n_valid + 2],
+                               atol=1e-6)
+
+
+def _router_case():
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(32, 16)).astype(np.float32)
+    w = rng.normal(size=(16, 8)).astype(np.float32) * 0.3
+    return jnp.asarray(x), jnp.asarray(w)
+
+
+def test_the_bias_changes_the_selection_and_never_a_weight():
+    x, w = _router_case()
+    kw = dict(top_k=2, scores="sigmoid", renorm=False)
+    sel0, a0 = moe.route(x, w, None, **kw)
+    bias = jnp.zeros((8,)).at[3].set(5.0)      # expert 3 always chosen
+    sel, a = moe.route(x, w, bias, **kw)
+    assert np.all(np.asarray(sel)[:, 0] == 3)
+    assert not np.array_equal(np.asarray(sel0), np.asarray(sel))
+    s = np.asarray(jax.nn.sigmoid(x @ w))
+    np.testing.assert_allclose(
+        np.asarray(a), np.take_along_axis(s, np.asarray(sel), 1), atol=1e-6)
+    assert np.all(np.asarray(a) < 1.0)         # the 5.0 is in no weight
+
+
+@pytest.mark.parametrize("scores", ["sigmoid", "softmax"])
+def test_renormalisation_its_epsilon_and_the_route_scale(scores):
+    x, w = _router_case()
+    sel, raw = moe.route(x, w, top_k=2, scores=scores, renorm=False)
+    _, a = moe.route(x, w, top_k=2, scores=scores, renorm=True, eps=0.0)
+    np.testing.assert_allclose(np.asarray(a).sum(1), 1.0, atol=1e-6)
+    _, a_eps = moe.route(x, w, top_k=2, scores=scores, renorm=True, eps=0.5)
+    raw = np.asarray(raw)
+    np.testing.assert_allclose(
+        np.asarray(a_eps), raw / (raw.sum(1, keepdims=True) + 0.5), atol=1e-6)
+    _, a_scaled = moe.route(x, w, top_k=2, scores=scores, renorm=True,
+                            scale=2.5)
+    np.testing.assert_allclose(np.asarray(a_scaled), 2.5 * np.asarray(a),
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["even", "skewed", "an empty expert",
+                                  "stacked layers", "padding"])
+def test_the_grouped_expert_product_against_a_per_token_loop(case):
+    rng = np.random.default_rng(9)
+    N, D, F, E, k = 24, 8, 12, 6, 2
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    w_gate = rng.normal(size=(3, E, D, F)).astype(np.float32) * 0.3
+    w_up = rng.normal(size=(3, E, D, F)).astype(np.float32) * 0.3
+    w_down = rng.normal(size=(3, E, F, D)).astype(np.float32) * 0.3
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(N)])
+    if case == "skewed":
+        sel[:20] = [0, 1]                   # most rows on two experts
+    if case == "an empty expert":
+        sel = np.where(sel == 4, 5, sel)    # nobody goes to expert 4 ...
+        sel[:, 1] = np.where(sel[:, 1] == sel[:, 0], 3, sel[:, 1])
+    weight = rng.uniform(0.1, 1.0, size=(N, k)).astype(np.float32)
+    valid = np.ones(N, bool)
+    if case == "padding":
+        valid[::3] = False
+    layer = 1 if case == "stacked layers" else None
+    ws = (w_gate, w_up, w_down) if layer is not None else (
+        w_gate[1], w_up[1], w_down[1])
+    out, rows = moe.grouped_experts(
+        jnp.asarray(x), jnp.asarray(sel), jnp.asarray(weight),
+        *map(jnp.asarray, ws), valid=jnp.asarray(valid), layer=layer)
+    want = np.zeros((N, D), np.float32)
+    count = np.zeros(E, int)
+    for t in range(N):
+        if not valid[t]:
+            continue
+        for e, a in zip(sel[t], weight[t]):
+            g = x[t] @ w_gate[1][e]
+            h = g / (1 + np.exp(-g)) * (x[t] @ w_up[1][e])
+            want[t] += a * (h @ w_down[1][e])
+            count[e] += 1
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=1e-5)
+    assert np.asarray(rows).tolist() == count.tolist()
+    if case == "an empty expert":
+        assert count[4] == 0
+
+
+def _stacks(rng, n, E, D, F, int8):
+    from llms_on_kubernetes_tpu.ops.quant import quantize
+
+    ws = [rng.normal(size=s).astype(np.float32) * 0.3
+          for s in ((n, E, D, F), (n, E, D, F), (n, E, F, D))]
+    if not int8:
+        return list(map(jnp.asarray, ws)), ws
+    qs = [quantize(jnp.asarray(w), reduce_axes=(2,)) for w in ws]
+    return qs, [np.asarray(q.dequantize(jnp.float32)) for q in qs]
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("dims", [dict(expert=4, model=2), dict(expert=2),
+                                  dict(model=4), dict(expert=8)],
+                         ids=lambda d: " x ".join(f"{k} {v}" for k, v in
+                                                  d.items()))
+def test_the_grouped_product_per_shard_of_a_mesh(dims, int8):
+    """Stacks sharded as parallel/sharding.param_specs shards them: every
+    device multiplies its experts and its slice of the width, and the sum
+    is what one device computes (int8: from the int8 stack as it is)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llms_on_kubernetes_tpu.parallel.mesh import (
+        make_mesh, set_active_mesh)
+
+    rng = np.random.default_rng(11)
+    N, D, F, E, k, n = 16, 8, 16, 8, 2, 2
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(N)])
+    sel[:6] = [0, 7]                                    # a skew across shards
+    weight = rng.uniform(0.1, 1.0, size=(N, k)).astype(np.float32)
+    valid = np.ones(N, bool)
+    valid[::5] = False
+    ws, plain = _stacks(rng, n, E, D, F, int8)
+    args = (jnp.asarray(x), jnp.asarray(sel), jnp.asarray(weight))
+    run = jax.jit(lambda ws: moe.grouped_experts(
+        *args, *ws, valid=jnp.asarray(valid), layer=1))
+    want, want_rows = moe.grouped_experts(
+        *args, *map(jnp.asarray, plain), valid=jnp.asarray(valid), layer=1)
+    mesh = make_mesh(data=1, seq=1, **{"expert": 1, "model": 1, **dims},
+                     devices=jax.devices()[:int(np.prod(list(dims.values())))])
+    e = "expert" if dims.get("expert", 1) > 1 else None
+    m = "model" if dims.get("model", 1) > 1 else None
+    specs = (P(None, e, None, m), P(None, e, None, m), P(None, e, m, None))
+    from llms_on_kubernetes_tpu.ops.quant import QTensor, scale_spec
+
+    def put(w, s):
+        if isinstance(w, QTensor):
+            return QTensor(put(w.data, s),
+                           put(w.scale, scale_spec(s, w.scale.shape)))
+        return jax.device_put(w, NamedSharding(mesh, s))
+
+    set_active_mesh(mesh)
+    try:
+        got, rows = run([put(w, s) for w, s in zip(ws, specs)])
+        text = run.lower([put(w, s) for w, s in zip(ws, specs)]) \
+            .compile().as_text()
+    finally:
+        set_active_mesh(None)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=1e-5)
+    assert np.asarray(rows).tolist() == np.asarray(want_rows).tolist()
+    assert "all-gather" not in text       # no stack is gathered to a device
+    assert np.asarray(got)[~valid].any() == False  # noqa: E712
+
+
+def test_int8_experts_against_a_per_token_loop_over_the_widened_stack():
+    rng = np.random.default_rng(5)
+    N, D, F, E, k = 12, 8, 16, 4, 2
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(N)])
+    weight = rng.uniform(0.1, 1.0, size=(N, k)).astype(np.float32)
+    qs, (w_gate, w_up, w_down) = _stacks(rng, 2, E, D, F, True)
+    out, _ = moe.grouped_experts(jnp.asarray(x), jnp.asarray(sel),
+                                 jnp.asarray(weight), *qs, layer=1)
+    want = np.zeros((N, D), np.float32)
+    for t in range(N):
+        for e, a in zip(sel[t], weight[t]):
+            g = x[t] @ w_gate[1][e]
+            want[t] += a * ((g / (1 + np.exp(-g)) * (x[t] @ w_up[1][e]))
+                            @ w_down[1][e])
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the configuration: published keys, the cut to a stage's layers, bytes
+# ---------------------------------------------------------------------------
+
+def test_from_hf_config_reads_the_published_keys():
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        doc = json.load(f)
+    cfg = from_hf_config(doc, name="x")
+    want = get_config(doc["registry_name"])
+    assert dataclasses.replace(cfg, name=want.name) == want
+    assert cfg.layer_runs == (
+        ("conv", "dense", 0, 1), ("conv", "moe", 1, 3), ("attn", "moe", 4, 1),
+        ("conv", "moe", 5, 3), ("attn", "moe", 8, 1))
+    assert (cfg.num_attn_layers, cfg.num_conv_layers, cfg.num_moe_layers) \
+        == (2, 7, 8)
+
+
+def test_the_registry_entry_is_the_published_model_and_the_cut_a_stage():
+    full = get_config("lfm2-24b-a2b")
+    assert (full.num_layers, full.num_attn_layers, full.num_dense_layers) \
+        == (40, 10, 2)
+    assert get_config("LiquidAI/LFM2-24B-A2B") is full
+    assert 23.8e9 < full.num_params < 23.9e9
+    cut = get_config("lfm2-24b-a2b@0,3-10")
+    assert cut.layer_types == tuple(full.layer_types[i]
+                                    for i in (0, 3, 4, 5, 6, 7, 8, 9, 10))
+    assert cut.num_dense_layers == 1 and 5.17e9 < cut.num_params < 5.19e9
+    for bad in ("lfm2-24b-a2b@", "lfm2-24b-a2b@3-1", "lfm2-24b-a2b@0,40",
+                "lfm2-24b-a2b@x", "nothing@0",
+                "mistral-7b@0-3"):      # one kind of layer: served whole
+        with pytest.raises(KeyError):
+            get_config(bad)
+
+
+@pytest.mark.parametrize("name", ["debug-lfm2", "lfm2-24b-a2b"])
+def test_expected_bytes_are_the_seeded_trees_and_the_shape_counts(name):
+    from harness import shapes_lfm2_moe as shapes
+
+    with open(os.path.join(REPO, "benchmark", "configs", f"{name}.json")) as f:
+        doc = json.load(f)
+    cfg = get_config(doc["registry_name"])
+    tree = jax.eval_shape(
+        lambda k: dec.init_params(cfg, k, dtype="bfloat16"),
+        jax.random.key(0))
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+    assert held == shapes.weight_bytes(doc) == doc["expected_bytes"]["weights"]
+    flags = doc["serve_flags"]
+    cc = CacheConfig(num_layers=cfg.num_attn_layers,
+                     num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+                     num_pages=flags["--num-pages"],
+                     page_size=flags["--page-size"])
+    assert cc.bytes_per_token == shapes.kv_bytes_per_token(doc)
+    assert cc.bytes_per_token * flags["--num-pages"] * flags["--page-size"] \
+        == shapes.pool_bytes(doc) == doc["expected_bytes"]["pool"]
+    conv = jax.eval_shape(lambda: dec.init_conv_state(
+        cfg, flags["--max-decode-slots"], "bfloat16"))
+    assert int(np.prod(conv.shape)) * 2 == shapes.conv_state_bytes(
+        doc, flags["--max-decode-slots"])
+
+
+def test_no_float32_copy_larger_than_one_layers_tensor():
+    """init_params draws one layer's tensor at a time: the generator holds
+    a float32 array of ONE layer's shape, never of a run's whole stack."""
+    shape = (CFG.num_experts, CFG.hidden_size, CFG.expert_width)
+    text = str(jax.make_jaxpr(
+        lambda k: dec._normal_stack(k, shape, 1.0, jnp.bfloat16))(
+            jax.random.split(jax.random.key(0), 3)))
+    assert "bf16[3,8,64,48]" in text and "f32[8,64,48]" in text
+    assert "f32[3,8,64,48]" not in text

@@ -163,10 +163,11 @@ def test_moe_block_matches_dense_topk():
     wu = rng.normal(size=(E, D, F)).astype(np.float32) * 0.1
     wd = rng.normal(size=(E, F, D)).astype(np.float32) * 0.1
 
-    got = moe_block(
+    got, rows = moe_block(
         jnp.asarray(x), jnp.asarray(router), jnp.asarray(wg), jnp.asarray(wu),
-        jnp.asarray(wd), top_k=k, capacity_factor=float(E) / k,  # no drops
+        jnp.asarray(wd), top_k=k,
     )
+    assert int(rows.sum()) == N * k
 
     # dense reference: every expert on every token, combine top-k
     logits = x @ router
@@ -183,26 +184,10 @@ def test_moe_block_matches_dense_topk():
     np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-3, atol=2e-3)
 
 
-def test_moe_capacity_drops_tokens():
-    # All tokens route to one expert; capacity 1 token => later tokens dropped.
-    N, D, F, E = 4, 4, 4, 2
-    x = np.ones((N, D), np.float32)
-    router = np.zeros((D, E), np.float32)
-    router[:, 0] = 10.0  # everyone picks expert 0 (then expert 1 as 2nd choice)
-    wg = np.ones((E, D, F), np.float32) * 0.1
-    wu = np.ones((E, D, F), np.float32) * 0.1
-    wd = np.ones((E, F, D), np.float32) * 0.1
-    out = moe_block(
-        jnp.asarray(x), jnp.asarray(router), jnp.asarray(wg), jnp.asarray(wu),
-        jnp.asarray(wd), top_k=1, capacity_factor=0.5,  # C = 1
-    )
-    out = np.asarray(out)
-    assert np.abs(out[0]).sum() > 0        # first token served
-    assert np.allclose(out[1:], 0.0)       # overflow tokens dropped
-
-
 def test_moe_padding_does_not_displace_real_tokens():
-    """Padding rows must not claim expert capacity (valid-mask semantics)."""
+    """Padding rows reach no expert and count nowhere (valid-mask
+    semantics); the expert path is dropless, so there is no capacity for
+    them to claim."""
     import jax.numpy as jnp
     N, D, F, E = 8, 4, 4, 2
     rng = np.random.default_rng(7)
@@ -215,13 +200,14 @@ def test_moe_padding_does_not_displace_real_tokens():
     wd = rng.normal(size=(E, F, D)).astype(np.float32) * 0.1
     valid = np.array([True] * 4 + [False] * 4)
 
-    masked = moe_block(
+    masked, rows = moe_block(
         jnp.asarray(x), jnp.asarray(router), jnp.asarray(wg), jnp.asarray(wu),
-        jnp.asarray(wd), top_k=1, capacity_factor=2.0, valid=jnp.asarray(valid),
+        jnp.asarray(wd), top_k=1, valid=jnp.asarray(valid),
     )
-    only_real = moe_block(
+    only_real, rows_real = moe_block(
         jnp.asarray(x[:4]), jnp.asarray(router), jnp.asarray(wg), jnp.asarray(wu),
-        jnp.asarray(wd), top_k=1, capacity_factor=4.0,  # same C=4
+        jnp.asarray(wd), top_k=1,
     )
+    assert rows.tolist() == rows_real.tolist() and int(rows.sum()) == 4
     np.testing.assert_allclose(np.asarray(masked)[:4], np.asarray(only_real), rtol=1e-5, atol=1e-5)
     assert np.allclose(np.asarray(masked)[4:], 0.0)

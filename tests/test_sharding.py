@@ -75,3 +75,38 @@ def test_mesh_shapes():
     assert m.shape == {"data": 1, "seq": 4, "expert": 1, "model": 2}
     with pytest.raises(ValueError):
         make_mesh(data=3)
+
+
+@pytest.mark.parametrize("quantization,mesh_dims", [
+    (None, dict(expert=4, model=2)),
+    ("int8", dict(expert=2, model=1)),
+], ids=["float, expert 4 x model 2", "int8, expert 2"])
+def test_engine_serves_routed_experts_shard_by_shard(quantization, mesh_dims):
+    """The engine sets the mesh the expert layers read (ops/moe._per_shard:
+    each device multiplies its experts and its slice of the width): greedy
+    tokens through prefill and fused decode windows are the one-device
+    engine's."""
+    from llms_on_kubernetes_tpu.engine.engine import (
+        Engine, EngineConfig, SamplingParams,
+    )
+    from llms_on_kubernetes_tpu.parallel.mesh import set_active_mesh
+
+    def greedy(mesh):
+        eng = Engine(EngineConfig(
+            model="debug-moe", dtype="float32", max_decode_slots=4,
+            page_size=8, num_pages=64, pages_per_slot=8,
+            prefill_buckets=(16, 32), decode_steps=4,
+            quantization=quantization), mesh=mesh)
+        try:
+            reqs = [eng.submit(p, SamplingParams(temperature=0.0,
+                                                 max_tokens=10))
+                    for p in ([1, 2, 3], [4, 5, 6, 7, 8], [9, 10])]
+            while any(not r.finished for r in reqs):
+                eng.step()
+            return [r.output for r in reqs]
+        finally:
+            set_active_mesh(None)
+
+    n = mesh_dims["expert"] * mesh_dims["model"]
+    assert greedy(make_mesh(data=1, devices=jax.devices()[:n],
+                            **mesh_dims)) == greedy(None)

@@ -218,3 +218,70 @@ def test_tensor_parallel_append_stays_on_each_chips_heads(topo, no_cache,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= shard
     assert mem.temp_size_in_bytes < shard // 8
+
+
+# mesh (expert x model) and the stacks' type -> an expert layer of eight
+# experts of 4096 x 14336 (two layers of an eight-layer stack, 32 rows)
+EXPERT_CASES = {
+    "one chip, int8": ((1, 1), "int8"),
+    "--ep 4, bfloat16": ((4, 1), "bfloat16"),
+    "--ep 4, int8": ((4, 1), "int8"),
+    "--tp 4, bfloat16": ((1, 4), "bfloat16"),
+    "--ep 2 --tp 2, int8": ((2, 2), "int8"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXPERT_CASES))
+def test_expert_stacks_stay_where_they_are_and_as_they_are(topo, no_cache,
+                                                           case):
+    """The grouped product takes each chip's shard of the stacks in place:
+    no stack is gathered to a chip for the unpartitionable group axis, no
+    layer is copied out of its stack, no int8 expert is widened in memory;
+    the one exchange of a layer is the sum of its [rows, hidden] result."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llms_on_kubernetes_tpu.ops import moe
+    from llms_on_kubernetes_tpu.ops.quant import QTensor
+    from llms_on_kubernetes_tpu.parallel.mesh import (
+        AXIS_EXPERT, AXIS_MODEL, make_mesh, set_active_mesh,
+    )
+
+    (ep, tp), dtype = EXPERT_CASES[case]
+    n, E, D, F, N = 8, 8, 4096, 14336, 32
+    mesh = make_mesh(expert=ep, model=tp, devices=list(topo.devices)[:ep * tp])
+    e = AXIS_EXPERT if ep > 1 else None
+    m = AXIS_MODEL if tp > 1 else None
+
+    def sds(shape, dt, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def stack(shape, spec, scale_spec):
+        if dtype == "bfloat16":
+            return sds(shape, jnp.bfloat16, spec)
+        return QTensor(sds(shape, jnp.int8, spec),
+                       sds((n, E, 1, shape[3]), jnp.float32, scale_spec))
+
+    up = (n, E, D, F), P(None, e, None, m), P(None, e, None, m)
+    stacks = (stack(*up), stack(*up),
+              stack((n, E, F, D), P(None, e, m, None), P(None, e)))
+
+    def two_layers(x, router, w_gate, w_up, w_down):
+        for i in range(2):
+            x = x + moe.moe_block(x, router[i], w_gate, w_up, w_down,
+                                  top_k=2, layer=i)[0]
+        return x
+
+    set_active_mesh(mesh)
+    try:
+        compiled = jax.jit(two_layers).lower(
+            sds((N, D), jnp.bfloat16), sds((n, D, E), jnp.bfloat16),
+            *stacks).compile()
+    finally:
+        set_active_mesh(None)
+    hlo = compiled.as_text()
+    assert "ragged-dot" in hlo and "all-gather" not in hlo
+    assert hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(") == (
+        2 if ep * tp > 1 else 0)
+    one_expert = D * F * (1 if dtype == "int8" else 2) // tp
+    assert compiled.memory_analysis().temp_size_in_bytes < one_expert // 4

@@ -551,7 +551,7 @@ def test_cell_decode_window_fused_in_place(mistral):
             if i == 2:
                 jax.block_until_ready(args[4].data)
                 t0 = time.perf_counter()
-            packs, _t, args[4], args[5], args[6], _ = compiled(*args)
+            packs, _t, args[4], args[5], args[6], _, _ = compiled(*args)
         jax.block_until_ready(packs)
         return (time.perf_counter() - t0) / n * 1e3
 

@@ -79,9 +79,8 @@ def test_load_hf_checkpoint_logit_parity(tmp_path, family):
     with torch.no_grad():
         want = hf(torch.tensor([prompt])).logits[0, -1].numpy()
     got = _prefill_logits(cfg, params, prompt)
-    # mixtral's HF impl drops no tokens (no capacity); ours with default
-    # capacity_factor may drop under adversarial routing, but 6 tokens over
-    # 4 experts with factor 2.0 gives C=6 >= N — exact parity expected.
+    # mixtral's HF impl drops no tokens (no capacity), and neither does
+    # ours (ops/moe.py is dropless) — exact parity expected.
     np.testing.assert_allclose(got, want, rtol=4e-3, atol=4e-3)
 
 
