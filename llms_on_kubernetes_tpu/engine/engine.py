@@ -3702,8 +3702,13 @@ class Engine:
         st["routed_rows"] += int(steps.sum())
         st["fullest_expert_rows"] += int(steps.max(axis=2).sum())
         st["mean_expert_rows"] += float(steps.sum()) / cfg.num_experts
+        from llms_on_kubernetes_tpu.ops import attention
+
+        # which grouped product the newest traced step took (ops/moe._plan)
+        impl, why = attention._chosen.get("experts", ("", ""))
         self.moe_last = {"kind": kind, "token_steps": len(steps),
-                         "rows_per_expert": steps[-1].tolist()}
+                         "rows_per_expert": steps[-1].tolist(),
+                         "product": f"{impl} ({why})"}
 
     def launch_view(self) -> dict:
         """What times the next decode step, for ``GET /debug/engine``:

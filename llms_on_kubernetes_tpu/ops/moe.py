@@ -7,12 +7,24 @@ One path for prefill, chunk and decode, and for every routed model
                     weights (``route``)
   sort              the N*k (token, expert) pairs by expert, stable, the
                     pairs of padding and idle tokens last
-  grouped product   ``jax.lax.ragged_dot``: row i of the sorted rows is
-                    multiplied with the weights of ITS expert, so the work
-                    is top_k x N rows whatever the number of experts (on
-                    the TPU XLA lowers it to a grouped-matmul kernel of its
-                    own; elsewhere to a masked dense product)
+  grouped product   row i of the sorted rows is multiplied with the weights
+                    of ITS expert, so the work is top_k x N rows whatever
+                    the number of experts. One algorithm, two tiles
+                    (``_plan``, from the static shape): on the TPU, up
+                    to the 4,096 rows an expert it was measured at, the
+                    Pallas kernel of ops/pallas_grouped.py: each
+                    expert's rows start at a multiple of a 16- to 128-row
+                    tile, and a grid step is one touched expert's weight
+                    block, read once, times the rows it got; everywhere
+                    else ``jax.lax.ragged_dot`` (on the TPU XLA's own
+                    grouped kernel, elsewhere a masked dense product)
   combine           the pairs back in token order, weighted and summed
+
+Which product a step was traced with is in the server's log, beside the
+attention dispatchers' picks: ``[attention] op=experts impl=pallas-compiled
+why=256 pairs over 64 experts, row tile 16`` or ``impl=xla why=ragged_dot,
+<the reason>``; in a capture the first is ``grouped_expert_matmul``, the
+second ``ragged-dot-none``.
 
 Dropless by construction: a token's result never depends on which other
 requests share its batch. There is no dispatch tensor over
@@ -68,27 +80,90 @@ def route(
         return sel, weight * scale
 
 
-def _grouped_dot(rows: jnp.ndarray, w, layer, group_sizes: jnp.ndarray,
-                 expert_of_row: jnp.ndarray) -> jnp.ndarray:
-    """rows [M, K] sorted by expert x layer ``layer`` of w [n, E, K, N]
-    -> [M, N] in rows' type.
+# The most rows an expert gets at the mean (pairs / experts) at which the
+# Pallas grouped kernel (ops/pallas_grouped.py) takes the three products:
+# the largest at which a v5e has timed it, and found it no slower than
+# ``ragged_dot``. XLA's own kernel pays a 256-row tile of MXU work for
+# every touched expert and block whatever rows it got, so the gap is widest
+# at a handful of rows: a layer of 64 experts of 2048 x 1536 reads 2.34 ->
+# 1.62 ms at 4 rows an expert at the mean (decode), 3.97 -> 1.80 at 8,
+# 4.25 -> 2.14 at 32, 5.10 -> 3.01 at 128, 6.81 -> 4.71 at 256, 11.2 -> 8.7
+# at 512, 19.4 -> 15.3 at 1,024, 38.5 -> 32.6 at 2,048; one of 8 experts of
+# 4096 x 3584 1.36 -> 1.32 at 8, 3.67 -> 2.40 at 256, 31.1 -> 26.3 at 4,096
+# (PERF.md, section 6, PR 39). No crossing was found; past what was
+# measured ``ragged_dot`` stays.
+KERNEL_MAX_MEAN_ROWS = 4096
 
-    The product is handed the WHOLE stack as n * E groups, every group of
-    another layer empty: a slice of the stack cannot be (the grouped
-    kernel is a custom call, whose operand XLA would first copy out of the
-    stack, 1.2 GB a layer at 64 experts of 2048 x 1536), and an empty
-    group costs the kernel nothing, it visits the groups that have rows.
-    An int8 ``QTensor`` goes in as int8 (the kernel widens a block as it
-    loads it: no widened copy of the experts is ever written) and its
+
+def _plan(x, pairs: int, stacks, experts: int) -> "int | None":
+    """The row tile of the Pallas grouped kernel for this expert layer, or
+    None where ``jax.lax.ragged_dot`` multiplies it: chosen from what the
+    trace can observe (backend, types, the pairs over the ``experts`` they
+    were routed among, VMEM) and recorded as ``op=experts`` beside the
+    attention dispatchers' picks."""
+    from llms_on_kubernetes_tpu.ops import attention, pallas_grouped
+
+    datas = [w.data if isinstance(w, QTensor) else w for w in stacks]
+    what = f"{pairs} pairs over {experts} experts"
+    if datas[0].shape[1] != experts:
+        what += f" ({datas[0].shape[1]} on this shard)"
+    mode = attention.pallas_mode()
+    tile, why = pallas_grouped.row_tile(pairs, experts), None
+    if mode is None:
+        why = attention._no_pallas_why()
+    elif pairs > KERNEL_MAX_MEAN_ROWS * experts:
+        why = f"{what}: {pairs // experts} rows an expert, not measured"
+    elif mode == "compiled" and x.dtype != jnp.bfloat16:
+        why = f"{x.dtype.name} rows"
+    elif mode == "compiled" and any(d % 128 for d in datas[0].shape[2:]):
+        why = ("experts of {} x {}: not multiples of 128"
+               .format(*datas[0].shape[2:]))
+    else:
+        need = max(pallas_grouped.grouped_vmem_bytes(
+            tile, *w.shape[2:], x.dtype.itemsize, w.dtype.itemsize)
+            for w in datas)
+        if need > attention.VMEM_BUDGET_BYTES:
+            why = (f"{what} need {attention._mib(need)} VMEM > "
+                   f"{attention._mib(attention.VMEM_BUDGET_BYTES)} budget")
+    if why is not None:
+        attention._choose("experts", "xla", f"ragged_dot, {why}")
+        return None
+    attention._choose("experts", f"pallas-{mode}", f"{what}, row tile {tile}")
+    return tile
+
+
+def _grouped_dot(rows: jnp.ndarray, w, layer, group_sizes: jnp.ndarray,
+                 expert_of_row: jnp.ndarray, lay=None) -> jnp.ndarray:
+    """rows [M, K] sorted by expert x layer ``layer`` of w [n, E, K, N]
+    -> [M, N] in rows' type; with ``lay`` the rows are group-aligned
+    (``pallas_grouped.layout``) and the Pallas kernel multiplies them.
+
+    Either product is handed the WHOLE stack: a slice of it cannot be (the
+    grouped kernel is a custom call, whose operand XLA would first copy
+    out of the stack, 1.2 GB a layer at 64 experts of 2048 x 1536).
+    ``ragged_dot`` sees n * E groups, every group of another layer empty
+    (an empty group costs it nothing, it visits the groups that have
+    rows); the Pallas kernel's index map names (layer, expert) itself.
+    An int8 ``QTensor`` goes in as int8 (both kernels widen a block as
+    they load it: no widened copy of the experts is ever written) and its
     per-column scale is applied to the result, by each row's expert."""
     data = w.data if isinstance(w, QTensor) else w.astype(rows.dtype)
     n, E = data.shape[:2]
-    if n > 1:
-        group_sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n * E,), group_sizes.dtype), group_sizes, (layer * E,))
-    out = jax.lax.ragged_dot(
-        rows, data.reshape(n * E, *data.shape[2:]), group_sizes,
-        preferred_element_type=rows.dtype)
+    if lay is not None:
+        from llms_on_kubernetes_tpu.ops import attention, pallas_grouped
+
+        out = pallas_grouped.grouped_matmul(
+            rows, data, layer, lay.tile_expert, lay.n_tiles,
+            tile=rows.shape[0] // lay.tile_expert.shape[0],
+            interpret=attention.pallas_mode() == "interpret")
+    else:
+        if n > 1:
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * E,), group_sizes.dtype), group_sizes,
+                (layer * E,))
+        out = jax.lax.ragged_dot(
+            rows, data.reshape(n * E, *data.shape[2:]), group_sizes,
+            preferred_element_type=rows.dtype)
     if isinstance(w, QTensor):
         scale = jax.lax.dynamic_index_in_dim(w.scale, layer, 0, False)
         scale = scale.reshape(E, -1)                                 # [E, N]
@@ -96,31 +171,47 @@ def _grouped_dot(rows: jnp.ndarray, w, layer, group_sizes: jnp.ndarray,
     return out
 
 
-def _experts(x, expert, weight, w_gate, w_up, w_down, layer, act):
+def _experts(x, expert, weight, w_gate, w_up, w_down, layer, act,
+             experts=None):
     """The sort, the three grouped products and the combine over the
     experts of the stacks given: ``expert`` [N*k] names each (token,
     choice) pair's expert among them, or their number for a pair that
     reaches none of them (padding, an idle row, another shard's expert).
-    Returns [N, D] float32."""
+    ``experts``: how many the pairs were routed among, where the stacks
+    hold a shard of them. Returns [N, D] float32."""
+    from llms_on_kubernetes_tpu.ops import pallas_grouped
+
     N, D = x.shape
-    k = expert.shape[0] // N
+    M = expert.shape[0]
+    k = M // N
     E = w_gate.shape[1]
     order = jnp.argsort(expert, stable=True)                         # [N*k]
-    expert_sorted = expert[order]
-    rows = jnp.sum(expert[:, None] == jnp.arange(E, dtype=jnp.int32),
-                   axis=0, dtype=jnp.int32)                          # [E]
-    xs = x[order // k]                                               # [N*k, D]
-    h = (act(_grouped_dot(xs, w_gate, layer, rows, expert_sorted))
-         * _grouped_dot(xs, w_up, layer, rows, expert_sorted))
-    ys = _grouped_dot(h, w_down, layer, rows, expert_sorted)         # [N*k, D]
-    # rows past the last group belong to no expert here: whatever the
-    # product left there is dropped, not multiplied by zero
-    ys = jnp.where((expert_sorted < E)[:, None],
-                   ys.astype(jnp.float32)
-                   * weight.reshape(N * k)[order][:, None], 0.0)
+    reaches = expert[:, None] == jnp.arange(E, dtype=jnp.int32)      # [N*k, E]
+    rows = jnp.sum(reaches, axis=0, dtype=jnp.int32)                 # [E]
+    # where the sorted pairs sit in the products' rows, and back
+    back = jnp.argsort(order)
+    tile = _plan(x, M, (w_gate, w_up, w_down), experts or E)
+    if tile is None:
+        lay, source, expert_of_row = None, order, expert[order]
+    else:
+        lay = pallas_grouped.layout(rows, M, tile)
+        source, expert_of_row = order[lay.source], lay.expert
+        # + the offset of the pair's expert (summed, not gathered)
+        back = back + jnp.sum(jnp.where(reaches, lay.offset[None, :], 0),
+                              axis=1)
+    xs = x[source // k]                                              # [rows, D]
+    h = (act(_grouped_dot(xs, w_gate, layer, rows, expert_of_row, lay))
+         * _grouped_dot(xs, w_up, layer, rows, expert_of_row, lay))
+    ys = _grouped_dot(h, w_down, layer, rows, expert_of_row, lay)    # [rows, D]
     # back to token order (the inverse permutation: a gather, not a
-    # scatter-add), then the k choices of a token are summed
-    return ys[jnp.argsort(order)].reshape(N, k, D).sum(axis=1)
+    # scatter-add). A pair that reaches no expert here has no row of its
+    # own: whatever it reads is dropped, not multiplied by zero. Then the
+    # k choices of a token are summed
+    mine = expert < E
+    ys = jnp.where(mine[:, None],
+                   ys[jnp.where(mine, back, 0)].astype(jnp.float32)
+                   * weight.reshape(M, 1), 0.0)
+    return ys.reshape(N, k, D).sum(axis=1)
 
 
 def _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act):
@@ -146,8 +237,9 @@ def _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act):
 
     mesh = get_active_mesh()
     e_ax = m_ax = None
+    experts = w_gate.shape[1]
     if mesh is not None:
-        e_ax = _axis(mesh, w_gate.shape[1], AXIS_EXPERT)
+        e_ax = _axis(mesh, experts, AXIS_EXPERT)
         m_ax = _axis(mesh, w_gate.shape[-1], AXIS_MODEL)
     if e_ax is None and m_ax is None:
         return _experts(x, expert, weight, w_gate, w_up, w_down, layer, act)
@@ -161,7 +253,7 @@ def _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act):
         first = 0 if e_ax is None else jax.lax.axis_index(e_ax) * mine
         here = (expert >= first) & (expert < first + mine)
         out = _experts(x, jnp.where(here, expert - first, mine), weight,
-                       w_gate, w_up, w_down, layer, act)
+                       w_gate, w_up, w_down, layer, act, experts)
         return jax.lax.psum(out.astype(x.dtype),
                             tuple(a for a in (e_ax, m_ax) if a is not None))
 

@@ -1119,7 +1119,9 @@ class OpenAIServer:
         times the next decode step: the lead, the device time each kind
         and shape of dispatch last took, the launches by rule, and under
         ``"experts"`` the rows each expert of each expert layer got in
-        the newest dispatch whose tokens were read.
+        the newest dispatch whose tokens were read, and ``"product"``:
+        which grouped product ops/moe.py last chose, as its
+        ``[attention] op=experts`` log line says it.
         ``?limit=N`` trims the first two to the most recent N."""
         limit = self._int_query(request, "limit", 0) or None
         snap = self.flight.snapshot(limit=limit)

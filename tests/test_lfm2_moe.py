@@ -256,6 +256,7 @@ def test_fused_windows_idle_rows_and_a_chunked_prompt(params32):
     assert eng.moe_stats["decode"]["routed_rows"] > 0
     assert eng.moe_stats["chunk"]["routed_rows"] > 0
     assert eng.moe_last["kind"] == "decode"
+    assert eng.moe_last["product"] == "xla (ragged_dot, cpu backend)"
 
 
 def test_a_slot_reused_by_a_shorter_request_starts_from_an_empty_state(
@@ -629,6 +630,197 @@ def test_int8_experts_against_a_per_token_loop_over_the_widened_stack():
             want[t] += a * ((g / (1 + np.exp(-g)) * (x[t] @ w_up[1][e]))
                             @ w_down[1][e])
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the Pallas grouped kernel (ops/pallas_grouped.py), interpreted on the CPU
+# ---------------------------------------------------------------------------
+
+def _loop(x, sel, weight, valid, w_gate, w_up, w_down):
+    """The expert layer one (token, choice) pair at a time, in numpy."""
+    want = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        for e, a in zip(sel[t], weight[t]):
+            if valid[t]:
+                g = x[t] @ w_gate[e]
+                want[t] += a * ((g / (1 + np.exp(-g)) * (x[t] @ w_up[e]))
+                                @ w_down[e])
+    return want
+
+
+def _kernel_case(case):
+    """(x, sel, weight, valid, stacks as given, the layer's float matrices,
+    layer) of one case of the grouped kernel's test."""
+    rng = np.random.default_rng(sorted(KERNEL_CASES).index(case))
+    N, D, F, E, k, n = 24, 16, 24, 6, 2, 3
+    if case == "a width in two column blocks of K in two":
+        D, F = 256, 1024
+    if case == "pairs no multiple of the tile":
+        N, k = 7, 3
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(N)])
+    valid = np.ones(N, bool)
+    if case == "idle and padding rows":
+        valid[::3] = False
+        valid[-5:] = False
+    if case == "no live row":
+        valid[:] = False
+    if case == "experts with no rows":
+        sel = np.where(sel % 2 == 1, sel - 1, sel)       # 1, 3, 5 get none
+        sel[:, 1] = (sel[:, 0] + 2) % E
+    if case == "an expert with more rows than a tile":
+        sel[:, 0], sel[:, 1] = 2, np.where(sel[:, 1] == 2, 3, sel[:, 1])
+    if case == "every pair to one expert":
+        sel[:] = 4                   # (a router never does: k distinct)
+    weight = rng.uniform(0.1, 1.0, size=(N, k)).astype(np.float32)
+    ws, plain = _stacks(rng, n, E, D, F, case == "int8 stacks")
+    return x, sel, weight, valid, ws, [w[1] for w in plain]
+
+
+KERNEL_CASES = {
+    "idle and padding rows": 16,
+    "no live row": 16,
+    "experts with no rows": 16,
+    "an expert with more rows than a tile": 16,    # 24 pairs on expert 2
+    "every pair to one expert": 16,
+    "pairs no multiple of the tile": 16,           # 21 pairs
+    "a width in two column blocks of K in two": 16,
+    "int8 stacks": 16,
+}
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["layer 1", "traced"])
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_grouped_kernel_against_a_per_token_loop_and_the_xla_path(
+        case, traced, monkeypatch):
+    """What ops/moe.py computes with the Pallas kernel under it, against a
+    loop over the pairs and against ``jax.lax.ragged_dot``, layer 1 of a
+    stack of three, as a number and as the index of a ``scan``."""
+    from llms_on_kubernetes_tpu.ops import attention, pallas_grouped
+
+    x, sel, weight, valid, ws, plain = _kernel_case(case)
+    if case == "a width in two column blocks of K in two":
+        monkeypatch.setattr(pallas_grouped, "BLOCK_BYTES", 128 * 512 * 4)
+        assert pallas_grouped.weight_block(256, 1024, 4) == (128, 512)
+        assert pallas_grouped.weight_block(1024, 256, 4) == (256, 256)
+    args = tuple(map(jnp.asarray, (x, sel, weight, valid)))
+
+    def layer(ws, i):
+        return moe.grouped_experts(*args[:3], *ws, valid=args[3], layer=i)
+
+    def run(ws):
+        if not traced:
+            return layer(ws, 1)
+        return jax.tree_util.tree_map(            # the run's scan: layer 1
+            lambda a: a[1], jax.lax.scan(
+                lambda c, i: (c, layer(ws, i)), 0, jnp.arange(3))[1])
+
+    got = {}
+    for impl in ("xla", "pallas"):
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", impl)
+        got[impl] = jax.jit(lambda ws: run(ws))(ws)    # traced anew
+        assert attention._chosen["experts"][0] == (
+            "pallas-interpret" if impl == "pallas" else "xla")
+    pairs = sel.size
+    assert attention._chosen["experts"][1] == (
+        f"{pairs} pairs over 6 experts, row tile {KERNEL_CASES[case]}")
+    want = _loop(x, sel, weight, valid, *plain)
+    for impl in got:
+        out, rows = got[impl]
+        np.testing.assert_allclose(
+            np.asarray(out), want, rtol=1e-5, err_msg=impl,
+            atol=3e-5 * max(1.0, float(np.abs(want).max())))
+        assert np.asarray(rows).tolist() == np.bincount(
+            sel[valid].reshape(-1), minlength=6).tolist()
+    assert not np.asarray(got["pallas"][0])[~valid].any()
+
+
+def test_a_rows_result_is_the_same_bits_whatever_shares_its_batch(
+        monkeypatch):
+    """Dropless by construction, the kernel too: a row alone in the batch,
+    among idle rows, among other rows that crowd its experts and push its
+    pairs into other tiles, reads the same bits."""
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    rng = np.random.default_rng(3)
+    N, D, F, E, k = 40, 128, 256, 8, 2
+    ws, _ = _stacks(rng, 2, E, D, F, False)
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(N)])
+    weight = rng.uniform(0.1, 1.0, size=(N, k)).astype(np.float32)
+    run = jax.jit(lambda x, sel, weight, valid: moe.grouped_experts(
+        x, sel, weight, *ws, valid=valid, layer=1)[0])
+
+    def row7(x, sel, valid):
+        return np.asarray(run(jnp.asarray(x), jnp.asarray(sel),
+                              jnp.asarray(weight), jnp.asarray(valid)))[7]
+
+    alone = np.zeros(N, bool)
+    alone[7] = True
+    want = row7(x, sel, np.ones(N, bool))
+    assert want.any()
+    assert np.array_equal(row7(x, sel, alone), want)
+    others = rng.normal(size=(N, D)).astype(np.float32)
+    others[7] = x[7]
+    crowd = np.tile(sel[7], (N, 1))           # everybody on row 7's experts
+    assert np.array_equal(row7(others, crowd, np.ones(N, bool)), want)
+    assert np.array_equal(row7(others[::-1].copy(), crowd, alone[::-1]),
+                          np.zeros(D, np.float32))     # row 7 is idle there
+
+
+def test_the_engine_on_the_grouped_kernel_is_held_to_the_reference(
+        params32, monkeypatch):
+    """The engine's own steps (prefill, a chunked prompt, fused K = 4
+    windows with idle rows) with the Pallas kernels interpreted under them:
+    the expert layers run the grouped kernel inside the runs' scan and the
+    window's loop, and every token is still the reference's."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    jax.clear_caches()      # the steps' traces are shared between engines
+    try:
+        eng = engine(params32)
+        reqs = [submit(eng, prompt(7, 21), 9), submit(eng, prompt(40, 22), 6)]
+        run(eng, reqs)
+    finally:
+        jax.clear_caches()
+    for r in reqs:
+        held_to_reference(params32, r)
+    assert attention._chosen["experts"][0] == "pallas-interpret"
+    # the pick of the step traced last, as the log's newest line has it
+    assert eng.moe_last["product"] == "pallas-interpret ({})".format(
+        attention._chosen["experts"][1])
+
+
+def test_the_group_aligned_layout_places_every_pair_once():
+    from llms_on_kubernetes_tpu.ops import pallas_grouped
+
+    rng = np.random.default_rng(0)
+    for pairs, E, tile in ((256, 64, 16), (21, 6, 16), (512, 8, 64), (5, 3, 8)):
+        expert = np.sort(rng.integers(0, E + 1, size=pairs))   # E: no expert
+        rows = np.bincount(expert, minlength=E + 1)[:E]
+        lay = jax.tree_util.tree_map(np.asarray, pallas_grouped.layout(
+            jnp.asarray(rows, jnp.int32), pairs, tile))
+        T = pallas_grouped.num_tiles(pairs, E, tile)
+        assert lay.tile_expert.shape == (T,) and lay.source.shape == (T * tile,)
+        assert lay.n_tiles[0] == sum(-(-r // tile) for r in rows) <= T
+        live = int(rows.sum())
+        spot = lay.offset[np.minimum(expert[:live], E - 1)] + np.arange(live)
+        assert len(set(spot.tolist())) == live            # no two share a row
+        assert (lay.source[spot] == np.arange(live)).all()
+        assert (lay.expert[spot] == expert[:live]).all()
+        assert (spot // tile < lay.n_tiles[0]).all()
+        # a tile is one expert's, and its block is the kernel's to fetch
+        assert (lay.tile_expert[spot // tile] == expert[:live]).all()
+        assert (lay.tile_expert < E).all()
+
+
+@pytest.mark.parametrize("pairs,experts,tile", [
+    (256, 64, 16), (512, 64, 16), (2048, 64, 32), (8192, 64, 128),
+    (64, 8, 16), (16384, 64, 128), (65536, 8, 128)])
+def test_the_row_tile_follows_the_mean_rows_an_expert(pairs, experts, tile):
+    from llms_on_kubernetes_tpu.ops import pallas_grouped
+
+    assert pallas_grouped.row_tile(pairs, experts) == tile
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ library, and every xdist worker imports this file).
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 # the dispatchers import ops/cp.py when they run; that module and the
@@ -220,34 +221,33 @@ def test_tensor_parallel_append_stays_on_each_chips_heads(topo, no_cache,
     assert mem.temp_size_in_bytes < shard // 8
 
 
-# mesh (expert x model) and the stacks' type -> an expert layer of eight
-# experts of 4096 x 14336 (two layers of an eight-layer stack, 32 rows)
+# mesh (expert x model), the stacks' type, (n, E, D, F, rows, top_k): an
+# expert layer of eight experts of 4096 x 14336 (two layers of an
+# eight-layer stack, 32 rows), and the lfm2-24b-a2b.long-answers cell's
+MIXTRAL = (8, 8, 4096, 14336, 32, 2)
 EXPERT_CASES = {
-    "one chip, int8": ((1, 1), "int8"),
-    "--ep 4, bfloat16": ((4, 1), "bfloat16"),
-    "--ep 4, int8": ((4, 1), "int8"),
-    "--tp 4, bfloat16": ((1, 4), "bfloat16"),
-    "--ep 2 --tp 2, int8": ((2, 2), "int8"),
+    "one chip, int8": ((1, 1), "int8", MIXTRAL),
+    "--ep 4, bfloat16": ((4, 1), "bfloat16", MIXTRAL),
+    "--ep 4, int8": ((4, 1), "int8", MIXTRAL),
+    "--tp 4, bfloat16": ((1, 4), "bfloat16", MIXTRAL),
+    "--ep 2 --tp 2, int8": ((2, 2), "int8", MIXTRAL),
+    "one chip, bfloat16, the cell": ((1, 1), "bfloat16",
+                                     (8, 64, 2048, 1536, 64, 4)),
 }
 
 
-@pytest.mark.parametrize("case", sorted(EXPERT_CASES))
-def test_expert_stacks_stay_where_they_are_and_as_they_are(topo, no_cache,
-                                                           case):
-    """The grouped product takes each chip's shard of the stacks in place:
-    no stack is gathered to a chip for the unpartitionable group axis, no
-    layer is copied out of its stack, no int8 expert is widened in memory;
-    the one exchange of a layer is the sum of its [rows, hidden] result."""
+def _expert_layers(topo, case):
+    """(two expert layers over stacked weights, their operands' shapes on
+    the case's mesh, the mesh)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from llms_on_kubernetes_tpu.ops import moe
     from llms_on_kubernetes_tpu.ops.quant import QTensor
     from llms_on_kubernetes_tpu.parallel.mesh import (
-        AXIS_EXPERT, AXIS_MODEL, make_mesh, set_active_mesh,
+        AXIS_EXPERT, AXIS_MODEL, make_mesh,
     )
 
-    (ep, tp), dtype = EXPERT_CASES[case]
-    n, E, D, F, N = 8, 8, 4096, 14336, 32
+    (ep, tp), dtype, (n, E, D, F, N, k) = EXPERT_CASES[case]
     mesh = make_mesh(expert=ep, model=tp, devices=list(topo.devices)[:ep * tp])
     e = AXIS_EXPERT if ep > 1 else None
     m = AXIS_MODEL if tp > 1 else None
@@ -269,19 +269,117 @@ def test_expert_stacks_stay_where_they_are_and_as_they_are(topo, no_cache,
     def two_layers(x, router, w_gate, w_up, w_down):
         for i in range(2):
             x = x + moe.moe_block(x, router[i], w_gate, w_up, w_down,
-                                  top_k=2, layer=i)[0]
+                                  top_k=k, layer=i)[0]
         return x
 
+    return two_layers, (sds((N, D), jnp.bfloat16),
+                        sds((n, D, E), jnp.bfloat16), *stacks), mesh
+
+
+def _grouped_calls(hlo):
+    """The lines of ``hlo`` that call the Pallas grouped kernel."""
+    return [line for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and "grouped_expert_matmul" in line]
+
+
+@pytest.mark.parametrize("case", sorted(EXPERT_CASES))
+def test_expert_stacks_stay_where_they_are_and_as_they_are(topo, no_cache,
+                                                           monkeypatch, case):
+    """The grouped product takes each chip's shard of the stacks in place:
+    no stack is gathered to a chip for the unpartitionable group axis, no
+    layer is copied out of its stack, no int8 expert is widened in memory;
+    the one exchange of a layer is the sum of its [rows, hidden] result.
+    At a handful of rows an expert the dispatcher says it took the Pallas
+    kernel, and the program holds it, three products a layer."""
+    from llms_on_kubernetes_tpu.ops import attention, pallas_grouped
+    from llms_on_kubernetes_tpu.parallel.mesh import set_active_mesh
+
+    (ep, tp), dtype, (n, E, D, F, N, k) = EXPERT_CASES[case]
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    two_layers, args, mesh = _expert_layers(topo, case)
     set_active_mesh(mesh)
     try:
-        compiled = jax.jit(two_layers).lower(
-            sds((N, D), jnp.bfloat16), sds((n, D, E), jnp.bfloat16),
-            *stacks).compile()
+        compiled = jax.jit(two_layers).lower(*args).compile()
     finally:
         set_active_mesh(None)
+    assert attention._chosen["experts"] == (
+        "pallas-compiled", f"{N * k} pairs over {E} experts"
+        + (f" ({E // ep} on this shard)" if ep > 1 else "") + ", row tile 16")
     hlo = compiled.as_text()
-    assert "ragged-dot" in hlo and "all-gather" not in hlo
+    assert len(_grouped_calls(hlo)) == 6 and "ragged-dot" not in hlo
+    assert "all-gather" not in hlo
     assert hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(") == (
         2 if ep * tp > 1 else 0)
     one_expert = D * F * (1 if dtype == "int8" else 2) // tp
-    assert compiled.memory_analysis().temp_size_in_bytes < one_expert // 4
+    # ... or, where an expert is smaller than the rows' own buffers (the
+    # cell: 1,216 group-aligned rows of 2048 and of 1536, in and out),
+    # under those: a layer of the stack is 1.2 GB there
+    tiles = pallas_grouped.num_tiles(N * k, E // ep, 16)
+    rows = 2 * tiles * 16 * (D + F // tp) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < max(
+        one_expert // 4, rows)
+
+
+def test_past_the_rows_an_expert_it_was_measured_at_ragged_dot_stays(
+        topo, no_cache, monkeypatch):
+    """A prefill of 32,768 rows over eight experts (8,192 pairs an expert
+    at the mean, twice what the kernel was timed at) stays on
+    ``jax.lax.ragged_dot``, and the dispatcher says why."""
+    from llms_on_kubernetes_tpu.ops import attention, moe
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setitem(EXPERT_CASES, "a prefill",
+                        ((1, 1), "bfloat16", (2, 8, 1024, 512, 32768, 2)))
+    two_layers, args, _ = _expert_layers(topo, "a prefill")
+    hlo = jax.jit(two_layers).lower(*args).compile().as_text()
+    assert attention._chosen["experts"] == (
+        "xla", "ragged_dot, 65536 pairs over 8 experts: 8192 rows an expert, "
+        "not measured")
+    assert "ragged-dot" in hlo and not _grouped_calls(hlo)
+    assert 65536 > moe.KERNEL_MAX_MEAN_ROWS * 8
+
+
+def test_grouped_kernel_is_given_the_vmem_the_dispatcher_counted(monkeypatch):
+    """At the cell's decode shape ``grouped_vmem_bytes``, which ops/moe.py
+    holds against the budget, is what each of the three products hands
+    Mosaic as its limit, and is the pipeline's two buffers of every block
+    plus the float32 product and the headroom."""
+    from llms_on_kubernetes_tpu.ops import attention, moe, pallas_grouped
+
+    n, E, D, F, N, k = 8, 64, 2048, 1536, 64, 4
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    bf16 = jnp.bfloat16
+    jaxpr = jax.make_jaxpr(
+        lambda x, sel, a, g, u, d: moe.grouped_experts(
+            x, sel, a, g, u, d, layer=3))(
+        jax.ShapeDtypeStruct((N, D), bf16),
+        jax.ShapeDtypeStruct((N, k), jnp.int32),
+        jax.ShapeDtypeStruct((N, k), jnp.float32),
+        jax.ShapeDtypeStruct((n, E, D, F), bf16),
+        jax.ShapeDtypeStruct((n, E, D, F), bf16),
+        jax.ShapeDtypeStruct((n, E, F, D), bf16)).jaxpr
+    calls = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr)
+    assert len(calls) == 3
+    for eqn, (K, width) in zip(calls, ((D, F), (D, F), (F, D))):
+        mapping = eqn.params["grid_mapping"]
+        blocks = sum(
+            2 * np.prod([getattr(d, "block_size", 1) for d in bm.block_shape])
+            * bm.array_aval.dtype.itemsize
+            for bm in mapping.block_mappings)
+        tk, tn = pallas_grouped.weight_block(K, width, 2)
+        assert tk == K and tk * tn * 2 >= 2 << 20     # an expert is read once
+        counted = pallas_grouped.grouped_vmem_bytes(16, K, width, 2, 2)
+        assert counted == (blocks + 16 * tn * 4
+                           + pallas_grouped._VMEM_HEADROOM)
+        limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        assert limit == counted <= attention.VMEM_BUDGET_BYTES

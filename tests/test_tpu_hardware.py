@@ -356,6 +356,92 @@ def test_cell_kernel_time_fetch_attend_both(monkeypatch):
     _report("pr37_kernel_split", said)
 
 
+# pairs a layer sorts at 64 experts top-4: the lfm2-24b-a2b.long-answers
+# cell's decode step (42 of 64 slots live) and its prefill shapes (1 x 128,
+# 1 x 512 / 4 x 128, 4 x 512), and two larger, around the rule's threshold
+GROUPED_SHAPES = [(64, 42), (128, 128), (512, 512), (2048, 2048),
+                  (4096, 4096), (8192, 8192)]
+
+
+def _expert_layer_case(tokens, live, E, k, D, dtype=jnp.bfloat16):
+    rng = np.random.default_rng(tokens)
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(tokens)])
+    valid = np.zeros(tokens, bool)
+    valid[rng.permutation(tokens)[:live]] = True
+    x = jax.random.normal(jax.random.key(tokens), (tokens, D), dtype)
+    return (x, jnp.asarray(sel, jnp.int32),
+            jnp.full((tokens, k), 1.0 / k, jnp.float32), jnp.asarray(valid))
+
+
+def _expert_stack(seed, n, E, K, N, int8=False):
+    """[n, E, K, N] drawn a layer at a time, as the server's are."""
+    from llms_on_kubernetes_tpu.ops.quant import quantize
+
+    w = jax.jit(lambda keys: jax.lax.map(
+        lambda key: (jax.random.normal(key, (E, K, N), jnp.bfloat16)
+                     * K ** -0.5).astype(jnp.bfloat16), keys))(
+        jax.random.split(jax.random.key(seed), n))
+    return quantize(w, reduce_axes=(2,)) if int8 else w
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bfloat16", "int8"])
+def test_cell_grouped_product_against_ragged_dot(monkeypatch, int8):
+    """An expert layer of the lfm2-24b-a2b cell (64 experts of 2048 x 1536,
+    top-4, layers of an 8-layer stack by a scanned index) through
+    ops/moe.py with the Pallas grouped kernel and with ``ragged_dot``
+    under it, shape by shape: the time of a layer either way (what
+    ``moe.KERNEL_MAX_MEAN_ROWS`` was set from: PERF.md, section 6, PR 39),
+    and the largest difference between the two results of one layer in
+    units of the result's bfloat16 spacing."""
+    import time
+
+    from llms_on_kubernetes_tpu.ops import attention, moe
+
+    n, E, D, F, k = (2 if int8 else 8), 64, 2048, 1536, 4
+    stacks = (_expert_stack(1, n, E, D, F, int8),
+              _expert_stack(2, n, E, D, F, int8),
+              _expert_stack(3, n, E, F, D, int8))
+    monkeypatch.setattr(moe, "KERNEL_MAX_MEAN_ROWS", 1 << 20)  # time both
+
+    def layers(x, sel, weight, valid, stacks):
+        def body(c, i):
+            out, _ = moe.grouped_experts(c, sel, weight, *stacks,
+                                         valid=valid, layer=i)
+            return (c + out * 0.01).astype(c.dtype), out
+        return jax.lax.scan(body, x, jnp.arange(n))[1]
+
+    said = {}
+    for tokens, live in GROUPED_SHAPES[:3 if int8 else None]:
+        args = _expert_layer_case(tokens, live, E, k, D)
+        got = {}
+        for impl, mode in (("ragged_dot", None), ("kernel", "compiled")):
+            monkeypatch.setattr(attention, "pallas_mode", lambda m=mode: m)
+            run = jax.jit(lambda *a: layers(*a))        # traced anew
+            best = float("inf")
+            for _ in range(6):                          # the first compiles
+                t0 = time.perf_counter()
+                out = jax.block_until_ready(run(*args, stacks))
+                best = min(best, time.perf_counter() - t0)
+            got[impl] = (best / n * 1e6,
+                         np.asarray(out[0].astype(jnp.float32)),
+                         attention._chosen["experts"])
+        a, b = got["kernel"][1], got["ragged_dot"][1]
+        spacing = 2.0 ** (np.floor(np.log2(np.maximum(
+            np.maximum(np.abs(a), np.abs(b)), 1e-30))) - 7)
+        assert got["kernel"][2][0] == "pallas-compiled"
+        assert got["ragged_dot"][2][0] == "xla"
+        assert not a[~np.asarray(args[3])].any() and np.abs(a).max() > 0
+        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2)
+        said[f"{tokens * k} pairs, {live} of {tokens} rows live"] = {
+            "ragged_dot_layer_us": round(got["ragged_dot"][0], 1),
+            "kernel_layer_us": round(got["kernel"][0], 1),
+            "kernel": got["kernel"][2][1],
+            "largest_difference_bf16_spacings": float(
+                (np.abs(a - b) / spacing).max()),
+            "elements_that_differ": float((a != b).mean())}
+    _report(f"pr39_grouped_{'int8' if int8 else 'bfloat16'}", said)
+
+
 def test_smoke_shape_fused_write_int8():
     from llms_on_kubernetes_tpu.ops.pallas_paged import (
         pallas_paged_attention_write_int8,
