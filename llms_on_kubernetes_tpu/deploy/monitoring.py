@@ -723,6 +723,15 @@ def grafana_dashboard() -> dict[str, Any]:
                ["llm_conv_state_bytes",
                 "sum by (why) "
                 "(rate(llm_prefix_reuse_skipped_total[5m]))"], 12, 144),
+        _panel(39, "Decode hand-over lag: a window complete on the device "
+               "to its tokens on the requests' queues, seconds per window "
+               "(every token of a window pays it; a request's own is span "
+               "decode.emit)",
+               ["sum by (kind) "
+                "(rate(llm_decode_emit_seconds_total[5m])) / "
+                "sum by (kind) (rate(llm_dispatches_total"
+                "{kind=~\"decode|spec\"}[5m]))"],
+               0, 152, unit="s"),
     ]
     return {
         "title": "LLM serving on TPU — cluster overview",

@@ -527,7 +527,10 @@ class Request:
     abort_reason: Optional[str] = None  # set by any thread; reaped by step()
     admitted_at: Optional[float] = None  # prefill dispatched (TTFT breakdown)
     first_token_at: Optional[float] = None
-    finished_at: Optional[float] = None  # terminal event recorded (_finish)
+    finished_at: Optional[float] = None  # slot released (_finish)
+    # the event that finishes the request was put on its queue
+    # (_hand_over): where its ``decode`` span ends and ``stream`` starts
+    last_token_at: Optional[float] = None
     # from the dispatch record of the prefill that produced the first
     # token (engine/ledger.py writes each once): the call that enqueued
     # it returned, the device was free for it, its read landed. They
@@ -535,6 +538,11 @@ class Request:
     prefill_launched_at: Optional[float] = None
     prefill_started_at: Optional[float] = None
     prefill_read_at: Optional[float] = None
+    # seqs of the booked dispatches in which the request consumed a decode
+    # token, oldest first (the ledger appends one as it books such a
+    # dispatch): what GoodputLedger.decode_account splits first_token_at..
+    # last_token_at by; empty without ledger
+    ride_seqs: list = dataclasses.field(default_factory=list)
     # server-side trace sink (duck-typed: anything with .event(name, **kv));
     # the API layer points this at the request's Trace so engine-side
     # preemption/deadline/stall land on the distributed timeline. None for
@@ -1656,6 +1664,15 @@ class Engine:
         # first tokens handed to their requests, by who did it (_hand_over);
         # the serving loop drains it into llm_first_tokens_total{delivered}
         self.first_tokens_handed = {"backpressure": 0, "step": 0}
+        # seconds from a decode window's completion on the device to the
+        # hand-over of its events at the end of the step() that collected
+        # it, summed by the window's kind (llm_decode_emit_seconds_total
+        # {kind}; per window: over llm_dispatches_total of that kind).
+        # _collected: (kind, completion) of the windows collected since
+        # the last hand-over, which ONE reading of the clock prices; kept
+        # with the ledger alone, whose drain is the counter's only reader
+        self.decode_emit_s = {"decode": 0.0, "spec": 0.0}
+        self._collected: list[tuple[str, float]] = []
         self._harvester: Optional[_Harvester] = None
         if self._async:
             self._harvester = _Harvester()
@@ -2159,23 +2176,43 @@ class Engine:
         with _phase("llmk.emit"):
             for ev in events:
                 self._hand_over(ev)
+            self._book_emit()
         if not self.has_work():
             self._saw_no_work = True
         return events
+
+    def _book_emit(self) -> None:
+        """The windows collected since the last call have just had their
+        events handed over: book how long each waited for that since it
+        was complete on the device (span ``decode.emit`` is the same lag
+        for a request's last window, ``llmk.emit`` the phase's own time on
+        the profiler's clock)."""
+        if not self._collected:
+            return
+        now = self._clock()
+        for kind, done in self._collected:
+            self.decode_emit_s[kind] += max(0.0, now - done)
+        self._collected.clear()
 
     def _hand_over(self, ev: StepEvent, where: str = "step") -> None:
         """Put an event on its request's queue, once. A first token's
         ``first_token_at`` is stamped HERE, where it leaves the engine,
         not where its read was collected: span ``prefill.emit`` ends when
-        the client can have the token. ``where`` says who handed a first
+        the client can have the token; and so is ``last_token_at``, with
+        the event that finishes the request (span ``decode`` ends there,
+        ``decode.emit`` with it). ``where`` says who handed a first
         token over: the backpressure wait of ``_harvest`` ("backpressure")
         or the end of a ``step()`` ("step")."""
         if ev.handed_over:
             return
         ev.handed_over = True
-        if ev.first:
-            ev.request.first_token_at = time.monotonic()
-            self.first_tokens_handed[where] += 1
+        if ev.first or ev.finished:
+            now = self._clock()
+            if ev.first:
+                ev.request.first_token_at = now
+                self.first_tokens_handed[where] += 1
+            if ev.finished:
+                ev.request.last_token_at = now
         payload = (ev.new_tokens, ev.finished, ev.finish_reason)
         ev.request.events.put(payload)
         if ev.request.on_event is not None:
@@ -3218,8 +3255,11 @@ class Engine:
         host = HostSample(arr[0])
         self._device_time_s += time.perf_counter() - t0
         self._book_moe("decode", arr, len(self.slots))
-        self.timeline.close(dseq, self._clock(),
+        t_read = self._clock()
+        self.timeline.close(dseq, t_read,
                             [(r, "decode", 1) for _i, r in active])
+        if self.ledger is not None:
+            self._collected.append(("decode", t_read))
 
         events: list[StepEvent] = []
         for i, r in active:
@@ -3937,9 +3977,12 @@ class Engine:
                     # accepted drafts = consumed tokens minus the one the
                     # plain path would have produced anyway
                     spec_accepted += max(0, consumed - 1)
-            self.timeline.close(
-                step.dseq, self._harvester.done_time(step.seq),
-                led_rows, window=arr.shape[0])
+            t_done = self._harvester.done_time(step.seq)
+            self.timeline.close(step.dseq, t_done, led_rows,
+                                window=arr.shape[0])
+            if self.ledger is not None:
+                self._collected.append(
+                    ("spec" if step.spec else "decode", t_done))
             self.decode_dispatches += 1
             self.decode_tokens += consumed_total
             self.early_exit_steps += wasted
@@ -3963,6 +4006,7 @@ class Engine:
         events = self._harvest(drain=True)
         for ev in events:
             self._hand_over(ev)
+        self._book_emit()
         return events
 
     # ------------------------------------------------------------------

@@ -32,6 +32,13 @@ tails to ``spec_waste``, and masked/abandoned rows to ``early_exit`` —
 waste is still booked against the request and tenant that caused it,
 but never counted as useful stream time.
 
+The same segments answer a second question, latency, not billing: where
+a request's time per output token went. A stream waits for the WHOLE of
+every window it rides, for the prefills the device runs between its
+windows, and for the engine thread to hand its tokens over
+(:meth:`GoodputLedger.decode_account`, published on the request's
+``decode`` span; the engine thread pays one append a row for it).
+
 FLOPs/bytes ride the same records (2 * active-params per token for
 compute; weight + KV-page traffic for memory), giving the ``llm_mfu_
 ratio`` / ``llm_mbu_ratio`` gauges (Chowdhery et al., PaLM 2022). A CPU
@@ -288,6 +295,10 @@ class DispatchTimeline:
         # seconds the record booked last ran over its shape's estimate
         self._prev_excess = 0.0
         self.lost = 0       # dropped unbooked: their reads never came
+        # the newest launch (t_call) among the records dropped unbooked:
+        # a request whose prefill was launched before it may have ridden
+        # one, so its decode account is not made up (decode_account)
+        self._lost_after = float("-inf")
         self._open.clear()
 
     # -- recording (engine thread) -------------------------------------
@@ -307,7 +318,7 @@ class DispatchTimeline:
             # dropped; its time falls to the next segment, or to idle
             while (len(self._open) >= MAX_OPEN
                    and not self._open[0].closed):
-                self._open.popleft()
+                self._dropped(self._open.popleft())
                 self.lost += 1
                 self._book_ready()
             self._open.append(rec)
@@ -376,11 +387,18 @@ class DispatchTimeline:
         launch raised (``seq``), or all of them (a wedged device)."""
         with self._lock:
             if seq is None:
+                for rec in self._open:
+                    self._dropped(rec)
                 self._open.clear()
             else:
                 self._open = collections.deque(
                     r for r in self._open if r.seq != seq)
                 self._book_ready()
+
+    def _dropped(self, rec: Dispatch) -> None:
+        """``rec`` leaves unbooked though it may have run (a launch that
+        raised never did: abandon(seq) does not come here)."""
+        self._lost_after = max(self._lost_after, rec.t_call)
 
     def _book(self, rec: Dispatch, t_done: float) -> float:
         """Segment ``rec``: when the device was free for it, how long it
@@ -482,8 +500,9 @@ class GoodputLedger(DispatchTimeline):
     All mutation happens on the engine thread via :meth:`open` /
     :meth:`launched` / :meth:`close`; readers (the serving loop's metrics
     drain, bench, /metrics callbacks, /debug/engine) take the same lock
-    through :meth:`snapshot` / :meth:`utilization` / :meth:`dispatches`,
-    so a scrape never sees a half-applied record.
+    through :meth:`snapshot` / :meth:`utilization` /
+    :meth:`dispatches_view` / :meth:`decode_account`, so a scrape never
+    sees a half-applied record.
     """
 
     def __init__(self, model_config: Any,
@@ -573,6 +592,12 @@ class GoodputLedger(DispatchTimeline):
                 req.chip_ms[phase] = req.chip_ms.get(phase, 0.0) + share_ms
             if phase == "decode":
                 self.decode_tokens += w
+                # it consumed a token of this dispatch: the seq is what
+                # decode_account finds the record by (once a dispatch: a
+                # row has one "decode" entry)
+                rides = getattr(req, "ride_seqs", None)
+                if rides is not None:
+                    rides.append(rec.seq)
             elif phase == "prefill":
                 self.prefill_tokens += w
                 if (req is not None and getattr(
@@ -623,6 +648,64 @@ class GoodputLedger(DispatchTimeline):
             mbu = sum(r.hbm_bytes for r in ent) / (
                 self.peak_bytes_s * elapsed)
             return min(mfu, 1.0), min(mbu, 1.0)
+
+    def decode_account(self, req: Any, t0: float, t1: float
+                       ) -> Optional[dict]:
+        """Where a request's token gap went between its first token's
+        hand-over ``t0`` and its last one's ``t1``, in seconds, from the
+        booked records (any thread; where a trace is finalized, never the
+        engine thread: that one pays an append a row in :meth:`_book`).
+
+        ``done`` is the booked completion of the last dispatch in which
+        the request consumed a token, clipped into ``[t0, t1]``; what
+        follows it is the hand-over of its last window (span
+        ``decode.emit``). ``[t0, done]`` is tiled by the device's
+        segments and idle gaps, each clipped to it: ``ride`` the WHOLE
+        segments of the decode and spec dispatches it consumed a token
+        of (once each, not its share by rows), ``prefill`` the segments
+        of prefill and chunk dispatches (other requests' first tokens
+        and, after a preemption, its own re-prefill), ``other`` the rest
+        (decode windows it did not ride, idle gaps), ``idle`` the idle
+        part of ``other``. None, and nothing guessed, where the ring no
+        longer reaches back to ``t0`` or a record launched since the
+        request's prefill was dropped unbooked. A window consumed and
+        not booked by now (an older launch still unread) is not in
+        ``done``: its time reads as hand-over."""
+        rides = getattr(req, "ride_seqs", None)
+        launched = getattr(req, "prefill_launched_at", None)
+        if rides is None or launched is None:
+            return None
+        with self._lock:
+            if self._lost_after >= launched:
+                return None
+            recs = list(self._records)
+        if not recs or recs[0].seg_start > t0:
+            return None
+        # newest first, back to the record the device finished before t0
+        # (seg_start never falls and no t_done lies past a later one's)
+        window = []
+        for rec in reversed(recs):
+            if max(rec.seg_start, rec.t_done) <= t0:
+                break
+            window.append(rec)
+        riding = set(rides)
+        done = max((r.t_done for r in window if r.seq in riding),
+                   default=t0)
+        done = min(max(done, t0), t1)
+        ride = prefill = idle = 0.0
+        for rec in window:
+            idle += max(0.0, min(rec.seg_start, done) - max(
+                rec.seg_start - rec.idle_before_ms / 1000.0, t0))
+            dur = min(rec.t_done, done) - max(rec.seg_start, t0)
+            if dur <= 0.0:
+                continue
+            if rec.kind in ("prefill", "chunk"):
+                prefill += dur
+            elif rec.seq in riding:
+                ride += dur
+        other = max(0.0, (done - t0) - ride - prefill)
+        return {"done": done, "ride": ride, "prefill": prefill,
+                "other": other, "idle": min(idle, other)}
 
     def dispatches_view(self, limit: int = 64) -> list[dict]:
         """The newest ``limit`` booked records, oldest first."""

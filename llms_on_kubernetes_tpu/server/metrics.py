@@ -328,10 +328,6 @@ def engine_metrics(registry: Registry) -> dict:
             "Request latency, submit to finish",
             (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0),
             registry, label_names=("model",)),
-        "decode_step": Histogram(
-            "llm_decode_step_seconds", "Per-decode-step latency",
-            (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5), registry,
-            label_names=("model",)),
         "batch_occupancy": Gauge(
             "llm_decode_batch_occupancy", "Active decode slots", registry),
         "kv_pages_used": Gauge(
@@ -470,7 +466,8 @@ def engine_metrics(registry: Registry) -> dict:
             "this vs bf16)", registry),
         "mfu": Gauge(
             "llm_mfu_ratio",
-            "Model FLOPs utilization over the trailing minute: achieved "
+            "Model FLOPs utilization over the trailing minute of "
+            "dispatches, computed when /metrics is scraped: achieved "
             "FLOP/s (2 * active params per planned token, wasted rows "
             "included) over the accelerator's nominal dense peak "
             "(PaLM-style MFU; never set on a CPU, which has no peak)",
@@ -511,6 +508,15 @@ def engine_metrics(registry: Registry) -> dict:
             "llm_dispatch_enqueue_seconds_total",
             "Host seconds inside the jitted calls that launched "
             "dispatches of each kind (a re-trace shows here)",
+            registry, label_names=("kind",)),
+        "decode_emit_seconds": Counter(
+            "llm_decode_emit_seconds_total",
+            "Seconds decode windows of each kind (decode, spec) waited, "
+            "complete on the device, for their tokens to be put on the "
+            "requests' queues at the end of the scheduler step that "
+            "collected them (the engine thread waking, collecting, the "
+            "rest of the step); over llm_dispatches_total of the kind it "
+            "is the hand-over lag a window, which every token of it pays",
             registry, label_names=("kind",)),
         "device_idle_seconds": Counter(
             "llm_device_idle_seconds_total",
@@ -616,6 +622,8 @@ def engine_metrics(registry: Registry) -> dict:
         for series in ("dispatches", "dispatch_device_seconds",
                        "dispatch_behind_seconds", "dispatch_enqueue_seconds"):
             m[series].labels(kind=kind)
+    for kind in ("decode", "spec"):
+        m["decode_emit_seconds"].labels(kind=kind)
     for host in IDLE_HOSTS:
         m["device_idle_seconds"].labels(host=host)
     return m

@@ -225,6 +225,7 @@ class EngineLoop(threading.Thread):
         self._led_tenant_seen: dict[tuple, float] = {}
         self._led_dispatches_seen = 0
         self._led_kind_seen: dict[tuple, float] = {}
+        self._led_emit_seen = {"decode": 0.0, "spec": 0.0}
         self._led_idle_seen: dict[str, float] = {}
         self._led_frame_seen = (0.0, 0.0)
         self.auto_profiles = 0
@@ -296,10 +297,8 @@ class EngineLoop(threading.Thread):
                 if ev.finished and ev.finish_reason in ("timeout", "stalled"))
             led = getattr(eng, "ledger", None)
             led_snap = led.snapshot() if led is not None else None
-            led_util = led.utilization() if led is not None else None
             if self.metrics:
                 m = self.metrics
-                m["decode_step"].labels(model=self.model_name).observe(dt)
                 if eng.preemptions > self._preempt_seen:
                     m["preemptions"].inc(eng.preemptions - self._preempt_seen)
                     self._preempt_seen = eng.preemptions
@@ -407,9 +406,6 @@ class EngineLoop(threading.Thread):
                                     (ms - seen) / 1000.0)
                             self._led_tenant_seen[key] = ms
                     self._drain_dispatch_totals(led_snap)
-                    if led_util is not None:
-                        m["mfu"].set(led_util[0])
-                        m["mbu"].set(led_util[1])
                 m["batch_occupancy"].set(occupancy)
                 m["kv_pages_used"].set(pages_used)
                 m["kv_pages_live"].set(eng.allocator.num_live_pages)
@@ -469,8 +465,6 @@ class EngineLoop(threading.Thread):
                         chip_attr_ms=round(attr - pa, 3),
                         chip_waste_ms=round(waste - pw, 3),
                     )
-                    if led_util is not None:
-                        frame["mfu"] = round(led_util[0], 5)
                 self.flight.record(**frame)
             if led is not None and led.take_anomaly():
                 self._trigger_auto_profile()
@@ -484,11 +478,17 @@ class EngineLoop(threading.Thread):
     def _drain_dispatch_totals(self, led_snap: dict) -> None:
         """The dispatch records' own totals into their counters: by kind
         of step, and by what the host was doing in each device gap.
-        They move only when a dispatch is booked."""
+        They move only when a dispatch is booked; the decode windows'
+        hand-over lag (Engine.decode_emit_s) when one was collected."""
+        m = self.metrics
+        for kind, s in getattr(self.engine, "decode_emit_s", {}).items():
+            if s > self._led_emit_seen[kind]:
+                m["decode_emit_seconds"].labels(kind=kind).inc(
+                    s - self._led_emit_seen[kind])
+                self._led_emit_seen[kind] = s
         if led_snap["dispatches"] == self._led_dispatches_seen:
             return
         self._led_dispatches_seen = led_snap["dispatches"]
-        m = self.metrics
         for kind, tot in led_snap["kinds"].items():
             for field, series, per_s in self._DISPATCH_SERIES:
                 seen = self._led_kind_seen.get((kind, field), 0.0)
@@ -1400,6 +1400,13 @@ class OpenAIServer:
             self.STATE_CODES.get(self.state, 0))
         # scrape-time freshness for device memory / live buffers
         self.telemetry.refresh()
+        # MFU/MBU over the trailing minute of dispatches: a walk of the
+        # ledger's ring, made for the reader and not after every step()
+        led = getattr(self.engine, "ledger", None)
+        util = led.utilization() if led is not None else None
+        if util is not None:
+            self.metrics["mfu"].set(util[0])
+            self.metrics["mbu"].set(util[1])
         return web.Response(
             text=self.registry.render(),
             content_type="text/plain", charset="utf-8",
@@ -1830,10 +1837,15 @@ class OpenAIServer:
 
     def _finalize_trace(self, trace, status: str, resp) -> None:
         """Derive the request's span timeline from the engine Request
-        timestamps (single writer each: submit/admit/first-token/finish)
-        and publish it. The phases are disjoint by construction, so their
-        durations sum to at most the end-to-end latency; the ``prefill``
-        phase has four children (:meth:`_split_first_token`)."""
+        timestamps (single writer each: submit/admit/first-token/last
+        hand-over) and publish it. The phases are disjoint by
+        construction, so their durations sum to at most the end-to-end
+        latency; the ``prefill`` phase has four children
+        (:meth:`_split_first_token`), the ``decode`` phase its parts as
+        attributes and one child (:meth:`_split_decode`). ``decode`` ends
+        and ``stream`` starts where the event that finishes the request
+        left the engine (``last_token_at``; ``finished_at``, the slot's
+        release, where there was no such hand-over)."""
         now = time.monotonic()
         many = len(trace.engine_reqs) > 1
 
@@ -1850,7 +1862,9 @@ class OpenAIServer:
             sub = req.submitted_at
             adm = req.admitted_at
             ft = req.first_token_at
-            fin = req.finished_at
+            fin = req.last_token_at
+            if fin is None:
+                fin = req.finished_at
             fin = now if fin is None else min(fin, now)
             eng_span("admission", trace.t0, sub, **meta)
             eng_span("queue", sub, adm if adm is not None else fin,
@@ -1876,7 +1890,8 @@ class OpenAIServer:
                              + req.chip_ms.get("early_exit", 0.0))
                     if waste:
                         dec_kw["chip_waste_ms"] = round(waste, 3)
-                eng_span("decode", ft, fin, **dec_kw)
+                self._split_decode(trace, req, ft, max(fin, ft), dec_kw,
+                                   meta)
             if fin < now:
                 # engine finished before the response flushed: the tail is
                 # stream/serialization time on the API side
@@ -1920,6 +1935,31 @@ class OpenAIServer:
                                  ("prefill.emit", read, ft)):
             trace.add_span(name, start, end, span_id=tracing.new_span_id(),
                            parent_span_id=parent_id, **meta)
+
+    def _split_decode(self, trace, req, ft: float, end: float,
+                      dec_kw: dict, meta: dict) -> None:
+        """The ``decode`` span, ``first_token_at`` to the last hand-over,
+        with where the request's token gap went as attributes in ms
+        (GoodputLedger.decode_account: ``ride_ms`` the whole decode
+        windows it consumed a token of, ``prefill_ms`` the prefill and
+        chunk dispatches run between them, ``other_ms`` the rest up to
+        its last window's completion on the device, ``idle_ms`` the idle
+        part of that) and one child, ``decode.emit``: from that completion
+        to the hand-over. The three and the child are disjoint and sum to
+        the span. No attribute and no child without a ledger, or where
+        its records no longer tell (none is guessed)."""
+        led = getattr(self.engine, "ledger", None)
+        acct = led.decode_account(req, ft, end) if led is not None else None
+        dec_id = tracing.new_span_id()
+        if acct is not None:
+            dec_kw.update((part + "_ms", round(acct[part] * 1000.0, 3))
+                          for part in ("ride", "prefill", "other", "idle"))
+        trace.add_span("decode", ft, end, span_id=dec_id,
+                       parent_span_id=trace.span_id, **dec_kw)
+        if acct is not None:
+            trace.add_span("decode.emit", acct["done"], end,
+                           span_id=tracing.new_span_id(),
+                           parent_span_id=dec_id, **meta)
 
     def _export_trace(self, trace) -> None:
         """Tail-sampling + OTLP enqueue for a finished fragment; never
