@@ -12,6 +12,18 @@ SURVEY §2.3); this is the TPU-native equivalent. Design:
   decoder adds ``l*P`` to the (per-layer-local) page table inside the
   layer body. n_kv is the sharded axis (mesh "model") so each TP shard
   holds its own heads' pages — the pool never crosses chips.
+- A page row is 128 lanes wherever that costs no byte: heads of 64 are
+  stored TWO TO A ROW (``heads_per_row``), heads 2h and 2h+1 side by
+  side, so the pool is [n_kv/2, L * P, page, 128] with
+  ``stored[h, p, t, j*64 + c] = logical[2*h + j, p, t, c]``. The bytes a
+  token takes are the same; the page DMA of the Pallas decode kernels,
+  which Mosaic compiles only for whole 128-lane rows, then serves such a
+  model with the kernel body it runs at 128 (ops/pallas_paged.py). Every
+  reader takes the layout from the pool it is handed: ``write_tokens``
+  reshapes the new rows to the pool's row (heads are adjacent, so that is
+  free), ``ops/attention._gather_pool`` un-pairs after its gather, and a
+  page's payload (host tier, prefill/decode hand-over) is the pool's bytes
+  in the pool's shape (``CacheConfig.pool_row``).
 
   Why flat instead of a leading [L, ...] axis: the layer loop is
   ``lax.scan``, and a pool that rides the scan as xs/ys gets its updated
@@ -59,10 +71,23 @@ class CacheConfig:
     # token) stored beside the data) — halves decode-attention HBM traffic
     # and doubles token capacity per chip. None => KV stored in `dtype`.
     kv_dtype: "Optional[str]" = None
+    # size of the mesh's ``model`` axis, over which the pool's head axis is
+    # sharded (parallel/sharding.pool_sharding): heads pair into one row
+    # only where every shard keeps whole pairs
+    model_shards: int = 1
 
     @property
     def max_seq_len(self) -> int:
         return self.pages_per_slot * self.page_size
+
+    @property
+    def pool_row(self) -> tuple[int, int]:
+        """(rows of heads, lanes a row) of the pools as stored: the first
+        and the last axis of ``init_pages``' shape and of a page's payload
+        (module docstring: two 64-wide heads share a 128-lane row)."""
+        n, _ = heads_per_row(self.num_kv_heads, self.head_dim, self.kv_dtype,
+                             self.model_shards)
+        return self.num_kv_heads // n, self.head_dim * n
 
     @property
     def bytes_per_page(self) -> int:
@@ -82,10 +107,32 @@ class CacheConfig:
         return self.bytes_per_page // self.page_size
 
 
+def heads_per_row(num_kv_heads: int, head_dim: int,
+                  kv_dtype: "Optional[str]" = None,
+                  model_shards: int = 1) -> tuple[int, str]:
+    """(heads a page row holds, why a 64-wide pool keeps one): two where
+    head_dim is 64, so a row is the 128 lanes the decode kernels' page DMA
+    needs. One, with the reason for the dispatcher's record, for an int8
+    pool (its scales are one a head and token, which the kernels apply to
+    a whole row of logits), an odd number of heads, and a model axis that
+    would split a pair; one, with no reason, at every other width."""
+    if head_dim != 64:
+        return 1, ""
+    if kv_dtype == "int8":
+        return 1, "an int8 pool keeps one scale a head, so heads stay apart"
+    if num_kv_heads % 2:
+        return 1, f"{num_kv_heads} kv heads do not pair"
+    if (num_kv_heads // 2) % model_shards:
+        return 1, (f"a model axis of {model_shards} does not divide "
+                   f"{num_kv_heads // 2} pairs of heads")
+    return 2, ""
+
+
 @jax.tree_util.register_pytree_node_class
 class KVPool:
     """One side (K or V) of the paged cache: flat head-major ``data``
-    [n_kv, L*P, page, d] plus, when int8-quantized, a per-token ``scale``
+    [n_kv, L*P, page, d] ([n_kv/2, L*P, page, 128] where two 64-wide heads
+    share a row) plus, when int8-quantized, a per-token ``scale``
     [n_kv, L*P, page] float32. A pytree, so it rides jit arguments,
     donation, lax.scan carries, and device_put shardings like the plain
     array it replaces."""
@@ -121,13 +168,14 @@ class KVPool:
 
 
 def init_pages(cfg: CacheConfig, sharding=None) -> tuple[KVPool, KVPool]:
-    """Flat head-major pools [n_kv, L * P, page, d] (layer l's block starts
+    """Flat head-major pools [n_kv, L * P, page, d], or [n_kv/2, L * P,
+    page, 128] at 64-wide heads (``cfg.pool_row``; layer l's block starts
     at l * P; see module docstring for why the layer axis is folded in).
     ``sharding`` (parallel/sharding.pool_sharding on the engine's mesh)
     creates every leaf already sharded, so no device ever holds a whole
     pool."""
-    shape = (cfg.num_kv_heads, cfg.num_layers * cfg.num_pages,
-             cfg.page_size, cfg.head_dim)
+    heads, lanes = cfg.pool_row
+    shape = (heads, cfg.num_layers * cfg.num_pages, cfg.page_size, lanes)
     if cfg.kv_dtype == "int8":
         def one():
             return KVPool(jnp.zeros(shape, jnp.int8, device=sharding),
@@ -174,8 +222,9 @@ _MAX_RMW_PAGES = 33
 # quantize-at-write twin kernel (pool bytes match this module's
 # quantize_kv bit-for-bit). The dispatcher takes the kernel wherever it
 # observes, at trace time, that it applies, and is exactly "dus" wherever
-# it does not (CP meshes, traced windows, head_dim not a multiple of 128,
-# int8 at a page_size that is not a multiple of 128, the XLA path
+# it does not (CP meshes, traced windows, a page row that is not a
+# multiple of 128 lanes: head_dim 96, or 64 where heads_per_row could not
+# pair; int8 at a page_size that is not a multiple of 128, the XLA path
 # off-TPU). Measured on a v5e at mistral-7b's shapes (PERF.md §6, PR 34):
 # the Mosaic kernel leaves the pool byte-identical to the "dus" loop and
 # updates it in place inside the K-step scan. The int8 twin has not been
@@ -279,7 +328,9 @@ def write_tokens(
 
     k_pages/v_pages: KVPool — data [n_kv, P_total, page, d] (flat
                      head-major pool) + optional per-token int8 scale
-    k, v:            [B, T, n_kv, d]
+    k, v:            [B, T, n_kv, d]; written as the pool's rows (two
+                     adjacent 64-wide heads are one 128-lane row of a
+                     paired pool: a reshape that moves nothing)
     page_table:      [B, pages_per_seq] int32 — GLOBAL page ids (the layer
                      body has already added its l*P block offset)
     positions:       [B, T] int32 token positions; each row's valid entries
@@ -307,8 +358,9 @@ def write_tokens(
     non-owned updates become read-merge no-ops (a blind DUS at a clamped
     local slot would corrupt a page another sequence owns there).
     """
-    B, T, n_kv, d = k.shape
-    page = k_pages.shape[2]
+    B, T = k.shape[:2]
+    n_kv, _, page, d = k_pages.shape
+    k, v = k.reshape(B, T, n_kv, d), v.reshape(B, T, n_kv, d)
     pps = page_table.shape[1]
     quant = k_pages.quantized
     if quant:
@@ -742,7 +794,8 @@ class HostKVCache:
     Payloads are raw pool bytes per page, all layers stacked —
     ``{"k": [n_kv, L, page, d], "v": ..., "ks": [n_kv, L, page] | None,
     "vs": ...}`` (int8 data + f32 scales for quantized pools, the pool
-    dtype otherwise) — so a reuse round-trips the exact bytes the device
+    dtype otherwise; a pool of paired 64-wide heads gives its own rows,
+    [n_kv/2, L, page, 128]) — so a reuse round-trips the exact bytes the device
     wrote and greedy streams stay bit-identical with the tier on or off.
 
     Keyed by tenant so one tenant's sessions can never be served another
@@ -873,7 +926,8 @@ def payload_shape_ok(payload, cache_config) -> bool:
         return False
     k, v = payload.get("k"), payload.get("v")
     ks, vs = payload.get("ks"), payload.get("vs")
-    want = (cc.num_kv_heads, cc.num_layers, cc.page_size, cc.head_dim)
+    heads, lanes = cc.pool_row
+    want = (heads, cc.num_layers, cc.page_size, lanes)
     data_dtype = np.dtype(np.int8 if cc.kv_dtype == "int8"
                           else jnp.dtype(cc.dtype).name)
     for side in (k, v):

@@ -1396,7 +1396,9 @@ class Engine:
         cfg = self.model_config
         _refuse_what_runs_cannot(cfg, engine_config, mesh, model_dir)
         self.mesh = mesh
-        from llms_on_kubernetes_tpu.parallel.mesh import AXIS_SEQ, set_active_mesh
+        from llms_on_kubernetes_tpu.parallel.mesh import (
+            AXIS_MODEL, AXIS_SEQ, set_active_mesh,
+        )
 
         if mesh is not None:
             sp = int(mesh.shape.get(AXIS_SEQ, 1))
@@ -1458,6 +1460,8 @@ class Engine:
             pages_per_slot=engine_config.pages_per_slot,
             dtype=engine_config.dtype,
             kv_dtype=engine_config.kv_cache_dtype,
+            model_shards=(int(mesh.shape[AXIS_MODEL])
+                          if mesh is not None else 1),
         )
         sharding = None
         if mesh is not None:

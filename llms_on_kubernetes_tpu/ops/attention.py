@@ -86,6 +86,14 @@ def _choose(op: str, impl: str, why: str) -> None:
               file=sys.stderr, flush=True)
 
 
+def _model_shards() -> int:
+    """Size of the active mesh's ``model`` axis (1 without a mesh)."""
+    from llms_on_kubernetes_tpu.parallel.mesh import AXIS_MODEL, get_active_mesh
+
+    mesh = get_active_mesh()
+    return int(mesh.shape[AXIS_MODEL]) if mesh is not None else 1
+
+
 def _per_kv_head_shard(fn, n_kv: int, args, head_axes, out_head_axes):
     """Run a Pallas call once per tensor-parallel shard.
 
@@ -103,8 +111,7 @@ def _per_kv_head_shard(fn, n_kv: int, args, head_axes, out_head_axes):
 
     from llms_on_kubernetes_tpu.parallel.mesh import AXIS_MODEL, get_active_mesh
 
-    mesh = get_active_mesh()
-    tp = int(mesh.shape[AXIS_MODEL]) if mesh is not None else 1
+    mesh, tp = get_active_mesh(), _model_shards()
     if tp == 1 or n_kv % tp != 0:
         return fn(*args)
 
@@ -129,12 +136,16 @@ def softcap(logits: jnp.ndarray, cap: Optional[float]) -> jnp.ndarray:
 def _gather_pool(pool, page_table, B: int, S: int, d: int) -> jnp.ndarray:
     """Materialize a pool's logical KV [n_kv, B, S, d] f32 through the page
     table, dequantizing per token when the pool is int8 (engine/cache.py
-    KVPool)."""
+    KVPool). A pool whose rows hold several ``d``-wide heads side by side
+    (cache.heads_per_row) is un-paired after the gather."""
     data = getattr(pool, "data", pool)   # raw arrays accepted (tests)
-    n_kv = data.shape[0]
-    x = data[:, page_table].reshape(n_kv, B, S, d).astype(jnp.float32)
+    rows, pair = data.shape[0], data.shape[3] // d
+    x = data[:, page_table].reshape(rows, B, S, pair * d).astype(jnp.float32)
+    if pair > 1:
+        x = jnp.moveaxis(x.reshape(rows, B, S, pair, d), 3, 1)
+        x = x.reshape(rows * pair, B, S, d)
     if getattr(pool, "quantized", False):
-        s = pool.scale[:, page_table].reshape(n_kv, B, S)
+        s = pool.scale[:, page_table].reshape(rows, B, S)
         x = x * s[..., None]
     return x
 
@@ -209,7 +220,8 @@ def paged_attention(
     """Single-token decode attention against the paged KV cache.
 
     q:          [B, n_q, d]       — one new token per active slot
-    k_pages:    [n_kv, P, page, d] — global page pool (this layer, head-major)
+    k_pages:    [n_kv, P, page, d] — global page pool (this layer,
+                head-major; [n_kv/2, P, page, 2d] where two heads share a row)
     v_pages:    [n_kv, P, page, d]
     page_table: [B, pages_per_seq] int32 — physical page ids per slot
     lengths:    [B] int32 — tokens in cache per slot INCLUDING the current
@@ -221,13 +233,14 @@ def paged_attention(
     through VMEM instead (pallas_paged.py).
     """
     B, n_q, d = q.shape
-    n_kv, P, page, _ = k_pages.shape
+    page = k_pages.shape[2]
     pages_per_seq = page_table.shape[1]
     S = pages_per_seq * page
-    group = n_q // n_kv
 
     k = _gather_pool(k_pages, page_table, B, S, d)
     v = _gather_pool(v_pages, page_table, B, S, d)
+    n_kv = k.shape[0]
+    group = n_q // n_kv
     qg = q.reshape(B, n_kv, group, d).astype(jnp.float32)
 
     logits = jnp.einsum("bkgd,kbsd->bkgs", qg, k) * scale   # [B, n_kv, g, S]
@@ -275,12 +288,12 @@ def chunk_attention(
     returns        [B, T, n_q, d]
     """
     B, T, n_q, d = q.shape
-    n_kv, P, page, _ = k_pages.shape
-    S = page_table.shape[1] * page
-    group = n_q // n_kv
+    S = page_table.shape[1] * k_pages.shape[2]
 
     k = _gather_pool(k_pages, page_table, B, S, d)
     v = _gather_pool(v_pages, page_table, B, S, d)
+    n_kv = k.shape[0]
+    group = n_q // n_kv
     qg = q.reshape(B, T, n_kv, group, d).astype(jnp.float32)
 
     logits = jnp.einsum("btkgd,kbsd->bkgts", qg, k) * scale  # [B,n_kv,g,T,S]
@@ -404,8 +417,12 @@ def dispatch_chunk_attention(q, k_pages, v_pages, page_table, history,
 
 
 def _paged_kernel_mode(q, k_pages, page_table, sliding_window):
-    """(mode, why) for the paged decode kernels on these operands: mode
-    is pallas_mode() when a kernel applies, else None with the reason."""
+    """(mode, note) for the paged decode kernels on these operands: mode
+    is pallas_mode() when a kernel applies, and the note is what the record
+    adds to the kernel's name ("" or the paired layout); else mode is None
+    and the note is the reason. Decided from the pool it is handed: its
+    stored shape and dtype against q's head_dim."""
+    from llms_on_kubernetes_tpu.engine.cache import heads_per_row
     from llms_on_kubernetes_tpu.ops.pallas_paged import paged_vmem_bytes
 
     mode = pallas_mode()
@@ -415,21 +432,33 @@ def _paged_kernel_mode(q, k_pages, page_table, sliding_window):
         return None, "traced (per-layer) sliding window"
     quantized = getattr(k_pages, "quantized", False)
     kd = getattr(k_pages, "data", k_pages)
-    n_kv, _, page, d = kd.shape
+    rows, _, page, lanes = kd.shape
+    d = q.shape[-1]
     if mode == "compiled":
         # Mosaic tiling on real TPU (the interpreter takes any shape): the
-        # manual page DMA needs a lane-aligned head_dim (d=64/96 models —
-        # TinyLlama, Phi-3 — take the XLA gather path), and the int8
-        # kernels' per-token scale DMAs land at lane offset i*page_size
-        if d % 128 != 0:
-            return None, f"head_dim {d} is not a multiple of 128"
+        # manual page DMA needs page rows of whole 128-lane tiles. Heads of
+        # 128 and 256 are such rows; heads of 64 are where the pool holds
+        # two to a row (cache.heads_per_row); any other width (96: Phi-3)
+        # takes the XLA gather path, it is not padded. The int8 kernels'
+        # per-token scale DMAs land at lane offset i*page_size
+        if lanes % 128 != 0:
+            why = f"head_dim {d} is not a multiple of 128"
+            if d != 64:
+                return None, (why + ", pairs into no 128-lane row, "
+                              "is not padded")
+            _, unpaired = heads_per_row(
+                rows, d, "int8" if quantized else None, _model_shards())
+            return None, (f"{why} and "
+                          f"{unpaired or 'the pool holds one head a row'}")
         if quantized and page % 128 != 0:
             return None, f"int8 KV needs page_size % 128 == 0, got {page}"
-    need = paged_vmem_bytes(n_kv, page, page_table.shape[1], d, kd.dtype,
+    need = paged_vmem_bytes(rows, page, page_table.shape[1], lanes, kd.dtype,
                             quantized)
     if need > VMEM_BUDGET_BYTES:
-        return None, (f"a block of {n_kv} x {d} heads needs "
+        return None, (f"a block of {rows} x {lanes} heads needs "
                       f"{_mib(need)} VMEM > {_mib(VMEM_BUDGET_BYTES)} budget")
+    if lanes != d:
+        return mode, f", {lanes // d} heads of {d} to a {lanes}-lane page row"
     return mode, ""
 
 
@@ -439,11 +468,12 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
     """Decode attention WITH the current token's KV append.
 
     Wherever the paged decode kernel applies (_paged_kernel_mode: compiled
-    or interpreted Pallas, a static window, and on the chip a lane-aligned
-    head_dim, a page that is a multiple of 8 and a block's staging inside
-    the VMEM budget) and the mesh has no seq axis, the write folds INTO
-    the attention kernel (pallas_paged.pallas_paged_attention_write): one
-    program a slot, run in turn, each attending its row a 512-token block
+    or interpreted Pallas, a static window, and on the chip a page row of
+    whole 128-lane tiles (head_dim 128 or 256, or 64 in a pool that holds
+    two heads to a row), a page that is a multiple of 8 and a block's
+    staging inside the VMEM budget) and the mesh has no seq axis, the
+    write folds INTO the attention kernel
+    (pallas_paged.pallas_paged_attention_write): one program a slot, run in turn, each attending its row a 512-token block
     at a time while the next block (the next LIVE slot's first, from a
     row's last) and that slot's 8-row write block are already being
     fetched; a live slot's program splices the new row into its write
@@ -467,9 +497,10 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
     from llms_on_kubernetes_tpu.parallel.mesh import seq_parallelism
 
     mode = None
+    kd_shape = getattr(k_pages, "data", k_pages).shape
     if kv_write_strategy() == "fused" and seq_parallelism() == 1:
-        mode, _ = _paged_kernel_mode(q, k_pages, page_table, sliding_window)
-        kd_shape = getattr(k_pages, "data", k_pages).shape
+        mode, note = _paged_kernel_mode(q, k_pages, page_table,
+                                        sliding_window)
         # the in-kernel append is an 8-token-block RMW (Mosaic sublane
         # tiling): sub-8 page sizes can't host an aligned block
         if mode == "compiled" and kd_shape[2] % 8 != 0:
@@ -487,20 +518,19 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
 
     kw = dict(scale=scale, sliding_window=sliding_window,
               attn_softcap=attn_softcap, interpret=mode == "interpret")
-    n_kv = k_new.shape[1]
     if getattr(k_pages, "quantized", False):
         _choose("decode", f"pallas-{mode}", "fused int8 write+attend kernel")
         attn, kd, ks, vd, vs = _per_kv_head_shard(
             lambda *a: pallas_paged.pallas_paged_attention_write_int8(*a, **kw),
-            n_kv,
+            kd_shape[0],
             (q, k_pages.data, k_pages.scale, v_pages.data, v_pages.scale,
              page_table, lengths, k_new, v_new),
             (1, 0, 0, 0, 0, None, None, 1, 1), (1, 0, 0, 0, 0))
         return attn, KVPool(kd, ks), KVPool(vd, vs)
-    _choose("decode", f"pallas-{mode}", "fused write+attend kernel")
+    _choose("decode", f"pallas-{mode}", "fused write+attend kernel" + note)
     attn, kd, vd = _per_kv_head_shard(
         lambda *a: pallas_paged.pallas_paged_attention_write(*a, **kw),
-        n_kv,
+        kd_shape[0],
         (q, getattr(k_pages, "data", k_pages),
          getattr(v_pages, "data", v_pages), page_table, lengths, k_new, v_new),
         (1, 0, 0, None, None, 1, 1), (1, 0, 0))
@@ -524,9 +554,9 @@ def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,
         return cp_paged_attention(
             q, k_pages, v_pages, page_table, lengths, scale=scale,
             sliding_window=sliding_window, attn_softcap=attn_softcap)
-    mode, why = _paged_kernel_mode(q, k_pages, page_table, sliding_window)
+    mode, note = _paged_kernel_mode(q, k_pages, page_table, sliding_window)
     if mode is None:
-        _choose("decode", "xla", why)
+        _choose("decode", "xla", note)
         return paged_attention(q, k_pages, v_pages, page_table, lengths,
                                scale=scale, sliding_window=sliding_window,
                                attn_softcap=attn_softcap)
@@ -544,7 +574,7 @@ def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,
             (q, k_pages.data, k_pages.scale, v_pages.data, v_pages.scale,
              page_table, lengths),
             (1, 0, 0, 0, 0, None, None), 1)
-    _choose("decode", f"pallas-{mode}", "paged kernel")
+    _choose("decode", f"pallas-{mode}", "paged kernel" + note)
     return _per_kv_head_shard(
         lambda *a: pallas_paged.pallas_paged_attention(*a, **kw),
         kd.shape[0],

@@ -16,6 +16,19 @@ slot's pages HBM→VMEM once and attend in place:
   each page is one strided [n_kv, page, d] block for every head at once —
   a single DMA with no sublane-tile slicing (a head-minor pool layout is
   rejected by Mosaic: slicing n_kv to 1 in the tiled sublane slot).
+- A page row is whole 128-lane tiles (Mosaic compiles the page DMA for
+  nothing narrower). Heads of 64 therefore come two to a row, a pool of
+  [n_kv/2, P, page, 128] (cache.heads_per_row), and the kernel bodies run
+  on it UNCHANGED as n_kv/2 heads of 128 with twice the group:
+  ``_decode_call`` lays q out as [B, n_kv/2, 2*group, 128], row j*group+g
+  holding query g of the pair's head j in lanes j*64 .. j*64+63 and zero
+  in the other head's, so q'.K' is that head's logits exactly and the
+  online softmax is per row as before; the new token's K/V rows
+  [B, n_kv, 64] are [B, n_kv/2, 128] by a reshape that moves nothing; and
+  of output row j*group+g the wrapper keeps lanes j*64 .. j*64+63 (the
+  other half is the probabilities times the OTHER head's values: finite,
+  dropped). The MXU does twice the (tiny) work; the bytes moved are the
+  live pages' only.
 - grid = (B,), sequential: ONE program a slot computes ALL kv heads with
   two batched MXU contractions a block ([group, d] x [d, blk] and
   [group, blk] x [blk, d], f32) under an online softmax. A v5e has one
@@ -593,12 +606,23 @@ def _decode_call(kernel, q, pools, page_table, lengths, news, *, interpret,
     """One attending kernel over ``pools`` (K, V; or K data, K scale, V
     data, V scale of an int8 pool) for q [B, n_q, d]. With ``news`` (the
     current token's K and V [B, n_kv, d]) the pools are updated in place
-    and returned after the attention [B, n_q, d]."""
-    B, n_q, d = q.shape
-    n_kv, _, page_size, _ = pools[0].shape
+    and returned after the attention [B, n_q, d]. Where a pool row holds
+    ``pair`` heads of q's width side by side, the kernel sees them as one
+    head of the row's width (module docstring)."""
+    B, n_q, head_dim = q.shape
+    n_kv, _, page_size, d = pools[0].shape
     pages_per_seq = page_table.shape[1]
-    group = n_q // n_kv
+    pair = d // head_dim
+    group = n_q // n_kv              # query rows a pool row: pair * the GQA group
     quantized, write = len(pools) == 4, bool(news)
+    q = q.reshape(B, n_kv, group, head_dim)
+    if pair > 1:
+        # each query row, in its own head's lanes of the row and zero in
+        # the others'
+        own = jnp.eye(pair, dtype=q.dtype)[:, None, :, None]
+        q = (q.reshape(B, n_kv, pair, group // pair, 1, head_dim)
+             * own).reshape(B, n_kv, group, d)
+        news = [x.reshape(B, n_kv, d) for x in news]
 
     def row_block(*shape):
         return pl.BlockSpec((1, *shape), lambda b, *_: (b, *(0,) * len(shape)))
@@ -635,8 +659,12 @@ def _decode_call(kernel, q, pools, page_table, lengths, news, *, interpret,
                 quantized)),
         interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q.reshape(B, n_kv, group, d), *pools, *news)
-    return (out.reshape(B, n_q, d), *pools_out)
+      q, *pools, *news)
+    if pair > 1:
+        # output row j*g+i is head j's: its values are lanes j*64 ...
+        out = out.reshape(B, n_kv, pair, group // pair, pair, head_dim)
+        out = jnp.stack([out[:, :, j, :, j] for j in range(pair)], axis=2)
+    return (out.reshape(B, n_q, head_dim), *pools_out)
 
 
 _STATIC = ("scale", "sliding_window", "attn_softcap", "interpret")
@@ -817,6 +845,9 @@ def pallas_paged_write_window(
     (k_pages, v_pages) updated in place via input/output aliasing."""
     n_kv, P, page_size, d = k_pages.shape
     B, W = k_new.shape[:2]
+    # the pool's rows: two adjacent 64-wide heads are one row of a paired
+    # pool (cache.heads_per_row), a reshape that moves nothing
+    k_new, v_new = k_new.reshape(B, W, n_kv, d), v_new.reshape(B, W, n_kv, d)
 
     kernel = functools.partial(
         _paged_kernel_write_window,

@@ -147,21 +147,34 @@ LAYOUTS = {
 PAGE, PPS, K = 8, 4, 4
 
 
-@pytest.fixture(scope="module", params=sorted(LAYOUTS))
-def tiny128(request):
+# head widths the compiled kernel takes: 128 as it is, 64 two heads to a
+# 128-lane page row (cache.heads_per_row); what the record adds for each
+WIDTHS = {128: "", 64: ", 2 heads of 64 to a 128-lane page row"}
+
+
+@pytest.fixture(scope="module", params=[
+    (layout, d) for d in sorted(WIDTHS) for layout in sorted(LAYOUTS)],
+    ids=lambda p: f"{p[0]}, {p[1]} wide")
+def tiny(request):
     """debug-tiny widened to a head_dim the compiled kernel would take, a
-    random pool (stale rows must be maskable garbage, not zeros), and one
-    layout of rows for a decode window."""
+    random pool in the layout the engine would give it (stale rows must be
+    maskable garbage, not zeros), and one layout of rows for a decode
+    window."""
     from llms_on_kubernetes_tpu.configs import get_config
     from llms_on_kubernetes_tpu.models.decoder import init_params
 
-    lengths0, budgets = LAYOUTS[request.param]
-    cfg = dataclasses.replace(get_config("debug-tiny"), head_dim=128)
+    layout, d = request.param
+    lengths0, budgets = LAYOUTS[layout]
+    cfg = dataclasses.replace(get_config("debug-tiny"), head_dim=d)
     params = init_params(cfg, jax.random.key(1), dtype="float32")
     B = len(lengths0)
     num_pages = B * PPS + 1
     rng = np.random.default_rng(5)
-    shape = (cfg.num_kv_heads, cfg.num_layers * num_pages, PAGE, cfg.head_dim)
+    heads, lanes = C.CacheConfig(
+        num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
+        head_dim=d).pool_row
+    assert lanes == 128
+    shape = (heads, cfg.num_layers * num_pages, PAGE, lanes)
     k0 = rng.normal(size=shape).astype(np.float32)
     v0 = rng.normal(size=shape).astype(np.float32)
     packed = window_rows(lengths0, budgets, rng.integers(1, 200, B), PAGE,
@@ -181,9 +194,10 @@ def _f32(bits):
     ("fused", "fused write+attend kernel"),
     ("dus", "paged kernel"),
 ])
-def test_decode_window_fused_and_two_op_match_xla(tiny128, monkeypatch,
+def test_decode_window_fused_and_two_op_match_xla(tiny, monkeypatch,
                                                   strategy, kernel):
-    cfg, params, packed, pools, lengths0, budgets = tiny128
+    cfg, params, packed, pools, lengths0, budgets = tiny
+    kernel += WIDTHS[cfg.head_dim]
     monkeypatch.setenv("LLMK_ATTENTION_IMPL", "xla")
     want, wk, wv, _ = decode_window(cfg, params, *pools(), packed, K, "dus")
     assert attention._chosen["decode"][0] == "xla"
@@ -206,12 +220,15 @@ def test_decode_window_fused_and_two_op_match_xla(tiny128, monkeypatch,
         assert n == written
 
 
-def _operands(rng, n_kv, d, page, kv_dtype):
+def _operands(rng, n_kv, d, page, kv_dtype, unpaired=False):
     B, pps, group = 3, 2, 2
     cc = C.CacheConfig(num_layers=1, num_kv_heads=n_kv, head_dim=d,
                        num_pages=B * pps + 1, page_size=page,
                        pages_per_slot=pps, dtype="float32", kv_dtype=kv_dtype)
     kp, vp = C.init_pages(cc)
+    if unpaired:     # a logical 64-wide pool, as a caller by hand may build
+        kp, vp = (C.KVPool(jnp.zeros((n_kv, *p.shape[1:3], d), p.dtype))
+                  for p in (kp, vp))
     pt = jnp.asarray(1 + np.arange(B * pps).reshape(B, pps), jnp.int32)
     hist = jnp.asarray(rng.normal(size=(B, page, n_kv, d)), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(page, dtype=jnp.int32), (B, page))
@@ -227,8 +244,20 @@ def _operands(rng, n_kv, d, page, kv_dtype):
 # what the dispatcher sees -> why it must take the two-op path by itself,
 # on a backend where Pallas compiles (pallas_mode() forced to "compiled")
 OBSERVED_OUT = {
-    "head_dim 64": (dict(n_kv=2, d=64, page=8, kv_dtype=None), 4,
-                    "head_dim 64 is not a multiple of 128"),
+    "head_dim 96": (dict(n_kv=2, d=96, page=8, kv_dtype=None), 4,
+                    "head_dim 96 is not a multiple of 128, pairs into no "
+                    "128-lane row, is not padded"),
+    "head_dim 64, odd kv heads": (
+        dict(n_kv=3, d=64, page=8, kv_dtype=None), 4,
+        "head_dim 64 is not a multiple of 128 and 3 kv heads do not pair"),
+    "head_dim 64, int8 pool": (
+        dict(n_kv=2, d=64, page=128, kv_dtype="int8"), 4,
+        "head_dim 64 is not a multiple of 128 and an int8 pool keeps one "
+        "scale a head, so heads stay apart"),
+    "head_dim 64, a pool nobody paired": (
+        dict(n_kv=2, d=64, page=8, kv_dtype=None, unpaired=True), 4,
+        "head_dim 64 is not a multiple of 128 and the pool holds one head "
+        "a row"),
     "traced window": (dict(n_kv=2, d=128, page=8, kv_dtype=None), "traced",
                       "traced (per-layer) sliding window"),
     "int8 pool at page 64": (dict(n_kv=2, d=128, page=64, kv_dtype="int8"), 4,

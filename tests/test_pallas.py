@@ -185,25 +185,49 @@ def test_paged_decode_through_cache_write_path(rng):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_engine_greedy_identical_under_pallas(monkeypatch):
+@pytest.mark.parametrize("head_dim", [16, 64])
+def test_engine_greedy_identical_under_pallas(monkeypatch, head_dim):
     """Full engine decode with LLMK_ATTENTION_IMPL=pallas (interpreted on
-    CPU) must emit the same greedy tokens as the XLA path."""
+    CPU) must emit the same greedy tokens as the XLA path: at debug-tiny's
+    own 16-wide heads, and widened to 64, where the engine's pool holds
+    two heads to a row and prefill, chunk and decode all read it."""
+    import dataclasses
+
+    from llms_on_kubernetes_tpu.configs import get_config
     from llms_on_kubernetes_tpu.engine.engine import Engine, EngineConfig, SamplingParams
+    from llms_on_kubernetes_tpu.ops import attention
+
+    cfg = dataclasses.replace(get_config("debug-tiny"), head_dim=head_dim)
 
     def run():
         eng = Engine(EngineConfig(
             model="debug-tiny", dtype="float32", max_decode_slots=2,
             page_size=16, num_pages=64, pages_per_slot=8,
             prefill_buckets=(16,),
-        ))
-        return eng.generate([1, 2, 3, 4, 5],
+        ), model_config=cfg)
+        assert eng.k_pages.shape[3] == (128 if head_dim == 64 else head_dim)
+        # 21 tokens: a prefill bucket and a chunk; 8 more by decode
+        return eng.generate(list(range(1, 22)),
                             SamplingParams(temperature=0.0, max_tokens=8))
 
+    # the engine's step traces are shared between Engine objects, and the
+    # variable is read at trace time: without this the second engine would
+    # run the first one's executables
+    jax.clear_caches()
     monkeypatch.setenv("LLMK_ATTENTION_IMPL", "xla")
     ref = run()
+    assert attention._chosen["decode"][0] == "xla"
+    jax.clear_caches()
     monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
-    out = run()
+    try:
+        out = run()
+    finally:
+        jax.clear_caches()
     assert out == ref, f"pallas diverged: {out} vs {ref}"
+    assert attention._chosen["decode"] == (
+        "pallas-interpret", "fused write+attend kernel" + (
+            ", 2 heads of 64 to a 128-lane page row" if head_dim == 64
+            else ""))
 
 
 def run_fused_write_case(rng, lengths_np, *, n_kv, group, d, page, pps,
@@ -413,6 +437,227 @@ def test_paged_write_window_matches_reference(rng):
 
 
 # ---------------------------------------------------------------------------
+# two 64-wide heads to a 128-lane page row (engine/cache.py heads_per_row)
+# ---------------------------------------------------------------------------
+
+def pair_heads(pool):
+    """A logical pool [n_kv, P, page, d] as the paired one
+    [n_kv/2, P, page, 2d]: stored[h, p, t, j*d + c] = logical[2h + j, p, t, c]."""
+    n_kv, P, page, d = pool.shape
+    x = jnp.moveaxis(jnp.reshape(pool, (n_kv // 2, 2, P, page, d)), 1, 3)
+    return x.reshape(n_kv // 2, P, page, 2 * d)
+
+
+# name -> (kv heads, GQA group, lengths incl. the current token, window);
+# page 8, 4 pages a slot
+PAIRED_CASES = {
+    "page boundary, group 4": (2, 4, [8, 9, 24, 25], None),
+    "idle rows and a length of one, group 1": (4, 1, [0, 5, 0, 8, 1], None),
+    "static window, group 4": (4, 4, [32, 13, 0, 7], 9),
+    "every row idle": (2, 1, [0, 0], None),
+}
+
+
+def _paired_case(rng, case):
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
+
+    n_kv, group, lengths_np, window = PAIRED_CASES[case]
+    lengths_np = np.asarray(lengths_np, np.int32)
+    B, d, page, pps = len(lengths_np), 64, 8, 4
+    k_pages, v_pages, table = _paged_setup(rng, B, n_kv, d, page, pps,
+                                           lengths_np)
+    q = jnp.asarray(rng.normal(size=(B, n_kv * group, d)), jnp.float32)
+    k_new = jnp.asarray(rng.normal(size=(B, n_kv, d)), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(B, n_kv, d)), jnp.float32)
+    wp = jnp.asarray(np.where(lengths_np > 0, lengths_np - 1, -1)[:, None])
+    # the two-op path on the LOGICAL 64-wide pool is the yardstick
+    kp_ref, vp_ref = write_tokens(
+        KVPool(k_pages), KVPool(v_pages), k_new[:, None], v_new[:, None],
+        table, wp)
+    ref = paged_attention(q, kp_ref, vp_ref, table, jnp.asarray(lengths_np),
+                          scale=d ** -0.5, sliding_window=window)
+    return (q, k_pages, v_pages, table, jnp.asarray(lengths_np), k_new, v_new,
+            wp, window, kp_ref.data, vp_ref.data, np.asarray(ref))
+
+
+@pytest.mark.parametrize("case", sorted(PAIRED_CASES))
+def test_paired_fused_write_matches_two_op_on_logical_pool(rng, case):
+    """The fused write+attend kernel on a pool of paired 64-wide heads:
+    the attention rows of the two-op path on the logical pool, and after
+    the append the SAME pool bytes (paired), outside trash page 0."""
+    from llms_on_kubernetes_tpu.ops.pallas_paged import (
+        pallas_paged_attention_write,
+    )
+
+    (q, k_pages, v_pages, table, lengths, k_new, v_new, _wp, window,
+     kp_ref, vp_ref, ref) = _paired_case(rng, case)
+    out, kp2, vp2 = pallas_paged_attention_write(
+        q, pair_heads(k_pages), pair_heads(v_pages), table, lengths, k_new,
+        v_new, scale=64 ** -0.5, sliding_window=window, interpret=True)
+    assert out.shape == q.shape and kp2.shape[3] == 128
+    act = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out)[act], ref[act],
+                               rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(kp2)[:, 1:],
+                                  np.asarray(pair_heads(kp_ref))[:, 1:])
+    np.testing.assert_array_equal(np.asarray(vp2)[:, 1:],
+                                  np.asarray(pair_heads(vp_ref))[:, 1:])
+
+
+@pytest.mark.parametrize("case", sorted(PAIRED_CASES))
+def test_paired_plain_kernel_and_reference_ops_read_the_pairs(rng, case):
+    """What else reads a paired pool: ``write_tokens`` (the token's row is
+    the pool's row by a reshape), the plain paged kernel, and the XLA
+    reference ops, which un-pair after their gather. All against the
+    logical pool's answers."""
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
+    from llms_on_kubernetes_tpu.ops.attention import chunk_attention
+
+    (q, k_pages, v_pages, table, lengths, k_new, v_new, wp, window,
+     kp_ref, vp_ref, ref) = _paired_case(rng, case)
+    kp2, vp2 = write_tokens(
+        KVPool(pair_heads(k_pages)), KVPool(pair_heads(v_pages)),
+        k_new[:, None], v_new[:, None], table, wp)
+    np.testing.assert_array_equal(np.asarray(kp2.data),
+                                  np.asarray(pair_heads(kp_ref)))
+    np.testing.assert_array_equal(np.asarray(vp2.data),
+                                  np.asarray(pair_heads(vp_ref)))
+    act = np.asarray(lengths) > 0
+    out = pallas_paged_attention(q, kp2.data, vp2.data, table, lengths,
+                                 scale=64 ** -0.5, sliding_window=window,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(out)[act], ref[act],
+                               rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(out)).all()
+    xla = paged_attention(q, kp2, vp2, table, lengths, scale=64 ** -0.5,
+                          sliding_window=window)
+    np.testing.assert_allclose(np.asarray(xla)[act], ref[act],
+                               rtol=1e-6, atol=1e-6)
+    # the chunk path: two queries a row ending at the row's last token
+    T = 2
+    qc = jnp.stack([q, q], 1)
+    hist = jnp.maximum(lengths - T, 0)
+    n = jnp.minimum(lengths, T)
+    want = chunk_attention(qc, kp_ref, vp_ref, table, hist, n,
+                           scale=64 ** -0.5, sliding_window=window)
+    got = chunk_attention(qc, kp2, vp2, table, hist, n, scale=64 ** -0.5,
+                          sliding_window=window)
+    np.testing.assert_allclose(np.asarray(got)[act], np.asarray(want)[act],
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_paired_prefill_page_merge_and_window_write(rng):
+    """A prefill's page merges into a paired pool (a chunk that starts
+    mid-page and crosses into fresh pages) and the speculative window
+    write leave the paired bytes of what they leave in a logical pool."""
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
+    from llms_on_kubernetes_tpu.ops.pallas_paged import (
+        pallas_paged_write_window,
+    )
+
+    n_kv, d, page, pps, T = 4, 64, 8, 4, 13
+    start = np.asarray([0, 5, 8], np.int32)
+    n_tok = np.asarray([13, 9, 0], np.int32)
+    B = len(start)
+    k_pages, v_pages, table = _paged_setup(rng, B, n_kv, d, page, pps,
+                                           start + T)
+    k = jnp.asarray(rng.normal(size=(B, T, n_kv, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, T, n_kv, d)), jnp.float32)
+    pos = start[:, None] + np.arange(T, dtype=np.int32)[None]
+    pos = jnp.asarray(np.where(np.arange(T)[None] < n_tok[:, None], pos, -1))
+    want = write_tokens(KVPool(k_pages), KVPool(v_pages), k, v, table, pos)
+    got = write_tokens(KVPool(pair_heads(k_pages)),
+                       KVPool(pair_heads(v_pages)), k, v, table, pos)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g.data)[:, 1:],
+                                      np.asarray(pair_heads(w.data))[:, 1:])
+
+    W = 4
+    widths = jnp.asarray([4, 2, 0], jnp.int32)
+    want = pallas_paged_write_window(
+        k_pages, v_pages, table, jnp.asarray(start), widths, k[:, :W],
+        v[:, :W], interpret=True)
+    got = pallas_paged_write_window(
+        pair_heads(k_pages), pair_heads(v_pages), table, jnp.asarray(start),
+        widths, k[:, :W], v[:, :W], interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g),
+                                      np.asarray(pair_heads(w)))
+
+
+# kv heads, head_dim, kv type, model axis -> (heads a row, the reason's gist)
+ROW_CASES = {
+    "64 wide, even heads": ((8, 64, None, 1), 2, ""),
+    "64 wide, model axis divides the pairs": ((8, 64, None, 4), 2, ""),
+    "64 wide, model axis splits a pair": ((4, 64, None, 4), 1, "model axis"),
+    "64 wide, odd heads": ((3, 64, None, 1), 1, "3 kv heads do not pair"),
+    "64 wide, int8": ((8, 64, "int8", 1), 1, "int8"),
+    "96 wide": ((32, 96, None, 1), 1, ""),
+    "128 wide": ((8, 128, None, 1), 1, ""),
+    "16 wide": ((2, 16, None, 1), 1, ""),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_CASES))
+def test_the_pool_says_its_own_row(case):
+    """One rule (``heads_per_row``) decides the stored row; the pools, a
+    page's payload check and the bytes a page takes all follow it, and
+    the bytes never change with the layout."""
+    from llms_on_kubernetes_tpu.engine.cache import (
+        heads_per_row, payload_shape_ok,
+    )
+
+    (n_kv, d, kv_dtype, shards), pair, gist = ROW_CASES[case]
+    got, why = heads_per_row(n_kv, d, kv_dtype, shards)
+    assert got == pair and gist in why and bool(why) == bool(gist)
+    cc = CacheConfig(num_layers=2, num_kv_heads=n_kv, head_dim=d,
+                     num_pages=3, page_size=8, pages_per_slot=2,
+                     kv_dtype=kv_dtype, model_shards=shards)
+    assert cc.pool_row == (n_kv // pair, d * pair)
+    kp, _ = init_pages(cc)
+    assert kp.shape == (n_kv // pair, 6, 8, d * pair)
+    per_tok = n_kv * (d + 4 if kv_dtype else 2 * d) * 2 * 2
+    assert cc.bytes_per_token == per_tok
+    assert sum(x.nbytes for x in jax.tree.leaves(kp)) * 2 == \
+        cc.bytes_per_page * 3
+    page = {"k": np.zeros((n_kv // pair, 2, 8, d * pair), kp.dtype),
+            "ks": None, "vs": None}
+    page["v"] = page["k"]
+    if kv_dtype:
+        page["ks"] = page["vs"] = np.zeros((n_kv, 2, 8), np.float32)
+    assert payload_shape_ok(page, cc)
+    if pair > 1:   # the logical shape is another engine's: a missing page
+        page["k"] = page["v"] = np.zeros((n_kv, 2, 8, d), kp.dtype)
+        assert not payload_shape_ok(page, cc)
+
+
+def test_gate_names_the_model_axis_that_would_split_a_pair(monkeypatch):
+    """4 KV heads of 64 under ``--tp 4``: the engine's pool stays one head
+    a row (each chip keeps one head), and the gate's reason says why."""
+    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.parallel.mesh import (
+        make_mesh, set_active_mesh,
+    )
+
+    cc = CacheConfig(num_layers=1, num_kv_heads=4, head_dim=64, num_pages=2,
+                     page_size=8, pages_per_slot=1, model_shards=4)
+    assert cc.pool_row == (4, 64)
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    set_active_mesh(make_mesh(model=4, devices=jax.devices()[:4]))
+    try:
+        mode, why = attention._paged_kernel_mode(
+            jnp.zeros((1, 32, 64), jnp.bfloat16),
+            jnp.zeros((4, 2, 8, 64), jnp.bfloat16),
+            jnp.zeros((1, 1), jnp.int32), None)
+    finally:
+        set_active_mesh(None)
+    assert mode is None and why == (
+        "head_dim 64 is not a multiple of 128 and a model axis of 4 does "
+        "not divide 2 pairs of heads")
+
+
+# ---------------------------------------------------------------------------
 # no fallback that hides the device
 # ---------------------------------------------------------------------------
 
@@ -463,6 +708,12 @@ def test_dispatchers_say_what_they_took(rng, monkeypatch, capfd):
         jnp.zeros((1, 256, 128), jnp.bfloat16),
         jnp.zeros((256, 2, 64, 128), jnp.bfloat16), pt, 4096)
     assert mode is None and "VMEM" in why and "256 x 128 heads" in why
+    # a pool of paired 64-wide heads names its layout on the record
+    mode, why = attention._paged_kernel_mode(
+        jnp.zeros((1, 32, 64), jnp.bfloat16),
+        jnp.zeros((4, 2, 64, 128), jnp.bfloat16), pt, None)
+    assert (mode, why) == (
+        "interpret", ", 2 heads of 64 to a 128-lane page row")
     err = capfd.readouterr().err
     assert err.count("[attention] op=prefill impl=xla "
                      "why=LLMK_ATTENTION_IMPL=xla") == 1

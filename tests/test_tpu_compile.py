@@ -57,29 +57,34 @@ def no_cache():
     compilation_cache.reset_cache()
 
 
-def _operands(dev, kv_dtype, page, pps, heads=None, pools=None):
-    """Shapes of dispatch_paged_attention_write's operands on ``dev``; a
-    tensor-parallel case places q / k_new / v_new by ``heads`` and the
-    pools by ``pools``."""
-    from llms_on_kubernetes_tpu.engine.cache import KVPool
+def _operands(dev, kv_dtype, page, pps, heads=None, pools=None, shards=1,
+              rows=ROWS, n_kv=N_KV, group=GROUP, d=D, pages=PAGES):
+    """Shapes of dispatch_paged_attention_write's operands on ``dev``, the
+    pools in the layout ``CacheConfig`` gives them; a tensor-parallel case
+    (``shards`` chips over ``model``) places q / k_new / v_new by ``heads``
+    and the pools by ``pools``."""
+    from llms_on_kubernetes_tpu.engine.cache import CacheConfig, KVPool
 
     def sds(shape, dtype, sharding=None):
         if sharding is None and dev is None:      # traced, never compiled
             return jax.ShapeDtypeStruct(shape, dtype)
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding or dev)
 
+    row, lanes = CacheConfig(num_layers=1, num_kv_heads=n_kv, head_dim=d,
+                             kv_dtype=kv_dtype, model_shards=shards).pool_row
+
     def pool():
-        data = sds((N_KV, PAGES, page, D),
+        data = sds((row, pages, page, lanes),
                    jnp.int8 if kv_dtype == "int8" else jnp.bfloat16, pools)
-        scale = (sds((N_KV, PAGES, page), jnp.float32, pools)
+        scale = (sds((row, pages, page), jnp.float32, pools)
                  if kv_dtype == "int8" else None)
         return KVPool(data, scale)
 
-    return (sds((ROWS, N_KV * GROUP, D), jnp.bfloat16, heads), pool(), pool(),
-            sds((ROWS, pps), jnp.int32), sds((ROWS,), jnp.int32),
-            sds((ROWS, N_KV, D), jnp.bfloat16, heads),
-            sds((ROWS, N_KV, D), jnp.bfloat16, heads),
-            sds((ROWS, 1), jnp.int32))
+    return (sds((rows, n_kv * group, d), jnp.bfloat16, heads), pool(), pool(),
+            sds((rows, pps), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows, n_kv, d), jnp.bfloat16, heads),
+            sds((rows, n_kv, d), jnp.bfloat16, heads),
+            sds((rows, 1), jnp.int32))
 
 
 def _compile_dispatch(args):
@@ -219,6 +224,179 @@ def test_tensor_parallel_append_stays_on_each_chips_heads(topo, no_cache,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= shard
     assert mem.temp_size_in_bytes < shard // 8
+
+
+# the lfm2-24b-a2b.long-answers cell: 32/8 heads of 64, 64 slots of 32 pages
+# of 64, two attention layers of 2049 pages in one flat pool
+CELL64 = dict(kv_dtype=None, page=64, pps=32, rows=64, n_kv=8, group=4, d=64,
+              pages=2 * 2049)
+
+
+def _pool_shaped_copies(hlo, pool):
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    return [ln for ln in hlo.splitlines()
+            if (" copy(" in ln or " copy-start(" in ln)
+            and shape in ln.split("=", 1)[1].split("copy")[0]]
+
+
+PAIRED = ", 2 heads of 64 to a 128-lane page row"
+
+
+def test_64_wide_cell_rides_the_kernel_on_paired_heads(one_chip, no_cache,
+                                                       monkeypatch):
+    """At the routed cell's shape the decode dispatcher takes the fused
+    write+attend kernel on the pool of paired heads, Mosaic accepts it
+    (its page DMA is refused at 64 lanes: the parent's reason), the record
+    names the layout, both pools come back in the buffers they came in,
+    nothing copies a pool or appends by ``dynamic-update-slice``, and the
+    kernel's VMEM is what ``paged_vmem_bytes`` counts for 4 rows of 128."""
+    from llms_on_kubernetes_tpu.ops import attention, pallas_paged
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    args = _operands(one_chip, **CELL64)
+    step = jax.jit(
+        lambda *a: attention.dispatch_paged_attention_write(
+            *a, scale=64 ** -0.5), donate_argnums=(1, 2))
+    compiled = step.lower(*args).compile()
+    assert attention._chosen["decode"] == (
+        "pallas-compiled", "fused write+attend kernel" + PAIRED)
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert " dynamic-update-slice(" not in hlo
+    assert not _pool_shaped_copies(hlo, args[1])
+    pools = sum(leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(args[1:3]))
+    assert pools == 537_133_056          # the configuration file's bytes
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools // 8
+
+    eqn = _pallas_call(jax.make_jaxpr(
+        lambda *a: attention.dispatch_paged_attention_write(
+            *a, scale=64 ** -0.5))(*_operands(None, **CELL64)).jaxpr)
+    counted = pallas_paged.paged_vmem_bytes(4, 64, 32, 128, jnp.bfloat16)
+    limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert limit == counted <= attention.VMEM_BUDGET_BYTES
+    # the kernel sees 4 heads of 128 with twice the group
+    assert eqn.invars[2].aval.shape == (64, 4, 8, 128)
+
+
+def test_two_op_setting_takes_the_plain_kernel_on_paired_heads(
+        one_chip, no_cache, monkeypatch):
+    """One gate: under ``kv_write="dus"`` the same pool gets the plain
+    paged kernel after the write loop, with the same note on its record."""
+    from llms_on_kubernetes_tpu.engine import cache
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setattr(cache, "_active_kv_write", "dus")
+    args = _operands(one_chip, **CELL64)
+    hlo = jax.jit(
+        lambda *a: attention.dispatch_paged_attention_write(
+            *a, scale=64 ** -0.5),
+        donate_argnums=(1, 2)).lower(*args).compile().as_text()
+    assert attention._chosen["decode"] == (
+        "pallas-compiled", "paged kernel" + PAIRED)
+    assert hlo.count("tpu_custom_call") == 1
+
+
+def test_tensor_parallel_keeps_whole_pairs_on_each_chip(topo, no_cache,
+                                                        monkeypatch):
+    """``--tp 4`` at 8 KV heads of 64: four pairs, one a chip. Each chip
+    runs the kernel on its own pair under ``shard_map`` and writes its own
+    shard of the pools in place; nothing gathers a pool. (At 4 KV heads the
+    axis would split a pair: ``heads_per_row`` then leaves the pool
+    unpaired and the gate says so: tests/test_pallas.py.)"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llms_on_kubernetes_tpu.configs import get_config
+    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.parallel.mesh import (
+        AXIS_MODEL, make_mesh, set_active_mesh,
+    )
+    from llms_on_kubernetes_tpu.parallel.sharding import pool_sharding
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    mesh = make_mesh(model=4, devices=list(topo.devices)[:4])
+    args = _operands(
+        NamedSharding(mesh, P()), **CELL64, shards=4,
+        heads=NamedSharding(mesh, P(None, AXIS_MODEL)),
+        pools=pool_sharding(get_config("lfm2-24b-a2b"), mesh))
+    set_active_mesh(mesh)
+    try:
+        compiled = jax.jit(
+            lambda *a: attention.dispatch_paged_attention_write(
+                *a, scale=64 ** -0.5),
+            donate_argnums=(1, 2)).lower(*args).compile()
+    finally:
+        set_active_mesh(None)
+    assert attention._chosen["decode"] == (
+        "pallas-compiled", "fused write+attend kernel" + PAIRED)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "all-gather" not in hlo
+    assert " dynamic-update-slice(" not in hlo
+    shard = sum(leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(args[1:3])) // 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= shard
+    assert mem.temp_size_in_bytes < shard // 8
+
+
+@pytest.mark.parametrize("rows,bucket", [(1, 512), (4, 128)])
+def test_prefill_write_into_paired_pool_copies_no_pool(one_chip, no_cache,
+                                                       rows, bucket):
+    """A prefill's ``write_tokens`` into the pool of paired heads stays the
+    in-place page merge it is: the token rows become pool rows by a
+    reshape, and nothing the size of a pool is copied or kept."""
+    from llms_on_kubernetes_tpu.engine.cache import write_tokens
+
+    _, kp, vp, pt, *_ = _operands(one_chip, **CELL64)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(write_tokens, donate_argnums=(0, 1)).lower(
+        kp, vp, sds((rows, bucket, 8, 64), jnp.bfloat16),
+        sds((rows, bucket, 8, 64), jnp.bfloat16),
+        sds((rows, CELL64["pps"]), jnp.int32),
+        sds((rows, bucket), jnp.int32)).compile()
+    pools = 2 * kp.data.size * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools // 8
+    assert not _pool_shaped_copies(compiled.as_text(), kp)
+
+
+def test_128_wide_call_has_the_operands_it_had(monkeypatch):
+    """At mistral-7b's shape nothing of the paired layout shows: the
+    kernel's operands are q as [rows, 8, 4, 128], the pools as they are and
+    the new rows as they came, and around the call there are two reshapes
+    (q in, the rows out) and no transpose, product or stack."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    args = _operands(None, None, PAGE, PPS)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: attention.dispatch_paged_attention_write(
+            *a, scale=D ** -0.5, sliding_window=4096))(*args).jaxpr
+    assert attention._chosen["decode"] == (
+        "pallas-compiled", "fused write+attend kernel")
+    eqn = _pallas_call(jaxpr)
+    assert [v.aval.shape for v in eqn.invars] == [
+        (ROWS, PPS), (ROWS,), (ROWS, N_KV, GROUP, D),
+        (N_KV, PAGES, PAGE, D), (N_KV, PAGES, PAGE, D),
+        (ROWS, N_KV, D), (ROWS, N_KV, D)]
+
+    def outside(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                continue
+            yield e.primitive.name
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from outside(sub)
+
+    names = [n for n in outside(jaxpr) if n not in ("pjit", "jit")]
+    assert sorted(names) == ["reshape", "reshape"], names
 
 
 # mesh (expert x model), the stacks' type, (n, E, D, F, rows, top_k): an

@@ -785,3 +785,226 @@ def test_cell_greedy_streams_fused_against_two_op(mistral, monkeypatch):
     worst = {k: max(r["max_abs_logprob_diff_before"] for r in said[k])
              for k in ("fused", "two_op_xla")}
     assert worst["fused"] <= max(2 * worst["two_op_xla"], 0.05), worst
+
+
+# ---------------------------------------------------------------------------
+# the lfm2-24b-a2b.long-answers cell (PR 41): 32/8 heads of 64, two to a
+# 128-lane page row; page 64, 32 pages a slot, 64 slots, 2 x 2049 pages
+# ---------------------------------------------------------------------------
+
+N_KV64, D64, ROWS64, PAGES64 = 8, 64, 64, 2 * 2049
+
+
+def _cell64_case(seed=41):
+    """About 30 of 64 rows live at about 400 tokens (the cell's decode
+    batch), a page's last row, a fresh page's first, one token; a pool of
+    the cell's size, random, in the logical layout [8, P, 64, 64]."""
+    rng = np.random.default_rng(seed)
+    lengths = np.zeros(ROWS64, np.int32)
+    live = rng.permutation(ROWS64)[:30]
+    lengths[live] = rng.integers(150, 900, 30)
+    lengths[live[:4]] = [64, 65, 1, 2048]
+    pool = [jnp.asarray(rng.standard_normal((N_KV64, PAGES64, 64, D64),
+                                            np.float32), jnp.bfloat16)
+            for _ in range(2)]
+    pt = jnp.asarray(2049 + 1 + np.arange(ROWS64 * 32).reshape(ROWS64, 32)
+                     % 2048, jnp.int32)          # the second layer's block
+    q = jnp.asarray(rng.normal(size=(ROWS64, N_KV64 * 4, D64)), jnp.bfloat16)
+    k_new = jnp.asarray(rng.normal(size=(ROWS64, N_KV64, D64)), jnp.bfloat16)
+    v_new = jnp.asarray(rng.normal(size=(ROWS64, N_KV64, D64)), jnp.bfloat16)
+    return pool, pt, jnp.asarray(lengths), q, k_new, v_new
+
+
+def test_cell64_paired_kernel_beside_the_xla_two_op_path():
+    """The fused write+attend kernel on the pool of paired 64-wide heads,
+    at the routed cell's shape: the rows of the XLA two-op path on the
+    logical pool (``write_tokens`` + the gather attention: what the cell
+    ran before), the same pool bytes after the append, and the time of
+    each, chained through q inside one executable with the pools in
+    place."""
+    import time
+
+    from test_pallas import pair_heads as _pair_heads
+
+    from llms_on_kubernetes_tpu.engine.cache import KVPool, write_tokens
+    from llms_on_kubernetes_tpu.ops.attention import paged_attention
+    from llms_on_kubernetes_tpu.ops.pallas_paged import (
+        pallas_paged_attention_write,
+    )
+
+    (kl, vl), pt, lengths, q, k_new, v_new = _cell64_case()
+    wp = jnp.where(lengths > 0, lengths - 1, -1)[:, None]
+    scale = D64 ** -0.5
+
+    @jax.jit
+    def two_op(q, kd, vd):
+        kp, vp = write_tokens(KVPool(kd), KVPool(vd), k_new[:, None],
+                              v_new[:, None], pt, wp)
+        return (paged_attention(q, kp, vp, pt, lengths, scale=scale),
+                kp.data, vp.data)
+
+    want, kr, vr = two_op(q, kl, vl)
+    kp, vp = _pair_heads(kl), _pair_heads(vl)
+    got, kd, vd = pallas_paged_attention_write(
+        q, kp, vp, pt, lengths, k_new, v_new, scale=scale)
+    _check_rows(got, want, lengths, 2e-2)
+    # (page 0 is the trash page the two-op path sends idle rows to)
+    np.testing.assert_array_equal(_f32(kd)[:, 1:],
+                                  _f32(_pair_heads(kr))[:, 1:])
+    np.testing.assert_array_equal(_f32(vd)[:, 1:],
+                                  _f32(_pair_heads(vr))[:, 1:])
+    assert (_f32(kd) != _f32(kp)).any()
+    del kr, vr, kd, vd
+
+    def time_us(step, args, n_calls):
+        @jax.jit
+        def chain(q, kd, vd):
+            def body(_, c):
+                o, kd, vd = step(*c)
+                return o.astype(q.dtype), kd, vd
+            return jax.lax.fori_loop(0, n_calls, body, (q, kd, vd))
+
+        best = float("inf")
+        for _ in range(4):                      # the first run compiles
+            t0 = time.perf_counter()
+            jax.block_until_ready(chain(*args))
+            best = min(best, time.perf_counter() - t0)
+        return best / n_calls * 1e6
+
+    fused = pallas_paged_attention_write.__wrapped__
+    live = int((np.asarray(lengths) > 0).sum())
+    tokens = int(np.asarray(lengths).sum())
+    said = {
+        "rows": ROWS64, "live_rows": live, "cached_tokens": tokens,
+        "paired_kernel_us": round(time_us(
+            lambda q, kd, vd: fused(q, kd, vd, pt, lengths, k_new, v_new,
+                                    scale=scale), (q, kp, vp), 256), 2),
+        "xla_two_op_us": round(time_us(
+            two_op.__wrapped__, (q, kl, vl), 32), 2),
+        # K and V of every cached token, once
+        "live_kv_bytes": tokens * N_KV64 * D64 * 2 * 2,
+    }
+    said["paired_kernel_hbm_share"] = round(
+        said["live_kv_bytes"] / 819e9 / (said["paired_kernel_us"] * 1e-6), 4)
+    _report("pr41_kernel_beside_two_op", said)
+    assert said["paired_kernel_us"] < said["xla_two_op_us"], said
+
+
+def test_cell64_greedy_streams_against_float32_reference(monkeypatch):
+    """64 greedy tokens on eight prompts (the golden file's three, each
+    also reversed, and the two shorter ones' first halves) at the cell's
+    nine layers: decoded with the paired kernel, and with the dispatcher's
+    gate shut (``write_tokens`` + the XLA gather attention, everything else
+    the same: the path the cell ran before). The yardstick is the float32
+    reference (benchmark/reference/lfm2_moe.py on the same bf16 weights),
+    fed each stream's own tokens: per step, the largest difference of the
+    log-probabilities of the reference's eight best ids, which is what the
+    benchmark's check compares at the first position."""
+    import json
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "benchmark"))
+    from reference import lfm2_moe as ref
+
+    from llms_on_kubernetes_tpu.configs import get_config
+    from llms_on_kubernetes_tpu.engine.cache import CacheConfig, init_pages
+    from llms_on_kubernetes_tpu.models import decoder as dec
+    from llms_on_kubernetes_tpu.ops import attention
+
+    cfg = get_config("lfm2-24b-a2b@0,3-10")
+    with open("benchmark/configs/lfm2-24b-a2b.json") as f:
+        ref_cfg = json.load(f)
+    with open("benchmark/golden/lfm2-24b-a2b.json") as f:
+        golden = json.load(f)
+    params = dec.init_params(cfg, jax.random.key(0), dtype="bfloat16")
+    texts = list(dict.fromkeys(p["content"] for p in golden["prompts"]))
+    texts = (texts + [t[::-1] for t in texts]
+             + [t[:len(t) // 2] for t in texts[:2]])
+    prompts = [[256] + list(f"<user>{t}</user>".encode()) for t in texts]
+    B, T, page, pps, N = len(prompts), 1024, 64, 32, 64
+    assert B == 8 and max(map(len, prompts)) + N <= T
+    cc = CacheConfig(num_layers=cfg.num_attn_layers,
+                     num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+                     num_pages=B * pps + 1, page_size=page, pages_per_slot=pps)
+    assert cc.pool_row == (4, 128)
+    pt = jnp.asarray(1 + np.arange(B * pps).reshape(B, pps), jnp.int32)
+    toks = np.zeros((B, T), np.int32)
+    for b, p in enumerate(prompts):
+        toks[b, :len(p)] = p
+    plen = np.asarray([len(p) for p in prompts], np.int32)
+    prefill = jax.jit(dec.forward_prefill, static_argnums=(1,),
+                      donate_argnums=(4, 5))
+    gate = attention._paged_kernel_mode
+
+    def stream(shut):
+        monkeypatch.setattr(
+            attention, "_paged_kernel_mode",
+            (lambda *a: (None, "shut by the test")) if shut else gate)
+        decode = jax.jit(
+            lambda p, *a, **kw: dec.forward_decode(p, cfg, *a, **kw),
+            donate_argnums=(3, 4))
+        kp, vp = init_pages(cc)
+        conv = dec.init_conv_state(cfg, B, "bfloat16")
+        first = []
+        for b in range(B):
+            logits, kp, vp, aux = prefill(
+                params, cfg, jnp.asarray(toks[b:b + 1]),
+                jnp.asarray(plen[b:b + 1]), kp, vp, pt[b:b + 1],
+                aux=dec.LayerAux(conv=conv,
+                                 slots=jnp.asarray([b], jnp.int32)))
+            conv = aux.conv
+            first.append(np.asarray(jax.nn.log_softmax(logits))[0])
+        lps = [np.stack(first)]
+        for n in range(1, N):
+            cur = jnp.asarray(lps[-1].argmax(-1), jnp.int32)
+            logits, kp, vp, aux = decode(
+                params, cur, jnp.asarray(plen + n), kp, vp, pt,
+                aux=dec.LayerAux(conv=conv))
+            conv = aux.conv
+            lps.append(np.asarray(jax.nn.log_softmax(logits)))
+        said = attention._chosen["decode"]
+        return np.stack(lps, 1), said                   # [B, N, V]
+
+    paired, said_paired = stream(False)
+    two_op, said_two_op = stream(True)
+    assert said_paired == (
+        "pallas-compiled",
+        "fused write+attend kernel, 2 heads of 64 to a 128-lane page row")
+    assert said_two_op == ("xla", "shut by the test")
+
+    def against_reference(lps):
+        rows = []
+        for b in range(B):
+            ids = lps[b].argmax(-1)                     # the stream's tokens
+            seq = np.zeros(T, np.int32)
+            seq[:plen[b]] = prompts[b]
+            seq[plen[b]:plen[b] + N] = ids
+            want = np.asarray(jax.nn.log_softmax(ref.logits_at(
+                ref_cfg, params, seq.tolist(),
+                list(range(plen[b] - 1, plen[b] - 1 + N)))))
+            best = np.argsort(want, -1)[:, -8:]
+            d = np.abs(np.take_along_axis(lps[b], best, -1)
+                       - np.take_along_axis(want, best, -1)).max(-1)   # [N]
+            rows.append({"prompt_tokens": int(plen[b]),
+                         "prefill_nats": float(d[0]),
+                         "decode_median_nats": float(np.median(d[1:])),
+                         "decode_max_nats": float(d[1:].max()),
+                         "same_top_id": int((ids == want.argmax(-1)).sum())})
+        return rows
+
+    said = {"tokens": N, "paired_kernel": against_reference(paired),
+            "xla_two_op": against_reference(two_op)}
+    parted = [np.nonzero(paired[b].argmax(-1) != two_op[b].argmax(-1))[0]
+              for b in range(B)]
+    said["first_parted_at"] = [int(p[0]) if p.size else None for p in parted]
+    _report("pr41_greedy_streams", said)
+    # position 0 is prefill's, the same executable on both sides
+    assert all(p != 0 for p in said["first_parted_at"])
+    tol = golden["tolerance"]["nats"]
+    for a, b in zip(said["paired_kernel"], said["xla_two_op"]):
+        assert a["prefill_nats"] < tol, said
+        # no further from the reference than the path it replaces
+        assert a["decode_median_nats"] <= max(
+            1.5 * b["decode_median_nats"], 0.1), said
