@@ -67,6 +67,32 @@ class ModelConfig:
     layer_types: Optional[tuple] = None
     conv_L_cache: int = 3                   # taps of the short convolution
     conv_bias: bool = False
+    # latent attention (DeepSeek MLA; ``kv_lora_rank`` > 0 makes every
+    # layer's operator "mla"): queries through a ``q_lora_rank`` bottleneck
+    # to ``num_heads`` heads of ``qk_nope_head_dim`` un-roped and
+    # ``qk_rope_head_dim`` roped dimensions (``head_dim`` is their sum);
+    # keys and values from ONE normalised ``kv_lora_rank``-wide latent a
+    # token plus one roped key shared by all heads, which is all the cache
+    # keeps (``latent_width`` values a token a layer); values
+    # ``v_head_dim`` wide
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # group-limited routing (DeepSeek-V3): the experts lie in ``n_group``
+    # groups of consecutive experts, and a token chooses among the
+    # ``topk_group`` groups whose two best selection scores sum highest
+    n_group: int = 1
+    topk_group: int = 1
+    # SwiGLU experts of ``expert_width`` that every token passes through,
+    # beside the routed ones (one network of n_shared_experts x the width)
+    n_shared_experts: int = 0
+    # one chip's share of an expert-parallel deployment: the expert stacks
+    # hold experts [first_expert, first_expert + experts_held) of the
+    # ``num_experts`` the router scores. None => every expert is held
+    experts_held: Optional[int] = None
+    first_expert: int = 0
     # activation / norm variants
     hidden_act: str = "silu"                # silu | gelu_tanh
     norm_style: str = "llama"               # llama: x*w ; gemma: x*(1+w)
@@ -107,10 +133,44 @@ class ModelConfig:
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches for one token: the normalised
+        latent and the shared roped key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """Dimensions of a head that are rotated."""
+        return self.qk_rope_head_dim if self.is_mla else self.head_dim
+
+    @property
+    def cache_row(self) -> tuple:
+        """(heads, width) of what one token keeps in one layer's pages: K
+        and V heads, or the one latent row of a latent layer (no V), padded
+        with zeros to whole 128-lane tiles (576 -> 640: the TPU's tiled
+        layout would pad a page's rows to that anyway, and a compiler left
+        to hide the padding re-lays the whole pool out around every
+        step)."""
+        if self.is_mla:
+            return 1, -(-self.latent_width // 128) * 128
+        return self.num_kv_heads, self.head_dim
+
+    @property
+    def num_held_experts(self) -> int:
+        """Experts whose weights are here (``experts_held``)."""
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
+
     def layer_kind(self, i: int) -> tuple:
-        """(operator, feed-forward) of layer ``i``: ("attn" | "conv",
-        "dense" | "moe")."""
-        op = ("conv" if self.layer_types is not None
+        """(operator, feed-forward) of layer ``i``: ("attn" | "conv" |
+        "mla", "dense" | "moe")."""
+        op = ("mla" if self.is_mla else "conv"
+              if self.layer_types is not None
               and self.layer_types[i] == "conv" else "attn")
         ff = "moe" if self.is_moe and i >= self.num_dense_layers else "dense"
         return op, ff
@@ -130,8 +190,9 @@ class ModelConfig:
 
     @property
     def num_attn_layers(self) -> int:
-        """Layers that keep keys and values: what the KV pool is sized by."""
-        return sum(n for op, _ff, _i, n in self.layer_runs if op == "attn")
+        """Layers that keep keys and values (or a latent row of them):
+        what the KV pool is sized by."""
+        return sum(n for op, _ff, _i, n in self.layer_runs if op != "conv")
 
     @property
     def num_conv_layers(self) -> int:
@@ -147,11 +208,19 @@ class ModelConfig:
         """Approximate parameter count (for memory budgeting)."""
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
         attn = d * self.q_dim * 2 + d * self.kv_dim * 2
+        if self.is_mla:
+            h = self.num_heads
+            attn = (d * self.q_lora_rank + self.q_lora_rank * h * self.head_dim
+                    + d * self.latent_width + self.kv_lora_rank * h
+                    * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
         conv = 4 * d * d + self.conv_L_cache * d
-        moe = (3 * d * self.expert_width + d) * self.num_experts
+        moe = (3 * d * self.expert_width * (self.num_held_experts
+                                            + self.n_shared_experts)
+               + d * self.num_experts)
         total = v * d * (1 if self.tie_word_embeddings else 2)
         for op, ff, _first, n in self.layer_runs:
-            total += n * ((attn if op == "attn" else conv)
+            total += n * ((conv if op == "conv" else attn)
                           + (moe if ff == "moe" else 3 * d * f))
         return total
 
@@ -392,6 +461,34 @@ _register(
     "LiquidAI/LFM2-24B-A2B",
 )
 
+# DeepSeek-V3: latent attention (MLA) in every layer, three leading dense
+# layers, then 256 sigmoid-scored experts in 8 groups (a token chooses 8
+# experts among its 4 best groups; a selection bias; the chosen scores
+# renormalised and scaled by 2.5) beside one shared expert; YaRN rope over
+# the 64 roped dimensions. The multi-token-prediction module of the
+# published checkpoint is a draft head and is not built.
+DEEPSEEK_YARN = {
+    "type": "yarn", "factor": 40, "original_max_position_embeddings": 4096,
+    "beta_fast": 32, "beta_slow": 1, "mscale": 1.0, "mscale_all_dim": 1.0,
+}
+_register(
+    ModelConfig(
+        "deepseek-v3",
+        vocab_size=129280, hidden_size=7168, intermediate_size=18432,
+        num_layers=61, num_heads=128, num_kv_heads=128, head_dim=192,
+        rope_theta=10000.0, rms_norm_eps=1e-6,
+        max_position_embeddings=163840, rope_scaling=DEEPSEEK_YARN,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        num_dense_layers=3, num_experts=256, num_experts_per_tok=8,
+        moe_intermediate_size=2048, moe_router="sigmoid",
+        use_expert_bias=True, norm_topk_prob=True, moe_renorm_eps=1e-20,
+        routed_scaling_factor=2.5, n_group=8, topk_group=4,
+        n_shared_experts=1,
+    ),
+    "deepseek-ai/DeepSeek-V3",
+)
+
 # Tiny configs for tests / local CPU smoke runs.
 _register(
     ModelConfig(
@@ -447,6 +544,31 @@ _register(
         use_expert_bias=True, norm_topk_prob=True, moe_renorm_eps=1e-6,
     ),
 )
+_register(
+    ModelConfig(
+        # deepseek-v3's mechanisms at a size the CPU tests hold: latent
+        # attention with both ranks and YaRN rope, two dense layers, then
+        # 8 experts in 2 groups (top-2 of the best group) beside a shared
+        # expert; a vocabulary wide enough to be sliced and still hold the
+        # byte tokenizer's 258 ids
+        # ("debug-deepseek@0,2-3+experts0-3+vocab0-259")
+        "debug-deepseek",
+        vocab_size=300, hidden_size=64, intermediate_size=128,
+        num_layers=4, num_heads=4, num_kv_heads=4, head_dim=24,
+        rope_theta=10000.0, rms_norm_eps=1e-6, max_position_embeddings=512,
+        rope_scaling={"type": "yarn", "factor": 4,
+                      "original_max_position_embeddings": 64,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1.0,
+                      "mscale_all_dim": 1.0},
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16,
+        num_dense_layers=2, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=48, moe_router="sigmoid",
+        use_expert_bias=True, norm_topk_prob=True, moe_renorm_eps=1e-20,
+        routed_scaling_factor=2.5, n_group=2, topk_group=1,
+        n_shared_experts=1,
+    ),
+)
 
 
 def _debug_mm() -> ModelConfig:
@@ -494,10 +616,11 @@ def cut_to_layers(cfg: ModelConfig, layers: tuple, name: str) -> ModelConfig:
     """``cfg`` with only the published ``layers`` (ascending indices): what
     ONE stage of a pipeline over depth holds. Every width stays; of the
     leading dense layers those among ``layers`` stay dense. Only for a
-    model whose layers are of several kinds (``layer_types``): its cut has
-    to keep whole periods of them, so it is named layer by layer; no other
-    entry can be served in part."""
-    if cfg.layer_types is None:
+    model whose stack is more than one run of layers of one kind (conv and
+    attention layers, or dense layers before expert layers): its cut has
+    to keep every kind, so it is named layer by layer; no other entry can
+    be served in part."""
+    if len(cfg.layer_runs) == 1:
         raise KeyError(f"{name!r}: {cfg.name} has one kind of layer and is "
                        f"served whole")
     if not layers or list(layers) != sorted(set(layers)) \
@@ -507,24 +630,65 @@ def cut_to_layers(cfg: ModelConfig, layers: tuple, name: str) -> ModelConfig:
     return dataclasses.replace(
         cfg, name=name, num_layers=len(layers),
         num_dense_layers=sum(1 for i in layers if i < cfg.num_dense_layers),
-        layer_types=tuple(cfg.layer_types[i] for i in layers))
+        layer_types=None if cfg.layer_types is None
+        else tuple(cfg.layer_types[i] for i in layers))
+
+
+def cut_to_share(cfg: ModelConfig, name: str, experts: "tuple | None" = None,
+                 vocab: "tuple | None" = None) -> ModelConfig:
+    """``cfg`` with ONE chip's share of each layer in a deployment that
+    divides a layer over several chips: the routed ``experts`` (first,
+    last) it holds, of the ``num_experts`` the router still scores, and the
+    rows (first, last) of the ``vocab``ulary. A sliced vocabulary is a
+    smaller vocabulary; a slice that starts past row 0 would matter to a
+    checkpoint alone, and none is loaded for such a model."""
+    if experts is not None:
+        lo, hi = experts
+        if not (cfg.is_moe and 0 <= lo <= hi < cfg.num_experts):
+            raise KeyError(f"{name!r}: experts must lie in 0-"
+                           f"{cfg.num_experts - 1} of a routed model")
+        cfg = dataclasses.replace(cfg, experts_held=hi - lo + 1,
+                                  first_expert=lo)
+    if vocab is not None:
+        lo, hi = vocab
+        if lo != 0 or not 0 < hi < cfg.vocab_size:
+            raise KeyError(f"{name!r}: a vocabulary slice is rows 0-<last> "
+                           f"below {cfg.vocab_size}")
+        cfg = dataclasses.replace(cfg, vocab_size=hi + 1)
+    return dataclasses.replace(cfg, name=name)
+
+
+def _span(text: str) -> tuple:
+    lo, _, hi = text.partition("-")
+    return int(lo), int(hi or lo)
 
 
 def get_config(name: str) -> ModelConfig:
-    """A registry entry by name or alias. ``<entry>@<layers>`` (``3-10``,
-    ``0,3-10``) is that entry cut to those published layers
+    """A registry entry by name or alias, or one chip's part of it:
+    ``<entry>@<layers>[+experts<a>-<b>][+vocab0-<b>]``. ``<layers>``
+    (``3-10``, ``0,3-10``) cuts the entry to those published layers
     (``cut_to_layers``): the depth one chip holds of a model that a
-    pipeline spreads over several."""
+    pipeline spreads over several. ``+experts0-15`` and ``+vocab0-16159``
+    give it this chip's share of each layer (``cut_to_share``):
+    ``deepseek-v3@0,3-7+experts0-15+vocab0-16159``."""
     base, at, spec = name.partition("@")
     if at:
+        layers, *shares = spec.split("+")
+        cfg = get_config(base)
         try:
-            layers = tuple(
-                i for part in spec.split(",")
-                for lo, _, hi in [part.partition("-")]
-                for i in range(int(lo), int(hi or lo) + 1))
-        except ValueError:
-            raise KeyError(f"{name!r}: layers are written 0,3-10") from None
-        return cut_to_layers(get_config(base), layers, name)
+            cfg = cut_to_layers(cfg, tuple(
+                i for part in layers.split(",")
+                for lo, hi in [_span(part)]
+                for i in range(lo, hi + 1)), name)
+            share = {}
+            for part in shares:
+                key = next(k for k in ("experts", "vocab")
+                           if part.startswith(k) and k not in share)
+                share[key] = _span(part[len(key):])
+        except (ValueError, StopIteration):
+            raise KeyError(f"{name!r}: a cut is written <entry>@0,3-10 or "
+                           f"<entry>@0,3-7+experts0-15+vocab0-16159") from None
+        return cut_to_share(cfg, name, **share)
     key = name if name in REGISTRY else ALIASES.get(name.lower(), name)
     if key not in REGISTRY:
         raise KeyError(
@@ -585,13 +749,18 @@ def from_hf_config(hf: dict | str, name: str = "hf-model") -> ModelConfig:
                     "non-interleaved (sectioned) mrope is not supported "
                     "yet; only mrope_interleaved=true")
             kw["mrope_section"] = tuple(int(x) for x in scaling["mrope_section"])
-        elif kind in ("llama3", "linear"):
+        elif kind in ("llama3", "linear") or (
+                kind == "yarn" and model_type == "deepseek_v3"):
             kw["rope_scaling"] = scaling
         elif kind is not None and kind != "default":
-            # fail fast: serving with a dropped scaling scheme (yarn,
-            # longrope, ...) silently produces wrong positions
+            # fail fast: serving with a dropped scaling scheme (longrope,
+            # dynamic, ...) silently produces wrong positions. Supported:
+            # llama3, linear, and yarn as deepseek_v3 applies it (on the
+            # frequencies and the softmax scale; ops/rope.py)
             raise NotImplementedError(
-                f"rope_scaling type {kind!r} is not supported yet"
+                f"rope_scaling type {kind!r} is not supported yet for "
+                f"model_type {model_type!r} (supported: llama3, linear, and "
+                f"yarn for deepseek_v3)"
             )
     if model_type in ("qwen2",):
         kw["attention_bias"] = True
@@ -658,6 +827,42 @@ def from_hf_config(hf: dict | str, name: str = "hf-model") -> ModelConfig:
         if kw["conv_bias"]:
             raise NotImplementedError(
                 "lfm2_moe with conv_bias=true is not supported")
+    if model_type == "deepseek_v3":
+        # the published keys (deepseek-ai/DeepSeek-V3 config.json). The
+        # multi-token-prediction module (num_nextn_predict_layers) is a
+        # draft head beside the model and is not built
+        if hf.get("scoring_func", "sigmoid") != "sigmoid" \
+                or hf.get("topk_method", "noaux_tc") != "noaux_tc" \
+                or int(hf.get("moe_layer_freq", 1)) != 1:
+            raise NotImplementedError(
+                "deepseek_v3 is served with sigmoid scores, noaux_tc "
+                "group-limited selection and experts in every layer past "
+                "first_k_dense_replace")
+        ys = kw.get("rope_scaling") or {}
+        if ys and float(ys.get("mscale", 1.0)) != float(
+                ys.get("mscale_all_dim", 0.0)):
+            raise NotImplementedError(
+                "yarn with mscale != mscale_all_dim (a factor on cos and "
+                "sin) is not supported")
+        nope, rope = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+        kw.update(
+            head_dim=nope + rope,
+            q_lora_rank=int(hf["q_lora_rank"]),
+            kv_lora_rank=int(hf["kv_lora_rank"]),
+            qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+            v_head_dim=int(hf["v_head_dim"]),
+            num_dense_layers=int(hf.get("first_k_dense_replace", 0)),
+            num_experts=int(hf["n_routed_experts"]),
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            moe_router="sigmoid", use_expert_bias=True,
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            moe_renorm_eps=1e-20,
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            n_group=int(hf.get("n_group", 1)),
+            topk_group=int(hf.get("topk_group", 1)),
+            n_shared_experts=int(hf.get("n_shared_experts") or 0),
+        )
     if hf.get("query_pre_attn_scalar") is not None:
         kw["query_pre_attn_scalar"] = float(hf["query_pre_attn_scalar"])
     if model_type.startswith("gemma"):

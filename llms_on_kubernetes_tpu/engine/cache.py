@@ -24,6 +24,15 @@ SURVEY §2.3); this is the TPU-native equivalent. Design:
   free), ``ops/attention._gather_pool`` un-pairs after its gather, and a
   page's payload (host tier, prefill/decode hand-over) is the pool's bytes
   in the pool's shape (``CacheConfig.pool_row``).
+- A LATENT pool (``CacheConfig.latent``: DeepSeek's MLA) keeps one row a
+  token a layer, the normalised latent and the shared roped key side by
+  side (576 values, then zeros up to 640: whole 128-lane tiles,
+  ``ModelConfig.cache_row``), from which both keys and values are
+  computed: the K pool is that one array, [1, L * P, page, 640], one
+  "head", and there is no V pool (``v_pages`` is a one-element
+  placeholder that rides the same arguments and is never read). ``write_latent`` writes it with the
+  in-place updates of ``write_tokens``; pages, tables and the allocator
+  are the same.
 
   Why flat instead of a leading [L, ...] axis: the layer loop is
   ``lax.scan``, and a pool that rides the scan as xs/ys gets its updated
@@ -75,6 +84,9 @@ class CacheConfig:
     # sharded (parallel/sharding.pool_sharding): heads pair into one row
     # only where every shard keeps whole pairs
     model_shards: int = 1
+    # one latent row a token a layer (num_kv_heads 1, head_dim its width)
+    # and no V side: see the module docstring
+    latent: bool = False
 
     @property
     def max_seq_len(self) -> int:
@@ -96,7 +108,8 @@ class CacheConfig:
         else:
             per_tok = (self.num_kv_heads * self.head_dim
                        * jnp.dtype(self.dtype).itemsize)
-        return 2 * self.num_layers * self.page_size * per_tok
+        sides = 1 if self.latent else 2
+        return sides * self.num_layers * self.page_size * per_tok
 
     @property
     def bytes_per_token(self) -> int:
@@ -185,6 +198,9 @@ def init_pages(cfg: CacheConfig, sharding=None) -> tuple[KVPool, KVPool]:
         raise ValueError(f"unsupported kv_dtype {cfg.kv_dtype!r} "
                          f"(None or 'int8')")
     dt = jnp.dtype(cfg.dtype)
+    if cfg.latent:
+        return (KVPool(jnp.zeros(shape, dt, device=sharding)),
+                KVPool(jnp.zeros((1, 1, 1, 1), dt)))
     return (KVPool(jnp.zeros(shape, dt, device=sharding)),
             KVPool(jnp.zeros(shape, dt, device=sharding)))
 
@@ -274,16 +290,15 @@ def _scatter_decode_writes() -> bool:
     return _active_kv_write in ("scatter", "scatter-linear")
 
 
-def _write_decode_scatter(kd, vd, ksc, vsc, k, v, ks, vs, pid, off, pos,
-                          owner, dt):
-    """One scatter per side for the whole decode batch.
+def _write_decode_scatter(pools, rows, scales, pid, off, pos, owner):
+    """One scatter per pool for the whole decode batch.
 
     Indices are UNIQUE by construction (each active slot appends into its
     own page; rows to drop get pid = pool_size + row, distinct and out of
     range so mode="drop" discards them without breaking the uniqueness
     promise)."""
     B = pid.shape[0]
-    total = kd.shape[1]
+    total = pools[0].data.shape[1]
     oob = total + jnp.arange(B, dtype=pid.dtype)
     drop = pos < 0
     if owner is not None:
@@ -292,27 +307,25 @@ def _write_decode_scatter(kd, vd, ksc, vsc, k, v, ks, vs, pid, off, pos,
         drop = drop | (lpid < 0) | (lpid >= width)
         pid = lpid
     pid = jnp.where(drop, oob, pid)
-    kh = jnp.moveaxis(k[:, 0].astype(dt), 1, 0)        # [n_kv, B, d]
-    vh = jnp.moveaxis(v[:, 0].astype(dt), 1, 0)
-    if _active_kv_write == "scatter-linear":
-        # single-dim scatter on a [n_kv, flat*page, d] view: one index
-        # vector, simplest possible lowering
-        page = kd.shape[2]
-        lin = pid * page + off
-        n_kv, total_p, _, d = kd.shape
-        kd = kd.reshape(n_kv, total_p * page, d).at[:, lin].set(
-            kh, unique_indices=True, mode="drop").reshape(kd.shape)
-        vd = vd.reshape(n_kv, total_p * page, d).at[:, lin].set(
-            vh, unique_indices=True, mode="drop").reshape(vd.shape)
-    else:
-        kd = kd.at[:, pid, off].set(kh, unique_indices=True, mode="drop")
-        vd = vd.at[:, pid, off].set(vh, unique_indices=True, mode="drop")
-    if ks is not None:
-        ksc = ksc.at[:, pid, off].set(ks[:, 0].T, unique_indices=True,
-                                      mode="drop")
-        vsc = vsc.at[:, pid, off].set(vs[:, 0].T, unique_indices=True,
-                                      mode="drop")
-    return KVPool(kd, ksc), KVPool(vd, vsc)
+    out = []
+    for pool, r, sc in zip(pools, rows, scales):
+        data, scale = pool.data, pool.scale
+        rh = jnp.moveaxis(r[:, 0], 1, 0)                # [n, B, d]
+        if _active_kv_write == "scatter-linear":
+            # single-dim scatter on a [n, flat*page, d] view: one index
+            # vector, simplest possible lowering
+            n, total_p, page, d = data.shape
+            data = data.reshape(n, total_p * page, d).at[
+                :, pid * page + off].set(
+                rh, unique_indices=True, mode="drop").reshape(data.shape)
+        else:
+            data = data.at[:, pid, off].set(rh, unique_indices=True,
+                                            mode="drop")
+        if sc is not None:
+            scale = scale.at[:, pid, off].set(sc[:, 0].T, unique_indices=True,
+                                              mode="drop")
+        out.append(KVPool(data, scale))
+    return out
 
 
 def write_tokens(
@@ -339,6 +352,35 @@ def write_tokens(
                      decode writes one token, prefill/chunk write a
                      front-packed chunk.
 
+    The update itself is :func:`_write_rows`', one implementation for
+    every pool (a latent pool's too: :func:`write_latent`).
+    """
+    B, T = k.shape[:2]
+    n_kv, _, _, d = k_pages.shape
+    k_pages, v_pages = _write_rows(
+        [k_pages, v_pages],
+        [k.reshape(B, T, n_kv, d), v.reshape(B, T, n_kv, d)],
+        page_table, positions, owner)
+    return k_pages, v_pages
+
+
+def write_latent(pool: "KVPool", rows: jnp.ndarray, page_table: jnp.ndarray,
+                 positions: jnp.ndarray) -> "KVPool":
+    """Write one layer's new latent rows [B, T, w] into a latent pool
+    [1, P_total, page, width] IN PLACE (w <= width: the rest of a pool
+    row is zeros, written as zeros): ``write_tokens``' update of one pool
+    with one "head". ``page_table`` and ``positions`` as there."""
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pool.shape[3] - rows.shape[2])))
+    return _write_rows([pool], [rows[:, :, None, :]], page_table, positions)[0]
+
+
+def _write_rows(pools: list, rows: list, page_table: jnp.ndarray,
+                positions: jnp.ndarray,
+                owner: "Optional[tuple]" = None) -> list:
+    """Write ``rows[i]`` [B, T, n, d] into ``pools[i]`` (data [n, P_total,
+    page, d]) IN PLACE, for pools that share a page table: K and V, or a
+    latent pool alone.
+
     Quantized pools write int8 data + per-token scale with the same DUS
     pattern (the quantization is per token, so an append never has to
     rescale previously written tokens).
@@ -347,7 +389,7 @@ def write_tokens(
     multi-GB pool in place — it materializes a full copy per call — and a
     pool riding a lax.scan/while carry pays a boundary copy too. So this
     uses ``dynamic_update_slice`` exclusively (verified in-place under
-    donation): one [n_kv, 1, 1, d] DUS per slot for decode (T==1), and a
+    donation): one [n, 1, 1, d] DUS per slot for decode (T==1), and a
     read-merge-write of each touched page for chunked writes. Callers must
     keep the layer loop UNROLLED (see decoder._run_layers) so no while
     loop ever carries the pool.
@@ -358,24 +400,22 @@ def write_tokens(
     non-owned updates become read-merge no-ops (a blind DUS at a clamped
     local slot would corrupt a page another sequence owns there).
     """
-    B, T = k.shape[:2]
-    n_kv, _, page, d = k_pages.shape
-    k, v = k.reshape(B, T, n_kv, d), v.reshape(B, T, n_kv, d)
+    B, T = rows[0].shape[:2]
+    page = pools[0].shape[2]
     pps = page_table.shape[1]
-    quant = k_pages.quantized
-    if quant:
-        kq, ks = quantize_kv(k)   # [B, T, n_kv] scale
-        vq, vs = quantize_kv(v)
-        k, v = kq, vq
-        dt = jnp.int8
-    else:
-        ks = vs = None
-        dt = k_pages.dtype
-    kd, vd = k_pages.data, v_pages.data
-    ksc, vsc = k_pages.scale, v_pages.scale
+    scales: list = []
+    for i, pool in enumerate(pools):
+        if pool.quantized:
+            rows[i], sc = quantize_kv(rows[i])          # [B, T, n] scale
+            scales.append(sc)
+        else:
+            rows[i] = rows[i].astype(pool.dtype)
+            scales.append(None)
+    datas = [pool.data for pool in pools]
+    dscales = [pool.scale for pool in pools]
 
     def rewrap():
-        return (KVPool(kd, ksc), KVPool(vd, vsc))
+        return [KVPool(d, sc) for d, sc in zip(datas, dscales)]
 
     if T == 1:
         pos = positions[:, 0]
@@ -386,8 +426,8 @@ def write_tokens(
         pid = jnp.where(pos < 0, 0, pid)
         off = jnp.where(pos < 0, 0, safe % page)
         if _scatter_decode_writes():
-            return _write_decode_scatter(
-                kd, vd, ksc, vsc, k, v, ks, vs, pid, off, pos, owner, dt)
+            return _write_decode_scatter(pools, rows, scales, pid, off, pos,
+                                         owner)
         owned = None
         if owner is not None:
             base, width = owner
@@ -404,31 +444,22 @@ def write_tokens(
         # Pallas write kernel of its own was bit-exact but made the step's
         # Mosaic compile blow up at B=64).
         for b in range(B):
-            upd_k = k[b, 0].astype(dt)[:, None, None, :]   # [n_kv, 1, 1, d]
-            upd_v = v[b, 0].astype(dt)[:, None, None, :]
-            if owned is not None:  # CP: non-owner preserves the old value
-                old_k = jax.lax.dynamic_slice(
-                    kd, (0, pid[b], off[b], 0), (kd.shape[0], 1, 1, d))
-                old_v = jax.lax.dynamic_slice(
-                    vd, (0, pid[b], off[b], 0), (vd.shape[0], 1, 1, d))
-                upd_k = jnp.where(owned[b], upd_k, old_k)
-                upd_v = jnp.where(owned[b], upd_v, old_v)
-            kd = jax.lax.dynamic_update_slice(kd, upd_k, (0, pid[b], off[b], 0))
-            vd = jax.lax.dynamic_update_slice(vd, upd_v, (0, pid[b], off[b], 0))
-            if quant:
-                upd_ks = ks[b, 0][:, None, None]
-                upd_vs = vs[b, 0][:, None, None]
+            for i, r in enumerate(rows):
+                at = (0, pid[b], off[b], 0)
+                upd = r[b, 0][:, None, None, :]             # [n, 1, 1, d]
+                if owned is not None:  # CP: non-owner preserves the old value
+                    upd = jnp.where(owned[b], upd, jax.lax.dynamic_slice(
+                        datas[i], at, upd.shape))
+                datas[i] = jax.lax.dynamic_update_slice(datas[i], upd, at)
+            for i, sc in enumerate(scales):
+                if sc is None:
+                    continue
+                at = (0, pid[b], off[b])
+                upd = sc[b, 0][:, None, None]
                 if owned is not None:
-                    old_ks = jax.lax.dynamic_slice(
-                        ksc, (0, pid[b], off[b]), (ksc.shape[0], 1, 1))
-                    old_vs = jax.lax.dynamic_slice(
-                        vsc, (0, pid[b], off[b]), (vsc.shape[0], 1, 1))
-                    upd_ks = jnp.where(owned[b], upd_ks, old_ks)
-                    upd_vs = jnp.where(owned[b], upd_vs, old_vs)
-                ksc = jax.lax.dynamic_update_slice(
-                    ksc, upd_ks, (0, pid[b], off[b]))
-                vsc = jax.lax.dynamic_update_slice(
-                    vsc, upd_vs, (0, pid[b], off[b]))
+                    upd = jnp.where(owned[b], upd, jax.lax.dynamic_slice(
+                        dscales[i], at, upd.shape))
+                dscales[i] = jax.lax.dynamic_update_slice(dscales[i], upd, at)
         return rewrap()
 
     n_touch = (T - 1) // page + 2  # max pages a T-token contiguous run spans
@@ -438,8 +469,7 @@ def write_tokens(
                 "context-parallel writes require the RMW page path; this "
                 f"chunk touches {n_touch} pages > {_MAX_RMW_PAGES} "
                 "(use a larger page_size or smaller prefill buckets)")
-        return _write_tokens_scatter(k_pages, v_pages, k, v, ks, vs,
-                                     page_table, positions)
+        return _write_rows_scatter(pools, rows, scales, page_table, positions)
 
     valid = positions >= 0                       # [B, T]
     # rows are front-packed: entry 0 is the first (lowest) position, or -1
@@ -448,8 +478,6 @@ def write_tokens(
     base_lg = pos0 // page
     page_iota = jnp.arange(page, dtype=jnp.int32)
     for b in range(B):
-        kb = k[b].astype(dt)                     # [T, n_kv, d]
-        vb = v[b].astype(dt)
         for j in range(n_touch):
             lg = base_lg[b] + j
             lg_c = jnp.clip(lg, 0, pps - 1)
@@ -464,11 +492,7 @@ def write_tokens(
             page_pos = lg * page + page_iota     # global positions [page]
             t_idx = page_pos - pos0[b]
             t_c = jnp.clip(t_idx, 0, T - 1)
-            new_k = jnp.take(kb, t_c, axis=0).transpose(1, 0, 2)  # [n_kv, page, d]
-            new_v = jnp.take(vb, t_c, axis=0).transpose(1, 0, 2)
-            if quant:
-                new_ks = jnp.take(ks[b], t_c, axis=0).T  # [n_kv, page]
-                new_vs = jnp.take(vs[b], t_c, axis=0).T
+            mask = None
             if j == 0 or own is not None:
                 # head page may hold a PREVIOUS chunk's tokens below pos0:
                 # read-merge-write. Every later page is append-territory —
@@ -482,54 +506,47 @@ def write_tokens(
                 mask = in_chunk & valid[b, t_c]  # [page]
                 if own is not None:
                     mask = mask & own
-                cur_k = jax.lax.dynamic_slice(
-                    kd, (0, pid, 0, 0), (n_kv, 1, page, d))[:, 0]
-                cur_v = jax.lax.dynamic_slice(
-                    vd, (0, pid, 0, 0), (n_kv, 1, page, d))[:, 0]
-                m = mask[None, :, None]
-                new_k = jnp.where(m, new_k, cur_k)
-                new_v = jnp.where(m, new_v, cur_v)
-                if quant:
-                    cur_ks = jax.lax.dynamic_slice(
-                        ksc, (0, pid, 0), (n_kv, 1, page))[:, 0]
-                    cur_vs = jax.lax.dynamic_slice(
-                        vsc, (0, pid, 0), (n_kv, 1, page))[:, 0]
-                    new_ks = jnp.where(mask[None, :], new_ks, cur_ks)
-                    new_vs = jnp.where(mask[None, :], new_vs, cur_vs)
-            kd = jax.lax.dynamic_update_slice(kd, new_k[:, None], (0, pid, 0, 0))
-            vd = jax.lax.dynamic_update_slice(vd, new_v[:, None], (0, pid, 0, 0))
-            if quant:
-                ksc = jax.lax.dynamic_update_slice(
-                    ksc, new_ks[:, None], (0, pid, 0))
-                vsc = jax.lax.dynamic_update_slice(
-                    vsc, new_vs[:, None], (0, pid, 0))
+            for i, r in enumerate(rows):
+                n, d = r.shape[2:]
+                new = jnp.take(r[b], t_c, axis=0).transpose(1, 0, 2)  # [n, page, d]
+                if mask is not None:
+                    cur = jax.lax.dynamic_slice(
+                        datas[i], (0, pid, 0, 0), (n, 1, page, d))[:, 0]
+                    new = jnp.where(mask[None, :, None], new, cur)
+                datas[i] = jax.lax.dynamic_update_slice(
+                    datas[i], new[:, None], (0, pid, 0, 0))
+                if scales[i] is None:
+                    continue
+                new = jnp.take(scales[i][b], t_c, axis=0).T       # [n, page]
+                if mask is not None:
+                    cur = jax.lax.dynamic_slice(
+                        dscales[i], (0, pid, 0), (n, 1, page))[:, 0]
+                    new = jnp.where(mask[None, :], new, cur)
+                dscales[i] = jax.lax.dynamic_update_slice(
+                    dscales[i], new[:, None], (0, pid, 0))
     return rewrap()
 
 
-def _write_tokens_scatter(k_pages, v_pages, k, v, ks, vs, page_table,
-                          positions):
+def _write_rows_scatter(pools, rows, scales, page_table, positions):
     """HLO-scatter fallback for huge chunks (costs one pool copy)."""
-    page = k_pages.shape[2]
+    page = pools[0].shape[2]
     trash = positions < 0
     pos = jnp.where(trash, 0, positions)
     logical_page = pos // page                                   # [B, T]
     page_ids = jnp.take_along_axis(page_table, logical_page, axis=1)
     page_ids = jnp.where(trash, 0, page_ids)
     offs = pos % page
-    # adjacent advanced indices on dims (1, 2): result [n_kv, B, T, d]
-    kh = jnp.moveaxis(k, 2, 0)
-    vh = jnp.moveaxis(v, 2, 0)
-    kd = k_pages.data.at[:, page_ids, offs].set(
-        kh.astype(k_pages.dtype), mode="drop")
-    vd = v_pages.data.at[:, page_ids, offs].set(
-        vh.astype(v_pages.dtype), mode="drop")
-    ksc, vsc = k_pages.scale, v_pages.scale
-    if k_pages.quantized:
-        ksc = ksc.at[:, page_ids, offs].set(
-            jnp.moveaxis(ks, 2, 0), mode="drop")
-        vsc = vsc.at[:, page_ids, offs].set(
-            jnp.moveaxis(vs, 2, 0), mode="drop")
-    return KVPool(kd, ksc), KVPool(vd, vsc)
+    out = []
+    for pool, r, sc in zip(pools, rows, scales):
+        # adjacent advanced indices on dims (1, 2): result [n, B, T, d]
+        data = pool.data.at[:, page_ids, offs].set(
+            jnp.moveaxis(r, 2, 0), mode="drop")
+        scale = pool.scale
+        if sc is not None:
+            scale = scale.at[:, page_ids, offs].set(
+                jnp.moveaxis(sc, 2, 0), mode="drop")
+        out.append(KVPool(data, scale))
+    return out
 
 
 class PageAllocator:

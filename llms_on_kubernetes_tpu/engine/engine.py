@@ -585,6 +585,21 @@ class InflightStep:
     dseq: int = -1                         # its dispatch record (ledger)
 
 
+@dataclasses.dataclass
+class ChunkChain:
+    """A prompt on the chunk path, part-way (async scheduling): one chunk
+    is launched a ``step()``, so a decode window, and a bucket's waiting
+    prompts every other turn, run between two chunks of it."""
+    slot: int
+    req: Request
+    tokens: list[int]                      # the whole prefill stream
+    start: int                             # where the chain began (adopted prefix)
+    pos: int                               # the next chunk's first position
+    resumed: bool
+    yielded: bool = False                  # prompts were let through since
+    #                                        its newest chunk
+
+
 class _Harvester(threading.Thread):
     """Off-thread device->host reader for async scheduling.
 
@@ -1348,8 +1363,12 @@ def _refuse_what_runs_cannot(cfg, ec, mesh, model_dir) -> None:
     seeded weights, by the plain decode window. Every feature that reads
     ``params["layers"]`` as one dict, or that moves KV pages without the
     conv state at their boundary, refuses such a model here, at start-up,
-    rather than answer wrongly."""
-    if len(cfg.layer_runs) == 1:
+    rather than answer wrongly. So does a model with latent attention
+    (its seeded tree is a tuple of runs whatever their number, and its
+    pool is one latent row a token with no V side), which also refuses an
+    int8 pool: the quantized write and the int8 kernels know K and V
+    heads."""
+    if len(cfg.layer_runs) == 1 and not cfg.is_mla:
         return
     asked = [
         ("a checkpoint (no tensor names of this family are mapped: serve "
@@ -1364,12 +1383,16 @@ def _refuse_what_runs_cannot(cfg, ec, mesh, model_dir) -> None:
          "conv state)", bool(ec.kv_host_cache_gb)),
         ("a prefill or decode role (the handoff moves KV pages alone)",
          ec.role not in (None, "both")),
+        ("an int8 KV cache (kv_cache_dtype: a latent row has no K and V "
+         "heads to scale)", cfg.is_mla and ec.kv_cache_dtype is not None),
     ]
     bad = [what for what, on in asked if on]
     if bad:
-        raise ValueError(
-            f"{cfg.name}: a stack of {len(cfg.layer_runs)} runs of layers "
-            f"of different kinds does not support: " + "; ".join(bad))
+        what = ("latent attention over a latent pool" if cfg.is_mla else
+                f"a stack of {len(cfg.layer_runs)} runs of layers of "
+                f"different kinds")
+        raise ValueError(f"{cfg.name}: {what} does not support: "
+                         + "; ".join(bad))
 
 
 class Engine:
@@ -1445,16 +1468,19 @@ class Engine:
                 self.params = init_params(
                     cfg, jax.random.key(engine_config.seed),
                     dtype=engine_config.dtype)
-            if mesh is not None and len(cfg.layer_runs) == 1:
+            if mesh is not None and len(cfg.layer_runs) == 1 \
+                    and not cfg.is_mla:
                 # (a stack of several runs is served on one device, where
                 # there is nothing to shard: _refuse_what_runs_cannot)
                 from llms_on_kubernetes_tpu.parallel.sharding import shard_params
                 self.params = shard_params(self.params, cfg, mesh)
 
+        cache_heads, cache_width = cfg.cache_row
         self.cache_config = CacheConfig(
             num_layers=cfg.num_attn_layers,
-            num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.head_dim,
+            num_kv_heads=cache_heads,
+            head_dim=cache_width,
+            latent=cfg.is_mla,
             num_pages=engine_config.num_pages,
             page_size=engine_config.page_size,
             pages_per_slot=engine_config.pages_per_slot,
@@ -1548,6 +1574,10 @@ class Engine:
         self.moe_stats = {kind: dict.fromkeys(MOE_STATS, 0.0)
                           for kind in ("prefill", "chunk", "decode")}
         self.moe_last: Optional[dict] = None   # /debug/engine "experts"
+        # prompt tokens each attention path took (a bucket's own rows, or
+        # a chunk over cached ones); with decode_tokens, what
+        # llm_mla_tokens_total{path} says of a latent model
+        self.path_tokens = {"prefill": 0, "chunk": 0}
         # fused multi-step decode accounting (metrics + bench):
         self.decode_dispatches = 0   # decode device dispatches
         self.decode_tokens = 0       # tokens committed to streams by decode
@@ -1656,11 +1686,15 @@ class Engine:
         # async scheduling state (see EngineConfig.async_scheduling)
         self._async = bool(engine_config.async_scheduling)
         self._inflight: "collections.deque[InflightStep]" = collections.deque()
-        # (request, harvester key, row) awaiting a first-token read
+        # (request, harvester key, row) awaiting a first-token read; row -1:
+        # the read of a chunk that is not its chain's last, which carries
+        # no token and is made for its completion time alone
         self._pending_first: list[tuple[Request, int, int]] = []
         # harvester key of a first-token read -> (kind of its dispatch, its
         # sample rows): what _book_moe needs when the read is consumed
         self._first_reads: dict = {}
+        # the one chunked prompt under way (ChunkChain), or None
+        self._chain: Optional[ChunkChain] = None
         self._seq_counter = iter(range(2 ** 62))     # decode steps (dense)
         # set by submit(): breaks the backpressure wait so admission (and
         # the new request's prefill dispatch) never waits out a read
@@ -2353,74 +2387,132 @@ class Engine:
         positions' KV is already in the slot's pages). The slot's pages
         for the WHOLE prompt are already allocated/adopted. Pure dispatch:
         each chunk chains on the previous through the donated page pool —
-        no host read here, so the async pipeline stays full. Returns the
-        FINAL chunk's (packed result, device tokens) pair (row 0 is the
-        request's first generated token) and the seq of the chain's one
-        dispatch record (only the final chunk is ever read, so the host
-        can time the chain, not its links)."""
+        no host read here. Returns the FINAL chunk's (packed result,
+        device tokens) pair (row 0 is the request's first generated token)
+        and the seq of the chain's one dispatch record. The synchronous
+        scheduler's: the pipelined one launches a chunk a ``step()``
+        (:meth:`_next_chunk`)."""
         n = len(prefill_tokens)
         step = max(self.config.prefill_buckets)
         chunks = -(-(n - start) // step)
         with self._dispatch("chunk", "_chunk_packed_step",
                             f"{chunks}x{self._bucket_for(min(step, n - start))}",
                             led_rows) as dseq:
-            pack, toks = self._chunk_chain(slot, req, prefill_tokens, start)
+            pos = start
+            while pos < n:
+                pack, toks, m = self._launch_chunk(
+                    slot, req, prefill_tokens, pos, start)
+                pos += m
         self.slot_len[slot] = n
         return pack, toks, dseq
 
-    def _chunk_chain(self, slot: int, req: Request,
-                     prefill_tokens: list[int], start: int):
+    def _launch_chunk(self, slot: int, req: Request,
+                      prefill_tokens: list[int], pos: int, start: int):
+        """Launch the chunk of ``prefill_tokens`` that begins at ``pos``
+        (inside a dispatch record). Returns its packed result, its device
+        tokens and how many tokens it took."""
         from llms_on_kubernetes_tpu.engine.multihost import MSG_CHUNK
 
         n = len(prefill_tokens)
-        step = max(self.config.prefill_buckets)
         pps = self.allocator.pages_per_slot
-        pack = toks = None
-        pos = start
-        while pos < n:
-            m = min(step, n - pos)
-            bucket = self._bucket_for(m)
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :m] = prefill_tokens[pos:pos + m]
-            packed = np.zeros((1, _CHK_COLS + pps), np.int32)
-            packed[0, 0] = m
-            packed[0, 1] = pos
-            packed[0, 2] = req.params.top_k
-            packed[0, 3] = np.float32(req.params.temperature).view(np.int32)
-            packed[0, 4] = np.float32(req.params.top_p).view(np.int32)
-            packed[0, 5] = req.seed
-            packed[0, 6] = np.float32(req.params.presence_penalty).view(np.int32)
-            packed[0, 7] = np.float32(req.params.frequency_penalty).view(np.int32)
-            packed[0, 8] = slot
-            packed[0, 9] = len(req.prompt)
-            packed[0, 10] = 1 if pos == start else 0  # first chunk: reset counts
-            packed[0, 11] = req.mrope_delta
-            packed[0, _ADP_CHK] = req.adapter_slot
-            # only the FINAL chunk's sample is the request's first real
-            # token; earlier chunks (and every chunk of a resumed
-            # request) sample discarded tokens unconstrained
-            final = pos + m >= n
-            if final and req.fsm_row >= 0 and not req.output:
-                packed[0, _FSM_CHK] = req.fsm_row
-                packed[0, _FSM_CHK + 1] = req.fsm_start
-            else:
-                packed[0, _FSM_CHK:_FSM_CHK + 2] = -1
-            use_fsm = packed[0, _FSM_CHK] >= 0
-            _pack_bias(packed, 0, _BIAS_CHK, req.params)
-            packed[0, _CHK_COLS:] = self.allocator.page_tables[slot]
-            self._mh_send(MSG_CHUNK, pre_tokens=tokens, pre_packed=packed,
-                          fsm_used=use_fsm)
-            (pack, toks, self.k_pages, self.v_pages, self.token_counts,
-             new_state, self.conv_state) = self._chunk_packed(
-                self.params, self.model_config, jnp.asarray(tokens),
-                jnp.asarray(packed), self.k_pages, self.v_pages,
-                self.token_counts, self._key,
-                self._fsm_args() if use_fsm else None, self.conv_state,
-            )
-            if new_state is not None:
-                self._fsm_state = new_state
-            pos += m
-        return pack, toks
+        m = min(max(self.config.prefill_buckets), n - pos)
+        bucket = self._bucket_for(m)
+        tokens = np.zeros((1, bucket), np.int32)
+        tokens[0, :m] = prefill_tokens[pos:pos + m]
+        packed = np.zeros((1, _CHK_COLS + pps), np.int32)
+        packed[0, 0] = m
+        packed[0, 1] = pos
+        packed[0, 2] = req.params.top_k
+        packed[0, 3] = np.float32(req.params.temperature).view(np.int32)
+        packed[0, 4] = np.float32(req.params.top_p).view(np.int32)
+        packed[0, 5] = req.seed
+        packed[0, 6] = np.float32(req.params.presence_penalty).view(np.int32)
+        packed[0, 7] = np.float32(req.params.frequency_penalty).view(np.int32)
+        packed[0, 8] = slot
+        packed[0, 9] = len(req.prompt)
+        packed[0, 10] = 1 if pos == start else 0  # first chunk: reset counts
+        packed[0, 11] = req.mrope_delta
+        packed[0, _ADP_CHK] = req.adapter_slot
+        # only the FINAL chunk's sample is the request's first real
+        # token; earlier chunks (and every chunk of a resumed
+        # request) sample discarded tokens unconstrained
+        final = pos + m >= n
+        if final and req.fsm_row >= 0 and not req.output:
+            packed[0, _FSM_CHK] = req.fsm_row
+            packed[0, _FSM_CHK + 1] = req.fsm_start
+        else:
+            packed[0, _FSM_CHK:_FSM_CHK + 2] = -1
+        use_fsm = packed[0, _FSM_CHK] >= 0
+        _pack_bias(packed, 0, _BIAS_CHK, req.params)
+        packed[0, _CHK_COLS:] = self.allocator.page_tables[slot]
+        self._mh_send(MSG_CHUNK, pre_tokens=tokens, pre_packed=packed,
+                      fsm_used=use_fsm)
+        self.path_tokens["chunk"] += m
+        (pack, toks, self.k_pages, self.v_pages, self.token_counts,
+         new_state, self.conv_state) = self._chunk_packed(
+            self.params, self.model_config, jnp.asarray(tokens),
+            jnp.asarray(packed), self.k_pages, self.v_pages,
+            self.token_counts, self._key,
+            self._fsm_args() if use_fsm else None, self.conv_state,
+        )
+        if new_state is not None:
+            self._fsm_state = new_state
+        return pack, toks, m
+
+    def _next_chunk(self) -> dict:
+        """Launch the next chunk of the prompt under way, ONE a ``step()``
+        and each a dispatch record of its own, so that the decode window
+        the step launches behind it (an admission's) runs before the
+        chunk after it: a stream waits behind a chunk, not behind a chain
+        of them. Each chunk is read, for the time it completed (a record
+        nobody reads would take the window behind it into its segment);
+        the last one's read carries the first token. Returns the
+        admission for the decode launch's merge: no slot before the last
+        chunk."""
+        ch = self._chain
+        ch.yielded = False
+        slot, req = ch.slot, ch.req
+        n = len(ch.tokens)
+        m = min(max(self.config.prefill_buckets), n - ch.pos)
+        with self._dispatch("chunk", "_chunk_packed_step",
+                            f"1x{self._bucket_for(m)}",
+                            [(req, "prefill", m)]) as dseq:
+            pack, toks, m = self._launch_chunk(
+                slot, req, ch.tokens, ch.pos, ch.start)
+        ch.pos += m
+        # what is written so far (a preemption spills no more than that)
+        self.slot_len[slot] = ch.pos
+        if ch.pos < n:
+            key = -1 - dseq
+            self._harvester.push(key, pack)
+            self._first_reads[key] = ("chunk", 1)
+            self._pending_first.append((req, key, -1))
+            return {"toks": toks, "slots": {}}
+        self._chain = None
+        return self._lone_admission(slot, req, ch.resumed, pack, toks, dseq)
+
+    def _lone_admission(self, slot: int, req: Request, resumed: bool,
+                        pack, toks, dseq: int) -> dict:
+        """The last (or only) dispatch of a prompt that runs alone is
+        launched: register its prefix and queue its first-token read.
+        Returns the admission for the decode launch's merge."""
+        if req.cache_salt is not None:
+            self.allocator.register_prefix(slot, req.prompt,
+                                           salt=req.cache_salt)
+        merge = {"toks": toks, "slots": {}}
+        if resumed:
+            # no first-token read: the host knows the re-prefill done
+            # only when the decode step launched behind it is read
+            req.pending_token = req.output[-1]
+            merge["slots"][slot] = (True, req.output[-1], 0)
+            self.timeline.close(dseq, None)
+        else:
+            key = -1 - dseq
+            self._harvester.push(key, pack)
+            self._first_reads[key] = ("chunk", 1)
+            merge["slots"][slot] = (False, 0, 0)
+            self._pending_first.append((req, key, 0))
+        return merge
 
     def _cache_salt_for(self, images) -> Optional[bytes]:
         """Prefix-cache digest salt, computed ONCE at submit (a blocked
@@ -2956,6 +3048,7 @@ class Engine:
             use_fsm = packed[0, _FSM_PRE] >= 0
             self._mh_send(MSG_PREFILL, pre_tokens=tokens, pre_packed=packed,
                           fsm_used=use_fsm)
+            self.path_tokens["prefill"] += n
             with self._dispatch("prefill", "_prefill_packed_step",
                                 f"1x{bucket}", led_rows) as dseq:
                 (pack, toks, self.k_pages, self.v_pages, self.token_counts,
@@ -3081,6 +3174,7 @@ class Engine:
         self._inflight.clear()
         self._pending_first = []
         self._first_reads.clear()
+        self._chain = None
         self.timeline.abandon()   # their reads will never come
         return events
 
@@ -3295,8 +3389,11 @@ class Engine:
     def _admit_async(self, events: list[StepEvent]):
         """Admission without host sync: prefill up to admit_batch waiting
         same-bucket requests in ONE padded call; first-token reads are
-        deferred to _harvest. Returns None or a dict describing the
-        admissions for the decode launch's on-device token merge."""
+        deferred to _harvest. A prompt of the chunk path (past the largest
+        bucket, or behind a cached prefix) is admitted alone and written
+        ONE CHUNK a call (_next_chunk), the bucket path taking every other
+        turn while prompts wait for it. Returns None or a dict describing
+        the admissions for the decode launch's on-device token merge."""
         from llms_on_kubernetes_tpu import faults
 
         if faults.is_active("queue_stall"):
@@ -3315,6 +3412,13 @@ class Engine:
         # (at worst a spurious backpressure wakeup), while anything already
         # queued is handled right here
         self._admit_wake.clear()
+        chain = self._chain
+        if chain is not None and (chain.req.finished
+                                  or self.slots[chain.slot] is not chain.req):
+            # aborted or preempted part-way: what is left of it goes
+            chain = self._chain = None
+        if chain is not None and chain.yielded:
+            return self._next_chunk()
         picked: list[tuple[int, "Request", bool, list[int]]] = []
         long_pick = None
         with self._lock:
@@ -3339,8 +3443,9 @@ class Engine:
                 if (hit > 0 or req.images is not None
                         or n > max(self.config.prefill_buckets)):
                     # cache-hit / multimodal / out-of-bucket prompt: runs
-                    # alone (chunk path or mm prefill)
-                    if picked or not self.allocator.can_allocate(slot, n + 1):
+                    # alone (chunk path or mm prefill), one at a time
+                    if (picked or chain is not None
+                            or not self.allocator.can_allocate(slot, n + 1)):
                         if hit:
                             self.allocator.rollback_adopt(slot)
                         self._host_adopt.pop(slot, None)
@@ -3377,34 +3482,21 @@ class Engine:
             # below is dispatched — its history attention reads them.
             # Outside the lock: the np.stack memcpy must not block submit()
             self._host_kv_commit(slot, req)
-            led_rows = [(req, "prefill", max(1, len(prefill_tokens) - hit))]
-            if req.images is not None and hit == 0:
-                pack, toks, dseq = self._dispatch_mm_prefill(
-                    slot, req, prefill_tokens, led_rows)
-            else:
+            if req.images is None or hit > 0:
                 # cache-hit remainder (pure text for multimodal hits) or
-                # an out-of-bucket text prompt
-                pack, toks, dseq = self._chunked_prefill(
-                    slot, req, prefill_tokens, led_rows, start=hit)
-            if req.cache_salt is not None:
-                self.allocator.register_prefix(slot, req.prompt,
-                                               salt=req.cache_salt)
-            merge = {"toks": toks, "slots": {}}
-            if resumed:
-                # no first-token read: the host knows the re-prefill done
-                # only when the decode step launched behind it is read
-                req.pending_token = req.output[-1]
-                merge["slots"][slot] = (True, req.output[-1], 0)
-                self.timeline.close(dseq, None)
-            else:
-                key = -1 - dseq
-                self._harvester.push(key, pack)
-                self._first_reads[key] = ("chunk", 1)
-                merge["slots"][slot] = (False, 0, 0)
-                self._pending_first.append((req, key, 0))
-            return merge
+                # an out-of-bucket text prompt: the chunk path, a chunk a
+                # step()
+                self._chain = ChunkChain(slot, req, prefill_tokens, hit, hit,
+                                         resumed)
+                return self._next_chunk()
+            led_rows = [(req, "prefill", max(1, len(prefill_tokens)))]
+            pack, toks, dseq = self._dispatch_mm_prefill(
+                slot, req, prefill_tokens, led_rows)
+            return self._lone_admission(slot, req, resumed, pack, toks, dseq)
         if not picked:
-            return None
+            return None if chain is None else self._next_chunk()
+        if chain is not None:
+            chain.yielded = True
 
         from llms_on_kubernetes_tpu.engine.multihost import MSG_PREFILL
 
@@ -3422,6 +3514,7 @@ class Engine:
             tokens[row, :n] = ptoks
             self._pack_prefill_row(packed, row, req, n, slot)
             self.slot_len[slot] = n
+            self.path_tokens["prefill"] += n
 
         use_fsm = bool((packed[:, _FSM_PRE] >= 0).any())
         self._mh_send(MSG_PREFILL, pre_tokens=tokens, pre_packed=packed,
@@ -3505,6 +3598,10 @@ class Engine:
             if r is None:
                 i += 1
                 continue
+            if self._chain is not None and i == self._chain.slot:
+                plan[i] = 0     # its prompt is still being written
+                i += 1
+                continue
             prior = infl.get(i, 0)
             base0 = int(self.slot_len[i]) + prior + 1
             extra = 1 if id(r) in first_pending else 0
@@ -3553,8 +3650,9 @@ class Engine:
             # every row's budget is consumed by in-flight work — a
             # dispatch would be all-masked. The pipeline is non-empty in
             # this state (empty pipeline => budget >= 1), so harvesting
-            # makes progress.
-            return "paced"
+            # makes progress. (Or the one row is a prompt part-way through
+            # its chunks: step() comes back to launch the next.)
+            return "paced" if self._chain is None else "launched"
 
         packed = self._pack_decode(
             active, plan, infl, admitted["slots"] if admitted else {})
@@ -3732,20 +3830,25 @@ class Engine:
         no device read of its own. A token step in which no row was live
         routed nothing and is no step."""
         cfg = self.model_config
-        got = moe_rows_of(np.asarray(arr), rows, cfg.num_moe_layers,
-                          cfg.num_experts)
+        # a model that holds a share of its experts reports, behind the
+        # rows of the experts held here, the pairs routed elsewhere
+        held = cfg.num_held_experts
+        width = held + (cfg.experts_held is not None)
+        got = moe_rows_of(np.asarray(arr), rows, cfg.num_moe_layers, width)
         if got is None:
             return
-        steps = got.reshape(-1, cfg.num_moe_layers, cfg.num_experts)
+        steps = got.reshape(-1, cfg.num_moe_layers, width)
         steps = steps[steps.sum(axis=(1, 2)) > 0]
         if not len(steps):
             return
         st = self.moe_stats[kind]
+        st["routed_rows"] += int(steps.sum())
+        steps = steps[..., :held]
+        st["held_rows"] += int(steps.sum())
         st["experts_touched"] += int((steps > 0).sum())
         st["expert_slots"] += steps.size
-        st["routed_rows"] += int(steps.sum())
         st["fullest_expert_rows"] += int(steps.max(axis=2).sum())
-        st["mean_expert_rows"] += float(steps.sum()) / cfg.num_experts
+        st["mean_expert_rows"] += float(steps.sum()) / held
         from llms_on_kubernetes_tpu.ops import attention
 
         # which grouped product the newest traced step took (ops/moe._plan)
@@ -3905,7 +4008,7 @@ class Engine:
             (done_entries if self._harvester.key_done(entry[1])
              else still).append(entry)
         for req, key, row in done_entries:
-            if req.finished:
+            if req.finished or row < 0:
                 continue
             host = HostSample(np.asarray(self._harvester.get(key)))
             tok = int(host.tokens[row])

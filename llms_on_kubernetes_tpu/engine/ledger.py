@@ -91,10 +91,12 @@ IDLE_HOSTS = ("no_work", "compile", "scheduling")
 DECODE_LAUNCH_RULES = ("timed", "late", "admission", "depth")
 # what the engine books of the expert layers (Engine.moe_stats,
 # llm_moe_<stat>_total{kind}), per kind of dispatch and summed over its
-# token steps and expert layers: experts that got at least one row /
-# experts there were / (token, expert) pairs routed / rows of each layer's
-# fullest expert / of its mean expert
-MOE_STATS = ("experts_touched", "expert_slots", "routed_rows",
+# token steps and expert layers: experts HELD here that got at least one
+# row / experts held / (token, expert) pairs routed, to any expert / those
+# of them that fell on experts held here (all, unless the model holds a
+# share: ModelConfig.experts_held) / rows of each layer's fullest held
+# expert / of its mean held expert
+MOE_STATS = ("experts_touched", "expert_slots", "routed_rows", "held_rows",
              "fullest_expert_rows", "mean_expert_rows")
 # launched and not yet booked: a pipeline holds async_depth decode steps
 # and the prefills of one admission round, a handful
@@ -132,8 +134,11 @@ def _active_params(cfg: Any) -> int:
     n = int(cfg.num_params)
     if getattr(cfg, "is_moe", False) and cfg.num_experts > 0:
         d, f, L = cfg.hidden_size, cfg.expert_width, cfg.num_moe_layers
-        all_mlp = 3 * d * f * cfg.num_experts
-        active_mlp = 3 * d * f * cfg.num_experts_per_tok
+        # of a token's experts, the share that is held here computes here
+        held = cfg.num_held_experts
+        all_mlp = 3 * d * f * held
+        active_mlp = 3 * d * f * cfg.num_experts_per_tok * held \
+            // cfg.num_experts
         n -= L * (all_mlp - active_mlp)
     return n
 
@@ -203,8 +208,10 @@ class Dispatch:
     The engine thread opens it at the launch site, marks it launched when
     the jitted call returns (the work is then in the device's queue) and
     closes it when its host read lands; the ledger fills the rest when it
-    books the record, in launch order. A chunked prefill's chain of
-    dispatches, of which only the last is read, is one record."""
+    books the record, in launch order. Each chunk of a chunked prefill
+    is a record and is read (the pipelined scheduler launches a decode
+    window between two of them); the synchronous scheduler's chain, of
+    which only the last dispatch is read, is one record."""
     seq: int
     kind: str                    # one of KINDS
     name: str                    # the jitted step's name
@@ -359,9 +366,11 @@ class DispatchTimeline:
             if rows is not None:
                 rec.rows = rows
             if t_done is not None:
+                # the newest read before its first token: a chunked
+                # prompt's last chunk (each chunk is a record and is read)
                 for req, phase, _w in rec.rows or ():
                     if (phase == "prefill" and req is not None and getattr(
-                            req, "prefill_read_at", None) is None):
+                            req, "first_token_at", None) is None):
                         req.prefill_read_at = t_done
             self._book_ready()
 
@@ -525,9 +534,10 @@ class GoodputLedger(DispatchTimeline):
         self.param_bytes = float(params * dtype_bytes)
         # KV traffic per token-step: one K+V page-write plus (amortized)
         # the read of its own history — bounded below by the write
+        cache_heads, cache_width = model_config.cache_row
         self.kv_bytes_per_token = float(
-            2 * model_config.num_attn_layers * model_config.kv_dim
-            * dtype_bytes)
+            (1 if model_config.is_mla else 2) * model_config.num_attn_layers
+            * cache_heads * cache_width * dtype_bytes)
 
         self.detector = detector
         # booked, newest last: the rolling window the MFU/MBU gauges are

@@ -44,7 +44,9 @@ from llms_on_kubernetes_tpu.ops.lora import lora_qeinsum
 from llms_on_kubernetes_tpu.ops.moe import moe_block
 from llms_on_kubernetes_tpu.ops.norms import rms_norm
 from llms_on_kubernetes_tpu.ops.quant import qeinsum
-from llms_on_kubernetes_tpu.ops.rope import apply_rope, rope_frequencies
+from llms_on_kubernetes_tpu.ops.rope import (
+    apply_rope, rope_frequencies, yarn_attention_factor,
+)
 
 Params = dict[str, Any]
 
@@ -86,7 +88,7 @@ def _unroll_layers() -> bool:
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: Optional[str] = None) -> Params:
     """Random-init parameters (layer-stacked). Layout matches weights.py loading."""
     dt = jnp.dtype(dtype or cfg.dtype)
-    if len(cfg.layer_runs) > 1:
+    if len(cfg.layer_runs) > 1 or cfg.is_mla:
         return _init_runs(cfg, key, dt)
     L, D, F = cfg.num_layers, cfg.hidden_size, cfg.expert_width
     H, KV, hd, V = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size
@@ -163,23 +165,36 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
     """Random-init parameters of a stack of more than one kind of layer:
     ``params["layers"]`` is a tuple with one layer-stacked dict for each
     run of ``cfg.layer_runs``. A layer's operator is attention (wq, wk, wv,
-    wo, q_norm, k_norm) or a gated short convolution (conv_in [D, 3D]: the
+    wo, q_norm, k_norm), a gated short convolution (conv_in [D, 3D]: the
     gates B and C and the input X, in that order; conv_w [D, taps], one
-    filter a channel; conv_out [D, D]); its feed-forward is a dense SwiGLU
-    network or routed experts (router, router_bias, w_gate/w_up/w_down
-    stacked over experts). ``attn_norm`` is the operator's norm whatever
-    the operator. The selection bias is NOT zero, so that a test can tell
-    selecting from weighing, and small beside the scores' own spread
-    (0.01 against 0.2): a trained bias evens the experts' load out, and a
-    random one as wide as the scores would pile the rows on a few."""
+    filter a channel; conv_out [D, D]) or latent attention (w_qa [D,
+    q_lora], q_a_norm, w_qn [H * nope, q_lora] and w_qr [H * rope,
+    q_lora]: the published q_b matrix's un-roped and roped outputs, output-
+    major as the checkpoint stores a linear layer; w_kva [D,
+    kv_lora + rope], kv_a_norm, w_uk [H, kv_lora, nope] and w_uv [H,
+    kv_lora, v]: the published kv_b matrix's two halves, head-major. Each
+    is the array a product reads as it lies: a matrix whose columns are
+    split after the product, or whose heads are 192 wide, is re-laid out
+    by the compiler before every step. wo [H, v, D]); its
+    feed-forward is a dense SwiGLU network or routed experts (router,
+    router_bias, w_gate/w_up/w_down stacked over the experts HELD here,
+    ``cfg.num_held_experts`` of the router's ``num_experts``; ws_gate,
+    ws_up, ws_down: the shared expert, where there is one). ``attn_norm``
+    is the operator's norm whatever the operator. The selection bias is
+    NOT zero, so that a test can tell selecting from weighing, and small
+    beside the scores' own spread (0.01 against 0.2): a trained bias evens
+    the experts' load out, and a random one as wide as the scores would
+    pile the rows on a few."""
     if cfg.attention_bias or cfg.post_norms or cfg.vision is not None \
-            or cfg.norm_style != "llama" or not cfg.qk_norm:
+            or cfg.norm_style != "llama" or not (cfg.qk_norm or cfg.is_mla):
         raise NotImplementedError(
             f"{cfg.name}: a stack of several kinds of layer is built for "
-            f"the LFM2 block only (llama norms, q/k norms, no biases)")
+            f"the LFM2 block (llama norms, q/k norms, no biases) and the "
+            f"DeepSeek block (latent attention) only")
     D, F, Fm = cfg.hidden_size, cfg.intermediate_size, cfg.expert_width
     H, KV, hd, V = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size
-    E, taps = cfg.num_experts, cfg.conv_L_cache
+    E, held, taps = cfg.num_experts, cfg.num_held_experts, cfg.conv_L_cache
+    Fs = cfg.n_shared_experts * Fm
     keys = iter(jax.random.split(key, 16 * len(cfg.layer_runs) + 4))
 
     def init(n, *shape, scale):
@@ -190,7 +205,20 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
     for op, ff, _first, n in cfg.layer_runs:
         lp: Params = {"attn_norm": jnp.ones((n, D), dt),
                       "mlp_norm": jnp.ones((n, D), dt)}
-        if op == "attn":
+        if op == "mla":
+            ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+            nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+            lp.update(
+                w_qa=init(n, D, ql, scale=D ** -0.5),
+                q_a_norm=jnp.ones((n, ql), dt),
+                w_qn=init(n, H * nope, ql, scale=ql ** -0.5),
+                w_qr=init(n, H * (hd - nope), ql, scale=ql ** -0.5),
+                w_kva=init(n, D, cfg.latent_width, scale=D ** -0.5),
+                kv_a_norm=jnp.ones((n, kl), dt),
+                w_uk=init(n, H, kl, nope, scale=kl ** -0.5),
+                w_uv=init(n, H, kl, vd, scale=kl ** -0.5),
+                wo=init(n, H, vd, D, scale=(H * vd) ** -0.5))
+        elif op == "attn":
             lp.update(
                 wq=init(n, D, H, hd, scale=D ** -0.5),
                 wk=init(n, D, KV, hd, scale=D ** -0.5),
@@ -207,9 +235,14 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
                 router=init(n, D, E, scale=D ** -0.5),
                 router_bias=(jax.random.normal(next(keys), (n, E),
                                                jnp.float32) * 0.01),
-                w_gate=init(n, E, D, Fm, scale=D ** -0.5),
-                w_up=init(n, E, D, Fm, scale=D ** -0.5),
-                w_down=init(n, E, Fm, D, scale=Fm ** -0.5))
+                w_gate=init(n, held, D, Fm, scale=D ** -0.5),
+                w_up=init(n, held, D, Fm, scale=D ** -0.5),
+                w_down=init(n, held, Fm, D, scale=Fm ** -0.5))
+            if Fs:
+                lp.update(
+                    ws_gate=init(n, D, Fs, scale=D ** -0.5),
+                    ws_up=init(n, D, Fs, scale=D ** -0.5),
+                    ws_down=init(n, Fs, D, scale=Fs ** -0.5))
         else:
             lp.update(
                 w_gate=init(n, D, F, scale=D ** -0.5),
@@ -286,6 +319,9 @@ def _qkv(lp: Params, cfg: ModelConfig, h: jnp.ndarray, adapter_idx=None):
 
 
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+# the most tokens an expert layer takes in one piece (a 2,048-token bucket;
+# four of them, 65,536 pairs of 7,168, would be 3.8 GB of rows)
+_MOE_TOKEN_BLOCK = 2048
 
 
 def _mlp(lp: Params, cfg: ModelConfig, h: jnp.ndarray, token_valid: jnp.ndarray,
@@ -297,16 +333,38 @@ def _mlp(lp: Params, cfg: ModelConfig, h: jnp.ndarray, token_valid: jnp.ndarray,
     if ff == "moe":
         B, T, D = h.shape
         stacks, layer = experts
-        out, rows = moe_block(
-            h.reshape(B * T, D), lp["router"],
-            *(stacks[k] for k in _EXPERT_STACKS), layer=layer,
-            top_k=cfg.num_experts_per_tok, act=act,
-            valid=token_valid.reshape(B * T),
-            bias=lp["router_bias"] if cfg.use_expert_bias else None,
-            scores=cfg.moe_router, renorm=cfg.norm_topk_prob,
-            eps=cfg.moe_renorm_eps, scale=cfg.routed_scaling_factor,
-        )
-        return out.reshape(B, T, D), rows
+
+        def routed(x, valid):
+            return moe_block(
+                x, lp["router"],
+                *(stacks[k] for k in _EXPERT_STACKS), layer=layer,
+                top_k=cfg.num_experts_per_tok, act=act, valid=valid,
+                bias=lp["router_bias"] if cfg.use_expert_bias else None,
+                scores=cfg.moe_router, renorm=cfg.norm_topk_prob,
+                eps=cfg.moe_renorm_eps, scale=cfg.routed_scaling_factor,
+                n_group=cfg.n_group, topk_group=cfg.topk_group,
+                first_expert=(None if cfg.experts_held is None
+                              else cfg.first_expert))
+
+        N = B * T
+        x, valid = h.reshape(N, D), token_valid.reshape(N)
+        if N > _MOE_TOKEN_BLOCK and N % _MOE_TOKEN_BLOCK == 0:
+            # the grouped product's rows are (token, choice) pairs, top_k
+            # times the tokens, in and out: a block of tokens at a time
+            nb = N // _MOE_TOKEN_BLOCK
+            out, rows = jax.lax.map(
+                lambda a: routed(*a), (x.reshape(nb, -1, D),
+                                       valid.reshape(nb, -1)))
+            out, rows = out.reshape(N, D), rows.sum(axis=0)
+        else:
+            out, rows = routed(x, valid)
+        out = out.reshape(B, T, D)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                gate = act(qeinsum("btd,df->btf", h, lp["ws_gate"]))
+                up = qeinsum("btd,df->btf", h, lp["ws_up"])
+                out = out + qeinsum("btf,fd->btd", gate * up, lp["ws_down"])
+        return out, rows
     gate = act(_lqe("btd,df->btf", h, lp, "w_gate", adapter_idx))
     up = _lqe("btd,df->btf", h, lp, "w_up", adapter_idx)
     return _lqe("btf,fd->btd", gate * up, lp, "w_down", adapter_idx), None
@@ -372,6 +430,10 @@ def _layer_step(
     h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
     if op == "conv":
         out, conv_state = _short_conv(lp, cfg, h, conv_state, n_valid)
+    elif op == "mla":
+        out, k_pages = _latent_attention(
+            cfg, inv_freq, page_table, positions, write_positions, lengths,
+            mode, h, lp, k_pages, rope_positions)
     else:
         out, k_pages, v_pages = _attention(
             cfg, inv_freq, page_table, positions, write_positions, lengths,
@@ -390,6 +452,62 @@ def _layer_step(
         m = rms_norm(m, lp["mlp_post_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
     x = x + m
     return x, k_pages, v_pages, conv_state, moe_rows
+
+
+def _latent_attention(cfg, inv_freq, page_table, positions, write_positions,
+                      lengths, mode, h, lp, pool, rope_positions):
+    """DeepSeek's latent attention (MLA) on the normed input ``h`` [B, T,
+    D]: (W_o of the attended values, the latent pool).
+
+      cq = norm_q(h W_qa);  [q_n | q_r]_h = cq [W_qn | W_qr]_h
+      [ckv | kr] = h W_kva;  c = norm_kv(ckv);  k_r = rope(kr);  q_r = rope
+      the cache row is [c | k_r]: one a token, shared by all heads
+
+    Prefill and chunks expand rows to heads (k_n,h = c W_UK,h, v_h = c
+    W_UV,h) and take score_h = (q_n,h . k_n,h + q_r,h . k_r) s; a decode
+    step absorbs the two into the query (q_n,h W_UK,h^T) and the output
+    (o_lat,h W_UV,h) and attends the rows as they lie: the same numbers
+    (ops/attention.py). s = head_dim^-0.5 m^2, m yarn's factor."""
+    from llms_on_kubernetes_tpu.engine.cache import write_latent
+    from llms_on_kubernetes_tpu.ops.attention import (
+        dispatch_latent_chunk, dispatch_latent_decode,
+        dispatch_latent_prefill,
+    )
+
+    nope, lat = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    eps = cfg.rms_norm_eps
+    scale = cfg.head_dim ** -0.5 * yarn_attention_factor(cfg.rope_scaling) ** 2
+    with jax.named_scope("mla.project"):
+        cq = rms_norm(qeinsum("btd,dr->btr", h, lp["w_qa"]), lp["q_a_norm"],
+                      eps)
+        q_n = qeinsum("btr,fr->btf", cq, lp["w_qn"]).reshape(
+            *h.shape[:2], cfg.num_heads, nope)
+        q_r = qeinsum("btr,fr->btf", cq, lp["w_qr"]).reshape(
+            *h.shape[:2], cfg.num_heads, cfg.qk_rope_head_dim)
+        ckv = qeinsum("btd,dw->btw", h, lp["w_kva"])
+        c = rms_norm(ckv[..., :lat], lp["kv_a_norm"], eps)
+        q_r, k_r = apply_rope(
+            q_r, ckv[:, :, None, lat:],
+            positions if rope_positions is None else rope_positions, inv_freq)
+        rows = jnp.concatenate([c, k_r[:, :, 0]], axis=-1)
+    pool = write_latent(pool, rows, page_table, write_positions)
+    w_uk, w_uv = lp["w_uk"].astype(h.dtype), lp["w_uv"].astype(h.dtype)
+    with jax.named_scope("mla.attend"):
+        if mode == "decode":
+            q_lat = jnp.einsum("bhk,hrk->bhr", q_n[:, 0], w_uk)
+            o_lat = dispatch_latent_decode(
+                jnp.concatenate([q_lat, q_r[:, 0]], axis=-1), pool,
+                page_table, lengths, scale=scale, lat=lat)
+            attn = jnp.einsum("bhr,hrk->bhk", o_lat, w_uv)[:, None]
+        elif mode == "prefill":
+            attn = dispatch_latent_prefill(q_n, q_r, rows, w_uk, w_uv,
+                                           lengths, scale=scale)
+        else:
+            attn = dispatch_latent_chunk(
+                q_n, q_r, pool, page_table, w_uk, w_uv, positions[:, 0],
+                lengths, scale=scale)
+    with jax.named_scope("mla.out"):
+        return qeinsum("bthk,hkd->btd", attn, lp["wo"]), pool
 
 
 def _attention(cfg, inv_freq, page_table, positions, write_positions,
@@ -486,7 +604,7 @@ def _run_layers(
     chosen at trace time. The pools hold the ATTENTION layers only, in
     stack order; the conv state (``aux.conv``) the conv layers. Returns
     (x, k_pages, v_pages, aux with the new state and the experts' rows)."""
-    inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
+    inv_freq = jnp.asarray(rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling))
     inv_freq_local = (
         jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_local_theta))
         if cfg.rope_local_theta is not None else None
@@ -593,10 +711,10 @@ def _run_layers(
             # boundary copy costs more than the whole rest of the step)
             unroll=n if _unroll_layers() else 1,
         )
-        if op == "attn":
-            a0 += n
-        else:
+        if op == "conv":
             c0 += n
+        else:
+            a0 += n
         if rows is not None:
             moe_rows.append(rows)
     if aux is not None:
@@ -692,7 +810,8 @@ def forward_score(
     token_valid = positions < lengths[:, None]
     from llms_on_kubernetes_tpu.engine.cache import KVPool
 
-    dummy_shape = (cfg.num_kv_heads, cfg.num_attn_layers, 1, cfg.head_dim)
+    heads, width = cfg.cache_row
+    dummy_shape = (heads, cfg.num_attn_layers, 1, width)
     k_pages = KVPool(jnp.zeros(dummy_shape, jnp.float32))
     v_pages = KVPool(jnp.zeros(dummy_shape, jnp.float32))
     page_table = jnp.zeros((B, 1), jnp.int32)

@@ -17,6 +17,7 @@ Layout choices (TPU-first):
 
 from __future__ import annotations
 
+import math
 import os
 import sys
 from typing import Optional
@@ -312,6 +313,196 @@ def chunk_attention(
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bkgts,kbsd->btkgd", probs, v)
     return out.reshape(B, T, n_q, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (DeepSeek MLA) over a latent pool (engine/cache.py)
+# ---------------------------------------------------------------------------
+# A cached row is [c | k_r | 0...]: the normalised latent, the one roped
+# key all heads share, and zeros up to whole 128-lane tiles. Prefill and
+# chunks EXPAND it to per-head keys and values (k_n,h = c W_UK,h and v_h =
+# c W_UV,h), a block of keys at a time; a decode step ABSORBS the expansion
+# into the query and the output and attends the rows as they lie
+# (multi-query attention with wide keys). W_UK [H, lat, nope] and W_UV
+# [H, lat, v] are head-major, so each of these is a matmul batched over
+# heads with the weight as it lies. Operands keep their type (bfloat16 on
+# the chip) and every product accumulates in float32.
+
+LATENT_KEY_BLOCK = 256
+
+
+def latent_expanded_attention(qn, qr, rows, w_uk, w_uv, q_pos, kv_len, *,
+                              scale: float, block: int = LATENT_KEY_BLOCK):
+    """Causal attention of queries over latent rows, expanded to heads.
+
+    qn, qr: [B, T, H, nope], [B, T, H, rope]  (the rope part rotated)
+    rows:   [B, S, >= lat + rope]   latent rows of positions 0..S-1
+    w_uk:   [H, lat, nope];  w_uv: [H, lat, v]
+    q_pos:  [B, T] int32            each query's position among the rows,
+                                    ascending along T
+    kv_len: [B] int32               rows that are written (0 => idle row)
+    returns [B, T, H, v]
+
+    One batch row at a time. Within it a loop over blocks of ``block``
+    keys, each expanded to heads ONCE, and under it a loop over the blocks
+    of queries that can see the key block (causal: those whose last
+    position is not before it), with a running softmax a query block. So
+    no [H, T, S] scores exist (at 128 heads, 2,048 queries and 9,216 keys
+    they would be 9.7 GB: a tile is [H, block, block]), a bucket pays for
+    the lower triangle of its square, and a chunk for its history, not for
+    the slot's whole window."""
+    S, T = rows.shape[1], qn.shape[1]
+    lat, rope = w_uk.shape[1], qr.shape[-1]
+    block, qb = math.gcd(S, block), math.gcd(T, block)
+    nq = T // qb
+
+    def one_row(args):
+        qn, qr, rows, q_pos, kv_len = args
+        H = qn.shape[1]
+        # [nq, H, qb, nope + rope]: one product a tile, not two and a sum
+        q = jnp.moveaxis(jnp.concatenate([qn, qr], axis=-1).reshape(
+            nq, qb, H, -1), 2, 1)
+        pos = q_pos.reshape(nq, qb)
+
+        def key_block(i, carry):
+            blk = jax.lax.dynamic_slice_in_dim(rows, i * block, block, 0)
+            c = blk[:, :lat]
+            k = jnp.concatenate(
+                [jnp.einsum("sr,hrk->hsk", c, w_uk),
+                 jnp.broadcast_to(blk[None, :, lat:lat + rope],
+                                  (H, block, rope))], axis=-1)
+            v = jnp.einsum("sr,hrk->hsk", c, w_uv)
+            k_pos = i * block + jnp.arange(block, dtype=jnp.int32)
+
+            def query_block(j, carry):
+                m, l, acc = carry
+                at = lambda a: jax.lax.dynamic_index_in_dim(a, j, 0, False)
+                s = jnp.einsum("htk,hsk->hts", at(q), k,
+                               preferred_element_type=jnp.float32) * scale
+                mask = ((k_pos[None, :] <= at(pos)[:, None])
+                        & (k_pos[None, :] < kv_len))[None]   # [1, qb, block]
+                s = jnp.where(mask, s, NEG_INF)
+                m_new = jnp.maximum(at(m), s.max(axis=-1))
+                p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+                alpha = jnp.exp(at(m) - m_new)
+                new = (m_new, at(l) * alpha + p.sum(axis=-1),
+                       at(acc) * alpha[..., None] + jnp.einsum(
+                           "hts,hsk->htk", p.astype(v.dtype), v,
+                           preferred_element_type=jnp.float32))
+                return tuple(jax.lax.dynamic_update_index_in_dim(a, n, j, 0)
+                             for a, n in zip(carry, new))
+
+            # the first block of queries whose last position reaches this
+            # block of keys (positions ascend along T)
+            first = jnp.sum(pos[:, -1] < i * block, dtype=jnp.int32)
+            return jax.lax.fori_loop(first, nq, query_block, carry)
+
+        init = (jnp.full((nq, H, qb), NEG_INF, jnp.float32),
+                jnp.zeros((nq, H, qb), jnp.float32),
+                jnp.zeros((nq, H, qb, w_uv.shape[2]), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, (kv_len + block - 1) // block,
+                                      key_block, init)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]         # [nq, H, qb, v]
+        return jnp.moveaxis(out, 1, 2).reshape(T, H, -1).astype(qn.dtype)
+
+    return jax.lax.map(one_row, (qn, qr, rows, q_pos, kv_len))
+
+
+def _gather_latent(pool, page_table):
+    """A latent pool's rows [B, S, width] through the page table."""
+    data = getattr(pool, "data", pool)[0]                    # [P, page, W]
+    B, pps = page_table.shape
+    return data[page_table].reshape(B, pps * data.shape[1], data.shape[2])
+
+
+LATENT_DECODE_PAGES = 8
+
+
+def latent_paged_attention(q_abs, pool, page_table, lengths, *, scale: float,
+                           lat: int, block_pages: int = LATENT_DECODE_PAGES):
+    """One decode token a slot against the latent pool, absorbed.
+
+    q_abs:   [B, H, lat + rope]  = [q_n,h W_UK,h^T | q_r,h]
+    pool:    [1, P, page, width >= lat + rope] (this layer's pages through
+             the table; past lat + rope a row is zeros)
+    lengths: [B] rows INCLUDING the current token's (already written)
+    returns  o_lat [B, H, lat] = softmax(q_abs . row) . row[:lat], float32
+             accumulation, in q_abs' type; W_UV is the caller's.
+
+    A loop over blocks of ``block_pages`` pages of every slot, gathered
+    through the table one block at a time, with a running softmax; it ends
+    at the longest live slot's last block. Nothing the size of the slots'
+    windows is ever written: gathered whole they are 377 MB a layer at 32
+    slots of 9,216 tokens, written once and read twice."""
+    data = getattr(pool, "data", pool)[0]                    # [P, page, W]
+    B, H, _ = q_abs.shape
+    page, W = data.shape[1:]
+    bp = math.gcd(page_table.shape[1], block_pages)
+    blk = bp * page
+    q_abs = jnp.pad(q_abs, ((0, 0), (0, 0), (0, W - q_abs.shape[2])))
+
+    def body(i, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(page_table, i * bp, bp, axis=1)
+        rows = data[ids].reshape(B, blk, W)
+        s = jnp.einsum("bhw,bsw->bhs", q_abs, rows,
+                       preferred_element_type=jnp.float32) * scale
+        mask = ((i * blk + jnp.arange(blk, dtype=jnp.int32))[None, :]
+                < lengths[:, None])[:, None]                 # [B, 1, blk]
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhs,bsr->bhr", p.astype(rows.dtype), rows[..., :lat],
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(axis=-1), acc
+
+    init = (jnp.full((B, H), NEG_INF, jnp.float32),
+            jnp.zeros((B, H), jnp.float32),
+            jnp.zeros((B, H, lat), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(
+        0, (jnp.max(lengths) + blk - 1) // blk, body, init)
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_abs.dtype)
+
+
+def dispatch_latent_prefill(qn, qr, rows, w_uk, w_uv, lengths, *, scale):
+    """A prompt bucket over its own latent rows (nothing cached is read)."""
+    B, T, H = qn.shape[:3]
+    _choose("prefill", "xla",
+            f"latent rows expanded to {H} heads a block of up to "
+            f"{LATENT_KEY_BLOCK} keys at a time, q.k "
+            f"{qn.shape[3] + qr.shape[3]} wide, bucket {T}; no latent kernel")
+    q_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    return latent_expanded_attention(qn, qr, rows, w_uk, w_uv, q_pos, lengths,
+                                     scale=scale)
+
+
+def dispatch_latent_chunk(qn, qr, pool, page_table, w_uk, w_uv, history,
+                          chunk_lengths, *, scale):
+    """A chunk over the cached latents of its history and its own rows,
+    which are already written: gathered through the page table, expanded
+    block by block up to the last written one."""
+    B, T = qn.shape[:2]
+    _choose("chunk", "xla",
+            "cached latent rows gathered through the page table, expanded "
+            f"a block of up to {LATENT_KEY_BLOCK} keys at a time as far as "
+            "the row's last written block; no latent kernel")
+    q_pos = history[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    return latent_expanded_attention(
+        qn, qr, _gather_latent(pool, page_table), w_uk, w_uv, q_pos,
+        history + chunk_lengths, scale=scale)
+
+
+def dispatch_latent_decode(q_abs, pool, page_table, lengths, *, scale, lat):
+    page = getattr(pool, "data", pool).shape[2]
+    _choose("decode", "xla",
+            f"absorbed: {q_abs.shape[1]} query heads over one "
+            f"{q_abs.shape[-1]}-wide row a token, gathered "
+            f"{LATENT_DECODE_PAGES * page} tokens of every slot at a time "
+            f"as far as the longest slot's last block; no latent kernel")
+    return latent_paged_attention(q_abs, pool, page_table, lengths,
+                                  scale=scale, lat=lat)
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,16 @@
 """Mixture-of-Experts block: a top-k router and a grouped expert product.
 
 One path for prefill, chunk and decode, and for every routed model
-(Mixtral, Qwen3-MoE, LFM2-MoE):
+(Mixtral, Qwen3-MoE, LFM2-MoE, DeepSeek-V3):
 
   route             scores [N, E] -> the k experts of each token and their
-                    weights (``route``)
+                    weights (``route``), among all experts or among those
+                    of the token's best groups
   sort              the N*k (token, expert) pairs by expert, stable, the
-                    pairs of padding and idle tokens last
+                    pairs of padding and idle tokens last, and with them
+                    the pairs of experts that are not held here (a layer
+                    may hold one chip's share of the router's experts:
+                    ``grouped_experts(held=)``)
   grouped product   row i of the sorted rows is multiplied with the weights
                     of ITS expert, so the work is top_k x N rows whatever
                     the number of experts. One algorithm, two tiles
@@ -53,12 +57,19 @@ def route(
     renorm: bool = True,
     eps: float = 0.0,
     scale: float = 1.0,
+    n_group: int = 1,
+    topk_group: int = 1,
 ):
     """x [N, D], router_w [D, E] -> (sel [N, k] int32, weight [N, k] f32).
 
     ``scores``: "softmax" over all experts, or "sigmoid" of each expert's
     logit alone. ``bias`` [E] is added to the scores for the SELECTION
     only; a weight is always the unbiased score of the expert chosen.
+    ``n_group`` > 1 limits the selection to groups (DeepSeek-V3): the
+    experts lie in ``n_group`` groups of E / n_group consecutive ones, a
+    group's score is the sum of its two largest selection scores, and only
+    the experts of the ``topk_group`` best groups can be chosen (the rest
+    are masked to -inf; a tie goes to the lower index, group or expert).
     ``renorm`` divides the chosen scores by their sum (+ ``eps``), then
     ``scale`` multiplies them. Float32 at the highest matmul precision:
     the product is tiny, and a near-tie decides which weights a token
@@ -73,6 +84,13 @@ def route(
         else:
             raise ValueError(f"unknown router scores {scores!r}")
         pick = s if bias is None else s + bias.astype(jnp.float32)
+        if n_group > 1:
+            N, E = pick.shape
+            best2, _ = jax.lax.top_k(pick.reshape(N, n_group, E // n_group), 2)
+            _, keep = jax.lax.top_k(best2.sum(axis=-1), topk_group)
+            kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
+            pick = jnp.where(jnp.repeat(kept, E // n_group, axis=1), pick,
+                             -jnp.inf)
         _, sel = jax.lax.top_k(pick, top_k)                          # [N, k]
         weight = jnp.take_along_axis(s, sel, axis=-1)
         if renorm:
@@ -214,7 +232,8 @@ def _experts(x, expert, weight, w_gate, w_up, w_down, layer, act,
     return ys.reshape(N, k, D).sum(axis=1)
 
 
-def _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act):
+def _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act,
+               routed_among=None):
     """``_experts`` once per shard of the expert stacks, the partial
     results summed over the mesh.
 
@@ -242,7 +261,8 @@ def _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act):
         e_ax = _axis(mesh, experts, AXIS_EXPERT)
         m_ax = _axis(mesh, w_gate.shape[-1], AXIS_MODEL)
     if e_ax is None and m_ax is None:
-        return _experts(x, expert, weight, w_gate, w_up, w_down, layer, act)
+        return _experts(x, expert, weight, w_gate, w_up, w_down, layer, act,
+                        routed_among)
 
     def spec(w, s):
         return QTensor(s, scale_spec(s, w.scale.shape)) if isinstance(
@@ -277,6 +297,7 @@ def grouped_experts(
     act=jax.nn.silu,
     valid: "jnp.ndarray | None" = None,
     layer=None,
+    held: "tuple | None" = None,
 ):
     """sum_i weight[:, i] * expert_{sel[:, i]}(x) for x [N, D], with
     w_gate/w_up [E, D, F] and w_down [E, F, D]; or, with ``layer`` given
@@ -284,10 +305,18 @@ def grouped_experts(
     run's layers, [n, E, D, F] and [n, E, F, D] (``_grouped_dot`` says
     why the stack is not sliced).
 
-    Returns (out [N, D], rows [E] int32): ``rows`` counts the (token,
-    expert) pairs each expert got. ``valid`` ([N] bool) keeps padding and
-    idle tokens out: they reach no expert, count nowhere, and their rows
-    of ``out`` are zero."""
+    ``held`` = (first, experts): the stacks hold experts [first, first +
+    E) of the ``experts`` that ``sel`` was chosen among, one chip's share
+    of an expert-parallel deployment. The sum is then over the chosen
+    experts that are held: a pair routed to another chip's expert is
+    dropped before the sort, as padding is, so it costs no row of a
+    product, no tile and no byte, and that partial result is the layer's.
+
+    Returns (out [N, D], rows int32): ``rows`` [E] counts the (token,
+    expert) pairs each expert got; with ``held``, [E + 1], the last entry
+    the pairs routed to experts held elsewhere. ``valid`` ([N] bool) keeps
+    padding and idle tokens out: they reach no expert, count nowhere, and
+    their rows of ``out`` are zero."""
     N, k = sel.shape
     if layer is None:
         layer = 0
@@ -296,11 +325,21 @@ def grouped_experts(
     E = w_gate.shape[1]
     with jax.named_scope("moe.experts"):
         expert = sel.reshape(N * k).astype(jnp.int32)
-        if valid is not None:
-            expert = jnp.where(jnp.repeat(valid, k), expert, E)  # sorts last
+        live = True if valid is None else jnp.repeat(valid, k)
+        experts = None
+        if held is not None:
+            first, experts = held
+            expert = expert - first
+            here = (expert >= 0) & (expert < E)
+            elsewhere = jnp.sum(live & ~here, dtype=jnp.int32)
+            live = live & here
+        expert = jnp.where(live, expert, E)                  # sorts last
         rows = jnp.sum(expert[:, None] == jnp.arange(E, dtype=jnp.int32),
                        axis=0, dtype=jnp.int32)                      # [E]
-        out = _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act)
+        out = _per_shard(x, expert, weight, w_gate, w_up, w_down, layer, act,
+                         experts)
+        if held is not None:
+            rows = jnp.concatenate([rows, elsewhere[None]])
         return out.astype(x.dtype), rows
 
 
@@ -320,11 +359,19 @@ def moe_block(
     eps: float = 0.0,
     scale: float = 1.0,
     layer=None,
+    n_group: int = 1,
+    topk_group: int = 1,
+    first_expert: "int | None" = None,
 ):
     """x: [N, D]; router_w: [D, E]; w_gate/w_up: [E, D, F]; w_down:
-    [E, F, D] (with ``layer``: that layer of [n, E, ...] stacks). Returns
-    (out [N, D], rows [E]): see ``route`` and ``grouped_experts``."""
+    [E, F, D] (with ``layer``: that layer of [n, E, ...] stacks; with
+    ``first_expert``: stacks of the experts held here, from that one on,
+    of the router's E). Returns (out [N, D], rows): see ``route`` and
+    ``grouped_experts``."""
     sel, weight = route(x, router_w, bias, top_k=top_k, scores=scores,
-                        renorm=renorm, eps=eps, scale=scale)
+                        renorm=renorm, eps=eps, scale=scale, n_group=n_group,
+                        topk_group=topk_group)
+    held = (None if first_expert is None
+            else (first_expert, router_w.shape[-1]))
     return grouped_experts(x, sel, weight, w_gate, w_up, w_down, act=act,
-                           valid=valid, layer=layer)
+                           valid=valid, layer=layer, held=held)

@@ -6,7 +6,10 @@ surrounding fusion, and avoiding the table keeps the decode step free of a
 max_len-sized HBM read per layer.
 
 Supports the llama3 long-context frequency rescaling used by Llama-3.1+
-(`rope_scaling={"rope_type": "llama3", ...}` in HF configs).
+(`rope_scaling={"rope_type": "llama3", ...}` in HF configs), linear
+scaling, and YaRN as DeepSeek-V3 applies it: interpolated frequencies
+(``rope_frequencies``) and a factor on the softmax scale
+(``yarn_attention_factor``).
 """
 
 from __future__ import annotations
@@ -25,15 +28,31 @@ def rope_frequencies(
 ) -> np.ndarray:
     """Inverse frequencies [head_dim // 2], float32.
 
-    Supported ``rope_scaling`` schemes: llama3 (Llama-3.1+) and linear
-    (e.g. Gemma-3 global layers). Anything else raises — silently dropping
-    a scaling scheme would serve wrong positions (see configs.from_hf_config).
+    Supported ``rope_scaling`` schemes: llama3 (Llama-3.1+), linear
+    (e.g. Gemma-3 global layers) and yarn (DeepSeek-V3). Anything else
+    raises — silently dropping a scaling scheme would serve wrong positions
+    (see configs.from_hf_config).
+
+    yarn: a pair whose wavelength fits ``beta_fast`` or more times into the
+    original context keeps its frequency, one that fits ``beta_slow`` or
+    fewer times is divided by ``factor``, and the pairs between are blended
+    linearly in the pair's index: with corr(n) = dim ln(orig / (2 pi n)) /
+    (2 ln theta), low = floor(corr(beta_fast)), high = ceil(corr(beta_slow)),
+    r_i = clip((i - low) / (high - low), 0, 1),
+    inv_freq_i = theta_i (1 - r_i) + theta_i / factor r_i.
     """
     inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
     if rope_scaling:
         kind = rope_scaling.get("rope_type", rope_scaling.get("type", "llama3"))
         if kind == "linear":
             return (inv_freq / float(rope_scaling.get("factor", 1.0))).astype(np.float32)
+        if kind == "yarn":
+            low, high = yarn_correction_range(head_dim, theta, rope_scaling)
+            ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                           / max(high - low, 1e-3), 0.0, 1.0)
+            factor = float(rope_scaling["factor"])
+            return (inv_freq * (1.0 - ramp)
+                    + inv_freq / factor * ramp).astype(np.float32)
         if kind != "llama3":
             raise NotImplementedError(f"unsupported rope_scaling type {kind!r}")
         factor = float(rope_scaling.get("factor", 8.0))
@@ -56,6 +75,37 @@ def rope_frequencies(
             ),
         )
     return inv_freq.astype(np.float32)
+
+
+def yarn_correction_range(head_dim: int, theta: float,
+                          rope_scaling: dict) -> tuple[int, int]:
+    """(low, high): the pair indices between which yarn blends the kept and
+    the interpolated frequency (``rope_frequencies``)."""
+    orig = float(rope_scaling.get("original_max_position_embeddings", 4096))
+
+    def corr(rotations: float) -> float:
+        return (head_dim * math.log(orig / (rotations * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    low = math.floor(corr(float(rope_scaling.get("beta_fast", 32))))
+    high = math.ceil(corr(float(rope_scaling.get("beta_slow", 1))))
+    return max(low, 0), min(high, head_dim - 1)
+
+
+def yarn_attention_factor(rope_scaling: Optional[dict]) -> float:
+    """m = 0.1 mscale_all_dim ln(factor) + 1: DeepSeek's yarn multiplies
+    the softmax scale by m squared (1.0 without yarn). The factor the
+    scheme also puts on cos and sin is mscale's m over mscale_all_dim's,
+    1 where the two are equal, which is all that is served
+    (configs.from_hf_config refuses the rest)."""
+    if not rope_scaling or rope_scaling.get(
+            "rope_type", rope_scaling.get("type")) != "yarn":
+        return 1.0
+    factor = float(rope_scaling["factor"])
+    all_dim = float(rope_scaling.get("mscale_all_dim", 0.0))
+    if factor <= 1.0 or not all_dim:
+        return 1.0
+    return 0.1 * all_dim * math.log(factor) + 1.0
 
 
 def apply_rope(

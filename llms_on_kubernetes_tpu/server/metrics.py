@@ -564,12 +564,21 @@ def engine_metrics(registry: Registry) -> dict:
         "moe_expert_slots": Counter(
             "llm_moe_expert_slots_total",
             "Experts there were to touch: token steps x expert layers x "
-            "experts (a window step in which no row was live is no step)",
+            "experts HELD here (all of them, unless the model holds one "
+            "chip's share; a window step in which no row was live is no "
+            "step)",
             registry, label_names=("kind",)),
         "moe_routed_rows": Counter(
             "llm_moe_routed_rows_total",
-            "(token, expert) pairs routed: live rows x experts per token "
-            "x expert layers, summed over token steps",
+            "(token, expert) pairs routed, to any expert: live rows x "
+            "experts per token x expert layers, summed over token steps",
+            registry, label_names=("kind",)),
+        "moe_held_rows": Counter(
+            "llm_moe_held_rows_total",
+            "(token, expert) pairs that fell on experts held here, the rows "
+            "the grouped product multiplied; equals "
+            "llm_moe_routed_rows_total unless the model holds a share of "
+            "its experts (then about held / experts of it at even routing)",
             registry, label_names=("kind",)),
         "moe_fullest_expert_rows": Counter(
             "llm_moe_fullest_expert_rows_total",
@@ -580,6 +589,19 @@ def engine_metrics(registry: Registry) -> dict:
             "llm_moe_mean_expert_rows_total",
             "Rows of the mean expert of each expert layer (routed rows / "
             "experts), summed", registry, label_names=("kind",)),
+        "mla_tokens": Counter(
+            "llm_mla_tokens_total",
+            "Tokens a latent-attention model (MLA) attended by each path: "
+            "prefill=a bucket over its own rows, expanded to heads; "
+            "chunk=a chunk over cached latent rows and its own, expanded; "
+            "decode=one token a step over cached rows, absorbed. 0 for a "
+            "model without latent attention",
+            registry, label_names=("path",)),
+        "latent_cache_bytes": Gauge(
+            "llm_latent_cache_bytes",
+            "Device bytes of the latent pool (one latent row a token a "
+            "layer, no V side) of a latent-attention model; 0 for a model "
+            "with K and V pools", registry),
         "conv_state_bytes": Gauge(
             "llm_conv_state_bytes",
             "Device bytes of the per-slot short-convolution state that "
@@ -613,6 +635,8 @@ def engine_metrics(registry: Registry) -> dict:
     )
 
     m["prefix_reuse_skipped"].labels(why="recurrent_state")
+    for path in ("prefill", "chunk", "decode"):
+        m["mla_tokens"].labels(path=path)
     for kind in ("prefill", "chunk", "decode"):
         for stat in MOE_STATS:
             m["moe_" + stat].labels(kind=kind)

@@ -207,6 +207,7 @@ class EngineLoop(threading.Thread):
         self._ttft_seen: set[str] = set()
         self._preempt_seen = 0
         self._moe_seen: collections.Counter = collections.Counter()
+        self._mla_seen: collections.Counter = collections.Counter()
         self._prefix_skipped_seen: collections.Counter = (
             collections.Counter())
         self._early_exit_seen = 0
@@ -386,6 +387,15 @@ class EngineLoop(threading.Thread):
                 cc = getattr(eng, "cache_config", None)
                 if cc is not None:
                     m["kv_bytes_per_token"].set(cc.bytes_per_token)
+                if getattr(eng.model_config, "is_mla", False):
+                    paths = dict(eng.path_tokens, decode=eng.decode_tokens)
+                    for path, v in paths.items():
+                        if v > self._mla_seen[path]:
+                            m["mla_tokens"].labels(path=path).inc(
+                                v - self._mla_seen[path])
+                            self._mla_seen[path] = v
+                    kp = eng.k_pages.data
+                    m["latent_cache_bytes"].set(kp.size * kp.dtype.itemsize)
                 conv = getattr(eng, "conv_state", None)
                 m["conv_state_bytes"].set(
                     0 if conv is None else conv.size * conv.dtype.itemsize)

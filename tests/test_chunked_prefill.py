@@ -153,7 +153,10 @@ def test_preempted_long_request_resumes_chunked(async_sched):
 
     tight = make_engine(num_pages=12, pages_per_slot=12, max_decode_slots=2,
                         async_scheduling=async_sched)
-    first = tight.submit(rng.integers(0, 256, size=9).tolist(), p)
+    # (the older stream decodes while the long prompt's chunks are written:
+    # it needs tokens enough to still hold its pages when the pool runs out)
+    first = tight.submit(rng.integers(0, 256, size=9).tolist(),
+                         SamplingParams(max_tokens=16, **GREEDY))
     second = tight.submit(long_prompt, p)
     for _ in range(500):
         if not tight.has_work():

@@ -561,3 +561,77 @@ def test_grouped_kernel_is_given_the_vmem_the_dispatcher_counted(monkeypatch):
                            + pallas_grouped._VMEM_HEADROOM)
         limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
         assert limit == counted <= attention.VMEM_BUDGET_BYTES
+
+
+# ---------------------------------------------------------------------------
+# deepseek-v3's cell: the engine's real-size steps for a described v5e
+# ---------------------------------------------------------------------------
+
+# the cell's geometry (benchmark/configs/deepseek-v3.json): 32 slots of 144
+# pages of 64 tokens, 4609 pages; step -> (rows, tokens a row)
+DEEPSEEK = "deepseek-v3@0,3-7+experts0-15+vocab0-16159"
+DEEPSEEK_STEPS = {"decode, K = 4": (32, 1), "prefill 1 x 2048": (1, 2048),
+                  "chunk 1 x 2048": (1, 2048)}
+V5E_BYTES = 16.9e9
+
+
+@pytest.mark.parametrize("step", sorted(DEEPSEEK_STEPS))
+def test_deepseek_v3_steps_fit_a_v5e_and_leave_the_latent_pool_in_place(
+        one_chip, no_cache, monkeypatch, step):
+    """The fused decode window, a 2,048-token bucket and a 2,048-token
+    chunk over a 9,216-token window, at the published widths with the
+    cell's weights (11.0 GB) and latent pool (2.27 GB): each compiles for a
+    v5e with at least a gigabyte to spare, the pool goes in and comes out
+    in one buffer, and no step copies it (a 576-wide row, left unpadded,
+    was re-laid out around every write: 386 pool-sized copies a window)."""
+    from llms_on_kubernetes_tpu.configs import get_config
+    from llms_on_kubernetes_tpu.engine import engine as E
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
+    from llms_on_kubernetes_tpu.models import decoder
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
+    cfg = get_config(DEEPSEEK)
+    slots, page, pps, pages = 32, 64, 144, 4609
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: decoder.init_params(cfg, jax.random.key(0),
+                                        dtype="bfloat16")))
+    pool = KVPool(sds((1, cfg.num_attn_layers * pages, page, 640),
+                      jnp.bfloat16))
+    no_v = KVPool(sds((1, 1, 1, 1), jnp.bfloat16))
+    counts = sds((slots, cfg.vocab_size), jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    rows, tokens = DEEPSEEK_STEPS[step]
+    if step.startswith("decode"):
+        compiled = jax.jit(
+            E._decode_multi_packed_step, static_argnums=(1, 2),
+            donate_argnums=(6, 7, 8, 11)).lower(
+                params, cfg, 4, sds((rows, E._DEC_COLS + pps), jnp.int32),
+                sds((rows,), jnp.int32), sds((1,), jnp.int32), pool, no_v,
+                counts, key, None, None).compile()
+    else:
+        fn, cols = ((E._prefill_packed_step, E._PRE_COLS)
+                    if step.startswith("prefill")
+                    else (E._chunk_packed_step, E._CHK_COLS))
+        compiled = jax.jit(fn, static_argnums=(1,),
+                           donate_argnums=(4, 5, 6, 9)).lower(
+            params, cfg, sds((rows, tokens), jnp.int32),
+            sds((rows, cols + pps), jnp.int32), pool, no_v, counts, key,
+            None, None).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = cfg.num_attn_layers * pages * page * 640 * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert peak < V5E_BYTES - 1e9, (step, peak)
+    assert not _pool_shaped_copies(compiled.as_text(), pool)
+    kind = step.split()[0].rstrip(",")
+    assert attention._chosen[kind][0] == "xla"
+    assert attention._chosen["experts"][0] == "pallas-compiled"
