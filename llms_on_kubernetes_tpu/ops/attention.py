@@ -327,6 +327,13 @@ def chunk_attention(
 # [H, lat, v] are head-major, so each of these is a matmul batched over
 # heads with the weight as it lies. Operands keep their type (bfloat16 on
 # the chip) and every product accumulates in float32.
+# The absorbed step has a kernel (pallas_paged.pallas_latent_attention,
+# chosen by dispatch_latent_decode): the paged decode pipeline with one kv
+# head of 640 lanes, 128 query heads to it and ONE pool that is key and, in
+# its first lat lanes, value, so a live slot's pages cross HBM -> VMEM once
+# and an idle slot's never. latent_paged_attention below is its reference
+# and what every other backend, and a mesh, serve with. The expanded paths
+# (buckets, chunks) have no kernel yet.
 
 LATENT_KEY_BLOCK = 256
 
@@ -494,15 +501,63 @@ def dispatch_latent_chunk(qn, qr, pool, page_table, w_uk, w_uv, history,
         history + chunk_lengths, scale=scale)
 
 
+def _latent_kernel_mode(pool, page_table):
+    """(mode, why not) for the latent decode kernel on this pool, as
+    ``_paged_kernel_mode`` is for the K/V pools' kernels: from the pool's
+    stored shape and type, the page table's width and the active mesh."""
+    from llms_on_kubernetes_tpu.ops.pallas_paged import latent_vmem_bytes
+    from llms_on_kubernetes_tpu.parallel.mesh import seq_parallelism
+
+    mode = pallas_mode()
+    if mode is None:
+        return None, _no_pallas_why()
+    if _model_shards() > 1 or seq_parallelism() > 1:
+        # one row a token that every head shares: no head axis to give
+        # each chip its own part of, as _per_kv_head_shard does
+        return None, (f"a mesh of model {_model_shards()} x seq "
+                      f"{seq_parallelism()}: a latent pool has one head, "
+                      "the kernel is not partitioned")
+    _, _, page, width = pool.shape
+    if mode == "compiled":
+        # Mosaic's tiling, as for the K/V pools: a page DMA is whole
+        # 128-lane rows landing at a multiple of 8 sublanes
+        if width % 128 != 0:
+            return None, f"a pool row of {width} is not a multiple of 128"
+        if page % 8 != 0:
+            return None, f"a page of {page} is not a multiple of 8"
+    need = latent_vmem_bytes(page, page_table.shape[1], width, pool.dtype)
+    if need > VMEM_BUDGET_BYTES:
+        return None, (f"a block of {width}-lane rows needs {_mib(need)} "
+                      f"VMEM > {_mib(VMEM_BUDGET_BYTES)} budget")
+    return mode, ""
+
+
 def dispatch_latent_decode(q_abs, pool, page_table, lengths, *, scale, lat):
-    page = getattr(pool, "data", pool).shape[2]
-    _choose("decode", "xla",
-            f"absorbed: {q_abs.shape[1]} query heads over one "
-            f"{q_abs.shape[-1]}-wide row a token, gathered "
-            f"{LATENT_DECODE_PAGES * page} tokens of every slot at a time "
-            f"as far as the longest slot's last block; no latent kernel")
-    return latent_paged_attention(q_abs, pool, page_table, lengths,
-                                  scale=scale, lat=lat)
+    """The absorbed decode step: the latent kernel
+    (pallas_paged.pallas_latent_attention: a program a slot, each live
+    slot's pages streamed through VMEM once, keys and values from the same
+    block) wherever ``_latent_kernel_mode`` lets it, else the XLA loop,
+    with its reason."""
+    data = getattr(pool, "data", pool)
+    heads, width = q_abs.shape[1], data.shape[3]
+    mode, why = _latent_kernel_mode(data, page_table)
+    if mode is None:
+        _choose("decode", "xla",
+                f"absorbed: {heads} query heads over one "
+                f"{q_abs.shape[-1]}-wide row a token, gathered "
+                f"{LATENT_DECODE_PAGES * data.shape[2]} tokens of every slot "
+                f"at a time as far as the longest slot's last block; {why}")
+        return latent_paged_attention(q_abs, pool, page_table, lengths,
+                                      scale=scale, lat=lat)
+
+    from llms_on_kubernetes_tpu.ops.pallas_paged import pallas_latent_attention
+
+    _choose("decode", f"pallas-{mode}",
+            f"latent: {heads} query heads over one {width}-lane row a "
+            "token, live pages only")
+    return pallas_latent_attention(q_abs, data, page_table, lengths,
+                                   scale=scale, lat=lat,
+                                   interpret=mode == "interpret")
 
 
 # ---------------------------------------------------------------------------

@@ -73,6 +73,22 @@ slot's pages HBM→VMEM once and attend in place:
   checked against ``attention.VMEM_BUDGET_BYTES`` where the kernel is
   chosen. Staging a block and not a slot makes it independent of the
   slot's length.
+- The LATENT pool (DeepSeek MLA: engine/cache.py, [1, P, page, width], one
+  row a token that all heads share, every layer's pages in one array) has
+  a fifth attending kernel, ``pallas_latent_attention``, for the absorbed
+  decode step: the same pipeline as the case n_kv = 1, group = the 128
+  query heads, with ONE source, because a row is the key ([c | k_r], 576
+  of 640 lanes, zeros behind) and its first 512 lanes are the value. A
+  live slot's pages are fetched once and the value operand is a slice of
+  the staged key block; staging is [2, 1, 512, 640] bf16 = 1.3 MB
+  (``latent_vmem_bytes``). 128 heads over one shared row is ~230 FLOP a
+  byte, the v5e's ridge, so its block body (``_attend_latent_block``)
+  hands the MXU the pool's own type with float32 accumulation, as the XLA
+  loop it replaces does, where ``_attend_block`` widens K and V to float32
+  (free at a group of 4). No window, no softcap, no scales, and no append:
+  the step's rows are written by ``cache.write_latent`` before the call
+  (192 one-row updates a step), and the pool goes in whole in HBM, read
+  only. The four K/V kernels trace what they traced before it existed.
 """
 
 from __future__ import annotations
@@ -188,10 +204,58 @@ def _attend_block(q, carry, k, v, ks, vs, start, n_valid, q_pos, *,
     return m_new, l, acc
 
 
+def _attend_latent_block(q, carry, buf, half, start, n_valid, *, scale: float,
+                         lat: int):
+    """``_attend_block`` for a latent pool: fold the block staged in
+    ``buf[half]`` ([1, blk, width], rows ``start`` ..., those below
+    ``n_valid`` written) into the partials of q [1, heads, width]. A row is
+    every head's key and, in its first ``lat`` lanes, every head's value:
+    the value operand is a slice of the block the scores were taken from.
+
+    Both products take their operands in the POOL'S type and accumulate in
+    float32, as ``attention.latent_paged_attention`` does. All the heads
+    share a row, so a block is ~230 FLOP a byte, the v5e's ridge: widened
+    to float32 first, as ``_attend_block`` can afford at a group of 4, the
+    products would bound the kernel several times over.
+
+    Rows that were not fetched or lie beyond ``n_valid`` may hold anything:
+    their scores are replaced and their probabilities zeroed as in
+    ``_attend_block``, and, value operand that they are, they are zeroed IN
+    the staging half first (0 * NaN is NaN): only in a block that has such
+    rows, a row's last. The half is this program's until the next fetch
+    into it, which starts after this block."""
+    m, l, acc = (x[0] for x in carry)
+    heads, blk = q.shape[1], buf.shape[2]
+
+    @pl.when(start + blk > n_valid)
+    def _zero_stale_rows():
+        row = start + jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+        buf[half, 0] = jnp.where(
+            row < n_valid, buf[half, 0].astype(jnp.float32), 0.0
+        ).astype(buf.dtype)
+
+    rows = buf[half, 0]                                # [blk, width]
+    both = jnp.promote_types(q.dtype, rows.dtype)      # one type on the chip
+    s = jax.lax.dot_general(
+        q[0].astype(both), rows.astype(both), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale    # [heads, blk]
+    mask = start + jax.lax.broadcasted_iota(
+        jnp.int32, (heads, blk), 1) < n_valid
+    s = jnp.where(mask, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m - m_new)
+    l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+    acc = alpha * acc + jnp.dot(p.astype(rows.dtype), rows[:, :lat],
+                                preferred_element_type=jnp.float32)
+    return m_new[None], l[None], acc[None]
+
+
 def _attend_pipelined(q, page_table_ref, lengths_ref, srcs, bufs, sems, st, *,
                       cur: int, append, page_size: int, pages_per_seq: int,
                       scale: float, sliding_window: Optional[int],
-                      attn_softcap: Optional[float]):
+                      attn_softcap: Optional[float],
+                      latent: Optional[int] = None):
     """This program's part of the launch-wide pipeline (module docstring):
     online-softmax attention of q [n_kv, group, d] (f32) over the cached
     keys of row ``program_id(0)``, one ``blk``-token block at a time, each
@@ -213,6 +277,11 @@ def _attend_pipelined(q, page_table_ref, lengths_ref, srcs, bufs, sems, st, *,
     into a 2048-token window must not pay 20x its KV bandwidth), and only
     blocks a static sliding window reaches; what else a half holds is
     masked (``_attend_block``).
+
+    ``latent`` (the latent kernel alone): the ONE source is a latent pool
+    whose row is key and, in its first ``latent`` lanes, value; a block is
+    folded by ``_attend_latent_block`` in the pool's type and the partial
+    ``acc`` is ``latent`` wide.
 
     Returns the partials (m [n_kv, group, 1], l [n_kv, group, 1],
     acc [n_kv, group, d]); the caller divides (and, in the write kernels,
@@ -295,6 +364,10 @@ def _attend_pipelined(q, page_table_ref, lengths_ref, srcs, bufs, sems, st, *,
         if append is not None:
             pl.when(last)(append.write)
 
+        if latent is not None:
+            return _attend_latent_block(
+                q, carry, bufs[0], half, (lo + j) * blk, length - cur,
+                scale=scale, lat=latent)
         k, v, *scales = (buf[half] for buf in bufs)
         ks, vs = scales or (None, None)
         return _attend_block(
@@ -304,7 +377,7 @@ def _attend_pipelined(q, page_table_ref, lengths_ref, srcs, bufs, sems, st, *,
 
     init = (jnp.full((n_kv, group, 1), NEG_INF, jnp.float32),
             jnp.zeros((n_kv, group, 1), jnp.float32),
-            jnp.zeros((n_kv, group, d), jnp.float32))
+            jnp.zeros((n_kv, group, latent or d), jnp.float32))
     part = jax.lax.fori_loop(0, n, body, init)
 
     if append is not None:
@@ -760,6 +833,89 @@ def pallas_paged_attention_write_int8(
         (k_new.astype(jnp.float32), v_new.astype(jnp.float32)),
         scale=scale, sliding_window=sliding_window,
         attn_softcap=attn_softcap, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# The latent pool's decode kernel (DeepSeek MLA, absorbed)
+# ---------------------------------------------------------------------------
+
+def latent_vmem_bytes(page_size: int, pages_per_seq: int, width: int,
+                      dtype) -> int:
+    """VMEM the latent kernel is given (``paged_vmem_bytes``' rule): both
+    halves of its ONE staging, 1.3 MB at 512 tokens of 640 bfloat16 lanes
+    however long the slot, and ``_VMEM_HEADROOM``."""
+    blk = _block_tokens(page_size, pages_per_seq)
+    return _VMEM_HEADROOM + 2 * blk * width * jnp.dtype(dtype).itemsize
+
+
+def _latent_kernel(
+    page_table_ref,   # SMEM [B, pages_per_seq] (scalar prefetch)
+    lengths_ref,      # SMEM [B]                (scalar prefetch)
+    q_ref,            # VMEM [1, 1, heads, width] absorbed queries, zero-padded
+    pool_hbm,         # ANY  [1, P, page, width]  every layer's latent rows
+    o_ref,            # VMEM [1, 1, heads, lat]
+    buf,              # VMEM [2, 1, blk, width] staging, both halves
+    sems,             # DMA semaphores [2, 1, pages a block]
+    st,               # SMEM [3] the pipeline's carried state
+    **kw,             # _attend_pipelined's: scale, latent, geometry
+):
+    """The pipeline's case of ONE kv head whose row every query head
+    shares (n_kv = 1, group = heads): a live slot's pages come HBM -> VMEM
+    once and serve as keys and as values; an idle slot moves nothing. The
+    current token's row is already in the pool (``cache.write_latent``), so
+    nothing is merged from registers (``cur`` = 0). q stays in its type:
+    it is an operand of the product (``_attend_latent_block``)."""
+    _, l, acc = _attend_pipelined(
+        q_ref[0], page_table_ref, lengths_ref, (pool_hbm,), (buf,), sems, st,
+        cur=0, append=None, sliding_window=None, attn_softcap=None, **kw)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "lat", "interpret"))
+def pallas_latent_attention(
+    q_abs: jnp.ndarray,        # [B, heads, <= width] = [q_n W_UK^T | q_r]
+    pool: jnp.ndarray,         # [1, P, page, width]; past lat + rope, zeros
+    page_table: jnp.ndarray,   # [B, pages_per_seq] int32 (this layer's pages)
+    lengths: jnp.ndarray,      # [B] int32 (incl. current token; 0 => idle)
+    *,
+    scale: float,
+    lat: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``attention.latent_paged_attention`` as a kernel: o_lat [B, heads,
+    lat] in q_abs' type. The pool is read where it lies, whole in HBM."""
+    B, heads, w = q_abs.shape
+    _, _, page_size, width = pool.shape
+    pages_per_seq = page_table.shape[1]
+    blk = _block_tokens(page_size, pages_per_seq)
+    q = jnp.pad(q_abs, ((0, 0), (0, 0), (0, width - w)))[:, None]
+
+    def row_block(d):
+        return pl.BlockSpec((1, 1, heads, d), lambda b, *_: (b, 0, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, page_size=page_size,
+                          pages_per_seq=pages_per_seq, scale=scale,
+                          latent=lat),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[row_block(width), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row_block(lat),
+            # both halves of ONE staging (a row is key and value), a DMA
+            # semaphore a half and page, the pipeline's state
+            scratch_shapes=[
+                pltpu.VMEM((2, 1, blk, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 1, blk // page_size)),
+                pltpu.SMEM((3,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, heads, lat), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=latent_vmem_bytes(
+                page_size, pages_per_seq, width, pool.dtype)),
+        interpret=check_interpret(interpret),
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
+    return out[:, 0]
 
 
 def _paged_kernel_write_window(

@@ -1131,7 +1131,11 @@ class OpenAIServer:
         ``"experts"`` the rows each expert of each expert layer got in
         the newest dispatch whose tokens were read, and ``"product"``:
         which grouped product ops/moe.py last chose, as its
-        ``[attention] op=experts`` log line says it.
+        ``[attention] op=experts`` log line says it, and under
+        ``"attention"`` what every dispatcher of ops/attention.py last
+        chose at trace time, ``{op: "impl (why)"}`` (the same lines'
+        ``op=prefill|chunk|decode|experts``: which kernel the steps this
+        process compiled run, the latent decode kernel among them).
         ``?limit=N`` trims the first two to the most recent N."""
         limit = self._int_query(request, "limit", 0) or None
         snap = self.flight.snapshot(limit=limit)
@@ -1144,6 +1148,10 @@ class OpenAIServer:
         # the newest booked dispatch's rows by expert layer and expert
         # (a decode window: its last live token step); None before one
         snap["experts"] = getattr(self.engine, "moe_last", None)
+        from llms_on_kubernetes_tpu.ops import attention
+
+        snap["attention"] = {op: f"{impl} ({why})" for op, (impl, why)
+                             in sorted(attention._chosen.items())}
         snap["state"] = self.state
         snap["model"] = self.model_name
         snap["role"] = self.engine.config.role or "both"

@@ -461,6 +461,50 @@ def test_the_prefix_cache_adopts_latent_pages(params32):
     held_to_reference(params32, b)
 
 
+@pytest.mark.parametrize("impl,said", [
+    ("auto", "xla (absorbed: 4 query heads over one"),
+    ("pallas", "pallas-interpret (latent: 4 query heads over one 128-lane "
+               "row a token, live pages only)"),
+])
+def test_debug_engine_lists_the_attention_records(params32, monkeypatch, impl,
+                                                  said):
+    """``GET /debug/engine`` says under ``"attention"`` what each dispatcher
+    last chose, the absorbed decode step's among them: the XLA loop with
+    its reason on the CPU backend, the latent kernel where the kernels run
+    (interpreted here), whose streams are the reference's too."""
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from llms_on_kubernetes_tpu.engine.tokenizer import ByteTokenizer
+    from llms_on_kubernetes_tpu.server.openai_api import OpenAIServer
+
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", impl)
+    jax.clear_caches()        # the engine's step traces outlive an Engine
+    attention._chosen.clear()
+    eng = engine(params32)
+    req = submit(eng, prompt(7, 61), 9)
+    run(eng, [req])
+    held_to_reference(params32, req)
+
+    async def body():
+        client = TestClient(TestServer(
+            OpenAIServer(eng, ByteTokenizer(), NAME).make_app()))
+        await client.start_server()
+        try:
+            return await (await client.get("/debug/engine")).json()
+        finally:
+            await client.close()
+
+    try:
+        snap = asyncio.run(body())
+    finally:
+        jax.clear_caches()
+    assert set(snap["attention"]) >= {"prefill", "decode", "experts"}
+    assert snap["attention"]["decode"].startswith(said)
+    assert snap["attention"]["prefill"].startswith("xla (latent rows expanded")
+
+
 def test_preemption_and_resume(params32):
     eng = engine(params32, max_decode_slots=2, num_pages=7)
     reqs = [submit(eng, prompt(12, 41), 18), submit(eng, prompt(12, 42), 18)]

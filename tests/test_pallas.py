@@ -325,6 +325,9 @@ for case in sorted(T.PIPELINE_CASES):
                            interpret=deferred, window=window, share=share)
 test_kv_int8.test_fused_write_int8_k1_matches_write_tokens(
     9, None, "three blocks", interpret=deferred)
+for case in ("idle rows among live ones", "page 64, three blocks",
+             "a stale staging half holds NaN beyond n_valid"):
+    T.check_latent_case(np.random.default_rng(0), case, interpret=deferred)
 from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu
 print("races", tpu.races.races_found)
 """
@@ -655,6 +658,136 @@ def test_gate_names_the_model_axis_that_would_split_a_pair(monkeypatch):
     assert mode is None and why == (
         "head_dim 64 is not a multiple of 128 and a model axis of 4 does "
         "not divide 2 pairs of heads")
+
+
+# ---------------------------------------------------------------------------
+# the latent pool's decode kernel (DeepSeek MLA, absorbed)
+# ---------------------------------------------------------------------------
+
+LAT, ROPE, WIDTH, HEADS = 16, 8, 32, 4
+# name -> (page, pages a slot, lengths incl. the current token, rows whose
+# first two pages are ONE pair of adopted prefix pages, pool type). A page
+# of 16 x 40 pages is two blocks of 320 tokens; 64 x 24 three of 512.
+LATENT_CASES = {
+    "idle rows among live ones": (16, 40, [0, 70, 0, 0, 330, 0], (), "float32"),
+    "all rows idle": (16, 40, [0, 0, 0], (), "float32"),
+    "a length of 1": (16, 40, [1, 300, 1], (), "float32"),
+    "lengths off a block and off a page boundary":
+        (16, 40, [321, 319, 17, 15, 640, 639], (), "float32"),
+    "a row of all 144 pages beside a row of one":
+        (64, 144, [9216, 5], (), "float32"),
+    "two rows share prefix pages": (16, 40, [200, 0, 300], (0, 2), "float32"),
+    "page 16, three blocks": (16, 96, [1500, 40, 1536], (), "float32"),
+    "page 64, three blocks": (64, 24, [1500, 40, 0, 1536, 33], (), "float32"),
+    "bfloat16 pool": (64, 24, [1500, 40, 0, 1536, 33], (), "bfloat16"),
+    "float32 pool, float32 queries": (64, 24, [600, 0, 513], (), "float32"),
+    # row 0's second block leaves its last page's unwritten rows (NaN here)
+    # at offsets 188-191 of staging half 1; row 2's second block lands in
+    # that half and fetches offsets 0-127 only: the NaN lies beyond its
+    # n_valid (88) in a block that is attended
+    "a stale staging half holds NaN beyond n_valid":
+        (64, 24, [700, 0, 600], (), "float32"),
+}
+
+
+def latent_case(rng, case):
+    """(q_abs, clean pool, poisoned pool, table, lengths) of a latent
+    case. The poisoned pool holds NaN wherever no row has written: page 0,
+    the pages no table names and a row's last page past its length (the
+    kernel must never let them through; the XLA loop multiplies them by a
+    zero probability, so it is given the clean pool)."""
+    page, pps, lengths, share, dtype = LATENT_CASES[case]
+    lengths = np.asarray(lengths, np.int32)
+    B, P = len(lengths), len(lengths) * pps + 1
+    pool = rng.normal(size=(1, P, page, WIDTH)).astype(np.float32)
+    pool[..., LAT + ROPE:] = 0.0
+    table = np.zeros((B, pps), np.int32)
+    perm = rng.permutation(P - 1) + 1
+    written = np.zeros((P, page), bool)
+    for b in range(B):
+        used = -(-lengths[b] // page)
+        table[b, :used] = perm[b * pps:b * pps + used]
+    for b in share[1:]:
+        table[b, :2] = table[share[0], :2]
+    for b in range(B):
+        for t in range(lengths[b]):
+            written[table[b, t // page], t % page] = True
+    poisoned = np.where(written[None, :, :, None], pool, np.nan)
+    q = jnp.asarray(rng.normal(size=(B, HEADS, LAT + ROPE)), dtype)
+    return (q, jnp.asarray(pool, dtype), jnp.asarray(poisoned, dtype),
+            jnp.asarray(table), jnp.asarray(lengths))
+
+
+def check_latent_case(rng, case, interpret=True):
+    from llms_on_kubernetes_tpu.ops.attention import latent_paged_attention
+    from llms_on_kubernetes_tpu.ops.pallas_paged import pallas_latent_attention
+
+    q, pool, poisoned, table, lengths = latent_case(rng, case)
+    ref = latent_paged_attention(q, pool, table, lengths, scale=0.2, lat=LAT)
+    out = pallas_latent_attention(q, poisoned, table, lengths, scale=0.2,
+                                  lat=LAT, interpret=interpret)
+    assert out.shape == (len(lengths), HEADS, LAT) and out.dtype == q.dtype
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    act = np.asarray(lengths) > 0
+    tol = 2e-2 if q.dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(out[act], ref[act], rtol=tol, atol=tol)
+    assert (out[~act] == 0).all()     # an idle row moves nothing: zeros
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_decode_kernel_matches_reference(rng, case):
+    """The latent kernel (one kv head whose row all query heads share, key
+    and value from the same staged block, products in the pool's type)
+    against ``latent_paged_attention`` on what the pipeline across rows
+    can get wrong, with NaN wherever nothing was written."""
+    check_latent_case(rng, case)
+
+
+def test_latent_dispatcher_says_why_it_took_the_xla_loop(rng, monkeypatch):
+    """``dispatch_latent_decode`` takes the kernel where it can and
+    otherwise the XLA loop, recording the reason: the CPU backend, a mesh
+    (a latent pool has one head: nothing to give each chip), a page no
+    multiple of 8 where Mosaic compiles it."""
+    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.parallel.mesh import (
+        make_mesh, set_active_mesh,
+    )
+
+    q, pool, _, table, lengths = latent_case(rng, "a length of 1")
+
+    def took():
+        attention._chosen.clear()
+        out = attention.dispatch_latent_decode(q, pool, table, lengths,
+                                               scale=0.2, lat=LAT)
+        return out, attention._chosen["decode"]
+
+    monkeypatch.delenv("LLMK_ATTENTION_IMPL", raising=False)
+    ref, (impl, why) = took()
+    assert impl == "xla" and why.startswith("absorbed: 4 query heads")
+    assert why.endswith("last block; cpu backend")
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    out, said = took()
+    assert said == ("pallas-interpret", "latent: 4 query heads over one "
+                    "32-lane row a token, live pages only")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
+                               atol=2e-5)
+    set_active_mesh(make_mesh(model=2, devices=jax.devices()[:2]))
+    try:
+        _, (impl, why) = took()
+    finally:
+        set_active_mesh(None)
+    assert impl == "xla" and "a mesh of model 2 x seq 1" in why
+    # on the chip: Mosaic's tiling decides, from the pool's stored shape
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    for shape, reason in (((1, 3, 4, 128), "a page of 4 is not a multiple "
+                                           "of 8"),
+                          ((1, 3, 16, 576), "a pool row of 576 is not a "
+                                            "multiple of 128")):
+        mode, why = attention._latent_kernel_mode(
+            jnp.zeros(shape, jnp.bfloat16), table)
+        assert mode is None and why == reason
+    assert attention._latent_kernel_mode(
+        jnp.zeros((1, 3, 64, 640), jnp.bfloat16), table) == ("compiled", "")
 
 
 # ---------------------------------------------------------------------------

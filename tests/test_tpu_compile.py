@@ -147,31 +147,48 @@ def test_decode_append_rides_the_kernel_in_place(one_chip, no_cache,
     assert mem.temp_size_in_bytes < pools // 8     # no copy of a pool
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
+LATENT_CASE = "latent 640-lane rows (deepseek-v3's cell)"
+
+
+@pytest.mark.parametrize("case", sorted(CASES) + [LATENT_CASE])
 def test_kernel_is_given_the_vmem_the_dispatcher_counted(monkeypatch, case):
     """``paged_vmem_bytes``, which the dispatcher holds against the budget,
     is what the kernel hands Mosaic as its limit, and is the kernel's own
     VMEM scratch (both halves of the staging and of the write blocks) plus
-    the headroom: a block's worth, whatever the slot's length."""
+    the headroom: a block's worth, whatever the slot's length. The latent
+    kernel's ``latent_vmem_bytes`` likewise: ONE staging, 1.3 MB."""
     from llms_on_kubernetes_tpu.ops import attention, pallas_paged
 
-    kv_dtype, page, pps, _ = CASES[case]
     monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
-    args = _operands(None, kv_dtype, page, pps)
-    eqn = _pallas_call(jax.make_jaxpr(
-        lambda *a: attention.dispatch_paged_attention_write(
-            *a, scale=D ** -0.5, sliding_window=4096))(*args).jaxpr)
+    if case == LATENT_CASE:
+        page, pps = 64, 144
+        pool = jax.ShapeDtypeStruct((1, 6 * 4609, page, 640), jnp.bfloat16)
+        eqn = _pallas_call(jax.make_jaxpr(
+            lambda *a: attention.dispatch_latent_decode(
+                *a, scale=0.1, lat=512))(
+            jax.ShapeDtypeStruct((ROWS, 128, 576), jnp.bfloat16), pool,
+            jax.ShapeDtypeStruct((ROWS, pps), jnp.int32),
+            jax.ShapeDtypeStruct((ROWS,), jnp.int32)).jaxpr)
+        counted = pallas_paged.latent_vmem_bytes(page, pps, 640, pool.dtype)
+        most = 2 * 512 * 640 * 2                   # both halves of a block
+    else:
+        kv_dtype, page, pps, _ = CASES[case]
+        args = _operands(None, kv_dtype, page, pps)
+        eqn = _pallas_call(jax.make_jaxpr(
+            lambda *a: attention.dispatch_paged_attention_write(
+                *a, scale=D ** -0.5, sliding_window=4096))(*args).jaxpr)
+        counted = pallas_paged.paged_vmem_bytes(
+            N_KV, page, pps, D, args[1].data.dtype, kv_dtype == "int8")
+        most = (6 << 20) - 1                       # 4 MiB + the write blocks
     scratch = sum(
         ref.size * ref.dtype.itemsize
         for ref in eqn.params["grid_mapping"].scratch_avals
         if "vmem" in str(ref.memory_space).lower())
-    counted = pallas_paged.paged_vmem_bytes(
-        N_KV, page, pps, D, args[1].data.dtype, kv_dtype == "int8")
     assert counted == scratch + pallas_paged._VMEM_HEADROOM
     limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
     assert limit == counted <= attention.VMEM_BUDGET_BYTES
     block = pallas_paged._block_tokens(page, pps)
-    assert block == 512 and scratch < 6 << 20   # 4 MiB + the write blocks
+    assert block == 512 and scratch <= most
 
 
 def test_two_op_setting_still_compiles_the_dus_loop(one_chip, no_cache,
@@ -630,8 +647,12 @@ def test_deepseek_v3_steps_fit_a_v5e_and_leave_the_latent_pool_in_place(
     assert mem.alias_size_in_bytes >= pool_bytes
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"[deepseek_compile] {step}: temp {mem.temp_size_in_bytes} B, "
+          f"peak {peak} B, {compiled.as_text().count(chr(10))} HLO lines")
     assert peak < V5E_BYTES - 1e9, (step, peak)
     assert not _pool_shaped_copies(compiled.as_text(), pool)
     kind = step.split()[0].rstrip(",")
-    assert attention._chosen[kind][0] == "xla"
+    # the token step rides the latent kernel; the prompts' paths have none
+    assert attention._chosen[kind][0] == (
+        "pallas-compiled" if kind == "decode" else "xla")
     assert attention._chosen["experts"][0] == "pallas-compiled"

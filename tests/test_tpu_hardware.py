@@ -1010,6 +1010,93 @@ def test_cell64_greedy_streams_against_float32_reference(monkeypatch):
             1.5 * b["decode_median_nats"], 0.1), said
 
 
+# deepseek-v3.long-prompts' decode step: 32 slots of 144 pages of 64 tokens,
+# six layers' pages in one latent pool of 640-lane rows, 128 heads, 14 slots
+# live (decode_occupancy 43-44 %) at lengths drawn as the mix draws them: a
+# prompt log-normal around 2,048 (sigma 0.8, 256-8,192) and part of an answer
+LATENT = dict(slots=32, live=14, page=64, pps=144, pages=4609, layers=6,
+              heads=128, lat=512, rope=64, width=640)
+
+
+def _latent_cell_case(seed=43):
+    g = LATENT
+    rng = np.random.default_rng(seed)
+    prompts = np.clip(np.exp(rng.normal(np.log(2048), 0.8, g["live"])),
+                      256, 8192)
+    lengths = np.zeros(g["slots"], np.int32)
+    rows = np.sort(rng.choice(g["slots"], g["live"], replace=False))
+    lengths[rows] = prompts + rng.integers(1, 384, g["live"])
+    pool = jax.random.normal(
+        jax.random.key(seed),
+        (1, g["layers"] * g["pages"], g["page"], g["width"]), jnp.bfloat16)
+    pool = pool.at[..., g["lat"] + g["rope"]:].set(0)
+    pt = jnp.asarray(1 + np.arange(g["slots"] * g["pps"]).reshape(
+        g["slots"], g["pps"]), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(g["slots"], g["heads"],
+                                     g["lat"] + g["rope"])), jnp.bfloat16)
+    return pool, pt, jnp.asarray(lengths), q
+
+
+def test_deepseek_cell_latent_kernel_beside_the_xla_loop(monkeypatch):
+    """The absorbed decode step's attention at the cell's shape, alone: the
+    latent kernel and the XLA loop it replaces (a block of 8 pages of all
+    32 slots gathered at a time, as far as the longest slot), six layers'
+    tables in turn, 60 calls chained through q inside one executable; the
+    kernel again with its DMAs alone and with its arithmetic alone
+    (test_cell_kernel_time_fetch_attend_both's split); and what the two
+    answer on the same rows."""
+    import time
+
+    from llms_on_kubernetes_tpu.ops import attention, pallas_paged
+
+    g = LATENT
+    pool, pt, lengths, q = _latent_cell_case()
+    scale, n_calls = 192 ** -0.5 * 1.36889 ** 2, 60
+    kernel = pallas_paged.pallas_latent_attention.__wrapped__
+
+    def time_us(fn):
+        @jax.jit
+        def chain(q, pool):
+            def step(i, q):
+                o = fn(q, pool, pt + (i % g["layers"]) * g["pages"], lengths,
+                       scale=scale, lat=g["lat"])
+                return jnp.concatenate([o, q[..., g["lat"]:]], axis=-1)
+            return jax.lax.fori_loop(0, n_calls, step, q)
+
+        best = float("inf")
+        for _ in range(4):                      # the first run compiles
+            t0 = time.perf_counter()
+            jax.block_until_ready(chain(q, pool))
+            best = min(best, time.perf_counter() - t0)
+        return best / n_calls * 1e6
+
+    live = np.asarray(lengths)
+    live_bytes = int(live.sum()) * g["width"] * 2
+    said = {"lengths": live[live > 0].tolist(), "live_rows_bytes": live_bytes}
+    for name, fn in (("xla_loop", attention.latent_paged_attention),
+                     ("kernel", kernel)):
+        us = time_us(fn)
+        said[name] = {"us_a_layer": round(us, 1),
+                      "live_rows_GB_s": round(live_bytes / us / 1e3, 1)}
+    with monkeypatch.context() as m:            # DMAs, no arithmetic
+        m.setattr(pallas_paged, "_attend_latent_block",
+                  lambda q, carry, *a, **kw: carry)
+        said["kernel"]["fetch_only_us"] = round(time_us(kernel), 1)
+    with monkeypatch.context() as m:            # arithmetic, no DMAs
+        m.setattr(pallas_paged, "pltpu", _NoDMA(pallas_paged.pltpu))
+        said["kernel"]["attend_only_us"] = round(time_us(kernel), 1)
+    want = _f32(attention.latent_paged_attention(
+        q, pool, pt, lengths, scale=scale, lat=g["lat"]))[live > 0]
+    got = _f32(pallas_paged.pallas_latent_attention(
+        q, pool, pt, lengths, scale=scale, lat=g["lat"]))[live > 0]
+    said["max_abs_diff"] = float(np.abs(got - want).max())
+    said["max_abs_value"] = float(np.abs(want).max())
+    _report("pr43_latent_kernel", said)
+    # both round p to bfloat16 on its way into the MXU, after a running
+    # maximum over the same 512-token blocks
+    np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -7)
+
+
 # ---------------------------------------------------------------------------
 # deepseek-v3's cell: the served functions, teacher-forced, against the
 # float32 reference's full forward pass
@@ -1112,8 +1199,10 @@ def test_deepseek_cell_teacher_forced_prefill_chunk_and_decode():
         for s in seqs:
             got[s].append(np.asarray(logits[s]))
     said = {op: attention._chosen[op] for op in ("prefill", "chunk", "decode")}
-    assert all(impl == "xla" for impl, _ in said.values())
-    assert "absorbed" in said["decode"][1]
+    assert said["prefill"][0] == said["chunk"][0] == "xla"
+    # the absorbed steps ran the latent kernel (PR 43)
+    assert said["decode"][0] == "pallas-compiled"
+    assert said["decode"][1].startswith("latent")
     del kp, vp
 
     def reference(p):
@@ -1184,9 +1273,9 @@ def test_deepseek_cell_teacher_forced_prefill_chunk_and_decode():
             "per_sequence_max": {s: float(control[s].max())
                                  for s in control}},
         "tolerance_nats": DEEPSEEK_TOL_NATS}
-    print("[pr42_teacher_forced]", json.dumps(report), flush=True)
+    print("[pr43_teacher_forced]", json.dumps(report), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/pr42_teacher_forced.json", "w") as f:
+    with open("chiprun_out/pr43_teacher_forced.json", "w") as f:
         json.dump(report, f, indent=1)
     assert max(served[s].max() for s in served) < DEEPSEEK_TOL_NATS
     assert all(control[s].max() > DEEPSEEK_TOL_NATS for s in control)
