@@ -314,13 +314,16 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     correct = bool(check["correct"])
     ctx = Context(cell=cell, bench=bench, records=records, window=(w0, w1),
                   censor_at=censor, setup_s=w0 - t_process_start)
-    e2e = {m["name"]: manifest.read_metric("end_to_end", m["name"], ctx)
-           for m in bench["end_to_end"]}
+    # every end-to-end reading that has a file, an entry or not: a
+    # statistic that repeats too widely to be held to a bound (a first
+    # token's time, since PR 45) is still printed in every run
+    e2e = {name: manifest.read_metric("end_to_end", name, ctx)
+           for name in manifest.metric_files("end_to_end")}
     met = stats.met_both_limits(window, mix["limits"], censor)
     timings = {} if rehearse else {
-        # every end-to-end metric, also those this cell does not report
-        # and that decide nothing here (above the knee the tails swing
-        # with the smallest change); never printed by a CPU rehearsal
+        # every file of end_to_end/, also those this cell does not
+        # report and that decide nothing here (above the knee the tails
+        # swing with the smallest change); never printed by a CPU rehearsal
         "end_to_end_all": e2e,
         "met_both_limits_share": met / len(window),
         "setup_phases_s": {"to_ready": t_ready - t_process_start,

@@ -95,6 +95,14 @@ def metrics_of(bench: dict, cell_name: str, kind: str) -> list:
 METRIC_DIRS = {"end_to_end": "end_to_end", "per_layer": "layer_metrics"}
 
 
+def metric_files(kind: str) -> list:
+    """The names of every metric that has a file, an entry of
+    ``BENCHMARK.json`` or not, in order."""
+    d = os.path.join(BENCH_DIR, METRIC_DIRS[kind])
+    return sorted(f[:-len(".json")] for f in os.listdir(d)
+                  if f.endswith(".json"))
+
+
 def load_reader(kind: str, name: str):
     """``<end_to_end|layer_metrics>/readers/<name>.py`` as a module; it
     has ``read(ctx, **args)``, which returns a number or None."""

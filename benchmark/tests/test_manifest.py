@@ -82,6 +82,111 @@ def test_every_cell_finds_its_files_and_reports_enough(cell):
     assert_fits_a_slot(loaded)
 
 
+def reported(cell: str) -> set:
+    """The names of the end-to-end metrics ``cell`` reports."""
+    return {m["name"] for m in manifest.metrics_of(BENCH, cell, "end_to_end")}
+
+
+def quantity(name: str) -> str:
+    """What an end-to-end metric is a statistic OF: the ``what`` its
+    percentile reader is given, or the ``quantity`` its file names."""
+    spec = manifest.load_json("end_to_end", f"{name}.json")
+    return spec.get("quantity") or spec["args"]["what"]
+
+
+@pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_every_cell_of_a_layer_metric_reports_what_it_moves(m):
+    """``moves`` names one end-to-end metric, and every cell the metric
+    lists reports it. A cell that reports the same quantity by another
+    statistic lists the metric's twin."""
+    assert m["moves"] in {e["name"] for e in BENCH["end_to_end"]}
+    cells = m.get("workloads", CELLS)
+    assert cells
+    for cell in cells:
+        assert m["moves"] in reported(cell), (m["name"], cell, m["moves"])
+
+
+TWINS = [m for m in BENCH["per_layer"] if "twin_of" in manifest.load_json(
+    "layer_metrics", f"{m['name']}.json")]
+
+
+@pytest.mark.parametrize("m", TWINS, ids=lambda m: m["name"])
+def test_a_twin_reads_what_its_original_reads(m):
+    """A quantity whose cells report different end-to-end metrics is
+    split: the twin's file names the reader and the arguments of the
+    original's, letter for letter, and moves another statistic of the
+    same quantity; no cell lists both."""
+    spec = manifest.load_json("layer_metrics", f"{m['name']}.json")
+    first = manifest.load_json("layer_metrics", f"{spec['twin_of']}.json")
+    assert m["name"].startswith(spec["twin_of"] + ".")
+    for key in ("layer", "module", "unit", "source", "reader", "args"):
+        assert spec[key] == first[key], (m["name"], key)
+    assert spec["moves"] != first["moves"]
+    assert quantity(spec["moves"]) == quantity(first["moves"])
+    entry, = [e for e in BENCH["per_layer"] if e["name"] == spec["twin_of"]]
+    assert entry["better"] == m["better"]
+    assert not set(entry["workloads"]) & set(m["workloads"])
+
+
+def test_there_are_twins_and_each_listed_file_is_an_entry():
+    assert TWINS
+    names = {m["name"] for m in BENCH["per_layer"]}
+    ldir = os.path.join(manifest.BENCH_DIR, "layer_metrics")
+    for f in os.listdir(ldir):
+        if f.endswith(".json"):
+            assert f[:-len(".json")] in names, f
+
+
+def test_the_windows_mean_time_per_token_is_the_short_windows_metric():
+    """``tpot_mean_ms``: the time per output token as ``tpot_p95_ms``
+    has it, pooled over the window's time and not a percentile over its
+    requests; reported by the one cell whose window holds too few
+    requests for a tail, which does not report the tail."""
+    spec = manifest.load_json("end_to_end", "tpot_mean_ms.json")
+    tail = manifest.load_json("end_to_end", "tpot_p95_ms.json")
+    assert spec["reader"] == "latency_pooled_mean" and spec["args"] == {}
+    assert tail["reader"] == "latency_percentile"
+    assert tail["args"] == {"what": "tpot", "pct": 95}
+    assert quantity("tpot_mean_ms") == quantity("tpot_p95_ms") == "tpot"
+    assert spec["unit"] == tail["unit"] == "ms"
+    entry, = [m for m in BENCH["end_to_end"] if m["name"] == "tpot_mean_ms"]
+    assert entry["workloads"] == ["deepseek-v3.long-prompts"]
+    assert entry["bound"] == 0.1 and entry["better"] == "lower"
+    for cell in CELLS:
+        e2e = reported(cell)
+        assert len(e2e & {"tpot_mean_ms", "tpot_p95_ms"}) == 1, cell
+        # a 95th percentile wants ten samples beyond it, 200 requests a
+        # window: the cell that reports the mean holds fewer
+        if "tpot_mean_ms" in e2e:
+            rate = manifest.load_cell(cell).rate_rps
+            assert rate * BENCH["run_seconds"] < 200
+
+
+def test_a_first_tokens_time_is_printed_and_read_but_holds_no_bound():
+    """Since PR 45 (the driver's check read ``ttft_p50_ms`` spreading by
+    more than half of the largest bound, and the builder's sets
+    ``ttft_p95_ms``) no cell reports a first-token time end to end. The
+    files stay, so every run prints them in ``end_to_end_all``
+    (``manifest.metric_files``), and ``mistral-7b.chat`` lists them per
+    layer under names of their own."""
+    entries = {m["name"] for m in BENCH["end_to_end"]}
+    files = manifest.metric_files("end_to_end")
+    assert entries <= set(files) and files == sorted(files)
+    for pct in (50, 95):
+        old, new = f"ttft_p{pct}_ms", f"first_token_p{pct}_ms"
+        assert old in files and old not in entries
+        spec = manifest.load_json("end_to_end", f"{old}.json")
+        assert spec["args"] == {"what": "ttft", "pct": pct}
+        layer = manifest.load_json("layer_metrics", f"{new}.json")
+        assert layer["reader"] == "first_token_percentile"
+        assert layer["args"] == {"pct": pct}
+        entry, = [m for m in BENCH["per_layer"] if m["name"] == new]
+        assert entry["workloads"] == ["mistral-7b.chat"]
+        assert entry["moves"] in reported("mistral-7b.chat")
+    for m in BENCH["per_layer"]:
+        assert not m["moves"].startswith("ttft_"), m["name"]
+
+
 def assert_fits_a_slot(loaded):
     """The longest prompt plus the longest answer fits a slot. (A prompt
     may be longer than the largest prefill bucket: it takes the chunk
@@ -120,8 +225,25 @@ def test_the_long_rehearsal_outgrows_the_largest_bucket():
 @pytest.mark.parametrize("cell", CELLS + ["debug-tiny.rehearse",
                                           "debug-moe.rehearse"])
 def test_a_mix_inside_its_buckets_warms_no_chunk_path(cell):
+    """And one whose longest prompt outgrows the largest bucket warms
+    that path: which of the two a cell is, its own files say."""
     loaded = manifest.load_cell(cell)
-    assert setup_steps.chunk_path_lengths(loaded.config, loaded.mix) == []
+    top = max(loaded.config["prefill_buckets"])
+    longest = loaded.mix["prompt_tokens"]["max"]
+    lengths = setup_steps.chunk_path_lengths(loaded.config, loaded.mix)
+    if longest <= top:
+        assert lengths == []
+    else:
+        assert lengths and all(top < n <= longest for n in lengths)
+
+
+def test_the_cell_of_long_prompts_warms_the_one_length_it_needs():
+    # buckets 512 and 2048, prompts to 8192 = 4 x 2048: full chunks alone
+    # need no request of their own; 6656 = 3 x 2048 + 512 ends in the
+    # bucket of 512
+    loaded = manifest.load_cell("deepseek-v3.long-prompts")
+    assert setup_steps.chunk_path_lengths(loaded.config,
+                                          loaded.mix) == [6656]
 
 
 @pytest.mark.parametrize("lo,hi,want", [

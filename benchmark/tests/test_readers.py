@@ -69,20 +69,78 @@ def read(name, ctx, kind="per_layer"):
     ("queue_wait_p95_ms", 29.0),                # 10 and 30; "late" is out
     ("prefill_span_p50_ms", 60.0),
     ("decode_step_ms.lat", 16.0),               # 64 ms a dispatch / K = 4
+    ("first_token_p50_ms", 39150.0),  # 300 ms and a refused request's 78 s
+    ("first_token_p95_ms", 74115.0),
 ])
 def test_reader_values(name, want):
     assert read(name, context()) == pytest.approx(want, rel=1e-3)
+
+
+@pytest.mark.parametrize("pct", [50, 95])
+def test_the_first_tokens_time_reads_the_same_per_layer_as_in_the_info_line(
+        pct):
+    """Since PR 45 no cell is held to a first-token time: the per-layer
+    ``first_token_p<pct>_ms`` and the file ``end_to_end/ttft_p<pct>_ms``
+    (printed in every run's ``end_to_end_all``) read one number, and
+    nothing where no request was due in the window."""
+    ctx = context()
+    assert read(f"first_token_p{pct}_ms", ctx) == read(
+        f"ttft_p{pct}_ms", ctx, "end_to_end")
+    assert read(f"first_token_p{pct}_ms", context(records=[])) is None
 
 
 @pytest.mark.parametrize("name,want", [
     ("ttft_p50_ms", 39150.0),     # 300 ms and a refused request's 78 s
     ("ttft_p95_ms", 74115.0),
     ("tpot_p95_ms", 166.667),     # 500 ms over the 3 tokens after the first
+    # 0.5 s of the one stream and the refused request's 48 s to the
+    # window's end, over the stream's 3 tokens after the first
+    ("tpot_mean_ms", 1000.0 * (0.5 + 48.0) / 3),
     ("setup_s", 200.0),
 ])
 def test_end_to_end_reader_values(name, want):
     assert read(name, context(), "end_to_end") == pytest.approx(want,
                                                                 rel=1e-3)
+
+
+def stream(i, start, ms_a_token, groups=2, part="window", status=200):
+    """A first token at ``start``, then ``groups`` groups of four."""
+    step = 0.004 * ms_a_token
+    return rec(i, start - 0.1, [(start, 1)] + [
+        (start + step * (k + 1), 4) for k in range(groups)],
+        part=part, status=status)
+
+
+def test_the_windows_mean_time_per_token_counts_its_own_time_and_work():
+    """``tpot_mean_ms``: every stream's seconds between its first and
+    its last token INSIDE the window, over the tokens delivered inside
+    it after the first groups. The preroll's streams count, what drains
+    after the window does not, and a failed request stays a stream that
+    delivers nothing until the run stopped looking."""
+    w0 = time.monotonic() - 60.0
+    at = dict(window=(w0, w0 + 50.0), censor_at=w0 + 80.0)
+    inside = stream(0, w0 + 10, 10.0)               # 0.08 s, 8 tokens
+    # first token 1 s before the window, a group every 0.4 s: the groups
+    # at +0.2 and +0.6 are the window's, 0.6 s of it are the stream's
+    before = stream(1, w0 - 1.0, 100.0, groups=4, part="preroll")
+    # a group every 2 s from 49 s: the one at 51 s drains outside, and
+    # the stream's time is cut at the window's end: 1 s, no token
+    across = stream(2, w0 + 49.0, 500.0)
+    later = stream(3, w0 + 51.0, 7.0, part="tail")
+    ctx = context(records=[inside, before, across, later], **at)
+    assert read("tpot_mean_ms", ctx, "end_to_end") == pytest.approx(
+        1000.0 * (0.08 + 0.6 + 1.0) / (8 + 8))
+    # one that stalls after its first token, and one refused outright:
+    # streams until the window's end (the run looked until 80 s)
+    stalled = stream(4, w0 + 40.0, 10.0, groups=0, status=500)
+    refused = rec(5, w0 + 45.0, [], status=503)
+    ctx = context(records=[inside, stalled, refused], **at)
+    assert read("tpot_mean_ms", ctx, "end_to_end") == pytest.approx(
+        1000.0 * (0.08 + 10.0 + 5.0) / 8)
+    # nothing delivered inside the window: nothing to read, not zero
+    for nothing in ([later], [refused]):
+        assert read("tpot_mean_ms", context(records=nothing, **at),
+                    "end_to_end") is None
 
 
 def test_decode_hbm_share_is_needed_bytes_over_peak_over_step_time():

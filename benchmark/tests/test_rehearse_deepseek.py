@@ -101,5 +101,5 @@ def test_the_cells_new_counter_metrics_have_something_to_read():
         assert all(side["metric"] in exported for side in sides)
         entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
         assert entry["layer"] == layer and entry["unit"] == spec["unit"] == "%"
-        assert entry["moves"] == spec["moves"] == "tpot_p95_ms"
+        assert entry["moves"] == spec["moves"] == "tpot_mean_ms"
         assert entry["better"] == "lower"

@@ -141,7 +141,7 @@ def test_each_file_loads_and_is_listed_for_both_cells(name):
     entry, = [m for m in BENCH["per_layer"] if m["name"] == name]
     assert entry["moves"] == spec["moves"] and entry["layer"] == spec["layer"]
     assert entry["source"] == spec["source"]
-    assert entry["workloads"] == ["mistral-7b.chat",
-                                  "lfm2-24b-a2b.long-answers"]
+    assert {"mistral-7b.chat",
+            "lfm2-24b-a2b.long-answers"} <= set(entry["workloads"])
     for cell in entry["workloads"]:
         assert entry in manifest.metrics_of(BENCH, cell, "per_layer")
