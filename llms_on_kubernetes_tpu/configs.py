@@ -61,12 +61,24 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     # layers [0, num_dense_layers) keep a dense network in an expert model
     num_dense_layers: int = 0
-    # a stack of more than one kind of layer (LFM2): per layer "conv" (a
-    # gated short convolution, state beside the KV pool) or
+    # a stack of more than one kind of layer (LFM2, Jamba): per layer
+    # "conv" (a gated short convolution, state beside the KV pool),
+    # "mamba" (a selective state-space mixer, state beside the KV pool) or
     # "full_attention". None => every layer is full attention
     layer_types: Optional[tuple] = None
     conv_L_cache: int = 3                   # taps of the short convolution
     conv_bias: bool = False
+    # the Mamba-1 mixer of a "mamba" layer: channels ``mamba_expand`` x
+    # hidden_size (``mamba_d_inner``), ``mamba_d_state`` states a channel,
+    # a causal depthwise convolution of ``mamba_d_conv`` taps before the
+    # scan, the step size through a ``mamba_dt_rank`` bottleneck
+    mamba_expand: int = 2
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 0
+    # False: attention without positional encoding of any kind (Jamba: the
+    # state-space layers carry the order)
+    use_rope: bool = True
     # latent attention (DeepSeek MLA; ``kv_lora_rank`` > 0 makes every
     # layer's operator "mla"): queries through a ``q_lora_rank`` bottleneck
     # to ``num_heads`` heads of ``qk_nope_head_dim`` un-roped and
@@ -168,10 +180,10 @@ class ModelConfig:
 
     def layer_kind(self, i: int) -> tuple:
         """(operator, feed-forward) of layer ``i``: ("attn" | "conv" |
-        "mla", "dense" | "moe")."""
-        op = ("mla" if self.is_mla else "conv"
-              if self.layer_types is not None
-              and self.layer_types[i] == "conv" else "attn")
+        "mamba" | "mla", "dense" | "moe")."""
+        kind = None if self.layer_types is None else self.layer_types[i]
+        op = ("mla" if self.is_mla
+              else kind if kind in ("conv", "mamba") else "attn")
         ff = "moe" if self.is_moe and i >= self.num_dense_layers else "dense"
         return op, ff
 
@@ -192,12 +204,29 @@ class ModelConfig:
     def num_attn_layers(self) -> int:
         """Layers that keep keys and values (or a latent row of them):
         what the KV pool is sized by."""
-        return sum(n for op, _ff, _i, n in self.layer_runs if op != "conv")
+        return sum(n for op, _ff, _i, n in self.layer_runs
+                   if op not in ("conv", "mamba"))
 
     @property
     def num_conv_layers(self) -> int:
         """Layers that keep a short-convolution state for each slot."""
-        return self.num_layers - self.num_attn_layers
+        return sum(n for op, _ff, _i, n in self.layer_runs if op == "conv")
+
+    @property
+    def num_mamba_layers(self) -> int:
+        """Layers that keep a convolution window and a state-space state
+        for each slot."""
+        return sum(n for op, _ff, _i, n in self.layer_runs if op == "mamba")
+
+    @property
+    def keeps_slot_state(self) -> bool:
+        """Some layer keeps per-slot state beside the KV pool: a sequence
+        cannot be continued from its cached pages alone."""
+        return bool(self.num_conv_layers or self.num_mamba_layers)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
 
     @property
     def num_moe_layers(self) -> int:
@@ -215,12 +244,17 @@ class ModelConfig:
                     * (self.qk_nope_head_dim + self.v_head_dim)
                     + h * self.v_head_dim * d)
         conv = 4 * d * d + self.conv_L_cache * d
+        di, ns, r = self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank
+        # in/out projections, taps and bias, x_proj and its three norms,
+        # dt_proj and bias, A_log, D
+        mamba = (3 * d * di + (self.mamba_d_conv + 1) * di
+                 + (di + 1) * (r + 2 * ns) + (r + 1) * di + ns * di + di)
         moe = (3 * d * self.expert_width * (self.num_held_experts
                                             + self.n_shared_experts)
                + d * self.num_experts)
         total = v * d * (1 if self.tie_word_embeddings else 2)
         for op, ff, _first, n in self.layer_runs:
-            total += n * ((conv if op == "conv" else attn)
+            total += n * ({"conv": conv, "mamba": mamba}.get(op, attn)
                           + (moe if ff == "moe" else 3 * d * f))
         return total
 
@@ -461,6 +495,34 @@ _register(
     "LiquidAI/LFM2-24B-A2B",
 )
 
+
+
+def _jamba_layers(n: int, period: int, offset: int) -> tuple:
+    """The Jamba family's rule: layer i is attention iff i % period ==
+    offset, else a Mamba mixer."""
+    return tuple("full_attention" if i % period == offset else "mamba"
+                 for i in range(n))
+
+
+# Jamba2-3B (AI21): 26 Mamba-1 layers (per-slot state beside the KV pool:
+# the convolution's last 3 inputs and a [5120, 16] float32 state-space
+# state) and 2 multi-query attention layers (7 and 21: one 128-wide KV head
+# under 20 query heads) WITHOUT positional encoding; every layer's network
+# is the dense SwiGLU (num_experts 1); tied embeddings. head_dim = 2560 / 20
+# is the family's convention, not a key of the published config.
+_register(
+    ModelConfig(
+        "jamba2-3b",
+        vocab_size=65536, hidden_size=2560, intermediate_size=8192,
+        num_layers=28, num_heads=20, num_kv_heads=1, head_dim=128,
+        rms_norm_eps=1e-6, max_position_embeddings=262144,
+        tie_word_embeddings=True, use_rope=False,
+        layer_types=_jamba_layers(28, 14, 7),
+        mamba_expand=2, mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=160,
+    ),
+    "ai21labs/AI21-Jamba2-3B",
+)
+
 # DeepSeek-V3: latent attention (MLA) in every layer, three leading dense
 # layers, then 256 sigmoid-scored experts in 8 groups (a token chooses 8
 # experts among its 4 best groups; a selection bias; the chosen scores
@@ -542,6 +604,19 @@ _register(
         num_dense_layers=1, num_experts=8, num_experts_per_tok=2,
         moe_intermediate_size=48, moe_router="sigmoid",
         use_expert_bias=True, norm_topk_prob=True, moe_renorm_eps=1e-6,
+    ),
+)
+_register(
+    ModelConfig(
+        # jamba2-3b's stack at a size the CPU tests hold: Mamba, Mamba,
+        # position-free multi-query attention, Mamba (three runs)
+        "debug-jamba",
+        vocab_size=258, hidden_size=64, intermediate_size=128,
+        num_layers=4, num_heads=4, num_kv_heads=1, head_dim=16,
+        rms_norm_eps=1e-6, max_position_embeddings=512,
+        tie_word_embeddings=True, use_rope=False,
+        layer_types=_jamba_layers(4, 4, 2),
+        mamba_expand=2, mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=8,
     ),
 )
 _register(
@@ -827,6 +902,34 @@ def from_hf_config(hf: dict | str, name: str = "hf-model") -> ModelConfig:
         if kw["conv_bias"]:
             raise NotImplementedError(
                 "lfm2_moe with conv_bias=true is not supported")
+    if model_type == "jamba":
+        # the published keys (ai21labs/AI21-Jamba2-3B config.json); the
+        # layer rule, the head size hidden / heads, the norms on the step
+        # size and on B and C, and attention without positions are the
+        # family's implementation's, not keys of the file
+        if int(hf.get("num_experts", 1)) > 1:
+            raise NotImplementedError(
+                "jamba with num_experts > 1 is not supported: the family's "
+                "expert layers (expert_layer_period/offset) are not built, "
+                "and no configuration here would measure them")
+        if hf.get("mamba_proj_bias", False) \
+                or not hf.get("mamba_conv_bias", True):
+            raise NotImplementedError(
+                "jamba is served with mamba_conv_bias=true and "
+                "mamba_proj_bias=false")
+        kw.update(
+            layer_types=_jamba_layers(
+                kw["num_layers"], int(hf.get("attn_layer_period", 8)),
+                int(hf.get("attn_layer_offset", 4))),
+            use_rope=False,
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            tie_word_embeddings=bool(hf.get("tie_word_embeddings", True)),
+            mamba_expand=int(hf.get("mamba_expand", 2)),
+            mamba_d_state=int(hf.get("mamba_d_state", 16)),
+            mamba_d_conv=int(hf.get("mamba_d_conv", 4)),
+            mamba_dt_rank=int(hf.get("mamba_dt_rank")
+                              or -(-hidden // 16)),
+        )
     if model_type == "deepseek_v3":
         # the published keys (deepseek-ai/DeepSeek-V3 config.json). The
         # multi-token-prediction module (num_nextn_predict_layers) is a

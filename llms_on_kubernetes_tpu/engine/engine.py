@@ -925,11 +925,11 @@ _DEC_COLS = _BIAS_DEC + 2 * LOGIT_BIAS_SLOTS
 
 def _forward(forward, cfg, conv, slots, *args, **kw):
     """``forward(*args, **kw)`` -> (logits, k_pages, v_pages, aux): with a
-    ``LayerAux`` for a model with conv layers (their per-slot state,
-    ``conv``; ``slots``: each row's, None where row i is slot i) or experts
-    (their row counts come back in it), and with None, traced exactly as
-    before there was one, for every other model."""
-    if not (cfg.num_conv_layers or cfg.is_moe):
+    ``LayerAux`` for a model with conv or Mamba layers (their per-slot
+    state, ``conv``; ``slots``: each row's, None where row i is slot i) or
+    experts (their row counts come back in it), and with None, traced
+    exactly as before there was one, for every other model."""
+    if not (cfg.keeps_slot_state or cfg.is_moe):
         return (*forward(*args, **kw), None)
     return forward(*args, aux=LayerAux(conv=conv, slots=slots), **kw)
 
@@ -1359,11 +1359,11 @@ def _slot_keys(base_key, seeds, lengths):
 
 def _refuse_what_runs_cannot(cfg, ec, mesh, model_dir) -> None:
     """A stack of several kinds of layer (``params["layers"]`` a tuple of
-    runs; conv layers with per-slot state) is served on one chip from
-    seeded weights, by the plain decode window. Every feature that reads
-    ``params["layers"]`` as one dict, or that moves KV pages without the
-    conv state at their boundary, refuses such a model here, at start-up,
-    rather than answer wrongly. So does a model with latent attention
+    runs; conv or Mamba layers with per-slot state) is served on one chip
+    from seeded weights, by the plain decode window. Every feature that
+    reads ``params["layers"]`` as one dict, or that moves KV pages without
+    the per-slot state at their boundary, refuses such a model here, at
+    start-up, rather than answer wrongly. So does a model with latent attention
     (its seeded tree is a tuple of runs whatever their number, and its
     pool is one latent row a token with no V side), which also refuses an
     int8 pool: the quantized write and the int8 kernels know K and V
@@ -1380,7 +1380,7 @@ def _refuse_what_runs_cannot(cfg, ec, mesh, model_dir) -> None:
         ("LoRA adapters", bool(ec.adapters)),
         ("speculation", ec.speculation is not None),
         ("the host KV tier (kv_host_cache_gb: a spilled page carries no "
-         "conv state)", bool(ec.kv_host_cache_gb)),
+         "conv or Mamba state)", bool(ec.kv_host_cache_gb)),
         ("a prefill or decode role (the handoff moves KV pages alone)",
          ec.role not in (None, "both")),
         ("an int8 KV cache (kv_cache_dtype: a latent row has no K and V "
@@ -1496,8 +1496,9 @@ class Engine:
             from llms_on_kubernetes_tpu.parallel.sharding import pool_sharding
             sharding = pool_sharding(cfg, mesh)
         self.k_pages, self.v_pages = init_pages(self.cache_config, sharding)
-        # the conv layers' per-slot state, beside the pools and donated
-        # through every step like them; None for a model without any
+        # the conv or Mamba layers' per-slot state, ONE object beside the
+        # pools and donated through every step like them (an array, or a
+        # decoder.MambaState); None for a model without such layers
         self.conv_state = init_conv_state(
             cfg, engine_config.max_decode_slots, engine_config.dtype)
         if (engine_config.kv_cache_dtype == "int8"
@@ -1578,6 +1579,11 @@ class Engine:
         # a chunk over cached ones); with decode_tokens, what
         # llm_mla_tokens_total{path} says of a latent model
         self.path_tokens = {"prefill": 0, "chunk": 0}
+        # positions the Mamba layers' scan (prefill, chunk) or step
+        # (decode) ran over, padding and idle rows included, as _dispatch
+        # is told them: llm_ssm_positions_total{path}. path_tokens and
+        # decode_tokens are the real ones among them
+        self.ssm_positions = {"prefill": 0, "chunk": 0, "decode": 0}
         # fused multi-step decode accounting (metrics + bench):
         self.decode_dispatches = 0   # decode device dispatches
         self.decode_tokens = 0       # tokens committed to streams by decode
@@ -2294,12 +2300,14 @@ class Engine:
 
     @contextlib.contextmanager
     def _dispatch(self, kind: str, name: str, shape: str,
-                  rows: Optional[list] = None):
+                  rows: Optional[list] = None, positions: int = 0):
         """Around the jitted call(s) of ONE device dispatch: opens its
         ledger record, puts ``llmk.dispatch`` with its kind and seq on the
         profiler's timeline, and on the way out stamps the launch (the
         work is enqueued) with the host time the call took and whether the
-        process re-traced meanwhile. Yields the dispatch's seq."""
+        process re-traced meanwhile. Yields the dispatch's seq.
+        ``positions``: how many positions every layer runs over (rows x
+        bucket, or K x slots), booked for a model with Mamba layers."""
         seq = next(self._dispatch_seq)
         # without the ledger nothing is attributed to a request (rows) and
         # nothing counts the process's compiles
@@ -2309,6 +2317,9 @@ class Engine:
         rec = self.timeline.open(
             seq, kind, name, shape, self._clock(),
             rows=rows if led is not None else None, after_no_work=no_work)
+        if self.model_config.num_mamba_layers:
+            self.ssm_positions[kind] += positions
+            rec.ssm_positions = positions
         try:
             with _phase("llmk.dispatch", kind=kind, seq=seq):
                 yield seq
@@ -2322,6 +2333,12 @@ class Engine:
 
             jlog("dispatch_retraced", kind=kind, step=name, shape=shape,
                  seq=seq, seconds=round(rec.enqueue_ms / 1000.0, 3))
+
+    @property
+    def slot_state_bytes(self) -> int:
+        """Device bytes of the per-slot state beside the pools."""
+        return sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(self.conv_state))
 
     def _mh_send(self, op: int, **fields) -> None:
         """Announce the next device call to follower pods (no-op single-host).
@@ -2397,7 +2414,9 @@ class Engine:
         chunks = -(-(n - start) // step)
         with self._dispatch("chunk", "_chunk_packed_step",
                             f"{chunks}x{self._bucket_for(min(step, n - start))}",
-                            led_rows) as dseq:
+                            led_rows,
+                            sum(self._bucket_for(min(step, n - p))
+                                for p in range(start, n, step))) as dseq:
             pos = start
             while pos < n:
                 pack, toks, m = self._launch_chunk(
@@ -2476,7 +2495,8 @@ class Engine:
         m = min(max(self.config.prefill_buckets), n - ch.pos)
         with self._dispatch("chunk", "_chunk_packed_step",
                             f"1x{self._bucket_for(m)}",
-                            [(req, "prefill", m)]) as dseq:
+                            [(req, "prefill", m)],
+                            self._bucket_for(m)) as dseq:
             pack, toks, m = self._launch_chunk(
                 slot, req, ch.tokens, ch.pos, ch.start)
         ch.pos += m
@@ -2555,8 +2575,8 @@ class Engine:
         if req.cache_salt is None:
             return 0
         if self.conv_state is not None:
-            # a cached page carries keys and values, not the conv layers'
-            # state at its end: nothing is adopted and the prompt is
+            # a cached page carries keys and values, not the conv or Mamba
+            # layers' state at its end: nothing is adopted and the prompt is
             # prefilled whole (_note_admission counts the skipped reuse)
             return 0
         hit = self.allocator.adopt_prefix(
@@ -3050,7 +3070,7 @@ class Engine:
                           fsm_used=use_fsm)
             self.path_tokens["prefill"] += n
             with self._dispatch("prefill", "_prefill_packed_step",
-                                f"1x{bucket}", led_rows) as dseq:
+                                f"1x{bucket}", led_rows, bucket) as dseq:
                 (pack, toks, self.k_pages, self.v_pages, self.token_counts,
                  new_state, self.conv_state) = self._prefill_packed(
                     self.params, self.model_config, jnp.asarray(tokens),
@@ -3337,7 +3357,8 @@ class Engine:
         use_fsm = self._fsm_any_active()
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
         with self._dispatch("decode", "_decode_multi_packed_step",
-                            f"1x{len(active)}") as dseq:
+                            f"1x{len(active)}",
+                            positions=len(self.slots)) as dseq:
             (pack, self._unread_toks, self.k_pages, self.v_pages,
              self.token_counts, new_state,
              self.conv_state) = self._decode_multi(
@@ -3523,7 +3544,7 @@ class Engine:
         led_rows = [(req, "prefill", max(1, len(ptoks)))
                     for _slot, req, _resumed, ptoks in picked]
         with self._dispatch("prefill", "_prefill_packed_step",
-                            f"{K}x{bucket}", led_rows) as dseq:
+                            f"{K}x{bucket}", led_rows, K * bucket) as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
              new_state, self.conv_state) = self._prefill_packed(
                 self.params, self.model_config, jnp.asarray(tokens),
@@ -3665,7 +3686,8 @@ class Engine:
         use_fsm = self._fsm_any_active()
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
         with self._dispatch("decode", "_decode_multi_packed_step",
-                            f"{K}x{len(active)}") as dseq:
+                            f"{K}x{len(active)}",
+                            positions=K * len(self.slots)) as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
              new_state, self.conv_state) = self._decode_multi(
                 self.params, self.model_config, K, jnp.asarray(packed),

@@ -238,6 +238,10 @@ class Dispatch:
     tokens: int = 0
     flops: float = 0.0
     hbm_bytes: float = 0.0
+    # positions the Mamba layers' scan or step ran over, padding and idle
+    # rows included (0: the model has no such layer; Engine._dispatch
+    # sets it); ``tokens`` is the real ones among them
+    ssm_positions: int = 0
 
     def to_dict(self, t0: float) -> dict:
         """JSON view; times in ms since ``t0`` (the ledger's first launch)."""
@@ -253,6 +257,9 @@ class Dispatch:
             d["idle_host"] = self.idle_host
         if self.end_clamped:
             d["end_clamped"] = True
+        if self.ssm_positions:
+            d["ssm_tokens"] = self.tokens
+            d["ssm_positions"] = self.ssm_positions
         return d
 
 

@@ -38,7 +38,7 @@ from llms_on_kubernetes_tpu.configs import ModelConfig
 from llms_on_kubernetes_tpu.ops.cp import dispatch_write_tokens as write_tokens
 from llms_on_kubernetes_tpu.ops.attention import (
     dispatch_chunk_attention, dispatch_paged_attention,
-    dispatch_prefill_attention, softcap,
+    dispatch_prefill_attention, record_choice, softcap,
 )
 from llms_on_kubernetes_tpu.ops.lora import lora_qeinsum
 from llms_on_kubernetes_tpu.ops.moe import moe_block
@@ -175,7 +175,10 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
     kv_lora, v]: the published kv_b matrix's two halves, head-major. Each
     is the array a product reads as it lies: a matrix whose columns are
     split after the product, or whose heads are 192 wide, is re-laid out
-    by the compiler before every step. wo [H, v, D]); its
+    by the compiler before every step. wo [H, v, D]) or a Mamba mixer
+    (``_mamba``: in_proj [D, 2 Di], conv_w [taps, Di] and A_log [N, Di]
+    with the channels on the lanes, conv_b, x_proj [Di, R + 2 N], dt_norm,
+    b_norm, c_norm, dt_proj [R, Di], dt_bias, D, out_proj [Di, D]); its
     feed-forward is a dense SwiGLU network or routed experts (router,
     router_bias, w_gate/w_up/w_down stacked over the experts HELD here,
     ``cfg.num_held_experts`` of the router's ``num_experts``; ws_gate,
@@ -186,11 +189,13 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
     the experts' load out, and a random one as wide as the scores would
     pile the rows on a few."""
     if cfg.attention_bias or cfg.post_norms or cfg.vision is not None \
-            or cfg.norm_style != "llama" or not (cfg.qk_norm or cfg.is_mla):
+            or cfg.norm_style != "llama" or not (
+                cfg.qk_norm or cfg.is_mla or cfg.num_mamba_layers):
         raise NotImplementedError(
             f"{cfg.name}: a stack of several kinds of layer is built for "
-            f"the LFM2 block (llama norms, q/k norms, no biases) and the "
-            f"DeepSeek block (latent attention) only")
+            f"the LFM2 block (llama norms, q/k norms, no biases), the "
+            f"DeepSeek block (latent attention) and the Jamba block (Mamba "
+            f"layers, attention without norms or positions) only")
     D, F, Fm = cfg.hidden_size, cfg.intermediate_size, cfg.expert_width
     H, KV, hd, V = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size
     E, held, taps = cfg.num_experts, cfg.num_held_experts, cfg.conv_L_cache
@@ -223,8 +228,35 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
                 wq=init(n, D, H, hd, scale=D ** -0.5),
                 wk=init(n, D, KV, hd, scale=D ** -0.5),
                 wv=init(n, D, KV, hd, scale=D ** -0.5),
-                wo=init(n, H, hd, D, scale=(H * hd) ** -0.5),
-                q_norm=jnp.ones((n, hd), dt), k_norm=jnp.ones((n, hd), dt))
+                wo=init(n, H, hd, D, scale=(H * hd) ** -0.5))
+            if cfg.qk_norm:
+                lp.update(q_norm=jnp.ones((n, hd), dt),
+                          k_norm=jnp.ones((n, hd), dt))
+        elif op == "mamba":
+            Di, N, R = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+            # the family's own start for the two that set the state's
+            # memory: A = -(1 .. N) a channel, and step sizes log-uniform
+            # in [1e-3, 1e-1] (dt_bias their inverse softplus), so that the
+            # state forgets over tens to thousands of tokens. Normal draws
+            # here would forget in one step or never, and a state carried
+            # wrongly would read the same as one carried rightly
+            step = jnp.exp(jax.random.uniform(next(keys), (n, Di), jnp.float32)
+                           * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3))
+            lp.update(
+                in_proj=init(n, D, 2 * Di, scale=D ** -0.5),
+                conv_w=init(n, cfg.mamba_d_conv, Di,
+                            scale=cfg.mamba_d_conv ** -0.5),
+                conv_b=init(n, Di, scale=0.1),
+                x_proj=init(n, Di, R + 2 * N, scale=Di ** -0.5),
+                dt_norm=jnp.ones((n, R), dt), b_norm=jnp.ones((n, N), dt),
+                c_norm=jnp.ones((n, N), dt),
+                dt_proj=init(n, R, Di, scale=R ** -0.5),
+                dt_bias=(step + jnp.log(-jnp.expm1(-step))).astype(dt),
+                A_log=jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[:, None],
+                    (n, N, Di)).astype(dt),
+                D=jnp.ones((n, Di), dt),
+                out_proj=init(n, Di, D, scale=Di ** -0.5))
         else:
             lp.update(
                 conv_in=init(n, D, 3 * D, scale=D ** -0.5),
@@ -275,12 +307,15 @@ class LayerAux:
     """What a forward pass is handed and hands back beside the KV pools,
     for a model that keeps per-slot state or routes to experts.
 
-    ``conv`` [n_conv_layers, slots + 1, taps - 1, D], in the activation
-    type: each conv layer's short-convolution state of every slot (the
-    last ``taps - 1`` gated inputs), donated and returned like the pools;
-    the last row is trash, where rows that are padding write. None where
-    the model has no conv layer, or for a pass from an empty state whose
-    state nobody keeps (scoring).
+    ``conv``: the per-slot state of the layers that keep one beside the KV
+    pool, ONE object donated and returned like the pools (the name is the
+    first such layer's). For a model with conv layers an array
+    [n_conv_layers, slots + 1, taps - 1, D] in the activation type: each
+    conv layer's short-convolution state of every slot (the last
+    ``taps - 1`` gated inputs). For a model with Mamba layers a
+    ``MambaState``. Either way the last row is trash, where rows that are
+    padding write. None where the model has no such layer, or for a pass
+    from an empty state whose state nobody keeps (scoring).
     ``slots`` [B]: the slot of each row (prefill, chunk); None where row i
     IS slot i (decode).
     ``moe_rows`` [n_moe_layers, E] int32, handed BACK: the (token, expert)
@@ -291,9 +326,33 @@ class LayerAux:
     moe_rows: "jnp.ndarray | None" = None
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class MambaState:
+    """What the Mamba layers keep for every slot (and the trash row).
+
+    ``conv`` [n_mamba_layers, slots + 1, (taps - 1) * Di], in the
+    activation type: the convolution's last ``taps - 1`` inputs, oldest
+    first, side by side on one row (three rows of a 16-row tile would
+    hold five times the bytes).
+    ``ssm`` [n_mamba_layers, slots + 1, N, Di] float32: the state-space
+    state h, a running sum over the whole sequence. The channels lie on
+    the lanes (the published h is [Di, N]: with 16 states on a 128-lane
+    row the TPU's tiled layout would hold eight times the bytes)."""
+    conv: jnp.ndarray
+    ssm: jnp.ndarray
+
+
 def init_conv_state(cfg: ModelConfig, slots: int, dtype=None):
-    """Zeroed short-convolution state for ``slots`` decode slots (and the
-    trash row), or None for a model without conv layers."""
+    """Zeroed per-slot state for ``slots`` decode slots (and the trash
+    row): the conv layers' array, the Mamba layers' ``MambaState``, or
+    None for a model with neither."""
+    if cfg.num_mamba_layers:
+        n, Di = cfg.num_mamba_layers, cfg.mamba_d_inner
+        return MambaState(
+            conv=jnp.zeros((n, slots + 1, (cfg.mamba_d_conv - 1) * Di),
+                           jnp.dtype(dtype or cfg.dtype)),
+            ssm=jnp.zeros((n, slots + 1, cfg.mamba_d_state, Di), jnp.float32))
     if not cfg.num_conv_layers:
         return None
     return jnp.zeros((cfg.num_conv_layers, slots + 1, cfg.conv_L_cache - 1,
@@ -399,6 +458,98 @@ def _short_conv(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
     return out, new_state
 
 
+# time steps of the state-space scan between two stores of h: the steps of
+# one block are unrolled into one loop body, so h crosses HBM once a block
+# and not once a step (measured on a v5e: PERF.md section 6, PR 46)
+_SSM_SCAN_BLOCK = 8
+
+
+def _ssm_scan(delta, A, x, Bm, Cm, h0):
+    """The selective scan, h_t = exp(delta_t A) h_(t-1) + (delta_t x_t) B_t
+    and y_t = h_t C_t, sequential in time: delta, x [B, T, Di] float32,
+    A [N, Di], Bm, Cm [B, T, N], h0 [B, N, Di] float32 -> (y [B, T, Di]
+    float32, h_T). Nothing of size [B, T, N, Di] exists: the carry is one
+    h, and a step reads its four inputs and writes its y. Decay is per
+    channel AND per state, so no matrix product expresses the
+    recurrence."""
+
+    def step(h, at):
+        d, xv, b, c = at                              # [B, Di] x2, [B, N] x2
+        h = jnp.exp(d[:, None] * A) * h + (d * xv)[:, None] * b[:, :, None]
+        return h, (h * c[:, :, None]).sum(axis=1)
+
+    if x.shape[1] == 1:                 # a decode step: no loop around it
+        h, y = step(h0, (delta[:, 0], x[:, 0], Bm[:, 0], Cm[:, 0]))
+        return y[:, None], h
+    h, y = jax.lax.scan(
+        step, h0, tuple(jnp.swapaxes(a, 0, 1) for a in (delta, x, Bm, Cm)),
+        unroll=min(_SSM_SCAN_BLOCK, x.shape[1]))
+    return jnp.swapaxes(y, 0, 1), h
+
+
+def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
+           state: "MambaState", n_valid: jnp.ndarray):
+    """Jamba's Mamba-1 mixer. u [B, T, D] (normed); ``state``, the rows'
+    own: ``conv`` [B, (taps-1) Di] the last taps - 1 convolution inputs
+    before u's first, ``ssm`` [B, N, Di] the state-space state h, zeros for
+    a fresh sequence; ``n_valid`` [B]: how many of the T positions are
+    real, from the left.
+
+      [x, z] = split2(u W_in)
+      x_t <- silu(b_c + sum_j w_c[j] * x_(t - (taps-1) + j))   (depthwise)
+      [dt, B, C] = split(x W_x), each through its own RMS norm
+      delta_t = softplus(dt_t W_dt + b_dt);  A = -exp(A_log)
+      h_t = exp(delta_t A) h_(t-1) + (delta_t x_t) B_t;  y_t = h_t C_t + D x_t
+      out = (y * silu(z)) W_out
+
+    Returns (out [B, T, D], the rows' state after their last real
+    position). Padding lies to the right of every real position and is
+    given delta = 0: exp(0 A) = 1 and 0 x B = 0, so h passes through a
+    padded step exactly as it was (the other way, a select of old against
+    new h a step, would read h twice)."""
+    taps, N, R = cfg.mamba_d_conv, cfg.mamba_d_state, cfg.mamba_dt_rank
+    B, T, _ = u.shape
+    Di, eps, f32 = cfg.mamba_d_inner, cfg.rms_norm_eps, jnp.float32
+    if T > 1:
+        record_choice(
+            "ssm_scan", "xla",
+            f"lax.scan over time, {_SSM_SCAN_BLOCK} steps unrolled a body, "
+            f"h [{N}, {Di}] float32 the carry: no kernel yet")
+    window, h = state.conv, state.ssm
+    with jax.named_scope("jamba.mamba"):
+        x, z = jnp.split(qeinsum("btd,de->bte", u, lp["in_proj"]), 2, axis=-1)
+        # the window's taps lie side by side on a row: lane slices in and
+        # out (a reshape to [B, taps - 1, Di] makes the compiler keep the
+        # whole state array transposed inside the decode window and copy it
+        # at both ends)
+        past = [window[:, j * Di:(j + 1) * Di] for j in range(taps - 1)]
+        xext = jnp.concatenate(
+            [jnp.stack(past, axis=1).astype(u.dtype), x], axis=1)
+        w = lp["conv_w"].astype(f32)                          # [taps, Di]
+        c = sum(xext[:, j:j + T].astype(f32) * w[j] for j in range(taps))
+        xc = jax.nn.silu(c + lp["conv_b"].astype(f32)).astype(u.dtype)
+        at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)
+        kept = jnp.take_along_axis(xext, at[:, :, None], axis=1)
+        window = jnp.concatenate([kept[:, j] for j in range(taps - 1)],
+                                 axis=-1)
+        dt, Bm, Cm = jnp.split(qeinsum("bte,er->btr", xc, lp["x_proj"]),
+                               [R, R + N], axis=-1)
+        dt = rms_norm(dt, lp["dt_norm"], eps)
+        Bm = rms_norm(Bm, lp["b_norm"], eps).astype(f32)
+        Cm = rms_norm(Cm, lp["c_norm"], eps).astype(f32)
+        delta = jax.nn.softplus(
+            qeinsum("btr,re->bte", dt, lp["dt_proj"]).astype(f32)
+            + lp["dt_bias"].astype(f32))
+        real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_valid[:, None]
+        delta = jnp.where(real[:, :, None], delta, 0.0)
+        xf = xc.astype(f32)
+        y, h = _ssm_scan(delta, -jnp.exp(lp["A_log"].astype(f32)), xf, Bm, Cm,
+                         h.astype(f32))
+        y = (y + lp["D"].astype(f32) * xf) * jax.nn.silu(z.astype(f32))
+        out = qeinsum("bte,ed->btd", y.astype(u.dtype), lp["out_proj"])
+    return out, MambaState(conv=window, ssm=h)
+
+
 def _layer_step(
     cfg: ModelConfig,
     inv_freq: jnp.ndarray,
@@ -419,17 +570,20 @@ def _layer_step(
     token_valid: "jnp.ndarray | None" = None,  # [B, T]; default: writes>=0
     adapter_idx: "jnp.ndarray | None" = None,  # [B] LoRA slot; -1 = base
     kind: tuple = ("attn", "dense"),   # cfg.layer_kind: trace-time structure
-    conv_state: "jnp.ndarray | None" = None,   # [B, taps-1, D] (conv layer)
+    conv_state=None,   # the rows' state: [B, taps-1, D] (conv layer), or
+                       # a MambaState of rows (Mamba layer)
     n_valid: "jnp.ndarray | None" = None,      # [B] real positions of T
     experts=None,                              # _mlp's, for an expert layer
 ):
     """One layer of ``kind`` (operator, feed-forward). Returns (x, k_pages,
-    v_pages, a conv layer's new state rows or None, the rows each expert
-    got or None)."""
+    v_pages, a conv or Mamba layer's new state rows or None, the rows each
+    expert got or None)."""
     op, ff = kind
     h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
     if op == "conv":
         out, conv_state = _short_conv(lp, cfg, h, conv_state, n_valid)
+    elif op == "mamba":
+        out, conv_state = _mamba(lp, cfg, h, conv_state, n_valid)
     elif op == "mla":
         out, k_pages = _latent_attention(
             cfg, inv_freq, page_table, positions, write_positions, lengths,
@@ -534,7 +688,7 @@ def _attention(cfg, inv_freq, page_table, positions, write_positions,
         from llms_on_kubernetes_tpu.ops.rope import apply_mrope
 
         q, k = apply_mrope(q, k, mm_pos3, inv_freq, cfg.mrope_section)
-    else:
+    elif cfg.use_rope:
         # rope_positions may be shifted by an mrope delta; ``positions``
         # stays token-indexed for attention masking / chunk history
         q, k = apply_rope(
@@ -602,9 +756,13 @@ def _run_layers(
     """The layer stack, run by run (``cfg.layer_runs``): each run is one
     ``lax.scan`` over its stacked parameters, with the body of its kind
     chosen at trace time. The pools hold the ATTENTION layers only, in
-    stack order; the conv state (``aux.conv``) the conv layers. Returns
+    stack order; the per-slot state (``aux.conv``) the conv or Mamba
+    layers, in theirs. Returns
     (x, k_pages, v_pages, aux with the new state and the experts' rows)."""
-    inv_freq = jnp.asarray(rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling))
+    # None: attention without positions (``_attention`` rotates nothing)
+    inv_freq = jnp.asarray(rope_frequencies(
+        cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)) \
+        if cfg.use_rope else None
     inv_freq_local = (
         jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_local_theta))
         if cfg.rope_local_theta is not None else None
@@ -621,7 +779,7 @@ def _run_layers(
     pages_per_layer = k_pages.shape[1] // max(n_attn, 1)
     conv = aux.conv if aux is not None else None
     B, T = x.shape[:2]
-    if cfg.num_conv_layers:
+    if cfg.keeps_slot_state:
         if conv is None and mode != "prefill":
             raise ValueError(
                 f"{cfg.name}: a {mode} pass continues a sequence and needs "
@@ -629,30 +787,55 @@ def _run_layers(
         n_valid = (lengths > 0).astype(jnp.int32) if mode == "decode" \
             else lengths
         slots = None if aux is None else aux.slots
-        trash = None if conv is None else conv.shape[1] - 1
+        trash = None if conv is None else \
+            jax.tree.leaves(conv)[0].shape[1] - 1
+        # the state a row starts a fresh sequence from, shaped as
+        # conv_rows'
+        if cfg.num_mamba_layers:
+            fresh = MambaState(
+                conv=jnp.zeros(
+                    (B, (cfg.mamba_d_conv - 1) * cfg.mamba_d_inner), x.dtype),
+                ssm=jnp.zeros((B, cfg.mamba_d_state, cfg.mamba_d_inner),
+                              jnp.float32))
+        else:
+            fresh = jnp.zeros((B, cfg.conv_L_cache - 1, x.shape[-1]),
+                              x.dtype)
+
+    def per_row(flags, a):
+        return flags.reshape(B, *[1] * (a.ndim - 1))
 
     def conv_rows(conv, ci):
-        """The state each row's convolution starts from, in conv layer ci."""
+        """The state each row's operator starts from, in conv (or Mamba)
+        layer ci: ``conv``'s arrays with the layer and slot axes taken."""
         if mode == "prefill":       # a fresh sequence: never a slot's past
-            return jnp.zeros((B, cfg.conv_L_cache - 1, x.shape[-1]), x.dtype)
-        if mode == "decode":        # row i is slot i
-            return jax.lax.dynamic_index_in_dim(conv, ci, 0, False)[:B]
-        rows = jax.lax.dynamic_index_in_dim(conv, ci, 0, False)[slots]
-        # a chunk continues its slot's sequence; a prompt's first chunk
-        # starts one, whatever the slot's last occupant left
-        return jnp.where((positions[:, 0] > 0)[:, None, None], rows, 0)
+            return fresh
+
+        def rows(a):
+            a = jax.lax.dynamic_index_in_dim(a, ci, 0, False)
+            if mode == "decode":    # row i is slot i
+                return a[:B]
+            # a chunk continues its slot's sequence; a prompt's first chunk
+            # starts one, whatever the slot's last occupant left
+            return jnp.where(per_row(positions[:, 0] > 0, a), a[slots], 0)
+
+        return jax.tree.map(rows, conv)
 
     def conv_write(conv, ci, old, new):
         if conv is None:
             return None
         live = lengths > 0
-        if mode == "decode":        # idle rows leave their slot as it was
-            rows = jnp.where(live[:, None, None], new, old).astype(conv.dtype)
-            return jax.lax.dynamic_update_slice(
-                conv, rows[None], (ci, 0, 0, 0))
-        # padding rows carry no slot: they write the trash row
-        return conv.at[ci, jnp.where(live, slots, trash)].set(
-            new.astype(conv.dtype))
+
+        def write(arr, old, new):
+            if mode == "decode":    # idle rows leave their slot as it was
+                rows = jnp.where(per_row(live, new), new, old)
+                return jax.lax.dynamic_update_slice(
+                    arr, rows.astype(arr.dtype)[None],
+                    (ci, *[0] * (arr.ndim - 1)))
+            # padding rows carry no slot: they write the trash row
+            return arr.at[ci, jnp.where(live, slots, trash)].set(
+                new.astype(arr.dtype))
+
+        return jax.tree.map(write, conv, old, new)
 
     def run_body(kind, stacks, first, a0, c0):
         """The scan body of one run: its kind, its expert stacks, and the
@@ -669,17 +852,18 @@ def _run_layers(
                 pt = page_table * n_attn + a_idx
             else:
                 pt = page_table + a_idx * pages_per_layer
-            old = conv_rows(cv, c_idx) if op == "conv" else None
+            keeps = op in ("conv", "mamba")
+            old = conv_rows(cv, c_idx) if keeps else None
             xc, kp, vp, new, rows = _layer_step(
                 cfg, inv_freq, pt, positions, write_positions, lengths, mode,
                 xc, lp, kp, vp, layer_idx=idx, inv_freq_local=inv_freq_local,
                 mm_groups=mm_groups, mm_pos3=mm_pos3,
                 rope_positions=rope_positions, token_valid=token_valid,
                 adapter_idx=adapter_idx, kind=kind, conv_state=old,
-                n_valid=n_valid if op == "conv" else None,
+                n_valid=n_valid if keeps else None,
                 experts=(stacks, i) if stacks else None,
             )
-            if op == "conv":
+            if keeps:
                 cv = conv_write(cv, c_idx, old, new)
             if deepstack is not None:
                 # DeepStack (Qwen3-VL): intermediate vision features are ADDED
@@ -711,7 +895,7 @@ def _run_layers(
             # boundary copy costs more than the whole rest of the step)
             unroll=n if _unroll_layers() else 1,
         )
-        if op == "conv":
+        if op in ("conv", "mamba"):
             c0 += n
         else:
             a0 += n

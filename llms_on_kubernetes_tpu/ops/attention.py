@@ -80,7 +80,11 @@ VMEM_BUDGET_BYTES = 96 << 20
 _chosen: dict[str, tuple[str, str]] = {}
 
 
-def _choose(op: str, impl: str, why: str) -> None:
+def record_choice(op: str, impl: str, why: str) -> None:
+    """Which implementation an operator runs and why, once a change: the
+    dispatchers here, and every other module that chooses one (the
+    experts' product, the state-space scan), under this one tag, which the
+    log readers know."""
     if _chosen.get(op) != (impl, why):
         _chosen[op] = (impl, why)
         print(f"[attention] op={op} impl={impl} why={why}",
@@ -476,7 +480,7 @@ def latent_paged_attention(q_abs, pool, page_table, lengths, *, scale: float,
 def dispatch_latent_prefill(qn, qr, rows, w_uk, w_uv, lengths, *, scale):
     """A prompt bucket over its own latent rows (nothing cached is read)."""
     B, T, H = qn.shape[:3]
-    _choose("prefill", "xla",
+    record_choice("prefill", "xla",
             f"latent rows expanded to {H} heads a block of up to "
             f"{LATENT_KEY_BLOCK} keys at a time, q.k "
             f"{qn.shape[3] + qr.shape[3]} wide, bucket {T}; no latent kernel")
@@ -491,7 +495,7 @@ def dispatch_latent_chunk(qn, qr, pool, page_table, w_uk, w_uv, history,
     which are already written: gathered through the page table, expanded
     block by block up to the last written one."""
     B, T = qn.shape[:2]
-    _choose("chunk", "xla",
+    record_choice("chunk", "xla",
             "cached latent rows gathered through the page table, expanded "
             f"a block of up to {LATENT_KEY_BLOCK} keys at a time as far as "
             "the row's last written block; no latent kernel")
@@ -542,7 +546,7 @@ def dispatch_latent_decode(q_abs, pool, page_table, lengths, *, scale, lat):
     heads, width = q_abs.shape[1], data.shape[3]
     mode, why = _latent_kernel_mode(data, page_table)
     if mode is None:
-        _choose("decode", "xla",
+        record_choice("decode", "xla",
                 f"absorbed: {heads} query heads over one "
                 f"{q_abs.shape[-1]}-wide row a token, gathered "
                 f"{LATENT_DECODE_PAGES * data.shape[2]} tokens of every slot "
@@ -552,7 +556,7 @@ def dispatch_latent_decode(q_abs, pool, page_table, lengths, *, scale, lat):
 
     from llms_on_kubernetes_tpu.ops.pallas_paged import pallas_latent_attention
 
-    _choose("decode", f"pallas-{mode}",
+    record_choice("decode", f"pallas-{mode}",
             f"latent: {heads} query heads over one {width}-lane row a "
             "token, live pages only")
     return pallas_latent_attention(q_abs, data, page_table, lengths,
@@ -583,7 +587,7 @@ def dispatch_prefill_attention(q, k, v, lengths, *, scale, sliding_window=None,
         # multimodal prompts take the XLA reference path: the image-block
         # bidirectional mask is a [B, T, T] override the flash/ring
         # kernels don't express (yet)
-        _choose("prefill", "xla", "multimodal image-block mask")
+        record_choice("prefill", "xla", "multimodal image-block mask")
         return prefill_attention(q, k, v, lengths, scale=scale,
                                  sliding_window=sliding_window,
                                  attn_softcap=attn_softcap,
@@ -599,7 +603,7 @@ def dispatch_prefill_attention(q, k, v, lengths, *, scale, sliding_window=None,
     if seq_parallelism() > 1 and static:
         from llms_on_kubernetes_tpu.ops.ring_attention import ring_prefill_attention
 
-        _choose("prefill", "ring", f"seq-parallel mesh ({seq_parallelism()})")
+        record_choice("prefill", "ring", f"seq-parallel mesh ({seq_parallelism()})")
         return ring_prefill_attention(
             q, k, v, lengths, get_active_mesh(), scale=scale,
             attn_softcap=attn_softcap, sliding_window=sliding_window,
@@ -623,11 +627,11 @@ def dispatch_prefill_attention(q, k, v, lengths, *, scale, sliding_window=None,
             why = (f"bucket {T} needs {_mib(need)} VMEM > "
                    f"{_mib(VMEM_BUDGET_BYTES)} budget")
     if why is not None:
-        _choose("prefill", "xla", why)
+        record_choice("prefill", "xla", why)
         return prefill_attention(q, k, v, lengths, scale=scale,
                                  sliding_window=sliding_window,
                                  attn_softcap=attn_softcap)
-    _choose("prefill", f"pallas-{mode}", f"flash kernel, bucket {T}")
+    record_choice("prefill", f"pallas-{mode}", f"flash kernel, bucket {T}")
     return _per_kv_head_shard(
         lambda q, k, v, lengths: flash_prefill_attention(
             q, k, v, lengths, scale=scale, sliding_window=sliding_window,
@@ -647,7 +651,7 @@ def dispatch_chunk_attention(q, k_pages, v_pages, page_table, history,
         # replicated inputs (pinned by tests/test_cp.py)
         from llms_on_kubernetes_tpu.ops.cp import cp_chunk_attention
 
-        _choose("chunk", "cp", f"seq-parallel mesh ({seq_parallelism()})")
+        record_choice("chunk", "cp", f"seq-parallel mesh ({seq_parallelism()})")
         return cp_chunk_attention(
             q, k_pages, v_pages, page_table, history, chunk_lengths,
             scale=scale, sliding_window=sliding_window,
@@ -655,7 +659,7 @@ def dispatch_chunk_attention(q, k_pages, v_pages, page_table, history,
     # XLA gather path everywhere for now: chunked prefill is bandwidth-bound
     # on the page gather, which XLA fuses acceptably; a Pallas paged-flash
     # chunk kernel is the designated upgrade path (see pallas_flash.py).
-    _choose("chunk", "xla", "no chunk kernel")
+    record_choice("chunk", "xla", "no chunk kernel")
     return chunk_attention(q, k_pages, v_pages, page_table, history,
                            chunk_lengths, scale=scale,
                            sliding_window=sliding_window,
@@ -765,7 +769,7 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
     kw = dict(scale=scale, sliding_window=sliding_window,
               attn_softcap=attn_softcap, interpret=mode == "interpret")
     if getattr(k_pages, "quantized", False):
-        _choose("decode", f"pallas-{mode}", "fused int8 write+attend kernel")
+        record_choice("decode", f"pallas-{mode}", "fused int8 write+attend kernel")
         attn, kd, ks, vd, vs = _per_kv_head_shard(
             lambda *a: pallas_paged.pallas_paged_attention_write_int8(*a, **kw),
             kd_shape[0],
@@ -773,7 +777,7 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
              page_table, lengths, k_new, v_new),
             (1, 0, 0, 0, 0, None, None, 1, 1), (1, 0, 0, 0, 0))
         return attn, KVPool(kd, ks), KVPool(vd, vs)
-    _choose("decode", f"pallas-{mode}", "fused write+attend kernel" + note)
+    record_choice("decode", f"pallas-{mode}", "fused write+attend kernel" + note)
     attn, kd, vd = _per_kv_head_shard(
         lambda *a: pallas_paged.pallas_paged_attention_write(*a, **kw),
         kd_shape[0],
@@ -796,13 +800,13 @@ def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,
         # (traced gemma window sizes hoist through the shard_map fine)
         from llms_on_kubernetes_tpu.ops.cp import cp_paged_attention
 
-        _choose("decode", "cp", f"seq-parallel mesh ({seq_parallelism()})")
+        record_choice("decode", "cp", f"seq-parallel mesh ({seq_parallelism()})")
         return cp_paged_attention(
             q, k_pages, v_pages, page_table, lengths, scale=scale,
             sliding_window=sliding_window, attn_softcap=attn_softcap)
     mode, note = _paged_kernel_mode(q, k_pages, page_table, sliding_window)
     if mode is None:
-        _choose("decode", "xla", note)
+        record_choice("decode", "xla", note)
         return paged_attention(q, k_pages, v_pages, page_table, lengths,
                                scale=scale, sliding_window=sliding_window,
                                attn_softcap=attn_softcap)
@@ -813,14 +817,14 @@ def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,
               attn_softcap=attn_softcap, interpret=mode == "interpret")
     kd = getattr(k_pages, "data", k_pages)
     if getattr(k_pages, "quantized", False):
-        _choose("decode", f"pallas-{mode}", "int8 paged kernel")
+        record_choice("decode", f"pallas-{mode}", "int8 paged kernel")
         return _per_kv_head_shard(
             lambda *a: pallas_paged.pallas_paged_attention_int8(*a, **kw),
             kd.shape[0],
             (q, k_pages.data, k_pages.scale, v_pages.data, v_pages.scale,
              page_table, lengths),
             (1, 0, 0, 0, 0, None, None), 1)
-    _choose("decode", f"pallas-{mode}", "paged kernel" + note)
+    record_choice("decode", f"pallas-{mode}", "paged kernel" + note)
     return _per_kv_head_shard(
         lambda *a: pallas_paged.pallas_paged_attention(*a, **kw),
         kd.shape[0],

@@ -144,9 +144,9 @@ def _plan(x, pairs: int, stacks, experts: int) -> "int | None":
             why = (f"{what} need {attention._mib(need)} VMEM > "
                    f"{attention._mib(attention.VMEM_BUDGET_BYTES)} budget")
     if why is not None:
-        attention._choose("experts", "xla", f"ragged_dot, {why}")
+        attention.record_choice("experts", "xla", f"ragged_dot, {why}")
         return None
-    attention._choose("experts", f"pallas-{mode}", f"{what}, row tile {tile}")
+    attention.record_choice("experts", f"pallas-{mode}", f"{what}, row tile {tile}")
     return tile
 
 

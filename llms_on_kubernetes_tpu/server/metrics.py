@@ -602,16 +602,31 @@ def engine_metrics(registry: Registry) -> dict:
             "Device bytes of the latent pool (one latent row a token a "
             "layer, no V side) of a latent-attention model; 0 for a model "
             "with K and V pools", registry),
+        "path_tokens": Counter(
+            "llm_path_tokens_total",
+            "Real tokens the model ran, by the path that ran them, whatever "
+            "the model: prefill=a bucket from an empty cache and state; "
+            "chunk=a chunk over its slot's cached positions and state; "
+            "decode=one step a token inside the decode window",
+            registry, label_names=("path",)),
+        "ssm_positions": Counter(
+            "llm_ssm_positions_total",
+            "Positions the Mamba layers' scan or step ran over by each "
+            "path, a bucket's padding and a decode window's idle rows "
+            "included; over llm_path_tokens_total it is the work done per "
+            "real token. 0 for a model without Mamba layers",
+            registry, label_names=("path",)),
         "conv_state_bytes": Gauge(
             "llm_conv_state_bytes",
-            "Device bytes of the per-slot short-convolution state that "
-            "conv layers keep beside the KV pool (0 for a model without "
-            "them)", registry),
+            "Device bytes of the per-slot state that conv layers (their "
+            "short-convolution window) or Mamba layers (their convolution "
+            "window and float32 state-space state) keep beside the KV pool "
+            "(0 for a model without them)", registry),
         "prefix_reuse_skipped": Counter(
             "llm_prefix_reuse_skipped_total",
             "Admissions that adopted no cached prefix though the prefix "
-            "cache was on, by reason: recurrent_state=the model has conv "
-            "layers, and a cached page holds no conv state at its end",
+            "cache was on, by reason: recurrent_state=the model has conv or "
+            "Mamba layers, and a cached page holds no such state at its end",
             registry, label_names=("why",)),
         "auto_profile": Counter(
             "llm_auto_profile_total",
@@ -637,6 +652,8 @@ def engine_metrics(registry: Registry) -> dict:
     m["prefix_reuse_skipped"].labels(why="recurrent_state")
     for path in ("prefill", "chunk", "decode"):
         m["mla_tokens"].labels(path=path)
+        m["path_tokens"].labels(path=path)
+        m["ssm_positions"].labels(path=path)
     for kind in ("prefill", "chunk", "decode"):
         for stat in MOE_STATS:
             m["moe_" + stat].labels(kind=kind)

@@ -207,7 +207,7 @@ class EngineLoop(threading.Thread):
         self._ttft_seen: set[str] = set()
         self._preempt_seen = 0
         self._moe_seen: collections.Counter = collections.Counter()
-        self._mla_seen: collections.Counter = collections.Counter()
+        self._path_seen: collections.Counter = collections.Counter()
         self._prefix_skipped_seen: collections.Counter = (
             collections.Counter())
         self._early_exit_seen = 0
@@ -387,18 +387,24 @@ class EngineLoop(threading.Thread):
                 cc = getattr(eng, "cache_config", None)
                 if cc is not None:
                     m["kv_bytes_per_token"].set(cc.bytes_per_token)
-                if getattr(eng.model_config, "is_mla", False):
-                    paths = dict(eng.path_tokens, decode=eng.decode_tokens)
-                    for path, v in paths.items():
-                        if v > self._mla_seen[path]:
-                            m["mla_tokens"].labels(path=path).inc(
-                                v - self._mla_seen[path])
-                            self._mla_seen[path] = v
+                # tokens by path, whatever the model; the same under the
+                # latent model's own name, which its benchmark metric reads
+                mla = getattr(eng.model_config, "is_mla", False)
+                counts = {"path_tokens": dict(eng.path_tokens,
+                                              decode=eng.decode_tokens),
+                          "ssm_positions": eng.ssm_positions}
+                for name, by_path in counts.items():
+                    for path, v in by_path.items():
+                        new = v - self._path_seen[name, path]
+                        if new > 0:
+                            m[name].labels(path=path).inc(new)
+                            if mla and name == "path_tokens":
+                                m["mla_tokens"].labels(path=path).inc(new)
+                            self._path_seen[name, path] = v
+                if mla:
                     kp = eng.k_pages.data
                     m["latent_cache_bytes"].set(kp.size * kp.dtype.itemsize)
-                conv = getattr(eng, "conv_state", None)
-                m["conv_state_bytes"].set(
-                    0 if conv is None else conv.size * conv.dtype.itemsize)
+                m["conv_state_bytes"].set(eng.slot_state_bytes)
                 if led_snap is not None:
                     series = dict(led_snap["phase_ms"])
                     series["idle"] = led_snap["idle_ms"]

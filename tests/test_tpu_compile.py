@@ -656,3 +656,61 @@ def test_deepseek_v3_steps_fit_a_v5e_and_leave_the_latent_pool_in_place(
     assert attention._chosen[kind][0] == (
         "pallas-compiled" if kind == "decode" else "xla")
     assert attention._chosen["experts"][0] == "pallas-compiled"
+
+
+def test_jamba_decode_window_keeps_the_mamba_state_in_place(
+        one_chip, no_cache, monkeypatch):
+    """The fused K = 4 decode window of jamba2-3b at its cell's sizes (6.06
+    GB of weights, a 0.27 GB pool, 1.2 GB of per-slot Mamba state): it
+    compiles for a v5e with half the chip to spare, the state goes in and
+    comes out in the buffers it came in, no step copies the float32
+    state-space state (one copy a step would be the whole cell), and the
+    paged decode kernel takes one KV head under 20 query heads."""
+    from llms_on_kubernetes_tpu.configs import get_config
+    from llms_on_kubernetes_tpu.engine import engine as E
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
+    from llms_on_kubernetes_tpu.models import decoder
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
+    cfg = get_config("jamba2-3b")
+    slots, page, pps, pages = 128, 64, 32, 4097
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def shaped(shape, dtype):
+        return sds(jax.ShapeDtypeStruct(shape, dtype))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: decoder.init_params(cfg, jax.random.key(0),
+                                    dtype="bfloat16")))
+    state = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: decoder.init_conv_state(cfg, slots, "bfloat16")))
+    pool = KVPool(shaped((1, cfg.num_attn_layers * pages, page, 128),
+                         jnp.bfloat16))
+    key = sds(jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = jax.jit(
+        E._decode_multi_packed_step, static_argnums=(1, 2),
+        donate_argnums=(6, 7, 8, 11)).lower(
+            params, cfg, 4, shaped((slots, E._DEC_COLS + pps), jnp.int32),
+            shaped((slots,), jnp.int32), shaped((1,), jnp.int32), pool, pool,
+            shaped((slots, cfg.vocab_size), jnp.int32), key, None,
+            state).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert state_bytes == 1_202_073_600
+    assert mem.alias_size_in_bytes >= state_bytes + 2 * 134_250_496
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"[jamba_compile] decode K = 4: temp {mem.temp_size_in_bytes} B, "
+          f"peak {peak} B")
+    assert peak < 8.5e9, peak
+    hlo = compiled.as_text()
+    assert not [ln for ln in hlo.splitlines()
+                if (" copy(" in ln or " copy-start(" in ln)
+                and "f32[26,129,16,5120]" in ln.split("=", 1)[1][:60]]
+    assert attention._chosen["decode"] == (
+        "pallas-compiled", "fused write+attend kernel")
