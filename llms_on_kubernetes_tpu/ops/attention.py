@@ -337,7 +337,11 @@ def chunk_attention(
 # its first lat lanes, value, so a live slot's pages cross HBM -> VMEM once
 # and an idle slot's never. latent_paged_attention below is its reference
 # and what every other backend, and a mesh, serve with. The expanded paths
-# (buckets, chunks) have no kernel yet.
+# (buckets, chunks) have one too (pallas_flash.flash_latent_attention,
+# chosen by dispatch_latent_prefill and dispatch_latent_chunk): a block of
+# keys is expanded to a few heads at a time in VMEM and the score tiles,
+# the running softmax and the accumulator never leave it.
+# latent_expanded_attention below is its reference and the fall-back.
 
 LATENT_KEY_BLOCK = 256
 
@@ -477,32 +481,98 @@ def latent_paged_attention(q_abs, pool, page_table, lengths, *, scale: float,
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_abs.dtype)
 
 
+def _latent_mesh_why() -> str:
+    """Why neither latent kernel runs under the active mesh ("" if it
+    does): one row a token that every head shares, so there is no head
+    axis to give each chip its own part of, as ``_per_kv_head_shard``
+    does."""
+    from llms_on_kubernetes_tpu.parallel.mesh import seq_parallelism
+
+    if _model_shards() == 1 and seq_parallelism() == 1:
+        return ""
+    return (f"a mesh of model {_model_shards()} x seq {seq_parallelism()}: "
+            "a latent pool has one head, the kernel is not partitioned")
+
+
+def _latent_flash_mode(qn, qr, S, w_uk, w_uv):
+    """(mode, why not) for the expanded paths' kernel
+    (pallas_flash.flash_latent_attention) at a bucket of queries over S
+    rows, as ``_latent_kernel_mode`` is for the decode kernel: from the
+    widths, the bucket, the active mesh and the VMEM a program needs."""
+    from llms_on_kubernetes_tpu.ops.pallas_flash import (
+        latent_flash_blocks, latent_flash_vmem_bytes,
+    )
+
+    mode = pallas_mode()
+    if mode is None:
+        return None, _no_pallas_why()
+    if _latent_mesh_why():
+        return None, _latent_mesh_why()
+    T, H, nope = qn.shape[1:]
+    lat, vd = w_uk.shape[1], w_uv.shape[2]
+    if mode == "compiled":
+        # Mosaic's tiling: heads lie side by side on the lanes, queries and
+        # keys on the sublanes of their blocks
+        qb, kb, _ = latent_flash_blocks(T, S, H)
+        for what, n in (("a latent", lat), ("an un-roped key", nope),
+                        ("a value", vd), (f"a key block of {S} rows", kb)):
+            if n % 128 != 0:
+                return None, f"{what} of {n} is not a multiple of 128"
+        if qb % 16 != 0:
+            return None, f"a query tile of bucket {T} is {qb} rows, not 16s"
+    need = latent_flash_vmem_bytes(T, S, H, lat, qr.shape[3], nope, vd,
+                                   qn.dtype.itemsize)
+    if need > VMEM_BUDGET_BYTES:
+        return None, (f"bucket {T} needs {_mib(need)} VMEM > "
+                      f"{_mib(VMEM_BUDGET_BYTES)} budget")
+    return mode, ""
+
+
+def _latent_flash(op, said, qn, qr, rows, w_uk, w_uv, history, kv_len, scale):
+    """The expanded paths' one choice: the flash kernel over latent rows
+    wherever ``_latent_flash_mode`` lets it, else the XLA loop
+    (``latent_expanded_attention``) with the reason; ``said`` is the
+    path's own half of the record."""
+    from llms_on_kubernetes_tpu.ops.pallas_flash import (
+        flash_latent_attention, latent_flash_blocks,
+    )
+
+    T, H = qn.shape[1:3]
+    mode, why = _latent_flash_mode(qn, qr, rows.shape[1], w_uk, w_uv)
+    if mode is None:
+        record_choice(op, "xla",
+                f"{said}, expanded to {H} heads a block of up to "
+                f"{LATENT_KEY_BLOCK} keys at a time as far as the row's "
+                f"last written block, bucket {T}; {why}")
+        q_pos = history[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        return latent_expanded_attention(qn, qr, rows, w_uk, w_uv, q_pos,
+                                         kv_len, scale=scale)
+    qb, kb, hb = latent_flash_blocks(T, rows.shape[1], H)
+    record_choice(op, f"pallas-{mode}",
+            f"latent flash kernel: {said}, a block of {kb} keys expanded "
+            f"to {hb} of {H} heads a program in VMEM, q.k "
+            f"{qn.shape[3] + qr.shape[3]} wide, query tiles of {qb}, "
+            f"bucket {T}")
+    return flash_latent_attention(qn, qr, rows, w_uk, w_uv, history, kv_len,
+                                  scale=scale, interpret=mode == "interpret")
+
+
 def dispatch_latent_prefill(qn, qr, rows, w_uk, w_uv, lengths, *, scale):
     """A prompt bucket over its own latent rows (nothing cached is read)."""
-    B, T, H = qn.shape[:3]
-    record_choice("prefill", "xla",
-            f"latent rows expanded to {H} heads a block of up to "
-            f"{LATENT_KEY_BLOCK} keys at a time, q.k "
-            f"{qn.shape[3] + qr.shape[3]} wide, bucket {T}; no latent kernel")
-    q_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    return latent_expanded_attention(qn, qr, rows, w_uk, w_uv, q_pos, lengths,
-                                     scale=scale)
+    return _latent_flash("prefill", "the bucket's own latent rows", qn, qr,
+                         rows, w_uk, w_uv, jnp.zeros_like(lengths), lengths,
+                         scale)
 
 
 def dispatch_latent_chunk(qn, qr, pool, page_table, w_uk, w_uv, history,
                           chunk_lengths, *, scale):
     """A chunk over the cached latents of its history and its own rows,
-    which are already written: gathered through the page table, expanded
-    block by block up to the last written one."""
-    B, T = qn.shape[:2]
-    record_choice("chunk", "xla",
-            "cached latent rows gathered through the page table, expanded "
-            f"a block of up to {LATENT_KEY_BLOCK} keys at a time as far as "
-            "the row's last written block; no latent kernel")
-    q_pos = history[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    return latent_expanded_attention(
-        qn, qr, _gather_latent(pool, page_table), w_uk, w_uv, q_pos,
-        history + chunk_lengths, scale=scale)
+    which are already written: gathered through the page table (11.8 MB a
+    layer at 9,216 tokens; the pool stays where it is), attended as far as
+    the row's last written block."""
+    return _latent_flash("chunk", "cached latent rows gathered through the "
+                         "page table", qn, qr, _gather_latent(pool, page_table),
+                         w_uk, w_uv, history, history + chunk_lengths, scale)
 
 
 def _latent_kernel_mode(pool, page_table):
@@ -510,17 +580,12 @@ def _latent_kernel_mode(pool, page_table):
     ``_paged_kernel_mode`` is for the K/V pools' kernels: from the pool's
     stored shape and type, the page table's width and the active mesh."""
     from llms_on_kubernetes_tpu.ops.pallas_paged import latent_vmem_bytes
-    from llms_on_kubernetes_tpu.parallel.mesh import seq_parallelism
 
     mode = pallas_mode()
     if mode is None:
         return None, _no_pallas_why()
-    if _model_shards() > 1 or seq_parallelism() > 1:
-        # one row a token that every head shares: no head axis to give
-        # each chip its own part of, as _per_kv_head_shard does
-        return None, (f"a mesh of model {_model_shards()} x seq "
-                      f"{seq_parallelism()}: a latent pool has one head, "
-                      "the kernel is not partitioned")
+    if _latent_mesh_why():
+        return None, _latent_mesh_why()
     _, _, page, width = pool.shape
     if mode == "compiled":
         # Mosaic's tiling, as for the K/V pools: a page DMA is whole

@@ -21,11 +21,26 @@ Kernel shape (v2):
   row of q-blocks without re-fetching.
 - Masking (causal + pad-length + optional sliding window) is additive in
   f32; softmax in f32 (same numerics policy as the reference impl).
+
+A second kernel, ``flash_latent_attention`` (below, PR 47), serves the two
+EXPANDED paths of latent attention (DeepSeek MLA: a prompt bucket over its
+own rows, a chunk over its history's cached rows). It is a function of its
+own because nothing above fits it: a key is 192 wide and a value 128, both
+exist only as a 512-wide latent row that all heads share, and a chunk's
+keys are too many to hold a head's whole K/V in VMEM. It STREAMS: grid
+(B, heads / 4, key blocks) with the keys innermost; a program expands one
+block of 512 latent rows to its four heads' keys and values in VMEM (never
+in HBM), and every tile of 512 queries that can see the block takes its
+scores, its running softmax and p.v there, the running maximum, sum and
+accumulator of all the bucket's queries resident in VMEM scratch. Its
+reference, and what every other backend and a mesh run, is
+``ops/attention.py::latent_expanded_attention``.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -143,3 +158,232 @@ def flash_prefill_attention(
         interpret=check_interpret(interpret),
     )(lengths.astype(jnp.int32), qh, kh, vh)
     return jnp.swapaxes(out, 1, 2)  # back to [B, T, n_q, d]
+
+
+# ---------------------------------------------------------------------------
+# Latent rows (DeepSeek MLA), expanded: prompt buckets and chunks
+# ---------------------------------------------------------------------------
+
+# queries a tile, keys a block, heads a program: chosen once from the
+# chip's readings at deepseek-v3's cell (PERF.md section 6, PR 47)
+LATENT_BLOCK_Q = 512
+LATENT_BLOCK_K = 512
+LATENT_HEADS = 4
+_LANES = 128
+_LATENT_HEADROOM = 8 << 20
+
+
+def latent_flash_blocks(T: int, S: int, H: int) -> tuple[int, int, int]:
+    """(queries a tile, keys a block, heads a program) at a bucket of T
+    queries over S rows and H heads: the constants, or what of them divides
+    the shape."""
+    return (math.gcd(T, LATENT_BLOCK_Q), math.gcd(S, LATENT_BLOCK_K),
+            math.gcd(H, LATENT_HEADS))
+
+
+def latent_flash_vmem_bytes(T: int, S: int, H: int, lat: int, rope: int,
+                            nope: int, vd: int, itemsize: int) -> int:
+    """VMEM a program of ``flash_latent_attention`` is given: its blocks of
+    queries, output, rows and the heads' two matrices (double-buffered by
+    the pipeline), the running maximum, sum and accumulator of every query
+    of its heads in float32, a key block's expansion to those heads, six
+    [tile, block] float32 temporaries (scores, mask, probabilities and
+    exp's) and ``_LATENT_HEADROOM``. ``rope`` counts as whole lane tiles."""
+    qb, kb, hb = latent_flash_blocks(T, S, H)
+    rope = _whole_lanes(rope)
+    blocks = (T * hb * (nope + rope + vd) + kb * (lat + rope)
+              + hb * lat * (nope + vd)) * itemsize
+    state = hb * T * (2 * _LANES + vd) * 4
+    block = hb * kb * (nope + vd) * (4 + itemsize) + 6 * qb * kb * 4
+    return 2 * blocks + state + block + _LATENT_HEADROOM
+
+
+def _whole_lanes(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
+def _lanes(x, n: int):
+    """A lane-replicated [rows, 128] column as [rows, n]."""
+    if n % _LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == _LANES else pltpu.repeat(x, n // _LANES, axis=1)
+
+
+def _flash_latent_kernel(
+    history_ref,   # SMEM [B] position of each row's first query (prefetch)
+    kv_len_ref,    # SMEM [B] rows that are written; 0 => idle (prefetch)
+    qn_ref,        # VMEM [1, T, hb * nope]   heads side by side on lanes
+    qr_ref,        # VMEM [1, T, hb * rp]     rotated part, zeros behind
+    rows_ref,      # VMEM [1, kb, lat + rp]   [c | k_r | 0] of a key block
+    wuk_ref,       # VMEM [hb, lat, nope]
+    wuv_ref,       # VMEM [hb, lat, vd]
+    o_ref,         # VMEM [1, T, hb * vd]
+    m_ref,         # VMEM [hb, T, 128] f32 running maximum, lane-replicated
+    l_ref,         # VMEM [hb, T, 128] f32 running sum
+    acc_ref,       # VMEM [hb, T, vd]  f32
+    *,
+    scale: float,
+    qb: int,
+    kb: int,
+):
+    """One batch row's ``hb`` heads against one block of ``kb`` keys (grid
+    (B, H / hb, S / kb), keys innermost): the block's latents expanded to
+    each head's keys and values ONCE, in VMEM, then every tile of ``qb``
+    queries that can see the block takes its scores, running softmax and
+    p.v there. ``latent_expanded_attention``'s numbers: operands in their
+    type, float32 products, maximum, sum and accumulator; p cast to the
+    values' type. Tiles wholly under the diagonal and inside ``kv_len``
+    skip the mask; key blocks past ``kv_len`` are not run (nor fetched: the
+    rows' index map stops at the last written block)."""
+    b, ki = pl.program_id(0), pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    hb, lat, nope = wuk_ref.shape
+    vd = wuv_ref.shape[2]
+    rp = rows_ref.shape[2] - lat
+    T = qn_ref.shape[1]
+    nq = T // qb
+    history, kv_len = history_ref[b], kv_len_ref[b]
+    k0 = ki * kb
+    nt = (((1,), (1,)), ((), ()))          # a . b^T
+
+    def tiles(body):
+        def run(j, carry):
+            body(pl.ds(pl.multiple_of(j * qb, qb), qb), j)
+            return carry
+        return run
+
+    @pl.when(ki == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(k0 < kv_len)
+    def _():
+        rows = rows_ref[0]
+        # a row nobody wrote may hold anything: p = 0 times it must be 0
+        written = k0 + jax.lax.broadcasted_iota(jnp.int32, (kb, 1), 0) < kv_len
+        rows = jnp.where(written, rows, jnp.zeros_like(rows))
+        c, kr = rows[:, :lat], rows[:, lat:]
+        # the first tile whose last query reaches this block, and the first
+        # whose every query sees all of it (none if the block is not whole)
+        first = jnp.maximum(k0 - history, 0) // qb
+        clear = jnp.where(
+            k0 + kb <= kv_len,
+            jnp.clip((k0 + kb - 1 - history + qb - 1) // qb, first, nq), nq)
+
+        kns = [jnp.dot(c, wuk_ref[i], preferred_element_type=jnp.float32
+                       ).astype(c.dtype) for i in range(hb)]
+        vs = [jnp.dot(c, wuv_ref[i], preferred_element_type=jnp.float32
+                      ).astype(c.dtype) for i in range(hb)]
+
+        def tile(r, j, i, masked):
+            s = (jax.lax.dot_general(
+                    qn_ref[0, r, i * nope:(i + 1) * nope], kns[i], nt,
+                    preferred_element_type=jnp.float32)
+                 + jax.lax.dot_general(
+                    qr_ref[0, r, i * rp:(i + 1) * rp], kr, nt,
+                    preferred_element_type=jnp.float32)) * scale
+            if masked:
+                q_pos = history + j * qb + jax.lax.broadcasted_iota(
+                    jnp.int32, (qb, kb), 0)
+                k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 1)
+                mask = (k_pos <= q_pos) & (k_pos < kv_len)
+                s = jnp.where(mask, s, NEG_INF)
+            m_old = m_ref[i, r, :]
+            m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, kb))
+            if masked:
+                p = jnp.where(mask, p, 0.0)
+            alpha = jnp.exp(m_old - m_new)
+            m_ref[i, r, :] = m_new
+            l_ref[i, r, :] = (l_ref[i, r, :] * alpha
+                              + p.sum(axis=-1, keepdims=True))
+            acc_ref[i, r, :] = (
+                acc_ref[i, r, :] * _lanes(alpha, vd) + jnp.dot(
+                    p.astype(vs[i].dtype), vs[i],
+                    preferred_element_type=jnp.float32))
+
+        # the heads' tiles side by side in one loop body: independent
+        # chains, so one head's softmax runs under another's products
+        # (7 % faster on a v5e than a loop a head)
+        def all_heads(masked):
+            def body(r, j):
+                for i in range(hb):
+                    tile(r, j, i, masked)
+            return tiles(body)
+
+        jax.lax.fori_loop(first, clear, all_heads(True), None)
+        jax.lax.fori_loop(clear, nq, all_heads(False), None)
+
+    @pl.when(ki == last)
+    def _():
+        for i in range(hb):
+            def write(r, j, i=i):
+                l = jnp.maximum(l_ref[i, r, :], 1e-30)
+                o_ref[0, r, i * vd:(i + 1) * vd] = (
+                    acc_ref[i, r, :] / _lanes(l, vd)).astype(o_ref.dtype)
+
+            jax.lax.fori_loop(0, nq, tiles(write), None)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def flash_latent_attention(
+    qn: jnp.ndarray,        # [B, T, H, nope]
+    qr: jnp.ndarray,        # [B, T, H, rope]  (rotated)
+    rows: jnp.ndarray,      # [B, S, >= lat + rope]: [c | k_r | zeros]
+    w_uk: jnp.ndarray,      # [H, lat, nope]
+    w_uv: jnp.ndarray,      # [H, lat, vd]
+    history: jnp.ndarray,   # [B] int32: query t of a row is at history + t
+    kv_len: jnp.ndarray,    # [B] int32: rows written (0 => idle row)
+    *,
+    scale: float,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``attention.latent_expanded_attention`` as a kernel, for queries at
+    consecutive positions: [B, T, H, vd] in qn's type. The rows are read as
+    they lie, a block of keys at a time; no score tile, no expanded key or
+    value and no running state is written to HBM."""
+    B, T, H, nope = qn.shape
+    S, lat, vd, rope = rows.shape[1], w_uk.shape[1], w_uv.shape[2], qr.shape[3]
+    qb, kb, hb = latent_flash_blocks(T, S, H)
+    # the rotated part in whole lane tiles: a cached row already is (zeros
+    # behind its 64 roped values); a bucket's own rows and q_r are padded
+    rp = _whole_lanes(rope)
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, max(
+        0, lat + rp - rows.shape[2]))))[..., :lat + rp]
+    qr = jnp.pad(qr, ((0, 0),) * 3 + ((0, rp - rope),))
+
+    def last_block(b, kv_len):
+        return jnp.maximum((kv_len[b] + kb - 1) // kb - 1, 0)
+
+    def heads(width):
+        return pl.BlockSpec((1, T, hb * width), lambda b, h, k, *_: (b, 0, h))
+
+    def matrix(width):
+        return pl.BlockSpec((hb, lat, width), lambda b, h, k, *_: (h, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_flash_latent_kernel, scale=scale, qb=qb, kb=kb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H // hb, S // kb),
+            in_specs=[
+                heads(nope), heads(rp),
+                pl.BlockSpec((1, kb, lat + rp), lambda b, h, k, hist, n: (
+                    b, jnp.minimum(k, last_block(b, n)), 0)),
+                matrix(nope), matrix(vd)],
+            out_specs=heads(vd),
+            scratch_shapes=[pltpu.VMEM((hb, T, _LANES), jnp.float32),
+                            pltpu.VMEM((hb, T, _LANES), jnp.float32),
+                            pltpu.VMEM((hb, T, vd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, T, H * vd), qn.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=latent_flash_vmem_bytes(
+                T, S, H, lat, rope, nope, vd, qn.dtype.itemsize)),
+        name="flash_latent_attention",
+        interpret=check_interpret(interpret),
+    )(history.astype(jnp.int32), kv_len.astype(jnp.int32),
+      qn.reshape(B, T, H * nope), qr.reshape(B, T, H * rp), rows, w_uk, w_uv)
+    return out.reshape(B, T, H, vd)

@@ -461,17 +461,20 @@ def test_the_prefix_cache_adopts_latent_pages(params32):
     held_to_reference(params32, b)
 
 
-@pytest.mark.parametrize("impl,said", [
-    ("auto", "xla (absorbed: 4 query heads over one"),
+@pytest.mark.parametrize("impl,said,prompts", [
+    ("auto", "xla (absorbed: 4 query heads over one",
+     "xla (the bucket's own latent rows, expanded to 4 heads"),
     ("pallas", "pallas-interpret (latent: 4 query heads over one 128-lane "
-               "row a token, live pages only)"),
+               "row a token, live pages only)",
+     "pallas-interpret (latent flash kernel: the bucket's own latent rows"),
 ])
 def test_debug_engine_lists_the_attention_records(params32, monkeypatch, impl,
-                                                  said):
+                                                  said, prompts):
     """``GET /debug/engine`` says under ``"attention"`` what each dispatcher
-    last chose, the absorbed decode step's among them: the XLA loop with
-    its reason on the CPU backend, the latent kernel where the kernels run
-    (interpreted here), whose streams are the reference's too."""
+    last chose, the absorbed decode step's and the prompt bucket's among
+    them: the XLA loops with their reason on the CPU backend, the latent
+    kernels where the kernels run (interpreted here), whose streams are
+    the reference's too."""
     import asyncio
 
     from aiohttp.test_utils import TestClient, TestServer
@@ -502,7 +505,7 @@ def test_debug_engine_lists_the_attention_records(params32, monkeypatch, impl,
         jax.clear_caches()
     assert set(snap["attention"]) >= {"prefill", "decode", "experts"}
     assert snap["attention"]["decode"].startswith(said)
-    assert snap["attention"]["prefill"].startswith("xla (latent rows expanded")
+    assert snap["attention"]["prefill"].startswith(prompts)
 
 
 def test_preemption_and_resume(params32):

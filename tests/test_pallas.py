@@ -690,16 +690,12 @@ LATENT_CASES = {
 }
 
 
-def latent_case(rng, case):
-    """(q_abs, clean pool, poisoned pool, table, lengths) of a latent
-    case. The poisoned pool holds NaN wherever no row has written: page 0,
-    the pages no table names and a row's last page past its length (the
-    kernel must never let them through; the XLA loop multiplies them by a
-    zero probability, so it is given the clean pool)."""
-    page, pps, lengths, share, dtype = LATENT_CASES[case]
-    lengths = np.asarray(lengths, np.int32)
+def _latent_pools(rng, lengths, page, pps, width, share=()):
+    """(clean pool, poisoned pool, page table) of rows of ``lengths``
+    written tokens behind a permuted page table: the poisoned pool holds
+    NaN wherever no row has written."""
     B, P = len(lengths), len(lengths) * pps + 1
-    pool = rng.normal(size=(1, P, page, WIDTH)).astype(np.float32)
+    pool = rng.normal(size=(1, P, page, width)).astype(np.float32)
     pool[..., LAT + ROPE:] = 0.0
     table = np.zeros((B, pps), np.int32)
     perm = rng.permutation(P - 1) + 1
@@ -712,8 +708,20 @@ def latent_case(rng, case):
     for b in range(B):
         for t in range(lengths[b]):
             written[table[b, t // page], t % page] = True
-    poisoned = np.where(written[None, :, :, None], pool, np.nan)
-    q = jnp.asarray(rng.normal(size=(B, HEADS, LAT + ROPE)), dtype)
+    return pool, np.where(written[None, :, :, None], pool, np.nan), table
+
+
+def latent_case(rng, case):
+    """(q_abs, clean pool, poisoned pool, table, lengths) of a latent
+    case. The poisoned pool holds NaN wherever no row has written: page 0,
+    the pages no table names and a row's last page past its length (the
+    kernel must never let them through; the XLA loop multiplies them by a
+    zero probability, so it is given the clean pool)."""
+    page, pps, lengths, share, dtype = LATENT_CASES[case]
+    lengths = np.asarray(lengths, np.int32)
+    pool, poisoned, table = _latent_pools(rng, lengths, page, pps, WIDTH,
+                                          share)
+    q = jnp.asarray(rng.normal(size=(len(lengths), HEADS, LAT + ROPE)), dtype)
     return (q, jnp.asarray(pool, dtype), jnp.asarray(poisoned, dtype),
             jnp.asarray(table), jnp.asarray(lengths))
 
@@ -788,6 +796,176 @@ def test_latent_dispatcher_says_why_it_took_the_xla_loop(rng, monkeypatch):
         assert mode is None and why == reason
     assert attention._latent_kernel_mode(
         jnp.zeros((1, 3, 64, 640), jnp.bfloat16), table) == ("compiled", "")
+
+
+# ---------------------------------------------------------------------------
+# the latent rows' flash kernel (DeepSeek MLA, expanded: buckets and chunks)
+# ---------------------------------------------------------------------------
+
+NOPE, VDIM, FLASH_WIDTH = 16, 16, 48
+# the kernel's constants at this size: query tiles of 16, key blocks of 32
+# (two pages of 16), two of the four heads a program
+FLASH_BLOCKS = {"LATENT_BLOCK_Q": 16, "LATENT_BLOCK_K": 32, "LATENT_HEADS": 2}
+# name -> (bucket, pages a slot or None for a bucket over its own rows,
+# (history, tokens) a row, type). A chunk's rows are read from a pool that
+# holds NaN wherever nothing was written, through a permuted page table.
+FLASH_CASES = {
+    "a bucket with ragged lengths": (64, None, [(0, 64), (0, 37), (0, 1),
+                                                (0, 33)], "float32"),
+    "a bucket beside an idle row": (64, None, [(0, 0), (0, 50)], "float32"),
+    "a chunk whose history ends inside a page and a key block":
+        (64, 16, [(37, 64)], "float32"),
+    "a chunk whose history ends on a page's edge inside a key block":
+        (64, 16, [(48, 64)], "float32"),
+    "a chunk whose history ends on both edges": (64, 16, [(64, 64)],
+                                                 "float32"),
+    "a last chunk padded to a smaller bucket": (32, 16, [(96, 11)],
+                                                "float32"),
+    "an idle row among chunks": (64, 16, [(70, 64), (0, 0), (128, 5)],
+                                 "float32"),
+    "two rows of different history": (64, 16, [(20, 64), (130, 40)],
+                                      "float32"),
+    "a chunk up to the slot's last row": (64, 16, [(192, 64)], "float32"),
+    "bfloat16 rows and queries": (64, 16, [(37, 64), (150, 20)], "bfloat16"),
+}
+
+
+def flash_case(rng, case):
+    """(qn, qr, clean rows, poisoned rows, w_uk, w_uv, history, kv_len) of
+    a flash case; a chunk's rows are gathered from the two pools."""
+    from llms_on_kubernetes_tpu.ops.attention import _gather_latent
+
+    T, pps, spans, dtype = FLASH_CASES[case]
+    history, tokens = np.asarray(spans, np.int32).T
+    kv_len, B, page = history + tokens, len(spans), 16
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), dtype)
+
+    qn, qr = normal(B, T, HEADS, NOPE), normal(B, T, HEADS, ROPE)
+    w_uk, w_uv = normal(HEADS, LAT, NOPE), normal(HEADS, LAT, VDIM)
+    if pps is None:                     # a bucket: its own rows, as wide
+        rows = normal(B, T, LAT + ROPE)  # as the projection leaves them
+        return (qn, qr, rows, rows, w_uk, w_uv, jnp.asarray(history),
+                jnp.asarray(kv_len))
+    pool, poisoned, table = _latent_pools(rng, kv_len, page, pps,
+                                          FLASH_WIDTH)
+    clean, poisoned = (_gather_latent(jnp.asarray(a, dtype),
+                                      jnp.asarray(table))
+                       for a in (pool, poisoned))
+    return (qn, qr, clean, poisoned, w_uk, w_uv, jnp.asarray(history),
+            jnp.asarray(kv_len))
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_latent_flash_kernel_matches_reference(rng, monkeypatch, case):
+    """``flash_latent_attention`` against ``latent_expanded_attention`` on
+    what blocks, tiles and the page table can get wrong: ragged lengths, a
+    history that ends inside or on the edge of a page and of a key block,
+    padded queries, idle rows (zeros, and nothing of them read), rows of
+    different history, and NaN in every cached row nobody wrote."""
+    from llms_on_kubernetes_tpu.ops import pallas_flash
+    from llms_on_kubernetes_tpu.ops.attention import latent_expanded_attention
+
+    for name, n in FLASH_BLOCKS.items():
+        monkeypatch.setattr(pallas_flash, name, n)
+    qn, qr, rows, poisoned, w_uk, w_uv, history, kv_len = flash_case(rng, case)
+    T = qn.shape[1]
+    q_pos = history[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    ref = latent_expanded_attention(qn, qr, rows, w_uk, w_uv, q_pos, kv_len,
+                                    scale=0.2, block=32)
+    # the traced function: the jitted one would keep another test's blocks
+    out = pallas_flash.flash_latent_attention.__wrapped__(
+        qn, qr, poisoned, w_uk, w_uv, history, kv_len, scale=0.2,
+        interpret=True)
+    assert out.shape == (len(kv_len), T, HEADS, VDIM) and out.dtype == qn.dtype
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    tol = 3e-2 if qn.dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    assert (out[np.asarray(kv_len) == 0] == 0).all()
+
+
+def test_latent_prompt_dispatchers_say_what_they_took(rng, monkeypatch):
+    """``dispatch_latent_prefill`` and ``dispatch_latent_chunk`` take the
+    flash kernel where they can and otherwise the XLA loop, recording the
+    reason: the CPU backend, a mesh (latent rows have one head), widths or
+    a bucket off Mosaic's tiling, a bucket whose running state is over the
+    VMEM budget; the cell's three shapes take the kernel."""
+    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.parallel.mesh import (
+        make_mesh, set_active_mesh,
+    )
+
+    qn, qr, rows, _, w_uk, w_uv, _, kv_len = flash_case(
+        rng, "a bucket with ragged lengths")
+    _, pool, _, table, lengths = latent_case(rng, "a length of 1")
+    cq = jnp.asarray(rng.normal(size=(3, 16, HEADS, NOPE + ROPE)), jnp.float32)
+    cw = jnp.asarray(rng.normal(size=(2, HEADS, LAT, NOPE)), jnp.float32)
+
+    def took():
+        attention._chosen.clear()
+        out = (attention.dispatch_latent_prefill(qn, qr, rows, w_uk, w_uv,
+                                                 kv_len, scale=0.2),
+               attention.dispatch_latent_chunk(
+                   cq[..., :NOPE], cq[..., NOPE:], pool, table, *cw,
+                   jnp.zeros_like(lengths), lengths, scale=0.2))
+        return out, attention._chosen["prefill"], attention._chosen["chunk"]
+
+    monkeypatch.delenv("LLMK_ATTENTION_IMPL", raising=False)
+    ref, prefill, chunk = took()
+    assert prefill == ("xla", "the bucket's own latent rows, expanded to 4 "
+                       "heads a block of up to 256 keys at a time as far as "
+                       "the row's last written block, bucket 64; cpu backend")
+    assert chunk[0] == "xla" and chunk[1].startswith(
+        "cached latent rows gathered through the page table, expanded to 4 ")
+    assert chunk[1].endswith("bucket 16; cpu backend")
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    out, prefill, chunk = took()
+    assert prefill == (
+        "pallas-interpret", "latent flash kernel: the bucket's own latent "
+        "rows, a block of 64 keys expanded to 4 of 4 heads a program in "
+        "VMEM, q.k 24 wide, query tiles of 64, bucket 64")
+    assert chunk == (
+        "pallas-interpret", "latent flash kernel: cached latent rows "
+        "gathered through the page table, a block of 128 keys expanded to "
+        "4 of 4 heads a program in VMEM, q.k 24 wide, query tiles of 16, "
+        "bucket 16")
+    for got, want in zip(out, ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    set_active_mesh(make_mesh(model=2, devices=jax.devices()[:2]))
+    try:
+        _, prefill, chunk = took()
+    finally:
+        set_active_mesh(None)
+    for impl, why in (prefill, chunk):
+        assert impl == "xla" and why.endswith(
+            "; a mesh of model 2 x seq 1: a latent pool has one head, the "
+            "kernel is not partitioned")
+    # on the chip: Mosaic's tiling and the VMEM a program's state takes
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+
+    def mode(T, S, lat=512, nope=128, vd=128, heads=128):
+        sds = jax.ShapeDtypeStruct
+        return attention._latent_flash_mode(
+            sds((1, T, heads, nope), jnp.bfloat16),
+            sds((1, T, heads, 64), jnp.bfloat16), S,
+            sds((heads, lat, nope), jnp.bfloat16),
+            sds((heads, lat, vd), jnp.bfloat16))
+
+    for T, S in ((512, 512), (2048, 2048), (2048, 9216)):   # the cell's
+        assert mode(T, S) == ("compiled", "")
+    assert mode(512, 512, nope=192) == (
+        None, "an un-roped key of 192 is not a multiple of 128")
+    assert mode(512, 512, lat=576) == (
+        None, "a latent of 576 is not a multiple of 128")
+    assert mode(512, 9216 + 64) == (
+        None, "a key block of 9280 rows of 64 is not a multiple of 128")
+    assert mode(8, 512) == (
+        None, "a query tile of bucket 8 is 8 rows, not 16s")
+    over, why = mode(16384, 16384)
+    assert over is None and why.startswith("bucket 16384 needs ")
+    assert why.endswith(" MiB VMEM > 96 MiB budget")
 
 
 # ---------------------------------------------------------------------------

@@ -588,24 +588,27 @@ def test_grouped_kernel_is_given_the_vmem_the_dispatcher_counted(monkeypatch):
 # pages of 64 tokens, 4609 pages; step -> (rows, tokens a row)
 DEEPSEEK = "deepseek-v3@0,3-7+experts0-15+vocab0-16159"
 DEEPSEEK_STEPS = {"decode, K = 4": (32, 1), "prefill 1 x 2048": (1, 2048),
-                  "chunk 1 x 2048": (1, 2048)}
+                  "prefill 4 x 512": (4, 512), "chunk 1 x 2048": (1, 2048)}
 V5E_BYTES = 16.9e9
 
 
 @pytest.mark.parametrize("step", sorted(DEEPSEEK_STEPS))
 def test_deepseek_v3_steps_fit_a_v5e_and_leave_the_latent_pool_in_place(
         one_chip, no_cache, monkeypatch, step):
-    """The fused decode window, a 2,048-token bucket and a 2,048-token
-    chunk over a 9,216-token window, at the published widths with the
-    cell's weights (11.0 GB) and latent pool (2.27 GB): each compiles for a
-    v5e with at least a gigabyte to spare, the pool goes in and comes out
-    in one buffer, and no step copies it (a 576-wide row, left unpadded,
-    was re-laid out around every write: 386 pool-sized copies a window)."""
+    """The fused decode window, both buckets and a 2,048-token chunk over
+    a 9,216-token window, at the published widths with the cell's weights
+    (11.0 GB) and latent pool (2.27 GB): each compiles for a v5e with at
+    least a gigabyte to spare, the pool goes in and comes out in one
+    buffer, and no step copies it (a 576-wide row, left unpadded, was
+    re-laid out around every write: 386 pool-sized copies a window). The
+    prompts' steps take the latent flash kernel (PR 47), six custom calls
+    beside the experts', and no tile of scores is left in the program
+    around them (the XLA loop's were f32[128,256,256], 33.5 MB each)."""
     from llms_on_kubernetes_tpu.configs import get_config
     from llms_on_kubernetes_tpu.engine import engine as E
     from llms_on_kubernetes_tpu.engine.cache import KVPool
     from llms_on_kubernetes_tpu.models import decoder
-    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.ops import attention, pallas_flash
 
     monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
     monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
@@ -652,10 +655,73 @@ def test_deepseek_v3_steps_fit_a_v5e_and_leave_the_latent_pool_in_place(
     assert peak < V5E_BYTES - 1e9, (step, peak)
     assert not _pool_shaped_copies(compiled.as_text(), pool)
     kind = step.split()[0].rstrip(",")
-    # the token step rides the latent kernel; the prompts' paths have none
-    assert attention._chosen[kind][0] == (
-        "pallas-compiled" if kind == "decode" else "xla")
+    # the token step rides the latent decode kernel, the prompts' steps
+    # the latent flash kernel
+    assert attention._chosen[kind][0] == "pallas-compiled"
     assert attention._chosen["experts"][0] == "pallas-compiled"
+    if kind != "decode":
+        import re
+
+        assert attention._chosen[kind][1].startswith("latent flash kernel")
+        hlo = compiled.as_text()
+        assert hlo.count("flash_latent_attention") >= cfg.num_attn_layers
+        qb, kb, _ = pallas_flash.latent_flash_blocks(
+            tokens, tokens if kind == "prefill" else pps * page,
+            cfg.num_heads)
+        # all heads' scores of a tile pair, at the XLA loop's blocks or
+        # the kernel's
+        tiles = re.findall(
+            rf"f32\[{cfg.num_heads},(?:256,256|{qb},{kb})\]", hlo)
+        assert not tiles, sorted(set(tiles))
+
+
+# bucket, rows attended: both buckets over their own rows, a chunk over the
+# slot's 144 pages of 64
+DEEPSEEK_FLASH = {"bucket 512": (512, 512), "bucket 2048": (2048, 2048),
+                  "chunk 2048 over a 144-page table": (2048, 9216)}
+
+
+@pytest.mark.parametrize("case", sorted(DEEPSEEK_FLASH))
+def test_latent_flash_kernel_is_given_the_vmem_the_dispatcher_counted(
+        one_chip, no_cache, monkeypatch, case):
+    """The prompts' attention alone at the cell's widths (128 heads, a
+    latent of 512, keys of 128 + 64): the dispatcher takes the kernel,
+    Mosaic takes it, it is given exactly the VMEM the dispatcher counted
+    against its budget, and a chunk's only temporary beside the padded
+    rotated queries is the slot's gathered rows (11.8 MB): no pool."""
+    from llms_on_kubernetes_tpu.ops import attention, pallas_flash
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    T, S = DEEPSEEK_FLASH[case]
+    H, lat, rope, nope, vd, page, pages = 128, 512, 64, 128, 128, 64, 4609
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = (sds((1, T, H, nope)), sds((1, T, H, rope)))
+    w = (sds((H, lat, nope)), sds((H, lat, vd)))
+    n = sds((1,), jnp.int32)
+    if case.startswith("bucket"):
+        fn = lambda *a: attention.dispatch_latent_prefill(*a, scale=0.1)
+        args = (*q, sds((1, T, lat + rope)), *w, n)
+    else:
+        fn = lambda *a: attention.dispatch_latent_chunk(*a, scale=0.1)
+        args = (*q, sds((1, 6 * pages, page, 640)),
+                sds((1, S // page), jnp.int32), *w, n, n)
+    eqn = _pallas_call(jax.make_jaxpr(fn)(*args).jaxpr)
+    counted = pallas_flash.latent_flash_vmem_bytes(T, S, H, lat, 128, nope,
+                                                   vd, 2)
+    limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert limit == counted <= attention.VMEM_BUDGET_BYTES
+    compiled = jax.jit(fn).lower(*args).compile()
+    kind = "prefill" if case.startswith("bucket") else "chunk"
+    assert attention._chosen[kind][0] == "pallas-compiled"
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    padded_qr = T * H * 128 * 2
+    gathered = 0 if kind == "prefill" else S * 640 * 2
+    # (a bucket's rows padded to 640 lanes are under a megabyte)
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= padded_qr + gathered + (2 << 20))
 
 
 def test_jamba_decode_window_keeps_the_mamba_state_in_place(

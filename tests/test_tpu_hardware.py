@@ -1097,6 +1097,99 @@ def test_deepseek_cell_latent_kernel_beside_the_xla_loop(monkeypatch):
     np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -7)
 
 
+# name -> (bucket, rows attended, history, calls chained in one executable):
+# the cell's two buckets over their own rows, and a 2,048-token chunk over
+# the slot's gathered 144 pages behind 2,048 and 6,144 tokens of history
+FLASH_SHAPES = {"bucket 512": (512, 512, 0, 16),
+                "bucket 2048": (2048, 2048, 0, 8),
+                "chunk 2048 over 2048": (2048, 9216, 2048, 6),
+                "chunk 2048 over 6144": (2048, 9216, 6144, 4)}
+
+
+def test_deepseek_cell_latent_flash_kernel_beside_the_xla_loop():
+    """The prompts' attention at the cell's shapes, alone (128 heads, a
+    latent of 512, one layer): the latent flash kernel and the XLA loop it
+    replaces (``latent_expanded_attention``: every [128, 256, 256] score
+    tile through HBM), calls chained through the queries inside one
+    executable; and what the two answer on the same rows."""
+    import time
+
+    from llms_on_kubernetes_tpu.ops import attention, pallas_flash
+
+    g = LATENT
+    H, lat, rope = g["heads"], g["lat"], g["rope"]
+    scale = 192 ** -0.5 * 1.36889 ** 2
+
+    def xla_loop(qn, qr, rows, w_uk, w_uv, history, kv_len):
+        q_pos = history[:, None] + jnp.arange(qn.shape[1],
+                                              dtype=jnp.int32)[None]
+        return attention.latent_expanded_attention(
+            qn, qr, rows, w_uk, w_uv, q_pos, kv_len, scale=scale)
+
+    def kernel(*args):
+        return pallas_flash.flash_latent_attention.__wrapped__(
+            *args, scale=scale)
+
+    def time_ms(fn, args, n_calls):
+        @jax.jit
+        def chain(qn, *rest):
+            return jax.lax.fori_loop(0, n_calls, lambda i, q: fn(q, *rest),
+                                     qn)
+
+        best = float("inf")
+        for _ in range(4):                      # the first run compiles
+            t0 = time.perf_counter()
+            jax.block_until_ready(chain(*args))
+            best = min(best, time.perf_counter() - t0)
+        return best / n_calls * 1e3
+
+    said = {"blocks": dict(zip(("queries", "keys", "heads"),
+                               pallas_flash.latent_flash_blocks(2048, 9216,
+                                                                H)))}
+    for name, (T, S, history, n_calls) in FLASH_SHAPES.items():
+        k = jax.random.split(jax.random.key(47), 5)
+        rows = jax.random.normal(k[2], (1, S, g["width"]), jnp.bfloat16)
+        rows = rows.at[..., lat + rope:].set(0)
+        args = (jax.random.normal(k[0], (1, T, H, 128), jnp.bfloat16),
+                jax.random.normal(k[1], (1, T, H, rope), jnp.bfloat16), rows,
+                jax.random.normal(k[3], (H, lat, 128), jnp.bfloat16)
+                * lat ** -0.5,
+                jax.random.normal(k[4], (H, lat, 128), jnp.bfloat16)
+                * lat ** -0.5,
+                jnp.asarray([history], jnp.int32),
+                jnp.asarray([history + T], jnp.int32))
+        # score and value products a head: the tiles the causal mask and
+        # kv_len leave, at the XLA loop's 256 x 256
+        pairs = sum(1 for i in range((history + T) // 256)
+                    for j in range(T // 256)
+                    if i * 256 <= history + j * 256 + 255)
+        flop = pairs * H * 256 * 256 * 2 * (128 + rope + 128)
+        want, got = _f32(xla_loop(*args)), _f32(kernel(*args))
+        # both against the same rows in float32 at the highest precision:
+        # the kernel may not be further from it than the loop it replaces
+        with jax.default_matmul_precision("highest"):
+            exact = _f32(xla_loop(*(a.astype(jnp.float32) if a.dtype
+                                    == jnp.bfloat16 else a for a in args)))
+        said[name] = {
+            "xla_loop_off_float32": float(np.abs(want - exact).max()),
+            "kernel_off_float32": float(np.abs(got - exact).max()),
+            "xla_loop_ms": round(time_ms(xla_loop, args, n_calls), 3),
+            "kernel_ms": round(time_ms(kernel, args, 2 * n_calls), 3),
+            "tile_pairs": pairs, "tiles_GFLOP": round(flop / 1e9, 1),
+            "max_abs_diff": float(np.abs(got - want).max()),
+            "max_abs_value": float(np.abs(want).max())}
+        said[name]["kernel_share_of_197_TFLOP_s"] = round(
+            flop / said[name]["kernel_ms"] / 197e9, 3)
+        # both round p to bfloat16 on its way into the MXU, after a running
+        # maximum over blocks of keys
+        np.testing.assert_allclose(got, want, rtol=2 ** -6, atol=2 ** -6)
+    _report("pr47_latent_flash", said)
+    for name in FLASH_SHAPES:
+        assert said[name]["kernel_ms"] < said[name]["xla_loop_ms"], said
+        assert (said[name]["kernel_off_float32"]
+                <= 1.5 * said[name]["xla_loop_off_float32"]), said
+
+
 # ---------------------------------------------------------------------------
 # deepseek-v3's cell: the served functions, teacher-forced, against the
 # float32 reference's full forward pass
@@ -1199,7 +1292,10 @@ def test_deepseek_cell_teacher_forced_prefill_chunk_and_decode():
         for s in seqs:
             got[s].append(np.asarray(logits[s]))
     said = {op: attention._chosen[op] for op in ("prefill", "chunk", "decode")}
-    assert said["prefill"][0] == said["chunk"][0] == "xla"
+    # the prompts' paths ran the latent flash kernel (PR 47)
+    assert said["prefill"][0] == said["chunk"][0] == "pallas-compiled"
+    assert said["prefill"][1].startswith("latent flash kernel")
+    assert said["chunk"][1].startswith("latent flash kernel")
     # the absorbed steps ran the latent kernel (PR 43)
     assert said["decode"][0] == "pallas-compiled"
     assert said["decode"][1].startswith("latent")
