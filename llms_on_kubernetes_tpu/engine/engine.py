@@ -1501,6 +1501,15 @@ class Engine:
         # decoder.MambaState); None for a model without such layers
         self.conv_state = init_conv_state(
             cfg, engine_config.max_decode_slots, engine_config.dtype)
+        # a Mamba model's token step visits the rows live at the launch
+        # where its state-space step is the kernel, every slot where it is
+        # the XLA step: what llm_ssm_positions_total{path="decode"} books
+        self._ssm_live_only = False
+        if cfg.num_mamba_layers:
+            from llms_on_kubernetes_tpu.ops.attention import ssm_step_mode
+
+            self._ssm_live_only = ssm_step_mode(
+                self.conv_state.ssm)[0] is not None
         if (engine_config.kv_cache_dtype == "int8"
                 and engine_config.page_size % 128 != 0
                 and jax.default_backend() == "tpu"):
@@ -1580,9 +1589,10 @@ class Engine:
         # llm_mla_tokens_total{path} says of a latent model
         self.path_tokens = {"prefill": 0, "chunk": 0}
         # positions the Mamba layers' scan (prefill, chunk) or step
-        # (decode) ran over, padding and idle rows included, as _dispatch
-        # is told them: llm_ssm_positions_total{path}. path_tokens and
-        # decode_tokens are the real ones among them
+        # (decode) ran over, padding included, and idle rows where the
+        # step visits them (_ssm_rows), as _dispatch is told them:
+        # llm_ssm_positions_total{path}. path_tokens and decode_tokens are
+        # the real ones among them
         self.ssm_positions = {"prefill": 0, "chunk": 0, "decode": 0}
         # fused multi-step decode accounting (metrics + bench):
         self.decode_dispatches = 0   # decode device dispatches
@@ -2298,6 +2308,11 @@ class Engine:
                 events.append(self._finish(r, r.abort_reason))
         return events
 
+    def _ssm_rows(self, live: int) -> int:
+        """Rows a token step's state-space update visits, of which ``live``
+        are live at the launch."""
+        return live if self._ssm_live_only else len(self.slots)
+
     @contextlib.contextmanager
     def _dispatch(self, kind: str, name: str, shape: str,
                   rows: Optional[list] = None, positions: int = 0):
@@ -2307,7 +2322,8 @@ class Engine:
         work is enqueued) with the host time the call took and whether the
         process re-traced meanwhile. Yields the dispatch's seq.
         ``positions``: how many positions every layer runs over (rows x
-        bucket, or K x slots), booked for a model with Mamba layers."""
+        bucket, or K x the rows a token step visits: ``_ssm_rows``),
+        booked for a model with Mamba layers."""
         seq = next(self._dispatch_seq)
         # without the ledger nothing is attributed to a request (rows) and
         # nothing counts the process's compiles
@@ -3358,7 +3374,7 @@ class Engine:
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
         with self._dispatch("decode", "_decode_multi_packed_step",
                             f"1x{len(active)}",
-                            positions=len(self.slots)) as dseq:
+                            positions=self._ssm_rows(len(active))) as dseq:
             (pack, self._unread_toks, self.k_pages, self.v_pages,
              self.token_counts, new_state,
              self.conv_state) = self._decode_multi(
@@ -3687,7 +3703,9 @@ class Engine:
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
         with self._dispatch("decode", "_decode_multi_packed_step",
                             f"{K}x{len(active)}",
-                            positions=K * len(self.slots)) as dseq:
+                            positions=K * self._ssm_rows(sum(
+                                plan.get(i, 0) > 0 for i, _r in active))
+                            ) as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
              new_state, self.conv_state) = self._decode_multi(
                 self.params, self.model_config, K, jnp.asarray(packed),

@@ -238,9 +238,10 @@ class Dispatch:
     tokens: int = 0
     flops: float = 0.0
     hbm_bytes: float = 0.0
-    # positions the Mamba layers' scan or step ran over, padding and idle
-    # rows included (0: the model has no such layer; Engine._dispatch
-    # sets it); ``tokens`` is the real ones among them
+    # positions the Mamba layers' scan or step ran over, padding included,
+    # and idle rows where the step visits them (0: the model has no such
+    # layer; Engine._dispatch sets it); ``tokens`` is the real ones among
+    # them
     ssm_positions: int = 0
 
     def to_dict(self, t0: float) -> dict:

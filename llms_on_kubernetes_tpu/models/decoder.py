@@ -38,7 +38,8 @@ from llms_on_kubernetes_tpu.configs import ModelConfig
 from llms_on_kubernetes_tpu.ops.cp import dispatch_write_tokens as write_tokens
 from llms_on_kubernetes_tpu.ops.attention import (
     dispatch_chunk_attention, dispatch_paged_attention,
-    dispatch_prefill_attention, record_choice, softcap,
+    dispatch_prefill_attention, dispatch_ssm_step, live_first, record_choice,
+    softcap,
 )
 from llms_on_kubernetes_tpu.ops.lora import lora_qeinsum
 from llms_on_kubernetes_tpu.ops.moe import moe_block
@@ -488,12 +489,16 @@ def _ssm_scan(delta, A, x, Bm, Cm, h0):
 
 
 def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
-           state: "MambaState", n_valid: jnp.ndarray):
+           state: "MambaState", n_valid: jnp.ndarray, ssm_at=None):
     """Jamba's Mamba-1 mixer. u [B, T, D] (normed); ``state``, the rows'
     own: ``conv`` [B, (taps-1) Di] the last taps - 1 convolution inputs
     before u's first, ``ssm`` [B, N, Di] the state-space state h, zeros for
     a fresh sequence; ``n_valid`` [B]: how many of the T positions are
-    real, from the left.
+    real, from the left. In a token step (``ssm_at`` = the layer's index
+    among the Mamba layers and ``live_first`` of the rows) ``ssm`` is the
+    WHOLE array [n_mamba_layers, slots + 1, N, Di], row i is slot i, and
+    the step updates the live slots' blocks where they lie
+    (``dispatch_ssm_step``).
 
       [x, z] = split2(u W_in)
       x_t <- silu(b_c + sum_j w_c[j] * x_(t - (taps-1) + j))   (depthwise)
@@ -503,10 +508,11 @@ def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
       out = (y * silu(z)) W_out
 
     Returns (out [B, T, D], the rows' state after their last real
-    position). Padding lies to the right of every real position and is
-    given delta = 0: exp(0 A) = 1 and 0 x B = 0, so h passes through a
-    padded step exactly as it was (the other way, a select of old against
-    new h a step, would read h twice)."""
+    position; in a token step ``ssm`` is the whole array again). Padding
+    lies to the right of every real position and is given delta = 0:
+    exp(0 A) = 1 and 0 x B = 0, so h passes through a padded step exactly
+    as it was (the other way, a select of old against new h a step, would
+    read h twice)."""
     taps, N, R = cfg.mamba_d_conv, cfg.mamba_d_state, cfg.mamba_dt_rank
     B, T, _ = u.shape
     Di, eps, f32 = cfg.mamba_d_inner, cfg.rms_norm_eps, jnp.float32
@@ -543,8 +549,13 @@ def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
         real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_valid[:, None]
         delta = jnp.where(real[:, :, None], delta, 0.0)
         xf = xc.astype(f32)
-        y, h = _ssm_scan(delta, -jnp.exp(lp["A_log"].astype(f32)), xf, Bm, Cm,
-                         h.astype(f32))
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        if ssm_at is None:
+            y, h = _ssm_scan(delta, A, xf, Bm, Cm, h.astype(f32))
+        else:
+            layer, live_slots = ssm_at
+            y, h = dispatch_ssm_step(delta, A, xf, Bm, Cm, h, layer,
+                                     n_valid > 0, live_slots)
         y = (y + lp["D"].astype(f32) * xf) * jax.nn.silu(z.astype(f32))
         out = qeinsum("bte,ed->btd", y.astype(u.dtype), lp["out_proj"])
     return out, MambaState(conv=window, ssm=h)
@@ -574,6 +585,7 @@ def _layer_step(
                        # a MambaState of rows (Mamba layer)
     n_valid: "jnp.ndarray | None" = None,      # [B] real positions of T
     experts=None,                              # _mlp's, for an expert layer
+    ssm_at=None,       # _mamba's, in a token step: conv_state.ssm is whole
 ):
     """One layer of ``kind`` (operator, feed-forward). Returns (x, k_pages,
     v_pages, a conv or Mamba layer's new state rows or None, the rows each
@@ -583,7 +595,7 @@ def _layer_step(
     if op == "conv":
         out, conv_state = _short_conv(lp, cfg, h, conv_state, n_valid)
     elif op == "mamba":
-        out, conv_state = _mamba(lp, cfg, h, conv_state, n_valid)
+        out, conv_state = _mamba(lp, cfg, h, conv_state, n_valid, ssm_at)
     elif op == "mla":
         out, k_pages = _latent_attention(
             cfg, inv_freq, page_table, positions, write_positions, lengths,
@@ -797,6 +809,10 @@ def _run_layers(
                     (B, (cfg.mamba_d_conv - 1) * cfg.mamba_d_inner), x.dtype),
                 ssm=jnp.zeros((B, cfg.mamba_d_state, cfg.mamba_d_inner),
                               jnp.float32))
+            # a token step walks the live slots of the whole state-space
+            # array, the same ones in every layer
+            live_slots = live_first(lengths > 0) if mode == "decode" \
+                else None
         else:
             fresh = jnp.zeros((B, cfg.conv_L_cache - 1, x.shape[-1]),
                               x.dtype)
@@ -853,7 +869,14 @@ def _run_layers(
             else:
                 pt = page_table + a_idx * pages_per_layer
             keeps = op in ("conv", "mamba")
-            old = conv_rows(cv, c_idx) if keeps else None
+            # a Mamba layer's token step takes the state-space array whole
+            # and hands it back (dispatch_ssm_step); the convolution's
+            # window goes by rows, as a conv layer's state does
+            whole = op == "mamba" and mode == "decode"
+            if whole:
+                old = MambaState(conv=conv_rows(cv.conv, c_idx), ssm=cv.ssm)
+            else:
+                old = conv_rows(cv, c_idx) if keeps else None
             xc, kp, vp, new, rows = _layer_step(
                 cfg, inv_freq, pt, positions, write_positions, lengths, mode,
                 xc, lp, kp, vp, layer_idx=idx, inv_freq_local=inv_freq_local,
@@ -862,8 +885,13 @@ def _run_layers(
                 adapter_idx=adapter_idx, kind=kind, conv_state=old,
                 n_valid=n_valid if keeps else None,
                 experts=(stacks, i) if stacks else None,
+                ssm_at=(c_idx, live_slots) if whole else None,
             )
-            if keeps:
+            if whole:
+                cv = MambaState(
+                    conv=conv_write(cv.conv, c_idx, old.conv, new.conv),
+                    ssm=new.ssm)
+            elif keeps:
                 cv = conv_write(cv, c_idx, old, new)
             if deepstack is not None:
                 # DeepStack (Qwen3-VL): intermediate vision features are ADDED

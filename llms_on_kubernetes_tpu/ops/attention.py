@@ -895,3 +895,92 @@ def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,
         kd.shape[0],
         (q, kd, getattr(v_pages, "data", v_pages), page_table, lengths),
         (1, 0, 0, None, None), 1)
+
+
+# ---------------------------------------------------------------------------
+# The Mamba layers' token step (no attention: it chooses as the others do)
+# ---------------------------------------------------------------------------
+
+def live_first(live: jnp.ndarray):
+    """(slots [B] int32, n [1] int32): the numbers of the live rows first,
+    in slot order, then the idle ones; and how many are live. What the
+    state-space step kernel walks, made once a step for every layer."""
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    return order, jnp.sum(live, dtype=jnp.int32).reshape(1)
+
+
+def ssm_step_mode(ssm):
+    """(mode, why not) for the state-space step kernel
+    (pallas_ssm.pallas_ssm_step) on the state array ``ssm`` [n_layers,
+    slots + 1, N, Di], as ``_latent_kernel_mode`` is for the latent pool's:
+    from the array's type and widths, the active mesh and the VMEM a
+    program needs. The engine asks too: where the kernel runs, a token step
+    visits the live rows alone."""
+    from llms_on_kubernetes_tpu.ops.pallas_ssm import (
+        LANES, ssm_step_vmem_bytes,
+    )
+    from llms_on_kubernetes_tpu.parallel.mesh import get_active_mesh
+
+    mode = pallas_mode()
+    if mode is None:
+        return None, _no_pallas_why()
+    mesh = get_active_mesh()
+    if mesh is not None and mesh.size > 1:
+        return None, (f"a mesh of {mesh.size} devices: the kernel walks one "
+                      "chip's state and is not partitioned")
+    N, Di = ssm.shape[2:]
+    if ssm.dtype != jnp.float32:
+        return None, f"the state is kept in {ssm.dtype}, the kernel's is float32"
+    if mode == "compiled":
+        # Mosaic's tiling: a slot's block is whole (8, 128) float32 tiles,
+        # worked through LANES lanes at a time
+        if Di % LANES != 0:
+            return None, f"{Di} channels are not a multiple of {LANES}"
+        if N % 8 != 0:
+            return None, f"{N} states are not a multiple of 8"
+    need = ssm_step_vmem_bytes(ssm.shape[1] - 1, N, Di)
+    if need > VMEM_BUDGET_BYTES:
+        return None, (f"a slot's [{N}, {Di}] block needs {_mib(need)} VMEM > "
+                      f"{_mib(VMEM_BUDGET_BYTES)} budget")
+    return mode, ""
+
+
+def dispatch_ssm_step(delta, A, x, Bm, Cm, ssm, layer, live, first):
+    """One token's state-space update of one Mamba layer on the WHOLE state
+    array: the kernel over the live slots (pallas_ssm.pallas_ssm_step: each
+    live slot's block through VMEM once, in place, ``y`` summed from the
+    block in hand; an idle slot and the trash row neither read nor written)
+    wherever ``ssm_step_mode`` lets it, else the XLA step
+    (decoder._ssm_scan's one-step branch on all B rows, old selected
+    against new for the idle ones, all B written back), with its reason.
+
+    delta, x [B, 1, Di] float32; A [N, Di]; Bm, Cm [B, 1, N]; ssm
+    [n_layers, slots + 1, N, Di] (row i is slot i); ``layer`` its index;
+    live [B] bool; ``first`` = ``live_first(live)``. Returns (y [B, 1, Di]
+    float32, of an idle row zeros or a step nobody keeps; ssm)."""
+    B, _, Di = delta.shape
+    N = A.shape[0]
+    mode, why = ssm_step_mode(ssm)
+    if mode is None:
+        from llms_on_kubernetes_tpu.models.decoder import _ssm_scan
+
+        record_choice("ssm_step", "xla",
+                      f"every slot's [{N}, {Di}] block read, {B} rows "
+                      f"computed, old selected against new and written "
+                      f"back; {why}")
+        old = jax.lax.dynamic_index_in_dim(ssm, layer, 0, False)[:B]
+        y, h = _ssm_scan(delta, A, x, Bm, Cm, old.astype(jnp.float32))
+        rows = jnp.where(live[:, None, None], h, old)
+        return y, jax.lax.dynamic_update_slice(
+            ssm, rows.astype(ssm.dtype)[None], (layer, 0, 0, 0))
+
+    from llms_on_kubernetes_tpu.ops.pallas_ssm import pallas_ssm_step
+
+    record_choice("ssm_step", f"pallas-{mode}",
+                  f"live slots only: a slot's [{N}, {Di}] float32 block "
+                  f"through VMEM once, updated and summed in place, of {B} "
+                  "rows")
+    y, ssm = pallas_ssm_step(delta[:, 0], A, x[:, 0], Bm[:, 0], Cm[:, 0], ssm,
+                             layer, *first, interpret=mode == "interpret")
+    # an idle row's y is whatever its buffer held
+    return jnp.where(live[:, None], y, 0.0)[:, None], ssm

@@ -612,9 +612,11 @@ def engine_metrics(registry: Registry) -> dict:
         "ssm_positions": Counter(
             "llm_ssm_positions_total",
             "Positions the Mamba layers' scan or step ran over by each "
-            "path, a bucket's padding and a decode window's idle rows "
-            "included; over llm_path_tokens_total it is the work done per "
-            "real token. 0 for a model without Mamba layers",
+            "path, a bucket's padding included, and a decode window's idle "
+            "rows where the state-space step is the XLA step over every "
+            "slot (the kernel visits the rows live at the launch alone); "
+            "over llm_path_tokens_total it is the work done per real "
+            "token. 0 for a model without Mamba layers",
             registry, label_names=("path",)),
         "conv_state_bytes": Gauge(
             "llm_conv_state_bytes",

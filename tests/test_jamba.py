@@ -267,6 +267,112 @@ def test_the_mixer_against_an_explicit_loop(n_valid):
 
 
 # ---------------------------------------------------------------------------
+# the token step's state-space kernel (ops/pallas_ssm.py, interpreted) against
+# ``_ssm_scan``'s one-step branch, and what ``dispatch_ssm_step`` chooses
+# ---------------------------------------------------------------------------
+
+def step_case(layers=3, slots=6, N=16, Di=256, dtype=jnp.float32, seed=0):
+    """A whole state array and one step's operands, as ``_mamba`` hands
+    them to ``dispatch_ssm_step``."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    return dict(
+        ssm=jax.random.normal(ks[0], (layers, slots + 1, N, Di)).astype(dtype),
+        delta=jax.nn.softplus(jax.random.normal(ks[1], (slots, 1, Di)) - 2.0),
+        x=jax.random.normal(ks[2], (slots, 1, Di)),
+        Bm=jax.random.normal(ks[3], (slots, 1, N)),
+        Cm=jax.random.normal(ks[4], (slots, 1, N)),
+        A=-jnp.exp(jax.random.normal(ks[5], (N, Di))))
+
+
+def dispatched(c, layer, live):
+    from llms_on_kubernetes_tpu.ops import attention
+
+    live = jnp.asarray(live, bool)
+    # a function of its own a call: the choice is made when it is traced
+    return jax.jit(lambda *a: attention.dispatch_ssm_step(*a))(
+        c["delta"], c["A"], c["x"], c["Bm"], c["Cm"], c["ssm"],
+        jnp.int32(layer), live, attention.live_first(live))
+
+
+LIVE = {"none": "000000", "one, first": "100000", "one, last": "000001",
+        "some, first": "111000", "some, last": "000111",
+        "scattered": "010110", "all": "111111"}
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("rows", list(LIVE))
+def test_the_step_kernel_updates_the_live_slots_and_no_other(
+        rows, layer, monkeypatch):
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    live = np.array([ch == "1" for ch in LIVE[rows]])
+    c = step_case()
+    before = np.asarray(c["ssm"])
+    y, after = dispatched(c, layer, live)
+    assert attention._chosen["ssm_step"][0] == "pallas-interpret"
+    y_want, h_want = dec._ssm_scan(c["delta"], c["A"], c["x"], c["Bm"],
+                                   c["Cm"], c["ssm"][layer, :len(live)])
+    y, after = np.asarray(y), np.asarray(after)
+    assert after.dtype == np.float32 and y.dtype == np.float32
+    # float32 rounding: XLA may fuse a product and a sum the kernel keeps
+    # apart (1 ulp of h seen)
+    np.testing.assert_allclose(after[layer, :len(live)][live],
+                               np.asarray(h_want)[live], rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(y[live], np.asarray(y_want)[live],
+                               rtol=1e-5, atol=1e-5)
+    assert not y[~live].any()
+    # every idle row, every other layer and the trash row: bit for bit
+    same = np.ones(before.shape[:2], bool)
+    same[layer, :len(live)] = ~live
+    assert same[:, -1].all() and same.sum() == same.size - live.sum()
+    np.testing.assert_array_equal(after[same], before[same])
+
+
+@pytest.mark.parametrize("why,word", [
+    ("a mesh", "a mesh of 2 devices"),
+    ("channels off the lanes", "192 channels are not a multiple of 512"),
+    ("channels off the chunks", "384 channels are not a multiple of 512"),
+    ("a bfloat16 state", "kept in bfloat16"),
+    ("the cpu", "cpu backend"),
+])
+def test_where_the_kernel_cannot_run_the_xla_step_does_and_says_why(
+        why, word, monkeypatch):
+    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.parallel import mesh as pmesh
+
+    kw = {}
+    if why == "a mesh":
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+        monkeypatch.setattr(pmesh, "_ACTIVE_MESH", jax.sharding.Mesh(
+            np.array(jax.devices()[:2]), (pmesh.AXIS_MODEL,)))
+    elif why.startswith("channels off"):
+        monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+        kw = {"Di": int(word.split()[0])}
+    elif why == "a bfloat16 state":
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+        kw = {"dtype": jnp.bfloat16}
+    c = step_case(**kw)
+    live = np.array([True, False, True, True, False, False])
+    y, after = dispatched(c, 1, live)
+    impl, said = attention._chosen["ssm_step"]
+    assert impl == "xla" and word in said, said
+    y_want, h_want = dec._ssm_scan(
+        c["delta"], c["A"], c["x"], c["Bm"], c["Cm"],
+        c["ssm"][1, :6].astype(jnp.float32))
+    assert after.dtype == c["ssm"].dtype
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_want),
+                               rtol=1e-5, atol=1e-5)
+    before = np.array(c["ssm"].astype(jnp.float32))
+    after = np.asarray(after.astype(jnp.float32))
+    tol = 1e-2 if why == "a bfloat16 state" else 2e-6
+    np.testing.assert_allclose(after[1, :6][live], np.asarray(h_want)[live],
+                               rtol=tol, atol=tol)
+    before[1, :6][live] = after[1, :6][live]
+    np.testing.assert_array_equal(after, before)       # the idle rows
+
+
+# ---------------------------------------------------------------------------
 # the engine: fused K = 4 windows, slot reuse, idle rows, admission while
 # others decode, preemption, the prefix cache. A request's every token is
 # held to the reference's full forward pass of prompt + output: the
@@ -309,8 +415,29 @@ def poison(eng):
     eng.conv_state = jax.tree.map(lambda a: a + 7.0, eng.conv_state)
 
 
+@pytest.fixture(params=["xla", "kernel"])
+def ssm_step(request, monkeypatch):
+    """The token step's state-space update as the XLA step over every slot,
+    and as the kernel over the live ones (interpreted here, with every other
+    Pallas kernel). The steps' traces are shared between engines and the
+    choice is made at trace time: cleared on the way in and out."""
+    if request.param == "kernel":
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    jax.clear_caches()
+    yield request.param
+    jax.clear_caches()
+
+
+def chose(ssm_step):
+    from llms_on_kubernetes_tpu.ops import attention
+
+    return attention._chosen["ssm_step"][0] == (
+        "pallas-interpret" if ssm_step == "kernel" else "xla")
+
+
 @pytest.mark.parametrize("scheduler", ["pipelined", "synchronous"])
-def test_fused_windows_idle_rows_and_a_chunked_prompt(params32, scheduler):
+def test_fused_windows_idle_rows_and_a_chunked_prompt(params32, scheduler,
+                                                      ssm_step):
     eng = engine(params32, async_scheduling=scheduler == "pipelined")
     poison(eng)
     reqs = [submit(eng, prompt(7, 21), 14),       # >= 3 windows of K = 4
@@ -323,14 +450,21 @@ def test_fused_windows_idle_rows_and_a_chunked_prompt(params32, scheduler):
     assert eng.path_tokens == {"prefill": 7, "chunk": 40}
     assert eng.ssm_positions["prefill"] == 16
     assert eng.ssm_positions["chunk"] == 32 + 16
-    assert eng.ssm_positions["decode"] % SLOTS == 0
+    assert chose(ssm_step)
+    # the XLA step runs every slot, the kernel the rows live at the launch
+    # (the whole window for a row that stops inside it)
+    if ssm_step == "xla":
+        assert eng.ssm_positions["decode"] % SLOTS == 0
+        assert eng.ssm_positions["decode"] >= 2 * eng.decode_tokens
+    else:
+        assert eng.ssm_positions["decode"] < eng.decode_tokens + 4 * 4
     assert eng.ssm_positions["decode"] >= eng.decode_tokens > 0
     booked = [d for d in eng.ledger.dispatches_view(64) if "ssm_positions" in d]
     assert {d["kind"] for d in booked} == {"prefill", "chunk", "decode"}
     assert all(d["ssm_tokens"] <= d["ssm_positions"] for d in booked)
 
 
-def test_a_slot_freed_and_taken_again_starts_from_zeros(params32):
+def test_a_slot_freed_and_taken_again_starts_from_zeros(params32, ssm_step):
     eng = engine(params32, max_decode_slots=1, num_pages=PPS + 1)
     first = submit(eng, prompt(30, 31), 9)
     run(eng, [first])
@@ -341,6 +475,75 @@ def test_a_slot_freed_and_taken_again_starts_from_zeros(params32):
     run(eng, [third])
     for r in (first, second, third):
         held_to_reference(params32, r)
+    assert chose(ssm_step)
+
+
+@pytest.mark.parametrize("ends", ["budget", "stop id"])
+def test_rows_that_stop_inside_a_window_under_the_kernel_and_the_xla_step(
+        params32, ends, monkeypatch):
+    """Three streams through K = 4 windows, two of which end INSIDE a window
+    (a budget that runs out at its second step; a stop id sampled there):
+    the device masks the row for the rest of the window and its slot's
+    state stays where its last live step put it. Greedy streams, their
+    log-probabilities and every slot's state are the same with the kernel
+    as with the XLA step."""
+    def streams(impl, stop_at=None):
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", impl)
+        jax.clear_caches()
+        try:
+            eng = engine(params32)
+            poison(eng)
+            stop = {} if stop_at is None else {"stop_token_ids": (stop_at,)}
+            reqs = [submit(eng, prompt(7, 41), 1 + 4 + 2),
+                    submit(eng, prompt(9, 42), 1 + 4 + 4 + 3, **stop),
+                    submit(eng, prompt(5, 43), 1 + 12)]
+            run(eng, reqs)
+        finally:
+            jax.clear_caches()
+        return reqs, np.asarray(eng.conv_state.ssm)
+
+    stop_at = None
+    if ends == "stop id":
+        # the second stream's seventh token, new to that stream at that
+        # step: the second step of its second window
+        plain, _ = streams("xla")
+        out = plain[1].output
+        stop_at = next(t for j, t in enumerate(out)
+                       if j % 4 in (2, 3) and j > 4 and t not in out[:j])
+    want, want_state = streams("xla", stop_at)
+    got, got_state = streams("pallas", stop_at)
+    for w, g in zip(want, got):
+        assert g.output == w.output
+        np.testing.assert_allclose(
+            [e[0] for e in g.output_logprobs],
+            [e[0] for e in w.output_logprobs], atol=1e-5)
+        held_to_reference(params32, g)
+    if stop_at is not None:
+        assert got[1].output[-1] == stop_at and len(got[1].output) < 12
+    # the slot nobody took keeps its poison, bit for bit; the trash row is
+    # the prompts' (padding rows write it), the same either way
+    np.testing.assert_array_equal(got_state[:, 3], want_state[:, 3])
+    np.testing.assert_allclose(got_state, want_state, rtol=1e-5, atol=1e-6)
+
+
+def test_decode_positions_over_decode_tokens_reads_one_with_the_kernel(
+        params32, ssm_step):
+    """``llm_ssm_positions_total{path="decode"}`` over
+    ``llm_path_tokens_total{path="decode"}`` (the engine's two counts that
+    the metrics page shows): one stream of whole windows in four slots
+    reads 1.0 where the kernel visits the live rows, slots / live = 4.0
+    where the XLA step runs every slot; each decode record carries it."""
+    eng = engine(params32)
+    r = submit(eng, prompt(7, 51), 1 + 8)
+    run(eng, [r])
+    assert chose(ssm_step) and eng._ssm_live_only == (ssm_step == "kernel")
+    assert eng.decode_tokens == 8
+    ratio = eng.ssm_positions["decode"] / eng.decode_tokens
+    assert ratio == (1.0 if ssm_step == "kernel" else SLOTS / 1)
+    booked = [d for d in eng.ledger.dispatches_view(64)
+              if d["kind"] == "decode"]
+    assert booked and sum(d["ssm_positions"] for d in booked) == \
+        eng.ssm_positions["decode"]
 
 
 def test_requests_admitted_while_others_decode_read_as_they_do_alone(

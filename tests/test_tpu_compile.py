@@ -730,8 +730,10 @@ def test_jamba_decode_window_keeps_the_mamba_state_in_place(
     GB of weights, a 0.27 GB pool, 1.2 GB of per-slot Mamba state): it
     compiles for a v5e with half the chip to spare, the state goes in and
     comes out in the buffers it came in, no step copies the float32
-    state-space state (one copy a step would be the whole cell), and the
-    paged decode kernel takes one KV head under 20 query heads."""
+    state-space state (one copy a step would be the whole cell), the
+    state-space step is the kernel over the live slots on that array in
+    place (26 of them a step), and the paged decode kernel takes one KV
+    head under 20 query heads."""
     from llms_on_kubernetes_tpu.configs import get_config
     from llms_on_kubernetes_tpu.engine import engine as E
     from llms_on_kubernetes_tpu.engine.cache import KVPool
@@ -780,3 +782,9 @@ def test_jamba_decode_window_keeps_the_mamba_state_in_place(
                 and "f32[26,129,16,5120]" in ln.split("=", 1)[1][:60]]
     assert attention._chosen["decode"] == (
         "pallas-compiled", "fused write+attend kernel")
+    assert attention._chosen["ssm_step"][0] == "pallas-compiled"
+    calls = [ln for ln in hlo.splitlines() if "ssm_step_live_slots" in ln
+             and "custom-call(" in ln]
+    assert len(calls) == cfg.num_mamba_layers
+    assert all("f32[26,129,16,5120]" in ln.split("custom-call(")[0]
+               for ln in calls)

@@ -1097,6 +1097,92 @@ def test_deepseek_cell_latent_kernel_beside_the_xla_loop(monkeypatch):
     np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -7)
 
 
+# the jamba2-3b.long-answers cell's Mamba state and the rows it decodes
+SSM_CELL = dict(layers=26, slots=128, N=16, Di=5120, live=(56, 128))
+
+
+def test_jamba_cell_ssm_step_kernel_beside_the_xla_step(monkeypatch):
+    """The Mamba layers' token step on the state at the
+    ``jamba2-3b.long-answers`` cell's shape (``f32[26,129,16,5120]``, 128
+    rows), alone: all 26 layers' updates in one executable on the donated
+    array, the kernel over the live slots (ops/pallas_ssm.py) and the XLA
+    step it replaces (every row computed, old selected against new, all
+    128 written back), with 56 rows live, as the cell decodes, and with all
+    128; and what the two leave in the state and in ``y``."""
+    import time
+
+    from llms_on_kubernetes_tpu.ops import attention
+
+    L, S, N, Di = (SSM_CELL[k] for k in ("layers", "slots", "N", "Di"))
+    ks = jax.random.split(jax.random.key(48), 6)
+    ops = (jax.nn.softplus(jax.random.normal(ks[1], (L, S, 1, Di)) - 3.0),
+           -jnp.exp(jax.random.normal(ks[5], (L, N, Di))),
+           jax.random.normal(ks[2], (L, S, 1, Di)),
+           jax.random.normal(ks[3], (L, S, 1, N)),
+           jax.random.normal(ks[4], (L, S, 1, N)))
+    rng = np.random.default_rng(48)
+    lives = {}
+    for n_live in SSM_CELL["live"]:
+        lives[n_live] = np.zeros(S, bool)
+        lives[n_live][rng.permutation(S)[:n_live]] = True
+
+    def fresh():
+        return jax.random.normal(ks[0], (L, S + 1, N, Di), jnp.float32)
+
+    def layers(ssm, live, ops):
+        first = attention.live_first(live)
+        ys = []
+        for l in range(L):
+            y, ssm = attention.dispatch_ssm_step(
+                *(a[l] for a in ops), ssm, jnp.int32(l), live, first)
+            ys.append(y)
+        return jnp.stack(ys), ssm
+
+    def run(fn):
+        """{rows live: (y, the state after one step, ms a step)}"""
+        out = {}
+        for n_live, mask in lives.items():
+            live = jnp.asarray(mask)
+            y, ssm = fn(fresh(), live, ops)         # the first call compiles
+            got = np.asarray(y), np.asarray(ssm)
+            t0 = time.perf_counter()
+            for _ in range(20):
+                y, ssm = fn(ssm, live, ops)
+            jax.block_until_ready((y, ssm))
+            out[n_live] = (*got, (time.perf_counter() - t0) / 20 * 1e3)
+        return out
+
+    with monkeypatch.context() as m:    # the choice is made at the trace
+        m.setattr(attention, "ssm_step_mode", lambda *a: (None, "shut"))
+        xla = run(jax.jit(lambda *a: layers(*a), donate_argnums=(0,)))
+        assert attention._chosen["ssm_step"][0] == "xla"
+    kernel = run(jax.jit(lambda *a: layers(*a), donate_argnums=(0,)))
+    assert attention._chosen["ssm_step"][0].startswith("pallas")
+    before, said = np.asarray(fresh()), {}
+    for n_live, mask in lives.items():
+        (y0, h0, ms0), (y1, h1, ms1) = xla[n_live], kernel[n_live]
+        # idle slots and the trash row: what they were, bit for bit
+        np.testing.assert_array_equal(h1[:, :S][:, ~mask],
+                                      before[:, :S][:, ~mask])
+        np.testing.assert_array_equal(h1[:, S], before[:, S])
+        np.testing.assert_allclose(h1, h0, rtol=1e-5, atol=1e-5)
+        # (an idle row's y: zeros from the kernel, a step nobody keeps from
+        # the XLA step)
+        np.testing.assert_allclose(y1[:, mask], y0[:, mask],
+                                   rtol=1e-4, atol=1e-4)
+        assert not y1[:, ~mask].any()
+        said[f"{n_live} live"] = {
+            "xla_step_ms": round(ms0, 3), "kernel_step_ms": round(ms1, 3),
+            "kernel_live_rows_GB_s": round(
+                n_live * L * 2 * N * Di * 4 / ms1 / 1e6, 1),
+            "max_abs_diff_h": float(np.abs(h1 - h0).max()),
+            "max_abs_diff_y": float(np.abs(y1 - y0)[:, mask].max())}
+    _report("pr48_ssm_step", said)
+    full, part = (said[f"{n} live"] for n in (S, SSM_CELL["live"][0]))
+    assert full["kernel_step_ms"] <= full["xla_step_ms"] * 1.02
+    assert part["kernel_step_ms"] * 1.8 <= part["xla_step_ms"]
+
+
 # name -> (bucket, rows attended, history, calls chained in one executable):
 # the cell's two buckets over their own rows, and a 2,048-token chunk over
 # the slot's gathered 144 pages behind 2,048 and 6,144 tokens of history
