@@ -223,111 +223,6 @@ def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 _MAX_RMW_PAGES = 33
 
 
-# Decode (T==1) write strategy. Part of the engine's STATIC config
-# (EngineConfig.kv_write, env LLMK_KV_WRITE as the default): the value is
-# baked into each engine's traced executables, so it is set via
-# set_kv_write_strategy() right before every dispatch block (the
-# set_active_mesh pattern) rather than read from the environment at trace
-# time — two engines in one process may differ, and mutating the env var
-# mid-process is no longer silently ignored (it was never re-read; now it
-# is explicitly documented as resolved once at EngineConfig construction).
-#
-# "fused" (the default): the decode write folds INTO the
-# Pallas attention kernel (ops/attention.dispatch_paged_attention_write) —
-# no separate write op at all; int8 KV pools route to the
-# quantize-at-write twin kernel (pool bytes match this module's
-# quantize_kv bit-for-bit). The dispatcher takes the kernel wherever it
-# observes, at trace time, that it applies, and is exactly "dus" wherever
-# it does not (CP meshes, traced windows, a page row that is not a
-# multiple of 128 lanes: head_dim 96, or 64 where heads_per_row could not
-# pair; int8 at a page_size that is not a multiple of 128, the XLA path
-# off-TPU). Measured on a v5e at mistral-7b's shapes (PERF.md §6, PR 34):
-# the Mosaic kernel leaves the pool byte-identical to the "dus" loop and
-# updates it in place inside the K-step scan. The int8 twin has not been
-# timed in a cell (PERF.md §7). "dus" | "scatter" | "scatter-linear"
-# force the two-op path with that write.
-KV_WRITE_STRATEGIES = ("fused", "dus", "scatter", "scatter-linear")
-_DEFAULT_KV_WRITE = "fused"
-_active_kv_write = _DEFAULT_KV_WRITE
-
-
-def set_kv_write_strategy(strategy: str) -> None:
-    global _active_kv_write
-    if strategy not in KV_WRITE_STRATEGIES:
-        raise ValueError(f"kv_write must be one of {KV_WRITE_STRATEGIES}, "
-                         f"got {strategy!r}")
-    _active_kv_write = strategy
-
-
-def kv_write_strategy() -> str:
-    return _active_kv_write
-
-
-def default_kv_write_strategy() -> str:
-    """Resolve the env default ONCE (EngineConfig construction time)."""
-    import os
-
-    s = os.environ.get("LLMK_KV_WRITE", _DEFAULT_KV_WRITE)
-    # legacy spelling: LLMK_KV_WRITE=scatter + LLMK_SCATTER_VARIANT=linear
-    if s == "scatter" and os.environ.get("LLMK_SCATTER_VARIANT") == "linear":
-        s = "scatter-linear"
-    return s if s in KV_WRITE_STRATEGIES else _DEFAULT_KV_WRITE
-
-
-def _scatter_decode_writes() -> bool:
-    """Decode (T==1) write strategy (see set_kv_write_strategy).
-
-    The per-slot DUS loop costs ~0.7 us PER OP in dispatch overhead
-    (profiled round 4: 4096 ops = 3.0 ms of a 23 ms Llama-3-8B step at
-    B=64). One scatter per (layer, side) cuts the op count 64x and is
-    MOSTLY in place — but XLA's TPU scatter lowering reserves one
-    ~0.37-pool-sized HBM temp (measured 786 MB for the 2.15 GB bench
-    pool; identical for 2-D and linearized index forms), which pushes the
-    Llama-3-8B@16GB-v5e bench config 786 MB past HBM at COMPILE time. So
-    DUS stays the default; scatter is the right choice whenever the
-    deployment has that much HBM headroom (smaller models, v5p, larger
-    slices)."""
-    return _active_kv_write in ("scatter", "scatter-linear")
-
-
-def _write_decode_scatter(pools, rows, scales, pid, off, pos, owner):
-    """One scatter per pool for the whole decode batch.
-
-    Indices are UNIQUE by construction (each active slot appends into its
-    own page; rows to drop get pid = pool_size + row, distinct and out of
-    range so mode="drop" discards them without breaking the uniqueness
-    promise)."""
-    B = pid.shape[0]
-    total = pools[0].data.shape[1]
-    oob = total + jnp.arange(B, dtype=pid.dtype)
-    drop = pos < 0
-    if owner is not None:
-        base, width = owner
-        lpid = pid - base
-        drop = drop | (lpid < 0) | (lpid >= width)
-        pid = lpid
-    pid = jnp.where(drop, oob, pid)
-    out = []
-    for pool, r, sc in zip(pools, rows, scales):
-        data, scale = pool.data, pool.scale
-        rh = jnp.moveaxis(r[:, 0], 1, 0)                # [n, B, d]
-        if _active_kv_write == "scatter-linear":
-            # single-dim scatter on a [n, flat*page, d] view: one index
-            # vector, simplest possible lowering
-            n, total_p, page, d = data.shape
-            data = data.reshape(n, total_p * page, d).at[
-                :, pid * page + off].set(
-                rh, unique_indices=True, mode="drop").reshape(data.shape)
-        else:
-            data = data.at[:, pid, off].set(rh, unique_indices=True,
-                                            mode="drop")
-        if sc is not None:
-            scale = scale.at[:, pid, off].set(sc[:, 0].T, unique_indices=True,
-                                              mode="drop")
-        out.append(KVPool(data, scale))
-    return out
-
-
 def write_tokens(
     k_pages: "KVPool",
     v_pages: "KVPool",
@@ -425,9 +320,6 @@ def _write_rows(pools: list, rows: list, page_table: jnp.ndarray,
         # padding -> trash page 0 (never read; keeps the write unconditional)
         pid = jnp.where(pos < 0, 0, pid)
         off = jnp.where(pos < 0, 0, safe % page)
-        if _scatter_decode_writes():
-            return _write_decode_scatter(pools, rows, scales, pid, off, pos,
-                                         owner)
         owned = None
         if owner is not None:
             base, width = owner

@@ -215,16 +215,6 @@ class EngineConfig:
     max_grammars: int = 4
     grammar_states: int = 4096
     grammar_classes: int = 512
-    # decode KV write strategy: "fused" (default: the append rides inside
-    # the paged decode kernel wherever the dispatcher observes that the
-    # kernel applies, and is "dus" elsewhere; measured on the chip, PERF.md
-    # §6, PR 34) | "dus" | "scatter" | "scatter-linear" (cache.py
-    # discusses the tradeoff). None => the LLMK_KV_WRITE env
-    # default, resolved ONCE in __post_init__ — the strategy is part of
-    # the engine's static config and baked into its executables, so env
-    # mutation after construction has no effect (by design, documented)
-    # and two engines in one process may use different strategies.
-    kv_write: Optional[str] = None
     # watchdog: a device step that produces no completion within
     # max(watchdog_stall_s, 50 x recent step estimate) is declared stalled
     # — the engine sheds all work (EngineStallError -> "stalled" finishes,
@@ -303,12 +293,6 @@ class EngineConfig:
     def __post_init__(self):
         import os
 
-        from llms_on_kubernetes_tpu.engine.cache import (
-            KV_WRITE_STRATEGIES, default_kv_write_strategy,
-        )
-
-        if self.kv_write is None:
-            self.kv_write = default_kv_write_strategy()
         if self.decode_steps is None:
             self.decode_steps = int(os.environ.get("LLMK_DECODE_STEPS", "4"))
         if self.decode_steps < 1:
@@ -386,10 +370,6 @@ class EngineConfig:
                 os.environ.get("LLMK_ANOMALY_COOLDOWN_S", "600"))
         if self.anomaly_cooldown_s < 0:
             self.anomaly_cooldown_s = 0.0
-        if self.kv_write not in KV_WRITE_STRATEGIES:
-            raise ValueError(
-                f"kv_write must be one of {KV_WRITE_STRATEGIES}, "
-                f"got {self.kv_write!r}")
         # grammar tables are int16 on device; ABSOLUTE (rebased) state and
         # class ids must fit, or the rebase in _ensure_grammar would wrap
         # silently and mask the wrong tokens
@@ -2193,11 +2173,9 @@ class Engine:
         # another Engine (tests, rolling restarts) between our __init__
         # and our first trace would otherwise leak ITS mesh into OUR
         # executables (observed: a CP engine traced mesh-less)
-        from llms_on_kubernetes_tpu.engine.cache import set_kv_write_strategy
         from llms_on_kubernetes_tpu.parallel.mesh import set_active_mesh
 
         set_active_mesh(self.mesh)
-        set_kv_write_strategy(self.config.kv_write)
         events: list[StepEvent] = []
         if self.wedged:
             # nothing left to drive; reap anything that slipped in between
@@ -2818,11 +2796,9 @@ class Engine:
         the same jitted programs in the same order; followers get the
         inputs by broadcast). Updates the pools/counts and returns the
         device SampleResult."""
-        from llms_on_kubernetes_tpu.engine.cache import set_kv_write_strategy
         from llms_on_kubernetes_tpu.parallel.mesh import set_active_mesh
 
         set_active_mesh(self.mesh)  # follower_loop calls this directly
-        set_kv_write_strategy(self.config.kv_write)
         cfg = self.model_config
         embeds, deep = self._encode_request_images(images)
         n_max = self.config.max_images_per_request

@@ -289,13 +289,8 @@ def follower_loop(engine: Any) -> None:
     """
     import jax.numpy as jnp
 
-    from llms_on_kubernetes_tpu.engine.cache import set_kv_write_strategy
     from llms_on_kubernetes_tpu.engine.engine import _CHK_COLS, _DEC_COLS, _PRE_COLS
 
-    # the follower traces the same executables this loop feeds; pin the
-    # trace-time context to THIS engine's config (engine.step does the
-    # same on the coordinator)
-    set_kv_write_strategy(engine.config.kv_write)
     shapes = ProtoShapes.from_engine_config(engine.config,
                                             engine.model_config)
     pps = engine.config.pages_per_slot

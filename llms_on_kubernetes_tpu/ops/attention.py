@@ -799,21 +799,25 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
     pools take the quantize-at-write twin
     (pallas_paged_attention_write_int8): the new row is quantized in
     registers with the same arithmetic as cache.quantize_kv, so pool
-    bytes match the DUS path. Everywhere else,
-    and under a kv_write setting other than "fused", this is exactly
-    write_tokens + dispatch_paged_attention. The choice is made from what
-    is observed at trace time, so it holds for a whole executable.
+    bytes match the DUS path (on a v5e at mistral-7b's shapes the Mosaic
+    kernel left the pool byte-identical to the DUS loop: PERF.md §6,
+    PR 34; the int8 twin has not been timed in a cell: PERF.md §7).
+    Everywhere else (a seq-parallel mesh, a traced window, a page row that
+    is not a multiple of 128 lanes: head_dim 96, or 64 where
+    cache.heads_per_row could not pair; int8 at a page that is not a
+    multiple of 128; off the TPU) this is exactly write_then_attend. The
+    choice is made from what is observed at trace time and from nothing a
+    user or an engine can set, so it holds for a whole executable.
 
     q [B, n_q, d]; k_new/v_new [B, n_kv, d] (post-rope);
     write_positions [B, 1] (negative => idle/trash).
     Returns (attn [B, n_q, d], k_pages, v_pages)."""
-    from llms_on_kubernetes_tpu.engine.cache import KVPool, kv_write_strategy
-    from llms_on_kubernetes_tpu.ops.cp import dispatch_write_tokens
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
     from llms_on_kubernetes_tpu.parallel.mesh import seq_parallelism
 
     mode = None
     kd_shape = getattr(k_pages, "data", k_pages).shape
-    if kv_write_strategy() == "fused" and seq_parallelism() == 1:
+    if seq_parallelism() == 1:
         mode, note = _paged_kernel_mode(q, k_pages, page_table,
                                         sliding_window)
         # the in-kernel append is an 8-token-block RMW (Mosaic sublane
@@ -821,13 +825,10 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
         if mode == "compiled" and kd_shape[2] % 8 != 0:
             mode = None
     if mode is None:
-        k_pages, v_pages = dispatch_write_tokens(
-            k_pages, v_pages, k_new[:, None], v_new[:, None], page_table,
-            write_positions)
-        attn = dispatch_paged_attention(
-            q, k_pages, v_pages, page_table, lengths, scale=scale,
-            sliding_window=sliding_window, attn_softcap=attn_softcap)
-        return attn, k_pages, v_pages
+        return write_then_attend(
+            q, k_pages, v_pages, page_table, lengths, k_new, v_new,
+            write_positions, scale=scale, sliding_window=sliding_window,
+            attn_softcap=attn_softcap)
 
     from llms_on_kubernetes_tpu.ops import pallas_paged
 
@@ -852,6 +853,26 @@ def dispatch_paged_attention_write(q, k_pages, v_pages, page_table, lengths,
     if hasattr(k_pages, "data"):
         return attn, KVPool(kd), KVPool(vd)
     return attn, kd, vd
+
+
+def write_then_attend(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
+                      write_positions, *, scale, sliding_window=None,
+                      attn_softcap=None):
+    """The two-op decode step: the token's row written by
+    cp.dispatch_write_tokens (the per-slot dynamic_update_slice loop of
+    cache._write_rows), then dispatch_paged_attention over the pool. What
+    dispatch_paged_attention_write falls back to, with its operands and
+    result, and the reference the write-and-attend kernels are tested
+    against."""
+    from llms_on_kubernetes_tpu.ops.cp import dispatch_write_tokens
+
+    k_pages, v_pages = dispatch_write_tokens(
+        k_pages, v_pages, k_new[:, None], v_new[:, None], page_table,
+        write_positions)
+    attn = dispatch_paged_attention(
+        q, k_pages, v_pages, page_table, lengths, scale=scale,
+        sliding_window=sliding_window, attn_softcap=attn_softcap)
+    return attn, k_pages, v_pages
 
 
 def dispatch_paged_attention(q, k_pages, v_pages, page_table, lengths, *,

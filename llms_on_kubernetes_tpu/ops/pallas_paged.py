@@ -916,285 +916,3 @@ def pallas_latent_attention(
         interpret=check_interpret(interpret),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
     return out[:, 0]
-
-
-def _paged_kernel_write_window(
-    page_table_ref,   # SMEM [B, pages_per_seq] (scalar prefetch)
-    base_ref,         # SMEM [B] first token's 0-based pool position
-    width_ref,        # SMEM [B] tokens to write (0 => idle row)
-    k_hbm,            # ANY  [n_kv, P, page, d] (aliased with k_out)
-    v_hbm,            # ANY  [n_kv, P, page, d]
-    k_new_ref,        # VMEM [1, W, n_kv, d] — window of new K rows
-    v_new_ref,        # VMEM [1, W, n_kv, d]
-    k_out,            # ANY  (alias of k_hbm)
-    v_out,            # ANY  (alias of v_hbm)
-    kblk,             # VMEM [n_kv, 8, d] write-block scratch
-    vblk,             # VMEM [n_kv, 8, d]
-    wsem,             # DMA semaphores [2]
-    *,
-    window: int,
-    page_size: int,
-):
-    """In-place append of a K-token WINDOW per slot (multi-step decode).
-
-    Same 8-sublane-tile READ-MODIFY-WRITE as _paged_kernel_write, applied
-    token-by-token through the window: fetch the aligned 8-row block the
-    token lands in, splice the row, DMA the block back, and WAIT before
-    the next token — consecutive window tokens often share a block, so
-    the RMW chain must be ordered. Tokens past the row's ``width`` (early
-    exit: the row stopped mid-window) are skipped, leaving the pool
-    byte-identical to a per-step write sequence that stopped there."""
-    b = pl.program_id(0)
-    base = base_ref[b]
-    width = width_ref[b]
-
-    # every fetch AND write-back goes through the OUTPUT alias: token t+1
-    # often lands in the same 8-row block as token t, and fetching from
-    # the input ref would re-read pre-window bytes — losing token t's
-    # splice (a lost update the interpret mode catches deterministically)
-    for t in range(window):
-        @pl.when(t < width)
-        def _rmw(t=t):
-            pos = base + t
-            w_pid = page_table_ref[b, pos // page_size]
-            off8 = pl.multiple_of((pos % page_size) // 8 * 8, 8)
-            pltpu.make_async_copy(
-                k_out.at[:, w_pid, pl.ds(off8, 8)], kblk, wsem.at[0]).start()
-            pltpu.make_async_copy(
-                v_out.at[:, w_pid, pl.ds(off8, 8)], vblk, wsem.at[1]).start()
-            pltpu.make_async_copy(
-                k_out.at[:, w_pid, pl.ds(off8, 8)], kblk, wsem.at[0]).wait()
-            pltpu.make_async_copy(
-                v_out.at[:, w_pid, pl.ds(off8, 8)], vblk, wsem.at[1]).wait()
-            row = jax.lax.broadcasted_iota(
-                jnp.int32, (1, 8, 1), 1) == (pos % page_size) - off8
-            k_row = k_new_ref[0, t]                      # [n_kv, d]
-            v_row = v_new_ref[0, t]
-            kblk[...] = jnp.where(row, k_row[:, None, :], kblk[...])
-            vblk[...] = jnp.where(row, v_row[:, None, :], vblk[...])
-            pltpu.make_async_copy(
-                kblk, k_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[0]).start()
-            pltpu.make_async_copy(
-                vblk, v_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[1]).start()
-            pltpu.make_async_copy(
-                kblk, k_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[0]).wait()
-            pltpu.make_async_copy(
-                vblk, v_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[1]).wait()
-
-
-def pallas_paged_write_window(
-    k_pages: jnp.ndarray,      # [n_kv, P, page, d] (head-major pool; donated)
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,   # [B, pages_per_seq] int32
-    base: jnp.ndarray,         # [B] int32 0-based position of token 0
-    widths: jnp.ndarray,       # [B] int32 tokens to write (<= window)
-    k_new: jnp.ndarray,        # [B, W, n_kv, d] window of new K rows
-    v_new: jnp.ndarray,        # [B, W, n_kv, d]
-    *,
-    interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused in-place append of up to W tokens per slot in ONE kernel
-    launch (see _paged_kernel_write_window). The multi-step decode
-    window's verify-k speculative path lands on this entry point: a
-    draft-and-verify step commits 0..W accepted tokens per slot, and
-    ``widths`` is exactly the per-slot acceptance count. Returns
-    (k_pages, v_pages) updated in place via input/output aliasing."""
-    n_kv, P, page_size, d = k_pages.shape
-    B, W = k_new.shape[:2]
-    # the pool's rows: two adjacent 64-wide heads are one row of a paired
-    # pool (cache.heads_per_row), a reshape that moves nothing
-    k_new, v_new = k_new.reshape(B, W, n_kv, d), v_new.reshape(B, W, n_kv, d)
-
-    kernel = functools.partial(
-        _paged_kernel_write_window,
-        window=W, page_size=page_size,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, W, n_kv, d), lambda b, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((1, W, n_kv, d), lambda b, *_: (b, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n_kv, 8, d), k_pages.dtype),
-            pltpu.VMEM((n_kv, 8, d), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    k_pages, v_pages = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ],
-        # inputs count scalar-prefetch args first: pt=0, base=1, widths=2,
-        # k_pages=3, v_pages=4, k_new=5, v_new=6; outputs: k=0, v=1
-        input_output_aliases={3: 0, 4: 1},
-        interpret=check_interpret(interpret),
-    )(page_table.astype(jnp.int32), base.astype(jnp.int32),
-      widths.astype(jnp.int32), k_pages, v_pages,
-      k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype))
-    return k_pages, v_pages
-
-
-def _paged_kernel_write_window_int8(
-    page_table_ref,   # SMEM [B, pages_per_seq] (scalar prefetch)
-    base_ref,         # SMEM [B] first token's 0-based pool position
-    width_ref,        # SMEM [B] tokens to write (0 => idle row)
-    kd_hbm,           # ANY  [n_kv, P, page, d] int8 (aliased with kd_out)
-    ks_hbm,           # ANY  [n_kv, P, page] f32     (aliased with ks_out)
-    vd_hbm,           # ANY  [n_kv, P, page, d] int8
-    vs_hbm,           # ANY  [n_kv, P, page] f32
-    k_new_ref,        # VMEM [1, W, n_kv, d] — window of new K rows (f32)
-    v_new_ref,        # VMEM [1, W, n_kv, d]
-    kd_out,           # ANY  (alias of kd_hbm)
-    ks_out,           # ANY  (alias of ks_hbm)
-    vd_out,           # ANY  (alias of vd_hbm)
-    vs_out,           # ANY  (alias of vs_hbm)
-    kblk,             # VMEM [n_kv, 8, d] int8 write-block scratch
-    vblk,             # VMEM [n_kv, 8, d] int8
-    ksrow,            # VMEM [n_kv, page] f32 scale-row scratch
-    vsrow,            # VMEM [n_kv, page] f32
-    wsem,             # DMA semaphores [4]
-    *,
-    window: int,
-    page_size: int,
-):
-    """In-place QUANTIZING append of a K-token window per slot — the int8
-    twin of _paged_kernel_write_window. Each committed token's row is
-    quantized in registers (bit-identical to cache.quantize_kv) and
-    spliced via the 8-sublane data RMW + full-page scale-row RMW (see
-    _paged_kernel_write_int8 for the lane-tiling rationale). The RMW
-    chain is ordered token-by-token: consecutive tokens often share a
-    data block AND always share the scale row while inside one page, so
-    every write-back completes before the next fetch."""
-    b = pl.program_id(0)
-    base = base_ref[b]
-    width = width_ref[b]
-
-    for t in range(window):
-        @pl.when(t < width)
-        def _rmw(t=t):
-            pos = base + t
-            w_pid = page_table_ref[b, pos // page_size]
-            off8 = pl.multiple_of((pos % page_size) // 8 * 8, 8)
-            pltpu.make_async_copy(
-                kd_out.at[:, w_pid, pl.ds(off8, 8)], kblk, wsem.at[0]).start()
-            pltpu.make_async_copy(
-                vd_out.at[:, w_pid, pl.ds(off8, 8)], vblk, wsem.at[1]).start()
-            pltpu.make_async_copy(
-                ks_out.at[:, w_pid], ksrow, wsem.at[2]).start()
-            pltpu.make_async_copy(
-                vs_out.at[:, w_pid], vsrow, wsem.at[3]).start()
-            pltpu.make_async_copy(
-                kd_out.at[:, w_pid, pl.ds(off8, 8)], kblk, wsem.at[0]).wait()
-            pltpu.make_async_copy(
-                vd_out.at[:, w_pid, pl.ds(off8, 8)], vblk, wsem.at[1]).wait()
-            pltpu.make_async_copy(
-                ks_out.at[:, w_pid], ksrow, wsem.at[2]).wait()
-            pltpu.make_async_copy(
-                vs_out.at[:, w_pid], vsrow, wsem.at[3]).wait()
-            kq, ks_new = _quantize_row(k_new_ref[0, t].astype(jnp.float32))
-            vq, vs_new = _quantize_row(v_new_ref[0, t].astype(jnp.float32))
-            row = jax.lax.broadcasted_iota(
-                jnp.int32, (1, 8, 1), 1) == (pos % page_size) - off8
-            kblk[...] = jnp.where(row, kq[:, None, :], kblk[...])
-            vblk[...] = jnp.where(row, vq[:, None, :], vblk[...])
-            lane = jax.lax.broadcasted_iota(
-                jnp.int32, (1, page_size), 1) == pos % page_size
-            ksrow[...] = jnp.where(lane, ks_new[:, None], ksrow[...])
-            vsrow[...] = jnp.where(lane, vs_new[:, None], vsrow[...])
-            pltpu.make_async_copy(
-                kblk, kd_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[0]).start()
-            pltpu.make_async_copy(
-                vblk, vd_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[1]).start()
-            pltpu.make_async_copy(
-                ksrow, ks_out.at[:, w_pid], wsem.at[2]).start()
-            pltpu.make_async_copy(
-                vsrow, vs_out.at[:, w_pid], wsem.at[3]).start()
-            pltpu.make_async_copy(
-                kblk, kd_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[0]).wait()
-            pltpu.make_async_copy(
-                vblk, vd_out.at[:, w_pid, pl.ds(off8, 8)], wsem.at[1]).wait()
-            pltpu.make_async_copy(
-                ksrow, ks_out.at[:, w_pid], wsem.at[2]).wait()
-            pltpu.make_async_copy(
-                vsrow, vs_out.at[:, w_pid], wsem.at[3]).wait()
-
-
-def pallas_paged_write_window_int8(
-    k_data: jnp.ndarray,       # [n_kv, P, page, d] int8 (donated)
-    k_scale: jnp.ndarray,      # [n_kv, P, page] f32    (donated)
-    v_data: jnp.ndarray,
-    v_scale: jnp.ndarray,
-    page_table: jnp.ndarray,   # [B, pages_per_seq] int32
-    base: jnp.ndarray,         # [B] int32 0-based position of token 0
-    widths: jnp.ndarray,       # [B] int32 tokens to write (<= window)
-    k_new: jnp.ndarray,        # [B, W, n_kv, d] window of new K rows
-    v_new: jnp.ndarray,        # [B, W, n_kv, d]
-    *,
-    interpret: bool = False,
-):
-    """Fused quantize-at-write append of up to W tokens per slot in ONE
-    kernel launch — the int8 storage mode of pallas_paged_write_window
-    (same entry-point contract: per-slot ``widths`` is the committed
-    window length, speculative rejects simply shrink it). Returns
-    (k_data, k_scale, v_data, v_scale) updated in place."""
-    n_kv, P, page_size, d = k_data.shape
-    B, W = k_new.shape[:2]
-
-    kernel = functools.partial(
-        _paged_kernel_write_window_int8,
-        window=W, page_size=page_size,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, W, n_kv, d), lambda b, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((1, W, n_kv, d), lambda b, *_: (b, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n_kv, 8, d), k_data.dtype),
-            pltpu.VMEM((n_kv, 8, d), v_data.dtype),
-            pltpu.VMEM((n_kv, page_size), jnp.float32),
-            pltpu.VMEM((n_kv, page_size), jnp.float32),
-            pltpu.SemaphoreType.DMA((4,)),
-        ],
-    )
-    kd, ks, vd, vs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_data.shape, k_data.dtype),
-            jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-            jax.ShapeDtypeStruct(v_data.shape, v_data.dtype),
-            jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
-        ],
-        # inputs count scalar-prefetch args first: pt=0, base=1, widths=2,
-        # k_data=3, k_scale=4, v_data=5, v_scale=6, k_new=7, v_new=8;
-        # outputs: kd=0, ks=1, vd=2, vs=3
-        input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
-        interpret=check_interpret(interpret),
-    )(page_table.astype(jnp.int32), base.astype(jnp.int32),
-      widths.astype(jnp.int32), k_data, k_scale, v_data, v_scale,
-      k_new.astype(jnp.float32), v_new.astype(jnp.float32))
-    return kd, ks, vd, vs

@@ -2,7 +2,9 @@
 
 ``dispatch_paged_attention_write`` takes the fused write+attend kernel
 wherever ``_paged_kernel_mode`` admits the paged decode kernel, and is
-``write_tokens`` + ``dispatch_paged_attention`` everywhere else. Here, on
+``attention.write_then_attend`` (``write_tokens`` +
+``dispatch_paged_attention``) everywhere else; no setting reaches that
+choice (PR 49). Here, on
 the CPU, the kernel runs through the Pallas interpreter inside the engine's
 own K-step decode window (``_decode_multi_packed_step``); the hardware
 suite (tests/test_tpu_hardware.py) drives the same window, through
@@ -66,12 +68,15 @@ def window_args(cfg, params, packed, k_pages, v_pages):
             jnp.zeros((B, cfg.vocab_size), jnp.int32), jax.random.key(0)]
 
 
-def decode_window(cfg, params, k_pages, v_pages, packed, K, strategy):
-    """One K-step window of the engine's decode step under ``strategy``
-    ("fused" or "dus"), traced afresh (the strategy is read at trace time).
-    Returns (packs [K, B, W] on the host, k_pages, v_pages, compiled)."""
-    before = C.kv_write_strategy()
-    C.set_kv_write_strategy(strategy)
+def decode_window(cfg, params, k_pages, v_pages, packed, K, two_op=False):
+    """One K-step window of the engine's decode step, traced afresh: as
+    the dispatcher chooses, or with ``two_op`` on the reference path
+    (``attention.write_then_attend`` in the dispatcher's place while the
+    step is traced). Returns (packs [K, B, W] on the host, k_pages,
+    v_pages, compiled)."""
+    dispatcher = attention.dispatch_paged_attention_write
+    if two_op:
+        attention.dispatch_paged_attention_write = attention.write_then_attend
     try:
         # a function of its own: jit's trace cache is keyed by the function
         step = jax.jit(
@@ -81,7 +86,7 @@ def decode_window(cfg, params, k_pages, v_pages, packed, K, strategy):
         compiled = step.lower(*args).compile()
         packs, _toks, k_pages, v_pages, _counts, _, _ = compiled(*args)
     finally:
-        C.set_kv_write_strategy(before)
+        attention.dispatch_paged_attention_write = dispatcher
     return np.asarray(packs), k_pages, v_pages, compiled
 
 
@@ -190,19 +195,20 @@ def _f32(bits):
     return bits.view(np.float32)
 
 
-@pytest.mark.parametrize("strategy,kernel", [
-    ("fused", "fused write+attend kernel"),
-    ("dus", "paged kernel"),
-])
+@pytest.mark.parametrize("two_op,kernel", [
+    (False, "fused write+attend kernel"),
+    (True, "paged kernel"),
+], ids=["fused", "two-op"])
 def test_decode_window_fused_and_two_op_match_xla(tiny, monkeypatch,
-                                                  strategy, kernel):
+                                                  two_op, kernel):
     cfg, params, packed, pools, lengths0, budgets = tiny
     kernel += WIDTHS[cfg.head_dim]
     monkeypatch.setenv("LLMK_ATTENTION_IMPL", "xla")
-    want, wk, wv, _ = decode_window(cfg, params, *pools(), packed, K, "dus")
+    want, wk, wv, _ = decode_window(cfg, params, *pools(), packed, K,
+                                    two_op=True)
     assert attention._chosen["decode"][0] == "xla"
     monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
-    got, gk, gv, _ = decode_window(cfg, params, *pools(), packed, K, strategy)
+    got, gk, gv, _ = decode_window(cfg, params, *pools(), packed, K, two_op)
     assert attention._chosen["decode"] == ("pallas-interpret", kernel)
 
     live = np.asarray(lengths0) > 0
@@ -267,11 +273,10 @@ OBSERVED_OUT = {
 
 @pytest.mark.parametrize("case", sorted(OBSERVED_OUT))
 def test_dispatcher_observes_itself_out(rng, monkeypatch, case):
-    """No setting asks for it: kv_write is the default, "fused". The
-    result is the two-op path's bit for bit, and the record says why."""
+    """Nothing asks for it. The result is the two-op path's bit for bit,
+    and the record says why."""
     geometry, window, why = OBSERVED_OUT[case]
     q, kp, vp, pt, lengths, k_new, v_new, wp = _operands(rng, **geometry)
-    assert C.kv_write_strategy() == "fused"
     monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
 
     def both(window):
@@ -290,3 +295,31 @@ def test_dispatcher_observes_itself_out(rng, monkeypatch, case):
     assert attention._chosen["decode"] == ("xla", why)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("how", ["environment", "field"])
+def test_no_setting_reaches_the_decode_write(monkeypatch, how):
+    """The choice is the code's alone (PR 49). The environment name that
+    used to take an engine off the kernel is read nowhere: an engine built
+    under it runs its K = 4 decode windows on the write-and-attend kernel.
+    The config field that carried it is not a field."""
+    if how == "field":
+        with pytest.raises(TypeError):
+            E.EngineConfig(model="debug-tiny", kv_write="dus")
+        return
+    monkeypatch.setenv("LLMK_KV_WRITE", "dus")
+    monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    # the engine's step traces are shared between Engine objects
+    jax.clear_caches()
+    try:
+        eng = E.Engine(E.EngineConfig(
+            model="debug-tiny", dtype="float32", max_decode_slots=2,
+            page_size=16, num_pages=64, pages_per_slot=8,
+            prefill_buckets=(16,), decode_steps=4))
+        out = eng.generate(list(range(1, 12)),
+                           E.SamplingParams(temperature=0.0, max_tokens=8))
+    finally:
+        jax.clear_caches()
+    assert len(out) == 8
+    assert attention._chosen["decode"] == ("pallas-interpret",
+                                           "fused write+attend kernel")

@@ -178,56 +178,6 @@ def test_fused_write_int8_k1_matches_write_tokens(window, softcap, geometry,
                                np.asarray(vp_ref.scale)[:, 1:], rtol=2e-7)
 
 
-def test_fused_write_window_int8_matches_splice():
-    """Windowed quantize-at-write append vs a numpy splice of jitted
-    quantize_kv outputs: written rows carry the quantized window bytes
-    (int8 exact, scales to 1 ulp); every OTHER pool byte — data and
-    scale — must be bit-untouched. Windows start mid-page, at a page
-    boundary, at position 0, cross into a fresh page, and one row is
-    idle (width 0)."""
-    from llms_on_kubernetes_tpu.ops.pallas_paged import (
-        pallas_paged_write_window_int8,
-    )
-
-    rng = np.random.default_rng(4)
-    KV, d, page, pps, W = 2, 8, 8, 4, 4
-    base = np.asarray([7, 8, 0, 15, 3], np.int32)
-    widths = np.asarray([4, 3, 4, 2, 0], np.int32)
-    B = len(base)
-    P = B * pps + 1
-    kd = jnp.asarray(rng.integers(-127, 128, size=(KV, P, page, d)), jnp.int8)
-    vd = jnp.asarray(rng.integers(-127, 128, size=(KV, P, page, d)), jnp.int8)
-    ks = jnp.asarray(rng.random(size=(KV, P, page)) + 0.1, jnp.float32)
-    vs = jnp.asarray(rng.random(size=(KV, P, page)) + 0.1, jnp.float32)
-    table = np.zeros((B, pps), np.int32)
-    for b in range(B):
-        table[b] = 1 + b * pps + np.arange(pps)
-    k_new = jnp.asarray(rng.normal(size=(B, W, KV, d)), jnp.float32)
-    v_new = jnp.asarray(rng.normal(size=(B, W, KV, d)), jnp.float32)
-
-    qfn = jax.jit(quantize_kv)
-    kq_d, kq_s = qfn(k_new)   # [B, W, KV, d] int8, [B, W, KV] f32
-    vq_d, vq_s = qfn(v_new)
-    kd_ref, ks_ref = np.asarray(kd).copy(), np.asarray(ks).copy()
-    vd_ref, vs_ref = np.asarray(vd).copy(), np.asarray(vs).copy()
-    for b in range(B):
-        for t in range(int(widths[b])):
-            p = int(base[b]) + t
-            pid = table[b, p // page]
-            kd_ref[:, pid, p % page] = np.asarray(kq_d)[b, t]
-            ks_ref[:, pid, p % page] = np.asarray(kq_s)[b, t]
-            vd_ref[:, pid, p % page] = np.asarray(vq_d)[b, t]
-            vs_ref[:, pid, p % page] = np.asarray(vq_s)[b, t]
-
-    kd2, ks2, vd2, vs2 = pallas_paged_write_window_int8(
-        kd, ks, vd, vs, jnp.asarray(table), jnp.asarray(base),
-        jnp.asarray(widths), k_new, v_new, interpret=True)
-    np.testing.assert_array_equal(np.asarray(kd2), kd_ref)
-    np.testing.assert_array_equal(np.asarray(vd2), vd_ref)
-    np.testing.assert_allclose(np.asarray(ks2), ks_ref, rtol=2e-7)
-    np.testing.assert_allclose(np.asarray(vs2), vs_ref, rtol=2e-7)
-
-
 def test_int8_kv_teacher_forced_parity_across_decode_windows():
     """int8 KV acceptance gate (PR-4 margin-triage pattern): the fused
     K=1 kernel, the K=4 window, and the K=4 speculative (ngram) path

@@ -805,13 +805,16 @@ def test_engine_spec_rejected_tails_book_spec_waste():
         prefill_buckets=(16, 32), async_scheduling=True, async_depth=2,
         decode_steps=4, speculation="ngram", ledger=True,
     ))
-    # lookup-friendly prompt: the drafter always has an n-gram to offer,
-    # the random-weights model rarely agrees => rejections happen
-    rep = [5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6]
-    reqs = [eng.submit(rep, SamplingParams(temperature=0.0, max_tokens=16)),
-            eng.submit([4, 5, 6, 7, 8],
-                       SamplingParams(temperature=0.0, max_tokens=16))]
+    # streams held to six tokens: the drafter always has an n-gram to
+    # offer, the random-weights model agrees with part of it => rejections
+    # happen (why a lookup-friendly prompt alone does not do it is said at
+    # SMALL_VOCAB)
+    from test_speculation import REPETITIVE, SMALL_VOCAB
+
+    p = SamplingParams(temperature=0.0, max_tokens=16, logit_bias=SMALL_VOCAB)
+    reqs = [eng.submit(REPETITIVE, p), eng.submit([4, 5, 6, 7, 8], p)]
     _run(eng, reqs)
+    assert 0 < eng.spec_accepted_tokens < eng.spec_drafted_tokens
     snap = eng.ledger.snapshot()
     total = snap["attributed_ms"] + snap["wasted_ms"] + snap["idle_ms"]
     assert total == pytest.approx(snap["window_ms"], rel=1e-6, abs=1e-3)

@@ -397,48 +397,6 @@ def test_paged_decode_fused_write_matches_reference(rng, window, softcap):
                                   np.asarray(vp_ref.data)[:, 1:])
 
 
-def test_paged_write_window_matches_reference(rng):
-    """Windowed fused append (multi-step decode substrate): ONE kernel
-    launch writes up to W tokens per slot; per-row ``widths`` model
-    early exit (a row that stopped mid-window commits only its prefix)
-    and idle rows. Written rows must carry the window's bytes exactly;
-    every other pool byte must be UNTOUCHED (unlike write_tokens'
-    chunked path, which backfills later pages with clamped-gather
-    filler, this kernel read-modify-writes 8-row blocks) — covering
-    windows that start mid-page, at a page boundary, at position 0, and
-    windows crossing into a fresh page."""
-    from llms_on_kubernetes_tpu.ops.pallas_paged import (
-        pallas_paged_write_window,
-    )
-
-    n_kv, d, page, pps, W = 2, 8, 8, 4, 4
-    base_np = np.asarray([7, 8, 0, 15, 3], np.int32)
-    widths_np = np.asarray([4, 3, 4, 2, 0], np.int32)
-    B = len(base_np)
-    k_pages, v_pages, table = _paged_setup(rng, B, n_kv, d, page, pps,
-                                           base_np + W)
-    k_new = jnp.asarray(rng.normal(size=(B, W, n_kv, d)), jnp.float32)
-    v_new = jnp.asarray(rng.normal(size=(B, W, n_kv, d)), jnp.float32)
-
-    # numpy reference: splice each written token's row into a copy of the
-    # original pool; everything else must round-trip bit-identically
-    table_np = np.asarray(table)
-    kp_ref = np.asarray(k_pages).copy()
-    vp_ref = np.asarray(v_pages).copy()
-    for b in range(B):
-        for t in range(int(widths_np[b])):
-            pos = int(base_np[b]) + t
-            pid = table_np[b, pos // page]
-            kp_ref[:, pid, pos % page] = np.asarray(k_new)[b, t]
-            vp_ref[:, pid, pos % page] = np.asarray(v_new)[b, t]
-
-    kp2, vp2 = pallas_paged_write_window(
-        k_pages, v_pages, table, jnp.asarray(base_np),
-        jnp.asarray(widths_np), k_new, v_new, interpret=True)
-    np.testing.assert_array_equal(np.asarray(kp2), kp_ref)
-    np.testing.assert_array_equal(np.asarray(vp2), vp_ref)
-
-
 # ---------------------------------------------------------------------------
 # two 64-wide heads to a 128-lane page row (engine/cache.py heads_per_row)
 # ---------------------------------------------------------------------------
@@ -550,14 +508,11 @@ def test_paired_plain_kernel_and_reference_ops_read_the_pairs(rng, case):
                                rtol=1e-6, atol=1e-6)
 
 
-def test_paired_prefill_page_merge_and_window_write(rng):
+def test_paired_prefill_page_merge(rng):
     """A prefill's page merges into a paired pool (a chunk that starts
-    mid-page and crosses into fresh pages) and the speculative window
-    write leave the paired bytes of what they leave in a logical pool."""
+    mid-page and crosses into fresh pages) leave the paired bytes of what
+    they leave in a logical pool."""
     from llms_on_kubernetes_tpu.engine.cache import KVPool
-    from llms_on_kubernetes_tpu.ops.pallas_paged import (
-        pallas_paged_write_window,
-    )
 
     n_kv, d, page, pps, T = 4, 64, 8, 4, 13
     start = np.asarray([0, 5, 8], np.int32)
@@ -575,18 +530,6 @@ def test_paired_prefill_page_merge_and_window_write(rng):
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g.data)[:, 1:],
                                       np.asarray(pair_heads(w.data))[:, 1:])
-
-    W = 4
-    widths = jnp.asarray([4, 2, 0], jnp.int32)
-    want = pallas_paged_write_window(
-        k_pages, v_pages, table, jnp.asarray(start), widths, k[:, :W],
-        v[:, :W], interpret=True)
-    got = pallas_paged_write_window(
-        pair_heads(k_pages), pair_heads(v_pages), table, jnp.asarray(start),
-        widths, k[:, :W], v[:, :W], interpret=True)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g),
-                                      np.asarray(pair_heads(w)))
 
 
 # kv heads, head_dim, kv type, model axis -> (heads a row, the reason's gist)

@@ -454,25 +454,3 @@ def test_logit_bias_duplicate_ids_rejected():
     with pytest.raises(ValueError, match="duplicate"):
         eng.submit([1, 2, 3], SamplingParams(
             logit_bias=((5, 10.0), (5, 10.0))))
-
-
-def test_kv_write_config_plumbing(monkeypatch):
-    """kv_write is static engine config: env resolved once at
-    EngineConfig construction, bad values rejected, and two engines in
-    one process may differ (round-4 advisor finding)."""
-    import pytest
-
-    monkeypatch.delenv("LLMK_KV_WRITE", raising=False)
-    monkeypatch.delenv("LLMK_SCATTER_VARIANT", raising=False)
-    cfg = EngineConfig(model="debug-tiny", kv_write="scatter")
-    assert cfg.kv_write == "scatter"
-    # unset: the fused append, which the dispatcher takes only where it
-    # observes that the kernel applies (tests/test_fused_decode_step.py)
-    assert EngineConfig(model="debug-tiny").kv_write == "fused"
-    monkeypatch.setenv("LLMK_KV_WRITE", "dus")
-    assert EngineConfig(model="debug-tiny").kv_write == "dus"
-    monkeypatch.setenv("LLMK_KV_WRITE", "scatter")
-    monkeypatch.setenv("LLMK_SCATTER_VARIANT", "linear")
-    assert EngineConfig(model="debug-tiny").kv_write == "scatter-linear"
-    with pytest.raises(ValueError, match="kv_write"):
-        EngineConfig(model="debug-tiny", kv_write="bogus")

@@ -5,9 +5,10 @@ packed K-step window, one ``forward_verify`` dispatch scores every window
 position, and exact-match acceptance keeps greedy streams bit-identical
 to speculation off. These tests pin that contract end to end:
 
-- model level: ``forward_verify`` logits are bit-identical to sequential
-  ``forward_decode`` at every window position (same chunk attention the
-  one-shot path produces position-by-position);
+- model level: ``forward_verify`` logits equal sequential
+  ``forward_decode``'s at every window position to float32 rounding, with
+  the same argmax (same chunk attention the one-shot path produces
+  position-by-position);
 - engine level: greedy AND seeded-sampled streams match speculation off
   exactly (same fold_in(base, seed)+position PRNG chain, same penalty
   counts);
@@ -35,6 +36,12 @@ from llms_on_kubernetes_tpu.engine.speculation import (
 PROMPTS = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 10], [11, 12, 13, 14]]
 # lookup-friendly: the tail n-gram [5, 6, 7, 5, 6] repeats inside the prompt
 REPETITIVE = [5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6]
+# A prompt alone does not keep the drafter fed: the first verify can only
+# follow the admission's plain window, and by then the context's tail is
+# random weights' output, which repeats nothing of the prompt. Held to six
+# tokens (an equal bias: the model still chooses among them) a stream
+# always has an n-gram to offer, and the model agrees with part of it.
+SMALL_VOCAB = tuple((t, 50.0) for t in range(4, 10))
 
 
 def _mk(speculation=None, **kw):
@@ -132,10 +139,16 @@ def test_draft_model_drafter_greedy_rollout():
 
 
 # ---------------------------------------------------------------------------
-# model level: verify == sequential decode, bit-identical
+# model level: verify == sequential decode, to float32 rounding
 # ---------------------------------------------------------------------------
 
-def test_forward_verify_bit_identical_to_sequential_decode():
+def test_forward_verify_matches_sequential_decode():
+    """One [1, K] window against K [1, 1] steps. They are different XLA
+    programs (a batched product against K single-row ones), so equal to
+    float32 rounding and not to the bit: the run reads 1.2e-6 absolute,
+    4.9e-5 relative on the logits and 1e-6 on the window's K/V rows, layer
+    0's among them. What acceptance rests on, the argmax at every window
+    position, is the same; the prompt's rows are the same bytes."""
     import jax
     import jax.numpy as jnp
 
@@ -184,9 +197,21 @@ def test_forward_verify_bit_identical_to_sequential_decode():
     vlg, kp2, vp2 = forward_verify(
         params, cfg, jnp.asarray(win), jnp.asarray([n], jnp.int32),
         jnp.asarray([K], jnp.int32), kp2, vp2, pt)
-    vlg = np.asarray(vlg)[0]
-    for j in range(K):
-        np.testing.assert_array_equal(vlg[j], seq_logits[j])
+    vlg, seq_logits = np.asarray(vlg)[0], np.stack(seq_logits)
+    np.testing.assert_allclose(vlg, seq_logits, rtol=2e-4, atol=5e-6)
+    assert vlg.argmax(-1).tolist() == fed[1:]
+    table = np.asarray(pt)[0]
+    for seq, win in ((kp, kp2), (vp, vp2)):
+        seq, win = np.asarray(seq.data), np.asarray(win.data)
+        for layer in range(cfg.num_layers):
+            for pos in range(n + K):
+                at = (slice(None), layer * cc.num_pages
+                      + table[pos // cc.page_size], pos % cc.page_size)
+                if pos < n:
+                    np.testing.assert_array_equal(win[at], seq[at])
+                else:
+                    np.testing.assert_allclose(win[at], seq[at], rtol=0,
+                                               atol=5e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -195,7 +220,7 @@ def test_forward_verify_bit_identical_to_sequential_decode():
 
 def test_greedy_bit_identical_spec_on_off():
     base, spec = _mk(), _mk("ngram")
-    p = SamplingParams(temperature=0.0, max_tokens=24)
+    p = SamplingParams(temperature=0.0, max_tokens=24, logit_bias=SMALL_VOCAB)
     r0 = _run(base, [base.submit(REPETITIVE, p)])
     r1 = _run(spec, [spec.submit(REPETITIVE, p)])
     assert r1[0].output == r0[0].output

@@ -87,12 +87,12 @@ def _operands(dev, kv_dtype, page, pps, heads=None, pools=None, shards=1,
             sds((rows, 1), jnp.int32))
 
 
-def _compile_dispatch(args):
+def _compile_dispatch(args, op=None):
     from llms_on_kubernetes_tpu.ops import attention
 
+    op = op or attention.dispatch_paged_attention_write
     step = jax.jit(
-        lambda *a: attention.dispatch_paged_attention_write(
-            *a, scale=D ** -0.5, sliding_window=4096),
+        lambda *a: op(*a, scale=D ** -0.5, sliding_window=4096),
         donate_argnums=(1, 2))
     return step.lower(*args).compile()
 
@@ -123,15 +123,13 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_decode_append_rides_the_kernel_in_place(one_chip, no_cache,
                                                  monkeypatch, case):
-    """With nothing set, the decode dispatcher takes the fused write+attend
-    kernel at the serving geometry; Mosaic accepts it; and the compiled
-    program writes no row by ``dynamic-update-slice``, copies no pool and
-    hands both pools back in the buffers they came in."""
-    from llms_on_kubernetes_tpu.engine import cache
+    """The decode dispatcher takes the fused write+attend kernel at the
+    serving geometry; Mosaic accepts it; and the compiled program writes no
+    row by ``dynamic-update-slice``, copies no pool and hands both pools
+    back in the buffers they came in."""
     from llms_on_kubernetes_tpu.ops import attention
 
     kv_dtype, page, pps, why = CASES[case]
-    assert cache.kv_write_strategy() == "fused"
     # the code asks the backend, which is the CPU here
     monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
     args = _operands(one_chip, kv_dtype, page, pps)
@@ -191,16 +189,15 @@ def test_kernel_is_given_the_vmem_the_dispatcher_counted(monkeypatch, case):
     assert block == 512 and scratch <= most
 
 
-def test_two_op_setting_still_compiles_the_dus_loop(one_chip, no_cache,
-                                                    monkeypatch):
-    """``kv_write="dus"`` (the path every shape the kernel does not take
+def test_two_op_path_still_compiles_the_dus_loop(one_chip, no_cache,
+                                                 monkeypatch):
+    """``write_then_attend`` (the path every shape the kernel does not take
     falls back to) keeps its per-slot loop and the plain paged kernel."""
-    from llms_on_kubernetes_tpu.engine import cache
     from llms_on_kubernetes_tpu.ops import attention
 
     monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
-    monkeypatch.setattr(cache, "_active_kv_write", "dus")
-    hlo = _compile_dispatch(_operands(one_chip, None, PAGE, PPS)).as_text()
+    hlo = _compile_dispatch(_operands(one_chip, None, PAGE, PPS),
+                            attention.write_then_attend).as_text()
     assert attention._chosen["decode"] == ("pallas-compiled", "paged kernel")
     assert hlo.count("tpu_custom_call") == 1
     assert hlo.count(" dynamic-update-slice(") >= 2 * ROWS
@@ -298,19 +295,16 @@ def test_64_wide_cell_rides_the_kernel_on_paired_heads(one_chip, no_cache,
     assert eqn.invars[2].aval.shape == (64, 4, 8, 128)
 
 
-def test_two_op_setting_takes_the_plain_kernel_on_paired_heads(
+def test_two_op_path_takes_the_plain_kernel_on_paired_heads(
         one_chip, no_cache, monkeypatch):
-    """One gate: under ``kv_write="dus"`` the same pool gets the plain
+    """One gate: through ``write_then_attend`` the same pool gets the plain
     paged kernel after the write loop, with the same note on its record."""
-    from llms_on_kubernetes_tpu.engine import cache
     from llms_on_kubernetes_tpu.ops import attention
 
     monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
-    monkeypatch.setattr(cache, "_active_kv_write", "dus")
     args = _operands(one_chip, **CELL64)
     hlo = jax.jit(
-        lambda *a: attention.dispatch_paged_attention_write(
-            *a, scale=64 ** -0.5),
+        lambda *a: attention.write_then_attend(*a, scale=64 ** -0.5),
         donate_argnums=(1, 2)).lower(*args).compile().as_text()
     assert attention._chosen["decode"] == (
         "pallas-compiled", "paged kernel" + PAIRED)

@@ -643,11 +643,11 @@ def test_cell_decode_window_fused_in_place(mistral):
 
     out, said = {}, {}
     pool_shape = f"bf16[{N_KV},{cfg.num_layers * num_pages},{page},{D}]"
-    for name, strategy in (("fused", "fused"), ("two_op", "dus")):
+    for name in ("fused", "two_op"):
         t0 = time.perf_counter()
         packs, kp, vp, compiled = decode_window(
             cfg, params, KVPool(jnp.asarray(k0)), KVPool(jnp.asarray(v0)),
-            packed, K, strategy)
+            packed, K, two_op=name == "two_op")
         took = time.perf_counter() - t0
         mem, hlo = compiled.memory_analysis(), compiled.as_text()
         said[name] = {
@@ -704,12 +704,11 @@ def test_cell_greedy_streams_fused_against_two_op(mistral, monkeypatch):
     path's than the reference's are."""
     import json
 
-    from llms_on_kubernetes_tpu.engine.cache import (
-        CacheConfig, init_pages, kv_write_strategy, set_kv_write_strategy,
-    )
+    from llms_on_kubernetes_tpu.engine.cache import CacheConfig, init_pages
     from llms_on_kubernetes_tpu.models.decoder import (
         forward_decode, forward_prefill,
     )
+    from llms_on_kubernetes_tpu.ops import attention
 
     cfg, params = mistral
     with open("benchmark/golden/mistral-7b.json") as f:
@@ -731,11 +730,12 @@ def test_cell_greedy_streams_fused_against_two_op(mistral, monkeypatch):
     prefill = jax.jit(lambda p, *a: forward_prefill(p, cfg, *a),
                       donate_argnums=(3, 4))
 
-    def stream(strategy, impl="auto"):
+    def stream(two_op, impl="auto"):
         monkeypatch.setenv("LLMK_ATTENTION_IMPL", impl)
-        before = kv_write_strategy()
-        set_kv_write_strategy(strategy)
-        try:
+        with monkeypatch.context() as m:
+            if two_op:    # the reference path in the dispatcher's place
+                m.setattr(attention, "dispatch_paged_attention_write",
+                          attention.write_then_attend)
             decode = jax.jit(lambda p, *a: forward_decode(p, cfg, *a),
                              donate_argnums=(3, 4))
             kp, vp = init_pages(cc)
@@ -751,11 +751,9 @@ def test_cell_greedy_streams_fused_against_two_op(mistral, monkeypatch):
                 logits, kp, vp = decode(params, cur, jnp.asarray(plen + n),
                                         kp, vp, pt)
                 lps.append(np.asarray(jax.nn.log_softmax(logits)))
-        finally:
-            set_kv_write_strategy(before)
         return np.stack(lps, 1)                       # [B, N, V]
 
-    two_op = stream("dus")
+    two_op = stream(True)
 
     def apart(two_op, other):
         rows = []
@@ -775,7 +773,7 @@ def test_cell_greedy_streams_fused_against_two_op(mistral, monkeypatch):
                     np.sqrt((d[min(1, n)] ** 2).mean()))})
         return rows
 
-    fused, xla = stream("fused"), stream("dus", "xla")
+    fused, xla = stream(False), stream(True, "xla")
     said = {"tokens": N, "fused": apart(two_op, fused),
             "two_op_xla": apart(two_op, xla),
             "fused_against_two_op_xla": apart(xla, fused)}
