@@ -61,10 +61,13 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     # layers [0, num_dense_layers) keep a dense network in an expert model
     num_dense_layers: int = 0
-    # a stack of more than one kind of layer (LFM2, Jamba): per layer
+    # a stack of more than one kind of layer (LFM2, Jamba, Mellum): per layer
     # "conv" (a gated short convolution, state beside the KV pool),
-    # "mamba" (a selective state-space mixer, state beside the KV pool) or
-    # "full_attention". None => every layer is full attention
+    # "mamba" (a selective state-space mixer, state beside the KV pool),
+    # "sliding_attention" (attention inside ``sliding_window``, rotated by
+    # the unscaled ``rope_theta``) or "full_attention" (no window where the
+    # stack names its window layers; ``rope_scaling`` is these layers').
+    # None => every layer is attention, inside ``sliding_window`` if set
     layer_types: Optional[tuple] = None
     conv_L_cache: int = 3                   # taps of the short convolution
     conv_bias: bool = False
@@ -179,13 +182,46 @@ class ModelConfig:
             else self.experts_held
 
     def layer_kind(self, i: int) -> tuple:
-        """(operator, feed-forward) of layer ``i``: ("attn" | "conv" |
-        "mamba" | "mla", "dense" | "moe")."""
+        """(operator, feed-forward) of layer ``i``: ("attn" | "swa" |
+        "conv" | "mamba" | "mla", "dense" | "moe"). "swa" is attention
+        inside the window, a kind of its own so that a run of such layers
+        is traced with the window as a Python int."""
         kind = None if self.layer_types is None else self.layer_types[i]
         op = ("mla" if self.is_mla
-              else kind if kind in ("conv", "mamba") else "attn")
+              else kind if kind in ("conv", "mamba")
+              else "swa" if kind == "sliding_attention" else "attn")
         ff = "moe" if self.is_moe and i >= self.num_dense_layers else "dense"
         return op, ff
+
+    def attn_window(self, op: str) -> Optional[int]:
+        """The window of an attention layer of operator ``op``, static: a
+        "swa" layer's is ``sliding_window``; an "attn" layer has none in a
+        stack that names its layers, and ``sliding_window`` (every layer's)
+        in one that does not."""
+        if op == "swa" or self.layer_types is None:
+            return self.sliding_window
+        return None
+
+    @property
+    def names_window_layers(self) -> bool:
+        """The stack spells its window layers in ``layer_types``: runs of
+        "swa" beside runs of "attn", each kind with a rotary scheme of its
+        own."""
+        return any(op == "swa" for op, *_ in self.layer_runs)
+
+    @property
+    def num_window_layers(self) -> int:
+        """Layers that can never read a cached row again once it is
+        ``sliding_window`` positions behind (what
+        llm_attn_window_rows_total counts by)."""
+        if self.sliding_window is None or self.is_mla:
+            return 0
+        if self.sliding_window_pattern is not None:
+            return sum(1 for i in range(self.num_layers)
+                       if (i + 1) % self.sliding_window_pattern)
+        return sum(n for op, _ff, _i, n in self.layer_runs
+                   if op in ("attn", "swa")
+                   and self.attn_window(op) is not None)
 
     @functools.cached_property
     def layer_runs(self) -> tuple:
@@ -199,6 +235,31 @@ class ModelConfig:
             else:
                 runs.append((*kind, i, 1))
         return tuple(runs)
+
+    @property
+    def attention_summary(self) -> Optional[str]:
+        """One line for the start-up log of a stack that names its window
+        layers: the pattern, the window, each kind's rotary scheme. None
+        for every other model."""
+        if not self.names_window_layers:
+            return None
+        runs = [(op, n) for op, _ff, _i, n in self.layer_runs
+                if op in ("attn", "swa")]
+        pattern = " ".join(f"{n}x{'sliding' if op == 'swa' else 'full'}"
+                           for op, n in runs)
+        scaling = self.rope_scaling or {}
+        kind = scaling.get("rope_type", scaling.get("type", "default"))
+        full = f"{kind} rotary, theta {self.rope_theta:g}"
+        if kind == "yarn":
+            full += (f", factor {scaling['factor']} over "
+                     f"{scaling['original_max_position_embeddings']}, "
+                     f"attention_factor {scaling.get('attention_factor')} "
+                     f"(served as its square on the softmax scale)")
+        return (f"{self.name}: attention layers {pattern}; sliding layers "
+                f"see {self.sliding_window} positions (a static window in "
+                f"every attention kernel), default rotary, theta "
+                f"{self.rope_local_theta or self.rope_theta:g}; full layers "
+                f"see all, {full}")
 
     @property
     def num_attn_layers(self) -> int:
@@ -523,6 +584,42 @@ _register(
     "ai21labs/AI21-Jamba2-3B",
 )
 
+def _mellum_layers(n: int) -> tuple:
+    """Mellum 2's published ``layer_types``: layer i is full attention iff
+    (i + 1) % 4 == 0, else attention inside the window."""
+    return tuple("full_attention" if (i + 1) % 4 == 0
+                 else "sliding_attention" for i in range(n))
+
+
+# Mellum2-12B-A2.5B (JetBrains): three layers of attention inside a window
+# of 1,024 to one of full attention (32 query heads over 4 KV heads of 128,
+# no q/k norms); both kinds rotate all 128 dimensions with theta 500,000,
+# the window layers plainly and the full layers with YaRN (factor 16 over
+# 8,192; attention_factor on cosine and sine, served as its square on the
+# softmax scale); every layer routes each token to 8 of 64 softmax-scored
+# experts of width 896, renormalised, with no shared expert and no bias
+# (intermediate_size 7,168 is published and used by no layer). The
+# multi-token-prediction head the family describes is not in the config
+# and is not built.
+MELLUM_YARN = {
+    "rope_type": "yarn", "factor": 16,
+    "original_max_position_embeddings": 8192, "beta_fast": 32,
+    "beta_slow": 1, "attention_factor": 1.2772588722239782,
+}
+_register(
+    ModelConfig(
+        "mellum2-12b",
+        vocab_size=98304, hidden_size=2304, intermediate_size=7168,
+        num_layers=28, num_heads=32, num_kv_heads=4, head_dim=128,
+        rope_theta=500000.0, rms_norm_eps=1e-6,
+        max_position_embeddings=131072, rope_scaling=MELLUM_YARN,
+        sliding_window=1024, layer_types=_mellum_layers(28),
+        num_experts=64, num_experts_per_tok=8, moe_intermediate_size=896,
+        moe_router="softmax", norm_topk_prob=True,
+    ),
+    "JetBrains/Mellum2-12B-A2.5B-Instruct",
+)
+
 # DeepSeek-V3: latent attention (MLA) in every layer, three leading dense
 # layers, then 256 sigmoid-scored experts in 8 groups (a token chooses 8
 # experts among its 4 best groups; a selection bias; the chosen scores
@@ -617,6 +714,24 @@ _register(
         tie_word_embeddings=True, use_rope=False,
         layer_types=_jamba_layers(4, 4, 2),
         mamba_expand=2, mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=8,
+    ),
+)
+_register(
+    ModelConfig(
+        # mellum2-12b's stack at a size the CPU tests hold: two periods of
+        # three window layers (8 positions) and a full layer with YaRN
+        # (factor 4 over 32), 8 softmax-routed experts top-2 in every layer
+        "debug-mellum",
+        vocab_size=258, hidden_size=64, intermediate_size=128,
+        num_layers=8, num_heads=4, num_kv_heads=2, head_dim=16,
+        rope_theta=10000.0, rms_norm_eps=1e-6, max_position_embeddings=512,
+        rope_scaling={"rope_type": "yarn", "factor": 4,
+                      "original_max_position_embeddings": 32,
+                      "beta_fast": 32, "beta_slow": 1,
+                      "attention_factor": 1.1386294361119891},
+        sliding_window=8, layer_types=_mellum_layers(8),
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=48,
+        moe_router="softmax", norm_topk_prob=True,
     ),
 )
 _register(
@@ -902,6 +1017,49 @@ def from_hf_config(hf: dict | str, name: str = "hf-model") -> ModelConfig:
         if kw["conv_bias"]:
             raise NotImplementedError(
                 "lfm2_moe with conv_bias=true is not supported")
+    if model_type == "mellum":
+        # the published keys (JetBrains/Mellum2-12B-A2.5B-Instruct
+        # config.json): the kind of every layer, and a rotary scheme for
+        # each kind of attention
+        types = tuple(hf["layer_types"])
+        sparse = hf.get("mlp_layer_types") or ["sparse"] * len(types)
+        if len(types) != kw["num_layers"] or set(types) - {
+                "sliding_attention", "full_attention"} \
+                or len(sparse) != len(types) or set(sparse) != {"sparse"}:
+            raise NotImplementedError(
+                f"mellum layer_types {sorted(set(types))} and "
+                f"mlp_layer_types {sorted(set(sparse))} over {len(types)} "
+                f"layers (num_hidden_layers {kw['num_layers']}): only "
+                f"sliding_attention and full_attention, every network "
+                f"sparse")
+        ropes = hf.get("rope_parameters") or {}
+        local = ropes.get("sliding_attention") or {}
+        full = ropes.get("full_attention") or {}
+        if local.get("rope_type", "default") != "default" \
+                or full.get("rope_type", "default") not in ("default", "yarn") \
+                or local.get("rope_theta") != full.get("rope_theta"):
+            raise NotImplementedError(
+                "mellum is served with plain rotary window layers and "
+                "plain or yarn full layers of one rope_theta")
+        if "sliding_attention" in types and not (
+                hf.get("use_sliding_window", True)
+                and hf.get("sliding_window")):
+            raise NotImplementedError(
+                "mellum sliding_attention layers need sliding_window")
+        kw.update(
+            layer_types=types,
+            rope_theta=float(full.get("rope_theta",
+                                      hf.get("rope_theta", 10000.0))),
+            rope_scaling=({k: v for k, v in full.items()
+                           if k != "rope_theta"}
+                          if full.get("rope_type") == "yarn" else None),
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            num_experts=int(hf["num_experts"]),
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            moe_router="softmax",
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        )
     if model_type == "jamba":
         # the published keys (ai21labs/AI21-Jamba2-3B config.json); the
         # layer rule, the head size hidden / heads, the norms on the step

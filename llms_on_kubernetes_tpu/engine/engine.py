@@ -29,6 +29,7 @@ import contextlib
 import dataclasses
 import itertools
 import queue
+import sys
 import threading
 import time
 from typing import Any, Optional
@@ -1398,6 +1399,9 @@ class Engine:
         self.model_config = model_config or get_config(engine_config.model)
         cfg = self.model_config
         _refuse_what_runs_cannot(cfg, engine_config, mesh, model_dir)
+        if cfg.attention_summary:
+            print(f"[model] {cfg.attention_summary}", file=sys.stderr,
+                  flush=True)
         self.mesh = mesh
         from llms_on_kubernetes_tpu.parallel.mesh import (
             AXIS_MODEL, AXIS_SEQ, set_active_mesh,
@@ -1574,6 +1578,15 @@ class Engine:
         # llm_ssm_positions_total{path}. path_tokens and decode_tokens are
         # the real ones among them
         self.ssm_positions = {"prefill": 0, "chunk": 0, "decode": 0}
+        # rows of cached keys and values the window layers HOLD ("cached")
+        # and can still READ ("reached": the last sliding_window of them),
+        # summed over every planned token step of every live row, from the
+        # lengths the scheduler holds when it plans a decode window:
+        # llm_attn_window_rows_total{rows}. The pool keeps every token of
+        # every layer while its sequence lives, so cached - reached is
+        # what a cache that held window layers at their window would give
+        # back. 0 for a model without window layers
+        self.window_rows = {"cached": 0, "reached": 0}
         # fused multi-step decode accounting (metrics + bench):
         self.decode_dispatches = 0   # decode device dispatches
         self.decode_tokens = 0       # tokens committed to streams by decode
@@ -2327,6 +2340,25 @@ class Engine:
 
             jlog("dispatch_retraced", kind=kind, step=name, shape=shape,
                  seq=seq, seconds=round(rec.enqueue_ms / 1000.0, 3))
+
+    def _count_window_rows(self, first_lengths: dict, plan: dict) -> None:
+        """Book a planned decode window's token steps in ``window_rows``:
+        row i runs ``plan[i]`` steps at contexts ``first_lengths[i]``,
+        + 1, ...: two arithmetic series a row, the second stopping at the
+        window."""
+        cfg = self.model_config
+        layers, window = cfg.num_window_layers, cfg.sliding_window
+        if not layers:
+            return
+        cached = reached = 0
+        for i, first in first_lengths.items():
+            n = plan.get(i, 0)
+            inside = min(n, max(window - first, 0))    # steps under it
+            cached += n * first + n * (n - 1) // 2
+            reached += (inside * first + inside * (inside - 1) // 2
+                        + (n - inside) * window)
+        self.window_rows["cached"] += layers * cached
+        self.window_rows["reached"] += layers * reached
 
     @property
     def slot_state_bytes(self) -> int:
@@ -3343,8 +3375,10 @@ class Engine:
         self.decode_tokens += len(active)
         self.steps_obs.append(1)
         # a window of one on every active row, every token host-known
-        packed = self._pack_decode(active, dict.fromkeys(
-            (i for i, _r in active), 1), {}, {})
+        ones = dict.fromkeys((i for i, _r in active), 1)
+        packed = self._pack_decode(active, ones, {}, {})
+        self._count_window_rows(
+            {i: int(self.slot_len[i]) + 1 for i in ones}, ones)
 
         use_fsm = self._fsm_any_active()
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
@@ -3669,6 +3703,9 @@ class Engine:
 
         packed = self._pack_decode(
             active, plan, infl, admitted["slots"] if admitted else {})
+        self._count_window_rows(
+            {i: int(self.slot_len[i]) + infl.get(i, 0) + 1
+             for i, _r in active}, plan)
         last_toks = (self._inflight[-1].toks if self._inflight
                      else self._unread_toks)
         prefill_toks = self._unread_prefill_toks

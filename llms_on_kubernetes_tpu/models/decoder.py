@@ -38,15 +38,15 @@ from llms_on_kubernetes_tpu.configs import ModelConfig
 from llms_on_kubernetes_tpu.ops.cp import dispatch_write_tokens as write_tokens
 from llms_on_kubernetes_tpu.ops.attention import (
     dispatch_chunk_attention, dispatch_paged_attention,
-    dispatch_prefill_attention, dispatch_ssm_step, live_first, record_choice,
-    softcap,
+    dispatch_prefill_attention, dispatch_ssm_step, layer_kind, live_first,
+    record_choice, softcap,
 )
 from llms_on_kubernetes_tpu.ops.lora import lora_qeinsum
 from llms_on_kubernetes_tpu.ops.moe import moe_block
 from llms_on_kubernetes_tpu.ops.norms import rms_norm
 from llms_on_kubernetes_tpu.ops.quant import qeinsum
 from llms_on_kubernetes_tpu.ops.rope import (
-    apply_rope, rope_frequencies, yarn_attention_factor,
+    apply_rope, rope_frequencies, yarn_attention_factor, yarn_cos_sin_factor,
 )
 
 Params = dict[str, Any]
@@ -191,12 +191,15 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
     pile the rows on a few."""
     if cfg.attention_bias or cfg.post_norms or cfg.vision is not None \
             or cfg.norm_style != "llama" or not (
-                cfg.qk_norm or cfg.is_mla or cfg.num_mamba_layers):
+                cfg.qk_norm or cfg.is_mla or cfg.num_mamba_layers
+                or cfg.names_window_layers):
         raise NotImplementedError(
             f"{cfg.name}: a stack of several kinds of layer is built for "
             f"the LFM2 block (llama norms, q/k norms, no biases), the "
-            f"DeepSeek block (latent attention) and the Jamba block (Mamba "
-            f"layers, attention without norms or positions) only")
+            f"DeepSeek block (latent attention), the Jamba block (Mamba "
+            f"layers, attention without norms or positions) and the Mellum "
+            f"block (window and full attention layers without q/k norms) "
+            f"only")
     D, F, Fm = cfg.hidden_size, cfg.intermediate_size, cfg.expert_width
     H, KV, hd, V = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size
     E, held, taps = cfg.num_experts, cfg.num_held_experts, cfg.conv_L_cache
@@ -224,7 +227,7 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
                 w_uk=init(n, H, kl, nope, scale=kl ** -0.5),
                 w_uv=init(n, H, kl, vd, scale=kl ** -0.5),
                 wo=init(n, H, vd, D, scale=(H * vd) ** -0.5))
-        elif op == "attn":
+        elif op in ("attn", "swa"):
             lp.update(
                 wq=init(n, D, H, hd, scale=D ** -0.5),
                 wk=init(n, D, KV, hd, scale=D ** -0.5),
@@ -264,10 +267,11 @@ def _init_runs(cfg: ModelConfig, key: jax.Array, dt) -> Params:
                 conv_w=init(n, D, taps, scale=taps ** -0.5),
                 conv_out=init(n, D, D, scale=D ** -0.5))
         if ff == "moe":
+            lp.update(router=init(n, D, E, scale=D ** -0.5))
+            if cfg.use_expert_bias:
+                lp.update(router_bias=jax.random.normal(
+                    next(keys), (n, E), jnp.float32) * 0.01)
             lp.update(
-                router=init(n, D, E, scale=D ** -0.5),
-                router_bias=(jax.random.normal(next(keys), (n, E),
-                                               jnp.float32) * 0.01),
                 w_gate=init(n, held, D, Fm, scale=D ** -0.5),
                 w_up=init(n, held, D, Fm, scale=D ** -0.5),
                 w_down=init(n, held, Fm, D, scale=Fm ** -0.5))
@@ -604,7 +608,7 @@ def _layer_step(
         out, k_pages, v_pages = _attention(
             cfg, inv_freq, page_table, positions, write_positions, lengths,
             mode, h, lp, k_pages, v_pages, layer_idx, inv_freq_local,
-            mm_groups, mm_pos3, rope_positions, adapter_idx)
+            mm_groups, mm_pos3, rope_positions, adapter_idx, op)
     if cfg.post_norms:
         out = rms_norm(out, lp["attn_post_norm"], cfg.rms_norm_eps, style=cfg.norm_style)
     x = x + out
@@ -679,19 +683,43 @@ def _latent_attention(cfg, inv_freq, page_table, positions, write_positions,
 def _attention(cfg, inv_freq, page_table, positions, write_positions,
                lengths, mode, h, lp, k_pages, v_pages, layer_idx,
                inv_freq_local, mm_groups, mm_pos3, rope_positions,
-               adapter_idx):
+               adapter_idx, op="attn"):
     """The attention operator on the normed input ``h``: (W_o of the
-    attended values, k_pages, v_pages)."""
+    attended values, k_pages, v_pages). ``op`` is the run's kind ("attn" |
+    "swa"): its window is a Python int or None (``cfg.attn_window``), so
+    every dispatcher sees it static and may take a kernel."""
     scale = (cfg.query_pre_attn_scalar or cfg.head_dim) ** -0.5
+    window = cfg.attn_window(op)
+    # a stack that names its window layers (``layer_types``): a window
+    # layer rotates by the unscaled theta (``inv_freq_local``), a full
+    # layer by the scaled frequencies, with yarn's factor on cosine and
+    # sine served as its square on the scale; each kind records its own
+    # choice of kernel
+    kind = None
+    if cfg.names_window_layers:
+        kind = "sliding" if op == "swa" else "full"
+        if op == "swa":
+            inv_freq = inv_freq_local
+        else:
+            scale *= yarn_cos_sin_factor(cfg.rope_scaling) ** 2
     # Gemma-2/3 interleaved attention: layer is global iff (i+1) % pattern == 0;
     # local layers use sliding_window + rope_local_theta. The window becomes a
     # traced scalar so one scanned layer body serves both layer kinds.
-    window = cfg.sliding_window
     if cfg.sliding_window_pattern is not None and layer_idx is not None:
         is_global = (layer_idx + 1) % cfg.sliding_window_pattern == 0
         window = jnp.where(is_global, jnp.int32(2 ** 30), jnp.int32(cfg.sliding_window))
         inv_freq = jnp.where(is_global, inv_freq, inv_freq_local)
+    with layer_kind(kind):
+        return _attend(cfg, inv_freq, page_table, positions, write_positions,
+                       lengths, mode, h, lp, k_pages, v_pages, mm_groups,
+                       mm_pos3, rope_positions, adapter_idx, scale, window)
 
+
+def _attend(cfg, inv_freq, page_table, positions, write_positions, lengths,
+            mode, h, lp, k_pages, v_pages, mm_groups, mm_pos3,
+            rope_positions, adapter_idx, scale, window):
+    """``_attention`` with the layer's scale, window and frequencies
+    chosen."""
     q, k, v = _qkv(lp, cfg, h, adapter_idx=adapter_idx)
     if mm_pos3 is not None:
         # multimodal prompt on an mrope model (Qwen3-VL): interleaved
@@ -775,9 +803,13 @@ def _run_layers(
     inv_freq = jnp.asarray(rope_frequencies(
         cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)) \
         if cfg.use_rope else None
+    # the window layers' frequencies: theta of their own where the config
+    # has one, never scaled
     inv_freq_local = (
-        jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_local_theta))
-        if cfg.rope_local_theta is not None else None
+        jnp.asarray(rope_frequencies(
+            cfg.head_dim, cfg.rope_local_theta or cfg.rope_theta))
+        if cfg.rope_local_theta is not None or cfg.names_window_layers
+        else None
     )
     # flat-pool layer folding. Default (layer-major): layer l's pages live
     # in the block [l*P, (l+1)*P). Context parallelism (seq>1 mesh)

@@ -17,6 +17,7 @@ Layout choices (TPU-first):
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import sys
@@ -80,11 +81,38 @@ VMEM_BUDGET_BYTES = 96 << 20
 _chosen: dict[str, tuple[str, str]] = {}
 
 
+# the kind of attention layer being traced ("sliding" | "full") in a stack
+# that mixes them, else None: see ``layer_kind``
+_kind: Optional[str] = None
+
+
+@contextlib.contextmanager
+def layer_kind(kind: Optional[str]):
+    """Around the trace of one attention layer of a stack that mixes window
+    and full layers: its dispatchers' records are keyed ``<op>_<kind>``
+    (``decode_sliding``: one word, as the log's readers take an op), so
+    that the two kinds do not overwrite each other's line, and its
+    operations lie under ``attn.<kind>`` in a trace. None (every other
+    model) changes nothing."""
+    global _kind
+    if kind is None:
+        yield
+        return
+    before, _kind = _kind, kind
+    try:
+        with jax.named_scope(f"attn.{kind}"):
+            yield
+    finally:
+        _kind = before
+
+
 def record_choice(op: str, impl: str, why: str) -> None:
     """Which implementation an operator runs and why, once a change: the
     dispatchers here, and every other module that chooses one (the
     experts' product, the state-space scan), under this one tag, which the
     log readers know."""
+    if _kind is not None and op in ("prefill", "chunk", "decode"):
+        op = f"{op}_{_kind}"
     if _chosen.get(op) != (impl, why):
         _chosen[op] = (impl, why)
         print(f"[attention] op={op} impl={impl} why={why}",
@@ -721,14 +749,87 @@ def dispatch_chunk_attention(q, k_pages, v_pages, page_table, history,
             q, k_pages, v_pages, page_table, history, chunk_lengths,
             scale=scale, sliding_window=sliding_window,
             attn_softcap=attn_softcap)
-    # XLA gather path everywhere for now: chunked prefill is bandwidth-bound
-    # on the page gather, which XLA fuses acceptably; a Pallas paged-flash
-    # chunk kernel is the designated upgrade path (see pallas_flash.py).
-    record_choice("chunk", "xla", "no chunk kernel")
-    return chunk_attention(q, k_pages, v_pages, page_table, history,
-                           chunk_lengths, scale=scale,
-                           sliding_window=sliding_window,
-                           attn_softcap=attn_softcap)
+    mode, why, pages = _chunk_kernel_mode(q, k_pages, page_table,
+                                          sliding_window)
+    if mode is None:
+        record_choice("chunk", "xla", why)
+        return chunk_attention(q, k_pages, v_pages, page_table, history,
+                               chunk_lengths, scale=scale,
+                               sliding_window=sliding_window,
+                               attn_softcap=attn_softcap)
+    from llms_on_kubernetes_tpu.ops.pallas_flash import flash_chunk_attention
+
+    record_choice("chunk", f"pallas-{mode}", why)
+    B, d = q.shape[0], q.shape[3]
+    page = k_pages.shape[2]
+    base = jnp.zeros_like(history)
+    if pages < page_table.shape[1]:
+        # a window layer: from the page that holds the first query's
+        # window edge (the slot's last pages where that would run past its
+        # end); the kernel's positions count from that page's first row
+        first = jnp.clip((history - sliding_window + 1) // page, 0,
+                         page_table.shape[1] - pages)
+        page_table = jnp.take_along_axis(
+            page_table, first[:, None] + jnp.arange(pages)[None, :], axis=1)
+        base = first * page
+
+    def gathered(pool):
+        data = getattr(pool, "data", pool)
+        return data[:, page_table].reshape(data.shape[0], B, pages * page, d)
+
+    return _per_kv_head_shard(
+        lambda q, k, v, history, kv_len: flash_chunk_attention(
+            q, k, v, history, kv_len, scale=scale,
+            sliding_window=sliding_window, attn_softcap=attn_softcap,
+            interpret=mode == "interpret"),
+        k_pages.shape[0],
+        (q, gathered(k_pages), gathered(v_pages), history - base,
+         jnp.where(chunk_lengths > 0, history + chunk_lengths - base, 0)),
+        (2, 0, 0, None, None), 2)
+
+
+def _chunk_kernel_mode(q, k_pages, page_table, sliding_window):
+    """(mode, what the record says, pages of a slot to gather) for a
+    chunk over its slot's cached keys: pallas_mode() where
+    ``pallas_flash.flash_chunk_attention`` applies (a static window, a
+    pool of plain arrays with one head a page row, blocks on Mosaic's
+    tiling and inside the VMEM budget), else None and the reason for the
+    XLA gather path."""
+    mode = pallas_mode()
+    if mode is None:
+        return None, _no_pallas_why(), None
+    if not _static_window(sliding_window):
+        return None, "traced (per-layer) sliding window", None
+    if getattr(k_pages, "quantized", False):
+        return None, "an int8 pool is dequantized by the XLA gather", None
+    from llms_on_kubernetes_tpu.ops.pallas_flash import (
+        chunk_flash_blocks, chunk_flash_vmem_bytes, chunk_gather_pages,
+    )
+
+    T, d = q.shape[1], q.shape[3]
+    page, lanes = k_pages.shape[2], k_pages.shape[3]
+    if lanes != d:
+        return None, (f"the pool holds {lanes // d} heads of {d} to a "
+                      f"{lanes}-lane page row"), None
+    slot = page_table.shape[1] * page
+    pages = chunk_gather_pages(T, page, page_table.shape[1], sliding_window)
+    S = pages * page
+    bq, kb = chunk_flash_blocks(T, S)
+    if T % bq:
+        return None, f"bucket {T} is not a multiple of {bq}", None
+    if mode == "compiled" and (d % 128 or bq % 8 or kb % 128):
+        return None, (f"head_dim {d}, {bq} queries or {kb} keys a block "
+                      f"are off Mosaic's tiling"), None
+    need = chunk_flash_vmem_bytes(T, S, d, q.dtype.itemsize)
+    if need > VMEM_BUDGET_BYTES:
+        return None, (f"{S} gathered keys need {_mib(need)} VMEM > "
+                      f"{_mib(VMEM_BUDGET_BYTES)} budget"), None
+    keys = f"a slot's {S}" if S == slot else f"{S} of a slot's {slot}"
+    window = "" if sliding_window is None else \
+        f" inside a window of {sliding_window}"
+    return mode, (f"flash chunk kernel: {keys} gathered keys, blocks of "
+                  f"{kb} a query block of {bq} can see{window}, bucket "
+                  f"{T}"), pages
 
 
 def _paged_kernel_mode(q, k_pages, page_table, sliding_window):

@@ -161,6 +161,159 @@ def flash_prefill_attention(
 
 
 # ---------------------------------------------------------------------------
+# A chunk of a prompt over its slot's cached keys and values
+# ---------------------------------------------------------------------------
+
+CHUNK_BLOCK_K = 512
+
+
+def chunk_flash_blocks(T: int, S: int) -> tuple[int, int]:
+    """(queries a program, keys a block) of ``flash_chunk_attention`` at a
+    chunk of T queries over S gathered keys."""
+    return min(BLOCK_Q, T), math.gcd(S, CHUNK_BLOCK_K)
+
+
+def chunk_gather_pages(T: int, page: int, slot_pages: int,
+                       sliding_window: Optional[int]) -> int:
+    """Pages of a slot that a chunk of T queries gathers: all of them
+    without a window; inside one, those from the page that holds the first
+    query's window edge to the last query's (``sliding_window + T - 1``
+    positions and the rest of the first page), in whole key blocks of the
+    size the whole slot would have."""
+    if sliding_window is None:
+        return slot_pages
+    kb = math.gcd(slot_pages * page, CHUNK_BLOCK_K)
+    unit = kb * page // math.gcd(kb, page)
+    need = sliding_window + T - 1 + page - 1
+    return min(-(-need // unit) * unit // page, slot_pages)
+
+
+def chunk_flash_vmem_bytes(T: int, S: int, d: int, itemsize: int) -> int:
+    """VMEM a program of ``flash_chunk_attention`` is given: the head's
+    gathered K and V (double-buffered by the pipeline), six [queries,
+    key block] float32 temporaries (scores, mask, probabilities and exp's),
+    the running state and 4 MiB for the q/o blocks and Mosaic's scratch."""
+    bq, kb = chunk_flash_blocks(T, S)
+    return (2 * 2 * S * d * itemsize + 6 * bq * kb * 4
+            + bq * (d + 2 * _LANES) * 4 + (4 << 20))
+
+
+def _flash_chunk_kernel(
+    history_ref,   # SMEM [B] position of each row's first query (prefetch)
+    kv_len_ref,    # SMEM [B] keys that are written; 0 => idle (prefetch)
+    q_ref,         # VMEM [1, 1, bq, d]
+    k_ref,         # VMEM [1, 1, S, d]   the slot's keys, gathered
+    v_ref,         # VMEM [1, 1, S, d]
+    o_ref,         # VMEM [1, 1, bq, d]
+    *,
+    scale: float,
+    sliding_window: Optional[int],
+    attn_softcap: Optional[float],
+    kb: int,
+):
+    """One block of ``bq`` queries of one head against the key blocks it
+    can see: from the block that holds the first query's window edge (0
+    without a window) to the block that holds the last query's own
+    position or the last written key. ``attention.chunk_attention``'s
+    numbers with the operands in their type: float32 products, maximum,
+    sum and accumulator; p cast to the values' type."""
+    b, qi = pl.program_id(0), pl.program_id(2)
+    bq, d = q_ref.shape[2], q_ref.shape[3]
+    S = k_ref.shape[2]
+    history, kv_len = history_ref[b], kv_len_ref[b]
+    q0 = history + qi * bq
+    q = q_ref[0, 0]
+    lo = 0 if sliding_window is None else (
+        jnp.maximum(q0 - sliding_window + 1, 0) // kb)
+    hi = jnp.minimum((jnp.minimum(q0 + bq, kv_len) + kb - 1) // kb, S // kb)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, kb), 0)
+    k_off = jax.lax.broadcasted_iota(jnp.int32, (bq, kb), 1)
+
+    def block(j, carry):
+        m_old, l_old, acc = carry
+        r = pl.ds(pl.multiple_of(j * kb, kb), kb)
+        s = jax.lax.dot_general(
+            q, k_ref[0, 0, r, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = softcap(s, attn_softcap)
+        k_pos = j * kb + k_off
+        mask = (k_pos <= q_pos) & (k_pos < kv_len)
+        if sliding_window is not None:
+            mask &= k_pos > q_pos - sliding_window
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+        # a row with no key yet in sight has m = NEG_INF and exp(0) = 1
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_old - m_new)
+        v = v_ref[0, 0, r, :]
+        return (m_new, l_old * alpha + p.sum(axis=-1, keepdims=True),
+                acc * alpha + jnp.dot(p.astype(v.dtype), v,
+                                      preferred_element_type=jnp.float32))
+
+    _, l, acc = jax.lax.fori_loop(
+        lo, hi, block,
+        (jnp.full((bq, 1), NEG_INF, jnp.float32),
+         jnp.zeros((bq, 1), jnp.float32), jnp.zeros((bq, d), jnp.float32)))
+    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "sliding_window", "attn_softcap",
+                              "interpret"))
+def flash_chunk_attention(
+    q: jnp.ndarray,           # [B, T, n_q, d]
+    k: jnp.ndarray,           # [n_kv, B, S, d]: a slot's pages, gathered
+    v: jnp.ndarray,
+    history: jnp.ndarray,     # [B] int32: query t of a row is at history + t
+    kv_len: jnp.ndarray,      # [B] int32: keys written (0 => idle row)
+    *,
+    scale: float,
+    sliding_window: Optional[int] = None,
+    attn_softcap: Optional[float] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``attention.chunk_attention`` as a kernel, over keys and values
+    already gathered through the page table (9 MB a KV head pair at 9,216
+    positions: nothing beside a [T, S] score tile a head in float32; a
+    window layer gathers ``chunk_gather_pages`` of them and counts its
+    positions from the first gathered row):
+    [B, T, n_q, d] in q's type. Grid (B, n_q, T / bq), a head's K and V
+    resident across its group's query blocks; key blocks outside a query
+    block's causal range and window are not computed. A query with no key
+    in sight (padding past the chunk's length) gives zeros."""
+    B, T, n_q, d = q.shape
+    n_kv, S = k.shape[0], k.shape[2]
+    group = n_q // n_kv
+    bq, kb = chunk_flash_blocks(T, S)
+    assert T % bq == 0 and S % kb == 0, (T, S)
+    out = pl.pallas_call(
+        functools.partial(_flash_chunk_kernel, scale=scale,
+                          sliding_window=sliding_window,
+                          attn_softcap=attn_softcap, kb=kb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, n_q, T // bq),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, *_: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, S, d),
+                             lambda b, h, i, *_: (h // group, b, 0, 0)),
+                pl.BlockSpec((1, 1, S, d),
+                             lambda b, h, i, *_: (h // group, b, 0, 0))],
+            out_specs=pl.BlockSpec((1, 1, bq, d),
+                                   lambda b, h, i, *_: (b, h, i, 0))),
+        out_shape=jax.ShapeDtypeStruct((B, n_q, T, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=chunk_flash_vmem_bytes(
+                T, S, d, q.dtype.itemsize)),
+        name="flash_chunk_attention",
+        interpret=check_interpret(interpret),
+    )(history.astype(jnp.int32), kv_len.astype(jnp.int32),
+      jnp.swapaxes(q, 1, 2), k, v)
+    return jnp.swapaxes(out, 1, 2)
+
+
+# ---------------------------------------------------------------------------
 # Latent rows (DeepSeek MLA), expanded: prompt buckets and chunks
 # ---------------------------------------------------------------------------
 

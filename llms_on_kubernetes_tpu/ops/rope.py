@@ -7,9 +7,10 @@ max_len-sized HBM read per layer.
 
 Supports the llama3 long-context frequency rescaling used by Llama-3.1+
 (`rope_scaling={"rope_type": "llama3", ...}` in HF configs), linear
-scaling, and YaRN as DeepSeek-V3 applies it: interpolated frequencies
-(``rope_frequencies``) and a factor on the softmax scale
-(``yarn_attention_factor``).
+scaling, and YaRN: interpolated frequencies (``rope_frequencies``) with, as
+DeepSeek-V3 applies it, a factor on the softmax scale
+(``yarn_attention_factor``) or, as a published ``attention_factor`` states
+it, a factor on cosine and sine (``yarn_cos_sin_factor``).
 """
 
 from __future__ import annotations
@@ -101,11 +102,35 @@ def yarn_attention_factor(rope_scaling: Optional[dict]) -> float:
     if not rope_scaling or rope_scaling.get(
             "rope_type", rope_scaling.get("type")) != "yarn":
         return 1.0
-    factor = float(rope_scaling["factor"])
-    all_dim = float(rope_scaling.get("mscale_all_dim", 0.0))
-    if factor <= 1.0 or not all_dim:
+    return _yarn_m(float(rope_scaling["factor"]),
+                   float(rope_scaling.get("mscale_all_dim", 0.0)))
+
+
+def _yarn_m(factor: float, mscale: float) -> float:
+    """yarn's magnitude correction 0.1 mscale ln(factor) + 1 (1.0 where
+    nothing is stretched)."""
+    if factor <= 1.0 or not mscale:
         return 1.0
-    return 0.1 * all_dim * math.log(factor) + 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_cos_sin_factor(rope_scaling: Optional[dict]) -> float:
+    """The factor yarn puts on cosine and sine (1.0 without yarn): the
+    published ``attention_factor``; without one, mscale's m over
+    mscale_all_dim's where the config gives both (DeepSeek: 1 where they
+    are equal), else 0.1 ln(factor) + 1. On a rotated query AND key it is
+    the same as its square on the softmax scale, which is how
+    models/decoder.py serves it: cached keys stay unscaled."""
+    if not rope_scaling or rope_scaling.get(
+            "rope_type", rope_scaling.get("type")) != "yarn":
+        return 1.0
+    if rope_scaling.get("attention_factor") is not None:
+        return float(rope_scaling["attention_factor"])
+    factor = float(rope_scaling["factor"])
+    if rope_scaling.get("mscale") and rope_scaling.get("mscale_all_dim"):
+        return (_yarn_m(factor, float(rope_scaling["mscale"]))
+                / _yarn_m(factor, float(rope_scaling["mscale_all_dim"])))
+    return _yarn_m(factor, 1.0)
 
 
 def apply_rope(

@@ -618,6 +618,16 @@ def engine_metrics(registry: Registry) -> dict:
             "over llm_path_tokens_total it is the work done per real "
             "token. 0 for a model without Mamba layers",
             registry, label_names=("path",)),
+        "attn_window_rows": Counter(
+            "llm_attn_window_rows_total",
+            "Rows of cached keys and values in the layers that attend inside "
+            "a sliding window, summed over every planned token step of every "
+            "live row (counted on the host where a decode window is planned): "
+            "cached=rows the pool holds for those layers (layers x length); "
+            "reached=rows those layers can still read (layers x min(length, "
+            "window)). reached over cached is the share of the window "
+            "layers' held rows that any later token can read; 0 for a model "
+            "without window layers", registry, label_names=("rows",)),
         "conv_state_bytes": Gauge(
             "llm_conv_state_bytes",
             "Device bytes of the per-slot state that conv layers (their "
@@ -656,6 +666,8 @@ def engine_metrics(registry: Registry) -> dict:
         m["mla_tokens"].labels(path=path)
         m["path_tokens"].labels(path=path)
         m["ssm_positions"].labels(path=path)
+    for rows in ("cached", "reached"):
+        m["attn_window_rows"].labels(rows=rows)
     for kind in ("prefill", "chunk", "decode"):
         for stat in MOE_STATS:
             m["moe_" + stat].labels(kind=kind)

@@ -390,14 +390,15 @@ class EngineLoop(threading.Thread):
                 # tokens by path, whatever the model; the same under the
                 # latent model's own name, which its benchmark metric reads
                 mla = getattr(eng.model_config, "is_mla", False)
-                counts = {"path_tokens": dict(eng.path_tokens,
-                                              decode=eng.decode_tokens),
-                          "ssm_positions": eng.ssm_positions}
-                for name, by_path in counts.items():
-                    for path, v in by_path.items():
+                counts = {"path_tokens": ("path", dict(
+                              eng.path_tokens, decode=eng.decode_tokens)),
+                          "ssm_positions": ("path", eng.ssm_positions),
+                          "attn_window_rows": ("rows", eng.window_rows)}
+                for name, (label, by_value) in counts.items():
+                    for path, v in by_value.items():
                         new = v - self._path_seen[name, path]
                         if new > 0:
-                            m[name].labels(path=path).inc(new)
+                            m[name].labels(**{label: path}).inc(new)
                             if mla and name == "path_tokens":
                                 m["mla_tokens"].labels(path=path).inc(new)
                             self._path_seen[name, path] = v

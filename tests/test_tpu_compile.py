@@ -782,3 +782,130 @@ def test_jamba_decode_window_keeps_the_mamba_state_in_place(
     assert len(calls) == cfg.num_mamba_layers
     assert all("f32[26,129,16,5120]" in ln.split("custom-call(")[0]
                for ln in calls)
+
+
+# ---------------------------------------------------------------------------
+# mellum2-12b's cell: three window layers to one full layer, 64 experts
+# ---------------------------------------------------------------------------
+
+MELLUM = "mellum2-12b@0-11"
+MELLUM_CELL = dict(slots=48, page=64, pps=144, pages=2561)
+MELLUM_STEPS = {"decode, K = 4": (48, 1), "prefill 1 x 2048": (1, 2048),
+                "prefill 4 x 512": (4, 512), "chunk 1 x 2048": (1, 2048)}
+
+
+def _mellum_step(one_chip, step, cell=MELLUM_CELL):
+    """The compiled step of the cell at its real size, for a described
+    v5e."""
+    from llms_on_kubernetes_tpu.configs import get_config
+    from llms_on_kubernetes_tpu.engine import engine as E
+    from llms_on_kubernetes_tpu.engine.cache import KVPool
+    from llms_on_kubernetes_tpu.models import decoder
+
+    cfg = get_config(MELLUM)
+    slots, page, pps, pages = (cell[k] for k in ("slots", "page", "pps",
+                                                 "pages"))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: decoder.init_params(cfg, jax.random.key(0),
+                                        dtype="bfloat16")))
+    shape = (cfg.num_kv_heads, cfg.num_attn_layers * pages, page,
+             cfg.head_dim)
+    k_pool, v_pool = (KVPool(sds(shape, jnp.bfloat16)) for _ in "kv")
+    counts = sds((slots, cfg.vocab_size), jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    rows, tokens = MELLUM_STEPS[step]
+    if step.startswith("decode"):
+        return cfg, k_pool, jax.jit(
+            E._decode_multi_packed_step, static_argnums=(1, 2),
+            donate_argnums=(6, 7, 8, 11)).lower(
+                params, cfg, 4, sds((rows, E._DEC_COLS + pps), jnp.int32),
+                sds((rows,), jnp.int32), sds((1,), jnp.int32), k_pool,
+                v_pool, counts, key, None, None).compile()
+    fn, cols = ((E._prefill_packed_step, E._PRE_COLS)
+                if step.startswith("prefill")
+                else (E._chunk_packed_step, E._CHK_COLS))
+    return cfg, k_pool, jax.jit(
+        fn, static_argnums=(1,), donate_argnums=(4, 5, 6, 9)).lower(
+            params, cfg, sds((rows, tokens), jnp.int32),
+            sds((rows, cols + pps), jnp.int32), k_pool, v_pool, counts,
+            key, None, None).compile()
+
+
+def _peak(mem) -> int:
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("step", sorted(MELLUM_STEPS))
+def test_mellum_steps_fit_a_v5e_on_kernels_with_static_windows(
+        one_chip, no_cache, monkeypatch, step):
+    """The fused decode window, both buckets and a 2,048-token chunk over a
+    9,216-token slot, at the published widths with the cell's weights
+    (10.93 GB) and pool (4.03 GB): each compiles for a v5e with room to
+    spare, both pools go in and come out in one buffer each, no step
+    copies a pool, and the window and the full layers BOTH take a Pallas
+    kernel (the window a Python int in each): the paged write-and-attend
+    kernel, the flash prefill kernel, the flash chunk kernel."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
+    attention._chosen.clear()
+    cfg, pool, compiled = _mellum_step(one_chip, step)
+    mem = compiled.memory_analysis()
+    hlo = compiled.as_text()
+    print(f"[mellum_compile] {step}: temp {mem.temp_size_in_bytes} B, "
+          f"peak {_peak(mem)} B, {hlo.count(chr(10))} HLO lines")
+    pool_bytes = 4_028_104_704          # K and V together
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert _peak(mem) < V5E_BYTES - 0.5e9, (step, _peak(mem))
+    assert not _pool_shaped_copies(hlo, pool)
+    kind = step.split()[0].rstrip(",")
+    for layers in ("sliding", "full"):
+        impl, why = attention._chosen[f"{kind}_{layers}"]
+        assert impl == "pallas-compiled", (layers, why)
+        if kind == "chunk":
+            assert ("inside a window of 1024" in why) == (layers == "sliding")
+            # a window layer gathers 56 of the slot's 144 pages
+            assert (("3584 of a slot's 9216" if layers == "sliding"
+                     else "a slot's 9216") + " gathered keys") in why
+    assert attention._chosen["experts"][0] == "pallas-compiled"
+    if kind == "chunk":
+        assert hlo.count("flash_chunk_attention") >= cfg.num_attn_layers
+
+
+def test_mellum_xla_chunk_path_leaves_no_room_beside_the_cells_pool(
+        one_chip, no_cache, monkeypatch):
+    """Why the chunk path has a kernel: the XLA gather path holds a layer's
+    scores [4, 8, 2048, 9216] in float32 (2.4 GB; written and read back
+    in each of the 12 layers), so a 2,048-token chunk over the cell's slot
+    peaks at 16.71 GB beside 10.93 GB of weights and a 3.22 GB pool
+    (2,049 pages): under a fifth of a gigabyte from the 16.9 GB a v5e
+    gives a process, where every step on the kernel leaves 2.2 GB or
+    more, which the cell's pool takes 0.8 GB of (2,561 pages)."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setattr(attention, "_chunk_kernel_mode",
+                        lambda *a: (None, "shut", None))
+    monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
+    jax.clear_caches()      # the step's trace is kept by its function
+    try:
+        _cfg, _pool, compiled = _mellum_step(
+            one_chip, "chunk 1 x 2048", dict(MELLUM_CELL, pages=2049))
+    except Exception as e:       # the compiler refuses what cannot fit
+        assert "RESOURCE_EXHAUSTED" in str(e) or "memory" in str(e).lower()
+        return
+    finally:
+        jax.clear_caches()
+    mem = compiled.memory_analysis()
+    print(f"[mellum_compile] xla chunk: temp {mem.temp_size_in_bytes} B, "
+          f"peak {_peak(mem)} B")
+    assert mem.temp_size_in_bytes > 2.4e9
+    assert _peak(mem) > V5E_BYTES - 0.5e9
