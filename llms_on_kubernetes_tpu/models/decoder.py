@@ -28,7 +28,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,9 +37,9 @@ import numpy as np
 from llms_on_kubernetes_tpu.configs import ModelConfig
 from llms_on_kubernetes_tpu.ops.cp import dispatch_write_tokens as write_tokens
 from llms_on_kubernetes_tpu.ops.attention import (
-    dispatch_chunk_attention, dispatch_paged_attention,
-    dispatch_prefill_attention, dispatch_ssm_step, layer_kind, live_first,
-    record_choice, softcap,
+    conv_token_step, dispatch_chunk_attention, dispatch_conv_step,
+    dispatch_paged_attention, dispatch_prefill_attention, dispatch_ssm_step,
+    layer_kind, live_first, live_tiles_first, record_choice, softcap,
 )
 from llms_on_kubernetes_tpu.ops.lora import lora_qeinsum
 from llms_on_kubernetes_tpu.ops.moe import moe_block
@@ -319,8 +319,12 @@ class LayerAux:
     conv layer's short-convolution state of every slot (the last
     ``taps - 1`` gated inputs). For a model with Mamba layers a
     ``MambaState``. Either way the last row is trash, where rows that are
-    padding write. None where the model has no such layer, or for a pass
-    from an empty state whose state nobody keeps (scoring).
+    padding write, and a token step (decode: row i is slot i) shifts a
+    live row's window by one input and leaves an idle row's state as it
+    was, by ONE select made where the window is convolved
+    (``attention.conv_token_step``, or the Mamba layers' kernel over live
+    rows). None where the model has no such layer, or for a pass from an
+    empty state whose state nobody keeps (scoring).
     ``slots`` [B]: the slot of each row (prefill, chunk); None where row i
     IS slot i (decode).
     ``moe_rows`` [n_moe_layers, E] int32, handed BACK: the (token, expert)
@@ -343,7 +347,14 @@ class MambaState:
     ``ssm`` [n_mamba_layers, slots + 1, N, Di] float32: the state-space
     state h, a running sum over the whole sequence. The channels lie on
     the lanes (the published h is [Di, N]: with 16 states on a 128-lane
-    row the TPU's tiled layout would hold eight times the bytes)."""
+    row the TPU's tiled layout would hold eight times the bytes).
+
+    A prompt's pass takes a layer's rows out of both by slot and writes
+    them back. A token step hands both arrays WHOLE to the layer and gets
+    them back (``_mamba``): a live slot's window drops its oldest input
+    and takes the token's as its newest, in one pass over the row where
+    it lies; an idle slot's window and h, and the trash row, are not
+    written."""
     conv: jnp.ndarray
     ssm: jnp.ndarray
 
@@ -453,13 +464,20 @@ def _short_conv(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
     with jax.named_scope("lfm2.conv"):
         bg, cg, xg = jnp.split(qeinsum("btd,de->bte", u, lp["conv_in"]), 3,
                                axis=-1)
-        zext = jnp.concatenate([state.astype(u.dtype), bg * xg], axis=1)
+        z = bg * xg
         w = lp["conv_w"].astype(jnp.float32)                  # [D, taps]
-        c = sum(zext[:, j:j + T].astype(jnp.float32) * w[:, j]
-                for j in range(taps))
+        if T == 1:
+            c, kept = conv_token_step(
+                [state[:, j] for j in range(taps - 1)], z[:, 0], w.T,
+                (n_valid > 0)[:, None])
+            c, new_state = c[:, None], jnp.stack(kept, axis=1)
+        else:
+            zext = jnp.concatenate([state.astype(u.dtype), z], axis=1)
+            c = sum(zext[:, j:j + T].astype(jnp.float32) * w[:, j]
+                    for j in range(taps))
+            at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)
+            new_state = jnp.take_along_axis(zext, at[:, :, None], axis=1)
         out = qeinsum("btd,de->bte", cg * c.astype(u.dtype), lp["conv_out"])
-        at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)
-        new_state = jnp.take_along_axis(zext, at[:, :, None], axis=1)
     return out, new_state
 
 
@@ -492,17 +510,50 @@ def _ssm_scan(delta, A, x, Bm, Cm, h0):
     return jnp.swapaxes(y, 0, 1), h
 
 
+def _mamba_conv(window, x, w, bias, n_valid):
+    """The Mamba layers' convolution at T positions, the general code:
+    window [B, (taps-1) Di] the rows' last taps - 1 inputs, oldest first;
+    x [B, T, Di]; w [taps, Di], bias [Di] float32; n_valid [B]. Returns
+    (silu(the causal depthwise sums + bias) [B, T, Di] in x's type, the
+    window after each row's last real position). A token step does the
+    same in one pass (``attention.dispatch_conv_step``)."""
+    taps, Di = w.shape
+    T = x.shape[1]
+    # the window's taps lie side by side on a row: lane slices in and out
+    # (a reshape to [B, taps - 1, Di] makes the compiler keep the whole
+    # state array transposed inside the decode window and copy it at both
+    # ends)
+    past = [window[:, j * Di:(j + 1) * Di] for j in range(taps - 1)]
+    xext = jnp.concatenate(
+        [jnp.stack(past, axis=1).astype(x.dtype), x], axis=1)
+    c = sum(xext[:, j:j + T].astype(jnp.float32) * w[j] for j in range(taps))
+    xc = jax.nn.silu(c + bias).astype(x.dtype)
+    at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)
+    kept = jnp.take_along_axis(xext, at[:, :, None], axis=1)
+    return xc, jnp.concatenate([kept[:, j] for j in range(taps - 1)], axis=-1)
+
+
+class TokenStep(NamedTuple):
+    """Where a Mamba layer's token step finds its state in the whole
+    arrays, and which of their rows it visits."""
+    layer: jnp.ndarray        # the layer's index among the Mamba layers
+    live_slots: tuple         # ``live_first`` of the rows
+    live_tiles: tuple         # ``live_tiles_first`` of the rows
+
+
 def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
-           state: "MambaState", n_valid: jnp.ndarray, ssm_at=None):
+           state: "MambaState", n_valid: jnp.ndarray, step=None):
     """Jamba's Mamba-1 mixer. u [B, T, D] (normed); ``state``, the rows'
     own: ``conv`` [B, (taps-1) Di] the last taps - 1 convolution inputs
     before u's first, ``ssm`` [B, N, Di] the state-space state h, zeros for
     a fresh sequence; ``n_valid`` [B]: how many of the T positions are
-    real, from the left. In a token step (``ssm_at`` = the layer's index
-    among the Mamba layers and ``live_first`` of the rows) ``ssm`` is the
-    WHOLE array [n_mamba_layers, slots + 1, N, Di], row i is slot i, and
-    the step updates the live slots' blocks where they lie
-    (``dispatch_ssm_step``).
+    real, from the left. In a token step (``step``, a ``TokenStep``; T = 1)
+    ``state`` is the WHOLE arrays, [n_mamba_layers, slots + 1, ...], row i
+    is slot i, and both halves are updated where they lie, the live slots
+    alone where the kernels run: the window read, convolved, shifted by
+    one tap and written back in one pass (``dispatch_conv_step``), h's
+    blocks likewise (``dispatch_ssm_step``); an idle slot's window and h
+    stay as they were.
 
       [x, z] = split2(u W_in)
       x_t <- silu(b_c + sum_j w_c[j] * x_(t - (taps-1) + j))   (depthwise)
@@ -512,11 +563,11 @@ def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
       out = (y * silu(z)) W_out
 
     Returns (out [B, T, D], the rows' state after their last real
-    position; in a token step ``ssm`` is the whole array again). Padding
-    lies to the right of every real position and is given delta = 0:
-    exp(0 A) = 1 and 0 x B = 0, so h passes through a padded step exactly
-    as it was (the other way, a select of old against new h a step, would
-    read h twice)."""
+    position; in a token step the whole arrays again). Padding lies to the
+    right of every real position and is given delta = 0: exp(0 A) = 1 and
+    0 x B = 0, so h passes through a padded step exactly as it was (the
+    other way, a select of old against new h a step, would read h
+    twice)."""
     taps, N, R = cfg.mamba_d_conv, cfg.mamba_d_state, cfg.mamba_dt_rank
     B, T, _ = u.shape
     Di, eps, f32 = cfg.mamba_d_inner, cfg.rms_norm_eps, jnp.float32
@@ -527,21 +578,20 @@ def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
             f"h [{N}, {Di}] float32 the carry: no kernel yet")
     window, h = state.conv, state.ssm
     with jax.named_scope("jamba.mamba"):
-        x, z = jnp.split(qeinsum("btd,de->bte", u, lp["in_proj"]), 2, axis=-1)
-        # the window's taps lie side by side on a row: lane slices in and
-        # out (a reshape to [B, taps - 1, Di] makes the compiler keep the
-        # whole state array transposed inside the decode window and copy it
-        # at both ends)
-        past = [window[:, j * Di:(j + 1) * Di] for j in range(taps - 1)]
-        xext = jnp.concatenate(
-            [jnp.stack(past, axis=1).astype(u.dtype), x], axis=1)
+        xz = qeinsum("btd,de->bte", u, lp["in_proj"])
+        x, z = jnp.split(xz, 2, axis=-1)
         w = lp["conv_w"].astype(f32)                          # [taps, Di]
-        c = sum(xext[:, j:j + T].astype(f32) * w[j] for j in range(taps))
-        xc = jax.nn.silu(c + lp["conv_b"].astype(f32)).astype(u.dtype)
-        at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)
-        kept = jnp.take_along_axis(xext, at[:, :, None], axis=1)
-        window = jnp.concatenate([kept[:, j] for j in range(taps - 1)],
-                                 axis=-1)
+        bias = lp["conv_b"].astype(f32)
+        if step is None:
+            xc, window = _mamba_conv(window, x, w, bias, n_valid)
+            xs = xc.astype(f32)
+        else:
+            # xs: xc as the state-space step reads it (of an idle row
+            # anything: that step visits none)
+            xc, xs, window = dispatch_conv_step(
+                xz[:, 0], w, bias, window, step.layer, n_valid > 0,
+                step.live_tiles)
+            xc, xs = xc[:, None], xs[:, None]
         dt, Bm, Cm = jnp.split(qeinsum("bte,er->btr", xc, lp["x_proj"]),
                                [R, R + N], axis=-1)
         dt = rms_norm(dt, lp["dt_norm"], eps)
@@ -552,15 +602,14 @@ def _mamba(lp: Params, cfg: ModelConfig, u: jnp.ndarray,
             + lp["dt_bias"].astype(f32))
         real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_valid[:, None]
         delta = jnp.where(real[:, :, None], delta, 0.0)
-        xf = xc.astype(f32)
         A = -jnp.exp(lp["A_log"].astype(f32))
-        if ssm_at is None:
-            y, h = _ssm_scan(delta, A, xf, Bm, Cm, h.astype(f32))
+        if step is None:
+            y, h = _ssm_scan(delta, A, xs, Bm, Cm, h.astype(f32))
         else:
-            layer, live_slots = ssm_at
-            y, h = dispatch_ssm_step(delta, A, xf, Bm, Cm, h, layer,
-                                     n_valid > 0, live_slots)
-        y = (y + lp["D"].astype(f32) * xf) * jax.nn.silu(z.astype(f32))
+            y, h = dispatch_ssm_step(delta, A, xs, Bm, Cm, h, step.layer,
+                                     n_valid > 0, step.live_slots)
+        y = (y + lp["D"].astype(f32) * xc.astype(f32)) \
+            * jax.nn.silu(z.astype(f32))
         out = qeinsum("bte,ed->btd", y.astype(u.dtype), lp["out_proj"])
     return out, MambaState(conv=window, ssm=h)
 
@@ -589,7 +638,7 @@ def _layer_step(
                        # a MambaState of rows (Mamba layer)
     n_valid: "jnp.ndarray | None" = None,      # [B] real positions of T
     experts=None,                              # _mlp's, for an expert layer
-    ssm_at=None,       # _mamba's, in a token step: conv_state.ssm is whole
+    token_step=None,   # _mamba's TokenStep: conv_state is the whole arrays
 ):
     """One layer of ``kind`` (operator, feed-forward). Returns (x, k_pages,
     v_pages, a conv or Mamba layer's new state rows or None, the rows each
@@ -599,7 +648,7 @@ def _layer_step(
     if op == "conv":
         out, conv_state = _short_conv(lp, cfg, h, conv_state, n_valid)
     elif op == "mamba":
-        out, conv_state = _mamba(lp, cfg, h, conv_state, n_valid, ssm_at)
+        out, conv_state = _mamba(lp, cfg, h, conv_state, n_valid, token_step)
     elif op == "mla":
         out, k_pages = _latent_attention(
             cfg, inv_freq, page_table, positions, write_positions, lengths,
@@ -841,10 +890,11 @@ def _run_layers(
                     (B, (cfg.mamba_d_conv - 1) * cfg.mamba_d_inner), x.dtype),
                 ssm=jnp.zeros((B, cfg.mamba_d_state, cfg.mamba_d_inner),
                               jnp.float32))
-            # a token step walks the live slots of the whole state-space
-            # array, the same ones in every layer
-            live_slots = live_first(lengths > 0) if mode == "decode" \
-                else None
+            # a token step walks the live rows of the whole arrays, the
+            # same ones in every layer
+            live_rows = (live_first(lengths > 0),
+                         live_tiles_first(lengths > 0)) \
+                if mode == "decode" else None
         else:
             fresh = jnp.zeros((B, cfg.conv_L_cache - 1, x.shape[-1]),
                               x.dtype)
@@ -868,22 +918,22 @@ def _run_layers(
 
         return jax.tree.map(rows, conv)
 
-    def conv_write(conv, ci, old, new):
+    def conv_write(conv, ci, new):
         if conv is None:
             return None
-        live = lengths > 0
 
-        def write(arr, old, new):
-            if mode == "decode":    # idle rows leave their slot as it was
-                rows = jnp.where(per_row(live, new), new, old)
+        def write(arr, new):
+            if mode == "decode":
+                # row i is slot i; an idle row's ``new`` is its slot's state
+                # as it was (the operator's token step selected it)
                 return jax.lax.dynamic_update_slice(
-                    arr, rows.astype(arr.dtype)[None],
+                    arr, new.astype(arr.dtype)[None],
                     (ci, *[0] * (arr.ndim - 1)))
             # padding rows carry no slot: they write the trash row
-            return arr.at[ci, jnp.where(live, slots, trash)].set(
+            return arr.at[ci, jnp.where(lengths > 0, slots, trash)].set(
                 new.astype(arr.dtype))
 
-        return jax.tree.map(write, conv, old, new)
+        return jax.tree.map(write, conv, new)
 
     def run_body(kind, stacks, first, a0, c0):
         """The scan body of one run: its kind, its expert stacks, and the
@@ -901,12 +951,12 @@ def _run_layers(
             else:
                 pt = page_table + a_idx * pages_per_layer
             keeps = op in ("conv", "mamba")
-            # a Mamba layer's token step takes the state-space array whole
-            # and hands it back (dispatch_ssm_step); the convolution's
-            # window goes by rows, as a conv layer's state does
+            # a Mamba layer's token step takes its state arrays whole and
+            # hands them back (dispatch_conv_step, dispatch_ssm_step); a
+            # conv layer's state goes by rows
             whole = op == "mamba" and mode == "decode"
             if whole:
-                old = MambaState(conv=conv_rows(cv.conv, c_idx), ssm=cv.ssm)
+                old = cv
             else:
                 old = conv_rows(cv, c_idx) if keeps else None
             xc, kp, vp, new, rows = _layer_step(
@@ -917,14 +967,12 @@ def _run_layers(
                 adapter_idx=adapter_idx, kind=kind, conv_state=old,
                 n_valid=n_valid if keeps else None,
                 experts=(stacks, i) if stacks else None,
-                ssm_at=(c_idx, live_slots) if whole else None,
+                token_step=TokenStep(c_idx, *live_rows) if whole else None,
             )
             if whole:
-                cv = MambaState(
-                    conv=conv_write(cv.conv, c_idx, old.conv, new.conv),
-                    ssm=new.ssm)
+                cv = new
             elif keeps:
-                cv = conv_write(cv, c_idx, old, new)
+                cv = conv_write(cv, c_idx, new)
             if deepstack is not None:
                 # DeepStack (Qwen3-VL): intermediate vision features are ADDED
                 # to the first n_taps decoder layers' outputs at image-token

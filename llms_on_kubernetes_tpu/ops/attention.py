@@ -1078,8 +1078,9 @@ def dispatch_ssm_step(delta, A, x, Bm, Cm, ssm, layer, live, first):
 
     delta, x [B, 1, Di] float32; A [N, Di]; Bm, Cm [B, 1, N]; ssm
     [n_layers, slots + 1, N, Di] (row i is slot i); ``layer`` its index;
-    live [B] bool; ``first`` = ``live_first(live)``. Returns (y [B, 1, Di]
-    float32, of an idle row zeros or a step nobody keeps; ssm)."""
+    live [B] bool; ``first`` = ``live_first(live)``. An idle row's delta is
+    0 and its x, B and C may be anything (``dispatch_conv_step``). Returns
+    (y [B, 1, Di] float32, zeros for an idle row; ssm)."""
     B, _, Di = delta.shape
     N = A.shape[0]
     mode, why = ssm_step_mode(ssm)
@@ -1093,8 +1094,9 @@ def dispatch_ssm_step(delta, A, x, Bm, Cm, ssm, layer, live, first):
         old = jax.lax.dynamic_index_in_dim(ssm, layer, 0, False)[:B]
         y, h = _ssm_scan(delta, A, x, Bm, Cm, old.astype(jnp.float32))
         rows = jnp.where(live[:, None, None], h, old)
-        return y, jax.lax.dynamic_update_slice(
-            ssm, rows.astype(ssm.dtype)[None], (layer, 0, 0, 0))
+        return (jnp.where(live[:, None, None], y, 0.0),
+                jax.lax.dynamic_update_slice(
+                    ssm, rows.astype(ssm.dtype)[None], (layer, 0, 0, 0)))
 
     from llms_on_kubernetes_tpu.ops.pallas_ssm import pallas_ssm_step
 
@@ -1106,3 +1108,107 @@ def dispatch_ssm_step(delta, A, x, Bm, Cm, ssm, layer, live, first):
                              layer, *first, interpret=mode == "interpret")
     # an idle row's y is whatever its buffer held
     return jnp.where(live[:, None], y, 0.0)[:, None], ssm
+
+
+def conv_token_step(past, x0: jnp.ndarray, w, live: jnp.ndarray):
+    """One token of a causal depthwise convolution over a kept window, in
+    one pass: ``past`` the window's taps - 1 inputs [B, D] each, oldest
+    first; x0 [B, D] the token's input; ``w`` the taps' weights, oldest
+    first, each [D] float32; live [B, 1]. Returns (the sum at the token
+    [B, D] float32, added oldest tap first as the general ``T``-position
+    code adds it; the window after the token, tap by tap in x0's type:
+    shifted by one with x0 last for a live row, as it was for an idle
+    one). That select is the token step's ONE: nothing downstream selects
+    old against new again."""
+    taps = [*(t.astype(x0.dtype) for t in past), x0]
+    c = sum(t.astype(jnp.float32) * w[j] for j, t in enumerate(taps))
+    return c, [jnp.where(live, new, old) for old, new in zip(taps, taps[1:])]
+
+
+def _conv_tile_rows(B: int) -> int:
+    """Rows of a tile of the convolution step kernel at B rows (the
+    interpreter takes rows that no tile divides as one tile)."""
+    from llms_on_kubernetes_tpu.ops.pallas_conv import TILE_ROWS
+
+    return TILE_ROWS if B % TILE_ROWS == 0 else B
+
+
+def live_tiles_first(live: jnp.ndarray):
+    """(tiles [B / rows] int32, n [1] int32): the tiles of rows that hold a
+    live row first, in order, then the others; and how many hold one. What
+    the convolution step kernel visits, made once a step for every
+    layer."""
+    held = live.reshape(-1, _conv_tile_rows(live.shape[0])).any(axis=1)
+    order = jnp.argsort(~held, stable=True).astype(jnp.int32)
+    return order, jnp.sum(held, dtype=jnp.int32).reshape(1)
+
+
+def conv_step_mode(conv, x_dtype, B: int, Di: int):
+    """(mode, why not) for the convolution step kernel
+    (pallas_conv.pallas_conv_step) on the window array ``conv`` [n_layers,
+    slots + 1, (taps - 1) Di] with B rows of x in ``x_dtype``, as
+    ``ssm_step_mode`` is for the state-space step's: from the array's type
+    and widths and the active mesh."""
+    from llms_on_kubernetes_tpu.ops.pallas_conv import TILE_ROWS
+    from llms_on_kubernetes_tpu.parallel.mesh import get_active_mesh
+
+    mode = pallas_mode()
+    if mode is None:
+        return None, _no_pallas_why()
+    mesh = get_active_mesh()
+    if mesh is not None and mesh.size > 1:
+        return None, (f"a mesh of {mesh.size} devices: the kernel walks one "
+                      "chip's windows and is not partitioned")
+    if conv.dtype != x_dtype:
+        return None, f"the window is kept in {conv.dtype}, x comes in {x_dtype}"
+    if mode == "compiled":
+        # Mosaic's tiling: whole tiles of sublanes, whole lanes a tap
+        if conv.dtype.itemsize not in (2, 4):
+            return None, f"no tile of {TILE_ROWS} rows in {conv.dtype}"
+        if B % TILE_ROWS != 0:
+            return None, f"{B} rows are not a multiple of {TILE_ROWS}"
+        if Di % 128 != 0:
+            return None, f"{Di} channels are not a multiple of 128"
+    return mode, ""
+
+
+def dispatch_conv_step(xz, w, b, conv, layer, live, tiles):
+    """One token's convolution of one Mamba layer on the WHOLE window
+    array, in one pass either way: the kernel over the tiles that hold a
+    live row (pallas_conv.pallas_conv_step: a tile of rows through VMEM
+    once, in place; a tile with no live row and the trash row neither read
+    nor written) wherever ``conv_step_mode`` lets it, else the XLA form
+    (``conv_token_step`` on all B rows, written back), with its reason.
+
+    xz [B, 2 Di]: the in-projection's result, x in its first Di lanes;
+    w [taps, Di], b [Di] float32; conv [n_layers, slots + 1, (taps - 1) Di]
+    (row i is slot i); ``layer`` its index; live [B] bool; ``tiles`` =
+    ``live_tiles_first(live)``. Returns (xc = silu(the sum + b) [B, Di] in
+    xz's type; the same in float32 for the state-space step, of an idle row
+    anything; conv)."""
+    B = xz.shape[0]
+    taps, Di = w.shape
+    mode, why = conv_step_mode(conv, xz.dtype, B, Di)
+    if mode is None:
+        record_choice("conv_step", "xla",
+                      f"one pass, T = 1: {B} rows of {taps - 1} taps read, "
+                      f"shifted, selected by liveness and written back; {why}")
+        rows = jax.lax.dynamic_index_in_dim(conv, layer, 0, False)[:B]
+        c, kept = conv_token_step(
+            [rows[:, j * Di:(j + 1) * Di] for j in range(taps - 1)],
+            xz[:, :Di], w, live[:, None])
+        xc = jax.nn.silu(c + b).astype(xz.dtype)
+        rows = jnp.concatenate(kept, axis=-1).astype(conv.dtype)
+        return xc, xc.astype(jnp.float32), jax.lax.dynamic_update_slice(
+            conv, rows[None], (layer, 0, 0))
+
+    from llms_on_kubernetes_tpu.ops.pallas_conv import pallas_conv_step
+
+    record_choice("conv_step", f"pallas-{mode}",
+                  f"live slots only, a tile of {_conv_tile_rows(B)} rows at "
+                  f"a time: {taps - 1} taps of {Di} a row through VMEM once, "
+                  f"convolved and shifted in place, of {B} rows")
+    xc, xf, conv = pallas_conv_step(xz, w, b, conv, layer, live, *tiles,
+                                    interpret=mode == "interpret")
+    # a row of a tile that was not visited is whatever its buffer held
+    return jnp.where(live[:, None], xc, 0), xf, conv

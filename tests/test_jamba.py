@@ -361,8 +361,9 @@ def test_where_the_kernel_cannot_run_the_xla_step_does_and_says_why(
         c["delta"], c["A"], c["x"], c["Bm"], c["Cm"],
         c["ssm"][1, :6].astype(jnp.float32))
     assert after.dtype == c["ssm"].dtype
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_want),
+    np.testing.assert_allclose(np.asarray(y)[live], np.asarray(y_want)[live],
                                rtol=1e-5, atol=1e-5)
+    assert not np.asarray(y)[~live].any()       # as the kernel leaves them
     before = np.array(c["ssm"].astype(jnp.float32))
     after = np.asarray(after.astype(jnp.float32))
     tol = 1e-2 if why == "a bfloat16 state" else 2e-6
@@ -370,6 +371,131 @@ def test_where_the_kernel_cannot_run_the_xla_step_does_and_says_why(
                                rtol=tol, atol=tol)
     before[1, :6][live] = after[1, :6][live]
     np.testing.assert_array_equal(after, before)       # the idle rows
+
+
+# ---------------------------------------------------------------------------
+# the token step's convolution in one pass (``dispatch_conv_step``: the XLA
+# form, and ops/pallas_conv.py interpreted) against the general T-position
+# code at T = 1 (``_mamba_conv``), bit for bit
+# ---------------------------------------------------------------------------
+
+CONV_ROWS = 32      # two of the kernel's tiles of 16 rows
+# the rows that decode at each step of a fused window of K = 4 ("1" live)
+CONV_STEPS = {
+    "all rows live": ["1" * 32] * 4,
+    "some idle, a tile with no live row": ["1011001110100001" + "0" * 16] * 4,
+    "no row live": ["0" * 32] * 2,
+    "a row that stops inside the window": [
+        "1" * 8 + "0" * 24, "1" * 8 + "0" * 24,
+        "1" * 5 + "0" * 27, "1" * 5 + "0" * 27],
+    "a slot freed and taken again": [
+        "1" * 20 + "0" * 12, "1" * 3 + "0" + "1" * 16 + "0" * 12,
+        "refill 3", "1" * 20 + "0" * 12],
+}
+
+
+def wide(a):
+    return np.asarray(a.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(CONV_STEPS))
+def test_the_one_pass_token_step_equals_the_general_code_at_one_position(
+        case, dtype, form, monkeypatch):
+    """``xc`` of every live row and the whole window array (the idle rows,
+    the other layers and the trash row too) after every step of a window,
+    in the type served and in float32. A refill writes a slot's window as
+    a prompt's last chunk would, between two steps."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    if form == "kernel":
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+    layers, layer, taps, Di, B = 3, 1, 4, 256, CONV_ROWS
+    ks = jax.random.split(jax.random.key(len(case)), 4)
+    conv = jax.random.normal(ks[0], (layers, B + 1, (taps - 1) * Di)
+                             ).astype(dtype)
+    w = jax.random.normal(ks[1], (taps, Di)).astype(dtype).astype(jnp.float32)
+    b = jax.random.normal(ks[2], (Di,)).astype(dtype).astype(jnp.float32)
+
+    @jax.jit
+    def one_pass(xz, conv, live):
+        xc, xs, conv = attention.dispatch_conv_step(
+            xz, w, b, conv, jnp.int32(layer), live,
+            attention.live_tiles_first(live))
+        return xc, xs, conv
+
+    @jax.jit
+    def general(xz, conv, live):
+        xc, rows = dec._mamba_conv(conv[layer, :B], xz[:, None, :Di], w, b,
+                                   live.astype(jnp.int32))
+        return xc[:, 0], conv.at[layer, :B].set(rows)
+
+    want = conv
+    for i, rows in enumerate(CONV_STEPS[case]):
+        if rows.startswith("refill"):
+            slot = int(rows.split()[1])
+            fresh = jax.random.normal(jax.random.fold_in(ks[3], 99),
+                                      conv.shape[2:]).astype(dtype)
+            conv, want = (a.at[layer, slot].set(fresh) for a in (conv, want))
+            continue
+        live = np.array([ch == "1" for ch in rows])
+        xz = jax.random.normal(jax.random.fold_in(ks[3], i),
+                               (B, 2 * Di)).astype(dtype)
+        xc, xs, conv = one_pass(xz, conv, jnp.asarray(live))
+        xc_want, want = general(xz, want, jnp.asarray(live))
+        assert attention._chosen["conv_step"][0] == (
+            "pallas-interpret" if form == "kernel" else "xla")
+        assert xc.dtype == conv.dtype == jnp.dtype(dtype)
+        assert xs.dtype == jnp.float32
+        np.testing.assert_array_equal(wide(xc)[live], wide(xc_want)[live])
+        np.testing.assert_array_equal(np.asarray(xs)[live], wide(xc)[live])
+        np.testing.assert_array_equal(wide(conv), wide(want))
+        if form == "kernel":    # rows the kernel never wrote are masked
+            assert not wide(xc)[~live].any()
+
+
+@pytest.mark.parametrize("why,word", [
+    ("a mesh", "a mesh of 2 devices"),
+    ("rows off the tiles", "24 rows are not a multiple of 16"),
+    ("channels off the lanes", "192 channels are not a multiple of 128"),
+    ("a window kept in another type", "kept in float32, x comes in bfloat16"),
+    ("the cpu", "cpu backend"),
+])
+def test_where_the_conv_kernel_cannot_run_the_xla_form_does_and_says_why(
+        why, word, monkeypatch):
+    from llms_on_kubernetes_tpu.ops import attention
+    from llms_on_kubernetes_tpu.parallel import mesh as pmesh
+
+    B, Di, xtype = 32, 256, jnp.float32
+    if why == "a mesh":
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+        monkeypatch.setattr(pmesh, "_ACTIVE_MESH", jax.sharding.Mesh(
+            np.array(jax.devices()[:2]), (pmesh.AXIS_MODEL,)))
+    elif why.endswith("the tiles"):
+        monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+        B = 24
+    elif why.endswith("the lanes"):
+        monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+        Di = 192
+    elif why.endswith("another type"):
+        monkeypatch.setenv("LLMK_ATTENTION_IMPL", "pallas")
+        xtype = jnp.bfloat16
+    ks = jax.random.split(jax.random.key(0), 3)
+    conv = jax.random.normal(ks[0], (2, B + 1, 3 * Di))
+    xz = jax.random.normal(ks[1], (B, 2 * Di)).astype(xtype)
+    w, b = jax.random.normal(ks[2], (4, Di)), jnp.zeros((Di,))
+    live = jnp.arange(B) % 3 != 0
+    xc, _, after = jax.jit(lambda *a: attention.dispatch_conv_step(*a))(
+        xz, w, b, conv, jnp.int32(1), live, attention.live_tiles_first(live))
+    impl, said = attention._chosen["conv_step"]
+    assert impl == "xla" and said.startswith("one pass, T = 1") \
+        and word in said, said
+    xc_want, rows = jax.jit(dec._mamba_conv)(
+        conv[1, :B], xz[:, None, :Di], w, b, live.astype(jnp.int32))
+    np.testing.assert_array_equal(wide(xc), wide(xc_want[:, 0]))
+    np.testing.assert_array_equal(
+        wide(after), wide(conv.at[1, :B].set(rows.astype(conv.dtype))))
 
 
 # ---------------------------------------------------------------------------

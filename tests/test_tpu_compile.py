@@ -718,26 +718,16 @@ def test_latent_flash_kernel_is_given_the_vmem_the_dispatcher_counted(
             <= padded_qr + gathered + (2 << 20))
 
 
-def test_jamba_decode_window_keeps_the_mamba_state_in_place(
-        one_chip, no_cache, monkeypatch):
-    """The fused K = 4 decode window of jamba2-3b at its cell's sizes (6.06
-    GB of weights, a 0.27 GB pool, 1.2 GB of per-slot Mamba state): it
-    compiles for a v5e with half the chip to spare, the state goes in and
-    comes out in the buffers it came in, no step copies the float32
-    state-space state (one copy a step would be the whole cell), the
-    state-space step is the kernel over the live slots on that array in
-    place (26 of them a step), and the paged decode kernel takes one KV
-    head under 20 query heads."""
+def _decode_window(one_chip, model, slots, pps, pool_shape):
+    """(cfg, the per-slot state's shapes, the compiled fused K = 4 decode
+    window) of a model that keeps slot state, at a cell's sizes, for a
+    described v5e."""
     from llms_on_kubernetes_tpu.configs import get_config
     from llms_on_kubernetes_tpu.engine import engine as E
     from llms_on_kubernetes_tpu.engine.cache import KVPool
     from llms_on_kubernetes_tpu.models import decoder
-    from llms_on_kubernetes_tpu.ops import attention
 
-    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
-    monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
-    cfg = get_config("jamba2-3b")
-    slots, page, pps, pages = 128, 64, 32, 4097
+    cfg = get_config(model)
 
     def sds(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -750,16 +740,51 @@ def test_jamba_decode_window_keeps_the_mamba_state_in_place(
                                     dtype="bfloat16")))
     state = jax.tree_util.tree_map(sds, jax.eval_shape(
         lambda: decoder.init_conv_state(cfg, slots, "bfloat16")))
-    pool = KVPool(shaped((1, cfg.num_attn_layers * pages, page, 128),
-                         jnp.bfloat16))
+    pool = KVPool(shaped(pool_shape, jnp.bfloat16))
     key = sds(jax.eval_shape(lambda: jax.random.key(0)))
-    compiled = jax.jit(
+    return cfg, state, jax.jit(
         E._decode_multi_packed_step, static_argnums=(1, 2),
         donate_argnums=(6, 7, 8, 11)).lower(
             params, cfg, 4, shaped((slots, E._DEC_COLS + pps), jnp.int32),
             shaped((slots,), jnp.int32), shaped((1,), jnp.int32), pool, pool,
             shaped((slots, cfg.vocab_size), jnp.int32), key, None,
             state).compile()
+
+
+def _scheduled_ops(hlo):
+    """The lines of an optimized HLO text that are ops of their own on the
+    device: every computation's but a fusion's body's, less the ones that
+    move nothing."""
+    out, fused = [], False
+    for ln in hlo.splitlines():
+        if ln.endswith("{") and ("(" in ln) and not ln.startswith(" "):
+            fused = "fused_computation" in ln
+        elif not fused and " = " in ln and not any(
+                f" {op}(" in ln for op in ("parameter", "bitcast", "tuple",
+                                           "get-tuple-element", "while")):
+            out.append(ln)
+    return out
+
+
+def test_jamba_decode_window_keeps_the_mamba_state_in_place(
+        one_chip, no_cache, monkeypatch):
+    """The fused K = 4 decode window of jamba2-3b at its cell's sizes (6.06
+    GB of weights, a 0.27 GB pool, 1.2 GB of per-slot Mamba state): it
+    compiles for a v5e with half the chip to spare, the state goes in and
+    comes out in the buffers it came in, no step copies the float32
+    state-space state (one copy a step would be the whole cell), the
+    state-space step is the kernel over the live slots on that array in
+    place (26 of them a step), the convolution window's step the kernel
+    over the live tiles of rows on ITS array in place (26 more) with no
+    other op over an array of the window's shape, and the paged decode
+    kernel takes one KV head under 20 query heads."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
+    cfg, state, compiled = _decode_window(
+        one_chip, "jamba2-3b", slots=128, pps=32,
+        pool_shape=(1, 2 * 4097, 64, 128))
     mem = compiled.memory_analysis()
     state_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(state))
@@ -782,6 +807,64 @@ def test_jamba_decode_window_keeps_the_mamba_state_in_place(
     assert len(calls) == cfg.num_mamba_layers
     assert all("f32[26,129,16,5120]" in ln.split("custom-call(")[0]
                for ln in calls)
+    # the convolution window's token step: one kernel a Mamba layer on the
+    # window array in place, and nothing else of the program touches an
+    # array of the window's shape (the general T-position code at T = 1 ran
+    # ten scheduled ops a layer over them, a re-layout copy among them; the
+    # XLA one-pass form runs four: PERF.md section 6, PR 52)
+    assert attention._chosen["conv_step"][0] == "pallas-compiled"
+    window = ("bf16[128,4,5120]", "bf16[128,3,5120]", "bf16[128,15360]",
+              "bf16[1,128,15360]", "bf16[26,129,15360]")
+    scheduled = _scheduled_ops(hlo)
+    assert not [ln for ln in scheduled
+                if (" copy(" in ln or " copy-start(" in ln)
+                and any(sh in ln.split(" copy")[0] for sh in window)]
+    calls = [ln for ln in scheduled if "conv_step_live_tiles" in ln
+             and "custom-call(" in ln]
+    assert len(calls) == cfg.num_mamba_layers
+    assert all("bf16[26,129,15360]" in ln.split("custom-call(")[0]
+               and "output_to_operand_aliasing={{2}: (8, {})}" in ln
+               for ln in calls)
+
+    def result(ln):     # the type of what the op writes, a tuple's whole
+        rhs = ln.split(" = ", 1)[1]
+        return rhs.split(") ", 1)[0] if rhs.startswith("(") \
+            else rhs.split("(", 1)[0]
+
+    others = [ln for ln in scheduled if ln not in calls
+              and any(sh in result(ln) for sh in window[:4])]
+    assert not others, others[:3]
+
+
+def test_lfm2_conv_layers_take_the_one_pass_token_step(one_chip, no_cache,
+                                                       monkeypatch):
+    """The fused K = 4 decode window of two conv layers and an attention
+    layer of lfm2-24b-a2b at its cell's sizes (64 slots, state
+    ``bf16[n, 65, 2, 2048]``): a conv layer's token step reads its rows
+    out of the state array, selects once and writes them back: two
+    scheduled ops a layer whose result has the state's shape where the
+    general T-position code at T = 1 ran four (the taps joined with the
+    input to ``bf16[64, 3, 2048]``, a gather by ``n_valid``, a reshape, a
+    second select), and no array of taps + 1 positions exists."""
+    from llms_on_kubernetes_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
+    monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
+    cfg, state, compiled = _decode_window(
+        one_chip, "lfm2-24b-a2b@4-6", slots=64, pps=32,
+        pool_shape=(4, 2049, 64, 128))
+    assert cfg.layer_types == ("conv", "conv", "full_attention")
+    assert state.shape == (2, 65, 2, 2048)
+    hlo = compiled.as_text()
+    scheduled = _scheduled_ops(hlo)
+    assert not [ln for ln in scheduled if "bf16[64,3,2048]" in ln]
+    shapes = ("bf16[64,2,2048]", "bf16[1,64,2,2048]", "bf16[2,65,2,2048]")
+    # (the whole array is copied at the window's two ends, as it was)
+    over = [ln for ln in scheduled if " copy(" not in ln
+            and any(sh in ln.split(" = ", 1)[1].split("(", 1)[0]
+                    for sh in shapes)]
+    assert len(over) <= 2 * cfg.num_conv_layers, over
+    assert not [ln for ln in over if " reshape(" in ln or " gather(" in ln]
 
 
 # ---------------------------------------------------------------------------

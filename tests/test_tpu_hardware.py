@@ -1181,6 +1181,89 @@ def test_jamba_cell_ssm_step_kernel_beside_the_xla_step(monkeypatch):
     assert part["kernel_step_ms"] * 1.8 <= part["xla_step_ms"]
 
 
+def test_jamba_cell_conv_step_kernel_beside_the_xla_form(monkeypatch):
+    """The Mamba layers' convolution window in a token step at the
+    ``jamba2-3b.long-answers`` cell's shape (``bf16[26,129,15360]``, 128
+    rows), alone: all 26 layers' steps in one executable on the donated
+    array, the kernel over the tiles that hold a live row
+    (ops/pallas_conv.py) and the XLA one-pass form it stands beside, with
+    56 rows live in the lowest slots (as the engine fills them), 56
+    scattered, and all 128; and what the two leave in the windows and in
+    ``xc``."""
+    import time
+
+    from llms_on_kubernetes_tpu.ops import attention
+
+    L, S, Di = (SSM_CELL[k] for k in ("layers", "slots", "Di"))
+    taps = 4
+    ks = jax.random.split(jax.random.key(52), 4)
+    xz = jax.random.normal(ks[1], (L, S, 2 * Di), jnp.bfloat16)
+    w = jax.random.normal(ks[2], (L, taps, Di), jnp.float32)
+    b = jax.random.normal(ks[3], (L, Di), jnp.float32)
+    rng = np.random.default_rng(52)
+    lives = {"56 lowest": np.arange(S) < 56, "56 scattered": np.zeros(S, bool),
+             "128": np.ones(S, bool)}
+    lives["56 scattered"][rng.permutation(S)[:56]] = True
+
+    def fresh():
+        return jax.random.normal(ks[0], (L, S + 1, (taps - 1) * Di),
+                                 jnp.bfloat16)
+
+    def layers(conv, live, xz, w, b):
+        tiles = attention.live_tiles_first(live)
+        xcs = []
+        for l in range(L):
+            xc, xs, conv = attention.dispatch_conv_step(
+                xz[l], w[l], b[l], conv, jnp.int32(l), live, tiles)
+            xcs.append(xc.astype(jnp.float32) + xs)
+        return jnp.stack(xcs), conv
+
+    def run(fn):
+        out = {}
+        for name, mask in lives.items():
+            live = jnp.asarray(mask)
+            xc, conv = fn(fresh(), live, xz, w, b)  # the first call compiles
+            got = np.asarray(xc), np.asarray(conv.astype(jnp.float32))
+            t0 = time.perf_counter()
+            for _ in range(20):
+                xc, conv = fn(conv, live, xz, w, b)
+            jax.block_until_ready((xc, conv))
+            out[name] = (*got, (time.perf_counter() - t0) / 20 * 1e3)
+        return out
+
+    with monkeypatch.context() as m:    # the choice is made at the trace
+        m.setattr(attention, "conv_step_mode", lambda *a: (None, "shut"))
+        xla = run(jax.jit(lambda *a: layers(*a), donate_argnums=(0,)))
+        assert attention._chosen["conv_step"][0] == "xla"
+    kernel = run(jax.jit(lambda *a: layers(*a), donate_argnums=(0,)))
+    assert attention._chosen["conv_step"][0] == "pallas-compiled"
+    before, said = np.asarray(fresh().astype(jnp.float32)), {}
+    for name, mask in lives.items():
+        (x0, c0, ms0), (x1, c1, ms1) = xla[name], kernel[name]
+        # the windows are moved, not computed: bit for bit, and an idle
+        # slot's and the trash row's what they were
+        np.testing.assert_array_equal(c1, c0)
+        np.testing.assert_array_equal(c1[:, :S][:, ~mask],
+                                      before[:, :S][:, ~mask])
+        np.testing.assert_array_equal(c1[:, S], before[:, S])
+        # xc: a bfloat16 ulp where the two round silu's last bit apart
+        np.testing.assert_allclose(x1[:, mask], x0[:, mask], rtol=2 ** -7,
+                                   atol=2 ** -7)
+        tiles = int(mask.reshape(-1, 16).any(axis=1).sum())
+        said[name] = {
+            "xla_form_ms": round(ms0, 3), "kernel_ms": round(ms1, 3),
+            "kernel_us_a_layer": round(ms1 / L * 1e3, 2),
+            "tiles_visited": tiles,
+            "kernel_GB_s": round(tiles * 16 * L * (6 * Di * 2 + Di * 8)
+                                 / ms1 / 1e6, 1),
+            "xc_rows_that_differ": int(
+                (x1[:, mask] != x0[:, mask]).any(axis=-1).sum())}
+    _report("pr52_conv_step", said)
+    assert said["128"]["kernel_ms"] <= said["128"]["xla_form_ms"]
+    assert said["56 lowest"]["kernel_ms"] * 1.5 \
+        <= said["56 lowest"]["xla_form_ms"]
+
+
 # name -> (bucket, rows attended, history, calls chained in one executable):
 # the cell's two buckets over their own rows, and a 2,048-token chunk over
 # the slot's gathered 144 pages behind 2,048 and 6,144 tokens of history
