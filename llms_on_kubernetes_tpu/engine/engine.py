@@ -43,7 +43,7 @@ from llms_on_kubernetes_tpu.engine import jit_events
 from llms_on_kubernetes_tpu.engine.cache import (
     CacheConfig, HostKVCache, PageAllocator, init_pages,
 )
-from llms_on_kubernetes_tpu.engine.ledger import DECODE_LAUNCH_RULES
+from llms_on_kubernetes_tpu.engine.ledger import DECODE_LAUNCH_RULES, SAMPLERS
 from llms_on_kubernetes_tpu.engine.qos import (
     TenantFairQueue, normalize_priority, priority_rank,
 )
@@ -776,14 +776,27 @@ def _merge_tokens(last_toks, src, vals, prefill_toks, prefill_row):
 
 def _count_decode_tokens(counts, tokens, active):
     """counts[b, tokens[b]] += active[b] — unrolled DUS (in-place; an HLO
-    scatter would copy the [B, V] buffer, see cache.write_tokens)."""
+    scatter would copy the [B, V] buffer, see cache.write_tokens), and none
+    of its 2 x B one-element ops in a token step where no row is active.
+
+    A row's counts are read by that row's own penalties alone, a request's
+    penalties never change while it lives, and a slot's counts are reset
+    where a request is admitted to it (_rebuild_count_rows: every prefill
+    row, a chunked prompt's first chunk; a resumed request replays its
+    outputs through those). So the callers pass as ``active`` the live rows
+    that ASK for a penalty (_window_asks), and a slot whose request asks for
+    none keeps whatever its counts held."""
     B = counts.shape[0]
-    active = active.astype(counts.dtype)
-    for b in range(B):
-        cur = jax.lax.dynamic_slice(counts, (b, tokens[b]), (1, 1))
-        counts = jax.lax.dynamic_update_slice(
-            counts, cur + active[b], (b, tokens[b]))
-    return counts
+
+    def count(counts):
+        inc = active.astype(counts.dtype)
+        for b in range(B):
+            cur = jax.lax.dynamic_slice(counts, (b, tokens[b]), (1, 1))
+            counts = jax.lax.dynamic_update_slice(
+                counts, cur + inc[b], (b, tokens[b]))
+        return counts
+
+    return jax.lax.cond(active.any(), count, lambda counts: counts, counts)
 
 
 def _rebuild_count_rows(counts, tokens, slots, history, prompt_len, lengths,
@@ -904,6 +917,23 @@ _BIAS_DEC = _STOP_DEC + STOP_SLOTS
 _DEC_COLS = _BIAS_DEC + 2 * LOGIT_BIAS_SLOTS
 
 
+def _window_asks(packed):
+    """What a decode window's packed rows ask of the sampler beyond the
+    plain draw: ``(penalised [B] bool, shaped scalar bool)``. A row counts
+    if it is live (length > 0: an idle slot and a row that rides masked
+    have 0); it is penalised if its presence or frequency penalty is not
+    zero, and the window is shaped if a row that counts is penalised or
+    carries a logit_bias entry (an id >= 0). ONE predicate for the
+    executable (jnp rows) and for the host's booking of the same rows
+    (numpy): a float is != 0 exactly where its bits less the sign are, so
+    -0.0 is zero on both sides and no flush-to-zero rule can part them."""
+    live = packed[:, 0] > 0
+    penalised = live & ((packed[:, 8:10] & 0x7FFFFFFF) != 0).any(axis=1)
+    biased = (packed[:, _BIAS_DEC:_BIAS_DEC + LOGIT_BIAS_SLOTS] >= 0).any(
+        axis=1)
+    return penalised, (penalised | (live & biased)).any()
+
+
 def _forward(forward, cfg, conv, slots, *args, **kw):
     """``forward(*args, **kw)`` -> (logits, k_pages, v_pages, aux): with a
     ``LayerAux`` for a model with conv or Mamba layers (their per-slot
@@ -959,10 +989,13 @@ def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
     accumulating, and its input token freezes so the host-side replay
     stays deterministic. The host (_emit) remains authoritative for
     finishes — the device mask can only under-run, never over-run, the
-    stream. Grammar rows ride the loop: the FSM state is scan carry,
-    masked+advanced per iteration. So does ``conv``, the conv layers'
-    per-slot state (None for a model without them): a masked row leaves
-    its slot's state where its last live step put it."""
+    stream. The penalty counts are kept, and penalties and logit_bias
+    applied, only in a window where a live row asks for one (_window_asks:
+    decided here from the packed rows, inside this one executable; the
+    same bits either way). Grammar rows ride the loop: the FSM state is
+    scan carry, masked+advanced per iteration. So does ``conv``, the conv
+    layers' per-slot state (None for a model without them): a masked row
+    leaves its slot's state where its last live step put it."""
     lengths0 = packed[:, 0]
     src, vals = packed[:, 1], packed[:, 2]
     top_ks = packed[:, 3]
@@ -987,13 +1020,14 @@ def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
     else:
         state0 = jnp.zeros_like(lengths0)
     alive0 = (lengths0 > 0) & (budget > 0)
+    penalised, shaped = _window_asks(packed)
 
     def body(carry, j):
         cur, alive, state, k_pages, v_pages, counts, conv = carry
         lengths = jnp.where(alive, lengths0 + j, 0)
         # the input token is always a previously-sampled OUTPUT token:
         # count it before sampling so this iteration's draw sees it
-        counts = _count_decode_tokens(counts, cur, lengths > 0)
+        counts = _count_decode_tokens(counts, cur, (lengths > 0) & penalised)
         logits, k_pages, v_pages, aux = _forward(
             forward_decode, cfg, conv, None,
             params, cfg, cur, lengths, k_pages, v_pages, page_table,
@@ -1006,7 +1040,7 @@ def _decode_multi_packed_step(params, cfg, K, packed, last_toks,
             allowed, nxt_all, constrained = _fsm_apply(fsm, g_rows, state)
         res = sample(logits, keys, temps, top_ks, top_ps,
                      penalties=(presence, frequency, counts), bias=bias,
-                     allowed=allowed)
+                     allowed=allowed, shaped=shaped)
         new_toks = jnp.where(alive, res.tokens, cur)
         if fsm is not None:
             state = jnp.where(constrained & alive,
@@ -1077,6 +1111,7 @@ def _decode_spec_packed_step(params, cfg, K, packed, k_pages, v_pages,
     else:
         state0 = jnp.zeros_like(lengths0)
     alive0 = (lengths0 > 0) & (budget > 0)
+    penalised, shaped = _window_asks(packed)
 
     # verify window: committed token + drafts; write length w covers only
     # prefix-contiguous drafts and never exceeds the planned page budget
@@ -1098,14 +1133,14 @@ def _decode_spec_packed_step(params, cfg, K, packed, k_pages, v_pages,
         lengths = jnp.where(alive, lengths0 + j, 0)
         # the input token is always a previously-committed OUTPUT token:
         # count it before sampling so this iteration's draw sees it
-        counts = _count_decode_tokens(counts, cur, lengths > 0)
+        counts = _count_decode_tokens(counts, cur, (lengths > 0) & penalised)
         keys = _slot_keys(base_key, seeds, lengths)
         allowed = nxt_all = constrained = None
         if fsm is not None:
             allowed, nxt_all, constrained = _fsm_apply(fsm, g_rows, state)
         res = sample(logits_all[:, j], keys, temps, top_ks, top_ps,
                      penalties=(presence, frequency, counts), bias=bias,
-                     allowed=allowed)
+                     allowed=allowed, shaped=shaped)
         new_toks = jnp.where(alive, res.tokens, cur)
         if fsm is not None:
             state = jnp.where(constrained & alive,
@@ -1776,6 +1811,9 @@ class Engine:
         # decode steps launched, by the rule that launched each; the
         # serving loop drains it into llm_decode_launches_total{when}
         self.decode_launches = dict.fromkeys(DECODE_LAUNCH_RULES, 0)
+        # decode windows launched, by what their rows asked of the sampler
+        # (_book_sampler); drained into llm_decode_windows_total{sampler}
+        self.decode_windows = dict.fromkeys(SAMPLERS, 0)
         # watchdog: set by _shed_wedged() when a device step exceeded the
         # stall budget; a wedged engine rejects submissions (the server
         # flips readiness and a restart is the only recovery)
@@ -2306,7 +2344,8 @@ class Engine:
 
     @contextlib.contextmanager
     def _dispatch(self, kind: str, name: str, shape: str,
-                  rows: Optional[list] = None, positions: int = 0):
+                  rows: Optional[list] = None, positions: int = 0,
+                  sampler: str = ""):
         """Around the jitted call(s) of ONE device dispatch: opens its
         ledger record, puts ``llmk.dispatch`` with its kind and seq on the
         profiler's timeline, and on the way out stamps the launch (the
@@ -2314,7 +2353,8 @@ class Engine:
         process re-traced meanwhile. Yields the dispatch's seq.
         ``positions``: how many positions every layer runs over (rows x
         bucket, or K x the rows a token step visits: ``_ssm_rows``),
-        booked for a model with Mamba layers."""
+        booked for a model with Mamba layers. ``sampler``: a decode
+        window's (``_book_sampler``), for its record."""
         seq = next(self._dispatch_seq)
         # without the ledger nothing is attributed to a request (rows) and
         # nothing counts the process's compiles
@@ -2327,6 +2367,7 @@ class Engine:
         if self.model_config.num_mamba_layers:
             self.ssm_positions[kind] += positions
             rec.ssm_positions = positions
+        rec.sampler = sampler
         try:
             with _phase("llmk.dispatch", kind=kind, seq=seq):
                 yield seq
@@ -2340,6 +2381,16 @@ class Engine:
 
             jlog("dispatch_retraced", kind=kind, step=name, shape=shape,
                  seq=seq, seconds=round(rec.enqueue_ms / 1000.0, 3))
+
+    def _book_sampler(self, packed: np.ndarray) -> str:
+        """Count a decode window about to be launched with these packed
+        rows under what they ask of the sampler, "plain" or "shaped": the
+        executable's own predicate on the same rows (_window_asks), so the
+        count says how often a window's token steps skipped the penalty
+        counts, the penalties and the bias scatter."""
+        sampler = SAMPLERS[int(_window_asks(packed)[1])]
+        self.decode_windows[sampler] += 1
+        return sampler
 
     def _count_window_rows(self, first_lengths: dict, plan: dict) -> None:
         """Book a planned decode window's token steps in ``window_rows``:
@@ -3384,7 +3435,8 @@ class Engine:
         self._mh_send(MSG_DECODE, dec_packed=packed, fsm_used=use_fsm)
         with self._dispatch("decode", "_decode_multi_packed_step",
                             f"1x{len(active)}",
-                            positions=self._ssm_rows(len(active))) as dseq:
+                            positions=self._ssm_rows(len(active)),
+                            sampler=self._book_sampler(packed)) as dseq:
             (pack, self._unread_toks, self.k_pages, self.v_pages,
              self.token_counts, new_state,
              self.conv_state) = self._decode_multi(
@@ -3717,8 +3769,8 @@ class Engine:
         with self._dispatch("decode", "_decode_multi_packed_step",
                             f"{K}x{len(active)}",
                             positions=K * self._ssm_rows(sum(
-                                plan.get(i, 0) > 0 for i, _r in active))
-                            ) as dseq:
+                                plan.get(i, 0) > 0 for i, _r in active)),
+                            sampler=self._book_sampler(packed)) as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
              new_state, self.conv_state) = self._decode_multi(
                 self.params, self.model_config, K, jnp.asarray(packed),
@@ -3799,7 +3851,8 @@ class Engine:
 
         use_fsm = self._fsm_any_active()
         with self._dispatch("spec", "_decode_spec_packed_step",
-                            f"{K}x{len(active)}") as dseq:
+                            f"{K}x{len(active)}",
+                            sampler=self._book_sampler(full)) as dseq:
             (pack, toks, self.k_pages, self.v_pages, self.token_counts,
              new_state) = self._decode_spec(
                 self.params, self.model_config, K, jnp.asarray(full),

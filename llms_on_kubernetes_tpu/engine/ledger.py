@@ -89,6 +89,12 @@ IDLE_HOSTS = ("no_work", "compile", "scheduling")
 # whose sampled tokens it merges; "depth" = as soon as the pipeline had
 # room, where the launch cannot be timed
 DECODE_LAUNCH_RULES = ("timed", "late", "admission", "depth")
+# what a decode window's rows ask of the sampler (Engine.decode_windows,
+# llm_decode_windows_total{sampler}): "shaped" = a live row carries a
+# presence or frequency penalty or a logit_bias entry, so every token step
+# of the window keeps the penalty counts, applies the penalties and scatters
+# the biases; "plain" = none does, and the window's steps skip all three
+SAMPLERS = ("plain", "shaped")
 # what the engine books of the expert layers (Engine.moe_stats,
 # llm_moe_<stat>_total{kind}), per kind of dispatch and summed over its
 # token steps and expert layers: experts HELD here that got at least one
@@ -243,6 +249,9 @@ class Dispatch:
     # layer; Engine._dispatch sets it); ``tokens`` is the real ones among
     # them
     ssm_positions: int = 0
+    # a decode window's sampler, as the host booked it from the packed rows
+    # (Engine._book_sampler): "plain" | "shaped"; "" for every other kind
+    sampler: str = ""
 
     def to_dict(self, t0: float) -> dict:
         """JSON view; times in ms since ``t0`` (the ledger's first launch)."""
@@ -261,6 +270,8 @@ class Dispatch:
         if self.ssm_positions:
             d["ssm_tokens"] = self.tokens
             d["ssm_positions"] = self.ssm_positions
+        if self.sampler:
+            d["sampler"] = self.sampler
         return d
 
 

@@ -10,6 +10,18 @@ sampled token ids ([B] int32) come back to the host each step. Greedy is
 expressed as temperature==0 via masking, not Python branching, so one
 executable covers all modes.
 
+Penalties and ``logit_bias`` are paid for by the token steps that hold a
+row asking for one, and by no other. They are the rare request (an OpenAI
+client leaves them at their defaults), and each is work over [slots, vocab]:
+the counts' update, the penalty arithmetic reading the counts, the bias
+scatter and the copy of the logits it forces (about 0.5 ms of a 10 ms token
+step at 128 x 65,536 on a v5e). ``sample(..., shaped=)`` takes a traced
+scalar (``engine._window_asks`` of the decode window's own packed rows: a
+live row with a nonzero penalty or a bias id) and puts the transforms AND
+the candidate extraction in one ``lax.cond``, so only [B, 64] and [B, 1]
+arrays leave it: still one executable, no flag, and the same bits for every
+row on either branch. The prompt paths, once a prompt, always shape.
+
 Top-k/top-p work on a FIXED top-MAX_CANDIDATES candidate set. On TPU the
 set is extracted with ``lax.approx_max_k`` (the hardware-native bucketed
 reduction; exact ``lax.top_k`` measured 2.6 ms/step for a 128K vocab on
@@ -51,6 +63,57 @@ MAX_CANDIDATES = 64
 LOGPROB_TOPK = 8
 
 
+def _shape_logits(logits, penalties, bias):
+    """The optional logit transforms on float32 ``logits`` [B, V]: the
+    penalties over ``counts``, then the ``logit_bias`` scatter. For a row
+    with zero penalties and no bias entry both are the identity
+    (``x - 0*... - 0*...``, ``+ 0.0``)."""
+    B = logits.shape[0]
+    if penalties is not None:
+        presence, frequency, counts = penalties
+        c = counts.astype(jnp.float32)
+        logits = logits - presence[:, None] * (c > 0) - frequency[:, None] * c
+    if bias is not None:
+        b_ids, b_vals = bias
+        rows = jnp.broadcast_to(
+            jnp.arange(B, dtype=jnp.int32)[:, None], b_ids.shape)
+        # padding id -1 would WRAP to column V-1 (jax normalizes negative
+        # indices before mode="drop" applies — verified), so zero the
+        # padded values explicitly; mode="drop" still guards any
+        # out-of-range positive id
+        b_vals = jnp.where(b_ids >= 0, b_vals.astype(jnp.float32), 0.0)
+        logits = logits.at[rows, jnp.maximum(b_ids, 0)].add(
+            b_vals, mode="drop")
+    return logits
+
+
+def _candidates(logits, allowed):
+    """(cand_logits [B, C], cand_idx [B, C], lse [B, 1]) of float32
+    ``logits`` [B, V]: the candidate set, sorted descending, and the
+    log-sum-exp over the FULL vocabulary (so probabilities and the top-p
+    cut are computed against the true distribution, not the truncated
+    one). Everything of the sampler that reads a [B, V] array is in here
+    or in ``_shape_logits``; what comes out is [B, 64] and [B, 1]."""
+    V = logits.shape[1]
+    if allowed is not None:
+        logits = jnp.where(allowed, logits, NEG_INF)
+    C = min(MAX_CANDIDATES, V)
+    # TPU: approx_max_k is the hardware-native bucketed reduction (exact
+    # top_k measured 2.6 ms/step at 128K vocab; approx ~free). Recall
+    # caveats and the greedy-exactness argument: module docstring.
+    exact = os.environ.get("LLMK_EXACT_SAMPLING", "0") == "1"
+    if jax.default_backend() == "tpu" and V > 4 * C and not exact:
+        cand_logits, cand_idx = jax.lax.approx_max_k(logits, C)
+    else:
+        cand_logits, cand_idx = jax.lax.top_k(logits, C)     # [B, C] each
+    lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)   # [B, 1]
+    # the barrier keeps XLA from hoisting the branches' common tail out of
+    # sample()'s conditional: compiled for a v5e without it, the exp-sum
+    # and the final top-k ran OUTSIDE, and each branch handed them the
+    # logits and the row maxima broadcast to [B, V] (two [B, V] outputs)
+    return jax.lax.optimization_barrier((cand_logits, cand_idx, lse))
+
+
 def sample(
     logits: jnp.ndarray,       # [B, V] float32
     keys: jax.Array,           # [B] PRNG keys (one per slot) or one scalar key
@@ -60,6 +123,7 @@ def sample(
     penalties: "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray] | None" = None,
     bias: "tuple[jnp.ndarray, jnp.ndarray] | None" = None,
     allowed: "jnp.ndarray | None" = None,
+    shaped: "jnp.ndarray | None" = None,
 ) -> "SampleResult":
     """Returns a SampleResult (tokens, chosen logprobs, top-K alternatives).
 
@@ -78,49 +142,37 @@ def sample(
     +100 bias forces and a -100 bias bans, vLLM semantics). Padding
     entries carry id -1 and are dropped by the scatter.
 
+    ``shaped`` (a traced scalar bool; the decode window's comes from
+    ``engine._window_asks``): whether any row that counts asks for a
+    penalty or a bias. False takes the candidates from the logits as they
+    came and touches neither ``counts`` nor the scatter: ONE ``lax.cond``
+    inside the one executable, whose branches return only the [B, 64]
+    candidates and the [B, 1] log-sum-exp. Both branches give a row
+    without penalties or bias the same bits. None (the prompt paths, once
+    a prompt): always shaped.
+
     ``allowed`` [B, V] bool: grammar-constrained decoding's per-step
     token mask (engine/grammar.py). Applied AFTER bias — a +100
     logit_bias must not defeat a grammar guarantee — and before
     candidate extraction, so reported logprobs renormalize over the
     allowed set (guided-decoding semantics)."""
-    B, V = logits.shape
+    B = logits.shape[0]
     logits = logits.astype(jnp.float32)
-    if penalties is not None:
-        presence, frequency, counts = penalties
-        c = counts.astype(jnp.float32)
-        logits = logits - presence[:, None] * (c > 0) - frequency[:, None] * c
-    if bias is not None:
-        b_ids, b_vals = bias
-        rows = jnp.broadcast_to(
-            jnp.arange(B, dtype=jnp.int32)[:, None], b_ids.shape)
-        # padding id -1 would WRAP to column V-1 (jax normalizes negative
-        # indices before mode="drop" applies — verified), so zero the
-        # padded values explicitly; mode="drop" still guards any
-        # out-of-range positive id
-        b_vals = jnp.where(b_ids >= 0, b_vals.astype(jnp.float32), 0.0)
-        logits = logits.at[rows, jnp.maximum(b_ids, 0)].add(
-            b_vals, mode="drop")
-    if allowed is not None:
-        logits = jnp.where(allowed, logits, NEG_INF)
-    C = min(MAX_CANDIDATES, V)
 
-    # --- candidate extraction (sorted descending) ---------------------
-    # TPU: approx_max_k is the hardware-native bucketed reduction (exact
-    # top_k measured 2.6 ms/step at 128K vocab; approx ~free). Recall
-    # caveats and the greedy-exactness argument: module docstring.
-    exact = os.environ.get("LLMK_EXACT_SAMPLING", "0") == "1"
-    if jax.default_backend() == "tpu" and V > 4 * C and not exact:
-        cand_logits, cand_idx = jax.lax.approx_max_k(logits, C)
+    def shaped_candidates():
+        return _candidates(_shape_logits(logits, penalties, bias), allowed)
+
+    if shaped is None or (penalties is None and bias is None):
+        cand_logits, cand_idx, lse = shaped_candidates()
     else:
-        cand_logits, cand_idx = jax.lax.top_k(logits, C)     # [B, C] each
+        cand_logits, cand_idx, lse = jax.lax.cond(
+            shaped, shaped_candidates, lambda: _candidates(logits, allowed))
+    C = cand_logits.shape[1]
 
     rank = jnp.arange(C, dtype=jnp.int32)[None, :]           # [1, C]
     k = jnp.where(top_k <= 0, C, jnp.minimum(top_k, C))[:, None]
     keep_k = rank < k
 
-    # softmax over the FULL vocab (so probabilities and the top-p cut are
-    # computed against the true distribution, not the truncated one)
-    lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)   # [B, 1]
     cand_probs = jnp.exp(cand_logits - lse)                  # [B, C]
     cumprob = jnp.cumsum(cand_probs, axis=-1)
     # keep tokens whose cumulative prob *before* them is < top_p (always

@@ -551,6 +551,17 @@ def engine_metrics(registry: Registry) -> dict:
             "never ran, a step no longer than two leads, multihost). "
             "late over all is the miss rate",
             registry, label_names=("when",)),
+        "decode_windows": Counter(
+            "llm_decode_windows_total",
+            "Decode windows launched, by what their rows asked of the "
+            "sampler (booked on the host from the window's packed rows, "
+            "with the predicate the executable evaluates on them): "
+            "shaped=a live row carries a presence or frequency penalty or "
+            "a logit_bias entry, so every token step of the window keeps "
+            "the penalty counts, applies the penalties and scatters the "
+            "biases over [slots, vocab]; plain=no live row does, and the "
+            "window's token steps skip all three",
+            registry, label_names=("sampler",)),
         # the expert layers (ops/moe.py), booked by the engine where a
         # dispatch's tokens are read (Engine._book_moe); kind is the
         # dispatch's: prefill | chunk | decode. All five are sums over
@@ -658,7 +669,7 @@ def engine_metrics(registry: Registry) -> dict:
         m["first_tokens"].labels(delivered=delivered)
     # likewise the dispatch counters, for every kind and host there is
     from llms_on_kubernetes_tpu.engine.ledger import (
-        DECODE_LAUNCH_RULES, IDLE_HOSTS, KINDS, MOE_STATS,
+        DECODE_LAUNCH_RULES, IDLE_HOSTS, KINDS, MOE_STATS, SAMPLERS,
     )
 
     m["prefix_reuse_skipped"].labels(why="recurrent_state")
@@ -673,6 +684,8 @@ def engine_metrics(registry: Registry) -> dict:
             m["moe_" + stat].labels(kind=kind)
     for when in DECODE_LAUNCH_RULES:
         m["decode_launches"].labels(when=when)
+    for sampler in SAMPLERS:
+        m["decode_windows"].labels(sampler=sampler)
     for kind in KINDS:
         for series in ("dispatches", "dispatch_device_seconds",
                        "dispatch_behind_seconds", "dispatch_enqueue_seconds"):

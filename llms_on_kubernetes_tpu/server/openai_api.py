@@ -393,7 +393,8 @@ class EngineLoop(threading.Thread):
                 counts = {"path_tokens": ("path", dict(
                               eng.path_tokens, decode=eng.decode_tokens)),
                           "ssm_positions": ("path", eng.ssm_positions),
-                          "attn_window_rows": ("rows", eng.window_rows)}
+                          "attn_window_rows": ("rows", eng.window_rows),
+                          "decode_windows": ("sampler", eng.decode_windows)}
                 for name, (label, by_value) in counts.items():
                     for path, v in by_value.items():
                         new = v - self._path_seen[name, path]

@@ -128,3 +128,136 @@ def test_sampling_param_validation():
     # boundary values are accepted
     eng.submit([1], SamplingParams(top_k=64, presence_penalty=2.0,
                                    frequency_penalty=-2.0, max_tokens=1))
+
+
+# ---------------------------------------------------------------------------
+# PR 53: a slot's counts are kept only while its request asks for a penalty
+# ---------------------------------------------------------------------------
+
+def _finish(eng, reqs):
+    for _ in range(2000):
+        if all(r.finished for r in reqs):
+            return
+        eng.step()
+    raise AssertionError("requests did not finish")
+
+
+@pytest.mark.parametrize("async_sched", [False, True])
+@pytest.mark.parametrize("preempt", [False, True])
+def test_slot_reuse_after_a_plain_request_starts_from_clean_counts(
+        async_sched, preempt):
+    """The stale-count hazard: a plain request runs and finishes on a slot
+    and keeps no counts there, so whatever the slot's row held stays (here:
+    fifty on every token the next answer holds, put there by hand), and a
+    request with a frequency
+    penalty admitted to that slot next produces exactly what it produces
+    alone on a fresh engine: its admission resets the slot's counts, a
+    preemption and resume in its middle rebuilds them from the replayed
+    output."""
+    pen = SamplingParams(max_tokens=14, frequency_penalty=0.8, **GREEDY)
+    plain = SamplingParams(max_tokens=10, **GREEDY)
+    prompt = [3, 17, 9, 5]
+    alone = make_engine(async_scheduling=async_sched).generate(prompt, pen)
+    assert alone != make_engine(async_scheduling=async_sched).generate(
+        prompt, SamplingParams(max_tokens=14, **GREEDY))
+
+    kw = dict(num_pages=7, pages_per_slot=8, max_decode_slots=2) if preempt \
+        else dict(max_decode_slots=1)
+    eng = make_engine(async_scheduling=async_sched, **kw)
+    for s in range(eng.config.max_decode_slots):
+        _finish(eng, [eng.submit([30 + s, 2, 8], plain)])
+    assert not np.asarray(eng.token_counts).any()   # plain: nothing counted
+    eng.token_counts = eng.token_counts.at[:, jnp.asarray(alone)].add(50)
+
+    before = eng.preemptions
+    first = eng.submit([40, 2, 8], SamplingParams(max_tokens=14, **GREEDY))
+    req = eng.submit(prompt, pen)       # the younger: the one preempted
+    _finish(eng, [first, req])
+    assert req.output == alone
+    if preempt:
+        assert eng.preemptions > before
+    assert eng.decode_windows["plain"] > 0 < eng.decode_windows["shaped"]
+
+
+def _packed_rows(rows):
+    """A decode window's packed rows [len(rows), _DEC_COLS + 1] from
+    ``(length, presence, frequency, first bias id)`` a row."""
+    from llms_on_kubernetes_tpu.engine import engine as E
+
+    packed = np.zeros((len(rows), E._DEC_COLS + 1), np.int32)
+    packed[:, E._BIAS_DEC:E._BIAS_DEC + E.LOGIT_BIAS_SLOTS] = -1
+    for i, (length, presence, frequency, bias_id) in enumerate(rows):
+        packed[i, 0] = length
+        packed[i, 8] = np.float32(presence).view(np.int32)
+        packed[i, 9] = np.float32(frequency).view(np.int32)
+        packed[i, E._BIAS_DEC] = bias_id
+    return packed
+
+
+IDLE, LIVE = 0, 9
+WINDOWS = {
+    # name: (rows, penalised rows, shaped)
+    "all_plain": ([(LIVE, 0.0, 0.0, -1), (LIVE, 0.0, 0.0, -1)], [], False),
+    "presence": ([(LIVE, 0.0, 0.0, -1), (LIVE, 0.5, 0.0, -1)], [1], True),
+    "frequency_negative": ([(LIVE, 0.0, -0.25, -1)], [0], True),
+    "bias_only": ([(LIVE, 0.0, 0.0, -1), (LIVE, 0.0, 0.0, 7)], [], True),
+    "bias_id_zero": ([(LIVE, 0.0, 0.0, 0)], [], True),
+    "idle_row_with_penalty": ([(IDLE, 1.0, 1.0, -1), (LIVE, 0.0, 0.0, -1)],
+                              [], False),
+    "idle_row_with_bias": ([(IDLE, 0.0, 0.0, 3), (LIVE, 0.0, 0.0, -1)],
+                           [], False),
+    "minus_zero_penalty": ([(LIVE, -0.0, -0.0, -1)], [], False),
+    "denormal_penalty": ([(LIVE, 1e-45, 0.0, -1)], [0], True),
+    "nan_penalty": ([(LIVE, float("nan"), 0.0, -1)], [0], True),
+    "no_live_row": ([(IDLE, 0.0, 0.0, -1)], [], False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WINDOWS))
+def test_host_booking_agrees_with_the_device_predicate(name):
+    """``_window_asks`` on the host's numpy rows (what
+    ``llm_decode_windows_total{sampler}`` books) and jitted on the same rows
+    as the executable sees them give the same answer: idle rows do not
+    count, ``-0.0`` is no penalty on either side, a denormal is one on
+    both (bits, so no flush-to-zero rule can part them)."""
+    from llms_on_kubernetes_tpu.engine import engine as E
+
+    rows, penalised, shaped = WINDOWS[name]
+    packed = _packed_rows(rows)
+    host_pen, host_shaped = E._window_asks(packed)
+    dev_pen, dev_shaped = jax.jit(E._window_asks)(jnp.asarray(packed))
+    assert np.flatnonzero(host_pen).tolist() == penalised
+    assert bool(host_shaped) is shaped
+    np.testing.assert_array_equal(np.asarray(dev_pen), host_pen)
+    assert bool(dev_shaped) is shaped
+
+    eng = Engine.__new__(Engine)        # the booking alone
+    eng.decode_windows = {"plain": 0, "shaped": 0}
+    assert eng._book_sampler(packed) == ("shaped" if shaped else "plain")
+    assert eng.decode_windows == {"plain": int(not shaped),
+                                  "shaped": int(shaped)}
+
+
+def test_windows_by_sampler_on_the_metrics_page_and_the_dispatch_records():
+    """``llm_decode_windows_total{sampler}`` exists with both children, and
+    a window's record in ``GET /debug/engine`` says which it was."""
+    from llms_on_kubernetes_tpu.server import metrics
+
+    text = metrics.Registry()
+    m = metrics.engine_metrics(text)
+    assert m["decode_windows"].name == "llm_decode_windows_total"
+    page = text.render()
+    for sampler in ("plain", "shaped"):
+        assert f'llm_decode_windows_total{{sampler="{sampler}"}} 0' in page
+
+    eng = make_engine()
+    _finish(eng, [eng.submit([1, 2, 3], SamplingParams(max_tokens=5, **GREEDY))])
+    _finish(eng, [eng.submit([1, 2, 3], SamplingParams(
+        max_tokens=5, logit_bias=((4, 2.0),), **GREEDY))])
+    records = [d for d in eng.ledger.dispatches_view(64)
+               if d["kind"] == "decode"]
+    assert {d["sampler"] for d in records} == {"plain", "shaped"}
+    assert sum(d["sampler"] == "shaped" for d in records) == \
+        eng.decode_windows["shaped"] > 0
+    assert not any("sampler" in d for d in eng.ledger.dispatches_view(64)
+                   if d["kind"] != "decode")

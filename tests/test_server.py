@@ -628,6 +628,47 @@ def test_labelled_counter_has_every_label_from_the_start(series, label,
     with_client(body)
 
 
+def test_decode_windows_by_sampler_on_metrics_and_debug_engine():
+    """``llm_decode_windows_total{sampler}`` starts with both labels at 0;
+    requests that ask for nothing move ``plain`` alone, one with a
+    ``logit_bias`` moves ``shaped``; a decode window's record in
+    ``GET /debug/engine`` says which it ran."""
+    def counts(text):
+        return {v: float(line.rsplit(" ", 1)[1])
+                for line in text.splitlines() for v in ("plain", "shaped")
+                if line.startswith(
+                    'llm_decode_windows_total{sampler="%s"}' % v)}
+
+    async def settled(client, done):
+        for _ in range(100):
+            got = counts(await (await client.get("/metrics")).text())
+            if done(got):
+                break
+            await asyncio.sleep(0.02)
+        return got
+
+    async def body(client):
+        got = counts(await (await client.get("/metrics")).text())
+        assert got == {"plain": 0.0, "shaped": 0.0}
+        await client.post("/v1/completions", json={
+            "prompt": "abc", "max_tokens": 12, "temperature": 0})
+        got = await settled(client, lambda c: c["plain"] >= 2.0)
+        assert got["plain"] >= 2.0 and got["shaped"] == 0.0
+        plain = got["plain"]
+        await client.post("/v1/completions", json={
+            "prompt": "abc", "max_tokens": 12, "temperature": 0,
+            "logit_bias": {"101": 4.0}})
+        got = await settled(client, lambda c: c["shaped"] >= 2.0)
+        assert got["shaped"] >= 2.0 and got["plain"] == plain
+        recs = (await (await client.get("/debug/engine")).json())[
+            "dispatches"]
+        said = [d.get("sampler") for d in recs if d["kind"] == "decode"]
+        assert said.count("shaped") == got["shaped"]
+        assert said.count("plain") == got["plain"]
+        assert not any("sampler" in d for d in recs if d["kind"] != "decode")
+    with_client(body)
+
+
 def test_debug_engine_lists_dispatch_records():
     """GET /debug/engine carries, beside its frames, the ledger's newest
     dispatch records; ``?limit`` trims both."""

@@ -782,6 +782,8 @@ def test_jamba_decode_window_keeps_the_mamba_state_in_place(
 
     monkeypatch.setattr(attention, "pallas_mode", lambda: "compiled")
     monkeypatch.setenv("LLMK_UNROLL_LAYERS", "1")
+    # the sampler's candidates as the chip takes them (approx_max_k)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, state, compiled = _decode_window(
         one_chip, "jamba2-3b", slots=128, pps=32,
         pool_shape=(1, 2 * 4097, 64, 128))
@@ -834,6 +836,21 @@ def test_jamba_decode_window_keeps_the_mamba_state_in_place(
     others = [ln for ln in scheduled if ln not in calls
               and any(sh in result(ln) for sh in window[:4])]
     assert not others, others[:3]
+    # PR 53: the penalty counts' update and the sampler's [slots, vocab]
+    # work each sit in a conditional of this one executable. The first
+    # hands the counts through in place (nothing copies them); out of the
+    # second come the candidates and the log-sum-exp alone (without the
+    # barrier in sampling._candidates XLA hoists the branches' common tail
+    # out and each branch hands it two float32 [128, 65536] arrays)
+    conds = [ln for ln in scheduled if " conditional(" in ln]
+    assert len(conds) == 2, len(conds)
+    assert sorted("[128,65536]" in result(ln) for ln in conds) == [
+        False, True], [result(ln) for ln in conds]
+    assert "s32[128,65536]" in result(
+        next(ln for ln in conds if "[128,65536]" in result(ln)))
+    assert not [ln for ln in hlo.splitlines()
+                if (" copy(" in ln or " copy-start(" in ln)
+                and "s32[128,65536]" in ln.split("=", 1)[1][:60]]
 
 
 def test_lfm2_conv_layers_take_the_one_pass_token_step(one_chip, no_cache,

@@ -283,23 +283,32 @@ class _NoDMA:
         return self._Copy()
 
 
-def _kernel_sides():
-    """(name, module) of this tree's ops/pallas_paged.py and, where the
-    chip call brought it, the parent commit's (.scratch/parent: the verify
-    skill's recipe)."""
+def _parent_module(rel, name):
+    """The parent commit's module ``rel`` of the package, under another
+    name, where the chip call brought the commit (.scratch/parent: the
+    verify skill's recipe); None where it did not."""
     import importlib.util
     import os
+    import sys
 
+    path = ".scratch/parent/llms_on_kubernetes_tpu/" + rel
+    if not os.path.exists(path):
+        return None
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod         # a module's dataclasses look it up there
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _kernel_sides():
+    """(name, module) of this tree's ops/pallas_paged.py and, where the
+    chip call brought it, the parent commit's."""
     from llms_on_kubernetes_tpu.ops import pallas_paged
 
-    sides = [("change", pallas_paged)]
-    path = ".scratch/parent/llms_on_kubernetes_tpu/ops/pallas_paged.py"
-    if os.path.exists(path):
-        spec = importlib.util.spec_from_file_location("parent_paged", path)
-        parent = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(parent)
-        sides.insert(0, ("parent", parent))
-    return sides
+    parent = _parent_module("ops/pallas_paged.py", "parent_paged")
+    return ([("parent", parent)] if parent else []) + [
+        ("change", pallas_paged)]
 
 
 def test_cell_kernel_time_fetch_attend_both(monkeypatch):
@@ -354,6 +363,153 @@ def test_cell_kernel_time_fetch_attend_both(monkeypatch):
                       "attend_only_us": round(attend, 2),
                       "both_us_a_live_row": round(both / live, 3)}
     _report("pr37_kernel_split", said)
+
+
+def _sampler_sides():
+    """(name, engine module, sampling module) of this tree and, where the
+    chip call brought it, of the parent commit."""
+    from llms_on_kubernetes_tpu.engine import engine, sampling
+
+    parent = [_parent_module(f"engine/{name}.py", "parent_" + name)
+              for name in ("engine", "sampling")]
+    return ([("parent", *parent)] if all(parent) else []) + [
+        ("change", engine, sampling)]
+
+
+# slots, vocabulary, rows live: the jamba2-3b.long-answers and the
+# mellum2-12b.long-prompts cells' token steps
+SAMPLER_SHAPES = [(128, 65536, 56), (48, 98304, 25)]
+
+
+@pytest.mark.parametrize("slots,vocab,live", SAMPLER_SHAPES)
+def test_cell_sampler_time_plain_against_shaped(slots, vocab, live):
+    """What a token step does OUTSIDE the model at a cell's [slots, vocab]:
+    the penalty counts' update and ``sample()`` over float32 logits, 64
+    steps chained in one executable (the counts and the logits in place; a
+    step's logits are new ones, as a head's product is), on rows that ask
+    for nothing and on the same rows with one penalty and one logit_bias
+    among them. us a step; the parent's code beside it where the call
+    brought .scratch/parent (it treats the two alike)."""
+    import inspect
+    import time
+
+    n_steps = 64
+    rng = np.random.default_rng(vocab)
+    logits = jnp.asarray(rng.normal(size=(slots, vocab)), jnp.float32)
+    toks = jnp.asarray(rng.integers(0, vocab, slots), jnp.int32)
+
+    def rows(E, asking):
+        packed = np.zeros((slots, E._DEC_COLS + 1), np.int32)
+        packed[:, 5] = np.float32(1.0).view(np.int32)
+        packed[:, E._BIAS_DEC:E._BIAS_DEC + E.LOGIT_BIAS_SLOTS] = -1
+        packed[:live, 0] = 100
+        if asking:
+            packed[0, 8:10] = np.float32(0.5).view(np.int32)
+            packed[1, E._BIAS_DEC] = 17
+            packed[1, E._BIAS_DEC + E.LOGIT_BIAS_SLOTS] = \
+                np.float32(2.0).view(np.int32)
+        return jnp.asarray(packed)
+
+    def time_us(E, S, packed):
+        looks = "shaped" in inspect.signature(S.sample).parameters
+
+        @jax.jit
+        def chain(logits, counts, toks, packed):
+            def f32(col):
+                return jax.lax.bitcast_convert_type(col, jnp.float32)
+
+            lengths0 = packed[:, 0]
+            penalties = f32(packed[:, 8]), f32(packed[:, 9])
+            bias = E._unpack_bias(packed, E._BIAS_DEC)
+            active, kw = lengths0 > 0, {}
+            if looks:
+                penalised, shaped = E._window_asks(packed)
+                active, kw = active & penalised, {"shaped": shaped}
+
+            def step(j, c):
+                logits, counts, cur = c
+                counts = E._count_decode_tokens(counts, cur, active)
+                res = S.sample(
+                    logits, E._slot_keys(jax.random.key(0), packed[:, 6],
+                                         lengths0 + j),
+                    f32(packed[:, 4]), packed[:, 3], f32(packed[:, 5]),
+                    penalties=(*penalties, counts), bias=bias, **kw)
+                logits = jax.lax.dynamic_update_slice(
+                    logits, res.logprobs[:1, None], (0, 0))
+                return logits, counts, res.tokens
+
+            return jax.lax.fori_loop(0, n_steps, step, (logits, counts, toks))
+
+        best = float("inf")
+        for _ in range(4):                      # the first run compiles
+            args = (logits + 0, jnp.zeros((slots, vocab), jnp.int32), toks,
+                    packed)
+            jax.block_until_ready(args)
+            t0 = time.perf_counter()
+            jax.block_until_ready(chain(*args))
+            best = min(best, time.perf_counter() - t0)
+        return round(best / n_steps * 1e6, 1)
+
+    said = {"slots": slots, "vocab": vocab, "live_rows": live}
+    for name, E, S in _sampler_sides():
+        said[name] = {"plain_rows_us": time_us(E, S, rows(E, False)),
+                      "asking_rows_us": time_us(E, S, rows(E, True))}
+    _report(f"pr53_sampler_{slots}x{vocab}", said)
+    plain, asking = said["change"].values()
+    assert plain < asking, said
+
+
+@pytest.mark.parametrize("slots,vocab", [s[:2] for s in SAMPLER_SHAPES])
+def test_cell_sampler_branches_give_the_same_bits(slots, vocab):
+    """``sample()`` at a cell's [slots, vocab] on the chip's own candidate
+    extraction (``approx_max_k``, which no CPU test runs): rows that ask
+    for nothing get the same tokens, log-probabilities and alternatives,
+    bit for bit, from the plain branch, from the shaped branch and from the
+    unconditioned call; rows among which one carries a penalty and one a
+    bias get from the shaped branch what the unconditioned call gives. (The
+    benchmark's output check probes with ``max_tokens`` 1, which the
+    PREFILL samples: it never enters a decode window's conditional.)"""
+    from llms_on_kubernetes_tpu.engine import sampling
+
+    rng = np.random.default_rng(slots)
+    logits = jnp.asarray(rng.normal(size=(slots, vocab)) * 3, jnp.float32)
+    counts = jnp.asarray(rng.integers(0, 3, (slots, vocab)), jnp.int32)
+    keys = jax.random.split(jax.random.key(1), slots)
+    temps = jnp.where(jnp.arange(slots) % 2 == 0, 0.0, 0.8)
+    top_k = jnp.full((slots,), 40, jnp.int32)
+    top_p = jnp.full((slots,), 0.95, jnp.float32)
+    zeros = jnp.zeros((slots,), jnp.float32)
+    no_ids = jnp.full((slots, 32), -1, jnp.int32)
+    vals = jnp.zeros((slots, 32), jnp.float32)
+
+    # (the logits and the counts go in as ARGUMENTS: closed over they are
+    # constants, and XLA folds the branch that reads nothing else on the
+    # host, in another order of summation)
+    @jax.jit
+    def draw(logits, counts, penalty, ids, vals, shaped):
+        return sampling.sample(
+            logits, keys, temps, top_k, top_p,
+            penalties=(penalty, penalty, counts), bias=(ids, vals),
+            shaped=shaped).host_pack()
+
+    def packs(penalty, ids, vals):
+        got = [np.asarray(draw(logits, counts, penalty, ids, vals, shaped))
+               for shaped in (jnp.bool_(False), jnp.bool_(True), None)]
+        return got
+
+    plain, shaped, always = packs(zeros, no_ids, vals)
+    _report(f"pr53_sampler_bits_{slots}x{vocab}", {
+        "rows": slots, "differing_ints_plain_vs_shaped": int(
+            (plain != shaped).sum()), "of": int(plain.size),
+        "rows_with_another_token": int((plain[:, 0] != shaped[:, 0]).sum())})
+    np.testing.assert_array_equal(plain, shaped)
+    np.testing.assert_array_equal(plain, always)
+    penalty = zeros.at[0].set(0.5)
+    ids, vals = no_ids.at[1, 0].set(17), vals.at[1, 0].set(50.0)
+    _, asked, always = packs(penalty, ids, vals)
+    np.testing.assert_array_equal(asked, always)
+    assert asked[1, 0] == 17                # the bias made it the argmax
+    assert (asked[2:] == plain[2:]).all() and (asked[:2] != plain[:2]).any()
 
 
 # pairs a layer sorts at 64 experts top-4: the lfm2-24b-a2b.long-answers
